@@ -20,22 +20,14 @@ Two legs on one (n=8, k=6) MDS-coded GEMM fleet:
 CPU-only, seconds. ``python examples/device_coord_demo.py``
 """
 
-import os
 import time
-
-_CACHE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "tests", ".jax_cache",
-)
 
 import jax
 
+from mpistragglers_jl_tpu.utils.compile_cache import wire_compile_cache
+
 jax.config.update("jax_enable_x64", True)  # bit-identical parity leg
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-except Exception:
-    pass  # cache is an optimization, never a requirement
+wire_compile_cache()
 
 import numpy as np
 

@@ -42,7 +42,6 @@ path and the single-chip execution mode.
 from __future__ import annotations
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 import numpy as np
 

@@ -45,7 +45,6 @@ from functools import partial
 from typing import Any
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

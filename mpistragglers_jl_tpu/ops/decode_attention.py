@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, _sds, _use_interpret
+from .flash_attention import _sds, _use_interpret
 
 _NEG = -1e30
 _LANE = 128
@@ -307,7 +307,7 @@ def quantized_decode_attention(
             pltpu.VMEM((rows, _LANE), jnp.float32),
             pltpu.VMEM((rows, _LANE), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -389,7 +389,7 @@ def _paged_call(q, cache_l: dict, pos, scale, page_table, P: int,
         kern,
         grid_spec=grid_spec,
         out_shape=_sds((B, rows, D), q.dtype, q),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -33,20 +33,12 @@ from __future__ import annotations
 import functools
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # matches parallel/ring_attention.py: large-negative mask
 _LANE = 128  # TPU lane width; m/l scratch is broadcast across lanes
-
-# pltpu.CompilerParams is the current spelling; older toolchains (the
-# CPU-only CI image lags the chip host) ship it as TPUCompilerParams —
-# same kwargs, so the kernels stay loadable on both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 def _grid_params():
@@ -56,13 +48,25 @@ def _grid_params():
     and must run in order. Without this annotation Mosaic assumes every
     grid axis is sequential — measured 20% slower on the round-3 chip
     (docs/PERF.md)."""
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() not in ("tpu",)
+    """Pallas kernels compile through Mosaic on ``tpu`` and run in the
+    Pallas interpreter on ``cpu`` (the test mesh). Any other platform
+    is an error: silently interpreting there would report a kernel
+    result the kernel never produced."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default JAX backend is {platform!r}"
+    )
 
 
 def _pick_block(L: int, block: int) -> int:

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P

@@ -78,7 +78,6 @@ import time
 from typing import Callable, Sequence
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P

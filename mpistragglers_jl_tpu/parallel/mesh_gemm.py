@@ -19,7 +19,6 @@ per-epoch traffic = B broadcast + one reduce-scatter over ICI.
 from __future__ import annotations
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

@@ -20,7 +20,6 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import numpy as np
 from jax.sharding import Mesh
 

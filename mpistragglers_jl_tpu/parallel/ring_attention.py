@@ -38,7 +38,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-from .. import _jax_compat  # noqa: F401  (installs older-JAX aliases)
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -2,11 +2,11 @@
 
 Stdlib-``ast``-only analyzers for the invariants the codebase
 otherwise encodes as prose and single runtime probes: the jax-free
-package root (GC001), the ``_jax_compat`` reach-through discipline
-(GC002), tracer hygiene inside jitted/scan code (GC003), strictly
-opt-in observability (GC004), cross-thread lock discipline (GC005),
-and — the v2 interprocedural set (ISSUE 8) — lock-order acyclicity
-with no blocking calls under a lock (GC006), RingAlloc slot/pin
+package root (GC001), tracer hygiene inside jitted/scan code
+(GC003), strictly opt-in observability (GC004), cross-thread lock
+discipline (GC005), and — the v2 interprocedural set (ISSUE 8) —
+lock-order acyclicity with no blocking calls under a lock (GC006),
+RingAlloc slot/pin
 lifetime (GC007), wall-clock discipline for the sim plane and the
 timing-margin flake family (GC008), cross-language protocol
 drift between transport.py and transport.cpp (GC009), and — ISSUE

@@ -7,10 +7,6 @@ Rule catalog (details in each module's docstring and docs/API.md):
 ====== ==================== ==========================================
 GC001  import-hygiene       package-root import closure stays free of
                             jax/accelerator stacks (module-level walk)
-GC002  compat-shim          shimmed jax APIs reached only after a
-                            module-level ``_jax_compat`` import;
-                            ``pltpu.CompilerParams`` only in
-                            ops/flash_attention.py
 GC003  tracer-leak          no host clocks / host RNG / ``.item()`` /
                             casts or Python branches on traced args in
                             jitted functions and lax bodies
@@ -58,7 +54,6 @@ GC013  stale-suppression    a `# graftcheck: disable=` comment that
 
 from . import (  # noqa: F401  (import == register)
     gc001_import_hygiene,
-    gc002_compat_shim,
     gc003_tracer_leak,
     gc004_dark_path,
     gc005_lock_discipline,

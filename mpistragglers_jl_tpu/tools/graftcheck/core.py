@@ -680,7 +680,7 @@ def run(
     findings: list[Finding] = []
     for mod in mods:
         # keyed on (relpath, content sha) — NOT content alone: checker
-        # results are path-dependent (GC002's CompilerParams home), so
+        # results are path-dependent (GC011's witness home), so
         # two identical-content files at different paths must never
         # replay each other's records
         key = f"{mod.relpath}\0{mod.sha}"

@@ -53,7 +53,7 @@ def fused_window(xs, mesh):
         out = jax.lax.scan(body, jnp.zeros(()), x)
         return out, w0
 
-    f = jax.shard_map(  # graftcheck: disable=GC002  (fixture file)
+    f = jax.shard_map(
         window, mesh=mesh, in_specs=None, out_specs=None
     )
     return f(xs)

@@ -47,7 +47,7 @@ def fused_window(xs, mesh, payload=None):
 
         return jax.lax.scan(body, jnp.zeros(()), x)
 
-    f = jax.shard_map(  # graftcheck: disable=GC002  (fixture file)
+    f = jax.shard_map(
         window, mesh=mesh, in_specs=None, out_specs=None
     )
     return f(xs), time.perf_counter() - t0

@@ -45,7 +45,6 @@ def _keys(findings):
     [
         ("gc001_bad_pkg", [("GC001", 6)]),
         ("gc001_hermetic_bad_pkg", [("GC001", 6)]),
-        ("gc002_bad.py", [("GC002", 11), ("GC002", 17), ("GC002", 21)]),
         (
             # lines 48/51 are the round-17 shard_map extension: a host
             # clock in the shard_map-wrapped callable itself and an
@@ -132,7 +131,7 @@ def test_bad_fixture_exact_findings(bad, expected):
 
 @pytest.mark.parametrize(
     "good",
-    ["gc001_good_pkg", "gc001_hermetic_good_pkg", "gc002_good.py",
+    ["gc001_good_pkg", "gc001_hermetic_good_pkg",
      "gc003_good.py", "gc004_good.py", "gc005_good.py",
      "gc010_good.py", "gc011_good_pkg", "gc012_good_pkg",
      "gc013_good.py"],
@@ -331,25 +330,22 @@ def test_missing_baseline_is_config_error():
 
 
 def test_identical_content_distinct_paths_not_conflated(tmp_path):
-    """GC002's verdict depends on the file's PATH (CompilerParams is
-    legal only in its home module), so two identical-content files
+    """GC011's verdict depends on the file's PATH (`def digest` is
+    legal only in sim/workload.py), so two identical-content files
     must be analyzed separately — the result record is keyed on
     (relpath, sha), not content alone (review finding)."""
-    pkg = tmp_path / "pkg" / "ops"
+    pkg = tmp_path / "pkg" / "sim"
     pkg.mkdir(parents=True)
     (tmp_path / "pkg" / "__init__.py").write_text("")
     (pkg / "__init__.py").write_text("")
-    src = (
-        "from jax.experimental.pallas import tpu as pltpu\n"
-        "def params():\n"
-        "    return pltpu.CompilerParams()\n"
-    )
-    (pkg / "flash_attention.py").write_text(src)  # the home: legal
-    (pkg / "attn_copy.py").write_text(src)  # same bytes: violation
+    src = "def digest(report):\n    return hash(report)\n"
+    (pkg / "workload.py").write_text(src)  # the home: legal
+    (pkg / "other.py").write_text(src)  # same bytes: violation
     for cache in (None, str(tmp_path / "c.json")):
-        res = run([str(tmp_path / "pkg")], cache_path=cache)
+        res = run([str(tmp_path / "pkg")], rules=["GC011"],
+                  cache_path=cache)
         assert [(f.rule, f.path) for f in res.fresh] == [
-            ("GC002", "pkg/ops/attn_copy.py")
+            ("GC011", "pkg/sim/other.py")
         ], [f.format() for f in res.fresh]
 
 
@@ -503,10 +499,10 @@ def test_package_self_run_is_clean():
 
     res = run([_PKG], baseline_path=DEFAULT_BASELINE)
     assert res.ok, "\n".join(f.format() for f in res.fresh)
-    # GC001-GC005 + the v2 set (ISSUE 8) + GC010 shed-by-name (r20)
-    # + GC011 witness-single-source (r21) + GC012 replay-purity and
-    # GC013 stale-suppression (ISSUE 18)
-    assert res.n_rules == 13
+    # GC001 + GC003-GC005 + the v2 set (ISSUE 8) + GC010
+    # shed-by-name (r20) + GC011 witness-single-source (r21) + GC012
+    # replay-purity and GC013 stale-suppression (ISSUE 18)
+    assert res.n_rules == 12
     assert res.n_files > 50  # the whole package, not a subset
 
 
@@ -549,18 +545,18 @@ def test_cli_exit_codes():
             timeout=120,
         )
 
-    bad = cli(os.path.join(_FIX, "gc002_bad.py"),
+    bad = cli(os.path.join(_FIX, "gc003_bad.py"),
               "--baseline", "none", "--no-cache")
     assert bad.returncode == 1
-    assert "GC002" in bad.stdout
-    good = cli(os.path.join(_FIX, "gc002_good.py"),
+    assert "GC003" in bad.stdout
+    good = cli(os.path.join(_FIX, "gc003_good.py"),
                "--baseline", "none", "--no-cache")
     assert good.returncode == 0
     missing = cli("definitely/not/a/path.py")
     assert missing.returncode == 2
     rules = cli("--list-rules")
     assert rules.returncode == 0
-    for rule in ("GC001", "GC002", "GC003", "GC004", "GC005",
+    for rule in ("GC001", "GC003", "GC004", "GC005",
                  "GC006", "GC007", "GC008", "GC009", "GC010",
                  "GC011", "GC012", "GC013"):
         assert rule in rules.stdout
@@ -723,7 +719,7 @@ def test_cli_sarif_report(tmp_path):
     assert loc["region"]["startLine"] == 6
     assert loc["artifactLocation"]["uriBaseId"] == "SRCROOT"
 
-    # in-source suppressions (gc003_bad.py lines 38/56) + '-' = stdout
+    # in-source suppression (gc003_bad.py line 38) + '-' = stdout
     r = cli(os.path.join(_FIX, "gc003_bad.py"),
             "--baseline", "none", "--no-cache", "--sarif", "-", "-q")
     assert r.returncode == 1
@@ -734,9 +730,9 @@ def test_cli_sarif_report(tmp_path):
         s["kind"] for x in doc["runs"][0]["results"]
         for s in x.get("suppressions", [])
     ]
-    assert kinds.count("inSource") == 2
+    assert kinds.count("inSource") == 1
 
-    unwritable = cli(os.path.join(_FIX, "gc002_good.py"),
+    unwritable = cli(os.path.join(_FIX, "gc003_good.py"),
                      "--baseline", "none", "--no-cache",
                      "--sarif", str(tmp_path / "no" / "dir" / "r"))
     assert unwritable.returncode == 2
