@@ -1,0 +1,99 @@
+"""The traffic generator: every seed offers the same requests, in the
+same cyclic order, from another starting point."""
+
+from __future__ import annotations
+
+import json
+from collections import Counter
+from pathlib import Path
+
+import pytest
+
+from chipbench import common, traffic_gen
+
+REPO = Path(__file__).resolve().parents[2]
+MIXES = ["chat_backlog", "mixed_backlog"]
+
+
+def mix(name):
+    return json.loads(
+        (REPO / "chipbench" / "traffic" / f"{name}.json").read_text())
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_the_traffic_is_one_round_repeated(name):
+    t = mix(name)
+    size = t["round"]
+    reqs = traffic_gen.ordered_requests(t)
+    base = traffic_gen.one_round(t)
+    assert len(reqs) == size * t["rounds"]
+    assert reqs[:size] == base
+    for i in range(0, len(reqs), size):
+        assert reqs[i:i + size] == base
+    assert len(set(base)) > size // 2  # not one length, a spread
+
+
+def test_every_seed_offers_the_same_lengths_and_other_contents():
+    t = mix("mixed_backlog")
+    reqs = traffic_gen.ordered_requests(t)[: t["round"]]
+    a = traffic_gen.prompts_for(reqs, 49152, 1)
+    b = traffic_gen.prompts_for(reqs, 49152, 2**31 + 12345)
+    assert [len(x) for x in a] == [len(x) for x in b] == [r[1] for r in reqs]
+    assert Counter(len(x) for x in a) == Counter(r[1] for r in reqs)
+    assert any((x != y).any() for x, y in zip(a, b))
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_a_round_holds_each_class_at_its_share_and_range(name):
+    t = mix(name)
+    base = traffic_gen.one_round(t)
+    count = Counter(r[0] for r in base)
+    for ci, c in enumerate(t["classes"]):
+        assert count[ci] == round(t["round"] * c["share"])
+    for ci, plen, olen in base:
+        c = t["classes"][ci]
+        assert c["prompt"][0] <= plen <= c["prompt"][1]
+        assert c["output"][0] <= olen <= c["output"][1]
+
+
+def test_the_round_spreads_short_and_long():
+    base = traffic_gen.one_round(mix("chat_backlog"))
+    outs = [r[2] for r in base]
+    half = len(outs) // 2
+    # neither half of the round holds much more work than the other
+    assert abs(sum(outs[:half]) - sum(outs[half:])) < 0.1 * sum(outs)
+
+
+def test_shares_that_do_not_fill_the_round_are_refused():
+    t = mix("mixed_backlog")
+    # 0.75 and 0.25 of 6 requests round to 4 and 2, which is not... 6?
+    # 4.5 -> 4 and 1.5 -> 2 happen to fill it; of 10 they give 8 + 2
+    assert len(traffic_gen.one_round({**t, "round": 10})) == 10
+    t["classes"][0]["share"] = 0.5
+    with pytest.raises(ValueError):
+        traffic_gen.one_round(t)
+
+
+def test_quantile_points_are_mid_quantiles():
+    # u = 1/8, 3/8, 5/8, 7/8 of the way from log 1 to log 256
+    assert common.quantile_points(1, 256, 4, "log_uniform") == [2, 8, 32, 128]
+    pts = common.quantile_points(32, 512, 4, "log_uniform")
+    assert pts == sorted(pts) and 32 < pts[0] and pts[-1] < 512
+    with pytest.raises(ValueError):
+        common.quantile_points(0, 10, 5, "uniform")
+
+
+def test_prompt_contents_follow_the_seed():
+    t = mix("chat_backlog")
+    reqs = traffic_gen.ordered_requests(t)[:4]
+    a = traffic_gen.prompts_for(reqs, 1000, 5)
+    b = traffic_gen.prompts_for(reqs, 1000, 5)
+    c = traffic_gen.prompts_for(reqs, 1000, 6)
+    assert all((x == y).all() for x, y in zip(a, b))
+    assert any((x != y).any() for x, y in zip(a, c))
+    assert [len(x) for x in a] == [r[1] for r in reqs]
+
+
+def test_percentile_of_a_weighted_multiset():
+    assert common.weighted_percentile([10, 20], [95, 5], 95) == 10
+    assert common.weighted_percentile([10, 20], [94, 6], 95) == 20
