@@ -346,24 +346,29 @@ def _serving_layer(x, lp, cache_l, pos, cfg, *, kv_slice=None,
         k, v = kv_slice(k), kv_slice(v)
     q, k = _rope_rows(q, pos), _rope_rows(k, pos)
     scale = cfg.head_dim ** -0.5
-    if paged is not None:
-        # kernel route only: the einsum paged tick runs THIS function
-        # with paged=None over per-tick gathered ring views instead
-        # (see _serving_scan_paged)
-        pt, W, P = paged
-        cache_l = _paged_write_rows(cache_l, k, v, pt, jnp.mod(pos, W), P)
-        o = _paged_attention_rows(q, cache_l, pt, pos, scale, P)
-    else:
-        W = cache_l["k"].shape[1]
-        cache_l = _ring_write_rows(cache_l, k, v, jnp.mod(pos, W))
-        o = _ring_attention_rows(q, cache_l, pos, scale,
-                                 use_kernel=use_kernel)
+    # scopes name the K/V traffic (cache write, scores, softmax, p @ v)
+    # and the MLP in a device trace; the projections stay outside both
+    with jax.named_scope("decode_attn"):
+        if paged is not None:
+            # kernel route only: the einsum paged tick runs THIS
+            # function with paged=None over per-tick gathered ring
+            # views instead (see _serving_scan_paged)
+            pt, W, P = paged
+            cache_l = _paged_write_rows(cache_l, k, v, pt,
+                                        jnp.mod(pos, W), P)
+            o = _paged_attention_rows(q, cache_l, pt, pos, scale, P)
+        else:
+            W = cache_l["k"].shape[1]
+            cache_l = _ring_write_rows(cache_l, k, v, jnp.mod(pos, W))
+            o = _ring_attention_rows(q, cache_l, pos, scale,
+                                     use_kernel=use_kernel)
     attn_out = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
     if tp_psum:
         attn_out = jax.lax.psum(attn_out, "tp")
     x = x + attn_out
-    h2 = _ln(x, lp["ln2_s"], lp["ln2_b"])
-    y = _mlp(h2, lp)
+    with jax.named_scope("decode_mlp"):
+        h2 = _ln(x, lp["ln2_s"], lp["ln2_b"])
+        y = _mlp(h2, lp)
     if tp_psum:
         y = jax.lax.psum(y, "tp")
     return x + y + lp["b2"], cache_l
@@ -449,12 +454,12 @@ def _serving_scan_dense(cfg: TransformerConfig, n_inner: int,
     the global routes on the next scheduler construction)."""
 
     @functools.partial(jax.jit, donate_argnums=(4,))
-    def run(params, tok, pos, done, caches, keys):
+    def serving_tick_dense(params, tok, pos, done, caches, keys):
         return _scan_body(params, tok, pos, done, caches, cfg, eos_id,
                           n_inner, keys, temperature=temperature,
                           top_k=top_k, use_kernel=use_kernel)
 
-    return run
+    return serving_tick_dense
 
 
 @functools.lru_cache(maxsize=32)
@@ -484,7 +489,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
     PERF.md byte model)."""
 
     @functools.partial(jax.jit, donate_argnums=(4,))
-    def run(params, tok, pos, done, caches, keys, pt):
+    def serving_tick_paged(params, tok, pos, done, caches, keys, pt):
         W = pt.shape[1] * P
         if use_kernel:
             return _scan_body(
@@ -492,18 +497,20 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
                 keys, temperature=temperature, top_k=top_k,
                 use_kernel=True, paged=(pt, W, P),
             )
-        views = [_paged_gather(cl, pt, W, P) for cl in caches]
+        with jax.named_scope("kv_page_gather"):
+            views = [_paged_gather(cl, pt, W, P) for cl in caches]
         tok, pos, done, views, toks = _scan_body(
             params, tok, pos, done, views, cfg, eos_id, n_inner, keys,
             temperature=temperature, top_k=top_k, use_kernel=False,
         )
-        caches = [
-            _paged_scatter(cl, vw, pt, P)
-            for cl, vw in zip(caches, views)
-        ]
+        with jax.named_scope("kv_page_scatter"):
+            caches = [
+                _paged_scatter(cl, vw, pt, P)
+                for cl, vw in zip(caches, views)
+            ]
         return tok, pos, done, caches, toks
 
-    return run
+    return serving_tick_paged
 
 
 @functools.lru_cache(maxsize=32)
@@ -520,7 +527,7 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
     pool is only read."""
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def run(cache, pages, pt_row, ell):
+    def serving_seed_prefix(cache, pages, pt_row, ell):
         s = jnp.arange(R)
         phys = pt_row[s // P] * P + s % P
         valid = s < ell
@@ -539,7 +546,7 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
             for cl, pl in zip(cache, pages)
         ]
 
-    return run
+    return serving_seed_prefix
 
 
 @functools.lru_cache(maxsize=32)
@@ -554,11 +561,11 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
     read (no donation)."""
 
     @jax.jit
-    def run(caches, pt_row):
+    def serving_gather_ring(caches, pt_row):
         W = pt_row.shape[0] * P
         return [_paged_gather(cl, pt_row[None], W, P) for cl in caches]
 
-    return run
+    return serving_gather_ring
 
 
 @functools.lru_cache(maxsize=32)
@@ -572,8 +579,8 @@ def _place_paged(cfg: TransformerConfig, P: int):
     request's page budget land in the null page."""
 
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
-    def run(caches, ring, tok, pos, done, keys, pt_row, s, tok0, pos0,
-            key):
+    def serving_place_pages(caches, ring, tok, pos, done, keys, pt_row,
+                            s, tok0, pos0, key):
         W = ring[0]["k"].shape[1]
         srows = jnp.arange(W)
         phys = pt_row[srows // P] * P + srows % P
@@ -585,7 +592,7 @@ def _place_paged(cfg: TransformerConfig, P: int):
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
 
-    return run
+    return serving_place_pages
 
 
 @functools.lru_cache(maxsize=32)
@@ -602,7 +609,7 @@ def _copy_pages_paged(cfg: TransformerConfig, P: int):
     bytes nothing reads unmasked) to bound compile count."""
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def run(caches, src, dst):
+    def serving_copy_pages(caches, src, dst):
         def cp(a):
             paged = a.reshape((a.shape[0] // P, P) + a.shape[1:])
             blk = jnp.take(paged, src, axis=0)
@@ -610,7 +617,7 @@ def _copy_pages_paged(cfg: TransformerConfig, P: int):
 
         return [{kk: cp(cl[kk]) for kk in cl} for cl in caches]
 
-    return run
+    return serving_copy_pages
 
 
 def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
@@ -672,7 +679,7 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
             use_kernel=routed,
         )
 
-    f = jax.shard_map(
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs(cfg, mesh), P("dp"), P("dp"), P("dp"),
@@ -686,7 +693,11 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
         check_vma=not _decode_kernel_interpreted(cfg, quantize_kv,
                                                  use_kernel),
     )
-    return jax.jit(f, donate_argnums=(4,))
+
+    def serving_tick_sharded(params, tok, pos, done, caches, keys):
+        return sharded(params, tok, pos, done, caches, keys)
+
+    return jax.jit(serving_tick_sharded, donate_argnums=(4,))
 
 
 # --------------------------------------------------------------------------
@@ -701,13 +712,13 @@ def _extend_chunk_dense(cfg: TransformerConfig, C: int, Lmax: int):
     Cache donated: chunks stream through one arena."""
 
     @functools.partial(jax.jit, donate_argnums=(2,))
-    def run(params, chunk, cache, offset):
+    def serving_prefill_chunk(params, chunk, cache, offset):
         logits, cache = _incremental_forward(
             params, chunk, cache, offset, cfg, prefill=False
         )
         return logits, cache
 
-    return run
+    return serving_prefill_chunk
 
 
 @functools.lru_cache(maxsize=32)
@@ -722,7 +733,7 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     W = _check_ring_cfg(cfg)
 
     @jax.jit
-    def run(cache, last_logits, true_len, last_off, key):
+    def serving_first_token(cache, last_logits, true_len, last_off, key):
         ring = [_ring_from_cache(cl, true_len, W) for cl in cache]
         lg = jnp.take(last_logits[0], true_len - 1 - last_off, axis=0)
         tok0 = _pick_rows(
@@ -731,7 +742,7 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
         )[0]
         return tok0, ring
 
-    return run
+    return serving_first_token
 
 
 @functools.lru_cache(maxsize=32)
@@ -741,7 +752,8 @@ def _place_dense(cfg: TransformerConfig):
     Everything donated — admission is an in-place row write."""
 
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
-    def run(caches, ring, tok, pos, done, keys, s, tok0, pos0, key):
+    def serving_place_ring(caches, ring, tok, pos, done, keys, s, tok0,
+                           pos0, key):
         caches = [
             {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
              for kk in c}
@@ -750,27 +762,61 @@ def _place_dense(cfg: TransformerConfig):
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
 
-    return run
+    return serving_place_ring
 
 
 # --------------------------------------------------------------------------
-# observability (obs/ registry + timeline, strictly opt-in)
+# observability: the tick's phase boundaries go to the profiler always;
+# the obs/ registry + timeline are strictly opt-in
 # --------------------------------------------------------------------------
+#
+# Every phase of ``ServingScheduler.step`` is one ``with`` block around a
+# ``jax.profiler.TraceAnnotation`` (obs/timeline.py: annotate), entered
+# whether or not anything is attached: an open profiler session is the
+# switch, and with none open a boundary costs an atomic check. Names are
+# fixed strings; what varies rides in the arguments. docs/API.md
+# ("Scheduler phases in a profiler trace") is the operator's table.
+
+
+class _LitPhase:
+    """A phase boundary of a LIT tick: the annotation every tick
+    enters, plus the two clock reads from which the span recorder, the
+    registry series and the flight ring are cut — one set of
+    boundaries, not a second set of stamps. Dark ticks enter the bare
+    annotation and read no clock."""
+
+    __slots__ = ("_ann", "t0", "t1")
+
+    def __init__(self, name: str, **args):
+        self._ann = _annotate(name, **args)
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_LitPhase":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
 
 
 class _ServingObs:
     """Instrument bundle for one scheduler, resolved ONCE at
     construction so the tick path only increments/observes. Built only
     when a registry or span recorder is attached — a dark scheduler's
-    tick does no observability work beyond ``is not None`` checks (the
-    tracer's opt-in contract, utils/trace.py), which the no-op
-    overhead test in tests/test_obs.py pins.
+    tick does no observability work beyond ``is not None`` checks and
+    the profiler's phase annotations (the tracer's opt-in contract,
+    utils/trace.py), which the no-op overhead tests in
+    tests/test_obs.py and tests/test_serving_spans.py pin.
     """
 
     def __init__(self, sched: "ServingScheduler", registry, spans):
         self.registry = registry
         self.spans = spans
-        self.annotate = _annotate
         # tokens delivered in the CURRENT tick (admission first-tokens
         # + trimmed decode harvest — the same population as
         # serving_tokens_total, so the per-tick rate and the running
@@ -953,13 +999,14 @@ class _ServingObs:
         c.inc()
 
     def tick_done(
-        self, sched: "ServingScheduler", retired, t0: float,
-        t1: float, t2: float | None,
+        self, sched: "ServingScheduler", retired, tick: _LitPhase,
+        admit: _LitPhase, decode: _LitPhase | None,
+        harvest: _LitPhase | None,
     ) -> None:
-        """t0 tick begin, t1 admissions done, t2 decode scan fetched
-        (None when no slot decoded this tick)."""
-        t3 = time.perf_counter()
-        wall = t3 - t0
+        """The closed phases of the tick just run (``serving.tick`` /
+        ``.admit`` / ``.decode`` / ``.harvest``; the last two None when
+        no slot decoded)."""
+        wall = tick.t1 - tick.t0
         n_toks, self._tick_toks = self._tick_toks, 0
         if self._r:
             self.m_ticks.inc()
@@ -967,7 +1014,7 @@ class _ServingObs:
             self.m_queue.set(sched.pending)
             self.m_active.set(sched.active)
             self.m_tok_rate.set(n_toks / wall if wall > 0 else 0.0)
-            if t2 is not None:
+            if decode is not None:
                 self.m_route.inc()
             for req in retired:
                 self.m_retired[req.reason].inc()
@@ -983,20 +1030,20 @@ class _ServingObs:
                 self.qos_gauges(sched)
         sp = self.spans
         if sp is not None:
-            tick = sched.tick_count
             sp.add(
-                f"tick {tick}", t0, wall, track="scheduler",
-                queue=sched.pending, active=sched.active,
-                tokens=n_toks, retired=len(retired),
+                f"tick {sched.tick_count}", tick.t0, wall,
+                track="scheduler", queue=sched.pending,
+                active=sched.active, tokens=n_toks,
+                retired=len(retired),
             )
-            sp.add("admit", t0, t1 - t0, track="scheduler")
-            if t2 is not None:
-                sp.add("decode", t1, t2 - t1, track="scheduler")
-                sp.add("retire", t2, t3 - t2, track="scheduler")
-            sp.count("queue_depth", sched.pending, t=t3)
-            sp.count("active_slots", sched.active, t=t3)
+            for name, ph in (("admit", admit), ("decode", decode),
+                             ("retire", harvest)):
+                if ph is not None:
+                    sp.add(name, ph.t0, ph.t1 - ph.t0, track="scheduler")
+            sp.count("queue_depth", sched.pending, t=tick.t1)
+            sp.count("active_slots", sched.active, t=tick.t1)
             if sched.paged:
-                sp.count("pages_used", sched.pool.used, t=t3)
+                sp.count("pages_used", sched.pool.used, t=tick.t1)
 
 
 # --------------------------------------------------------------------------
@@ -1168,7 +1215,13 @@ class ServingScheduler:
     watchdog probes; ``exporter=`` (an :class:`~..obs.ObsServer`) to
     register the tick-freshness ``/healthz`` check and the span
     recorder as a ``/trace`` source. With none of them, the tick path
-    does no observability work at all.
+    reads no clock and builds no registry object. What every tick does,
+    attached or not, is enter one ``jax.profiler.TraceAnnotation`` per
+    phase (``serving.tick`` around ``serving.admit`` / ``.decode`` /
+    ``.harvest``; the table is in docs/API.md): an open profiler
+    session sees them on the device trace's clock, and with none open
+    each costs an atomic check. ``spans=`` and ``flight=`` cut their
+    spans at the same boundaries.
     """
 
     def __init__(self, params, cfg: TransformerConfig, *, slots: int = 8,
@@ -1483,9 +1536,11 @@ class ServingScheduler:
 
     def _decode_scan_fetch(self) -> np.ndarray:
         """Run the jitted decode tick and fence the tokens to host."""
-        (self._tok, self._pos, self._done, self._caches,
-         toks) = self._scan(*self._scan_args())
-        return np.asarray(toks)  # (S, n_inner) one fetch per tick
+        with _annotate("serving.decode_dispatch"):
+            (self._tok, self._pos, self._done, self._caches,
+             toks) = self._scan(*self._scan_args())
+        with _annotate("serving.decode_wait"):
+            return np.asarray(toks)  # (S, n_inner) one fetch per tick
 
     def lower_tick(self):
         """The decode tick's program lowered against the live state
@@ -1504,72 +1559,84 @@ class ServingScheduler:
     def step(self) -> list[Request]:
         """One scheduler tick; returns the requests retired in it
         (including any that retire at admission — max_new == 1 or a
-        first-token EOS). When instrumented (``registry=``/``spans=``)
-        the tick records admit/decode/retire spans and the queue/slot/
-        token series; dark, the only additions to the hot path are
-        ``obs is not None`` checks."""
+        first-token EOS). Every phase is a profiler annotation
+        (``serving.tick`` around ``serving.admit`` / ``.decode`` /
+        ``.harvest``, see ``_LitPhase``): a ``jax.profiler`` session
+        sees them on the device trace's clock, and with none open they
+        cost an atomic check each. When instrumented (``registry=`` /
+        ``spans=`` / ``flight=`` / ``exporter=``) the same boundaries
+        also read the clock for the admit/decode/retire spans and the
+        queue/slot/token series; dark, the hot path reads no clock."""
         obs = self._obs
         flight = self._flight
         lit = self._stamp_ticks  # obs, flight, OR exporter attached
-        t0 = time.perf_counter() if lit else 0.0
+        phase = _LitPhase if lit else _annotate
         self.tick_count += 1
         retired: list[Request] = []
-        self._advance_admissions(retired)
-        self._admit_from_queue(retired)
-        t1 = time.perf_counter() if obs is not None else 0.0
-        t2 = None
-        decoding = [
-            s for s, r in enumerate(self._slot_req)
-            if r is not None and s not in self._admitting
-        ]
-        if decoding:
-            if self.paged:
-                # COW pass: every page the next n_inner writes touch
-                # must be exclusively owned BEFORE the jitted scan runs
-                # (the device program never sees shared pages)
-                self._prepare_tick_pages(decoding)
-            if obs is None:
-                host = self._decode_scan_fetch()
-            else:
-                # device-side span: visible inside jax.profiler traces
-                # on real chips, a no-op wherever the profiler is not
-                with obs.annotate("serving.decode_scan"):
+        decode = harvest = None
+        n_admitting = len(self._admitting)
+        n_free = self._slot_req.count(None)
+        with phase(
+            "serving.tick", tick=self.tick_count, queue=self.pending,
+            decoding=self.S - n_free - n_admitting,
+            admitting=n_admitting, free=n_free,
+        ) as tick:
+            with phase("serving.admit") as admit:
+                self._advance_admissions(retired)
+                self._admit_from_queue(retired)
+            decoding = [
+                s for s, r in enumerate(self._slot_req)
+                if r is not None and s not in self._admitting
+            ]
+            if decoding:
+                with phase("serving.decode",
+                           slots=len(decoding)) as decode:
+                    if self.paged:
+                        # COW pass: every page the next n_inner writes
+                        # touch must be exclusively owned BEFORE the
+                        # jitted scan runs (the device program never
+                        # sees shared pages)
+                        self._prepare_tick_pages(decoding)
                     host = self._decode_scan_fetch()
-                t2 = time.perf_counter()
-            if self.paged:
-                for s in decoding:
-                    self._host_pos[s] += self.n_inner
-            for s in decoding:
-                req = self._slot_req[s]
-                n_before = len(req.tokens) if obs is not None else 0
-                req.tokens.extend(int(t) for t in host[s])
-                due = self._retire_if_due(req)
-                if obs is not None:
-                    # count AFTER the retirement trim: the EOS-clamped
-                    # tail the host strips was never delivered to
-                    # anyone, and a tokens/s series inflated by it
-                    # would overstate throughput by up to n_inner-1
-                    # per retiring request
-                    obs.tokens_emitted(
-                        req, len(req.tokens) - n_before, t2
-                    )
-                if due:
-                    self._free_slot(s)
-                    retired.append(req)
+                with phase("serving.harvest") as harvest:
+                    n_tokens = n_retired = 0
+                    for s in decoding:
+                        if self.paged:
+                            self._host_pos[s] += self.n_inner
+                        req = self._slot_req[s]
+                        n_before = len(req.tokens)
+                        req.tokens.extend(int(t) for t in host[s])
+                        due = self._retire_if_due(req)
+                        # count AFTER the retirement trim: the
+                        # EOS-clamped tail the host strips was never
+                        # delivered to anyone, and a tokens/s series
+                        # inflated by it would overstate throughput by
+                        # up to n_inner-1 per retiring request
+                        n_new = len(req.tokens) - n_before
+                        n_tokens += n_new
+                        if obs is not None:
+                            obs.tokens_emitted(req, n_new, decode.t1)
+                        if due:
+                            self._free_slot(s)
+                            retired.append(req)
+                            n_retired += 1
+                    harvest.set_metadata(tokens=n_tokens,
+                                         retired=n_retired)
         if obs is not None:
-            obs.tick_done(self, retired, t0, t1, t2)
+            obs.tick_done(self, retired, tick, admit, decode, harvest)
         if lit:
-            now = time.perf_counter()
-            self.last_tick_at = now
+            self.last_tick_at = tick.t1
             if flight is not None:
                 flight.span(
-                    f"tick {self.tick_count}", t0, now - t0,
+                    f"tick {self.tick_count}", tick.t0,
+                    tick.t1 - tick.t0,
                     src="scheduler", track="scheduler",
                     queue=self.pending, active=self.active,
                     retired=len(retired),
                 )
                 flight.counter(
-                    "serving_ticks_total", self.tick_count, t=now
+                    "serving_ticks_total", self.tick_count,
+                    t=tick.t1,
                 )
         return retired
 
@@ -2058,28 +2125,34 @@ class ServingScheduler:
         Tp = req.prompt.size
         base = 0
         admit_kw: dict[str, Any] = {}
-        if self.paged:
-            base, admit_kw = self._commit_pages(req, plan)
-        rem = Tp - base
-        n_chunks = -(-rem // self.C)
-        padded = np.zeros((1, n_chunks * self.C), np.int32)
-        padded[0, :rem] = req.prompt[base:]
-        cache = _fresh_cache(self.cfg, 1, self.Lmax,
-                             self.quantize_kv)
-        if base:
-            # skip the shared prefix's prefill outright: its K/V
-            # seed the transient cache from the resident pages
-            # (identical bytes to what this prefill would compute)
-            cache = self._seed(
-                cache, self._caches,
-                jnp.asarray(admit_kw["pids"], jnp.int32),
-                jnp.int32(base),
+        with _annotate("serving.admit_new", req=req.id, slot=s,
+                       prompt_tokens=Tp) as span:
+            if self.paged:
+                base, admit_kw = self._commit_pages(req, plan)
+            rem = Tp - base
+            n_chunks = -(-rem // self.C)
+            span.set_metadata(
+                chunks=n_chunks,
+                shared_pages=base // self.P if self.paged else 0,
             )
-        self._slot_req[s] = req
-        self._admitting[s] = _Admitting(
-            req, cache, jnp.asarray(padded), n_chunks, base=base,
-            **admit_kw,
-        )
+            padded = np.zeros((1, n_chunks * self.C), np.int32)
+            padded[0, :rem] = req.prompt[base:]
+            cache = _fresh_cache(self.cfg, 1, self.Lmax,
+                                 self.quantize_kv)
+            if base:
+                # skip the shared prefix's prefill outright: its K/V
+                # seed the transient cache from the resident pages
+                # (identical bytes to what this prefill would compute)
+                cache = self._seed(
+                    cache, self._caches,
+                    jnp.asarray(admit_kw["pids"], jnp.int32),
+                    jnp.int32(base),
+                )
+            self._slot_req[s] = req
+            self._admitting[s] = _Admitting(
+                req, cache, jnp.asarray(padded), n_chunks, base=base,
+                **admit_kw,
+            )
         req.admitted_tick = self.tick_count
         if self._obs is not None and req.tenant is not None:
             self._obs.qos_admitted(self, req.tenant)
@@ -2434,12 +2507,16 @@ class ServingScheduler:
                            retired: list[Request]) -> None:
         st = self._admitting[s]
         i = st.next_chunk
-        chunk = jax.lax.dynamic_slice_in_dim(
-            st.padded, i * self.C, self.C, axis=1
-        )
-        st.last_logits, st.cache = self._extend(
-            self.params, chunk, st.cache, jnp.int32(st.base + i * self.C)
-        )
+        rid = st.req.id
+        with _annotate("serving.prefill_chunk", req=rid, slot=s,
+                       chunk=i, of=st.n_chunks):
+            chunk = jax.lax.dynamic_slice_in_dim(
+                st.padded, i * self.C, self.C, axis=1
+            )
+            st.last_logits, st.cache = self._extend(
+                self.params, chunk, st.cache,
+                jnp.int32(st.base + i * self.C),
+            )
         st.next_chunk += 1
         if self._obs is not None:
             self._obs.prefill_chunk()
@@ -2450,41 +2527,46 @@ class ServingScheduler:
             )
         if st.next_chunk < st.n_chunks:
             return
-        Tp = st.req.prompt.size
-        rkey = (st.req.key if st.req.key is not None
-                else jax.random.key(st.req.id + 1))
-        tok0, ring = self._finish(
-            st.cache, st.last_logits, jnp.int32(Tp),
-            jnp.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
-        )
-        if self.paged:
-            # install the page table NOW (stale row writes landed in
-            # the null page until this point), then scatter the ring
-            # window into the pages and flip the row live
-            self._pt_host[s] = st.pids
-            self._pt_dev = None
-            self._host_pos[s] = Tp
-            self._slot_wraps[s] = st.wraps
-            (self._caches, self._tok, self._pos, self._done,
-             self._keys) = self._place_p(
-                self._caches, ring, self._tok, self._pos, self._done,
-                self._keys, jnp.asarray(self._pt_host[s]),
-                jnp.int32(s), tok0, jnp.int32(Tp), rkey,
+        with _annotate("serving.first_token", req=rid, slot=s):
+            Tp = st.req.prompt.size
+            rkey = (st.req.key if st.req.key is not None
+                    else jax.random.key(st.req.id + 1))
+            tok0, ring = self._finish(
+                st.cache, st.last_logits, jnp.int32(Tp),
+                jnp.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
             )
-            # the prompt-covered pages now hold exactly the content
-            # their chained prefix digests describe — publish them for
-            # future admissions to share (first-wins; the shared ones
-            # are already registered)
-            for j in range(st.n_cover):
-                self.pool.register(st.digests[j], st.pids[j],
-                                   volatile=st.wraps)
-        else:
-            (self._caches, self._tok, self._pos, self._done,
-             self._keys) = self._place(
-                self._caches, ring, self._tok, self._pos, self._done,
-                self._keys, jnp.int32(s), tok0, jnp.int32(Tp), rkey,
-            )
-        st.req.tokens.append(int(tok0))
+            if self.paged:
+                # install the page table NOW (stale row writes landed in
+                # the null page until this point), then scatter the ring
+                # window into the pages and flip the row live
+                self._pt_host[s] = st.pids
+                self._pt_dev = None
+                self._host_pos[s] = Tp
+                self._slot_wraps[s] = st.wraps
+                (self._caches, self._tok, self._pos, self._done,
+                 self._keys) = self._place_p(
+                    self._caches, ring, self._tok, self._pos, self._done,
+                    self._keys, jnp.asarray(self._pt_host[s]),
+                    jnp.int32(s), tok0, jnp.int32(Tp), rkey,
+                )
+                # the prompt-covered pages now hold exactly the content
+                # their chained prefix digests describe — publish them for
+                # future admissions to share (first-wins; the shared ones
+                # are already registered)
+                for j in range(st.n_cover):
+                    self.pool.register(st.digests[j], st.pids[j],
+                                       volatile=st.wraps)
+            else:
+                (self._caches, self._tok, self._pos, self._done,
+                 self._keys) = self._place(
+                    self._caches, ring, self._tok, self._pos, self._done,
+                    self._keys, jnp.int32(s), tok0, jnp.int32(Tp), rkey,
+                )
+        # the one place admission blocks on the device: the request's
+        # first token comes back before the tick's decode is dispatched
+        with _annotate("serving.first_token_wait", req=rid):
+            first = int(tok0)
+        st.req.tokens.append(first)
         if self._obs is not None:
             self._obs.first_token(st.req, time.perf_counter())
         if self._trace is not None and st.req.trace is not None:

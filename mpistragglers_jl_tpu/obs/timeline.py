@@ -16,6 +16,7 @@ the instrumented code paths unchanged.
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from contextlib import contextmanager
@@ -232,17 +233,43 @@ def dump_merged_chrome_trace(
     return n
 
 
-@contextmanager
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` when jax's profiler is
-    importable, a no-op otherwise — instrumented device code (the
-    serving decode scan, a coded train step) shows up inside
-    ``jax.profiler.trace`` captures on real chips while CPU CI and
-    numpy-only installs run the identical path."""
+class _NoAnnotation:
+    """What :func:`annotate` hands out where ``jax.profiler`` cannot be
+    imported: the same three methods, doing nothing."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **args) -> None:
+        return None
+
+
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, looked up once (this module
+    stays stdlib-only at import); None where jax or its profiler is
+    absent."""
     try:
         from jax.profiler import TraceAnnotation
     except Exception:  # jax absent or profiler unavailable
-        yield
-        return
-    with TraceAnnotation(name):
-        yield
+        return None
+    return TraceAnnotation
+
+
+def annotate(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` carrying
+    ``args`` (ints and strings; what varies goes here, never into the
+    name), to be used as ``with annotate(...):``. It is written into
+    the profiler's own trace, on the clock the device's operations are
+    stamped with, whenever a profiler session is open
+    (``jax.profiler.start_trace``); with no session open entering and
+    leaving it is an atomic check, and the arguments are never
+    formatted. ``set_metadata(**more)`` on the returned object adds
+    arguments known only before the span closes. Where jax's profiler
+    cannot be imported the object does nothing, so CPU CI and
+    numpy-only installs run the identical path."""
+    cls = _trace_annotation()
+    return _NoAnnotation() if cls is None else cls(name, **args)

@@ -1,0 +1,330 @@
+"""The scheduler's phase boundaries as a profiler session sees them
+(models/serving.py: ``serving.*`` annotations, entered with nothing
+attached), what they cost with no session open, and the stable names
+of the serving programs and of the parts of the tick."""
+
+from __future__ import annotations
+
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+SPANS = {
+    "serving.tick", "serving.admit", "serving.admit_new",
+    "serving.prefill_chunk", "serving.first_token",
+    "serving.first_token_wait", "serving.decode",
+    "serving.decode_dispatch", "serving.decode_wait", "serving.harvest",
+}
+INSIDE = {
+    "serving.admit": "serving.tick",
+    "serving.decode": "serving.tick",
+    "serving.harvest": "serving.tick",
+    "serving.admit_new": "serving.admit",
+    "serving.prefill_chunk": "serving.admit",
+    "serving.first_token": "serving.admit",
+    "serving.first_token_wait": "serving.admit",
+    "serving.decode_dispatch": "serving.decode",
+    "serving.decode_wait": "serving.decode",
+}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    from mpistragglers_jl_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    cfg = TransformerConfig(
+        vocab=37, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=64, attn_window=8,
+    )
+    return cfg, init_params(cfg, seed=3)
+
+
+def _sched(cfg, params, **kw):
+    from mpistragglers_jl_tpu.models.serving import ServingScheduler
+
+    kw.setdefault("page_tokens", 4)
+    return ServingScheduler(
+        params, cfg, slots=2, n_inner=4, prompt_chunk=8, max_prompt=32,
+        quantize_kv=True, **kw,
+    )
+
+
+def _host_events(log_dir):
+    """[(name, start_ns, end_ns, args)] of the ``serving.*`` host events
+    of the newest trace under ``log_dir``, in order of start."""
+    from jax.profiler import ProfileData
+
+    path = sorted(
+        glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                  recursive=True),
+        key=os.path.getmtime,
+    )[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serving."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.fixture(scope="module")
+def traced(tiny, tmp_path_factory):
+    """A dark paged scheduler run to the end under a profiler session:
+    three requests over two slots (one prompt of two chunks, one slot
+    reused). Returns (events, what the scheduler's own state was when
+    each tick began and ended, the requests)."""
+    import jax
+
+    cfg, params = tiny
+    sched = _sched(cfg, params)
+    rng = np.random.default_rng(0)
+    reqs = [
+        sched.submit(rng.integers(1, cfg.vocab, size=p), max_new=m)
+        for p, m in [(5, 6), (11, 9), (3, 5)]
+    ]
+    log_dir = str(tmp_path_factory.mktemp("serving_trace"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    state = []
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        while sched.pending or sched.active:
+            n_free = sched._slot_req.count(None)
+            begin = {
+                "tick": sched.tick_count + 1, "queue": sched.pending,
+                "admitting": len(sched._admitting), "free": n_free,
+                "decoding": sched.S - n_free - len(sched._admitting),
+            }
+            before = {r.id: len(r.tokens) for r in reqs}
+            first = {r.id for r in reqs if not r.tokens}
+            retired = sched.step()
+            delivered = {
+                r.id: len(r.tokens) - before[r.id] for r in reqs
+            }
+            state.append((begin, delivered, first,
+                          [r.id for r in retired]))
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.finished for r in reqs)
+    return _host_events(log_dir), state, reqs
+
+
+def _children(events, parent):
+    _, a, b, _ = parent
+    return [e for e in events if e is not parent and a <= e[1]
+            and e[2] <= b]
+
+
+def test_every_span_of_the_table_is_written_and_nested(traced):
+    events, state, _ = traced
+    assert {e[0] for e in events} == SPANS
+    by = {n: [e for e in events if e[0] == n] for n in SPANS}
+    assert len(by["serving.tick"]) == len(state)
+    for name, outer in INSIDE.items():
+        for e in by[name]:
+            holders = [o for o in by[outer]
+                       if o[1] <= e[1] and e[2] <= o[2]]
+            assert len(holders) == 1, (name, outer)
+
+
+def test_admit_decode_harvest_partition_the_tick(traced):
+    events, _, _ = traced
+    tick_ns = left_ns = 0
+    for tick in (e for e in events if e[0] == "serving.tick"):
+        parts = [
+            e for e in _children(events, tick)
+            if e[0] in ("serving.admit", "serving.decode",
+                        "serving.harvest")
+        ]
+        names = [e[0] for e in parts]
+        assert names in (
+            ["serving.admit"],
+            ["serving.admit", "serving.decode", "serving.harvest"],
+        )
+        for a, b in zip(parts, parts[1:]):
+            assert a[2] <= b[1]  # in order, no overlap
+        tick_ns += tick[2] - tick[1]
+        left_ns += (tick[2] - tick[1]) - sum(e[2] - e[1] for e in parts)
+    # what the three leave is the tick's own self time
+    assert 0 <= left_ns <= 0.1 * tick_ns
+
+
+def test_arguments_equal_the_schedulers_own_state(traced, tiny):
+    events, state, reqs = traced
+    ticks = [e for e in events if e[0] == "serving.tick"]
+    for tick, (begin, delivered, first, retired) in zip(ticks, state):
+        assert tick[3] == begin
+        inside = _children(events, tick)
+        harvest = [e for e in inside if e[0] == "serving.harvest"]
+        decode = [e for e in inside if e[0] == "serving.decode"]
+        # tokens delivered by the decode scan: all but first tokens
+        firsts = {e[3]["req"] for e in inside
+                  if e[0] == "serving.first_token_wait"}
+        assert firsts <= first
+        from_decode = sum(delivered.values()) - len(firsts)
+        if harvest:
+            # no request here retires at admission, so whoever got a
+            # first token this tick decodes in it
+            assert decode[0][3] == {
+                "slots": begin["decoding"] + len(firsts)}
+            assert harvest[0][3] == {"tokens": from_decode,
+                                     "retired": len(retired)}
+        else:
+            assert from_decode == 0
+    by_id = {r.id: r for r in reqs}
+    new = [e for e in events if e[0] == "serving.admit_new"]
+    assert sorted(e[3]["req"] for e in new) == sorted(by_id)
+    for e in new:
+        r = by_id[e[3]["req"]]
+        assert e[3]["prompt_tokens"] == r.prompt.size
+        assert e[3]["chunks"] == -(-r.prompt.size // 8)
+        assert e[3]["shared_pages"] == 0
+        assert e[3]["slot"] in (0, 1)
+
+
+def test_the_spans_of_one_request_share_its_id(traced):
+    events, _, reqs = traced
+    for r in reqs:
+        mine = [e for e in events if e[3].get("req") == r.id]
+        names = [e[0] for e in mine]
+        chunks = -(-r.prompt.size // 8)
+        assert names.count("serving.admit_new") == 1
+        assert names.count("serving.prefill_chunk") == chunks
+        assert names.count("serving.first_token") == 1
+        assert names.count("serving.first_token_wait") == 1
+        # in the order of a request's life, on one slot
+        assert names[0] == "serving.admit_new"
+        assert names[-1] == "serving.first_token_wait"
+        assert len({e[3]["slot"] for e in mine if "slot" in e[3]}) == 1
+        cursor = [e[3]["chunk"] for e in mine
+                  if e[0] == "serving.prefill_chunk"]
+        assert cursor == list(range(chunks))
+        assert {e[3]["of"] for e in mine
+                if e[0] == "serving.prefill_chunk"} == {chunks}
+
+
+def test_recorder_spans_are_cut_at_the_same_boundaries(tiny):
+    """``spans=`` draws admit/decode/retire from the phases the
+    profiler sees: each recorder span lies inside its tick's, in
+    order."""
+    from mpistragglers_jl_tpu.obs import SpanRecorder
+
+    cfg, params = tiny
+    rec = SpanRecorder("serving")
+    sched = _sched(cfg, params, spans=rec)
+    sched.submit(np.arange(1, 6, dtype=np.int32), max_new=6)
+    sched.run()
+    ticks = [s for s in rec.spans if s[1].startswith("tick ")]
+    parts = [s for s in rec.spans if s[1] in ("admit", "decode", "retire")]
+    assert len(ticks) == sched.tick_count
+    for _, _, t0, dur, _ in ticks:
+        mine = [s for s in parts if t0 <= s[2] and s[2] + s[3] <= t0 + dur]
+        assert [s[1] for s in mine] in (
+            ["admit"], ["admit", "decode", "retire"])
+        for a, b in zip(mine, mine[1:]):
+            assert a[2] + a[3] <= b[2]
+
+
+def test_annotations_cost_under_a_thousandth_of_a_tick():
+    """With no profiler session open: the annotations of a heavy tick
+    (sixteen slots each advancing a prefill chunk, four of them
+    finishing their admission), measured directly as
+    test_obs.py::test_noop_overhead_under_budget measures the guards,
+    against 0.1% of the serving cells' 160 ms tick."""
+    from mpistragglers_jl_tpu.obs.timeline import annotate
+
+    def heavy_tick():
+        with annotate("serving.tick", tick=7, queue=3, decoding=12,
+                      admitting=4, free=0):
+            with annotate("serving.admit"):
+                for s in range(16):
+                    with annotate("serving.prefill_chunk", req=s,
+                                  slot=s, chunk=1, of=4):
+                        pass
+                for s in range(4):
+                    with annotate("serving.admit_new", req=s, slot=s,
+                                  prompt_tokens=300) as span:
+                        span.set_metadata(chunks=2, shared_pages=0)
+                    with annotate("serving.first_token", req=s, slot=s):
+                        pass
+                    with annotate("serving.first_token_wait", req=s):
+                        pass
+            with annotate("serving.decode", slots=12):
+                with annotate("serving.decode_dispatch"):
+                    pass
+                with annotate("serving.decode_wait"):
+                    pass
+            with annotate("serving.harvest") as span:
+                span.set_metadata(tokens=96, retired=1)
+
+    heavy_tick()
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(200):
+            heavy_tick()
+        best = min(best, (time.perf_counter() - t0) / 200)
+    assert best <= 0.001 * 0.160, (
+        f"{best * 1e6:.1f} us for the annotations of one tick"
+    )
+
+
+def test_annotate_takes_arguments_and_resolves_its_class_once():
+    from mpistragglers_jl_tpu.obs import timeline
+
+    with timeline.annotate("serving.tick", tick=1) as span:
+        span.set_metadata(more=2)
+    assert timeline._trace_annotation() is timeline._trace_annotation()
+    assert timeline._trace_annotation.cache_info().hits >= 1
+    quiet = timeline._NoAnnotation()
+    with quiet as span:
+        span.set_metadata(anything=1)
+
+
+PROGRAMS = {
+    "_scan": "serving_tick_paged", "_seed": "serving_seed_prefix",
+    "_place_p": "serving_place_pages", "_copy": "serving_copy_pages",
+    "_gather": "serving_gather_ring", "_extend": "serving_prefill_chunk",
+    "_finish": "serving_first_token", "_place": "serving_place_ring",
+}
+
+
+@pytest.mark.parametrize("attr,name", sorted(PROGRAMS.items()))
+def test_serving_programs_have_names_of_their_own(tiny, attr, name):
+    cfg, params = tiny
+    assert getattr(_sched(cfg, params), attr).__name__ == name
+
+
+def test_dense_and_sharded_ticks_are_named_too(tiny):
+    import jax
+    from jax.sharding import Mesh
+
+    from mpistragglers_jl_tpu.models.serving import make_serving_scan
+
+    cfg, params = tiny
+    dense = _sched(cfg, params, page_tokens=None)
+    assert dense._scan.__name__ == "serving_tick_dense"
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    assert make_serving_scan(cfg, mesh, 2).__name__ == (
+        "serving_tick_sharded")
+
+
+def test_the_tick_program_carries_its_scopes(tiny):
+    cfg, params = tiny
+    text = _sched(cfg, params).lower_tick().as_text(debug_info=True)
+    assert "module @jit_serving_tick_paged" in text
+    for scope in ("kv_page_gather", "kv_page_scatter", "decode_attn",
+                  "decode_mlp"):
+        assert scope in text, scope
