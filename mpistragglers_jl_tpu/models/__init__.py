@@ -72,6 +72,7 @@ def clear_cached_programs() -> None:
     for cache in (
         decode._dense_runner,
         speculative._spec_runner,
+        serving._fresh_arena,
         serving._serving_scan_dense,
         serving._serving_scan_paged,
         serving._extend_chunk_dense,
@@ -83,6 +84,8 @@ def clear_cached_programs() -> None:
         serving._gather_ring_paged,
     ):
         cache.cache_clear()
+    # the one serving program jitted at module level (shapes alone key it)
+    serving.serving_reset_arena.clear_cache()
 
 
 def __getattr__(name):

@@ -130,12 +130,14 @@ __all__ = [
 ]
 
 
-def _fresh_cache(cfg: TransformerConfig, B: int, L: int,
-                 quantize_kv: bool = False) -> list[dict]:
-    """Zeroed positional/ring cache with DISTINCT buffers per leaf.
-    decode.py's ``_zero_cache_layer`` aliases one zeros array for k and
-    v (fine undonated); the serving programs donate their caches, and
-    donating the same buffer twice is an XLA execution error."""
+@functools.lru_cache(maxsize=32)
+def _fresh_arena(cfg: TransformerConfig, B: int, L: int,
+                 quantize_kv: bool):
+    """Jitted ``serving_fresh_arena() -> [per-layer dict]``: every leaf
+    of a zeroed ``(B, L, kv_heads, head_dim)`` cache out of ONE
+    dispatch. An eager ``jnp.zeros`` per leaf is a program launch and
+    an allocation each, four a layer, while the device has nothing
+    queued: admission's whole host cost at 30 layers."""
     shape = (B, L, cfg.kv_heads, cfg.head_dim)
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
@@ -146,7 +148,33 @@ def _fresh_cache(cfg: TransformerConfig, B: int, L: int,
             out["v_s"] = jnp.zeros(shape[:3], jnp.float32)
         return out
 
-    return [layer() for _ in range(cfg.n_layers)]
+    @jax.jit
+    def serving_fresh_arena():
+        return [layer() for _ in range(cfg.n_layers)]
+
+    return serving_fresh_arena
+
+
+def _fresh_cache(cfg: TransformerConfig, B: int, L: int,
+                 quantize_kv: bool = False) -> list[dict]:
+    """Zeroed positional/ring cache with DISTINCT buffers per leaf,
+    made by one program (:func:`_fresh_arena`). decode.py's
+    ``_zero_cache_layer`` aliases one zeros array for k and v (fine
+    undonated); the serving programs donate their caches, and donating
+    the same buffer twice is an XLA execution error — a program's
+    outputs are buffers of their own, which tests/test_serving_arena.py
+    holds it to."""
+    return _fresh_arena(cfg, B, L, bool(quantize_kv))()
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), keep_unused=True)
+def serving_reset_arena(arena):
+    """Zero a dead prefill arena IN PLACE: the arena is donated and
+    every output takes its input's buffer (``keep_unused`` keeps the
+    unread inputs in the program, so the donation has something to
+    alias), one dispatch and no allocation. What comes back is
+    byte for byte what :func:`_fresh_cache` makes."""
+    return jax.tree.map(jnp.zeros_like, arena)
 
 
 def _fresh_pages(cfg: TransformerConfig, n_pages: int, P: int,
@@ -520,7 +548,8 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
     holds position s, so the page rows ARE the positional rows and
     admission can skip recomputing them. ``R`` (static) bounds the
     gather at ``min(W, Lmax)``; rows at and past ``ell`` stay zero —
-    exactly the arena :func:`_fresh_cache` hands to prefill. The
+    exactly the arena admission hands to prefill (zeros out of
+    :func:`_fresh_cache` or, recycled, :func:`serving_reset_arena`). The
     seeded bytes are the pages' bytes, which are the bytes this very
     prefill would have produced (pinned by the paged parity tests), so
     the oracle identity survives the skip. Cache donated; the page
@@ -1102,11 +1131,13 @@ class Request:
 
 class _Admitting:
     """Per-slot chunked-prefill state machine: the transient positional
-    cache plus the chunk cursor. Paged admissions additionally carry
-    the page plan: ``base`` (tokens of shared prefix whose prefill is
-    SKIPPED — chunk i runs at offset ``base + i*C``), ``pids`` (the
-    slot's full page table, installed into the device table only at
-    finish — until then the row's stale writes land in the null page),
+    cache (the arena: taken from the scheduler's free list, back on it
+    when the admission ends) plus the chunk cursor. Paged admissions
+    additionally carry the page plan: ``base`` (tokens of shared prefix
+    whose prefill is SKIPPED — chunk i runs at offset ``base + i*C``),
+    ``pids`` (the slot's full page table, installed into the device
+    table only at finish — until then the row's stale writes land in
+    the null page),
     ``digests``/``n_cover`` (prefix digests to register at finish) and
     ``wraps`` (whether this request can wrap its ring — registered
     pages are then volatile)."""
@@ -1301,6 +1332,11 @@ class ServingScheduler:
             self._cold_count: dict[str, int] = {}
         self._slot_req: list[Request | None] = [None] * self.S
         self._admitting: dict[int, _Admitting] = {}  # slot -> state
+        # dead prefill arenas awaiting their next admission: one comes
+        # back at every exit of admission (_release_arena) and a new
+        # one is made only while this list is empty, so list plus live
+        # _Admitting.cache never exceed the slots
+        self._free_arenas: list[list[dict]] = []
         self.tick_count = 0
         # device-resident row state + batched ring cache arena
         self.temperature = float(temperature)
@@ -1666,17 +1702,20 @@ class ServingScheduler:
         for s, r in enumerate(self._slot_req):
             if r is req:
                 st = self._admitting.pop(s, None)
-                if st is not None and self.paged:
-                    # mid-admission the slot's pages live in the plan
-                    # (_pt_host[s] stays NULL until finish), so
-                    # _free_slot's table walk would miss them — release
-                    # the committed plan here
-                    n_refs = 0
-                    for pid in st.pids:
-                        if pid != NULL_PAGE:
-                            self.pool.decref(int(pid), wrapper=st.wraps)
-                            n_refs += 1
-                    self._tenant_debit(req.tenant, n_refs)
+                if st is not None:
+                    self._release_arena(st)
+                    if self.paged:
+                        # mid-admission the slot's pages live in the
+                        # plan (_pt_host[s] stays NULL until finish), so
+                        # _free_slot's table walk would miss them —
+                        # release the committed plan here
+                        n_refs = 0
+                        for pid in st.pids:
+                            if pid != NULL_PAGE:
+                                self.pool.decref(int(pid),
+                                                 wrapper=st.wraps)
+                                n_refs += 1
+                        self._tenant_debit(req.tenant, n_refs)
                 self._free_slot(s)
                 self._retire_cancelled(req)
                 return True
@@ -2117,6 +2156,26 @@ class ServingScheduler:
             s = free.pop(0)
             self._admit_into(s, req, plan, retired)
 
+    def _take_arena(self) -> tuple[list[dict], str]:
+        """The zeroed ``(1, max_prompt)`` positional cache an admission
+        prefills into, and where it came from: ``"reused"`` (a dead
+        arena off the free list, zeroed in place by
+        :func:`serving_reset_arena`) or ``"new"`` (the list was empty:
+        more prompts are in prefill at once than ever before). Either
+        way one dispatch, and the same zeros."""
+        if self._free_arenas:
+            return serving_reset_arena(self._free_arenas.pop()), "reused"
+        return (_fresh_cache(self.cfg, 1, self.Lmax, self.quantize_kv),
+                "new")
+
+    def _release_arena(self, st: _Admitting) -> None:
+        """An admission is over (first token, or cancelled between two
+        chunks): its arena is dead and goes to the free list. ``st.cache``
+        is the binding the last program RETURNED (every donating program
+        rebinds it), so the list never holds a donated buffer."""
+        self._free_arenas.append(st.cache)
+        st.cache = None
+
     def _admit_into(self, s: int, req: Request, plan,
                     retired: list[Request]) -> None:
         """Install one dequeued request into free slot ``s`` (the
@@ -2131,14 +2190,14 @@ class ServingScheduler:
                 base, admit_kw = self._commit_pages(req, plan)
             rem = Tp - base
             n_chunks = -(-rem // self.C)
+            padded = np.zeros((1, n_chunks * self.C), np.int32)
+            padded[0, :rem] = req.prompt[base:]
+            cache, arena = self._take_arena()
             span.set_metadata(
                 chunks=n_chunks,
                 shared_pages=base // self.P if self.paged else 0,
+                arena=arena,
             )
-            padded = np.zeros((1, n_chunks * self.C), np.int32)
-            padded[0, :rem] = req.prompt[base:]
-            cache = _fresh_cache(self.cfg, 1, self.Lmax,
-                                 self.quantize_kv)
             if base:
                 # skip the shared prefix's prefill outright: its K/V
                 # seed the transient cache from the resident pages
@@ -2535,6 +2594,8 @@ class ServingScheduler:
                 st.cache, st.last_logits, jnp.int32(Tp),
                 jnp.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
             )
+            # _finish read the arena without donating it: recycle it
+            self._release_arena(st)
             if self.paged:
                 # install the page table NOW (stale row writes landed in
                 # the null page until this point), then scatter the ring

@@ -454,6 +454,7 @@ def test_clear_cached_programs_drops_all_model_caches():
     generate_ring_dense(PARAMS, jnp.asarray(_prompt(3))[None], 2, CFG)
     caches = (
         decode._dense_runner, speculative._spec_runner,
+        serving._fresh_arena,
         serving._serving_scan_dense, serving._extend_chunk_dense,
         serving._finish_admit_dense, serving._place_dense,
     )

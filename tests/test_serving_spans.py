@@ -5,6 +5,7 @@ of the serving programs and of the parts of the tick."""
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import os
 import time
@@ -78,14 +79,28 @@ def _host_events(log_dir):
     return sorted(out, key=lambda e: (e[1], -e[2]))
 
 
+@contextlib.contextmanager
+def _profiled(log_dir):
+    """A profiler session that keeps host events (the annotations) and
+    leaves Python's own frames out."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
 @pytest.fixture(scope="module")
 def traced(tiny, tmp_path_factory):
     """A dark paged scheduler run to the end under a profiler session:
     three requests over two slots (one prompt of two chunks, one slot
     reused). Returns (events, what the scheduler's own state was when
     each tick began and ended, the requests)."""
-    import jax
-
     cfg, params = tiny
     sched = _sched(cfg, params)
     rng = np.random.default_rng(0)
@@ -94,12 +109,8 @@ def traced(tiny, tmp_path_factory):
         for p, m in [(5, 6), (11, 9), (3, 5)]
     ]
     log_dir = str(tmp_path_factory.mktemp("serving_trace"))
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 2
     state = []
-    jax.profiler.start_trace(log_dir, profiler_options=opts)
-    try:
+    with _profiled(log_dir):
         while sched.pending or sched.active:
             n_free = sched._slot_req.count(None)
             begin = {
@@ -115,8 +126,6 @@ def traced(tiny, tmp_path_factory):
             }
             state.append((begin, delivered, first,
                           [r.id for r in retired]))
-    finally:
-        jax.profiler.stop_trace()
     assert all(r.finished for r in reqs)
     return _host_events(log_dir), state, reqs
 
@@ -215,6 +224,45 @@ def test_the_spans_of_one_request_share_its_id(traced):
                 if e[0] == "serving.prefill_chunk"} == {chunks}
 
 
+def test_admit_new_says_where_its_arena_came_from(traced):
+    """The scheduler's first admission finds the free list empty; the
+    second runs in the same tick, after the first prompt's one chunk
+    gave its arena back, and the third long after."""
+    events, _, _ = traced
+    new = [e for e in events if e[0] == "serving.admit_new"]
+    assert [e[3]["arena"] for e in new] == ["new", "reused", "reused"]
+
+
+@pytest.mark.parametrize("quantize_kv", [True, False],
+                         ids=["int8", "bf16"])
+@pytest.mark.parametrize("page_tokens", [4, None], ids=["paged", "dense"])
+def test_a_backlog_of_one_chunk_prompts_reuses_one_arena(
+        tiny, tmp_path, page_tokens, quantize_kv):
+    """``arena="new"`` exactly when the free list was empty: with every
+    prompt through prefill in the tick that admits it, that is the
+    scheduler's first admission and no other."""
+    from mpistragglers_jl_tpu.models.serving import ServingScheduler
+
+    cfg, params = tiny
+    sched = ServingScheduler(
+        params, cfg, slots=2, n_inner=4, prompt_chunk=8, max_prompt=32,
+        quantize_kv=quantize_kv, page_tokens=page_tokens,
+    )
+    rng = np.random.default_rng(1)
+    reqs = [
+        sched.submit(rng.integers(1, cfg.vocab, size=int(n)), max_new=3)
+        for n in rng.integers(1, 9, size=7)
+    ]
+    with _profiled(str(tmp_path)):
+        sched.run()
+    assert all(r.finished for r in reqs)
+    new = [e for e in _host_events(str(tmp_path))
+           if e[0] == "serving.admit_new"]
+    assert [e[3]["req"] for e in new] == [r.id for r in reqs]
+    assert [e[3]["arena"] for e in new] == ["new"] + ["reused"] * 6
+    assert len(sched._free_arenas) == 1
+
+
 def test_recorder_spans_are_cut_at_the_same_boundaries(tiny):
     """``spans=`` draws admit/decode/retire from the phases the
     profiler sees: each recorder span lies inside its tick's, in
@@ -256,7 +304,8 @@ def test_annotations_cost_under_a_thousandth_of_a_tick():
                 for s in range(4):
                     with annotate("serving.admit_new", req=s, slot=s,
                                   prompt_tokens=300) as span:
-                        span.set_metadata(chunks=2, shared_pages=0)
+                        span.set_metadata(chunks=2, shared_pages=0,
+                                          arena="reused")
                     with annotate("serving.first_token", req=s, slot=s):
                         pass
                     with annotate("serving.first_token_wait", req=s):
