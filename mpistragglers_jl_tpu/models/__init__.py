@@ -55,6 +55,11 @@ _HOME = {
     "switch_route_indices": "moe",
     "moe_ffn_dense": "moe",
     "moe_ffn_sharded": "moe",
+    "init_topk_layer": "moe",
+    "topk_route": "moe",
+    "grouped_matmul": "moe",
+    "moe_ffn_topk": "moe",
+    "ring_widths": "decode",
 }
 
 __all__ = list(_HOME) + ["clear_cached_programs"]

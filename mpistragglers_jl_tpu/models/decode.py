@@ -65,13 +65,15 @@ from ..parallel.ring_attention import (
     _group_scores,
     resolve_attention_impl,
 )
-from .moe import moe_ffn_dense, moe_ffn_sharded
 from .transformer import (
     TransformerConfig,
     _kv_tp_sharded,
-    _ln,
-    _mlp,
     _rope,
+    attn_qkv,
+    attn_merge,
+    embed,
+    ffn_half,
+    head_logits,
     make_kv_slice,
     param_specs,
 )
@@ -87,6 +89,7 @@ __all__ = [
     "generate_ring_dense",
     "init_ring_cache",
     "ring_from_cache",
+    "ring_widths",
     "make_generate",
     "make_ring_generate",
     "make_prefill",
@@ -444,23 +447,21 @@ def _ring_cached_attention(q, cache_l, pos, scale, use_kernel=_UNSET):
     return o.astype(q.dtype)
 
 
-def _incremental_layer(x, lp, cache_l, qpos, cfg, *, chunk_attn, kv_slice,
-                       tp_psum, ring=False, decode_kernel=_UNSET):
-    """One layer of the incremental forward: write the chunk's K/V into
-    the cache at ``qpos`` positions, attend, MLP. Returns (x, cache_l).
-    ``tp_psum=True`` combines the head-shard out-projection and the
-    d_ff-shard down-projection over the ``tp`` axis, exactly like the
-    training path (models/transformer.py ``_forward_local``).
+def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
+                       kv_slice, tp_psum, ring=False,
+                       decode_kernel=_UNSET):
+    """Layer ``li`` of the incremental forward: write the chunk's K/V
+    into the cache at ``qpos`` positions, attend, feed-forward. Returns
+    (x, cache_l). The block itself is models/transformer.py's
+    (``attn_qkv`` / ``attn_merge`` / ``ffn_half``); this function owns
+    only where K/V live. ``tp_psum=True`` combines the head-shard
+    out-projection and the d_ff-shard down-projection over the ``tp``
+    axis, exactly like the training path (``_forward_local``).
     ``ring=True`` treats the cache as the O(W) circular window buffer
     (single-token chunks only): the write lands at slot ``pos % W`` and
     attention runs through :func:`_ring_cached_attention`."""
-    h = _ln(x, lp["ln1_s"], lp["ln1_b"])
-    q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
-    k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
-    v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
-    if kv_slice is not None:
-        k, v = kv_slice(k), kv_slice(v)
-    q, k = _rope(q, qpos), _rope(k, qpos)
+    q, k, v, gate = attn_qkv(x, lp, cfg, li, partial(_rope, pos=qpos),
+                             kv_slice)
     off = qpos[0]
     if ring:
         off = jnp.mod(off, cache_l["k"].shape[1])
@@ -470,32 +471,15 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, *, chunk_attn, kv_slice,
         # prefill at offset 0: attention lives entirely inside the chunk,
         # so the configured chunk kernel (flash on TPU) does the work on
         # the exact (unquantized) chunk K/V — only the cache quantizes
-        o = chunk_attn(q, k, v)
+        o = chunk_attn(q, k, v, window=cfg.windows[li])
     elif ring:
         o = _ring_cached_attention(q, cache_l, qpos[0], scale,
                                    use_kernel=decode_kernel)
     else:
-        o = _cached_attention(q, cache_l, qpos, scale, cfg.attn_window,
+        o = _cached_attention(q, cache_l, qpos, scale, cfg.windows[li],
                               use_kernel=decode_kernel)
-    attn_out = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
-    if tp_psum:
-        attn_out = jax.lax.psum(attn_out, "tp")
-    x = x + attn_out
-    h2 = _ln(x, lp["ln2_s"], lp["ln2_b"])
-    if cfg.n_experts:
-        if tp_psum:
-            # inside the mesh program: expert-parallel routing, exactly
-            # the training path's MoE branch (_forward_local) — experts
-            # over ep via all_to_all, hidden dims over tp
-            y, ybias, _ = moe_ffn_sharded(h2, lp, cfg.capacity_factor)
-            x = x + jax.lax.psum(y, "tp") + ybias
-        else:
-            x = x + moe_ffn_dense(h2, lp, cfg.capacity_factor)[0]
-    else:
-        y = _mlp(h2, lp)
-        if tp_psum:
-            y = jax.lax.psum(y, "tp")
-        x = x + y + lp["b2"]
+    x = attn_merge(x, o, gate, lp, cfg, tp_psum=tp_psum)
+    x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
     return x, cache_l
 
 
@@ -524,20 +508,17 @@ def _incremental_forward(params, tokens, cache, offset, cfg,
     if prefill:
         chunk_attn = partial(
             resolve_attention_impl(cfg.attn_impl), causal=True,
-            window=cfg.attn_window,
         )
-    x = params["emb"][tokens]
+    x = embed(params, tokens, cfg)
     new_cache = []
-    for lp, cache_l in zip(params["layers"], cache):
+    for li, (lp, cache_l) in enumerate(zip(params["layers"], cache)):
         x, cache_l = _incremental_layer(
-            x, lp, cache_l, qpos, cfg,
+            x, lp, cache_l, qpos, cfg, li,
             chunk_attn=chunk_attn, kv_slice=kv_slice, tp_psum=tp_psum,
             ring=ring, decode_kernel=decode_kernel,
         )
         new_cache.append(cache_l)
-    x = _ln(x, params["lnf_s"], params["lnf_b"])
-    logits = jnp.einsum("bld,vd->blv", x, params["emb"])
-    return logits, new_cache
+    return head_logits(params, x, cfg), new_cache
 
 
 # --------------------------------------------------------------------------
@@ -642,14 +623,41 @@ def decode_step_dense(params, token, cache, pos, cfg: TransformerConfig):
 # --------------------------------------------------------------------------
 
 
+def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
+    """Ring width of every layer's serving cache. A window layer's ring
+    is its window. A full-attention layer's is the context budget
+    ``cfg.max_context``: a ring that wide never wraps inside the
+    budget, so slot ``s`` holds position ``s`` and the one invariant
+    (``kpos = pos - ((pos - s) mod W)``, valid iff ``kpos >= 0``) is
+    plain causal attention there."""
+    out = []
+    for w in cfg.windows:
+        if w is None:
+            if cfg.max_context is None:
+                raise ValueError(
+                    "the ring cache needs a width for every layer: a "
+                    "sliding-window layer's is its window "
+                    "(TransformerConfig(attn_window=W) or layer_windows)"
+                    ", a full-attention layer's is the context budget "
+                    "(TransformerConfig(max_context=N); the max_len "
+                    "cache of init_cache needs neither)"
+                )
+            w = cfg.max_context
+        out.append(int(w))
+    return tuple(out)
+
+
 def _check_ring_cfg(cfg: TransformerConfig) -> int:
-    if cfg.attn_window is None:
+    """The one ring width of a configuration whose layers all share it
+    (the single-request ring generators and the sharded tick)."""
+    widths = set(ring_widths(cfg))
+    if len(widths) > 1:
         raise ValueError(
-            "the ring cache is the sliding-window cache: set "
-            "TransformerConfig(attn_window=W) to use it (full-attention "
-            "configs need every position — use the max_len cache)"
+            "this ring-cache program keeps one width for all layers; "
+            f"the configuration has layers of more than one cache width "
+            f"({sorted(widths)}). ServingScheduler serves it"
         )
-    return cfg.attn_window
+    return widths.pop()
 
 
 def init_ring_cache(

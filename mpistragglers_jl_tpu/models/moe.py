@@ -52,6 +52,10 @@ __all__ = [
     "switch_route_indices",
     "moe_ffn_dense",
     "moe_ffn_sharded",
+    "init_topk_layer",
+    "topk_route",
+    "grouped_matmul",
+    "moe_ffn_topk",
 ]
 
 
@@ -374,3 +378,149 @@ def moe_ffn_sharded(x: jax.Array, mp: dict, capacity_factor: float,
 
 def _capacity(tokens: int, n_experts: int, capacity_factor: float) -> int:
     return max(1, int(np.ceil(tokens / n_experts * capacity_factor)))
+
+
+# --------------------------------------------------------------------------
+# dropless top-k experts beside shared ones (serving and the dense forward)
+# --------------------------------------------------------------------------
+#
+# The Switch layer above seats tokens in a fixed number of slots per
+# expert and drops the overflow, so what a token gets depends on which
+# other tokens are in the call. The layer below never drops: every
+# token is multiplied by exactly its own ``k`` experts, whatever the
+# rest of the batch chose. It is therefore a per-token function, and a
+# prompt prefilled in chunks, a decode step and the whole forward all
+# give a token the same result. That is what lets the serving
+# scheduler take it.
+
+
+def init_topk_layer(rng: np.random.Generator, cfg) -> dict:
+    """Per-layer params of the dropless layer: the router (float32, as
+    the Switch router and for the same reason) with its per-expert
+    selection bias, ``n_experts`` gated experts stacked on a leading
+    axis, and ``shared_experts`` always-on ones as one gated MLP of
+    their summed width. The bias is small and not zero, so that
+    "selects but does not weigh" shows in every test that uses it."""
+    D, E, F = cfg.d_model, cfg.n_experts, cfg.expert_width()
+    sd = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[-2]), cfg.dtype
+    )
+    out = {
+        "router": jnp.asarray(
+            rng.standard_normal((D, E)) / np.sqrt(D), jnp.float32),
+        "router_bias": jnp.asarray(
+            rng.standard_normal((E,)) * 0.02, jnp.float32),
+        "we_gate": sd(E, D, F),
+        "we_up": sd(E, D, F),
+        # float(): np.float64 scalars promote f32 params under x64
+        "we_down": sd(E, F, D) / float(np.sqrt(cfg.n_layers)),
+    }
+    Fs = cfg.shared_experts * F
+    if Fs:
+        out.update({
+            "ws_gate": sd(D, Fs),
+            "ws_up": sd(D, Fs),
+            "ws_down": sd(Fs, D) / float(np.sqrt(cfg.n_layers)),
+        })
+    return out
+
+
+def topk_route(x2d: jax.Array, router: jax.Array, bias: jax.Array,
+               k: int, scale: float):
+    """Sigmoid top-k routing of (T, D) tokens: scores
+    ``s = sigmoid(x @ router)`` in float32; the ``k`` experts with the
+    largest ``s + bias`` are chosen (the bias selects and does not
+    weigh); their weights are ``s`` itself, normalised to sum to one
+    and multiplied by ``scale``. Returns ``(idx (T, k) int32,
+    w (T, k) float32)``."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x2d, router, preferred_element_type=jnp.float32,
+    ))
+    _, idx = jax.lax.top_k(s + bias, k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx, w
+
+
+# m, k and n tile of the grouped product. Read on one v5e chip at the
+# widths this was written for (128 experts, 2048 -> 1024 -> 2048,
+# bfloat16; PR 26, PERF.md section 6): the three products of a
+# 2048-row prefill chunk took 2.58 ms at this tiling against 3.33 ms
+# at (128, 512, 512) and 5.38 ms through ``jax.lax.ragged_dot``; of a
+# 128-row decode step, 1.50 against 1.91 and 1.90 ms.
+_GROUP_TILE = (128, 1024, 1024)
+
+
+def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
+                   out_dtype) -> jax.Array:
+    """``xs[rows of group e] @ w[e]`` for every group: ``xs`` (M, K)
+    holds each group's rows together, in group order, ``w`` is
+    (E, K, N) and ``sizes`` (E,) int32 counts each group's rows (zero
+    allowed). One Pallas call (``megablox.gmm``) that visits, tile by
+    tile, only the (row tile, group) pairs that exist: it reads the
+    matrices of the groups that have rows and multiplies each row once.
+    Rows are padded to the kernel's row tile; the padding belongs to
+    no group and is cut off again."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from ..ops.flash_attention import _use_interpret
+
+    M, K = xs.shape
+    N = w.shape[2]
+    tm = _GROUP_TILE[0] if M >= _GROUP_TILE[0] else -(-M // 8) * 8
+    pad = -M % tm
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    out = gmm(
+        xs, w, sizes, preferred_element_type=out_dtype,
+        tiling=(tm, min(K, _GROUP_TILE[1]), min(N, _GROUP_TILE[2])),
+        interpret=_use_interpret(),
+    )
+    return out[:M] if pad else out
+
+
+def moe_ffn_topk(h: jax.Array, lp: dict, cfg):
+    """Dropless top-k experts plus the shared expert on (B, L, D).
+
+    The ``T * k`` (token, expert) pairs are sorted by expert, each
+    expert's rows are multiplied by that expert's matrices alone
+    (:func:`grouped_matmul`, one call per matrix: it reads the experts
+    that have rows and does ``2 * rows * D * F`` operations, not
+    ``n_experts`` times that), and the weighted results go back to
+    their tokens. Returns ``(y, hit)``: ``hit`` counts the experts that
+    got at least one row.
+    """
+    B, L, D = h.shape
+    T, k, E = B * L, cfg.experts_per_token, cfg.n_experts
+    x = h.reshape(T, D)
+    with jax.named_scope("moe_route"):
+        idx, w = topk_route(x, lp["router"], lp["router_bias"], k,
+                            cfg.route_scale)
+        # pair p = (token p // k, its (p % k)-th expert), by expert
+        expert, pair = jax.lax.sort(
+            (idx.reshape(-1), jnp.arange(T * k, dtype=jnp.int32)),
+            num_keys=1, is_stable=True,
+        )
+        sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+        hit = jnp.sum(sizes > 0)
+    with jax.named_scope("moe_experts"):
+        xs = jnp.take(x, pair // k, axis=0)  # (T*k, D), grouped
+        a = grouped_matmul(xs, lp["we_gate"], sizes, x.dtype)
+        b = grouped_matmul(xs, lp["we_up"], sizes, x.dtype)
+        ys = grouped_matmul(jax.nn.silu(a) * b, lp["we_down"], sizes,
+                            jnp.float32)
+        ys = ys * jnp.take(w.reshape(-1), pair)[:, None]
+        # back to token order: pair p's row sits at sorted position
+        # inv[p]; a token's k rows are then adjacent
+        inv = jnp.zeros((T * k,), jnp.int32).at[pair].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = jnp.take(ys, inv, axis=0).reshape(T, k, D).sum(axis=1)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe_shared"):
+            a = jax.nn.silu(jnp.einsum("td,df->tf", x, lp["ws_gate"]))
+            a = a * jnp.einsum("td,df->tf", x, lp["ws_up"])
+            y = y + jnp.einsum(
+                "tf,fd->td", a, lp["ws_down"],
+                preferred_element_type=jnp.float32,
+            )
+    return y.astype(h.dtype).reshape(B, L, D), hit

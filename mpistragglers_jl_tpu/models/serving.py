@@ -18,6 +18,16 @@ Design (TPU-first):
   (models/decode.py) makes every slot a fixed-size arena regardless of
   how long its request runs, so slot reuse is a row overwrite, never a
   reallocation, and one compiled program serves every scheduler tick.
+* **A width per layer.** The block is data (``TransformerConfig``):
+  a layer's ring is as wide as its own attention span
+  (``decode.ring_widths``) — the window of a sliding-window layer, the
+  context budget ``max_context`` of a full-attention layer, a ring
+  that never wraps inside the budget, so the one ring invariant serves
+  both. Layers of one width are one *kind*: paged, each kind has its
+  own :class:`PagePool` and page table (``_PageKind``), and a request
+  holds pages of every kind. Dropless top-k expert layers
+  (``models/moe.py`` ``moe_ffn_topk``) are per token, so chunks and
+  decode steps give what the whole forward gives.
 * **Per-row positions.** Unlike ``decode_step_ring_dense`` (one scalar
   position for the whole batch), every slot decodes at its own global
   position: RoPE angles, ring-slot writes, and the ``kpos >= 0``
@@ -104,6 +114,7 @@ from .decode import (
     _pick_token,
     _ring_from_cache,
     _route_kernel,
+    ring_widths,
 )
 from .paging import (
     NULL_PAGE,
@@ -114,8 +125,11 @@ from .paging import (
 from ..qos import DeficitScheduler, TenantRegistry
 from .transformer import (
     TransformerConfig,
-    _ln,
-    _mlp,
+    attn_merge,
+    attn_qkv,
+    embed,
+    ffn_half,
+    head_logits,
     make_kv_slice,
     param_specs,
 )
@@ -131,17 +145,19 @@ __all__ = [
 
 
 @functools.lru_cache(maxsize=32)
-def _fresh_arena(cfg: TransformerConfig, B: int, L: int,
+def _fresh_arena(cfg: TransformerConfig, B: int, L,
                  quantize_kv: bool):
     """Jitted ``serving_fresh_arena() -> [per-layer dict]``: every leaf
     of a zeroed ``(B, L, kv_heads, head_dim)`` cache out of ONE
-    dispatch. An eager ``jnp.zeros`` per leaf is a program launch and
+    dispatch (``L``: one length for all layers, or a tuple with one per
+    layer). An eager ``jnp.zeros`` per leaf is a program launch and
     an allocation each, four a layer, while the device has nothing
     queued: admission's whole host cost at 30 layers."""
-    shape = (B, L, cfg.kv_heads, cfg.head_dim)
+    lengths = (L,) * cfg.n_layers if isinstance(L, int) else tuple(L)
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
-    def layer():
+    def layer(length):
+        shape = (B, length, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
             out["k_s"] = jnp.zeros(shape[:3], jnp.float32)
@@ -150,12 +166,12 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L: int,
 
     @jax.jit
     def serving_fresh_arena():
-        return [layer() for _ in range(cfg.n_layers)]
+        return [layer(length) for length in lengths]
 
     return serving_fresh_arena
 
 
-def _fresh_cache(cfg: TransformerConfig, B: int, L: int,
+def _fresh_cache(cfg: TransformerConfig, B: int, L,
                  quantize_kv: bool = False) -> list[dict]:
     """Zeroed positional/ring cache with DISTINCT buffers per leaf,
     made by one program (:func:`_fresh_arena`). decode.py's
@@ -177,25 +193,52 @@ def serving_reset_arena(arena):
     return jax.tree.map(jnp.zeros_like, arena)
 
 
-def _fresh_pages(cfg: TransformerConfig, n_pages: int, P: int,
+def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
                  quantize_kv: bool = False) -> list[dict]:
     """Zeroed per-layer PAGE POOL: K/V live in one flat
     ``(n_pages * P, kv_heads, head_dim)`` row arena per layer (scales
     ``(n_pages * P, kv_heads)`` when int8), shared by every slot —
-    page ``p`` owns rows ``[p*P, (p+1)*P)``. Page 0 is the reserved
+    page ``p`` owns rows ``[p*P, (p+1)*P)``. ``n_pages``: one count for
+    all layers, or a tuple with one per layer (layers of one cache
+    width share a page table, and so a count). Page 0 is the reserved
     null page (:data:`~.paging.NULL_PAGE`): rows nothing reads
     unmasked, the landing zone for retired-but-still-ticking rows."""
-    shape = (n_pages * P, cfg.kv_heads, cfg.head_dim)
+    counts = ((n_pages,) * cfg.n_layers if isinstance(n_pages, int)
+              else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
-    def layer():
+    def layer(n):
+        shape = (n * P, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
             out["k_s"] = jnp.zeros(shape[:2], jnp.float32)
             out["v_s"] = jnp.zeros(shape[:2], jnp.float32)
         return out
 
-    return [layer() for _ in range(cfg.n_layers)]
+    return [layer(n) for n in counts]
+
+
+def _layer_kinds(cfg: TransformerConfig) -> tuple[tuple[int, ...],
+                                                  tuple[int, ...]]:
+    """``(kinds, kind_of_layer)``: the distinct cache widths of the
+    configuration's layers, narrowest first, and each layer's index
+    into them. Layers of one width share one page table and one
+    :class:`~.paging.PagePool`; a configuration of sliding-window
+    layers alone has one kind."""
+    widths = ring_widths(cfg)
+    kinds = tuple(sorted(set(widths)))
+    return kinds, tuple(kinds.index(w) for w in widths)
+
+
+def _layer_tables(cfg: TransformerConfig, pt) -> list:
+    """Each layer's page table (or page-table row) out of ``pt``: one
+    array where all layers share a width, else a tuple with one per
+    kind in :func:`_layer_kinds` order."""
+    tables = tuple(pt) if isinstance(pt, (tuple, list)) else (pt,)
+    _, kind_of = _layer_kinds(cfg)
+    if len(tables) == 1:
+        return [tables[0]] * cfg.n_layers
+    return [tables[k] for k in kind_of]
 
 
 # --------------------------------------------------------------------------
@@ -360,19 +403,17 @@ def _paged_attention_rows(q, cache_l, pt, pos, scale, P):
     )
 
 
-def _serving_layer(x, lp, cache_l, pos, cfg, *, kv_slice=None,
+def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
                    tp_psum=False, use_kernel=False, paged=None):
-    """One layer of the per-row serving step (the dense-FFN half of
-    decode.py's ``_incremental_layer`` with per-row positions).
+    """Layer ``li`` of the per-row serving step: models/transformer.py's
+    block with per-row positions and the K/V store of this module.
     ``paged`` = (page_table, W, PAGE_TOKENS) switches the cache
-    write/read to the page-pool layout; None is the slot-ring path."""
-    h = _ln(x, lp["ln1_s"], lp["ln1_b"])
-    q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
-    k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
-    v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
-    if kv_slice is not None:
-        k, v = kv_slice(k), kv_slice(v)
-    q, k = _rope_rows(q, pos), _rope_rows(k, pos)
+    write/read to the page-pool layout; None is the slot-ring path.
+    Returns ``(x, cache_l, hit)``; ``hit`` is the number of experts
+    that got a row in a dropless expert layer, None elsewhere."""
+    q, k, v, gate = attn_qkv(
+        x, lp, cfg, li, functools.partial(_rope_rows, pos=pos), kv_slice
+    )
     scale = cfg.head_dim ** -0.5
     # scopes name the K/V traffic (cache write, scores, softmax, p @ v)
     # and the MLP in a device trace; the projections stay outside both
@@ -390,31 +431,34 @@ def _serving_layer(x, lp, cache_l, pos, cfg, *, kv_slice=None,
             cache_l = _ring_write_rows(cache_l, k, v, jnp.mod(pos, W))
             o = _ring_attention_rows(q, cache_l, pos, scale,
                                      use_kernel=use_kernel)
-    attn_out = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
-    if tp_psum:
-        attn_out = jax.lax.psum(attn_out, "tp")
-    x = x + attn_out
+    x = attn_merge(x, o, gate, lp, cfg, tp_psum=tp_psum)
     with jax.named_scope("decode_mlp"):
-        h2 = _ln(x, lp["ln2_s"], lp["ln2_b"])
-        y = _mlp(h2, lp)
-    if tp_psum:
-        y = jax.lax.psum(y, "tp")
-    return x + y + lp["b2"], cache_l
+        x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
+    return x, cache_l, hit
 
 
 def _serving_forward(params, tok, pos, caches, cfg, *, kv_slice=None,
                      tp_psum=False, use_kernel=False, paged=None):
-    """(tok (S,), pos (S,), caches) -> (logits (S, V), caches)."""
-    x = params["emb"][tok[:, None]]  # (S, 1, d)
+    """(tok (S,), pos (S,), caches) -> (logits (S, V), caches, hits).
+    ``paged``: ``(per-layer page tables, PAGE_TOKENS)``. ``hits`` sums,
+    over the dropless expert layers, the experts that got a row this
+    step (None for a configuration without such a layer)."""
+    x = embed(params, tok[:, None], cfg)  # (S, 1, d)
     new = []
-    for lp, cl in zip(params["layers"], caches):
-        x, cl = _serving_layer(x, lp, cl, pos, cfg, kv_slice=kv_slice,
-                               tp_psum=tp_psum, use_kernel=use_kernel,
-                               paged=paged)
+    hits = None
+    for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
+        paged_l = None
+        if paged is not None:
+            pt = paged[0][li]
+            paged_l = (pt, pt.shape[1] * paged[1], paged[1])
+        x, cl, hit = _serving_layer(
+            x, lp, cl, pos, cfg, li, kv_slice=kv_slice, tp_psum=tp_psum,
+            use_kernel=use_kernel, paged=paged_l,
+        )
         new.append(cl)
-    x = _ln(x, params["lnf_s"], params["lnf_b"])
-    logits = jnp.einsum("bld,vd->blv", x, params["emb"])
-    return logits[:, 0], new
+        if hit is not None:
+            hits = hit if hits is None else hits + hit
+    return head_logits(params, x, cfg)[:, 0], new, hits
 
 
 def serving_decode_step_dense(params, tok, pos, caches,
@@ -424,8 +468,8 @@ def serving_decode_step_dense(params, tok, pos, caches,
     sibling is :func:`~.decode.decode_step_ring_dense`. Always the
     einsum path — this is the reference step the kernelized tick is
     pinned against."""
-    _check_ring_cfg(cfg)
-    return _serving_forward(params, tok, pos, caches, cfg)
+    ring_widths(cfg)
+    return _serving_forward(params, tok, pos, caches, cfg)[:2]
 
 
 def _pick_rows(lg, pos, keys, temperature, top_k, dtype):
@@ -452,21 +496,29 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
     or per-row keyed sampling when ``temperature > 0``; ``keys`` is
     required — a silent shared-default key would couple every
     scheduler's streams).
-    Returns (tok, pos, done, caches, toks (S, n_inner))."""
+    Returns (tok, pos, done, caches, toks (S, n_inner)). A
+    configuration with dropless expert layers gets one more ROW on
+    ``toks``, (S + 1, n_inner): step by step, the experts that got a
+    row, summed over those layers — the ``experts_hit`` counter rides
+    home in the fetch that brings the tokens."""
 
     def step(carry, _):
         tok, pos, done, caches = carry
-        lg, caches = _serving_forward(
+        lg, caches, hits = _serving_forward(
             params, tok, pos, caches, cfg, kv_slice=kv_slice,
             tp_psum=tp_psum, use_kernel=use_kernel, paged=paged,
         )
         nxt = _pick_rows(lg, pos, keys, temperature, top_k, tok.dtype)
         nxt, done = _eos_clamp(nxt, tok, done, eos_id)
-        return (nxt, pos + 1, done, caches), nxt
+        out = nxt if hits is None else (nxt, hits.astype(nxt.dtype))
+        return (nxt, pos + 1, done, caches), out
 
     (tok, pos, done, caches), toks = jax.lax.scan(
         step, (tok, pos, done, caches), None, length=n_inner
     )
+    if isinstance(toks, tuple):
+        toks, hits = toks
+        toks = jnp.concatenate([toks, hits[:, None]], axis=1)
     return tok, pos, done, caches, toks.swapaxes(0, 1)
 
 
@@ -518,23 +570,28 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def serving_tick_paged(params, tok, pos, done, caches, keys, pt):
-        W = pt.shape[1] * P
+        # one table for all layers, or one per cache width: each layer
+        # reads through its own kind's (``_layer_tables``)
+        pts = _layer_tables(cfg, pt)
         if use_kernel:
             return _scan_body(
                 params, tok, pos, done, caches, cfg, eos_id, n_inner,
                 keys, temperature=temperature, top_k=top_k,
-                use_kernel=True, paged=(pt, W, P),
+                use_kernel=True, paged=(pts, P),
             )
         with jax.named_scope("kv_page_gather"):
-            views = [_paged_gather(cl, pt, W, P) for cl in caches]
+            views = [
+                _paged_gather(cl, t, t.shape[1] * P, P)
+                for cl, t in zip(caches, pts)
+            ]
         tok, pos, done, views, toks = _scan_body(
             params, tok, pos, done, views, cfg, eos_id, n_inner, keys,
             temperature=temperature, top_k=top_k, use_kernel=False,
         )
         with jax.named_scope("kv_page_scatter"):
             caches = [
-                _paged_scatter(cl, vw, pt, P)
-                for cl, vw in zip(caches, views)
+                _paged_scatter(cl, vw, t, P)
+                for cl, vw, t in zip(caches, views, pts)
             ]
         return tok, pos, done, caches, toks
 
@@ -558,10 +615,10 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
     @functools.partial(jax.jit, donate_argnums=(0,))
     def serving_seed_prefix(cache, pages, pt_row, ell):
         s = jnp.arange(R)
-        phys = pt_row[s // P] * P + s % P
         valid = s < ell
 
-        def seed(c, pg):
+        def seed(c, pg, row):
+            phys = row[s // P] * P + s % P
             g = jnp.take(pg, phys, axis=0)  # (R, ...)
             g = jnp.where(
                 valid.reshape((R,) + (1,) * (g.ndim - 1)), g, 0
@@ -571,8 +628,9 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
             )
 
         return [
-            {kk: seed(cl[kk], pl[kk]) for kk in cl}
-            for cl, pl in zip(cache, pages)
+            {kk: seed(cl[kk], pl[kk], row) for kk in cl}
+            for cl, pl, row in zip(cache, pages,
+                                   _layer_tables(cfg, pt_row))
         ]
 
     return serving_seed_prefix
@@ -591,8 +649,10 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
 
     @jax.jit
     def serving_gather_ring(caches, pt_row):
-        W = pt_row.shape[0] * P
-        return [_paged_gather(cl, pt_row[None], W, P) for cl in caches]
+        return [
+            _paged_gather(cl, row[None], row.shape[0] * P, P)
+            for cl, row in zip(caches, _layer_tables(cfg, pt_row))
+        ]
 
     return serving_gather_ring
 
@@ -610,13 +670,18 @@ def _place_paged(cfg: TransformerConfig, P: int):
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
     def serving_place_pages(caches, ring, tok, pos, done, keys, pt_row,
                             s, tok0, pos0, key):
-        W = ring[0]["k"].shape[1]
-        srows = jnp.arange(W)
-        phys = pt_row[srows // P] * P + srows % P
+        # where each ring slot of each cache width lives in its pool
+        kinds, kind_of = _layer_kinds(cfg)
+        rows = tuple(pt_row) if isinstance(pt_row, (tuple, list)) \
+            else (pt_row,)
+        phys = []
+        for row, W in zip(rows, kinds):
+            srows = jnp.arange(W)
+            phys.append(row[srows // P] * P + srows % P)
         caches = [
-            {kk: c[kk].at[phys].set(r[kk][0].astype(c[kk].dtype))
+            {kk: c[kk].at[phys[k]].set(r[kk][0].astype(c[kk].dtype))
              for kk in c}
-            for c, r in zip(caches, ring)
+            for c, r, k in zip(caches, ring, kind_of)
         ]
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
@@ -649,6 +714,21 @@ def _copy_pages_paged(cfg: TransformerConfig, P: int):
     return serving_copy_pages
 
 
+def _refuse_switch_experts(cfg: TransformerConfig) -> None:
+    """The top-1 Switch layer seats tokens by capacity per CALL, so a
+    prompt's chunks and a decode step drop differently from the whole
+    forward; ``make_generate`` serves it. Dropless top-k expert layers
+    (``layer_experts``) are per token and are served here."""
+    if cfg.n_experts and cfg.layer_experts is None:
+        raise ValueError(
+            "the serving tick covers dense-FFN layers and dropless "
+            "top-k expert layers (TransformerConfig(layer_experts=...)); "
+            "the top-1 Switch layer drops by capacity per call "
+            "(models/decode.py prefill caveat) and is served via "
+            "make_generate"
+        )
+
+
 def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
                       *, eos_id: int | None = None,
                       quantize_kv: bool = False,
@@ -664,12 +744,7 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
     paths detect the layout)."""
     _check_ring_cfg(cfg)
     _check_sampling_params(temperature, top_k)
-    if cfg.n_experts:
-        raise ValueError(
-            "serving scheduler covers dense-FFN configs; MoE decode "
-            "routes per chunk (models/decode.py prefill caveat) and is "
-            "served via make_generate"
-        )
+    _refuse_switch_experts(cfg)
     tp = int(mesh.shape["tp"])
     if cfg.kv_heads % tp != 0 and tp % cfg.kv_heads != 0:
         raise ValueError(
@@ -759,11 +834,12 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     key at the prompt's last position — decode.py's fold discipline):
     (cache, last_logits (1, C, V), true_len, last_off, key) ->
     (tok0 (), ring leaves (1, W, ...))."""
-    W = _check_ring_cfg(cfg)
+    widths = ring_widths(cfg)
 
     @jax.jit
     def serving_first_token(cache, last_logits, true_len, last_off, key):
-        ring = [_ring_from_cache(cl, true_len, W) for cl in cache]
+        ring = [_ring_from_cache(cl, true_len, W)
+                for cl, W in zip(cache, widths)]
         lg = jnp.take(last_logits[0], true_len - 1 - last_off, axis=0)
         tok0 = _pick_rows(
             lg[None], (true_len - 1)[None], key[None], temperature,
@@ -1129,22 +1205,44 @@ class Request:
         self._trace_owned = False
 
 
+class _PageKind:
+    """One cache width's share of the paged arena: the layers that have
+    it, their :class:`PagePool`, the host-authoritative page table
+    (``(slots, W // P)``) those layers read through, and per slot
+    whether the resident request's lifetime can wrap a ring this wide
+    (its departure must then drop the wrapper count on every page it
+    holds — paging.py). A configuration of sliding-window layers alone
+    has one kind; window layers beside full-attention layers have two,
+    and a request holds pages of both."""
+
+    def __init__(self, name: str, W: int, P: int, n_pages: int,
+                 slots: int, layers: tuple[int, ...]):
+        self.name = name
+        self.W = W
+        self.max_pages = W // P
+        self.layers = layers
+        self.pool = PagePool(n_pages, P)
+        self.pt_host = np.full((slots, self.max_pages), NULL_PAGE,
+                               np.int32)
+        self.slot_wraps = [False] * slots
+
+
 class _Admitting:
     """Per-slot chunked-prefill state machine: the transient positional
     cache (the arena: taken from the scheduler's free list, back on it
     when the admission ends) plus the chunk cursor. Paged admissions
     additionally carry the page plan: ``base`` (tokens of shared prefix
     whose prefill is SKIPPED — chunk i runs at offset ``base + i*C``),
-    ``pids`` (the slot's full page table, installed into the device
-    table only at finish — until then the row's stale writes land in
-    the null page),
-    ``digests``/``n_cover`` (prefix digests to register at finish) and
-    ``wraps`` (whether this request can wrap its ring — registered
-    pages are then volatile)."""
+    ``pids`` (the slot's full page-table row of every cache width, in
+    the scheduler's kind order, installed into the device tables only
+    at finish — until then the row's stale writes land in the null
+    page), ``digests``/``n_cover`` (prefix digests to register at
+    finish) and ``wraps`` (per width, whether this request can wrap
+    that ring — registered pages are then volatile)."""
 
     def __init__(self, req: Request, cache, padded, n_chunks: int, *,
                  base: int = 0, pids=None, digests=(), n_cover: int = 0,
-                 wraps: bool = False):
+                 wraps=()):
         self.req = req
         self.cache = cache
         self.padded = padded  # (1, n_chunks * C) int32
@@ -1265,24 +1363,33 @@ class ServingScheduler:
                  max_queue: int | None = None, registry=None,
                  spans=None, flight=None, exporter=None, trace=None,
                  cache=None):
-        W = _check_ring_cfg(cfg)
+        # every layer's ring width, and the distinct ones ("kinds",
+        # narrowest first): W is the narrowest, which is the whole
+        # story for a configuration of sliding-window layers alone
+        widths = ring_widths(cfg)
+        kinds, kind_of = _layer_kinds(cfg)
+        W = kinds[0]
         _check_sampling_params(temperature, top_k)
-        if cfg.n_experts:
-            raise ValueError(
-                "serving scheduler covers dense-FFN configs (MoE: see "
-                "make_serving_scan's error note)"
-            )
+        _refuse_switch_experts(cfg)
         if slots < 1 or n_inner < 1:
             raise ValueError("slots and n_inner must be >= 1")
         if prompt_chunk > max_prompt:
             raise ValueError("prompt_chunk must be <= max_prompt")
+        if len(kinds) > 1 and (qos is not None or cache is not None):
+            raise ValueError(
+                "page quotas (qos=) and the fleet prefix cache (cache=) "
+                "count and move pages of one pool; this configuration "
+                "has layers of more than one cache width "
+                f"({list(kinds)}), a pool each"
+            )
         self.paged = page_tokens is not None
         if self.paged:
             self.P = int(page_tokens)
-            if self.P < 1 or W % self.P != 0:
+            if self.P < 1 or any(w % self.P for w in kinds):
                 raise ValueError(
                     f"page_tokens must divide the attention window "
-                    f"(W={W}), got {page_tokens}"
+                    f"(W={W}) and every other cache width "
+                    f"({list(kinds)}), got {page_tokens}"
                 )
             self.max_pages = W // self.P
         elif cache_pages is not None:
@@ -1294,6 +1401,18 @@ class ServingScheduler:
         self.cfg = cfg
         self.S = int(slots)
         self.W = W
+        # the narrowest width that is a context BUDGET and not a
+        # window: a request that could write past it is refused at
+        # submit (a ring that wide must never wrap)
+        self._context = min(
+            (w for w, span in zip(widths, cfg.windows) if span is None),
+            default=None,
+        )
+        # dropless expert layers: the tick counts the experts that got
+        # a row, and the count comes home with the tokens
+        self._expert_layers = sum(
+            cfg.dropless(li) for li in range(cfg.n_layers))
+        self.experts_hit: float | None = None
         self.n_inner = int(n_inner)
         self.eos_id = eos_id
         self.C = int(prompt_chunk)
@@ -1350,34 +1469,63 @@ class ServingScheduler:
             # slots x W. The default matches the slot-ring footprint
             # (every slot could hold a full window) plus the null page
             # — opting into paging never means LESS capacity.
-            n_pages = (
-                int(cache_pages) if cache_pages is not None
-                else self.S * self.max_pages + 1
-            )
-            if n_pages < self.max_pages + 1:
+            # With several cache widths every kind has a pool of its
+            # own, sized the same way; cache_pages is then one count
+            # per kind, narrowest first.
+            if cache_pages is None or isinstance(cache_pages, int):
+                if cache_pages is not None and len(kinds) > 1:
+                    raise ValueError(
+                        f"cache_pages needs one count per cache width "
+                        f"({list(kinds)}), got {cache_pages}"
+                    )
+                cache_pages = [cache_pages] * len(kinds)
+            if len(cache_pages) != len(kinds):
                 raise ValueError(
-                    f"cache_pages {n_pages} cannot hold even one "
-                    f"window-filling request ({self.max_pages} pages "
-                    "+ the null page)"
+                    f"cache_pages names {len(cache_pages)} pools, the "
+                    f"configuration has {len(kinds)} cache widths"
                 )
-            self.pool = PagePool(n_pages, self.P)
-            self._caches = _fresh_pages(cfg, n_pages, self.P,
-                                        self.quantize_kv)
-            # host-authoritative page table; the device copy refreshes
-            # lazily whenever admission/COW/retirement dirties it
-            self._pt_host = np.full((self.S, self.max_pages),
-                                    NULL_PAGE, np.int32)
+            span_of = dict(zip(widths, cfg.windows))
+            n_windows = sum(span_of[w] is not None for w in kinds)
+            self._kinds: list[_PageKind] = []
+            for k, (Wk, n) in enumerate(zip(kinds, cache_pages)):
+                n_pages = (int(n) if n is not None
+                           else self.S * (Wk // self.P) + 1)
+                if n_pages < Wk // self.P + 1:
+                    raise ValueError(
+                        f"cache_pages {n_pages} cannot hold even one "
+                        f"window-filling request ({Wk // self.P} pages "
+                        "+ the null page)"
+                    )
+                name = ("full" if span_of[Wk] is None
+                        else "window" if n_windows == 1
+                        else f"window{Wk}")
+                self._kinds.append(_PageKind(
+                    name, Wk, self.P, n_pages, self.S,
+                    tuple(li for li, kk in enumerate(kind_of) if kk == k),
+                ))
+            self._caches = _fresh_pages(
+                cfg, tuple(self._kinds[k].pool.n_pages for k in kind_of),
+                self.P, self.quantize_kv,
+            )
+            # the narrowest kind under the names the single-width code
+            # (quotas, fleet cache, migration) reads: the pool, the
+            # host-authoritative page table (the device copy refreshes
+            # lazily whenever admission/COW/retirement dirties it) and
+            # the per-slot wrap flags
+            self.pool = self._kinds[0].pool
+            self._pt_host = self._kinds[0].pt_host
+            self._slot_wraps = self._kinds[0].slot_wraps
             self._pt_dev = None
             # per-slot global position mirror (the COW pass must know
             # which ring pages the NEXT tick will write, host-side)
             self._host_pos = [0] * self.S
-            # per-slot wrap flag: whether the resident request's
-            # lifetime can wrap the ring — its departure must drop the
-            # wrapper count on every page it holds (paging.py)
-            self._slot_wraps = [False] * self.S
         else:
             self.pool = None
-            self._caches = _fresh_cache(cfg, self.S, W, self.quantize_kv)
+            self._kinds = []
+            self._caches = _fresh_cache(
+                cfg, self.S, W if len(kinds) == 1 else widths,
+                self.quantize_kv,
+            )
         # int8 Pallas kernel routing, resolved at construction against
         # THIS scheduler's slot count (decode.py's auto gate: the tick
         # batches all S slots into one kernel call per layer, which is
@@ -1527,6 +1675,15 @@ class ServingScheduler:
                 f"prompt of {req.prompt.size} tokens exceeds max_prompt "
                 f"{self.Lmax}; raise max_prompt (one-time recompile)"
             )
+        if (self._context is not None and req.prompt.size + req.max_new
+                + self.n_inner > self._context):
+            raise ValueError(
+                f"prompt of {req.prompt.size} tokens plus max_new "
+                f"{req.max_new} (and the retirement tick's {self.n_inner}"
+                f" steps) passes max_context {self._context}: a "
+                "full-attention layer's ring is that wide and must "
+                "never wrap; raise TransformerConfig(max_context=)"
+            )
         obs = self._obs
         if obs is not None:
             req._t_submit = time.perf_counter()
@@ -1576,7 +1733,14 @@ class ServingScheduler:
             (self._tok, self._pos, self._done, self._caches,
              toks) = self._scan(*self._scan_args())
         with _annotate("serving.decode_wait"):
-            return np.asarray(toks)  # (S, n_inner) one fetch per tick
+            host = np.asarray(toks)  # (S, n_inner) one fetch per tick
+        if self._expert_layers:
+            # the tick's own counter, in the row under the tokens: the
+            # mean, per expert layer and step, of experts with a row
+            self.experts_hit = float(host[self.S].sum()) / (
+                self.n_inner * self._expert_layers)
+            host = host[:self.S]
+        return host
 
     def lower_tick(self):
         """The decode tick's program lowered against the live state
@@ -1585,12 +1749,27 @@ class ServingScheduler:
         not, since the slot-ring path re-gates at trace time."""
         return self._scan.lower(*self._scan_args())
 
+    @property
+    def pools(self) -> dict[str, PagePool]:
+        """The paged arena's pools by cache width: ``{"window": ...}``
+        for sliding-window layers alone, ``"window"`` and ``"full"``
+        where full-attention layers stand beside them (empty unpaged)."""
+        return {kd.name: kd.pool for kd in self._kinds}
+
     def _device_pt(self):
-        """The device page table, refreshed from the host-authoritative
-        copy when admission/COW/retirement dirtied it."""
+        """The device page tables, one per cache width, refreshed from
+        the host-authoritative copies when admission/COW/retirement
+        dirtied them."""
         if self._pt_dev is None:
-            self._pt_dev = jnp.asarray(self._pt_host)
+            self._pt_dev = tuple(
+                jnp.asarray(kd.pt_host) for kd in self._kinds)
         return self._pt_dev
+
+    @staticmethod
+    def _pt_rows(rows):
+        """One slot's page-table row per kind, as the admission
+        programs take it."""
+        return tuple(jnp.asarray(r, jnp.int32) for r in rows)
 
     def step(self) -> list[Request]:
         """One scheduler tick; returns the requests retired in it
@@ -1616,6 +1795,10 @@ class ServingScheduler:
             "serving.tick", tick=self.tick_count, queue=self.pending,
             decoding=self.S - n_free - n_admitting,
             admitting=n_admitting, free=n_free,
+            # pages in use when the tick begins, by cache width (where
+            # the layers have more than one)
+            **({f"pages_{kd.name}": kd.pool.used for kd in self._kinds}
+               if len(self._kinds) > 1 else {}),
         ) as tick:
             with phase("serving.admit") as admit:
                 self._advance_admissions(retired)
@@ -1658,6 +1841,9 @@ class ServingScheduler:
                             n_retired += 1
                     harvest.set_metadata(tokens=n_tokens,
                                          retired=n_retired)
+                    if self._expert_layers:
+                        harvest.set_metadata(
+                            experts_hit=self.experts_hit)
         if obs is not None:
             obs.tick_done(self, retired, tick, admit, decode, harvest)
         if lit:
@@ -1710,11 +1896,13 @@ class ServingScheduler:
                         # _free_slot's table walk would miss them —
                         # release the committed plan here
                         n_refs = 0
-                        for pid in st.pids:
-                            if pid != NULL_PAGE:
-                                self.pool.decref(int(pid),
-                                                 wrapper=st.wraps)
-                                n_refs += 1
+                        for kd, pids, wraps in zip(self._kinds, st.pids,
+                                                   st.wraps):
+                            for pid in pids:
+                                if pid != NULL_PAGE:
+                                    kd.pool.decref(int(pid),
+                                                   wrapper=wraps)
+                                    n_refs += 1
                         self._tenant_debit(req.tenant, n_refs)
                 self._free_slot(s)
                 self._retire_cancelled(req)
@@ -1753,6 +1941,12 @@ class ServingScheduler:
         (first token emitted), not finished. None otherwise."""
         if not self.paged or req.finished or not req.tokens:
             return None
+        if len(self._kinds) > 1:
+            raise ValueError(
+                "KV-page migration moves one ring view per layer "
+                "through one page table; this configuration has layers "
+                "of more than one cache width"
+            )
         for s, r in enumerate(self._slot_req):
             if r is req and s not in self._admitting:
                 return s
@@ -1897,6 +2091,12 @@ class ServingScheduler:
         return state
 
     def _check_adopt_compat(self, state: dict) -> None:
+        if len(self._kinds) > 1:
+            raise ValueError(
+                "adopt_page_state: a migrated image is one ring view "
+                "per layer behind one page table; this configuration "
+                "has layers of more than one cache width"
+            )
         for k, want in (
             ("P", self.P), ("W", self.W),
             ("quantize_kv", self.quantize_kv),
@@ -2204,8 +2404,7 @@ class ServingScheduler:
                 # (identical bytes to what this prefill would compute)
                 cache = self._seed(
                     cache, self._caches,
-                    jnp.asarray(admit_kw["pids"], jnp.int32),
-                    jnp.int32(base),
+                    self._pt_rows(admit_kw["pids"]), jnp.int32(base),
                 )
             self._slot_req[s] = req
             self._admitting[s] = _Admitting(
@@ -2227,35 +2426,44 @@ class ServingScheduler:
     # -- paged admission planning --------------------------------------
 
     def _plan_pages(self, req: Request):
-        """Page budget for ``req``: which resident prefix pages it can
-        share, how many fresh pages it needs, and how many COW
-        reservations the shares must attach (one per share that can
-        ever end in a write — the sharer wraps its ring, or the page's
-        owner does). Returns None when the pool cannot cover the plan
-        — the caller leaves the request queued.
+        """Page budget for ``req``, for every cache width: which
+        resident prefix pages it can share, how many fresh pages it
+        needs, and how many COW reservations the shares must attach
+        (one per share that can ever end in a write — the sharer wraps
+        its ring, or the page's owner does). Returns None when a pool
+        cannot cover the plan — the caller leaves the request queued.
 
         The budget is the request's whole lifetime upper bound: ring
-        slots ``[0, min(W, Tp + max_new + n_inner))`` — prefill plus
-        every decode write including the bounded overshoot of the
-        retirement tick — so :class:`PagePoolExhausted` is unreachable
-        mid-decode (the capacity contract the fuzz tests pin)."""
-        shared, digests, n_pages, wraps, n_fresh, reserve, fetch = \
-            self._page_needs(req)
-        if not self.pool.can_alloc(n_fresh, reserve=reserve):
-            return None
-        return (shared, digests, n_pages, wraps, fetch)
+        slots ``[0, min(W, Tp + max_new + n_inner))`` of each width —
+        prefill plus every decode write including the bounded overshoot
+        of the retirement tick — so :class:`PagePoolExhausted` is
+        unreachable mid-decode (the capacity contract the fuzz tests
+        pin)."""
+        digests, fetch, needs = self._page_needs(req)
+        for kd, (_, _, _, n_fresh, reserve) in zip(self._kinds, needs):
+            if not kd.pool.can_alloc(n_fresh, reserve=reserve):
+                return None
+        return (digests, fetch, [n[:3] for n in needs])
 
     def _page_needs(self, req: Request):
         """The share walk + budget arithmetic both planners share:
-        (shared, digests, n_pages, wraps, n_fresh, reserve, fetch),
-        computed WITHOUT consulting pool capacity —
+        ``(digests, fetch, needs)`` with, per cache width in kind
+        order, ``needs[k] = (shared, n_pages, wraps, n_fresh,
+        reserve)``, computed WITHOUT consulting pool capacity —
         :meth:`_plan_pages` checks ``can_alloc`` and
         :meth:`_plan_pages_qos` turns the same numbers into a reclaim
-        shortfall instead. ``fetch`` is the fleet-cache extension:
-        where the LOCAL share walk breaks, the walk continues against
-        the fleet directory (host-DRAM store / peer replicas), and
-        every contiguously-probeable digest becomes a planned fetch —
-        a fresh allocation whose prefill is replaced by a page copy.
+        shortfall instead.
+
+        A shared prefix skips its prefill in EVERY layer, so a page
+        index is shared only where every kind holds that page: the
+        share run is the shortest over the kinds, and it exists only
+        for a prompt inside the narrowest ring (beyond it that ring's
+        pages hold late positions). ``fetch`` is the fleet-cache
+        extension (one cache width only): where the LOCAL share walk
+        breaks, the walk continues against the fleet directory
+        (host-DRAM store / peer replicas), and every
+        contiguously-probeable digest becomes a planned fetch — a fresh
+        allocation whose prefill is replaced by a page copy.
         Budget-wise fetched pages ARE fresh pages (they are inside
         ``n_fresh``), so the capacity/quota arithmetic is unchanged;
         only the prefill skip differs, and a fetch that fails at
@@ -2264,8 +2472,8 @@ class ServingScheduler:
         Tp = req.prompt.size
         W, P = self.W, self.P
         digests: list[bytes] = []
-        shared: list[int] = []
         fetch: list[bytes] = []
+        m = 0
         if Tp <= W:
             # within-window prompts: ring slot s == position s, so the
             # page content is determined by the page-aligned prefix —
@@ -2275,57 +2483,68 @@ class ServingScheduler:
             # cap: at least the prompt's last token must prefill (the
             # first sampled token needs its logits)
             shareable = digests[: (Tp - 1) // P]
-            for d in shareable:
-                pid = self.pool.lookup(d)
-                if pid is None:
-                    break
-                shared.append(pid)
+            m = len(shareable)
+            for kd in self._kinds:
+                for j, d in enumerate(shareable[:m]):
+                    if kd.pool.lookup(d) is None:
+                        m = j
+                        break
             if self.cache is not None:
-                for d in shareable[len(shared):]:
+                for d in shareable[m:]:
                     if self.cache.probe(
                             d, exclude=self.cache_name) is None:
                         break
                     fetch.append(d)
-        m = len(shared)
         horizon = Tp + req.max_new + self.n_inner
-        wraps = horizon > W
-        n_pages = -(-min(W, horizon) // P)
-        reserve = sum(
-            1 for pid in shared
-            if self.pool.share_needs_reserve(pid, wraps)
-        )
-        return (shared, digests, n_pages, wraps, n_pages - m, reserve,
-                fetch)
+        needs = []
+        for kd in self._kinds:
+            shared = [kd.pool.lookup(d) for d in digests[:m]]
+            wraps = horizon > kd.W
+            n_pages = -(-min(kd.W, horizon) // P)
+            reserve = sum(
+                1 for pid in shared
+                if kd.pool.share_needs_reserve(pid, wraps)
+            )
+            needs.append((shared, n_pages, wraps, n_pages - m, reserve))
+        return digests, fetch, needs
 
     def _commit_pages(self, req: Request, plan) -> tuple[int, dict]:
-        """Execute an admission plan: take references on the shared
-        pages (attaching their COW reservations), FETCH the planned
-        fleet-cache pages (host DRAM or a peer replica — each fetched
-        page is a fresh allocation filled with the transferred bytes
-        and registered, extending the prefill skip past the local
-        share run), and allocate the fresh tail. A fetch that comes
-        back empty (eviction, partition, kill raced the plan) stops
-        the fetch run and the remaining pages prefill as budgeted —
-        the cache saves work or does nothing, never corrupts.
+        """Execute an admission plan, cache width by cache width: take
+        references on the shared pages (attaching their COW
+        reservations), FETCH the planned fleet-cache pages (host DRAM
+        or a peer replica — each fetched page is a fresh allocation
+        filled with the transferred bytes and registered, extending
+        the prefill skip past the local share run), and allocate the
+        fresh tail. A fetch that comes back empty (eviction,
+        partition, kill raced the plan) stops the fetch run and the
+        remaining pages prefill as budgeted — the cache saves work or
+        does nothing, never corrupts.
         Returns (base, _Admitting kwargs)."""
-        shared, digests, n_pages, wraps, fetch = plan
-        m = len(shared)
-        pids = [NULL_PAGE] * self.max_pages
-        for j, pid in enumerate(shared):
-            self.pool.share(
-                pid, reserve=self.pool.share_needs_reserve(pid, wraps),
-                wrapper=wraps,
-            )
-            pids[j] = pid
-            if self._trace is not None and req.trace is not None:
-                self._trace.event(
-                    req.trace, "share_hit", time.perf_counter(),
-                    page=int(pid),
+        digests, fetch, needs = plan
+        m = len(needs[0][0])
+        all_pids = []
+        for kd, (shared, n_pages, wraps) in zip(self._kinds, needs):
+            pids = [NULL_PAGE] * kd.max_pages
+            for j, pid in enumerate(shared):
+                kd.pool.share(
+                    pid,
+                    reserve=kd.pool.share_needs_reserve(pid, wraps),
+                    wrapper=wraps,
                 )
-            if self._drr is not None and pid in self._cold:
-                # a cold page found its next sharer: the cache's hold
-                # transfers to the new slot (warm)
-                self._warm_cold(pid)
+                pids[j] = pid
+                if self._trace is not None and req.trace is not None:
+                    self._trace.event(
+                        req.trace, "share_hit", time.perf_counter(),
+                        page=int(pid),
+                    )
+                if self._drr is not None and pid in self._cold:
+                    # a cold page found its next sharer: the cache's
+                    # hold transfers to the new slot (warm)
+                    self._warm_cold(pid)
+            all_pids.append(pids)
+        # the fleet cache is a one-width feature (refused otherwise at
+        # construction): its pages go to the one pool there is
+        pids, wraps = all_pids[0], needs[0][2]
         n_fetched = 0
         for d in fetch:
             got = self.cache.fetch(d, exclude=self.cache_name)
@@ -2348,19 +2567,21 @@ class ServingScheduler:
                     page=int(pid), tier=src,
                 )
         m += n_fetched
-        for j in range(m, n_pages):
-            pids[j] = self.pool.alloc()
+        for kd, pids, (_, n_pages, _) in zip(self._kinds, all_pids,
+                                            needs):
+            for j in range(m, n_pages):
+                pids[j] = kd.pool.alloc()
         if self._drr is not None and req.tenant is not None:
             self._tenant_pages[req.tenant] = (
-                self._tenant_pages.get(req.tenant, 0) + n_pages
+                self._tenant_pages.get(req.tenant, 0) + needs[0][1]
             )
         # pages fully covered by the prompt hold registerable prefix
         # content once prefill lands them (done at finish)
         n_cover = min(req.prompt.size // self.P, self.max_pages) \
             if req.prompt.size <= self.W else 0
         return m * self.P, {
-            "pids": pids, "digests": tuple(digests),
-            "n_cover": n_cover, "wraps": wraps,
+            "pids": all_pids, "digests": tuple(digests),
+            "n_cover": n_cover, "wraps": [n[2] for n in needs],
         }
 
     # -- QoS page quotas + cold-page reclaim (qos= only) ----------------
@@ -2465,8 +2686,10 @@ class ServingScheduler:
         request still cannot be planned — the DRR pass then defers
         this tenant, not the rotation."""
         contract = self._qos.get(req.tenant)
-        shared, digests, n_pages, wraps, n_fresh, reserve, fetch = \
-            self._page_needs(req)
+        # quotas run on one cache width (refused otherwise at
+        # construction), so there is one kind's needs to read
+        digests, fetch, needs = self._page_needs(req)
+        shared, n_pages, wraps, n_fresh, reserve = needs[0]
         # the plan's own shares are never reclaim victims: evicting
         # one to make room would trade a prefill skip for a fresh
         # page — strictly worse on both bytes and time. (A resident
@@ -2498,7 +2721,7 @@ class ServingScheduler:
                                              tenant=req.tenant):
                     return None
                 need -= 1
-        return (shared, digests, n_pages, wraps, fetch)
+        return (digests, fetch, [(shared, n_pages, wraps)])
 
     def _prepare_tick_pages(self, decoding: list[int]) -> None:
         """Pre-tick COW pass: the next ``n_inner`` decode steps write
@@ -2510,15 +2733,23 @@ class ServingScheduler:
         out of the share table (its bytes are about to change). After
         this pass the device scan only ever writes exclusively-owned
         pages — COW is invisible to the compiled program."""
+        for kd in self._kinds:
+            self._prepare_kind_pages(kd, decoding)
+
+    def _prepare_kind_pages(self, kd: _PageKind,
+                            decoding: list[int]) -> None:
+        """:meth:`_prepare_tick_pages` for the layers of one cache
+        width: their ring, their pool, their page table."""
+        pool, pt_host = kd.pool, kd.pt_host
         copies: list[tuple[int, int]] = []
         for s in decoding:
             pos = self._host_pos[s]
             touched = {
-                ((pos + t) % self.W) // self.P
+                ((pos + t) % kd.W) // self.P
                 for t in range(self.n_inner)
             }
             for j in sorted(touched):
-                pid = int(self._pt_host[s, j])
+                pid = int(pt_host[s, j])
                 if pid == NULL_PAGE:
                     # defensive: the admission budget allocates every
                     # touchable page eagerly, so this is unreachable
@@ -2527,8 +2758,8 @@ class ServingScheduler:
                         f"slot {s} page {j} unallocated at write time "
                         "(admission budget bug)"
                     )
-                if self.pool.refcount(pid) > 1:
-                    new = self.pool.cow_alloc(pid)
+                if pool.refcount(pid) > 1:
+                    new = pool.cow_alloc(pid)
                     copies.append((pid, new))
                     if self._trace is not None:
                         _r = self._slot_req[s]
@@ -2540,12 +2771,11 @@ class ServingScheduler:
                     # the writer leaves the shared page for its copy;
                     # only wrapping slots ever write shared pages, so
                     # the page's wrapper count drops with it
-                    self.pool.decref(pid,
-                                     wrapper=self._slot_wraps[s])
-                    self._pt_host[s, j] = new
+                    pool.decref(pid, wrapper=kd.slot_wraps[s])
+                    pt_host[s, j] = new
                     self._pt_dev = None
                 else:
-                    self.pool.note_write(pid)
+                    pool.note_write(pid)
         if copies:
             # one device call for the whole tick's copies; pad to a
             # power of two with null-page self-copies so the jitted
@@ -2554,9 +2784,14 @@ class ServingScheduler:
             n = 1 << (len(copies) - 1).bit_length()
             copies += [(NULL_PAGE, NULL_PAGE)] * (n - len(copies))
             src, dst = (np.asarray(c, np.int32) for c in zip(*copies))
-            self._caches = self._copy(
-                self._caches, jnp.asarray(src), jnp.asarray(dst)
+            # the copy runs over this kind's layers alone: all of them
+            # where there is one width
+            moved = self._copy(
+                [self._caches[li] for li in kd.layers],
+                jnp.asarray(src), jnp.asarray(dst),
             )
+            for li, cl in zip(kd.layers, moved):
+                self._caches[li] = cl
 
     def _advance_admissions(self, retired: list[Request]) -> None:
         for s in list(self._admitting):
@@ -2600,23 +2835,28 @@ class ServingScheduler:
                 # install the page table NOW (stale row writes landed in
                 # the null page until this point), then scatter the ring
                 # window into the pages and flip the row live
-                self._pt_host[s] = st.pids
+                for kd, pids, wraps in zip(self._kinds, st.pids,
+                                           st.wraps):
+                    kd.pt_host[s] = pids
+                    kd.slot_wraps[s] = wraps
                 self._pt_dev = None
                 self._host_pos[s] = Tp
-                self._slot_wraps[s] = st.wraps
                 (self._caches, self._tok, self._pos, self._done,
                  self._keys) = self._place_p(
                     self._caches, ring, self._tok, self._pos, self._done,
-                    self._keys, jnp.asarray(self._pt_host[s]),
+                    self._keys,
+                    self._pt_rows(kd.pt_host[s] for kd in self._kinds),
                     jnp.int32(s), tok0, jnp.int32(Tp), rkey,
                 )
                 # the prompt-covered pages now hold exactly the content
                 # their chained prefix digests describe — publish them for
                 # future admissions to share (first-wins; the shared ones
                 # are already registered)
-                for j in range(st.n_cover):
-                    self.pool.register(st.digests[j], st.pids[j],
-                                       volatile=st.wraps)
+                for kd, pids, wraps in zip(self._kinds, st.pids,
+                                           st.wraps):
+                    for j in range(st.n_cover):
+                        kd.pool.register(st.digests[j], pids[j],
+                                         volatile=wraps)
             else:
                 (self._caches, self._tok, self._pos, self._done,
                  self._keys) = self._place(
@@ -2724,6 +2964,15 @@ class ServingScheduler:
                                      wrapper=self._slot_wraps[s])
             self._tenant_debit(tenant, n_refs)
             self._pt_host[s] = NULL_PAGE
+            self._slot_wraps[s] = False
+            # the wider kinds' pages (quotas and the fleet cache are
+            # one-width features, so nothing goes cold or spills here)
+            for kd in self._kinds[1:]:
+                for pid in kd.pt_host[s]:
+                    if pid != NULL_PAGE:
+                        kd.pool.decref(int(pid),
+                                       wrapper=kd.slot_wraps[s])
+                kd.pt_host[s] = NULL_PAGE
+                kd.slot_wraps[s] = False
             self._pt_dev = None
             self._host_pos[s] = 0
-            self._slot_wraps[s] = False

@@ -57,8 +57,10 @@ from ..parallel.ring_attention import (
 )
 from .moe import (
     init_moe_layer,
+    init_topk_layer,
     moe_ffn_dense,
     moe_ffn_sharded,
+    moe_ffn_topk,
     moe_layer_specs,
 )
 
@@ -117,6 +119,42 @@ class TransformerConfig:
     # backward may fuse/order differently — tests/test_transformer.py).
     remat: bool = False
     dtype: Any = jnp.float32
+    # -- the block as data ------------------------------------------------
+    # Everything below defaults to the block above (pre-LayerNorm,
+    # biased tanh-GELU MLP, tied head, one window, rotary everywhere).
+    # The dense forward, the incremental forward (models/decode.py) and
+    # the serving tick (models/serving.py) read the same fields through
+    # the same three functions (:func:`attn_qkv`, :func:`attn_merge`,
+    # :func:`ffn_half`); the sharded programs take the default block
+    # only (:func:`require_plain_block`).
+    d_head: int | None = None       # head size; None = d_model // n_heads
+    norm: str = "layernorm"         # | "rmsnorm" (a scale, no bias)
+    norm_eps: float = 1e-5
+    ffn: str = "gelu"               # | "swiglu" (gated, no bias)
+    tie_head: bool = True           # False: params["head"] (vocab, d_model)
+    qk_norm: bool = False           # norm over each head of q and k
+    attn_gate: bool = False         # o * sigmoid(h @ wog) before wo
+    post_norm: bool = False         # a norm after each half, too
+    emb_scale: float = 1.0          # x0 = emb[tok] * emb_scale
+    # per-layer attention span: an int is a sliding window, None every
+    # earlier position. None for the whole field = ``attn_window`` in
+    # every layer.
+    layer_windows: tuple | None = None
+    rope_full: bool = True          # rotary on full-attention layers
+    # per-layer feed-forward: True = dropless top-k experts
+    # (models/moe.py ``moe_ffn_topk``) of ``n_experts`` at width
+    # ``d_expert``, ``experts_per_token`` a token, beside
+    # ``shared_experts`` always-on ones; False = the dense ``ffn``.
+    # None for the whole field keeps ``n_experts``'s old meaning (the
+    # top-1 Switch layer in every layer).
+    layer_experts: tuple | None = None
+    experts_per_token: int = 1
+    d_expert: int | None = None
+    shared_experts: int = 0
+    route_scale: float = 1.0
+    # positions a full-attention layer's serving cache holds: a request
+    # whose prompt and answer could pass it is refused at submit
+    max_context: int | None = None
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -129,16 +167,36 @@ class TransformerConfig:
                 'attn_impl="flash" requires attn="ulysses" (ring '
                 "attention has no per-device full-sequence kernel)"
             )
-        if self.d_model % self.n_heads != 0:
+        if self.d_head is None and self.d_model % self.n_heads != 0:
             raise ValueError(
                 f"d_model {self.d_model} not divisible by n_heads "
                 f"{self.n_heads}"
             )
-        if (self.d_model // self.n_heads) % 2 != 0:
+        if self.head_dim % 2 != 0:
             raise ValueError(
-                f"RoPE requires even head_dim, got "
-                f"{self.d_model // self.n_heads}"
+                f"RoPE requires even head_dim, got {self.head_dim}"
             )
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown ffn {self.ffn!r}")
+        for name in ("layer_windows", "layer_experts"):
+            pat = getattr(self, name)
+            if pat is not None and len(pat) != self.n_layers:
+                raise ValueError(
+                    f"{name} names {len(pat)} layers, n_layers is "
+                    f"{self.n_layers}"
+                )
+        if self.layer_windows is not None and any(
+            w is not None and w < 1 for w in self.layer_windows
+        ):
+            raise ValueError("every window in layer_windows must be >= 1")
+        if self.layer_experts is not None and any(self.layer_experts):
+            if not 1 <= self.experts_per_token <= self.n_experts:
+                raise ValueError(
+                    f"experts_per_token {self.experts_per_token} must "
+                    f"lie in [1, n_experts={self.n_experts}]"
+                )
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(
                 f"attn_window must be >= 1, got {self.attn_window}"
@@ -153,7 +211,42 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         return self.d_model // self.n_heads
+
+    @property
+    def windows(self) -> tuple:
+        """Attention span of every layer (None = every position)."""
+        if self.layer_windows is not None:
+            return tuple(self.layer_windows)
+        return (self.attn_window,) * self.n_layers
+
+    def dropless(self, li: int) -> bool:
+        """Is layer ``li``'s feed-forward the dropless top-k experts?"""
+        return bool(self.layer_experts is not None
+                    and self.layer_experts[li])
+
+    def rope_at(self, li: int) -> bool:
+        return self.rope_full or self.windows[li] is not None
+
+    @property
+    def plain_block(self) -> bool:
+        """The default block in every layer (pre-LayerNorm, GELU MLP or
+        Switch experts, tied head, one attention span): what the
+        sharded programs (train step, ``make_generate``,
+        ``make_serving_scan``) and ``param_specs`` are written for."""
+        return (
+            len(set(self.windows)) == 1
+            and not (self.layer_experts and any(self.layer_experts))
+            and self.norm == "layernorm" and self.ffn == "gelu"
+            and self.tie_head and self.d_head is None
+            and not (self.qk_norm or self.attn_gate or self.post_norm)
+            and self.emb_scale == 1.0 and self.rope_full
+        )
+
+    def expert_width(self) -> int:
+        return self.d_ff if self.d_expert is None else self.d_expert
 
     @property
     def kv_heads(self) -> int:
@@ -162,32 +255,60 @@ class TransformerConfig:
 
 
 def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
-    """Plain pytree-of-arrays parameters (replicable / shardable)."""
+    """Plain pytree-of-arrays parameters (replicable / shardable). The
+    leaves follow the configuration's block: a LayerNorm has ``_s`` and
+    ``_b``, an RMSNorm ``_s`` alone; the GELU MLP ``w1 b1 w2 b2``, the
+    gated one ``w_gate w_up w_down``; a dropless expert layer
+    (:func:`~.moe.init_topk_layer`) its router, experts and shared
+    expert; an untied head ``params["head"]``."""
     rng = np.random.default_rng(seed)
     D, H, Dh, F = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
     Hkv = cfg.kv_heads
     sd = lambda *s: jnp.asarray(
         rng.standard_normal(s) / np.sqrt(s[0]), cfg.dtype
     )
+
+    def norm(name, width=D):
+        out = {name + "_s": jnp.ones((width,), cfg.dtype)}
+        if cfg.norm == "layernorm":
+            out[name + "_b"] = jnp.zeros((width,), cfg.dtype)
+        return out
+
     layers = []
-    for _ in range(cfg.n_layers):
+    for li in range(cfg.n_layers):
         layer = {
-            "ln1_s": jnp.ones((D,), cfg.dtype),
-            "ln1_b": jnp.zeros((D,), cfg.dtype),
+            **norm("ln1"),
             "wq": sd(D, H, Dh),
             "wk": sd(D, Hkv, Dh),
             "wv": sd(D, Hkv, Dh),
             # NB float(): an np.float64 scalar would silently promote
             # the param to f64 under jax_enable_x64
             "wo": sd(H, Dh, D) / float(np.sqrt(cfg.n_layers)),
-            "ln2_s": jnp.ones((D,), cfg.dtype),
-            "ln2_b": jnp.zeros((D,), cfg.dtype),
+            **norm("ln2"),
         }
-        if cfg.n_experts:
+        if cfg.attn_gate:
+            layer["wog"] = sd(D, H, Dh)
+        if cfg.qk_norm:
+            layer["qn_s"] = jnp.ones((Dh,), cfg.dtype)
+            layer["kn_s"] = jnp.ones((Dh,), cfg.dtype)
+        if cfg.post_norm:
+            layer.update(norm("ln1p"))
+            layer.update(norm("ln2p"))
+        if cfg.dropless(li):
+            layer.update(init_topk_layer(rng, cfg))
+        elif cfg.n_experts and cfg.layer_experts is None:
             layer.update(
                 init_moe_layer(
                     rng, D, F, cfg.n_experts, cfg.n_layers, cfg.dtype
                 )
+            )
+        elif cfg.ffn == "swiglu":
+            layer.update(
+                {
+                    "w_gate": sd(D, F),
+                    "w_up": sd(D, F),
+                    "w_down": sd(F, D) / float(np.sqrt(cfg.n_layers)),
+                }
             )
         else:
             layer.update(
@@ -199,14 +320,39 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
                 }
             )
         layers.append(layer)
-    return {
+    params = {
         "emb": jnp.asarray(
             rng.standard_normal((cfg.vocab, D)) * 0.02, cfg.dtype
         ),
         "layers": layers,
-        "lnf_s": jnp.ones((D,), cfg.dtype),
-        "lnf_b": jnp.zeros((D,), cfg.dtype),
+        **norm("lnf"),
     }
+    if not cfg.tie_head:
+        params["head"] = jnp.asarray(
+            rng.standard_normal((cfg.vocab, D)) * 0.02, cfg.dtype
+        )
+    return params
+
+
+def require_plain_block(cfg: TransformerConfig, what: str) -> None:
+    """The sharded programs are written for one kind of layer. Refuse,
+    by mechanism, a configuration they cannot run."""
+    if cfg.plain_block:
+        return
+    why = []
+    if len(set(cfg.windows)) > 1:
+        why.append("layers of more than one cache width")
+    if cfg.layer_experts and any(cfg.layer_experts):
+        why.append("dropless top-k expert layers")
+    if not why:
+        why.append("a block other than pre-LayerNorm / GELU MLP / "
+                   "tied head at head_dim = d_model // n_heads")
+    raise ValueError(
+        f"{what} runs the default block in every layer; this "
+        f"configuration has {' and '.join(why)}. One chip serves it "
+        "through ServingScheduler, forward_dense and the dense "
+        "decode functions"
+    )
 
 
 def _kv_tp_sharded(cfg: TransformerConfig, mesh: Mesh | None) -> bool:
@@ -237,6 +383,7 @@ def param_specs(cfg: TransformerConfig, mesh: Mesh | None = None) -> dict:
     ``tp`` (Megatron split), everything else replicated. Pass ``mesh``
     so GQA configs whose kv_heads < tp degree fall back to replicated
     K/V projections (see :func:`_kv_tp_sharded`)."""
+    require_plain_block(cfg, "param_specs (the sharded layout)")
     kv = P(None, "tp", None) if _kv_tp_sharded(cfg, mesh) else P()
     layer = {
         "ln1_s": P(), "ln1_b": P(),
@@ -272,6 +419,21 @@ def _ln(x, s, b, eps=1e-5):
     return ((x - mu) * jax.lax.rsqrt(var + eps)).astype(s.dtype) * s + b
 
 
+def _rms(x, s, eps=1e-5):
+    """RMSNorm over the last axis (a scale, no bias), in float32."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps)).astype(s.dtype) * s
+
+
+def _norm(x, p, name, cfg):
+    """The configuration's norm with the leaves ``<name>_s`` (and
+    ``<name>_b`` for a LayerNorm) of ``p``."""
+    if cfg.norm == "rmsnorm":
+        return _rms(x, p[name + "_s"], cfg.norm_eps)
+    return _ln(x, p[name + "_s"], p[name + "_b"], cfg.norm_eps)
+
+
 def _rope(x, pos):
     """Rotary embedding; pos carries GLOBAL token positions (L,)."""
     B, L, H, Dh = x.shape
@@ -286,27 +448,107 @@ def _rope(x, pos):
     )
 
 
-def _attn_block(x, lp, pos, attn_fn, kv_slice=None):
-    """Attention half-block on (B, L?, D) activations; the head dim may
-    be the tp-local shard — the caller supplies matching weights and the
-    tp psum when sharded (``attn_fn`` closes over sp specifics).
-    ``kv_slice`` post-selects kv heads from tp-replicated K/V
-    projections (the GQA kv_heads < tp case — see
+# The block, written once. Every forward (dense, sharded, incremental,
+# serving tick) is: attn_qkv -> its own attention over its own K/V
+# store -> attn_merge -> ffn_half. What a forward brings of its own is
+# where K/V live and how positions reach the rotary (``rope``).
+
+
+def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
+    """First part of layer ``li``'s attention half on (B, L, D): norm,
+    projections, the q/k norms, rotary. ``rope(t)`` rotates a
+    (B, L, H, Dh) tensor at the caller's positions. Returns
+    ``(q, k, v, gate)``; ``gate`` (None without ``attn_gate``) goes to
+    :func:`attn_merge`. ``kv_slice`` post-selects kv heads from
+    tp-replicated K/V projections (the GQA kv_heads < tp case — see
     :func:`_kv_tp_sharded`)."""
-    h = _ln(x, lp["ln1_s"], lp["ln1_b"])
+    h = _norm(x, lp, "ln1", cfg)
     q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
     k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
     v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
     if kv_slice is not None:
         k, v = kv_slice(k), kv_slice(v)
-    q, k = _rope(q, pos), _rope(k, pos)
-    o = attn_fn(q, k, v)
-    return jnp.einsum("blhk,hkd->bld", o, lp["wo"])
+    if cfg.qk_norm:
+        q = _rms(q, lp["qn_s"], cfg.norm_eps)
+        k = _rms(k, lp["kn_s"], cfg.norm_eps)
+    if cfg.rope_at(li):
+        q, k = rope(q), rope(k)
+    gate = None
+    if cfg.attn_gate:
+        gate = jnp.einsum("bld,dhk->blhk", h, lp["wog"])
+    return q, k, v, gate
+
+
+def attn_merge(x, o, gate, lp, cfg, tp_psum=False):
+    """Second part of the attention half: the output gate, the
+    out-projection (summed over ``tp`` when the heads were a shard),
+    the norm after the half, the residual."""
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+    a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
+    if tp_psum:
+        a = jax.lax.psum(a, "tp")
+    if cfg.post_norm:
+        a = _norm(a, lp, "ln1p", cfg)
+    return x + a
 
 
 def _mlp(x, lp):
     a = jax.nn.gelu(jnp.einsum("bld,df->blf", x, lp["w1"]) + lp["b1"])
     return jnp.einsum("blf,fd->bld", a, lp["w2"])
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    a = jax.nn.silu(jnp.einsum("bld,df->blf", x, w_gate))
+    return jnp.einsum("blf,fd->bld", a * jnp.einsum("bld,df->blf", x, w_up),
+                      w_down)
+
+
+def ffn_half(x, lp, cfg, li, *, tp_psum=False):
+    """Layer ``li``'s feed-forward half on (B, L, D). Returns
+    ``(x, aux, hit)``: the Switch layer's load-balance loss (0
+    elsewhere) and, for a dropless expert layer, how many experts got
+    at least one token (None elsewhere). ``tp_psum`` is the sharded
+    programs' (plain block only): hidden widths are ``tp`` shards."""
+    h = _norm(x, lp, "ln2", cfg)
+    aux, hit = jnp.float32(0.0), None
+    if cfg.dropless(li):
+        y, hit = moe_ffn_topk(h, lp, cfg)
+    elif cfg.n_experts and cfg.layer_experts is None:
+        if tp_psum:
+            # expert hidden dims are tp shards; bias rides outside the
+            # psum (it is tp-replicated, see moe_ffn_sharded)
+            y, ybias, aux = moe_ffn_sharded(h, lp, cfg.capacity_factor)
+            y = jax.lax.psum(y, "tp") + ybias
+        else:
+            y, aux = moe_ffn_dense(h, lp, cfg.capacity_factor)
+    elif cfg.ffn == "swiglu":
+        y = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+    else:
+        y = _mlp(h, lp)
+        if tp_psum:
+            y = jax.lax.psum(y, "tp")  # d_ff shard partial-sum
+        if not cfg.post_norm:
+            return x + y + lp["b2"], aux, hit  # b2 replicated
+        y = y + lp["b2"]
+    if cfg.post_norm:
+        y = _norm(y, lp, "ln2p", cfg)
+    return x + y, aux, hit
+
+
+def embed(params, tokens, cfg):
+    x = params["emb"][tokens]
+    if cfg.emb_scale != 1.0:
+        x = x * jnp.asarray(cfg.emb_scale, x.dtype)
+    return x
+
+
+def head_logits(params, x, cfg):
+    """Final norm and the output head on (B, L, D): the tied embedding,
+    or ``params["head"]``."""
+    x = _norm(x, params, "lnf", cfg)
+    w = params["emb"] if cfg.tie_head else params["head"]
+    return jnp.einsum("bld,vd->blv", x, w)
 
 
 def make_kv_slice(cfg: TransformerConfig):
@@ -346,31 +588,31 @@ def forward_dense(params: dict, tokens: jax.Array, cfg: TransformerConfig):
 def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
     """Dense forward returning (logits, summed MoE aux loss)."""
     pos = jnp.arange(tokens.shape[1])
-    x = params["emb"][tokens]
-    attn_fn = _local_attention(cfg)
+    x = embed(params, tokens, cfg)
+    rope = partial(_rope, pos=pos)
+    impl = resolve_attention_impl(cfg.attn_impl)
 
-    def one_layer(x, lp):
-        attn_out = _attn_block(x, lp, pos, attn_fn)
-        x = x + attn_out
-        h = _ln(x, lp["ln2_s"], lp["ln2_b"])
-        if cfg.n_experts:
-            y, a = moe_ffn_dense(h, lp, cfg.capacity_factor)
-            return x + y, a
-        return x + _mlp(h, lp) + lp["b2"], jnp.float32(0.0)
+    def one_layer(x, lp, li):
+        q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
+        o = impl(q, k, v, causal=True, window=cfg.windows[li])
+        x = attn_merge(x, o, gate, lp, cfg)
+        x, a, _ = ffn_half(x, lp, cfg, li)
+        return x, a
 
-    layer_fn = jax.checkpoint(one_layer) if cfg.remat else one_layer
+    layer_fn = (jax.checkpoint(one_layer, static_argnums=(2,))
+                if cfg.remat else one_layer)
     aux = jnp.float32(0.0)
-    for lp in params["layers"]:
-        x, a = layer_fn(x, lp)
+    for li, lp in enumerate(params["layers"]):
+        x, a = layer_fn(x, lp, li)
         aux = aux + a
-    x = _ln(x, params["lnf_s"], params["lnf_b"])
-    return jnp.einsum("bld,vd->blv", x, params["emb"]), aux  # tied head
+    return head_logits(params, x, cfg), aux
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig):
     """Per-shard forward: tokens are the batch/sequence-local chunk,
     params the tp/ep-local shards. Returns (local logits (B', L', V),
     summed MoE aux loss)."""
+    require_plain_block(cfg, "the sharded forward")
     Lc = tokens.shape[1]
     pos = jax.lax.axis_index("sp") * Lc + jnp.arange(Lc)
     if cfg.attn == "ring":
@@ -386,21 +628,15 @@ def _forward_local(params, tokens, cfg: TransformerConfig):
     else:
         raise ValueError(f"unknown sharded attention kind {cfg.attn!r}")
     kv_slice = make_kv_slice(cfg)
-    x = params["emb"][tokens]
+    x = embed(params, tokens, cfg)
+    rope = partial(_rope, pos=pos)
 
     def one_layer(x, lp):
-        attn_out = _attn_block(x, lp, pos, attn, kv_slice)
+        q, k, v, gate = attn_qkv(x, lp, cfg, 0, rope, kv_slice)
         # tp combine: heads were a shard, the out-projection partial-sums
-        attn_out = jax.lax.psum(attn_out, "tp")
-        x = x + attn_out
-        h = _ln(x, lp["ln2_s"], lp["ln2_b"])
-        if cfg.n_experts:
-            y, ybias, a = moe_ffn_sharded(h, lp, cfg.capacity_factor)
-            # expert hidden dims are tp shards; bias rides outside the
-            # psum (it is tp-replicated, see moe_ffn_sharded)
-            return x + jax.lax.psum(y, "tp") + ybias, a
-        y = jax.lax.psum(_mlp(h, lp), "tp")  # d_ff shard partial-sum
-        return x + y + lp["b2"], jnp.float32(0.0)  # b2 replicated
+        x = attn_merge(x, attn(q, k, v), gate, lp, cfg, tp_psum=True)
+        x, a, _ = ffn_half(x, lp, cfg, 0, tp_psum=True)
+        return x, a
 
     # remat recomputes each layer's activations in the backward — the
     # collectives inside (tp psum, ring ppermute / ulysses all_to_all,
@@ -410,8 +646,7 @@ def _forward_local(params, tokens, cfg: TransformerConfig):
     for lp in params["layers"]:
         x, a = layer_fn(x, lp)
         aux = aux + a
-    x = _ln(x, params["lnf_s"], params["lnf_b"])
-    return jnp.einsum("bld,vd->blv", x, params["emb"]), aux
+    return head_logits(params, x, cfg), aux
 
 
 def batch_axes(cfg: TransformerConfig) -> tuple[str, ...]:

@@ -539,14 +539,19 @@ def _stage_apply_payload(stacked_local, payload, pos, cfg):
     loss accumulates into the payload scalar that rides the pipeline to
     the head."""
     from ..models.moe import moe_ffn_dense
-    from ..models.transformer import _attn_block, _ln, _local_attention, _mlp
+    from ..models.transformer import (
+        _ln, _local_attention, _mlp, _rope, attn_merge, attn_qkv,
+        require_plain_block,
+    )
 
+    require_plain_block(cfg, "a pipeline stage")
     attn_fn = _local_attention(cfg)
     x, aux = payload
 
     def one_layer(carry, lp):
         h, a = carry
-        h = h + _attn_block(h, lp, pos, attn_fn)
+        q, k, v, gate = attn_qkv(h, lp, cfg, 0, partial(_rope, pos=pos))
+        h = attn_merge(h, attn_fn(q, k, v), gate, lp, cfg)
         h2 = _ln(h, lp["ln2_s"], lp["ln2_b"])
         if cfg.n_experts:
             y, la = moe_ffn_dense(h2, lp, cfg.capacity_factor)
