@@ -161,24 +161,26 @@ def _kernel_viable(q, cache_l) -> bool:
     """Trace-time shape gate shared by EVERY int8-kernel call site
     (masked ``_cached_attention``, ring ``_ring_cached_attention``,
     and serving's per-row ``_ring_attention_rows``): quantized cache,
-    single query, lane-aligned head_dim, a GQA group that fits the
-    kernel's 8 sublanes (ops/decode_attention._SUB), and a 128-multiple
-    block divisor for the cache length. One predicate so the routing
+    single query, lane-aligned head_dim, query heads in whole GQA
+    groups (any group: the kernel's q tile is the group rounded up to
+    8 sublanes, ops/decode_attention._group_tile), and a 128-multiple
+    block divisor for the cache length whose working set, at that
+    tile, fits the kernel's VMEM budget. One predicate so the routing
     sites cannot drift from the kernel's actual constraints."""
     if not _is_quantized(cache_l):
         return False
     Hq, Hkv = q.shape[2], cache_l["k"].shape[2]
-    if (
-        q.shape[1] != 1
-        or q.shape[-1] % 128 != 0
-        or Hq % Hkv != 0
-        or Hq // Hkv > 8
-    ):
+    if q.shape[1] != 1 or q.shape[-1] % 128 != 0 or Hq % Hkv != 0:
         return False
-    from ..ops.decode_attention import DEFAULT_BLOCK_K, _pick_block_128
+    from ..ops.decode_attention import (
+        DEFAULT_BLOCK_K,
+        _group_tile,
+        _pick_block_128,
+    )
 
     return _pick_block_128(
-        cache_l["k"].shape[1], DEFAULT_BLOCK_K, Hkv, q.shape[-1]
+        cache_l["k"].shape[1], DEFAULT_BLOCK_K, Hkv, q.shape[-1],
+        _group_tile(Hq // Hkv),
     ) is not None
 
 
@@ -203,20 +205,29 @@ def _paged_kernel_possible(cfg, quantize_kv: bool, page_tokens: int,
                            use_kernel=_UNSET) -> bool:
     """Could the PAGED serving tick route the int8 kernel's page-table
     mode? ``_kernel_possible``'s cfg-static guard plus the paged-only
-    conditions the dense gather fallback does not have: the GQA group
-    must fit the kernel's 8-row tile (trace-time in the dense path,
-    cfg-static here — the serving tick fixes its routing at
-    construction) and the page size must be a streamable k-block
-    (``ops.decode_attention.paged_block_viable``). The serving
-    scheduler resolves this ONCE at construction against its slot
-    count; there is no trace-time re-gate on the paged path."""
+    conditions the dense gather fallback does not have: query heads in
+    whole GQA groups (trace-time in the dense path, cfg-static here —
+    the serving tick fixes its routing at construction; any group
+    routes, the kernel's q tile follows it), a page size that is a
+    streamable k-block (``ops.decode_attention.paged_block_viable``)
+    and one page of it inside the kernel's VMEM budget at this head
+    count and group tile. The serving scheduler resolves this ONCE at
+    construction against its slot count; there is no trace-time
+    re-gate on the paged path."""
     if not _kernel_possible(cfg, quantize_kv, use_kernel):
         return False
-    if cfg.n_heads // cfg.kv_heads > 8 or cfg.n_heads % cfg.kv_heads:
+    if cfg.n_heads % cfg.kv_heads:
         return False
-    from ..ops.decode_attention import paged_block_viable
+    from ..ops.decode_attention import (
+        _group_tile,
+        _pages_per_step,
+        paged_block_viable,
+    )
 
-    return paged_block_viable(page_tokens)
+    return paged_block_viable(page_tokens) and _pages_per_step(
+        1, page_tokens, cfg.kv_heads, cfg.head_dim,
+        _group_tile(cfg.n_heads // cfg.kv_heads),
+    ) is not None
 
 
 def _decode_kernel_interpreted(
@@ -252,10 +263,13 @@ def _kv_quantize(x):
     matters: per-position scales ride the cache (tiny — no D axis) and
     dequantization folds into the attention einsums as a rank-1 scale
     on scores (K) and probabilities (V), so no dequantized copy is
-    *required* at full size. Measured reality (docs/PERF.md): XLA
-    materializes one anyway before the dot, so on the current
-    toolchain this is a MEMORY feature (half the cache bytes), not a
-    latency feature."""
+    *required* at full size. Measured reality: in the einsum form XLA
+    materializes one anyway before the dot (docs/PERF.md), so there
+    int8 only halves the cache's bytes; through the Pallas kernel
+    (ops/decode_attention.py), which dequantizes in VMEM, it is a
+    latency feature too: the paged serving tick that routes it reads
+    pages in place, and StarCoder2-3B's tick fell from 156 ms to 73
+    when it did (`serve_sc2_chat`; PERF.md section 6, PR 27)."""
     xf = x.astype(jnp.float32)
     s = jnp.max(jnp.abs(xf), axis=-1) / 127.0
     s = jnp.maximum(s, 1e-8)  # all-zero rows (unwritten slots)

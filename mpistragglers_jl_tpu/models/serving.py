@@ -193,29 +193,71 @@ def serving_reset_arena(arena):
     return jax.tree.map(jnp.zeros_like, arena)
 
 
+def paged_scale_lanes(P: int) -> int:
+    """The minor axis of a pool's scale leaves at page size ``P``: the
+    kernel's rule (ops/decode_attention.py), imported where it is used
+    like the kernel itself."""
+    from ..ops.decode_attention import paged_scale_lanes as lanes
+
+    return lanes(P)
+
+
 def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
                  quantize_kv: bool = False) -> list[dict]:
-    """Zeroed per-layer PAGE POOL: K/V live in one flat
-    ``(n_pages * P, kv_heads, head_dim)`` row arena per layer (scales
-    ``(n_pages * P, kv_heads)`` when int8), shared by every slot —
-    page ``p`` owns rows ``[p*P, (p+1)*P)``. ``n_pages``: one count for
-    all layers, or a tuple with one per layer (layers of one cache
-    width share a page table, and so a count). Page 0 is the reserved
-    null page (:data:`~.paging.NULL_PAGE`): rows nothing reads
-    unmasked, the landing zone for retired-but-still-ticking rows."""
+    """Zeroed per-layer PAGE POOL, shared by every slot, in the layout
+    the paged decode kernel's blocks have (ops/decode_attention.py), so
+    that a tick reads and writes pages where they lie and nothing of
+    the pool's size is ever re-laid out: K/V ``(n_pages, P, kv_heads *
+    head_dim)``, a page one contiguous block of P rows; int8 scales
+    ``(n_pages, kv_heads, lanes)`` with a page's P positions on the
+    minor axis, as the scores want them, and that axis padded to whole
+    128-lane rows (``paged_scale_lanes``; the padding is never read):
+    the device stores a leaf with a narrow minor axis transposed and
+    re-lays it out at every program's door, and this shape it stores
+    as the kernel reads it. ``n_pages``: one count for all layers, or a
+    tuple with one per layer (layers of one cache width share a page
+    table, and so a count). Page 0 is the reserved null page
+    (:data:`~.paging.NULL_PAGE`): rows nothing reads unmasked, the
+    landing zone for retired-but-still-ticking rows."""
     counts = ((n_pages,) * cfg.n_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(n):
-        shape = (n * P, cfg.kv_heads, cfg.head_dim)
+        shape = (n, P, cfg.kv_heads * cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
-            out["k_s"] = jnp.zeros(shape[:2], jnp.float32)
-            out["v_s"] = jnp.zeros(shape[:2], jnp.float32)
+            sshape = (n, cfg.kv_heads, paged_scale_lanes(P))
+            out["k_s"] = jnp.zeros(sshape, jnp.float32)
+            out["v_s"] = jnp.zeros(sshape, jnp.float32)
         return out
 
     return [layer(n) for n in counts]
+
+
+def _rows_to_pages(kk: str, x, P: int):
+    """Cache rows to pool-layout page blocks (:func:`_fresh_pages`):
+    K/V ``(..., n * P, Hkv, D) -> (..., n, P, Hkv * D)``, a scale leaf
+    (``kk`` ends in ``_s``) ``(..., n * P, Hkv) -> (..., n, Hkv,
+    lanes)``, zeros in the lanes past P."""
+    if kk.endswith("_s"):
+        lead, (L, H) = x.shape[:-2], x.shape[-2:]
+        x = jnp.swapaxes(x.reshape(lead + (L // P, P, H)), -1, -2)
+        pad = [(0, 0)] * (x.ndim - 1) + [(0, paged_scale_lanes(P) - P)]
+        return jnp.pad(x, pad)
+    lead, (L, H, D) = x.shape[:-3], x.shape[-3:]
+    return x.reshape(lead + (L // P, P, H * D))
+
+
+def _pages_to_rows(kk: str, blk, Hkv: int, P: int):
+    """Inverse of :func:`_rows_to_pages`: ``(..., n, P, Hkv * D) ->
+    (..., n * P, Hkv, D)`` and ``(..., n, Hkv, lanes) -> (..., n * P,
+    Hkv)``."""
+    lead, n = blk.shape[:-3], blk.shape[-3]
+    if kk.endswith("_s"):
+        blk = jnp.swapaxes(blk[..., :P], -1, -2)
+        return blk.reshape(lead + (n * P, Hkv))
+    return blk.reshape(lead + (n * P, Hkv, blk.shape[-1] // Hkv))
 
 
 def _layer_kinds(cfg: TransformerConfig) -> tuple[tuple[int, ...],
@@ -318,16 +360,29 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False):
 
 def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
     """Write each row's single-token K/V through its page table:
-    ring slot ``slot[i]`` of row i lives at pool row
-    ``pt[i, slot // P] * P + slot % P``. The scheduler's pre-tick COW
-    pass guarantees every page written here is exclusively owned (or
-    the null page, for retired rows) — the device program never has to
-    know pages can be shared."""
-    rows = jnp.arange(k.shape[0])
-    phys = pt[rows, slot // P] * P + slot % P  # (S,)
+    ring slot ``slot[i]`` of row i is row ``slot % P`` of pool page
+    ``pt[i, slot // P]``. The scheduler's pre-tick COW pass guarantees
+    every page written here is exclusively owned (or the null page,
+    for retired rows) — the device program never has to know pages
+    can be shared."""
+    S = k.shape[0]
+    page = pt[jnp.arange(S), slot // P]  # (S,)
+    off = slot % P
 
-    def put(c, u):
-        return c.at[phys].set(u[:, 0].astype(c.dtype))
+    def put(c, u):  # K/V: one (Hkv * D,) row of its page
+        return c.at[page, off].set(u[:, 0].reshape(S, -1).astype(c.dtype))
+
+    def put_s(c, u):
+        # scales: one position of each head's row of its page. Whole
+        # (Hkv, lanes) blocks out and back (a few KB), the position
+        # replaced on the way: an element scatter into this leaf makes
+        # the compiler keep it heads-minor through the scan, and
+        # re-lay it out for every kernel call
+        blk = jnp.where(
+            jnp.arange(c.shape[2]) == off[:, None, None],
+            u[:, 0, :, None].astype(c.dtype), jnp.take(c, page, axis=0),
+        )
+        return c.at[page].set(blk)
 
     if not _is_quantized(cache_l):
         return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v)}
@@ -336,30 +391,26 @@ def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
     return {
         "k": put(cache_l["k"], kq),
         "v": put(cache_l["v"], vq),
-        "k_s": put(cache_l["k_s"], ks),
-        "v_s": put(cache_l["v_s"], vs),
+        "k_s": put_s(cache_l["k_s"], ks),
+        "v_s": put_s(cache_l["v_s"], vs),
     }
 
 
-def _paged_gather(cache_l: dict, pt, W: int, P: int):
+def _paged_gather(cache_l: dict, pt, Hkv: int, P: int):
     """Materialize every slot's W-row ring view out of the page pool:
     one PAGE-BLOCK ``jnp.take`` per leaf — ``(S, max_pages)`` indices
-    moving contiguous P-row blocks. Page p's rows are ring slots
-    ``[j*P, (j+1)*P)`` in offset order, so reshaping the block gather
-    yields EXACTLY the slot-ring layout ``(S, W, ...)`` and the einsum
-    path runs the unchanged dense ring math on it — dense and paged
-    decode are the identical math by construction, which is what the
-    CPU parity tests lean on. Speed note: this gather runs once per
-    TICK (hoisted out of the decode scan — see ``_serving_scan_paged``;
-    a per-step gather measured 0.66x the slot tick). Null page-table
-    entries resolve to page 0, whose rows are only ever reached by
-    ``kpos < 0`` (masked) slots."""
-    S = pt.shape[0]
-    flat = pt.reshape(-1)  # (S * max_pages,)
+    moving whole pages. Page p's rows are ring slots ``[j*P, (j+1)*P)``
+    in offset order, so the gathered blocks, read as rows
+    (:func:`_pages_to_rows`), are EXACTLY the slot-ring layout
+    ``(S, W, Hkv, ...)`` and the einsum path runs the unchanged dense
+    ring math on it — dense and paged decode are the identical math by
+    construction, which is what the CPU parity tests lean on. Speed
+    note: this gather runs once per TICK (hoisted out of the decode
+    scan — see ``_serving_scan_paged``; a per-step gather measured
+    0.66x the slot tick). Null page-table entries resolve to page 0,
+    whose rows are only ever reached by ``kpos < 0`` (masked) slots."""
     return {
-        kk: jnp.take(
-            a.reshape((a.shape[0] // P, P) + a.shape[1:]), flat, axis=0
-        ).reshape((S, W) + a.shape[1:])
+        kk: _pages_to_rows(kk, jnp.take(a, pt, axis=0), Hkv, P)
         for kk, a in cache_l.items()
     }
 
@@ -372,25 +423,21 @@ def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int):
     exclusively owned (the pre-tick COW pass), so shared pages come
     back exactly as they went out. Null entries dump into page 0,
     which nothing reads unmasked."""
-    flat = pt.reshape(-1)
-    out = {}
-    for kk, a in cache_l.items():
-        paged_shape = (a.shape[0] // P, P) + a.shape[1:]
-        upd = view_l[kk].astype(a.dtype).reshape(
-            (flat.shape[0],) + paged_shape[1:]
-        )
-        out[kk] = a.reshape(paged_shape).at[flat].set(upd).reshape(
-            a.shape
-        )
-    return out
+    return {
+        kk: a.at[pt].set(
+            _rows_to_pages(kk, view_l[kk], P).astype(a.dtype))
+        for kk, a in cache_l.items()
+    }
 
 
 def _paged_attention_rows(q, cache_l, pt, pos, scale, P):
     """Single-query ring attention THROUGH the page table — the Pallas
     paged KERNEL route only (ops/decode_attention.py): the per-slot
     page-index row rides scalar-prefetch SMEM next to the per-row
-    positions and the block index maps gather K/V pages directly, so
-    HBM traffic is the W live rows. The einsum tick never reads
+    positions and the kernel copies in the pages a row has filled,
+    straight out of the pool, so HBM traffic is the live rows and a
+    table entry no position has reached costs nothing. The einsum tick
+    never reads
     through the table per step — ``_serving_scan_paged`` hoists the
     gather out of the scan instead (``_paged_gather`` + the unchanged
     dense ring math). Routing is resolved at scheduler construction
@@ -581,7 +628,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
             )
         with jax.named_scope("kv_page_gather"):
             views = [
-                _paged_gather(cl, t, t.shape[1] * P, P)
+                _paged_gather(cl, t, cfg.kv_heads, P)
                 for cl, t in zip(caches, pts)
             ]
         tok, pos, done, views, toks = _scan_body(
@@ -614,12 +661,13 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def serving_seed_prefix(cache, pages, pt_row, ell):
-        s = jnp.arange(R)
-        valid = s < ell
+        valid = jnp.arange(R) < ell
+        nb = -(-R // P)  # pages that cover rows [0, R)
 
-        def seed(c, pg, row):
-            phys = row[s // P] * P + s % P
-            g = jnp.take(pg, phys, axis=0)  # (R, ...)
+        def seed(kk, c, pg, row):
+            g = _pages_to_rows(
+                kk, jnp.take(pg, row[:nb], axis=0), cfg.kv_heads, P
+            )[:R]  # (R, ...)
             g = jnp.where(
                 valid.reshape((R,) + (1,) * (g.ndim - 1)), g, 0
             )
@@ -628,7 +676,7 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
             )
 
         return [
-            {kk: seed(cl[kk], pl[kk], row) for kk in cl}
+            {kk: seed(kk, cl[kk], pl[kk], row) for kk in cl}
             for cl, pl, row in zip(cache, pages,
                                    _layer_tables(cfg, pt_row))
         ]
@@ -650,7 +698,7 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
     @jax.jit
     def serving_gather_ring(caches, pt_row):
         return [
-            _paged_gather(cl, row[None], row.shape[0] * P, P)
+            _paged_gather(cl, row[None], cfg.kv_heads, P)
             for cl, row in zip(caches, _layer_tables(cfg, pt_row))
         ]
 
@@ -670,18 +718,14 @@ def _place_paged(cfg: TransformerConfig, P: int):
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
     def serving_place_pages(caches, ring, tok, pos, done, keys, pt_row,
                             s, tok0, pos0, key):
-        # where each ring slot of each cache width lives in its pool
-        kinds, kind_of = _layer_kinds(cfg)
-        rows = tuple(pt_row) if isinstance(pt_row, (tuple, list)) \
-            else (pt_row,)
-        phys = []
-        for row, W in zip(rows, kinds):
-            srows = jnp.arange(W)
-            phys.append(row[srows // P] * P + srows % P)
+        # the ring's rows as whole pages, each to the pool page its
+        # cache width's table row names: a page-block scatter
+        rows = _layer_tables(cfg, pt_row)
         caches = [
-            {kk: c[kk].at[phys[k]].set(r[kk][0].astype(c[kk].dtype))
+            {kk: c[kk].at[row].set(
+                _rows_to_pages(kk, r[kk][0], P).astype(c[kk].dtype))
              for kk in c}
-            for c, r, k in zip(caches, ring, kind_of)
+            for c, r, row in zip(caches, ring, rows)
         ]
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
@@ -705,9 +749,7 @@ def _copy_pages_paged(cfg: TransformerConfig, P: int):
     @functools.partial(jax.jit, donate_argnums=(0,))
     def serving_copy_pages(caches, src, dst):
         def cp(a):
-            paged = a.reshape((a.shape[0] // P, P) + a.shape[1:])
-            blk = jnp.take(paged, src, axis=0)
-            return paged.at[dst].set(blk).reshape(a.shape)
+            return a.at[dst].set(jnp.take(a, src, axis=0))
 
         return [{kk: cp(cl[kk]) for kk in cl} for cl in caches]
 
@@ -1795,6 +1837,9 @@ class ServingScheduler:
             "serving.tick", tick=self.tick_count, queue=self.pending,
             decoding=self.S - n_free - n_admitting,
             admitting=n_admitting, free=n_free,
+            # the route the tick's attention takes: 1 the int8 Pallas
+            # kernel, 0 the einsum (over gathered views when paged)
+            kernel=int(self.use_kernel),
             # pages in use when the tick begins, by cache width (where
             # the layers have more than one)
             **({f"pages_{kd.name}": kd.pool.used for kd in self._kinds}
@@ -1957,7 +2002,7 @@ class ServingScheduler:
         total = 0
         for cl in self._caches:
             for a in cl.values():
-                total += a.nbytes * self.P // a.shape[0]
+                total += a.nbytes // a.shape[0]
         return total
 
     def _page_payload(self, pid: int) -> np.ndarray:
@@ -1967,11 +2012,10 @@ class ServingScheduler:
         the fleet cache's wire/storage format: two schedulers with the
         same config produce byte-identical payloads for the same
         digest, which is what the spill/fetch parity tests pin."""
-        P = self.P
         parts = []
         for cl in self._caches:
             for kk in sorted(cl):
-                a = np.asarray(cl[kk][pid * P:(pid + 1) * P])
+                a = np.asarray(cl[kk][pid])
                 parts.append(
                     np.ascontiguousarray(a).reshape(-1).view(np.uint8)
                 )
@@ -1984,7 +2028,6 @@ class ServingScheduler:
         geometry bug refused by name (the cache hub validates
         page-byte equality at attach, so this only fires on config
         drift between attach and fetch)."""
-        P = self.P
         buf = np.asarray(payload).reshape(-1).view(np.uint8)
         if buf.size != self._page_row_bytes():
             raise ValueError(
@@ -1995,14 +2038,11 @@ class ServingScheduler:
         for cl in self._caches:
             for kk in sorted(cl):
                 a = cl[kk]
-                row_shape = (P,) + a.shape[1:]
-                nb = a.dtype.itemsize * int(np.prod(row_shape))
+                nb = a.dtype.itemsize * int(np.prod(a.shape[1:]))
                 vals = np.frombuffer(
                     buf[off:off + nb].tobytes(), dtype=a.dtype
-                ).reshape(row_shape)
-                cl[kk] = a.at[pid * P:(pid + 1) * P].set(
-                    jnp.asarray(vals)
-                )
+                ).reshape(a.shape[1:])
+                cl[kk] = a.at[pid].set(jnp.asarray(vals))
                 off += nb
 
     def _spill_page(self, pid: int, *,

@@ -24,8 +24,10 @@ kv heads (static row/lane slices, one MXU dot per head group):
   sequential k-blocks: block j+1's int8 K/V DMA overlaps block j's
   dots, so the stream never stalls on HBM;
 * the q heads ride the sublane axis, each GQA group zero-padded to
-  the 8-row tile (``(Hkv * 8, D)`` total); padding rows compute
-  garbage that is sliced off at the end, never normalized;
+  whole 8-row sublane tiles (``_group_tile``: a group of 12 takes 16
+  rows, 8 takes 8, 3 takes 8; ``(Hkv * tile, D)`` total), so any group
+  is served; padding rows compute garbage that is sliced off at the
+  end, never normalized;
 * per-(position, head) f32 scales arrive in their native
   ``(B, L, Hkv)`` layout too (whole-trailing-dim blocks are
   tile-legal) — NOTHING is transposed or copied outside the kernel;
@@ -45,13 +47,22 @@ kv heads (static row/lane slices, one MXU dot per head group):
   (``page_table=``): K/V live in a pool of ``(page_tokens,
   Hkv*D)``-row pages shared by every serving slot, and each row's
   ``(max_pages,)`` int32 page-index vector rides scalar-prefetch SMEM
-  so the BLOCK INDEX MAP itself dereferences the page table — block
-  ``(b, j)`` DMAs page ``page_table[b, j]`` straight out of the pool.
-  The k-block size becomes ``page_tokens`` and the math is otherwise
-  the identical ring-mode online softmax (``W = max_pages *
-  page_tokens``), so the paged serving tick and the dense gather
-  fallback (models/serving.py ``_paged_gather`` + the einsum rows)
-  stay numerically interchangeable.
+  and the pools stay in HBM: the grid is ``(B,)`` and the kernel
+  itself dereferences the table, copying in a row's LIVE pages (those
+  up to its position; all of them once the ring has wrapped) ``n`` to
+  a block (``_pages_per_step``: the largest of 8, 4, 2, 1 that divides
+  ``max_pages`` and fits the VMEM budget), double-buffered, the pages
+  joined in VMEM into one ``n * page_tokens``-row k-block. Measured on
+  the v5e (PERF.md, PR 27): handing every table entry to a block spec
+  costs 0.07 us an entry and operand whether the page is live or not
+  (0.29 ms a call at 16 rows x 64 entries, however many entries share
+  a grid step), and a short request leaves most of its table
+  unfilled; visiting live pages only is what makes a 64-entry table
+  cheap. The math is otherwise the identical ring-mode
+  online softmax (``W = max_pages * page_tokens``), so the paged
+  serving tick and the dense gather fallback (models/serving.py
+  ``_paged_gather`` + the einsum rows) stay numerically
+  interchangeable.
 
 Inference-only: no VJP (the cache is never differentiated through).
 Interpret mode on non-TPU backends keeps the path testable on the CI
@@ -71,26 +82,43 @@ from .flash_attention import _sds, _use_interpret
 
 _NEG = -1e30
 _LANE = 128
-_SUB = 8  # TPU sublane tile: each GQA group pads to this many q rows
+_SUB = 8  # TPU sublane tile: each GQA group pads to whole tiles of it
 
-__all__ = ["quantized_decode_attention", "paged_block_viable"]
+__all__ = ["quantized_decode_attention", "paged_block_viable",
+           "paged_scale_lanes"]
 
 
 # Scoped-VMEM budget per (block row x kv head), CALIBRATED on the
 # bench chip: Mosaic's stack allocation for this kernel measured
 # ~1435 B/(row*head) at D=128 (bk=5632, Hkv=2 hit 16.16 MiB against
 # the 16 MiB scoped limit) — double-buffered int8 K/V plus the f32
-# score/probability intermediates and allocator slack.
+# score/probability intermediates and allocator slack. That reading
+# was taken at an 8-row group tile; the three (tile, bk) f32
+# intermediates (scores, probabilities, scaled probabilities) grow
+# with the tile, 12 bytes a (row, head) for each row beyond 8.
 _VMEM_PER_ROW_HEAD = 11.3  # bytes per (row, head, D/128 lane group)
+_VMEM_PER_TILE_ROW = 12  # f32 s, p, pv: bytes per (row, head, tile row)
 _VMEM_CAP = 12 * 2 ** 20
 # default k-block budget; the models/decode.py routing gate imports
 # THIS constant so the two call sites cannot drift
 DEFAULT_BLOCK_K = 8192
 
 
+def _group_tile(g: int) -> int:
+    """Rows of the q tile one K/V head's GQA group takes: the group
+    rounded up to whole 8-row sublane tiles (12 -> 16, 8 -> 8, 3 -> 8)."""
+    return -(-int(g) // _SUB) * _SUB
+
+
+def _row_head_bytes(D: int, G: int) -> float:
+    """Calibrated scoped-VMEM bytes per (k-block row, K/V head) at head
+    size ``D`` and group tile ``G``."""
+    return D * _VMEM_PER_ROW_HEAD + (G - _SUB) * _VMEM_PER_TILE_ROW
+
+
 def paged_block_viable(page_tokens: int) -> bool:
     """Could the kernel stream ``page_tokens``-row k-blocks? Pages ride
-    the sublane axis of the ``(1, page_tokens, Hkv*D)`` block, so a
+    the sublane axis of the ``(page_tokens, Hkv*D)`` copy, so a
     compiled TPU kernel needs the int8 sublane tile (32 rows); the
     interpreter has no tiling and accepts any 8-row multiple (the CI
     parity surface — PAGE_TOKENS=16 tests run interpreted). The
@@ -102,24 +130,112 @@ def paged_block_viable(page_tokens: int) -> bool:
     return _use_interpret() or P % 32 == 0
 
 
-def _paged_kernel(pos_ref, pt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                  o_ref, acc, m_sc, l_sc, **kw):
-    """Scalar-prefetch entry: the page table is consumed ENTIRELY by
-    the block index maps (it decides which page each (b, j) step DMAs);
-    the online-softmax body is the ring-mode ``_kernel`` unchanged."""
-    del pt_ref
-    _kernel(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-            acc, m_sc, l_sc, **kw)
+def paged_scale_lanes(page_tokens: int) -> int:
+    """Minor axis of a page pool's scale leaves ``(n_pages, Hkv,
+    lanes)``: a page's positions rounded up to whole 128-lane rows.
+    At that width the device stores the leaf row-major, as the kernel's
+    ``(1, Hkv, lanes)`` blocks read it; a narrower minor axis it stores
+    transposed, and every program re-lays the leaf out on entry."""
+    return -(-int(page_tokens) // _LANE) * _LANE
+
+
+def _pages_per_step(max_pages: int, P: int, Hkv: int, D: int,
+                    G: int) -> int | None:
+    """Pages to a block of the paged form's loop: the largest of 8, 4,
+    2, 1 that divides ``max_pages`` and whose joined ``n * P``-row
+    k-block fits the calibrated VMEM budget at this head count and
+    group tile (64 -> 8, 32 -> 8, 68 -> 4). None: not even one page
+    fits — the caller keeps the gather route. On the v5e, 16 rows of
+    100 to 800 positions: 0.068 ms a call at 1, 0.037 at 8; rings that
+    have wrapped, 0.50 against 0.16 (PERF.md, PR 27)."""
+    for n in (8, 4, 2, 1):
+        if (max_pages % n == 0
+                and n * P * Hkv * _row_head_bytes(D, G) <= _VMEM_CAP):
+            return n
+    return None
+
+
+def _paged_kernel(pos_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
+                  o_ref, kbuf, ksbuf, vbuf, vsbuf, sem, acc, m_sc, l_sc,
+                  *, n, P, max_pages, scale, Hkv, D, G):
+    """One row of the batch a grid step: the pools stay in HBM and the
+    kernel copies in only the row's LIVE pages (those up to its
+    position; every page once the ring has wrapped), ``n`` a block,
+    the next block's copies in flight while this one's dots run. A
+    table entry that is never visited costs nothing, which is what
+    makes a 64-entry table cheap for a request that fills five.
+
+    ``kbuf``/``vbuf``: ``(2, n, P, Hkv*D)`` int8, ``ksbuf``/``vsbuf``:
+    ``(2, n, Hkv, lanes)`` float32, two buffers each; ``sem``: one DMA
+    semaphore a buffer. A page's scales arrive as ``(Hkv, lanes)``,
+    its P positions on the first lanes of head h's row: a slice is the
+    row the scores want."""
+    b = pl.program_id(0)
+    pos = pos_ref[b]
+    live = jnp.minimum(pos // P + 1, max_pages)  # pages with a live row
+    bk = n * P
+
+    def copies(blk, buf):
+        # the block's n pages; past the row's last live page repeat it
+        # (masked rows either way), so every block is n whole copies
+        out = []
+        for i in range(n):
+            page = pt_ref[b, jnp.minimum(blk * n + i, live - 1)]
+            for pool, dst in ((k_hbm, kbuf), (ks_hbm, ksbuf),
+                              (v_hbm, vbuf), (vs_hbm, vsbuf)):
+                out.append(pltpu.make_async_copy(
+                    pool.at[page], dst.at[buf, i], sem.at[buf]))
+        return out
+
+    def join(parts, axis):
+        return parts[0] if n == 1 else jnp.concatenate(parts, axis=axis)
+
+    acc[:] = jnp.zeros_like(acc)
+    m_sc[:] = jnp.full_like(m_sc, _NEG)
+    l_sc[:] = jnp.zeros_like(l_sc)
+    for c in copies(0, 0):
+        c.start()
+
+    def block(blk, carry):
+        buf = blk % 2
+
+        @pl.when((blk + 1) * n < live)
+        def _prefetch():
+            for c in copies(blk + 1, 1 - buf):
+                c.start()
+
+        for c in copies(blk, buf):
+            c.wait()
+        kpos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        # ring validity: slot s <= pos, or the ring has wrapped
+        mask = jnp.logical_or(kpos <= pos, pos >= max_pages * P)
+
+        def rows(sbuf):  # head h's (1, n * P) row of a block's scales
+            pages = [sbuf[buf, i] for i in range(n)]  # n x (Hkv, lanes)
+            return lambda h: join([x[h:h + 1, :P] for x in pages], 1)
+
+        _update(
+            q_ref,
+            (join([kbuf[buf, i] for i in range(n)], 0),
+             join([vbuf[buf, i] for i in range(n)], 0),
+             rows(ksbuf), rows(vsbuf)),
+            mask, acc, m_sc, l_sc, scale=scale, Hkv=Hkv, D=D, G=G,
+        )
+        return carry
+
+    jax.lax.fori_loop(0, (live + n - 1) // n, block, 0)
+    l = jnp.maximum(l_sc[:, :1], 1e-20)
+    o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
 
 
 def _pick_block_128(L: int, block: int, Hkv: int = 2,
-                    D: int = 128) -> int | None:
+                    D: int = 128, G: int = _SUB) -> int | None:
     """Largest lane-aligned block (multiple of 128) <= ``block``
-    dividing L whose calibrated working set fits scoped VMEM. Lengths
-    with no such divisor fall back to the whole dimension in one block
-    (block == dim is always tile-legal) when IT fits; otherwise None —
-    the caller keeps the einsum path."""
-    cap = int(_VMEM_CAP / (Hkv * D * _VMEM_PER_ROW_HEAD))
+    dividing L whose calibrated working set (at group tile ``G``) fits
+    scoped VMEM. Lengths with no such divisor fall back to the whole
+    dimension in one block (block == dim is always tile-legal) when IT
+    fits; otherwise None — the caller keeps the einsum path."""
+    cap = int(_VMEM_CAP / (Hkv * _row_head_bytes(D, G)))
     b = min(block, L, max(cap, 128))
     b -= b % 128
     while b >= 128:
@@ -132,7 +248,21 @@ def _pick_block_128(L: int, block: int, Hkv: int = 2,
 
 
 def _kernel(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-            acc, m_sc, l_sc, *, scale, window, bk, nk, Hkv, D, ring):
+            acc, m_sc, l_sc, **kw):
+    def load():
+        ksb = ks_ref[0].astype(jnp.float32)  # (bk, Hkv)
+        vsb = vs_ref[0].astype(jnp.float32)
+        return (k_ref[0], v_ref[0],
+                lambda h: ksb[:, h][None, :], lambda h: vsb[:, h][None, :])
+
+    _attend(pos_ref, q_ref, load, o_ref, acc, m_sc, l_sc, **kw)
+
+
+def _attend(pos_ref, q_ref, load, o_ref, acc, m_sc, l_sc, *, scale,
+            window, bk, nk, Hkv, D, G, ring):
+    """Grid step ``(b, j)`` of the dense forms: k-block j of row b.
+    ``load()``, called only when the step runs, reads the block
+    ``_update`` takes."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[b]  # this row's global decode position
@@ -152,7 +282,7 @@ def _kernel(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
         run = jnp.logical_and(run, pos - (j * bk + bk - 1) < window)
 
     @pl.when(run)
-    def _update():
+    def _run():
         kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         if ring:
             # slot s holds position pos - ((pos - s) mod W); kpos >= 0
@@ -162,43 +292,79 @@ def _kernel(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
             mask = kpos <= pos
             if window is not None:
                 mask = jnp.logical_and(mask, pos - kpos < window)
-        kblk = k_ref[0]  # (bk, Hkv*D) int8, one contiguous DMA
-        vblk = v_ref[0]
-        ksb = ks_ref[0].astype(jnp.float32)  # (bk, Hkv)
-        vsb = vs_ref[0].astype(jnp.float32)
-        # static loop over kv heads: static row/lane slices, one MXU
-        # dot per GQA group — the grouping costs index math, not DMA
-        for h in range(Hkv):
-            rows = slice(h * _SUB, (h + 1) * _SUB)
-            q = q_ref[0][rows]  # (SUB, D): g live rows + padding
-            kb = kblk[:, h * D:(h + 1) * D].astype(q.dtype)
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (SUB, bk)
-            s = s * ksb[:, h][None, :]
-            s = jnp.where(mask, s, _NEG)
-            m_prev = m_sc[rows, :1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(mask, p, 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            l_sc[rows] = jnp.broadcast_to(
-                l_sc[rows, :1] * corr + p.sum(axis=-1, keepdims=True),
-                (_SUB, _LANE),
-            )
-            vb = vblk[:, h * D:(h + 1) * D].astype(jnp.float32)
-            pv = p * vsb[:, h][None, :]
-            acc[rows] = acc[rows] * corr + jax.lax.dot_general(
-                pv, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_sc[rows] = jnp.broadcast_to(m_new, (_SUB, _LANE))
+        _update(q_ref, load(), mask, acc, m_sc, l_sc, scale=scale,
+                Hkv=Hkv, D=D, G=G)
 
     @pl.when(j == nk - 1)
     def _finish():
         l = jnp.maximum(l_sc[:, :1], 1e-20)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
+
+
+def _update(q_ref, block, mask, acc, m_sc, l_sc, *, scale, Hkv, D, G):
+    """One k-block's online-softmax update of ``acc``/``m_sc``/``l_sc``.
+    ``block``: int8 K and V ``(bk, Hkv*D)`` as the cache lays them out,
+    and for each of the two a function from a K/V head to its ``(1,
+    bk)`` row of float32 scales. ``mask``: ``(1, bk)``, the block's
+    valid positions. ``G`` is the q tile's rows per K/V head
+    (``_group_tile``)."""
+    kblk, vblk, k_scale, v_scale = block
+    # static loop over kv heads: static row/lane slices, one MXU dot
+    # per GQA group — the grouping costs index math, not DMA
+    for h in range(Hkv):
+        rows = slice(h * G, (h + 1) * G)
+        q = q_ref[0][rows]  # (G, D): g live rows + padding
+        kb = kblk[:, h * D:(h + 1) * D].astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (G, bk)
+        s = s * k_scale(h)
+        s = jnp.where(mask, s, _NEG)
+        m_prev = m_sc[rows, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[rows] = jnp.broadcast_to(
+            l_sc[rows, :1] * corr + p.sum(axis=-1, keepdims=True),
+            (G, _LANE),
+        )
+        vb = vblk[:, h * D:(h + 1) * D].astype(jnp.float32)
+        pv = p * v_scale(h)
+        acc[rows] = acc[rows] * corr + jax.lax.dot_general(
+            pv, vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_sc[rows] = jnp.broadcast_to(m_new, (G, _LANE))
+
+
+def _tile_q(q, Hkv: int):
+    """(B, 1, H, D) -> ``(B, Hkv * G, D)`` and ``G``: each K/V head's g
+    q rows padded to the group tile (tiny — no cache-sized copies)."""
+    B, T, H, D = q.shape
+    if T != 1:
+        raise ValueError(f"decode kernel is single-query, got T={T}")
+    g = H // Hkv
+    G = _group_tile(g)
+    q3 = q.reshape(B, Hkv, g, D)
+    if g < G:
+        q3 = jnp.pad(q3, ((0, 0), (0, 0), (0, G - g), (0, 0)))
+    return q3.reshape(B, Hkv * G, D), G
+
+
+def _untile_o(o3, q, Hkv: int, G: int):
+    """Drop each group's padding rows: (B, Hkv*G, D) -> (B, 1, H, D)."""
+    B, _, H, D = q.shape
+    return o3.reshape(B, Hkv, G, D)[:, :, :H // Hkv].reshape(B, 1, H, D)
+
+
+def _scratch(rows: int, D: int) -> list:
+    return [
+        pltpu.VMEM((rows, D), jnp.float32),
+        pltpu.VMEM((rows, _LANE), jnp.float32),
+        pltpu.VMEM((rows, _LANE), jnp.float32),
+    ]
 
 
 def quantized_decode_attention(
@@ -224,13 +390,14 @@ def quantized_decode_attention(
     in ring mode — the ring IS the window.
 
     ``page_table=`` (ring mode only) reads the PAGED ring layout:
-    ``cache_l`` leaves are page pools — {"k","v"} int8 ``(n_pages *
-    page_tokens, Hkv, D)`` + scales ``(n_pages * page_tokens, Hkv)``
-    shared by all rows — and ``page_table`` is the ``(B, max_pages)``
-    int32 table mapping row b's ring page j to its pool page. The
-    table rides scalar-prefetch SMEM and is dereferenced by the block
-    index maps, so each (b, j) grid step DMAs exactly the page the
-    table names — the HBM traffic of a decode step is the W live rows,
+    ``cache_l`` leaves are page pools in the layout the kernel copies
+    pages in, so nothing is re-laid out on the way — {"k","v"} int8
+    ``(n_pages, page_tokens, Hkv * D)`` + scales ``(n_pages, Hkv,
+    paged_scale_lanes(page_tokens))``, shared by all rows — and
+    ``page_table`` is the ``(B, max_pages)`` int32 table mapping row
+    b's ring page j to its pool page. The table rides scalar-prefetch
+    SMEM and the kernel dereferences it, copying in exactly the pages a
+    row has filled — the HBM traffic of a decode step is the live rows,
     never the pool (see module docstring). ``W = max_pages *
     page_tokens`` and the validity mask is ring mode's unchanged.
     """
@@ -249,14 +416,12 @@ def quantized_decode_attention(
             raise ValueError("page_table needs page_tokens")
         return _paged_call(q, cache_l, pos, scale, page_table,
                            int(page_tokens), interpret)
-    B, T, H, D = q.shape
-    if T != 1:
-        raise ValueError(f"decode kernel is single-query, got T={T}")
+    B, _, _, D = q.shape
     kc, vc = cache_l["k"], cache_l["v"]
     ks, vs = cache_l["k_s"], cache_l["v_s"]
     L, Hkv = kc.shape[1], kc.shape[2]
-    g = H // Hkv
-    bk = _pick_block_128(L, block_k, Hkv, D)
+    q3, G = _tile_q(q, Hkv)
+    bk = _pick_block_128(L, block_k, Hkv, D, G)
     if bk is None:
         raise ValueError(
             f"cache length {L} has no multiple-of-128 divisor <= "
@@ -265,18 +430,7 @@ def quantized_decode_attention(
             "use the einsum path"
         )
     nk = L // bk
-    if g > _SUB:
-        raise ValueError(
-            f"GQA group {g} exceeds the kernel's {_SUB}-row group tile"
-        )
-
-    # (B, 1, H, D) -> (B, Hkv*SUB, D): each kv head's g q-rows padded
-    # to the 8-row tile (tiny — no cache-sized copies anywhere here)
-    q3 = q.reshape(B, Hkv, g, D)
-    if g < _SUB:
-        q3 = jnp.pad(q3, ((0, 0), (0, 0), (0, _SUB - g), (0, 0)))
-    q3 = q3.reshape(B, Hkv * _SUB, D)
-    rows = Hkv * _SUB
+    rows = Hkv * G
     kf = kc.reshape(B, L, Hkv * D)  # free: (Hkv, D) tail is contiguous
     vf = vc.reshape(B, L, Hkv * D)
     # scalar pos broadcasts to every row; a (B,) vector rides as-is
@@ -286,7 +440,7 @@ def quantized_decode_attention(
 
     kern = functools.partial(
         _kernel, scale=scale, window=window, bk=bk, nk=nk, Hkv=Hkv,
-        D=D, ring=ring,
+        D=D, G=G, ring=ring,
     )
     o3 = pl.pallas_call(
         kern,
@@ -302,96 +456,98 @@ def quantized_decode_attention(
         ],
         out_specs=pl.BlockSpec((1, rows, D), lambda b, j: (b, 0, 0)),
         out_shape=_sds((B, rows, D), q.dtype, q),
-        scratch_shapes=[
-            pltpu.VMEM((rows, D), jnp.float32),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-        ],
+        scratch_shapes=_scratch(rows, D),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(posv, q3, kf, ks, vf, vs)
-    # (B, Hkv*SUB, D) -> drop each group's padding rows -> (B, 1, H, D)
-    return o3.reshape(B, Hkv, _SUB, D)[:, :, :g].reshape(B, 1, H, D)
+    return _untile_o(o3, q, Hkv, G)
 
 
 def _paged_call(q, cache_l: dict, pos, scale, page_table, P: int,
                 interpret: bool):
-    """Paged-ring pallas_call: grid (B, max_pages), k-block = one page,
-    block index maps dereference the scalar-prefetched page table."""
-    B, T, H, D = q.shape
-    if T != 1:
-        raise ValueError(f"decode kernel is single-query, got T={T}")
+    """The paged form: the pools checked against the layout the kernel
+    reads, the pages a block worked out from the shapes
+    (``_pages_per_step``), then the jitted call
+    (``paged_decode_attention``, the name a device trace shows)."""
+    D = q.shape[-1]
+    kc, ks = cache_l["k"], cache_l["k_s"]
+    Hkv, lanes = ks.shape[1], paged_scale_lanes(P)
+    if kc.shape[1:] != (P, Hkv * D) or ks.shape[2] != lanes:
+        raise ValueError(
+            f"page pool leaves {kc.shape} / {ks.shape} are not "
+            f"(pages, {P}, {Hkv}*{D}) / (pages, {Hkv}, {lanes})"
+        )
+    n = _pages_per_step(page_table.shape[1], P, Hkv, D,
+                        _group_tile(q.shape[2] // Hkv))
+    if n is None:
+        raise ValueError(
+            f"a page of {P} rows x {Hkv} heads of {D} does not fit the "
+            "kernel's VMEM budget; use the gather route"
+        )
+    return paged_decode_attention(q, cache_l, pos, page_table,
+                                  scale=scale, P=P, n=n,
+                                  interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "P", "n", "interpret"))
+def paged_decode_attention(q, cache_l: dict, pos, page_table, *, scale,
+                           P: int, n: int, interpret: bool):
+    """Paged-ring pallas_call: grid ``(B,)``, the pools left in HBM and
+    the scalar-prefetched page table dereferenced inside the kernel,
+    which copies in a row's live pages ``n`` at a time and nothing else
+    (``_paged_kernel``).
+
+    Jitted so that the layers of a tick, which call it at one set of
+    shapes, share ONE traced and lowered kernel: traced per call, 30
+    layers' kernels were 5 of the 6 seconds a StarCoder2 tick took to
+    lower, in every process, before the compile cache is even asked."""
+    B, _, _, D = q.shape
     kc, vc = cache_l["k"], cache_l["v"]
     ks, vs = cache_l["k_s"], cache_l["v_s"]
-    Nphys, Hkv = kc.shape[0], kc.shape[1]
-    g = H // Hkv
-    if g > _SUB:
-        raise ValueError(
-            f"GQA group {g} exceeds the kernel's {_SUB}-row group tile"
-        )
-    if Nphys % P != 0:
-        raise ValueError(
-            f"page pool of {Nphys} rows is not a multiple of "
-            f"page_tokens {P}"
-        )
-    npages = Nphys // P
+    Hkv, lanes = ks.shape[1], ks.shape[2]
     max_pages = page_table.shape[1]
-
-    q3 = q.reshape(B, Hkv, g, D)
-    if g < _SUB:
-        q3 = jnp.pad(q3, ((0, 0), (0, 0), (0, _SUB - g), (0, 0)))
-    q3 = q3.reshape(B, Hkv * _SUB, D)
-    rows = Hkv * _SUB
-    # pool leaves reshaped page-major — free (the trailing dims are
-    # contiguous), and each block below is one page's rows
-    kf = kc.reshape(npages, P, Hkv * D)
-    vf = vc.reshape(npages, P, Hkv * D)
-    ksr = ks.reshape(npages, P, Hkv)
-    vsr = vs.reshape(npages, P, Hkv)
+    q3, G = _tile_q(q, Hkv)
+    rows = Hkv * G
     posv = jnp.broadcast_to(
         jnp.asarray(pos, jnp.int32).reshape(-1), (B,)
     )
     ptv = page_table.astype(jnp.int32)
 
     kern = functools.partial(
-        _paged_kernel, scale=scale, window=None, bk=P, nk=max_pages,
-        Hkv=Hkv, D=D, ring=True,
+        _paged_kernel, n=n, P=P, max_pages=max_pages, scale=scale,
+        Hkv=Hkv, D=D, G=G,
     )
 
-    def _page(b, j, pos_ref, pt_ref):
-        del pos_ref
-        return (pt_ref[b, j], 0, 0)
-
-    def _row(b, j, pos_ref, pt_ref):
+    def _row(b, pos_ref, pt_ref):
         del pos_ref, pt_ref
         return (b, 0, 0)
 
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, rows, D), _row),
-            pl.BlockSpec((1, P, Hkv * D), _page),
-            pl.BlockSpec((1, P, Hkv), _page),
-            pl.BlockSpec((1, P, Hkv * D), _page),
-            pl.BlockSpec((1, P, Hkv), _page),
-        ],
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, rows, D), _row), hbm, hbm, hbm, hbm],
         out_specs=pl.BlockSpec((1, rows, D), _row),
         scratch_shapes=[
-            pltpu.VMEM((rows, D), jnp.float32),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-        ],
+            pltpu.VMEM((2, n, P, Hkv * D), kc.dtype),
+            pltpu.VMEM((2, n, Hkv, lanes), ks.dtype),
+            pltpu.VMEM((2, n, P, Hkv * D), vc.dtype),
+            pltpu.VMEM((2, n, Hkv, lanes), vs.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ] + _scratch(rows, D),
     )
     o3 = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=_sds((B, rows, D), q.dtype, q),
+        # a row starts and waits all of its own copies: rows are
+        # independent grid steps
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("parallel",)
         ),
         interpret=interpret,
-    )(posv, ptv, q3, kf, ksr, vf, vsr)
-    return o3.reshape(B, Hkv, _SUB, D)[:, :, :g].reshape(B, 1, H, D)
+    )(posv, ptv, q3, kc, ks, vc, vs)
+    return _untile_o(o3, q, Hkv, G)

@@ -52,7 +52,12 @@ def _oracle(q, cache_l, pos, scale, window=None):
     return _cache_pv(p, cache_l).astype(q.dtype)
 
 
-@pytest.mark.parametrize("Hq,Hkv", [(8, 2), (4, 4), (8, 1)])
+# GQA groups of 4, 1 and 8 (one 8-row q tile a K/V head), then 12, 16
+# and 3: the tile follows the group (16, 16 and 8 rows)
+GROUPS = [(8, 2), (4, 4), (8, 1), (24, 2), (16, 1), (12, 4)]
+
+
+@pytest.mark.parametrize("Hq,Hkv", GROUPS)
 @pytest.mark.parametrize("pos", [0, 7, 200, 255])
 def test_kernel_matches_einsum_oracle(Hq, Hkv, pos):
     B, L, D = 2, 256, 128
@@ -106,7 +111,7 @@ def _ring_oracle(q, cache_l, pos, scale):
 
 
 @pytest.mark.parametrize("B", [1, 4, 8])
-@pytest.mark.parametrize("Hq,Hkv", [(8, 2), (4, 4), (8, 1)])
+@pytest.mark.parametrize("Hq,Hkv", GROUPS)
 def test_batched_kernel_per_row_positions_match_oracle(B, Hq, Hkv):
     """The batched grid with a (B,) position vector — every row at its
     own decode step, the serving scheduler's shape — matches the
@@ -155,7 +160,8 @@ def test_ring_kernel_matches_ring_einsum(B, W, pos):
     )
 
 
-@pytest.mark.parametrize("Hq,Hkv", [(8, 2), (8, 1), (4, 4)])
+@pytest.mark.parametrize("Hq,Hkv", [(8, 2), (8, 1), (4, 4), (24, 2),
+                                    (16, 1), (12, 4)])
 def test_ring_kernel_per_row_positions(Hq, Hkv):
     """Per-row positions in ring mode — the serving tick's exact call:
     rows simultaneously in warmup, at the wrap boundary, and deep."""
@@ -172,6 +178,94 @@ def test_ring_kernel_per_row_positions(Hq, Hkv):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
     )
+
+
+def _paged_case(B, Hq, Hkv, P, max_pages, pos, seed):
+    """A page pool in the serving layout with every row's pages
+    scattered over it: row 1 shares row 0's first page, the last row's
+    last table entry is the null page (page 0, poisoned: a huge scale
+    that would swamp anything it leaked into), the rest is shuffled.
+    Returns (q, pool, page table, positions)."""
+    from mpistragglers_jl_tpu.models.serving import _rows_to_pages
+
+    D = 128
+    rng = np.random.default_rng(seed)
+    n_pages = B * max_pages + 1
+    rows = _quant_cache(1, n_pages * P, Hkv, D, seed=seed)
+    rows["k_s"] = rows["k_s"].at[:, :P].set(1e9)
+    rows["v_s"] = rows["v_s"].at[:, :P].set(1e9)
+    pool = {kk: _rows_to_pages(kk, a[0], P) for kk, a in rows.items()}
+    pt = rng.permutation(np.arange(1, n_pages)).reshape(B, max_pages)
+    if B > 1:
+        pt[1, 0] = pt[0, 0]
+    q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
+    pos = np.asarray(pos, np.int32)
+    if pos[-1] < (max_pages - 1) * P:  # the entry is never reached
+        pt[-1, -1] = 0
+    return q, pool, jnp.asarray(pt, jnp.int32), jnp.asarray(pos)
+
+
+def _paged_oracle(q, pool, pt, pos, P, Hkv):
+    """The gather route of the serving tick: every row's ring view out
+    of the pool, then the einsum rows."""
+    from mpistragglers_jl_tpu.models.serving import (
+        _paged_gather,
+        _ring_attention_rows,
+    )
+
+    view = _paged_gather(pool, pt, Hkv, P)
+    return _ring_attention_rows(q, view, pos, q.shape[-1] ** -0.5)
+
+
+# (max_pages, pages a grid step, positions of three rows): tables of
+# 64 and 32 entries take 8 pages a step, one of 68 takes 4; rows in
+# their first page only (every later step predicated off), rows mid
+# table, and rows whose ring has wrapped (every page live)
+PAGED = [
+    (64, 8, (0, 9, 15)),
+    (64, 8, (17, 500, 1023)),
+    (64, 8, (1024, 1500, 5000)),
+    (32, 8, (3, 200, 511)),
+    (32, 8, (512, 513, 2000)),
+    (68, 4, (5, 700, 1087)),
+    (68, 4, (1088, 1100, 4000)),
+]
+
+
+@pytest.mark.parametrize("Hq,Hkv", [(24, 2), (8, 1)])
+@pytest.mark.parametrize("max_pages,n,pos", PAGED)
+def test_paged_kernel_matches_gather_route(Hq, Hkv, max_pages, n, pos):
+    """The page-table form, several pages a grid step, against the
+    serving tick's gather route (``_paged_gather`` +
+    ``_ring_attention_rows``) on the same pool and table."""
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        _group_tile,
+        _pages_per_step,
+    )
+
+    P = 16
+    assert _pages_per_step(
+        max_pages, P, Hkv, 128, _group_tile(Hq // Hkv)) == n
+    q, pool, pt, posv = _paged_case(
+        3, Hq, Hkv, P, max_pages, pos, seed=max_pages + pos[1])
+    want = _paged_oracle(q, pool, pt, posv, P, Hkv)
+    got = quantized_decode_attention(
+        q, pool, posv, 128 ** -0.5, ring=True, page_table=pt,
+        page_tokens=P, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
+    )
+
+
+def test_paged_kernel_refuses_a_pool_in_another_layout():
+    q, pool, pt, posv = _paged_case(2, 8, 2, 16, 8, (3, 40), seed=1)
+    rows = {kk: a.reshape((-1,) + a.shape[2:]) for kk, a in pool.items()}
+    with pytest.raises(ValueError, match="page pool leaves"):
+        quantized_decode_attention(
+            q, rows, posv, 1.0, ring=True, page_table=pt,
+            page_tokens=16, interpret=True,
+        )
 
 
 def test_ring_rejects_window():

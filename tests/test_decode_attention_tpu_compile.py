@@ -1,0 +1,97 @@
+"""The int8 decode kernel compiled for a v5e that is described, not
+attached (the TPU's compiler is installed here): what the interpreter
+cannot refuse, Mosaic can: a slice off the tiling, a copy it cannot
+lower, more VMEM than a kernel may hold. Shapes are the serving cells'
+own. Nothing runs, so nothing here is a measurement.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library (on-chip-measurement guide, s. 2).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mpistragglers_jl_tpu.ops.decode_attention import (
+    paged_scale_lanes,
+    quantized_decode_attention,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compiled_text(fn, *args):
+    # as the chip runs it: the suite's 64-bit mode (conftest.py) is not
+    # the program's, and Mosaic has no float64
+    with jax.enable_x64(False):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (query heads, K/V heads, table entries, pool pages): StarCoder2-3B's
+# group of 12 on a 64-entry table, Trinity-Mini's group of 8 on its
+# window table (32) and its full-attention table (68)
+CELLS = [(24, 2, 64, 1025), (32, 4, 32, 513), (32, 4, 68, 1089)]
+
+
+@pytest.mark.parametrize("H,Hkv,max_pages,n_pages", CELLS)
+def test_paged_kernel_compiles_for_the_v5e(one_chip, H, Hkv, max_pages,
+                                           n_pages):
+    B, D, P = 16, 128, 64
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, k, ks, v, vs, pos, pt):
+        return quantized_decode_attention(
+            q, {"k": k, "k_s": ks, "v": v, "v_s": vs}, pos, D ** -0.5,
+            ring=True, page_table=pt, page_tokens=P, interpret=False,
+        )
+
+    pool = sds((n_pages, P, Hkv * D), jnp.int8)
+    scales = sds((n_pages, Hkv, paged_scale_lanes(P)), jnp.float32)
+    text = _compiled_text(
+        call, sds((B, 1, H, D), jnp.bfloat16), pool, scales, pool,
+        scales, sds((B,), jnp.int32), sds((B, max_pages), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+    # the pools reach the kernel as they are stored: no operation makes
+    # another array of a pool leaf's shape on the way in
+    for leaf in (f"s8[{n_pages},{P},{Hkv * D}]",
+                 f"f32[{n_pages},{Hkv},{paged_scale_lanes(P)}]"):
+        made = [ln for ln in text.splitlines()
+                if f"= {leaf}" in ln and " parameter(" not in ln]
+        assert not made, made[0]
+
+
+def test_ring_kernel_compiles_at_a_group_of_12(one_chip):
+    B, L, H, Hkv, D = 16, 4096, 24, 2, 128
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, k, ks, v, vs, pos):
+        return quantized_decode_attention(
+            q, {"k": k, "k_s": ks, "v": v, "v_s": vs}, pos, D ** -0.5,
+            ring=True, interpret=False,
+        )
+
+    text = _compiled_text(
+        call, sds((B, 1, H, D), jnp.bfloat16),
+        sds((B, L, Hkv, D), jnp.int8), sds((B, L, Hkv), jnp.float32),
+        sds((B, L, Hkv, D), jnp.int8), sds((B, L, Hkv), jnp.float32),
+        sds((B,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text

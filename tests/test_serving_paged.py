@@ -16,7 +16,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mpistragglers_jl_tpu.models.decode import generate_ring_dense
+from mpistragglers_jl_tpu.models.decode import (
+    _kernel_viable,
+    _paged_kernel_possible,
+    generate_ring_dense,
+    use_decode_kernel,
+)
 from mpistragglers_jl_tpu.models.serving import ServingScheduler
 from mpistragglers_jl_tpu.models.transformer import (
     TransformerConfig,
@@ -39,6 +44,15 @@ KCFG = TransformerConfig(
     d_ff=256, attn_window=128,
 )
 KPARAMS = init_params(KCFG, seed=31)
+
+
+# a GQA group of 12 on one K/V head (StarCoder2's group): the kernel's
+# q tile is 16 rows; W=32 in pages of 8 wraps within a short stream
+GCFG = TransformerConfig(
+    vocab=97, d_model=1536, n_heads=12, n_kv_heads=1, n_layers=2,
+    d_ff=256, attn_window=32,
+)
+GPARAMS = init_params(GCFG, seed=41)
 
 
 def _prompt(n, vocab=CFG.vocab):
@@ -234,6 +248,90 @@ def test_paged_page_sizes_and_kernel_tick_match_oracle(
                        quantize_kv=quantize_kv)
         assert r.tokens == want, f"request {r.id}"
     _drained(sched)
+
+
+def _group12_schedule(sched):
+    """Six requests through four slots: two share a page-aligned
+    8-token prefix and both wrap the 32-row window (COW of the shared
+    page), slots retire and are reused."""
+    sys_prompt = _prompt(8, GCFG.vocab)
+    prompts = [
+        np.concatenate([sys_prompt, _prompt(3, GCFG.vocab)]),
+        np.concatenate([sys_prompt, _prompt(5, GCFG.vocab)]),
+        _prompt(6, GCFG.vocab), _prompt(20, GCFG.vocab),
+        _prompt(2, GCFG.vocab), _prompt(13, GCFG.vocab),
+    ]
+    pairs = [(sched.submit(p, max_new=n), p, n)
+             for p, n in zip(prompts, (30, 27, 9, 16, 5, 7))]
+    sched.run()
+    return pairs
+
+
+def test_group_of_12_routes_the_kernel_and_matches_gather_and_oracle():
+    """12 query heads on one K/V head at head size 128: the paged tick
+    routes the int8 kernel (no group is refused any more), and over a
+    schedule with admission, retirement and COW its streams equal the
+    gather route's (the kernel forced off) and the dense oracle's."""
+    assert GCFG.head_dim == 128
+
+    def make():
+        return ServingScheduler(GPARAMS, GCFG, slots=4, n_inner=4,
+                                prompt_chunk=16, max_prompt=32,
+                                quantize_kv=True, page_tokens=8)
+
+    kern = make()
+    assert kern.use_kernel
+    state = RNG.bit_generator.state
+    by_kernel = _group12_schedule(kern)
+    assert kern.pool.share_hits > 0 and kern.pool.cow_copies > 0
+    _drained(kern)
+    use_decode_kernel(False)
+    try:
+        gather = make()
+    finally:
+        use_decode_kernel(None)
+    assert not gather.use_kernel
+    RNG.bit_generator.state = state  # the same prompts again
+    by_gather = _group12_schedule(gather)
+    for (r, p, n), (rg, pg, _) in zip(by_kernel, by_gather):
+        assert np.array_equal(p, pg)
+        assert r.finished and r.tokens == rg.tokens, f"request {r.id}"
+        assert r.tokens == _oracle(p, n, params=GPARAMS, cfg=GCFG,
+                                   quantize_kv=True), f"request {r.id}"
+
+
+def test_what_the_kernel_route_still_refuses(monkeypatch):
+    """A group of any size routes; what the kernel really cannot take
+    is still refused: a cache that is not int8, a head size off the
+    lane width, query heads that do not fall into whole groups, and,
+    compiled, a page that is not whole int8 sublane tiles."""
+    from mpistragglers_jl_tpu.models.decode import _zero_cache_layer
+    from mpistragglers_jl_tpu.ops import decode_attention as da
+
+    assert _paged_kernel_possible(GCFG, True, 8)
+    assert not _paged_kernel_possible(GCFG, False, 8)  # bfloat16 cache
+    assert not _paged_kernel_possible(CFG, True, 8)  # head size 8
+    d64 = TransformerConfig(vocab=97, d_model=768, n_heads=12,
+                            n_kv_heads=1, n_layers=1, d_ff=64,
+                            attn_window=32)
+    assert d64.head_dim == 64 and not _paged_kernel_possible(d64, True, 8)
+    q = jnp.zeros((4, 1, 12, 128), jnp.bfloat16)
+    assert _kernel_viable(q, _zero_cache_layer(4, 256, 1, 128, q.dtype, True))
+    assert not _kernel_viable(
+        q, _zero_cache_layer(4, 256, 1, 128, q.dtype, False))
+    assert not _kernel_viable(  # 12 heads on 5: no whole groups
+        q, _zero_cache_layer(4, 256, 5, 128, q.dtype, True))
+    assert not _kernel_viable(
+        q[..., :64], _zero_cache_layer(4, 256, 1, 64, q.dtype, True))
+    assert not _kernel_viable(  # two queries: prefill, not decode
+        jnp.zeros((4, 2, 12, 128), jnp.bfloat16),
+        _zero_cache_layer(4, 256, 1, 128, q.dtype, True))
+    # interpreted, any page of whole 8-row tiles streams; compiled, the
+    # int8 tile is 32 rows
+    assert _paged_kernel_possible(GCFG, True, 48)
+    monkeypatch.setattr(da, "_use_interpret", lambda: False)
+    assert not _paged_kernel_possible(GCFG, True, 48)
+    assert _paged_kernel_possible(GCFG, True, 64)
 
 
 def test_page_pool_metrics_exported():

@@ -117,6 +117,7 @@ def traced(tiny, tmp_path_factory):
                 "tick": sched.tick_count + 1, "queue": sched.pending,
                 "admitting": len(sched._admitting), "free": n_free,
                 "decoding": sched.S - n_free - len(sched._admitting),
+                "kernel": int(sched.use_kernel),
             }
             before = {r.id: len(r.tokens) for r in reqs}
             first = {r.id for r in reqs if not r.tokens}
@@ -285,6 +286,49 @@ def test_recorder_spans_are_cut_at_the_same_boundaries(tiny):
             assert a[2] + a[3] <= b[2]
 
 
+@pytest.mark.parametrize("slots,routed", [(4, 1), (2, 0)])
+def test_tick_span_says_which_route_the_tick_took(monkeypatch, slots,
+                                                  routed):
+    """``serving.tick`` carries ``kernel``: 1 where the scheduler
+    resolved the int8 Pallas kernel (head size 128, int8 pages, enough
+    slots a call), 0 where the tick gathers and takes the einsum."""
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    cfg = TransformerConfig(vocab=97, d_model=256, n_heads=2,
+                            n_kv_heads=1, n_layers=1, d_ff=64,
+                            attn_window=32)
+    seen = []
+
+    class Span:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+        def set_metadata(self, **args):
+            pass
+
+    def record(name, **args):
+        seen.append((name, args))
+        return Span()
+
+    monkeypatch.setattr(serving, "_annotate", record)
+    sched = serving.ServingScheduler(
+        init_params(cfg, seed=3), cfg, slots=slots, n_inner=2,
+        prompt_chunk=8, max_prompt=16, quantize_kv=True, page_tokens=8,
+    )
+    assert sched.use_kernel == bool(routed)
+    sched.submit(np.arange(1, 6), max_new=3)
+    sched.step()
+    ticks = [args for name, args in seen if name == "serving.tick"]
+    assert [t["kernel"] for t in ticks] == [routed]
+
+
 def test_annotations_cost_under_a_thousandth_of_a_tick():
     """With no profiler session open: the annotations of a heavy tick
     (sixteen slots each advancing a prefill chunk, four of them
@@ -295,7 +339,7 @@ def test_annotations_cost_under_a_thousandth_of_a_tick():
 
     def heavy_tick():
         with annotate("serving.tick", tick=7, queue=3, decoding=12,
-                      admitting=4, free=0):
+                      admitting=4, free=0, kernel=1):
             with annotate("serving.admit"):
                 for s in range(16):
                     with annotate("serving.prefill_chunk", req=s,
