@@ -44,6 +44,7 @@ class Run:
     traffic: dict
     seed: int
     seconds: float
+    run_seconds: float  # the manifest's: the length --seconds has in a check
     trace: bool
     devices: list
     spans: common.Spans
@@ -121,7 +122,8 @@ def execute(root: Path, workload: str, seed: int, seconds: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = Run(
         root=root, cell=cell, config=config, traffic=traffic,
-        seed=int(seed), seconds=float(seconds), trace=bool(trace),
+        seed=int(seed), seconds=float(seconds),
+        run_seconds=float(manifest["run_seconds"]), trace=bool(trace),
         devices=devices, spans=common.Spans(annotate=bool(trace)),
         t_start=t_start, trace_dir=trace_dir, peaks=peaks,
     )

@@ -41,7 +41,8 @@ TRAFFIC = {
                    "reference_steps": 3, "warm_steps": 1,
                    "trace_seconds": 1},
     "tiny_backlog": {
-        "round": 8, "rounds": 60, "warm_rounds": 2, "check_requests": 10,
+        "round": 8, "rounds": 156, "warm_rounds": 2, "window_rounds": 150,
+        "check_requests": 10,
         "trace_seconds": 1,
         "classes": [
             {"name": "short", "share": 0.75,

@@ -159,9 +159,9 @@ def test_train_control_in_lower_precision_fails_a_limit(tiny_root):
 
 @pytest.mark.parametrize("seed", [7, 2**31 + 8])
 def test_serve_control_in_lower_precision_fails_a_limit(tiny_root, seed):
-    # which requests finish in so short a window follows the host's
-    # speed, so the readings move from run to run: the sound ones have
-    # to pass both limits and the control to fail one, as on the chip
+    # the window is a stretch of the schedule (two rounds at this
+    # --seconds), so a seed's readings repeat: the sound ones have to
+    # pass both limits and the control to fail one, as on the chip
     row = control.readings(tiny_root, "tiny_serve", seed, 0.3, ["fp8"],
                            require_chip=False)
     limit = json.loads(

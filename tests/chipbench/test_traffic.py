@@ -33,6 +33,45 @@ def test_the_traffic_is_one_round_repeated(name):
     assert len(set(base)) > size // 2  # not one length, a spread
 
 
+SERVING_FILES = sorted(
+    p.stem for p in (REPO / "chipbench" / "traffic").glob("*.json")
+    if "round" in json.loads(p.read_text()))
+
+
+def test_the_mixes_named_here_are_the_serving_files():
+    assert SERVING_FILES == sorted(MIXES)
+
+
+@pytest.mark.parametrize("name", SERVING_FILES)
+def test_a_serving_file_states_its_window_in_rounds_and_outlasts_it(name):
+    t = mix(name)
+    for key in ("round", "rounds", "warm_rounds", "window_rounds"):
+        assert isinstance(t[key], int) and t[key] >= 1, key
+    # two rounds to spare: the slots are full when the window closes
+    assert t["warm_rounds"] + t["window_rounds"] + 2 <= t["rounds"]
+    assert t["check_requests"] <= t["round"] * t["window_rounds"]
+
+
+WINDOW_TOKENS = 20000
+
+
+@pytest.mark.parametrize("name", SERVING_FILES)
+def test_the_window_is_the_fewest_rounds_holding_20000_output_tokens(name):
+    """``window_rounds`` follows from the file alone, not from any
+    program's tick series: every serving cell's ``itl_p95_ms`` is the
+    percentile of about as many token gaps, a thousand beyond it."""
+    t = mix(name)
+    per_round = sum(olen for _, _, olen in traffic_gen.one_round(t))
+    assert t["window_rounds"] == -(-WINDOW_TOKENS // per_round)
+
+
+def test_the_tiny_mix_states_its_window_too():
+    import _tiny
+
+    t = _tiny.TRAFFIC["tiny_backlog"]
+    assert t["warm_rounds"] + t["window_rounds"] + 2 <= t["rounds"]
+
+
 def test_every_seed_offers_the_same_lengths_and_other_contents():
     t = mix("mixed_backlog")
     reqs = traffic_gen.ordered_requests(t)[: t["round"]]
