@@ -22,7 +22,7 @@ Run it anywhere:
     python examples/long_context_training.py --seq 2048 --d-model 512
 
 On the bench chip the same program trains 32 k-token sequences at
-~36 k tokens/s (docs/PERF.md "Long context on one chip") — lengths
+~36 k tokens/s (earlier installation, not repeated on this one) — lengths
 where materializing attention cannot even allocate its score matrices.
 """
 

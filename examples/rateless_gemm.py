@@ -59,8 +59,7 @@ def main():
     # incremental redundancy (fresh generation-1 draws rescuing an
     # undecodable window). The systematic default (round 3) peels this
     # trace within generation 0 — better in production, but then there
-    # is nothing to demonstrate; its overhead win is measured by
-    # bench.py's rateless_overhead rung.
+    # is nothing to demonstrate.
     rg = RatelessLTGemm(A, N, K, seed=SEED, delay_fn=permanent_straggler,
                         systematic=False)
     try:

@@ -28,8 +28,7 @@ of fault tolerance as a stated contract, not an aspiration):
 * :mod:`.report` — :class:`ChaosReport` with a sha256 digest witness
   like ``WorkloadReport``'s: two runs of the same seeded episode must
   agree on one short string, which is what lets the whole episode
-  suite gate tier-1 (tests/test_chaos.py) and the round-20 bench rung
-  (benchmarks/chaos_bench.py).
+  suite gate tier-1 (tests/test_chaos.py).
 
 Static enforcement rides along: graftcheck GC010 (shed-by-name — no
 code path drops a request without a string reason) and GC008 extended

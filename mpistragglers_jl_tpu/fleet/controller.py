@@ -417,8 +417,7 @@ class FleetController:
     def chip_seconds(self, t: float | None = None) -> float:
         """Chip-time consumed so far: one chip-second per provisioned
         replica per clock second — the quantity the elastic fleet
-        saves against static peak provisioning (docs/PERF.md round
-        18)."""
+        saves against static peak provisioning."""
         now = self._now() if t is None else float(t)
         total = sum(self._chip_seconds)
         for up_at in self._up_since:

@@ -99,62 +99,30 @@ __all__ = [
 
 _NEG = -1e30  # matches parallel/ring_attention.py
 
-# int8 decode-kernel routing (ops/decode_attention.py). Tri-state:
+# int8 decode-kernel routing (ops/decode_attention.py). Nothing sets
+# it: every program factory resolves the route once, from what it sees
+# (``_kernel_possible(cfg, quantize_kv) and _route_kernel(B)``; the
+# paged tick ``_paged_kernel_possible``), and hands the inner functions
+# a plain bool. Route and ``check_vma`` come from the same two
+# predicates.
 #
-#   None  (default) — AUTO: route the kernel only for BATCHED decode
-#         (local batch >= KERNEL_MIN_BATCH). Measured (docs/PERF.md):
-#         standalone the kernel beats the bf16 einsum 1.22x at its DMA
-#         floor, but inside the generation scan each pallas_call pays a
-#         launch/carry-aliasing boundary cost of ~0.02-0.04 ms/layer.
-#         That cost is PER CALL, so batching divides it by the rows the
-#         call serves: at B=1 it swamps the byte win (0.70-0.91x), at
-#         B >= 4 the amortized boundary rides under the streaming win.
-#   True  — force the kernel at every batch (tests, attribution).
-#   False — force the einsum dequant path.
-_USE_DECODE_KERNEL: bool | None = None
-
-# The auto threshold: the r5 boundary attribution (~0.03 ms/call) over
-# the kernel's standalone margin (~0.012 ms at the 16k flagship shape)
-# crosses under 4 rows per call; serving runs S=8.
+# The kernel routes only for BATCHED decode. Standalone it beat the
+# bf16 einsum 1.22x at its DMA floor, but inside the generation scan
+# each pallas_call pays a launch/carry-aliasing boundary cost of
+# ~0.02-0.04 ms/layer. That cost is PER CALL, so batching divides it by
+# the rows the call serves: at B=1 it swamped the byte win
+# (0.70-0.91x), at B >= 4 the amortized boundary rode under the
+# streaming win. The threshold (~0.03 ms/call of boundary over the
+# kernel's standalone margin of ~0.012 ms at a 16k cache crosses under
+# 4 rows per call) was measured on the earlier installation and has no
+# reading on this one: every cell serves 16 slots (ROADMAP D5).
 KERNEL_MIN_BATCH = 4
 
-_UNSET = object()  # "no snapshot" sentinel for _kernel_possible
 
-
-def use_decode_kernel(enabled: bool | None) -> None:
-    """Set int8 decode-attention routing: ``True`` forces the Pallas
-    kernel, ``False`` forces the einsum dequant path, ``None`` restores
-    the batched AUTO default (kernel iff local batch >=
-    ``KERNEL_MIN_BATCH`` — see the module note). The flag is part of
-    the dense runners' cache key, so toggling always takes effect on
-    the next dense ``generate_*`` call — already-compiled programs for
-    the other setting stay cached and are reused on a toggle back.
-    ``make_*`` closures snapshot the flag at *make* time (routing and
-    shard_map's vma setting must agree); rebuild them to change
-    routing."""
-    global _USE_DECODE_KERNEL
-    _USE_DECODE_KERNEL = None if enabled is None else bool(enabled)
-
-
-def _decode_kernel_enabled() -> bool | None:
-    return _USE_DECODE_KERNEL
-
-
-def _route_kernel(use_kernel, B: int) -> bool:
-    """Resolve the tri-state toggle at a concrete (trace-time) local
-    batch. ``_UNSET`` reads the live global; an explicit ``None`` is a
-    caller's make-time AUTO snapshot and resolves WITHOUT re-reading
-    the global — routing and the snapshot-derived ``check_vma`` setting
-    must come from ONE reading (make_generate / make_serving_scan), or
-    a toggle flipped between make and first trace would bake a program
-    whose routing disagrees with its vma mode. AUTO routes the kernel
-    only when the call serves enough rows (``KERNEL_MIN_BATCH``) to
-    amortize the scan/custom_call boundary cost."""
-    if use_kernel is _UNSET:
-        use_kernel = _USE_DECODE_KERNEL
-    if use_kernel is None:
-        return B >= KERNEL_MIN_BATCH
-    return bool(use_kernel)
+def _route_kernel(B: int) -> bool:
+    """Does a call serving ``B`` local rows amortize the kernel's
+    scan/custom_call boundary cost? (``KERNEL_MIN_BATCH``.)"""
+    return B >= KERNEL_MIN_BATCH
 
 
 def _kernel_viable(q, cache_l) -> bool:
@@ -184,25 +152,18 @@ def _kernel_viable(q, cache_l) -> bool:
     ) is not None
 
 
-def _kernel_possible(cfg, quantize_kv: bool, use_kernel=_UNSET) -> bool:
+def _kernel_possible(cfg, quantize_kv: bool) -> bool:
     """Could a program for ``cfg`` route T=1 cached attention through
     the int8 kernel? The shard-invariant part of ``_cached_attention``'s
-    guard (toggle not forced off, quantized cache, lane-aligned
-    head_dim); the remaining conditions (GQA ratio, block divisor,
-    batch threshold under auto) depend on per-shard shapes and stay
-    trace-time. Used both to keep the flag out of cache keys where it
-    is inert and to scope the vma carve-out. ``None`` (auto) counts as
-    possible — the batch is not known here."""
-    if use_kernel is _UNSET:
-        use_kernel = _USE_DECODE_KERNEL
-    return bool(
-        quantize_kv and use_kernel is not False
-        and cfg.head_dim % 128 == 0
-    )
+    guard (quantized cache, lane-aligned head_dim); the remaining
+    conditions (GQA ratio, block divisor, ``_route_kernel``'s batch
+    threshold) depend on per-shard shapes and stay trace-time. Also
+    scopes the vma carve-out (``_decode_kernel_interpreted``)."""
+    return bool(quantize_kv and cfg.head_dim % 128 == 0)
 
 
-def _paged_kernel_possible(cfg, quantize_kv: bool, page_tokens: int,
-                           use_kernel=_UNSET) -> bool:
+def _paged_kernel_possible(cfg, quantize_kv: bool,
+                           page_tokens: int) -> bool:
     """Could the PAGED serving tick route the int8 kernel's page-table
     mode? ``_kernel_possible``'s cfg-static guard plus the paged-only
     conditions the dense gather fallback does not have: query heads in
@@ -214,7 +175,7 @@ def _paged_kernel_possible(cfg, quantize_kv: bool, page_tokens: int,
     count and group tile. The serving scheduler resolves this ONCE at
     construction against its slot count; there is no trace-time
     re-gate on the paged path."""
-    if not _kernel_possible(cfg, quantize_kv, use_kernel):
+    if not _kernel_possible(cfg, quantize_kv):
         return False
     if cfg.n_heads % cfg.kv_heads:
         return False
@@ -230,22 +191,18 @@ def _paged_kernel_possible(cfg, quantize_kv: bool, page_tokens: int,
     ) is not None
 
 
-def _decode_kernel_interpreted(
-    cfg, quantize_kv: bool, use_kernel=_UNSET
-) -> bool:
+def _decode_kernel_interpreted(cfg, quantize_kv: bool) -> bool:
     """True iff a quantized decode program for ``cfg`` could trace the
     int8 Pallas kernel via the Pallas *interpreter* (non-TPU mesh) —
     shard_map's varying-axes checking must be off for it, the same
-    carve-out ``_flash_interpreted`` gives the flash kernels.
-    ``use_kernel`` is the make-time snapshot of the toggle; defaults to
-    the live flag. A slight over-approximation is safe only in one
-    direction: claiming "kernel" for a kernel-free program silently
-    loses vma checking, so the cfg-static guard conditions are all
-    applied here. Under the AUTO default the per-shard batch is not
-    known at make time, so auto counts as "kernel" — a small-batch
-    auto program on an interpreted mesh runs without vma checking (the
-    conservative direction is unreachable without the batch)."""
-    if not _kernel_possible(cfg, quantize_kv, use_kernel):
+    carve-out ``_flash_interpreted`` gives the flash kernels. A slight
+    over-approximation is safe only in one direction: claiming "kernel"
+    for a kernel-free program silently loses vma checking, so the
+    cfg-static guard conditions are all applied here. The per-shard
+    batch is not known at make time, so a small-batch program on an
+    interpreted mesh runs without vma checking (the conservative
+    direction is unreachable without the batch)."""
+    if not _kernel_possible(cfg, quantize_kv):
         return False
     from ..ops.flash_attention import _use_interpret
 
@@ -264,7 +221,7 @@ def _kv_quantize(x):
     dequantization folds into the attention einsums as a rank-1 scale
     on scores (K) and probabilities (V), so no dequantized copy is
     *required* at full size. Measured reality: in the einsum form XLA
-    materializes one anyway before the dot (docs/PERF.md), so there
+    materializes one anyway before the dot, so there
     int8 only halves the cache's bytes; through the Pallas kernel
     (ops/decode_attention.py), which dequantizes in VMEM, it is a
     latency feature too: the paged serving tick that routes it reads
@@ -390,7 +347,7 @@ def shard_cache(cache, cfg: TransformerConfig, mesh: Mesh):
 
 
 def _cached_attention(q, cache_l, qpos, scale, window=None,
-                      use_kernel=_UNSET):
+                      use_kernel: bool = False):
     """Grouped attention of the chunk's queries against the full cache.
 
     q: (B, T, H, D); the cache holds (B, Lmax, Hkv, D) at positions
@@ -401,17 +358,10 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     int8 caches at T == 1 take the Pallas decode kernel
     (ops/decode_attention.py): it dequantizes in VMEM, so HBM reads
     really are the int8 bytes — the einsum form's ``.astype`` is
-    materialized by XLA and gives half the bytes back (docs/PERF.md).
-    ``use_kernel`` pins the routing decision (callers that also pick a
-    vma setting from it must pass their snapshot — even an AUTO
-    ``None`` snapshot resolves without re-reading the global, see
-    ``_route_kernel``); the ``_UNSET`` default reads the global toggle,
-    whose AUTO default routes the kernel only for batched calls (the
-    per-call scan boundary cost amortizes over the batch rows).
+    materialized by XLA and gives half the bytes back.
+    ``use_kernel`` is the program's resolved route (the module note).
     """
-    if _route_kernel(use_kernel, q.shape[0]) and _kernel_viable(
-        q, cache_l
-    ):
+    if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 
         return quantized_decode_attention(
@@ -428,7 +378,8 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     return o.astype(q.dtype)
 
 
-def _ring_cached_attention(q, cache_l, pos, scale, use_kernel=_UNSET):
+def _ring_cached_attention(q, cache_l, pos, scale,
+                           use_kernel: bool = False):
     """Single-query attention against an O(W) ring cache.
 
     q: (B, 1, H, D); the cache holds (B, W, Hkv, D) where slot ``s``
@@ -445,9 +396,7 @@ def _ring_cached_attention(q, cache_l, pos, scale, use_kernel=_UNSET):
     identical ``kpos >= 0`` predicate in VMEM) — the window serving
     scan gets the dequantize-in-registers win at batch."""
     W = cache_l["k"].shape[1]
-    if _route_kernel(use_kernel, q.shape[0]) and _kernel_viable(
-        q, cache_l
-    ):
+    if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 
         return quantized_decode_attention(
@@ -463,7 +412,7 @@ def _ring_cached_attention(q, cache_l, pos, scale, use_kernel=_UNSET):
 
 def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
                        kv_slice, tp_psum, ring=False,
-                       decode_kernel=_UNSET):
+                       decode_kernel: bool = False):
     """Layer ``li`` of the incremental forward: write the chunk's K/V
     into the cache at ``qpos`` positions, attend, feed-forward. Returns
     (x, cache_l). The block itself is models/transformer.py's
@@ -499,17 +448,14 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
 
 def _incremental_forward(params, tokens, cache, offset, cfg,
                          *, prefill, kv_slice=None, tp_psum=False,
-                         ring=False, decode_kernel=_UNSET):
+                         ring=False, decode_kernel: bool = False):
     """Chunk forward at global ``offset``; returns (logits, cache).
 
     ``prefill=True`` (static) means offset is known to be 0 and chunk
     attention uses the configured kernel; otherwise attention runs
     against the cache — the ``max_len`` positional cache by default,
     the O(W) ring buffer when ``ring=True``. ``decode_kernel`` is the
-    caller's make-time snapshot of the int8-kernel toggle — a ``None``
-    snapshot pins AUTO without re-reading the global (``_route_kernel``)
-    — or ``_UNSET`` (the default) to read the live global at trace
-    time.
+    program's resolved int8-kernel route (the module note).
     """
     T = tokens.shape[1]
     if ring and (T != 1 or prefill):
@@ -627,7 +573,9 @@ def decode_step_dense(params, token, cache, pos, cfg: TransformerConfig):
     (scalar; caller keeps pos < the cache's max_len — out-of-range
     writes clamp, they do not error). Returns (logits (B, V), cache)."""
     logits, cache = _incremental_forward(
-        params, token[:, None], cache, pos, cfg, prefill=False
+        params, token[:, None], cache, pos, cfg, prefill=False,
+        decode_kernel=_kernel_possible(cfg, _is_quantized(cache[0]))
+        and _route_kernel(token.shape[0]),
     )
     return logits[:, 0], cache
 
@@ -738,7 +686,9 @@ def decode_step_ring_dense(params, token, cache, pos,
     window."""
     _check_ring_cfg(cfg)
     logits, cache = _incremental_forward(
-        params, token[:, None], cache, pos, cfg, prefill=False, ring=True
+        params, token[:, None], cache, pos, cfg, prefill=False, ring=True,
+        decode_kernel=_kernel_possible(cfg, _is_quantized(cache[0]))
+        and _route_kernel(token.shape[0]),
     )
     return logits[:, 0], cache
 
@@ -804,7 +754,7 @@ def _eos_clamp(nxt, tok, done, eos_id):
 def _dense_runner(cfg: TransformerConfig, B: int, Tp: int, n_new: int,
                   max_len: int, temperature: float, top_k: int | None,
                   eos_id: int | None, quantize_kv: bool,
-                  ring: bool = False, use_kernel: bool = False):
+                  ring: bool = False):
     """Shape-keyed jitted prefill+scan generation program (one compile
     per (cfg, shapes, sampling); the cache is built inside the jit, not
     baked in as a constant). ``ring=True`` is the O(W) sliding-window
@@ -822,10 +772,13 @@ def _dense_runner(cfg: TransformerConfig, B: int, Tp: int, n_new: int,
     chunks — so ``generate_ring_dense(quantize_kv=True)`` is the
     scheduler's stream as an IDENTITY, not a coincidence
     (tests/test_serving.py pins it). The masked (non-ring) generator
-    keeps the exact-prefill property docs/PERF.md documents; the
+    keeps its exact prefill (the prompt attends its raw K/V); the
     aligned prefill runs CHUNKED (``_aligned_quantized_prefill``), so
     its score memory is O(C * Tp) and long prompts stay servable."""
     W = _check_ring_cfg(cfg) if ring else None
+    # the ring kernel (ops/decode_attention ring=True) routes under the
+    # same gate as the masked path
+    use_kernel = _kernel_possible(cfg, quantize_kv) and _route_kernel(B)
 
     @jax.jit
     def run(params, prompt, key):
@@ -894,8 +847,6 @@ def generate_dense(params, prompt, n_new: int, cfg: TransformerConfig,
     return _dense_runner(
         cfg, B, Tp, n_new, max_len, float(temperature), top_k, eos_id,
         quantize_kv,
-        use_kernel=_kernel_possible(cfg, quantize_kv)
-        and _route_kernel(_UNSET, B),
     )(params, prompt, key)
 
 
@@ -927,10 +878,6 @@ def generate_ring_dense(params, prompt, n_new: int,
     return _dense_runner(
         cfg, B, Tp, n_new, 0, float(temperature), top_k, eos_id,
         quantize_kv, ring=True,
-        # the ring kernel (ops/decode_attention ring=True) routes under
-        # the same gate as the masked path
-        use_kernel=_kernel_possible(cfg, quantize_kv)
-        and _route_kernel(_UNSET, B),
     )(params, prompt, key)
 
 
@@ -990,16 +937,13 @@ def make_decode_step(cfg: TransformerConfig, mesh: Mesh, *,
     _check_decode_mesh(cfg, mesh)
     bax = decode_batch_axes(cfg)
     cspecs = cache_specs(cfg, quantize_kv=quantize_kv)
-    # snapshot the kernel toggle NOW: routing (traced at first call)
-    # and check_vma (fixed here) must come from the same reading, or a
-    # toggle between make and first call splits them
-    use_kernel = _decode_kernel_enabled()
 
     def local(params, token, cache, pos):
         logits, cache = _incremental_forward(
             params, token[:, None], cache, pos, cfg, prefill=False,
             kv_slice=make_kv_slice(cfg), tp_psum=True,
-            decode_kernel=use_kernel,
+            decode_kernel=_kernel_possible(cfg, quantize_kv)
+            and _route_kernel(token.shape[0]),
         )
         return logits[:, 0], cache
 
@@ -1010,10 +954,10 @@ def make_decode_step(cfg: TransformerConfig, mesh: Mesh, *,
             param_specs(cfg, mesh), P(bax), cspecs, P(),
         ),
         out_specs=(P(bax, None), cspecs),
-        # decode traces no FLASH kernel, but with quantize_kv + the
-        # kernel toggle it traces the int8 decode kernel — which needs
-        # the same interpreted-Pallas vma carve-out
-        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv, use_kernel),
+        # decode traces no FLASH kernel, but with quantize_kv it can
+        # trace the int8 decode kernel — which needs the same
+        # interpreted-Pallas vma carve-out
+        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv),
     )
     return jax.jit(f, donate_argnums=(2,))
 
@@ -1049,7 +993,6 @@ def make_extend(cfg: TransformerConfig, mesh: Mesh, *,
     _check_decode_mesh(cfg, mesh)
     bax = decode_batch_axes(cfg)
     cspecs = cache_specs(cfg, quantize_kv=quantize_kv)
-    use_kernel = _decode_kernel_enabled()  # same snapshot discipline
 
     def local(params, tokens, cache, offset):
         # the T-vs-cache half of the clamp guard is trace-time checkable
@@ -1059,7 +1002,8 @@ def make_extend(cfg: TransformerConfig, mesh: Mesh, *,
         logits, cache = _incremental_forward(
             params, tokens, cache, offset, cfg, prefill=False,
             kv_slice=make_kv_slice(cfg), tp_psum=True,
-            decode_kernel=use_kernel,
+            decode_kernel=_kernel_possible(cfg, quantize_kv)
+            and _route_kernel(tokens.shape[0]),
         )
         return logits, cache
 
@@ -1071,9 +1015,9 @@ def make_extend(cfg: TransformerConfig, mesh: Mesh, *,
         ),
         out_specs=(P(bax, None, None), cspecs),
         # extend is chunked (T > 1) on every real path, but a T == 1
-        # chunk with quantize_kv + the kernel toggle traces the int8
-        # decode kernel like a decode step — same vma carve-out
-        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv, use_kernel),
+        # chunk with quantize_kv can trace the int8 decode kernel like
+        # a decode step — same vma carve-out
+        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv),
     )
     return jax.jit(f)
 
@@ -1113,17 +1057,13 @@ def make_generate(cfg: TransformerConfig, mesh: Mesh, n_new: int,
     if n_new < 1:
         raise ValueError(f"n_new must be >= 1, got {n_new}")
     _check_sampling_params(temperature, top_k)
-    use_kernel = _decode_kernel_enabled()  # make-time snapshot
 
     def local(params, prompt, key):
         B, Tp = prompt.shape
-        # resolve the tri-state snapshot at THIS shard's batch (auto
-        # routes the kernel only when the call serves enough rows to
-        # amortize the scan boundary cost — see _route_kernel)
-        routed = (
-            _kernel_possible(cfg, quantize_kv, use_kernel)
-            and _route_kernel(use_kernel, B)
-        )
+        # resolved at THIS shard's batch (the kernel routes only when
+        # the call serves enough rows to amortize the scan boundary
+        # cost — see _route_kernel)
+        routed = _kernel_possible(cfg, quantize_kv) and _route_kernel(B)
         if ring:
             L = Tp  # transient positional prefill cache, gathered below
         else:
@@ -1208,7 +1148,7 @@ def make_generate(cfg: TransformerConfig, mesh: Mesh, n_new: int,
         # in the scan steps — either needs the vma carve-out
         check_vma=not (
             _flash_interpreted(cfg.attn_impl)
-            or _decode_kernel_interpreted(cfg, quantize_kv, use_kernel)
+            or _decode_kernel_interpreted(cfg, quantize_kv)
         ),
     )
     jitted = jax.jit(f)

@@ -4,7 +4,7 @@ A unified fleet makes compute-bound, bursty PREFILL and memory-
 bandwidth-bound, steady DECODE contend for the same chips: one
 long-prompt burst inflates every tick it shares a scheduler with, and
 decode p99 — the inter-token latency users feel — collapses (ROADMAP
-item 1; docs/PERF.md round 16 prices it). This module splits the
+item 1). This module splits the
 serving tier in two and moves a request's KV state between the tiers as
 a portable page-layout transfer, in the spirit of memory-efficient
 array redistribution (arXiv 2112.01075): plan the layout, move pages,
@@ -98,7 +98,7 @@ class MigrationTicket:
         self.pages = int(state["n_pages"])
         # bytes actually moved: the request's page set across every
         # layer and leaf (W rows are gathered, but only pages rows are
-        # live content — the byte model prices pages, docs/PERF.md)
+        # live content — the byte model prices pages)
         per_page = 0
         for cl in state["ring"]:
             for a in cl.values():

@@ -22,8 +22,8 @@ Design (TPU-first, GShard/Switch lineage):
   Mesh-TF one-hot einsum formulation (:func:`switch_route`) is kept as
   the oracle the gather form is tested equal against: its (T, E*C, D)
   dispatch matmuls are quadratic in token count and cost more than the
-  expert FFNs themselves at flagship token counts (docs/PERF.md
-  round 4).
+  expert FFNs themselves at flagship token counts (round 4: earlier
+  installation, not repeated on this one).
 * **Expert parallelism = all_to_all over ``"ep"``.** Experts are
   sharded over the ``ep`` mesh axis and the *batch* is sharded over
   ``(dp, ep)`` — every ep member holds distinct tokens, so the tiled
@@ -132,8 +132,8 @@ def _route(x2d: jax.Array, wg: jax.Array):
     Returns ``(expert (T,), slot (T,), gate (T,) f32, aux)``."""
     # f32 ACCUMULATION without materializing an f32 copy of the whole
     # (T, D) activation (the astype form wrote+read 2x64 MB per layer
-    # for a 4-column matmul — the single largest routing cost measured
-    # in benchmarks/moe_route_attrib.py). The router WEIGHT is not
+    # for a 4-column matmul — the single largest routing cost measured;
+    # earlier installation, not repeated on this one). The router WEIGHT is not
     # downcast to the activation dtype: wg stays f32 (it is only
     # (D, E)) and the mixed-precision dot accumulates in f32 via
     # preferred_element_type — bf16 rounding touches the activations
@@ -216,7 +216,8 @@ def _route_tables(x2d: jax.Array, wg: jax.Array, capacity: int):
 # directions of both ops are GATHERS. Left to autodiff, the transpose
 # of each gather is a scatter-add, and TPU scatter-adds (plus the
 # sentinel row's duplicate indices) measured as the dominant routing
-# cost in the r4 rung (benchmarks/moe_route_attrib.py); the custom
+# cost in the r4 rung (earlier installation, not repeated on this
+# one); the custom
 # VJPs below express each backward as the inverse gather instead,
 # eliminating every (T-or-EC, D)-scale scatter from the layer.
 

@@ -5,7 +5,7 @@ no serving); this is north-star serving scope (VERDICT r4 next-#1),
 converting the round-4 serving inventory (ring cache, GQA decode, int8
 KV, speculative/hedged) from single-request features into aggregate
 throughput. At B=1 a decode step is weight-read-bound — the HBM traffic
-is the parameters, amortized over one token (docs/PERF.md). Batching S
+is the parameters, amortized over one token (PERF.md section 5). Batching S
 concurrent requests into one step amortizes the same weight reads over
 S tokens; until the KV-cache reads dominate, aggregate tokens/s scales
 near-linearly with S. That economics is the whole point of this module.
@@ -101,9 +101,7 @@ from .decode import (
     _cache_scores,
     _check_ring_cfg,
     _check_sampling_params,
-    _decode_kernel_enabled,
     _decode_kernel_interpreted,
-    _UNSET,
     _eos_clamp,
     _incremental_forward,
     _is_quantized,
@@ -338,7 +336,7 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False):
     serves all S slots, so the scan/custom_call boundary cost that
     sinks the kernel at B=1 is paid once per S tokens — the batched
     regime is where int8 finally converts its byte win into time
-    (docs/PERF.md). Default False: this function is also the dense
+    (PERF.md section 6, PR 27). Default False: this function is also the dense
     ORACLE step (``serving_decode_step_dense``), which stays einsum so
     kernel-vs-einsum parity is testable against it."""
     W = cache_l["k"].shape[1]
@@ -577,8 +575,7 @@ def _serving_scan_dense(cfg: TransformerConfig, n_inner: int,
     """Jitted dense tick: (params, tok, pos, done, caches, keys) ->
     (tok, pos, done, caches, toks). Caches donated — the tick updates
     the arena in place in HBM. ``use_kernel`` is the scheduler's
-    RESOLVED int8-kernel routing (part of the cache key, so toggling
-    the global routes on the next scheduler construction)."""
+    RESOLVED int8-kernel routing."""
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def serving_tick_dense(params, tok, pos, done, caches, keys):
@@ -607,13 +604,14 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
     the unchanged dense ring scan runs on the views (the paged einsum
     tick IS the slot-ring tick on a gathered arena — parity by
     construction), and one scatter writes the views back through the
-    table. A per-step gather measured 0.66x the slot tick on the bench
-    box (XLA re-materializes the view every step inside the scan);
+    table. A per-step gather measured 0.66x the slot tick (earlier
+    installation, not repeated on this one: XLA re-materializes the
+    view every step inside the scan);
     hoisted, the gather amortizes over ``n_inner`` steps and the tick
     lands within the <= 5% budget. The trade is a transient
     ``(S, W)``-row view per layer during the tick — active-slot bytes,
-    not pool bytes; the kernel route has no such transient (docs/
-    PERF.md byte model)."""
+    not pool bytes; the kernel route has no such transient (PERF.md
+    section 4: 4.83 GB reserved against 0.69)."""
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def serving_tick_paged(params, tok, pos, done, caches, keys, pt):
@@ -806,17 +804,14 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
         sspec = P("dp", None, "tp")
         layer_spec["k_s"], layer_spec["v_s"] = sspec, sspec
     cspecs = [dict(layer_spec) for _ in range(cfg.n_layers)]
-    # make-time snapshot of the int8-kernel toggle (decode.py's
-    # discipline: routing and check_vma must come from one reading)
-    use_kernel = _decode_kernel_enabled()
 
     def local(params, tok, pos, done, caches, keys):
         # resolve at this shard's slot count: one ring-kernel call per
-        # layer serves every local slot, so the auto gate compares the
+        # layer serves every local slot, so the gate compares the
         # per-call boundary cost against S_local amortizing rows
         routed = (
-            _kernel_possible(cfg, quantize_kv, use_kernel)
-            and _route_kernel(use_kernel, tok.shape[0])
+            _kernel_possible(cfg, quantize_kv)
+            and _route_kernel(tok.shape[0])
         )
         return _scan_body(
             params, tok, pos, done, caches, cfg, eos_id, n_inner,
@@ -832,12 +827,11 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
                   cspecs, P("dp")),
         out_specs=(P("dp"), P("dp"), P("dp"), cspecs,
                    P("dp", None)),
-        # quantize_kv + the kernel toggle routes the int8 ring kernel
-        # inside the tick — interpreted Pallas needs the same vma
-        # carve-out as decode.py's make_decode_step; einsum-only
-        # programs keep varying-axes checking on
-        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv,
-                                                 use_kernel),
+        # quantize_kv can route the int8 ring kernel inside the tick —
+        # interpreted Pallas needs the same vma carve-out as
+        # decode.py's make_decode_step; einsum-only programs keep
+        # varying-axes checking on
+        check_vma=not _decode_kernel_interpreted(cfg, quantize_kv),
     )
 
     def serving_tick_sharded(params, tok, pos, done, caches, keys):
@@ -1018,7 +1012,7 @@ class _ServingObs:
             "serving_prefill_chunks_total",
             help="admission prefill chunks advanced",
         )
-        # the AUTO gate's resolved decision for THIS scheduler (fixed
+        # the route resolved for THIS scheduler (fixed
         # at construction against its slot count — see use_kernel);
         # incremented once per decode tick, so the series records when
         # the kernel route actually fired, not just that it could
@@ -1569,7 +1563,7 @@ class ServingScheduler:
                 self.quantize_kv,
             )
         # int8 Pallas kernel routing, resolved at construction against
-        # THIS scheduler's slot count (decode.py's auto gate: the tick
+        # THIS scheduler's slot count (decode.py's _route_kernel: the tick
         # batches all S slots into one kernel call per layer, which is
         # what amortizes the scan boundary cost the B=1 path cannot).
         # The paged tick adds the page-geometry conditions
@@ -1578,7 +1572,7 @@ class ServingScheduler:
         if self.paged:
             self.use_kernel = (
                 _paged_kernel_possible(cfg, self.quantize_kv, self.P)
-                and _route_kernel(_UNSET, self.S)
+                and _route_kernel(self.S)
             )
             self._scan = _serving_scan_paged(
                 cfg, self.n_inner, eos_id, self.temperature, top_k,
@@ -1592,7 +1586,7 @@ class ServingScheduler:
         else:
             self.use_kernel = (
                 _kernel_possible(cfg, self.quantize_kv)
-                and _route_kernel(_UNSET, self.S)
+                and _route_kernel(self.S)
             )
             self._scan = _serving_scan_dense(
                 cfg, self.n_inner, eos_id, self.temperature, top_k,

@@ -669,8 +669,8 @@ def nll_loss(logits, targets, axes):
     ``log_softmax`` + gather: same math, same gradient (softmax minus
     one-hot), but the full (B, L, V) normalized array is never
     materialized in f32 — only the reductions are. On the chip that is
-    10.5 ms of a 116 ms flagship step (measured round 4, docs/PERF.md
-    phase table: the head+loss phase drops 22.5 -> 12.0 ms)."""
+    10.5 ms of a 116 ms flagship step (round 4: the head+loss phase
+    drops 22.5 -> 12.0 ms; earlier installation, not repeated on this one)."""
     logits = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]

@@ -1,15 +1,17 @@
 """Pallas TPU decode attention over the int8 KV cache.
 
-Why this kernel exists (measured, docs/PERF.md "int8 KV cache"): the
-einsum-form dequantization — int8 cache ``.astype(bf16)`` feeding the
-attention dots — is *expressed* as a fused rank-1 correction, but XLA
+Why this kernel exists (measured; earlier installation, not repeated
+on this one): the einsum-form dequantization — int8 cache
+``.astype(bf16)`` feeding the attention dots — is *expressed* as a
+fused rank-1 correction, but XLA
 materializes the converted operand in HBM, so the int8 cache read half
 the bytes and then paid them back with interest (0.70x vs the bf16
 cache). The fix is the standard Pallas move: stream the int8 blocks
 through VMEM and dequantize in registers, so HBM traffic really is the
 int8 bytes plus scales.
 
-Layout lesson (both dead ends measured on the chip, docs/PERF.md):
+Layout lesson (both dead ends measured on the chip; earlier
+installation, not repeated on this one):
 a head-major kernel layout needs a transpose of the whole cache —
 XLA materializes it per layer per step and the win drowns (0.82x);
 slicing one head's D-chunk per grid row from the native layout makes

@@ -47,7 +47,7 @@ def _grid_params():
     forward/dq, q blocks in dk/dv) carries loop state through scratch
     and must run in order. Without this annotation Mosaic assumes every
     grid axis is sequential — measured 20% slower on the round-3 chip
-    (docs/PERF.md)."""
+    (earlier installation, not repeated on this one)."""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
@@ -357,7 +357,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     (reduced outside). The split kernels recompute s and dp twice —
     7 block-dots + 2 exps per (i, j); this shares them: 5 dots + 1 exp,
     a ~25% executed-FLOP cut exactly where the short-sequence
-    attention tax lives (docs/PERF.md round-4 phase table)."""
+    attention tax lives."""
     i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -471,7 +471,8 @@ def _use_fused_bwd() -> bool:
     for the 8-layer attention phase. The kernel is VPU/HBM-co-bound at
     these shapes, so cutting MXU dots does not pay while the extra
     ~nq x f32 dk/dv traffic does. Kept selectable (bwd_impl="fused")
-    so the measurement stays reproducible; docs/PERF.md round 4."""
+    so the measurement stays reproducible (round 4: earlier
+    installation, not repeated on this one)."""
     return False
 
 
@@ -604,7 +605,8 @@ def flash_attention(
     lengths; ``interpret`` defaults to compiled on TPU and interpret
     mode elsewhere.
 
-    Block defaults are tuned on the real chip (round 3, docs/PERF.md):
+    Block defaults were tuned on the chip (round 3: earlier
+    installation, not repeated on this one):
     1024x1024 is ~5x the forward throughput of 128x128 (small blocks
     drown in grid overhead — 16k grid steps at L=2048) and the largest
     size whose backward kernels stay inside the 16 MiB VMEM scoped
@@ -619,7 +621,7 @@ def flash_attention(
     resolves to split: the fused variant measured SLOWER on the chip
     at the flagship shape (27.5 vs 16.6 ms for the 8-layer phase) —
     the partial-buffer HBM traffic outweighs the dot saving on this
-    VPU/HBM-co-bound kernel (see ``_use_fused_bwd``; docs/PERF.md).
+    VPU/HBM-co-bound kernel (see ``_use_fused_bwd``).
     """
     B, Lq, H, D = q.shape
     Lk = k.shape[1]

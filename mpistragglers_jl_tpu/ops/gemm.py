@@ -110,7 +110,7 @@ class DistributedGemm:
         # accuracy. Benchmarks may pass precision=None for peak MXU rate.
         #
         # ``batch=True``: coalesced dispatch — each device's workers run
-        # as ONE fused stacked matmul per epoch (see CodedGemm/PERF.md);
+        # as ONE fused stacked matmul per epoch (see CodedGemm);
         # requires homogeneous row_splits, incompatible with delay_fn.
         self.precision = precision
         m = A.shape[0]

@@ -21,7 +21,7 @@ fixes both at once:
 
 Decode cost drops from one ``O(((H-1) n_inner)^3)`` solve + its
 ``O(k^2)``-per-row apply to ``L`` small ``O(k_inner^3)`` solves plus an
-O(n) outer pass (docs/PERF.md round-14 worked example), and the epoch
+O(n) outer pass, and the epoch
 returns the moment ``L`` groups each clear their *inner* floor — a
 straggling or dead host is simply never waited on.
 
@@ -61,7 +61,8 @@ def _decode_groups(G_S, shards):
     (the first cut) paid per-call dispatch overhead L times over —
     measured 0.84x the flat decode at the bench shape; batched, the
     decode does its ``L * O(k_inner^3)`` work in a single dispatch and
-    the >= 2x decode-cost win is real (docs/PERF.md round-14).
+    the >= 2x decode-cost win is real (earlier installation, not
+    repeated on this one).
 
     ``G_S``: (g, k, k) per-group generator submatrices; ``shards``:
     (g, k, rows, cols) per-group fresh shard stacks."""
@@ -376,7 +377,7 @@ class HierarchicalCodedGemm:
             # host-side gather, ONE transfer: stacking device shards
             # with nested jnp.stack costs one dispatch per shard
             # (measured 3.6 ms vs 0.45 ms for the numpy gather at the
-            # bench shape — docs/PERF.md round-14)
+            # bench shape; earlier installation, not repeated on this one)
             shards = jnp.asarray(np.stack([
                 np.stack([
                     np.asarray(pool.results[int(self.group_indices[g][j])])

@@ -61,7 +61,8 @@ def patch_distribution(k: int) -> np.ndarray:
     shards exist to PATCH the few missing ones — the optimal patch has
     moderate degree (cover a missing block with high probability
     without binding several missing blocks together and stalling the
-    peel). Measured over the straggler ensembles in docs/PERF.md:
+    peel). Measured over seeded straggler ensembles (earlier
+    installation, not repeated on this one):
     beats the robust-soliton tail at every k/straggler count tried
     (e.g. k=16, 2 stragglers: 1.13x vs 1.29x shards consumed) and
     degrades gracefully when half the workers are lost."""

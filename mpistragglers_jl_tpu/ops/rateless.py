@@ -97,7 +97,8 @@ class RatelessLTGemm:
         first k shards ARE the source blocks, so a straggler-free epoch
         peels from k arrivals and a straggler costs only the draws
         until its missing block is covered — measured overhead drops
-        from ~1.6x to ~1.25x of k at (n=8, k=8) (docs/PERF.md round 3).
+        from ~1.6x to ~1.25x of k at (n=8, k=8) (earlier
+        installation, not repeated on this one).
         Set False for the classic all-soliton stream."""
         if dtype is not None:
             A = np.asarray(A, dtype=dtype)

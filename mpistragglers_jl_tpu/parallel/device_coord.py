@@ -3,7 +3,7 @@ host off the epoch hot path.
 
 Every epoch of the host ``asyncmap`` loop (pool.py) re-enters the
 interpreter: dispatch bookkeeping, arrival stamping, the decode
-trigger — 2 + 3W host touches per epoch (docs/PERF.md round 17). With
+trigger — 2 + 3W host touches per epoch. With
 transport zero-copy (round 12) and the decode batched (round 14) that
 interpreter round-trip is the dominant per-epoch cost left — ROADMAP
 item 4, the Amdahl item. This module inverts the control flow of the

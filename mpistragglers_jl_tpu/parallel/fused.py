@@ -531,8 +531,9 @@ def select_coded_gemm(
     On a multi-device mesh the fused path's structural win (no k-shard
     gather onto one device, decode riding ICI) is decisive; on ONE
     device the two paths differ only by dispatch economics that sit
-    inside the session's noise band (measured 0.95-1.10x across rounds
-    — docs/PERF.md). So instead of hardcoding a loser, probe both on
+    inside the session's noise band (measured 0.95-1.10x across rounds;
+    earlier installation, not repeated on this one). So instead of
+    hardcoding a loser, probe both on
     THIS machine: alternating timed chains of ``probe_epochs``
     epochs (the fused-bench discipline: alternation, so slow drift
     between chains lands on both candidates alike), keep the winner,

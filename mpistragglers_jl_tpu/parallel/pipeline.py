@@ -172,7 +172,7 @@ def measure_bubble(mesh: Mesh, n_microbatch: int, schedule: str = "1f1b",
     formulas count ideal schedule ticks, while an implementation may
     spend extra ticks on bookkeeping (the circular engine's final
     emission hop costs one tick beyond the analytic ``v*M + p - 1``) —
-    exactly the gap this function exists to expose (docs/PERF.md).
+    exactly the gap this function exists to expose.
     """
     import numpy as np
 

@@ -29,8 +29,7 @@ entitlement; :class:`~..sim.workload.SimReplica` (``qos=``) runs the
 identical DRR on virtual time so the isolation claim — a tenant
 flooding 10x its budget moves compliant tenants' p99 TTFT by less
 than a pinned epsilon while utilization stays above a floor — is
-measured and replayed bit-identically (tests/test_qos.py,
-benchmarks/qos_bench.py).
+measured and replayed bit-identically (tests/test_qos.py).
 
 Wall-clock purity: graftcheck GC008 covers ``qos/`` like ``sim/`` and
 ``fleet/`` — nothing here reads an OS clock; buckets refill from the

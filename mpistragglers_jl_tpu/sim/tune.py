@@ -1345,8 +1345,7 @@ def sweep_harvest_k(
       pays ``host_harvest_s`` per harvest (stage + harvest, 2/K per
       epoch amortized). ``utility`` per K is effective epochs/second:
       ``epochs / (virtual_s + n_harvests * host_harvest_s)``. Pass the
-      bench-measured costs for this box
-      (benchmarks/device_coord_bench.py measures both).
+      costs measured on this box.
     * **staleness** — a result decoded at the window's first epoch is
       only visible to the host at the window's end; ``staleness_s``
       per K is the maximum such age (≈ the longest window's virtual

@@ -1,5 +1,5 @@
 """Where XLA's persistent compilation cache lives: one rule for the
-test suite, ``bench.py``, ``chip_smoke.py`` and the examples.
+test suite, ``chip_smoke.py`` and the examples.
 
 The directory is part of what a cached executable is found by, so it
 must not move between runs. Where ``JAX_COMPILATION_CACHE_DIR`` is set

@@ -300,10 +300,11 @@ def test_kernel_block_predication_excludes_future():
 
 @pytest.mark.slow
 def test_kernel_rides_generation_at_head_dim_128():
-    """End-to-end: with the kernel toggled on, a D=128 config's
-    quantized greedy generation routes decode steps through it and
-    matches the exact-cache stream, dense path."""
-    from mpistragglers_jl_tpu.models.decode import use_decode_kernel
+    """End-to-end: at a batch the rule routes (KERNEL_MIN_BATCH rows),
+    a D=128 config's quantized greedy generation runs its decode steps
+    through the kernel and matches the exact-cache stream, dense
+    path."""
+    from mpistragglers_jl_tpu.models.decode import KERNEL_MIN_BATCH
 
     cfg = TransformerConfig(
         vocab=97, d_model=256, n_heads=2, n_kv_heads=1, n_layers=2,
@@ -312,14 +313,18 @@ def test_kernel_rides_generation_at_head_dim_128():
     assert cfg.head_dim == 128
     params = init_params(cfg, seed=7)
     rng = np.random.default_rng(8)
-    prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 6)), jnp.int32)
+    prompt = jnp.asarray(
+        rng.integers(0, cfg.vocab, (KERNEL_MIN_BATCH, 6)), jnp.int32
+    )
+
+    def quantized(p):
+        return generate_dense(params, p, 7, cfg, quantize_kv=True)
+
+    assert "pallas_call" in str(jax.make_jaxpr(quantized)(prompt))
     want = generate_dense(params, prompt, 7, cfg)
-    use_decode_kernel(True)
-    try:
-        got = generate_dense(params, prompt, 7, cfg, quantize_kv=True)
-    finally:
-        use_decode_kernel(None)  # restore the batched-AUTO default
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(quantized(prompt)), np.asarray(want)
+    )
 
 
 def test_kernel_validation():

@@ -19,6 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mpistragglers_jl_tpu.models.decode import (
     _aligned_quantized_prefill,
+    _incremental_forward,
     _kv_quantize,
     decode_step_dense,
     generate_dense,
@@ -29,6 +30,7 @@ from mpistragglers_jl_tpu.models.decode import (
     make_prefill,
     make_ring_generate,
     prefill_dense,
+    ring_from_cache,
     shard_cache,
 )
 from mpistragglers_jl_tpu.models.transformer import (
@@ -225,73 +227,112 @@ D128 = TransformerConfig(
 )  # head_dim 128: the decode kernel's lane gate
 
 
-@pytest.mark.parametrize("window", [None, 128])
-def test_batched_auto_kernel_in_scan_matches_einsum(window):
-    """B=4 >= KERNEL_MIN_BATCH: the AUTO default routes the in-scan
-    decode steps through the Pallas int8 kernel (interpreted on the CI
-    mesh) — token streams equal the einsum dequant path exactly, full
-    and sliding-window masks both."""
-    from mpistragglers_jl_tpu.models.decode import (
-        KERNEL_MIN_BATCH,
-        use_decode_kernel,
+def _traces_kernel(f, *args) -> bool:
+    """Did tracing ``f(*args)`` reach the Pallas decode kernel?"""
+    return "pallas_call" in str(jax.make_jaxpr(f)(*args))
+
+
+def _greedy(params, prompt, n_new, cfg, *, decode_kernel, ring=False):
+    """The dense runners' quantized greedy program with the decode
+    route given by hand, as the resolved bool the inner functions
+    take: the only way to the route the rule would not pick."""
+    B, Tp = prompt.shape
+    c = init_cache(cfg, B, Tp if ring else Tp + n_new, quantize_kv=True)
+    if ring:
+        logits, c = _aligned_quantized_prefill(
+            params, prompt, c, cfg, decode_kernel=decode_kernel
+        )
+        c = ring_from_cache(c, Tp, cfg)
+    else:
+        logits, c = prefill_dense(params, prompt, c, cfg)
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(prompt.dtype)
+
+    def step(carry, pos):
+        tok, c = carry
+        lg, c = _incremental_forward(
+            params, tok[:, None], c, pos, cfg, prefill=False, ring=ring,
+            decode_kernel=decode_kernel,
+        )
+        return (jnp.argmax(lg[:, 0], axis=-1).astype(tok.dtype), c), tok
+
+    (tok, _), toks = jax.lax.scan(
+        step, (tok, c), Tp + jnp.arange(n_new - 1)
     )
+    return jnp.concatenate([toks, tok[None]], axis=0).swapaxes(0, 1)
+
+
+@pytest.mark.parametrize("window", [None, 128])
+def test_batched_kernel_in_scan_matches_einsum(window):
+    """B=4 >= KERNEL_MIN_BATCH: the in-scan decode steps route through
+    the Pallas int8 kernel (interpreted on the CI mesh) — token streams
+    equal the einsum dequant path exactly, full and sliding-window
+    masks both."""
+    from mpistragglers_jl_tpu.models.decode import KERNEL_MIN_BATCH
 
     cfg = dataclasses.replace(D128, attn_window=window)
     params = init_params(cfg, seed=9)
     B = KERNEL_MIN_BATCH
     rng = np.random.default_rng(10)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (B, 6)), jnp.int32)
-    use_decode_kernel(False)
-    try:
-        want = generate_dense(params, prompt, 7, cfg, quantize_kv=True)
-    finally:
-        use_decode_kernel(None)  # the AUTO default routes at B >= 4
-    got = generate_dense(params, prompt, 7, cfg, quantize_kv=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def routed(p):
+        return generate_dense(params, p, 7, cfg, quantize_kv=True)
+
+    def einsum(p):
+        return _greedy(params, p, 7, cfg, decode_kernel=False)
+
+    assert _traces_kernel(routed, prompt)
+    assert not _traces_kernel(einsum, prompt)
+    np.testing.assert_array_equal(
+        np.asarray(routed(prompt)), np.asarray(jax.jit(einsum)(prompt))
+    )
 
 
 def test_ring_kernel_in_scan_matches_einsum():
-    """The O(W) ring generator at batch: AUTO routes the kernel's
-    ring mode inside the decode scan; streams equal the einsum path."""
-    from mpistragglers_jl_tpu.models.decode import use_decode_kernel
-
+    """The O(W) ring generator at batch: the kernel's ring mode routes
+    inside the decode scan; streams equal the einsum path."""
     cfg = dataclasses.replace(D128, attn_window=128)
     params = init_params(cfg, seed=11)
     rng = np.random.default_rng(12)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (4, 6)), jnp.int32)
-    use_decode_kernel(False)
-    try:
-        want = generate_ring_dense(params, prompt, 8, cfg,
-                                   quantize_kv=True)
-    finally:
-        use_decode_kernel(None)
-    got = generate_ring_dense(params, prompt, 8, cfg, quantize_kv=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def routed(p):
+        return generate_ring_dense(params, p, 8, cfg, quantize_kv=True)
+
+    def einsum(p):
+        return _greedy(params, p, 8, cfg, decode_kernel=False, ring=True)
+
+    assert _traces_kernel(routed, prompt)
+    assert not _traces_kernel(einsum, prompt)
+    np.testing.assert_array_equal(
+        np.asarray(routed(prompt)), np.asarray(jax.jit(einsum)(prompt))
+    )
 
 
-def test_auto_skips_kernel_below_min_batch():
-    """B=1 under AUTO stays on the einsum path (the per-call scan
-    boundary cost isn't amortized): the program must still match the
-    forced-einsum stream AND the forced-kernel stream — routing is a
-    perf decision, never a numerics one."""
-    from mpistragglers_jl_tpu.models.decode import use_decode_kernel
-
+def test_kernel_not_routed_below_min_batch():
+    """B=1 stays on the einsum path (the per-call scan boundary cost
+    isn't amortized): the program must still match the einsum stream
+    AND the kernel stream — routing is a perf decision, never a
+    numerics one."""
     params = init_params(D128, seed=13)
     rng = np.random.default_rng(14)
     prompt = jnp.asarray(rng.integers(0, D128.vocab, (1, 5)), jnp.int32)
-    auto = generate_dense(params, prompt, 6, D128, quantize_kv=True)
-    use_decode_kernel(False)
-    try:
-        ein = generate_dense(params, prompt, 6, D128, quantize_kv=True)
-    finally:
-        use_decode_kernel(None)
-    use_decode_kernel(True)
-    try:
-        kern = generate_dense(params, prompt, 6, D128, quantize_kv=True)
-    finally:
-        use_decode_kernel(None)
-    np.testing.assert_array_equal(np.asarray(auto), np.asarray(ein))
-    np.testing.assert_array_equal(np.asarray(auto), np.asarray(kern))
+
+    def routed(p):
+        return generate_dense(params, p, 6, D128, quantize_kv=True)
+
+    def by_hand(decode_kernel):
+        return lambda p: _greedy(params, p, 6, D128,
+                                 decode_kernel=decode_kernel)
+
+    assert not _traces_kernel(routed, prompt)
+    assert not _traces_kernel(by_hand(False), prompt)
+    assert _traces_kernel(by_hand(True), prompt)
+    auto = np.asarray(routed(prompt))
+    np.testing.assert_array_equal(
+        auto, np.asarray(jax.jit(by_hand(False))(prompt)))
+    np.testing.assert_array_equal(
+        auto, np.asarray(jax.jit(by_hand(True))(prompt)))
 
 
 def test_shard_cache_places_scale_leaves():

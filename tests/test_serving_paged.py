@@ -17,10 +17,10 @@ import jax
 import jax.numpy as jnp
 
 from mpistragglers_jl_tpu.models.decode import (
+    KERNEL_MIN_BATCH,
     _kernel_viable,
     _paged_kernel_possible,
     generate_ring_dense,
-    use_decode_kernel,
 )
 from mpistragglers_jl_tpu.models.serving import ServingScheduler
 from mpistragglers_jl_tpu.models.transformer import (
@@ -251,9 +251,9 @@ def test_paged_page_sizes_and_kernel_tick_match_oracle(
 
 
 def _group12_schedule(sched):
-    """Six requests through four slots: two share a page-aligned
-    8-token prefix and both wrap the 32-row window (COW of the shared
-    page), slots retire and are reused."""
+    """Six requests through four slots (or three): two share a
+    page-aligned 8-token prefix and both wrap the 32-row window (COW of
+    the shared page), slots retire and are reused."""
     sys_prompt = _prompt(8, GCFG.vocab)
     prompts = [
         np.concatenate([sys_prompt, _prompt(3, GCFG.vocab)]),
@@ -271,11 +271,12 @@ def test_group_of_12_routes_the_kernel_and_matches_gather_and_oracle():
     """12 query heads on one K/V head at head size 128: the paged tick
     routes the int8 kernel (no group is refused any more), and over a
     schedule with admission, retirement and COW its streams equal the
-    gather route's (the kernel forced off) and the dense oracle's."""
+    gather route's (a scheduler of three slots, under
+    ``KERNEL_MIN_BATCH``) and the dense oracle's."""
     assert GCFG.head_dim == 128
 
-    def make():
-        return ServingScheduler(GPARAMS, GCFG, slots=4, n_inner=4,
+    def make(slots=KERNEL_MIN_BATCH):
+        return ServingScheduler(GPARAMS, GCFG, slots=slots, n_inner=4,
                                 prompt_chunk=16, max_prompt=32,
                                 quantize_kv=True, page_tokens=8)
 
@@ -285,11 +286,8 @@ def test_group_of_12_routes_the_kernel_and_matches_gather_and_oracle():
     by_kernel = _group12_schedule(kern)
     assert kern.pool.share_hits > 0 and kern.pool.cow_copies > 0
     _drained(kern)
-    use_decode_kernel(False)
-    try:
-        gather = make()
-    finally:
-        use_decode_kernel(None)
+    # one slot fewer than the kernel routes for: the int8 gather route
+    gather = make(slots=KERNEL_MIN_BATCH - 1)
     assert not gather.use_kernel
     RNG.bit_generator.state = state  # the same prompts again
     by_gather = _group12_schedule(gather)

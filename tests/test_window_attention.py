@@ -318,48 +318,3 @@ def test_public_ring_from_cache_matches_private_and_guards():
     # prefilling a too-short arena refuses at trace time, too
     with pytest.raises(ValueError, match="does not fit the cache"):
         prefill_dense(params, toks, short, cfg)
-
-
-def test_use_decode_kernel_toggle_takes_effect_after_compile():
-    """ADVICE r4: the kernel toggle must not be silently ignored for
-    shapes whose dense runner already compiled. The flag is part of the
-    runner cache key, so a toggle selects a different (new) program
-    while every already-compiled program for the other setting stays
-    cached for reuse."""
-    from mpistragglers_jl_tpu.models.decode import (
-        _dense_runner,
-        use_decode_kernel,
-    )
-
-    # the flag can route only on lane-aligned head_dim + quantized cache
-    cfg = dataclasses.replace(
-        CFG, d_model=256, n_heads=2, n_kv_heads=1, d_ff=64
-    )
-    assert cfg.head_dim == 128
-    params = init_params(cfg, seed=9)
-    rng = np.random.default_rng(10)
-    prompt = jnp.asarray(rng.integers(0, cfg.vocab, (1, 4)), jnp.int32)
-    generate_dense(params, prompt, 3, cfg, quantize_kv=True)
-    before = _dense_runner.cache_info().currsize
-    assert before > 0
-    use_decode_kernel(True)
-    try:
-        # same call re-traces under the new flag: a NEW cache entry,
-        # nothing evicted (programs for the other setting survive)
-        generate_dense(params, prompt, 3, cfg, quantize_kv=True)
-        assert _dense_runner.cache_info().currsize == before + 1
-        # the flag is INERT for bf16 caches: no extra entry, cache hit
-        generate_dense(params, prompt, 3, cfg)
-        n_after_bf16 = _dense_runner.cache_info().currsize
-        hits0 = _dense_runner.cache_info().hits
-        use_decode_kernel(False)
-        generate_dense(params, prompt, 3, cfg)
-        assert _dense_runner.cache_info().currsize == n_after_bf16
-        assert _dense_runner.cache_info().hits == hits0 + 1
-        # toggling back reuses the original quantized entry too
-        use_decode_kernel(True)
-        use_decode_kernel(False)
-        generate_dense(params, prompt, 3, cfg, quantize_kv=True)
-        assert _dense_runner.cache_info().currsize == n_after_bf16
-    finally:
-        use_decode_kernel(None)  # restore the batched-AUTO default
