@@ -11,7 +11,11 @@ Design (TPU-first):
   ``off`` writes its per-layer K/V into the cache at ``[off, off+T)``
   and attends causally. Prefill (``off == 0``) needs no cache reads, so
   it runs the configured chunk kernel — the flash Pallas kernel for
-  long prompts. Decode (``T == 1``) attends the single query against
+  long prompts. A later chunk (``T > 1`` at ``off > 0``: chunked
+  prefill, speculative verification) walks the key blocks of the cache
+  its rows can see with an online softmax (:func:`_chunk_attention`),
+  so it costs what ``off + T`` holds and not ``max_len``. Decode
+  (``T == 1``) attends the single query against
   the whole cache through the grouped GQA einsums
   (:func:`~..parallel.ring_attention._group_scores`), so MQA/GQA
   configs read ``kv_heads`` cache heads, not ``n_heads`` — the KV
@@ -346,21 +350,106 @@ def shard_cache(cache, cfg: TransformerConfig, mesh: Mesh):
     )
 
 
+# key rows one step of the chunk walk scores (``_chunk_attention``): the
+# per-block (B, H, T, CHUNK_BLOCK_K) float32 scores are all a chunk
+# ever holds, whatever the cache's length
+CHUNK_BLOCK_K = 512
+
+
+def _chunk_attention(q, cache_l, qpos, scale, window):
+    """A chunk's (T > 1) grouped attention, walking the key blocks its
+    queries can see: block ``j`` holds the cache rows ``[j*bk,
+    (j+1)*bk)`` (``bk = min(CHUNK_BLOCK_K, Lmax)``; absolute positions,
+    so a query row meets the same blocks in the same order whatever the
+    chunk size or the cache's length), and the walk runs from the block
+    of the first row the widest query sees (``off - window + 1``, or 0)
+    to the block of the chunk's last row, both traced from ``off =
+    qpos[0]``. Running maximum, sum and accumulator are the online
+    softmax of ``parallel/ring_attention._block_update``; a block
+    that is all masked for a row leaves that row as it was (``p`` is 0
+    and the rescale ``exp(0)``). Scores, mask and p.v of a block are
+    ``_cache_scores`` / ``_band_mask`` / ``_cache_pv`` on the block's
+    rows, int8 rows dequantized with their per-position scales. No
+    (H, T, Lmax) tensor exists and a block outside the walk costs
+    nothing: the work follows the rows the chunk can see, not the
+    cache's length."""
+    T = q.shape[1]
+    Lmax = cache_l["k"].shape[1]
+    bk = min(CHUNK_BLOCK_K, Lmax)
+    off = qpos[0]
+    lo = 0 if window is None else jnp.maximum(off - window + 1, 0) // bk
+    hi = jnp.minimum(-(-(off + T) // bk), -(-Lmax // bk))
+    # accumulators derived from q: they inherit its varying mesh axes
+    # (make_extend runs this under shard_map), as in ring_self_attention
+    o0 = q.astype(jnp.float32) * 0.0
+    zeros = o0.sum(-1).transpose(0, 2, 1)  # (B, H, T)
+
+    def block(j, carry):
+        o, m, l = carry
+        # the last block of a cache that is no multiple of bk slides
+        # back inside it; its rows below j*bk are block j-1's
+        start = jnp.minimum(j * bk, Lmax - bk)
+        blk = {
+            name: jax.lax.dynamic_slice_in_dim(a, start, bk, axis=1)
+            for name, a in cache_l.items()
+        }
+        kpos = start + jnp.arange(bk)
+        # the one band predicate (parallel/ring_attention._band_mask):
+        # the serving path cannot silently diverge from the training
+        # oracle
+        mask = _band_mask(qpos, kpos, True, window)
+        if Lmax % bk:
+            mask = jnp.logical_and(mask, (kpos >= j * bk)[None, :])
+        mask = mask[None, None]
+        s = jnp.where(mask, _cache_scores(q, blk, scale), _NEG)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)  # (B, H, T)
+        l = l * corr + p.sum(axis=-1)
+        o = o * corr.transpose(0, 2, 1)[..., None] + _cache_pv(p, blk)
+        return o, m_new, l
+
+    o, _, l = jax.lax.fori_loop(lo, hi, block, (o0, zeros + _NEG, zeros))
+    # every row sees at least itself while the caller keeps off + T <=
+    # Lmax (its contract); past it nothing is promised, only no NaN
+    l = jnp.maximum(l, 1e-20)
+    return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
+
+
+def _chunk_rows_seen(off: int, T: int, Lmax: int, windows) -> int:
+    """Key rows the walk of :func:`_chunk_attention` scores for a chunk
+    of ``T`` queries at ``off`` in its widest layer (``windows``: every
+    layer's, ``cfg.windows``): the same ``lo`` and ``hi`` in host
+    integers, for a scheduler that counts what its chunks attend
+    without a read from the device."""
+    bk = min(CHUNK_BLOCK_K, Lmax)
+    lo = 0
+    if all(w is not None for w in windows):
+        lo = max(off - max(windows) + 1, 0) // bk
+    return min(off + T, Lmax) - lo * bk
+
+
 def _cached_attention(q, cache_l, qpos, scale, window=None,
                       use_kernel: bool = False):
-    """Grouped attention of the chunk's queries against the full cache.
+    """Grouped attention of the chunk's queries against the rows of the
+    cache they can see.
 
     q: (B, T, H, D); the cache holds (B, Lmax, Hkv, D) at positions
     ``arange(Lmax)``; validity is ``kpos <= qpos`` (cache entries past
-    the chunk are zeros AND masked; entries below the offset are real),
-    intersected with the sliding-window band when ``window`` is set.
+    the chunk are zeros or an earlier request's leftovers AND masked;
+    entries below the offset are real), intersected with the
+    sliding-window band when ``window`` is set.
 
-    int8 caches at T == 1 take the Pallas decode kernel
-    (ops/decode_attention.py): it dequantizes in VMEM, so HBM reads
+    A chunk (T > 1) walks the key blocks it can see
+    (:func:`_chunk_attention`). A single query scores the whole cache
+    at once: int8 caches take the Pallas decode kernel
+    (ops/decode_attention.py), which dequantizes in VMEM, so HBM reads
     really are the int8 bytes — the einsum form's ``.astype`` is
     materialized by XLA and gives half the bytes back.
     ``use_kernel`` is the program's resolved route (the module note).
     """
+    if q.shape[1] > 1:
+        return _chunk_attention(q, cache_l, qpos, scale, window)
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 
@@ -368,13 +457,13 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
             q, cache_l, qpos[0], scale, window
         )
     Lmax = cache_l["k"].shape[1]
-    s = _cache_scores(q, cache_l, scale)  # (B, H, T, Lmax) f32
+    s = _cache_scores(q, cache_l, scale)  # (B, H, 1, Lmax) f32
     # the one band predicate (parallel/ring_attention._band_mask): the
     # serving path cannot silently diverge from the training oracle
     mask = _band_mask(qpos, jnp.arange(Lmax), True, window)
     s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
-    o = _cache_pv(p, cache_l)  # (B, T, H, D) f32
+    o = _cache_pv(p, cache_l)  # (B, 1, H, D) f32
     return o.astype(q.dtype)
 
 
@@ -446,10 +535,13 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     return x, cache_l
 
 
-def _incremental_forward(params, tokens, cache, offset, cfg,
-                         *, prefill, kv_slice=None, tp_psum=False,
-                         ring=False, decode_kernel: bool = False):
-    """Chunk forward at global ``offset``; returns (logits, cache).
+def _incremental_hidden(params, tokens, cache, offset, cfg,
+                        *, prefill, kv_slice=None, tp_psum=False,
+                        ring=False, decode_kernel: bool = False):
+    """Chunk forward at global ``offset`` up to the last layer's output;
+    returns (hidden (B, T, d), cache): :func:`_incremental_forward`
+    without the head, for callers that read few of the chunk's rows (the
+    server's prefill chunk reads one a request) or none.
 
     ``prefill=True`` (static) means offset is known to be 0 and chunk
     attention uses the configured kernel; otherwise attention runs
@@ -478,7 +570,16 @@ def _incremental_forward(params, tokens, cache, offset, cfg,
             ring=ring, decode_kernel=decode_kernel,
         )
         new_cache.append(cache_l)
-    return head_logits(params, x, cfg), new_cache
+    return x, new_cache
+
+
+def _incremental_forward(params, tokens, cache, offset, cfg, **kw):
+    """Chunk forward at global ``offset``; returns (logits (B, T, V),
+    cache): the head on every row of :func:`_incremental_hidden`, whose
+    keywords these are."""
+    x, cache = _incremental_hidden(params, tokens, cache, offset, cfg,
+                                   **kw)
+    return head_logits(params, x, cfg), cache
 
 
 # --------------------------------------------------------------------------
@@ -506,14 +607,15 @@ def _aligned_quantized_prefill(params, prompt, cache, cfg, *,
     the only math the serving scheduler's chunked admission can ever
     evaluate — raw K/V of earlier chunks are gone once written. Per-
     position absmax quantization makes the chunk size invisible (a
-    position's scale never depends on its neighbours), so any C yields
-    the identical stream; C=512 keeps the materialized causal scores at
-    O(C * Tp) per layer instead of the O(Tp^2) a one-shot aligned call
-    would allocate — the flagship 16k prompt stays servable through
-    this path, not just test-scale oracles.
+    position's scale never depends on its neighbours) and the chunk
+    walks key blocks laid on absolute positions
+    (:func:`_chunk_attention`), so any C yields the identical stream;
+    the scores that exist at one time are a block's, O(C *
+    CHUNK_BLOCK_K) per layer whatever Tp — the flagship 16k prompt
+    stays servable through this path, not just test-scale oracles.
 
     The shape-identical full chunks run under ONE ``lax.scan`` body
-    (their logits are discarded; only the cache carries), so trace and
+    (no head runs on them; only the cache carries), so trace and
     compile cost stay flat in Tp — a python loop would retrace the
     whole per-layer forward Tp/C times. At most two chunks trace
     directly at the tail: the one whose logits the caller needs, plus
@@ -534,7 +636,7 @@ def _aligned_quantized_prefill(params, prompt, cache, cfg, *,
 
         def body(cache, xs):
             ch, off = xs
-            _, cache = _incremental_forward(
+            _, cache = _incremental_hidden(
                 params, ch, cache, off, cfg, prefill=False,
                 kv_slice=kv_slice, tp_psum=tp_psum,
                 decode_kernel=decode_kernel,
@@ -981,9 +1083,12 @@ def make_extend(cfg: TransformerConfig, mesh: Mesh, *,
     ``make_prefill`` (the
     incremental forward is the training forward evaluated causally —
     tests/test_decode.py pins the chunked == one-shot == dense-oracle
-    chain). The chunk attends through the masked cached-attention path
-    (offset 0 one-shot prefill keeps the flash chunk kernel); the
-    MoE capacity caveat of :func:`prefill_dense` applies per chunk.
+    chain). The chunk attends the cache through the walk of
+    :func:`_chunk_attention`: key blocks from the first its queries can
+    see to its own last row, so a chunk's attention costs what ``offset
+    + T`` (or the window) holds, not ``max_len`` (offset 0 one-shot
+    prefill keeps the flash chunk kernel); the MoE capacity caveat of
+    :func:`prefill_dense` applies per chunk.
 
     The cache is NOT donated here (the T=1 decode step donates its
     own): each chunk's program writes a fresh cache pytree. Chunked

@@ -43,8 +43,11 @@ Design (TPU-first):
 * **Chunked prefill interleaved with decode.** Admission does not
   stall in-flight requests behind a long prompt: each tick advances
   every admitting request by ONE C-token prefill chunk (through the
-  masked cached-attention path, exactly ``make_extend``'s semantics)
-  and then runs the decode scan. With ``quantize_kv=True`` each chunk
+  cached-attention path, exactly ``make_extend``'s semantics: the
+  chunk attends the key blocks its rows can see,
+  ``decode._chunk_attention``, so its work follows the prompt's
+  length so far and not the arena's) and then runs the decode scan.
+  With ``quantize_kv=True`` each chunk
   attends the already-quantized cache — the only math available once
   earlier chunks' raw K/V are gone — and per-position absmax
   quantization makes the chunk size invisible, so the stream is
@@ -55,9 +58,11 @@ Design (TPU-first):
   tests/test_serving.py). A request's prefill lands in a
   transient positional cache; on the last chunk the final-W window
   gathers into its slot's ring rows (``ring_from_cache`` math with a
-  traced length) and the first token comes from the last chunk's
-  logits. Decode stall per tick is bounded by one chunk, not one
-  prompt.
+  traced length) and the first token comes from the head applied to
+  ONE row of the last chunk's hidden state, the prompt's last
+  position: a chunk program stops at the last layer's output, so the
+  head's weights are read once a request. Decode stall per tick is
+  bounded by one chunk, not one prompt.
 * **EOS retirement + slot reuse.** Rows that emit ``eos_id`` keep
   emitting it on-device (static shapes; ``_eos_clamp``); the host
   strips the tail, retires the request (EOS or its ``max_new`` budget),
@@ -102,8 +107,9 @@ from .decode import (
     _check_ring_cfg,
     _check_sampling_params,
     _decode_kernel_interpreted,
+    _chunk_rows_seen,
     _eos_clamp,
-    _incremental_forward,
+    _incremental_hidden,
     _is_quantized,
     _kernel_possible,
     _kernel_viable,
@@ -848,15 +854,20 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
 @functools.lru_cache(maxsize=32)
 def _extend_chunk_dense(cfg: TransformerConfig, C: int, Lmax: int):
     """One C-token prefill chunk into a (1, Lmax) transient positional
-    cache at dynamic ``offset`` (make_extend semantics, dense B=1).
-    Cache donated: chunks stream through one arena."""
+    cache at dynamic ``offset`` (make_extend semantics, dense B=1):
+    (params, chunk (1, C), cache, offset) -> (hidden (1, C, d), cache).
+    The chunk attends the key blocks its rows can see
+    (``decode._chunk_attention``: work follows ``offset + C``, not
+    ``Lmax``) and stops at the last layer's output: the head runs in
+    :func:`_finish_admit_dense`, on the one row a request reads. One
+    program per ``(cfg, C, Lmax)``; ``offset`` is traced. Cache
+    donated: chunks stream through one arena."""
 
     @functools.partial(jax.jit, donate_argnums=(2,))
     def serving_prefill_chunk(params, chunk, cache, offset):
-        logits, cache = _incremental_forward(
+        return _incremental_hidden(
             params, chunk, cache, offset, cfg, prefill=False
         )
-        return logits, cache
 
     return serving_prefill_chunk
 
@@ -866,19 +877,26 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
                         temperature: float = 0.0,
                         top_k: int | None = None):
     """Gather the last-W window of a filled positional cache into ring
-    rows + pick the first token (greedy, or sampled with the request's
-    key at the prompt's last position — decode.py's fold discipline):
-    (cache, last_logits (1, C, V), true_len, last_off, key) ->
+    rows + pick the first token: the head on row ``true_len - 1 -
+    last_off`` of the last chunk's hidden state, the prompt's last
+    position (greedy, or sampled with the request's key there —
+    decode.py's fold discipline), so the head's weights are read once a
+    request:
+    (params, cache, last_hidden (1, C, d), true_len, last_off, key) ->
     (tok0 (), ring leaves (1, W, ...))."""
     widths = ring_widths(cfg)
 
     @jax.jit
-    def serving_first_token(cache, last_logits, true_len, last_off, key):
+    def serving_first_token(params, cache, last_hidden, true_len,
+                            last_off, key):
         ring = [_ring_from_cache(cl, true_len, W)
                 for cl, W in zip(cache, widths)]
-        lg = jnp.take(last_logits[0], true_len - 1 - last_off, axis=0)
+        row = jax.lax.dynamic_slice_in_dim(
+            last_hidden, true_len - 1 - last_off, 1, axis=1
+        )
+        lg = head_logits(params, row, cfg)[:, 0]  # (1, V)
         tok0 = _pick_rows(
-            lg[None], (true_len - 1)[None], key[None], temperature,
+            lg, (true_len - 1)[None], key[None], temperature,
             top_k, jnp.int32,
         )[0]
         return tok0, ring
@@ -1284,7 +1302,7 @@ class _Admitting:
         self.padded = padded  # (1, n_chunks * C) int32
         self.n_chunks = n_chunks
         self.next_chunk = 0
-        self.last_logits = None
+        self.last_hidden = None  # (1, C, d) of the newest chunk
         self.base = base
         self.pids = pids
         self.digests = digests
@@ -2836,14 +2854,16 @@ class ServingScheduler:
         st = self._admitting[s]
         i = st.next_chunk
         rid = st.req.id
+        off = st.base + i * self.C
         with _annotate("serving.prefill_chunk", req=rid, slot=s,
-                       chunk=i, of=st.n_chunks):
+                       chunk=i, of=st.n_chunks,
+                       rows_seen=_chunk_rows_seen(
+                           off, self.C, self.Lmax, self.cfg.windows)):
             chunk = jax.lax.dynamic_slice_in_dim(
                 st.padded, i * self.C, self.C, axis=1
             )
-            st.last_logits, st.cache = self._extend(
-                self.params, chunk, st.cache,
-                jnp.int32(st.base + i * self.C),
+            st.last_hidden, st.cache = self._extend(
+                self.params, chunk, st.cache, jnp.int32(off),
             )
         st.next_chunk += 1
         if self._obs is not None:
@@ -2860,7 +2880,7 @@ class ServingScheduler:
             rkey = (st.req.key if st.req.key is not None
                     else jax.random.key(st.req.id + 1))
             tok0, ring = self._finish(
-                st.cache, st.last_logits, jnp.int32(Tp),
+                self.params, st.cache, st.last_hidden, jnp.int32(Tp),
                 jnp.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
             )
             # _finish read the arena without donating it: recycle it

@@ -264,6 +264,46 @@ def test_a_backlog_of_one_chunk_prompts_reuses_one_arena(
     assert len(sched._free_arenas) == 1
 
 
+def test_prefill_chunk_says_how_many_key_rows_it_attends(tmp_path):
+    """``rows_seen`` on ``serving.prefill_chunk``: the key rows the
+    chunk's attention walks, from the host's own arithmetic. A prompt
+    of three chunks of 256 prints 256, 512, 768; the same prompt
+    again, 11 of its pages of 64 shared, prefills one chunk at ``base``
+    704 and prints ``base + C``."""
+    from mpistragglers_jl_tpu.models.serving import ServingScheduler
+    from mpistragglers_jl_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    cfg = TransformerConfig(
+        vocab=37, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=64, attn_window=1024,
+    )
+    sched = ServingScheduler(
+        init_params(cfg, seed=3), cfg, slots=2, n_inner=2,
+        prompt_chunk=256, max_prompt=1024, quantize_kv=True,
+        page_tokens=64,
+    )
+    prompt = np.random.default_rng(4).integers(1, cfg.vocab, size=768)
+    with _profiled(str(tmp_path)):
+        first = sched.submit(prompt, max_new=40)
+        while not first.tokens:
+            sched.step()
+        second = sched.submit(prompt, max_new=2)
+        sched.run()
+    assert first.finished and second.finished
+    chunks = [e[3] for e in _host_events(str(tmp_path))
+              if e[0] == "serving.prefill_chunk"]
+    assert [(c["req"], c["chunk"], c["of"], c["rows_seen"])
+            for c in chunks] == [
+        (first.id, 0, 3, 256), (first.id, 1, 3, 512),
+        (first.id, 2, 3, 768), (second.id, 0, 1, 704 + 256),
+    ]
+    # against chunks x Lmax: what share of the arena is still scored
+    assert sum(c["rows_seen"] for c in chunks) / (4 * 1024) < 0.61
+
+
 def test_recorder_spans_are_cut_at_the_same_boundaries(tiny):
     """``spans=`` draws admit/decode/retire from the phases the
     profiler sees: each recorder span lies inside its tick's, in
