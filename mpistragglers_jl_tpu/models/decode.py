@@ -77,6 +77,8 @@ from .transformer import (
     attn_merge,
     embed,
     ffn_half,
+    gdn_half,
+    gdn_zero_state,
     head_logits,
     make_kv_slice,
     param_specs,
@@ -318,9 +320,11 @@ def init_cache(
     size)."""
     H = _cache_heads_global(cfg, mesh)
     return [
-        _zero_cache_layer(batch, max_len, H, cfg.head_dim, cfg.dtype,
-                          quantize_kv)
-        for _ in range(cfg.n_layers)
+        # a gated delta-rule layer keeps its fixed block of state
+        gdn_zero_state(cfg, batch) if cfg.gdn(li)
+        else _zero_cache_layer(batch, max_len, H, cfg.head_dim, cfg.dtype,
+                               quantize_kv)
+        for li in range(cfg.n_layers)
     ]
 
 
@@ -501,7 +505,7 @@ def _ring_cached_attention(q, cache_l, pos, scale,
 
 def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
                        kv_slice, tp_psum, ring=False,
-                       decode_kernel: bool = False):
+                       decode_kernel: bool = False, valid=None):
     """Layer ``li`` of the incremental forward: write the chunk's K/V
     into the cache at ``qpos`` positions, attend, feed-forward. Returns
     (x, cache_l). The block itself is models/transformer.py's
@@ -511,9 +515,16 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     axis, exactly like the training path (``_forward_local``).
     ``ring=True`` treats the cache as the O(W) circular window buffer
     (single-token chunks only): the write lands at slot ``pos % W`` and
-    attention runs through :func:`_ring_cached_attention`."""
-    q, k, v, gate = attn_qkv(x, lp, cfg, li, partial(_rope, pos=qpos),
-                             kv_slice)
+    attention runs through :func:`_ring_cached_attention`. A gated
+    delta-rule layer's ``cache_l`` is its state (no rows), carried
+    through the chunk; of ``valid`` see ``gdn_half``."""
+    if cfg.gdn(li):
+        x, cache_l = gdn_half(x, lp, cache_l, cfg, valid)
+        x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
+        return x, cache_l
+    q, k, v, gate = attn_qkv(
+        x, lp, cfg, li, partial(_rope, pos=qpos, theta=cfg.rope_theta),
+        kv_slice)
     off = qpos[0]
     if ring:
         off = jnp.mod(off, cache_l["k"].shape[1])
@@ -537,7 +548,8 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
 
 def _incremental_hidden(params, tokens, cache, offset, cfg,
                         *, prefill, kv_slice=None, tp_psum=False,
-                        ring=False, decode_kernel: bool = False):
+                        ring=False, decode_kernel: bool = False,
+                        valid=None):
     """Chunk forward at global ``offset`` up to the last layer's output;
     returns (hidden (B, T, d), cache): :func:`_incremental_forward`
     without the head, for callers that read few of the chunk's rows (the
@@ -547,7 +559,10 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
     attention uses the configured kernel; otherwise attention runs
     against the cache — the ``max_len`` positional cache by default,
     the O(W) ring buffer when ``ring=True``. ``decode_kernel`` is the
-    program's resolved int8-kernel route (the module note).
+    program's resolved int8-kernel route (the module note). ``valid``
+    (a traced count, None = all) is how many leading rows of the chunk
+    are the prompt's and not padding: attention never reads the
+    padding, a recurrent layer must be told to skip it.
     """
     T = tokens.shape[1]
     if ring and (T != 1 or prefill):
@@ -567,7 +582,7 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
         x, cache_l = _incremental_layer(
             x, lp, cache_l, qpos, cfg, li,
             chunk_attn=chunk_attn, kv_slice=kv_slice, tp_psum=tp_psum,
-            ring=ring, decode_kernel=decode_kernel,
+            ring=ring, decode_kernel=decode_kernel, valid=valid,
         )
         new_cache.append(cache_l)
     return x, new_cache
@@ -591,7 +606,10 @@ def _check_prefill_fits(T: int, cache) -> None:
     """Trace-time guard: ``dynamic_update_slice`` CLAMPS out-of-range
     offsets, so an over-long chunk would silently wrap the tail of the
     cache instead of erroring."""
-    Lmax = jax.tree.leaves(cache)[0].shape[1]
+    rows = [cl["k"] for cl in cache if "k" in cl]
+    if not rows:  # recurrent layers alone: no row a token, no bound
+        return
+    Lmax = rows[0].shape[1]
     if T > Lmax:
         raise ValueError(
             f"chunk of {T} tokens does not fit the cache (max_len "
@@ -693,9 +711,27 @@ def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
     ``cfg.max_context``: a ring that wide never wraps inside the
     budget, so slot ``s`` holds position ``s`` and the one invariant
     (``kpos = pos - ((pos - s) mod W)``, valid iff ``kpos >= 0``) is
-    plain causal attention there."""
+    plain causal attention there. A gated delta-rule layer has no
+    rows and so no width: a configuration with one is refused here
+    (``ServingScheduler`` serves it, its state a fixed block a slot)."""
+    if cfg.state_layers:
+        raise ValueError(
+            "the ring cache is rows of K/V, a position each; this "
+            "configuration has gated delta-rule layers, whose state is "
+            "one fixed block a request and has no width. "
+            "ServingScheduler serves it"
+        )
+    return _row_widths(cfg)
+
+
+def _row_widths(cfg: TransformerConfig) -> tuple:
+    """:func:`ring_widths` with None for a layer that keeps recurrent
+    state in place of rows."""
     out = []
-    for w in cfg.windows:
+    for li, w in enumerate(cfg.windows):
+        if cfg.gdn(li):
+            out.append(None)
+            continue
         if w is None:
             if cfg.max_context is None:
                 raise ValueError(

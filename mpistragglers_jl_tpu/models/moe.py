@@ -409,13 +409,18 @@ def init_topk_layer(rng: np.random.Generator, cfg) -> dict:
     out = {
         "router": jnp.asarray(
             rng.standard_normal((D, E)) / np.sqrt(D), jnp.float32),
-        "router_bias": jnp.asarray(
-            rng.standard_normal((E,)) * 0.02, jnp.float32),
-        "we_gate": sd(E, D, F),
-        "we_up": sd(E, D, F),
-        # float(): np.float64 scalars promote f32 params under x64
-        "we_down": sd(E, F, D) / float(np.sqrt(cfg.n_layers)),
     }
+    if cfg.route_score == "sigmoid":
+        out["router_bias"] = jnp.asarray(
+            rng.standard_normal((E,)) * 0.02, jnp.float32)
+    # the router scores all E experts; the matrices are the held ones'
+    Eh = cfg.held_experts
+    out.update({
+        "we_gate": sd(Eh, D, F),
+        "we_up": sd(Eh, D, F),
+        # float(): np.float64 scalars promote f32 params under x64
+        "we_down": sd(Eh, F, D) / float(np.sqrt(cfg.n_layers)),
+    })
     Fs = cfg.shared_experts * F
     if Fs:
         out.update({
@@ -423,21 +428,27 @@ def init_topk_layer(rng: np.random.Generator, cfg) -> dict:
             "ws_up": sd(D, Fs),
             "ws_down": sd(Fs, D) / float(np.sqrt(cfg.n_layers)),
         })
+        if cfg.shared_gate:
+            out["ws_sgate"] = sd(D, 1)
     return out
 
 
-def topk_route(x2d: jax.Array, router: jax.Array, bias: jax.Array,
-               k: int, scale: float):
-    """Sigmoid top-k routing of (T, D) tokens: scores
-    ``s = sigmoid(x @ router)`` in float32; the ``k`` experts with the
-    largest ``s + bias`` are chosen (the bias selects and does not
-    weigh); their weights are ``s`` itself, normalised to sum to one
-    and multiplied by ``scale``. Returns ``(idx (T, k) int32,
-    w (T, k) float32)``."""
-    s = jax.nn.sigmoid(jnp.einsum(
+def topk_route(x2d: jax.Array, router: jax.Array, bias, k: int,
+               scale: float, score: str = "sigmoid"):
+    """Top-k routing of (T, D) tokens: scores ``s = sigmoid(x @
+    router)`` (or, ``score="softmax"``, the softmax over all experts)
+    in float32; the ``k`` experts with the largest ``s + bias`` are
+    chosen (the bias selects and does not weigh; None: no bias); their
+    weights are ``s`` itself, normalised to sum to one and multiplied
+    by ``scale``. Returns ``(idx (T, k) int32, w (T, k) float32)``."""
+    logits = jnp.einsum(
         "td,de->te", x2d, router, preferred_element_type=jnp.float32,
-    ))
-    _, idx = jax.lax.top_k(s + bias, k)
+    )
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s if bias is None else s + bias, k)
     w = jnp.take_along_axis(s, idx, axis=1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return idx, w
@@ -490,20 +501,40 @@ def moe_ffn_topk(h: jax.Array, lp: dict, cfg):
     ``n_experts`` times that), and the weighted results go back to
     their tokens. Returns ``(y, hit)``: ``hit`` counts the experts that
     got at least one row.
+
+    With ``cfg.experts_held = (lo, hi)`` the matrices here are those of
+    experts ``[lo, hi)`` alone. The router still scores all
+    ``n_experts``, and a token's weights are normalised over its ``k``
+    chosen wherever they live; the pairs that fall on a held expert are
+    computed and the others left out (a further chip's part of the
+    sum). ``hit`` is then ``[held experts that got a row, pairs that
+    fell on held experts]``.
     """
     B, L, D = h.shape
     T, k, E = B * L, cfg.experts_per_token, cfg.n_experts
     x = h.reshape(T, D)
+    held = cfg.experts_held
     with jax.named_scope("moe_route"):
-        idx, w = topk_route(x, lp["router"], lp["router_bias"], k,
-                            cfg.route_scale)
+        idx, w = topk_route(x, lp["router"], lp.get("router_bias"), k,
+                            cfg.route_scale, cfg.route_score)
+        idx = idx.reshape(-1)
+        if held is not None:
+            # held experts count from 0; a pair for any other expert
+            # sorts behind them all, into no group
+            lo, E = held[0], held[1] - held[0]
+            here = (idx >= lo) & (idx < lo + E)
+            idx = jnp.where(here, idx - lo, E)
         # pair p = (token p // k, its (p % k)-th expert), by expert
         expert, pair = jax.lax.sort(
-            (idx.reshape(-1), jnp.arange(T * k, dtype=jnp.int32)),
+            (idx, jnp.arange(T * k, dtype=jnp.int32)),
             num_keys=1, is_stable=True,
         )
-        sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
-        hit = jnp.sum(sizes > 0)
+        if held is None:
+            sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+            hit = jnp.sum(sizes > 0)
+        else:
+            sizes = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+            hit = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes)])
     with jax.named_scope("moe_experts"):
         xs = jnp.take(x, pair // k, axis=0)  # (T*k, D), grouped
         a = grouped_matmul(xs, lp["we_gate"], sizes, x.dtype)
@@ -511,6 +542,9 @@ def moe_ffn_topk(h: jax.Array, lp: dict, cfg):
         ys = grouped_matmul(jax.nn.silu(a) * b, lp["we_down"], sizes,
                             jnp.float32)
         ys = ys * jnp.take(w.reshape(-1), pair)[:, None]
+        if held is not None:
+            # rows of no group were never written by the product
+            ys = jnp.where((expert < E)[:, None], ys, 0.0)
         # back to token order: pair p's row sits at sorted position
         # inv[p]; a token's k rows are then adjacent
         inv = jnp.zeros((T * k,), jnp.int32).at[pair].set(
@@ -520,8 +554,13 @@ def moe_ffn_topk(h: jax.Array, lp: dict, cfg):
         with jax.named_scope("moe_shared"):
             a = jax.nn.silu(jnp.einsum("td,df->tf", x, lp["ws_gate"]))
             a = a * jnp.einsum("td,df->tf", x, lp["ws_up"])
-            y = y + jnp.einsum(
+            ysh = jnp.einsum(
                 "tf,fd->td", a, lp["ws_down"],
                 preferred_element_type=jnp.float32,
             )
+            if "ws_sgate" in lp:
+                ysh = ysh * jax.nn.sigmoid(jnp.einsum(
+                    "td,do->to", x, lp["ws_sgate"],
+                    preferred_element_type=jnp.float32))
+            y = y + ysh
     return y.astype(h.dtype).reshape(B, L, D), hit

@@ -28,6 +28,18 @@ Design (TPU-first):
   holds pages of every kind. Dropless top-k expert layers
   (``models/moe.py`` ``moe_ffn_topk``) are per token, so chunks and
   decode steps give what the whole forward gives.
+* **A second kind of state.** A gated delta-rule layer
+  (``TransformerConfig(layer_mixers=...)``) keeps no row a token: its
+  cache is one fixed block a slot (``S`` and the last rows of its
+  conv, ``transformer.gdn_zero_state``), beside the other layers'
+  pages. A prefill chunk carries it through the arena and is told how
+  many of its rows are real (a recurrence would swallow the padding
+  that attention never reads); placement writes the block over the
+  slot's old one, so a reused slot starts from the new prompt's state.
+  The state at a page boundary is kept nowhere, so such a
+  configuration shares no prefix page (``shares_prefixes`` is False),
+  and ``qos=``, ``cache=``, page migration, ``make_serving_scan`` and
+  speculation refuse it by mechanism.
 * **Per-row positions.** Unlike ``decode_step_ring_dense`` (one scalar
   position for the whole batch), every slot decodes at its own global
   position: RoPE angles, ring-slot writes, and the ``kpos >= 0``
@@ -118,6 +130,7 @@ from .decode import (
     _pick_token,
     _ring_from_cache,
     _route_kernel,
+    _row_widths,
     ring_widths,
 )
 from .paging import (
@@ -133,6 +146,8 @@ from .transformer import (
     attn_qkv,
     embed,
     ffn_half,
+    gdn_half,
+    gdn_zero_state,
     head_logits,
     make_kv_slice,
     param_specs,
@@ -160,7 +175,9 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
     lengths = (L,) * cfg.n_layers if isinstance(L, int) else tuple(L)
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
-    def layer(length):
+    def layer(li, length):
+        if cfg.gdn(li):  # no rows: the layer's fixed block of state
+            return gdn_zero_state(cfg, B)
         shape = (B, length, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
@@ -170,7 +187,7 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
 
     @jax.jit
     def serving_fresh_arena():
-        return [layer(length) for length in lengths]
+        return [layer(li, length) for li, length in enumerate(lengths)]
 
     return serving_fresh_arena
 
@@ -207,7 +224,7 @@ def paged_scale_lanes(P: int) -> int:
 
 
 def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
-                 quantize_kv: bool = False) -> list[dict]:
+                 quantize_kv: bool = False, slots: int = 0) -> list[dict]:
     """Zeroed per-layer PAGE POOL, shared by every slot, in the layout
     the paged decode kernel's blocks have (ops/decode_attention.py), so
     that a tick reads and writes pages where they lie and nothing of
@@ -222,12 +239,16 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     tuple with one per layer (layers of one cache width share a page
     table, and so a count). Page 0 is the reserved null page
     (:data:`~.paging.NULL_PAGE`): rows nothing reads unmasked, the
-    landing zone for retired-but-still-ticking rows."""
+    landing zone for retired-but-still-ticking rows. A gated
+    delta-rule layer has no pages: its leaf is the fixed block of state
+    of each of the ``slots`` (its page count is not read)."""
     counts = ((n_pages,) * cfg.n_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
-    def layer(n):
+    def layer(li, n):
+        if cfg.gdn(li):
+            return gdn_zero_state(cfg, slots)
         shape = (n, P, cfg.kv_heads * cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
@@ -236,7 +257,7 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
             out["v_s"] = jnp.zeros(sshape, jnp.float32)
         return out
 
-    return [layer(n) for n in counts]
+    return [layer(li, n) for li, n in enumerate(counts)]
 
 
 def _rows_to_pages(kk: str, x, P: int):
@@ -270,10 +291,12 @@ def _layer_kinds(cfg: TransformerConfig) -> tuple[tuple[int, ...],
     configuration's layers, narrowest first, and each layer's index
     into them. Layers of one width share one page table and one
     :class:`~.paging.PagePool`; a configuration of sliding-window
-    layers alone has one kind."""
-    widths = ring_widths(cfg)
-    kinds = tuple(sorted(set(widths)))
-    return kinds, tuple(kinds.index(w) for w in widths)
+    layers alone has one kind. A layer that keeps recurrent state has
+    no rows, no width and no kind (None)."""
+    widths = _row_widths(cfg)
+    kinds = tuple(sorted({w for w in widths if w is not None}))
+    return kinds, tuple(
+        None if w is None else kinds.index(w) for w in widths)
 
 
 def _layer_tables(cfg: TransformerConfig, pt) -> list:
@@ -284,7 +307,7 @@ def _layer_tables(cfg: TransformerConfig, pt) -> list:
     _, kind_of = _layer_kinds(cfg)
     if len(tables) == 1:
         return [tables[0]] * cfg.n_layers
-    return [tables[k] for k in kind_of]
+    return [None if k is None else tables[k] for k in kind_of]
 
 
 # --------------------------------------------------------------------------
@@ -292,13 +315,13 @@ def _layer_tables(cfg: TransformerConfig, pt) -> list:
 # --------------------------------------------------------------------------
 
 
-def _rope_rows(x, pos):
+def _rope_rows(x, pos, theta: float = 10000.0):
     """Rotary embedding for single-token rows: x (S, 1, H, D), pos (S,)
     global positions — the per-row counterpart of transformer._rope
-    (which shares one position vector across the batch)."""
+    (which shares one position vector across the batch), at its base."""
     Dh = x.shape[-1]
     half = Dh // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (S, half)
     cos = jnp.cos(ang)[:, None, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[:, None, None, :].astype(x.dtype)
@@ -461,9 +484,18 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     ``paged`` = (page_table, W, PAGE_TOKENS) switches the cache
     write/read to the page-pool layout; None is the slot-ring path.
     Returns ``(x, cache_l, hit)``; ``hit`` is the number of experts
-    that got a row in a dropless expert layer, None elsewhere."""
+    that got a row in a dropless expert layer, None elsewhere. A gated
+    delta-rule layer's ``cache_l`` is every slot's state: one step of
+    the recurrence a row, no position and no page."""
+    if cfg.gdn(li):
+        x, cache_l = gdn_half(x, lp, cache_l, cfg)
+        with jax.named_scope("decode_mlp"):
+            x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
+        return x, cache_l, hit
     q, k, v, gate = attn_qkv(
-        x, lp, cfg, li, functools.partial(_rope_rows, pos=pos), kv_slice
+        x, lp, cfg, li,
+        functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta),
+        kv_slice
     )
     scale = cfg.head_dim ** -0.5
     # scopes name the K/V traffic (cache write, scores, softmax, p @ v)
@@ -499,7 +531,7 @@ def _serving_forward(params, tok, pos, caches, cfg, *, kv_slice=None,
     hits = None
     for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
         paged_l = None
-        if paged is not None:
+        if paged is not None and not cfg.gdn(li):
             pt = paged[0][li]
             paged_l = (pt, pt.shape[1] * paged[1], paged[1])
         x, cl, hit = _serving_layer(
@@ -551,7 +583,9 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
     configuration with dropless expert layers gets one more ROW on
     ``toks``, (S + 1, n_inner): step by step, the experts that got a
     row, summed over those layers — the ``experts_hit`` counter rides
-    home in the fetch that brings the tokens."""
+    home in the fetch that brings the tokens. Where the layers hold a
+    share of their experts (``experts_held``) a second row follows:
+    the pairs that fell on held experts."""
 
     def step(carry, _):
         tok, pos, done, caches = carry
@@ -569,7 +603,9 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
     )
     if isinstance(toks, tuple):
         toks, hits = toks
-        toks = jnp.concatenate([toks, hits[:, None]], axis=1)
+        if hits.ndim == 1:  # one counter a step; a share of experts, two
+            hits = hits[:, None]
+        toks = jnp.concatenate([toks, hits], axis=1)
     return tok, pos, done, caches, toks.swapaxes(0, 1)
 
 
@@ -631,9 +667,10 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
                 use_kernel=True, paged=(pts, P),
             )
         with jax.named_scope("kv_page_gather"):
+            # (a recurrent layer's state is no page: it goes through)
             views = [
-                _paged_gather(cl, t, cfg.kv_heads, P)
-                for cl, t in zip(caches, pts)
+                cl if cfg.gdn(li) else _paged_gather(cl, t, cfg.kv_heads, P)
+                for li, (cl, t) in enumerate(zip(caches, pts))
             ]
         tok, pos, done, views, toks = _scan_body(
             params, tok, pos, done, views, cfg, eos_id, n_inner, keys,
@@ -641,8 +678,8 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         )
         with jax.named_scope("kv_page_scatter"):
             caches = [
-                _paged_scatter(cl, vw, t, P)
-                for cl, vw, t in zip(caches, views, pts)
+                vw if cfg.gdn(li) else _paged_scatter(cl, vw, t, P)
+                for li, (cl, vw, t) in enumerate(zip(caches, views, pts))
             ]
         return tok, pos, done, caches, toks
 
@@ -726,10 +763,13 @@ def _place_paged(cfg: TransformerConfig, P: int):
         # cache width's table row names: a page-block scatter
         rows = _layer_tables(cfg, pt_row)
         caches = [
+            # a recurrent layer's block of state goes over slot s's
+            {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
+             for kk in c} if cfg.gdn(li) else
             {kk: c[kk].at[row].set(
                 _rows_to_pages(kk, r[kk][0], P).astype(c[kk].dtype))
              for kk in c}
-            for c, r, row in zip(caches, ring, rows)
+            for li, (c, r, row) in enumerate(zip(caches, ring, rows))
         ]
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
@@ -775,6 +815,18 @@ def _refuse_switch_experts(cfg: TransformerConfig) -> None:
         )
 
 
+def _refuse_state_layers(cfg: TransformerConfig, what: str,
+                         why: str) -> None:
+    """``what`` is written for caches that are rows of K/V; refuse, by
+    mechanism, a configuration with recurrent layers."""
+    if cfg.state_layers:
+        raise ValueError(
+            f"{what}: this configuration has gated delta-rule layers, "
+            f"whose per-request state is one fixed block and no row a "
+            f"token; {why}"
+        )
+
+
 def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
                       *, eos_id: int | None = None,
                       quantize_kv: bool = False,
@@ -788,6 +840,10 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
     used only at ``temperature > 0``). ``quantize_kv=True`` serves an int8 ring
     cache (scale leaves shard like their K/V; the per-row write/score
     paths detect the layout)."""
+    _refuse_state_layers(
+        cfg, "make_serving_scan (the sharded tick)",
+        "its cache specs shard rows over dp and heads over tp. One "
+        "chip serves it through ServingScheduler")
     _check_ring_cfg(cfg)
     _check_sampling_params(temperature, top_k)
     _refuse_switch_experts(cfg)
@@ -861,12 +917,15 @@ def _extend_chunk_dense(cfg: TransformerConfig, C: int, Lmax: int):
     ``Lmax``) and stops at the last layer's output: the head runs in
     :func:`_finish_admit_dense`, on the one row a request reads. One
     program per ``(cfg, C, Lmax)``; ``offset`` is traced. Cache
-    donated: chunks stream through one arena."""
+    donated: chunks stream through one arena. ``valid`` (a traced
+    count; a configuration with recurrent layers passes it, no other)
+    is how many of the chunk's rows are the prompt's: the padding
+    after them must leave a recurrent layer's state alone."""
 
     @functools.partial(jax.jit, donate_argnums=(2,))
-    def serving_prefill_chunk(params, chunk, cache, offset):
+    def serving_prefill_chunk(params, chunk, cache, offset, valid=None):
         return _incremental_hidden(
-            params, chunk, cache, offset, cfg, prefill=False
+            params, chunk, cache, offset, cfg, prefill=False, valid=valid
         )
 
     return serving_prefill_chunk
@@ -883,13 +942,14 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     decode.py's fold discipline), so the head's weights are read once a
     request:
     (params, cache, last_hidden (1, C, d), true_len, last_off, key) ->
-    (tok0 (), ring leaves (1, W, ...))."""
-    widths = ring_widths(cfg)
+    (tok0 (), ring leaves (1, W, ...)). A recurrent layer's "ring" is
+    its state as the last chunk left it."""
+    widths = _row_widths(cfg)
 
     @jax.jit
     def serving_first_token(params, cache, last_hidden, true_len,
                             last_off, key):
-        ring = [_ring_from_cache(cl, true_len, W)
+        ring = [cl if W is None else _ring_from_cache(cl, true_len, W)
                 for cl, W in zip(cache, widths)]
         row = jax.lax.dynamic_slice_in_dim(
             last_hidden, true_len - 1 - last_off, 1, axis=1
@@ -1299,7 +1359,7 @@ class _Admitting:
                  wraps=()):
         self.req = req
         self.cache = cache
-        self.padded = padded  # (1, n_chunks * C) int32
+        self.padded = padded  # (1, n_chunks * C) int32, on the host
         self.n_chunks = n_chunks
         self.next_chunk = 0
         self.last_hidden = None  # (1, C, d) of the newest chunk
@@ -1420,11 +1480,27 @@ class ServingScheduler:
         # every layer's ring width, and the distinct ones ("kinds",
         # narrowest first): W is the narrowest, which is the whole
         # story for a configuration of sliding-window layers alone
-        widths = ring_widths(cfg)
+        widths = _row_widths(cfg)
         kinds, kind_of = _layer_kinds(cfg)
+        if not kinds:
+            raise ValueError(
+                "every layer keeps recurrent state and none K/V rows: "
+                "the scheduler's positions, pages and context budget "
+                "are those of an attention layer, and there is none"
+            )
         W = kinds[0]
         _check_sampling_params(temperature, top_k)
         _refuse_switch_experts(cfg)
+        if qos is not None or cache is not None:
+            _refuse_state_layers(
+                cfg, "page quotas (qos=) and the fleet prefix cache "
+                "(cache=)", "both count and move prefix pages, and a "
+                "prefix page is no use without the state at its "
+                "boundary, which is kept nowhere")
+        # a prompt's resident prefix pages let admission skip their
+        # prefill; the recurrent state at the page boundary exists
+        # nowhere, so with state layers nothing is shared or registered
+        self.shares_prefixes = not cfg.state_layers
         if slots < 1 or n_inner < 1:
             raise ValueError("slots and n_inner must be >= 1")
         if prompt_chunk > max_prompt:
@@ -1459,7 +1535,8 @@ class ServingScheduler:
         # window: a request that could write past it is refused at
         # submit (a ring that wide must never wrap)
         self._context = min(
-            (w for w, span in zip(widths, cfg.windows) if span is None),
+            (w for w, span in zip(widths, cfg.windows)
+             if span is None and w is not None),
             default=None,
         )
         # dropless expert layers: the tick counts the experts that got
@@ -1467,6 +1544,9 @@ class ServingScheduler:
         self._expert_layers = sum(
             cfg.dropless(li) for li in range(cfg.n_layers))
         self.experts_hit: float | None = None
+        # where the layers hold a share of their experts: the pairs
+        # (token, chosen expert) that fell on held experts, the same mean
+        self.pairs_local: float | None = None
         self.n_inner = int(n_inner)
         self.eos_id = eos_id
         self.C = int(prompt_chunk)
@@ -1538,7 +1618,8 @@ class ServingScheduler:
                     f"cache_pages names {len(cache_pages)} pools, the "
                     f"configuration has {len(kinds)} cache widths"
                 )
-            span_of = dict(zip(widths, cfg.windows))
+            span_of = {w: span for w, span in zip(widths, cfg.windows)
+                       if w is not None}
             n_windows = sum(span_of[w] is not None for w in kinds)
             self._kinds: list[_PageKind] = []
             for k, (Wk, n) in enumerate(zip(kinds, cache_pages)):
@@ -1558,8 +1639,9 @@ class ServingScheduler:
                     tuple(li for li, kk in enumerate(kind_of) if kk == k),
                 ))
             self._caches = _fresh_pages(
-                cfg, tuple(self._kinds[k].pool.n_pages for k in kind_of),
-                self.P, self.quantize_kv,
+                cfg, tuple(0 if k is None else self._kinds[k].pool.n_pages
+                           for k in kind_of),
+                self.P, self.quantize_kv, slots=self.S,
             )
             # the narrowest kind under the names the single-width code
             # (quotas, fleet cache, migration) reads: the pool, the
@@ -1577,7 +1659,8 @@ class ServingScheduler:
             self.pool = None
             self._kinds = []
             self._caches = _fresh_cache(
-                cfg, self.S, W if len(kinds) == 1 else widths,
+                cfg, self.S,
+                W if len(kinds) == 1 else tuple(w or 0 for w in widths),
                 self.quantize_kv,
             )
         # int8 Pallas kernel routing, resolved at construction against
@@ -1791,8 +1874,10 @@ class ServingScheduler:
         if self._expert_layers:
             # the tick's own counter, in the row under the tokens: the
             # mean, per expert layer and step, of experts with a row
-            self.experts_hit = float(host[self.S].sum()) / (
-                self.n_inner * self._expert_layers)
+            per = self.n_inner * self._expert_layers
+            self.experts_hit = float(host[self.S].sum()) / per
+            if self.cfg.experts_held is not None:
+                self.pairs_local = float(host[self.S + 1].sum()) / per
             host = host[:self.S]
         return host
 
@@ -1823,7 +1908,7 @@ class ServingScheduler:
     def _pt_rows(rows):
         """One slot's page-table row per kind, as the admission
         programs take it."""
-        return tuple(jnp.asarray(r, jnp.int32) for r in rows)
+        return tuple(np.array(r, np.int32) for r in rows)
 
     def step(self) -> list[Request]:
         """One scheduler tick; returns the requests retired in it
@@ -1856,6 +1941,9 @@ class ServingScheduler:
             # the layers have more than one)
             **({f"pages_{kd.name}": kd.pool.used for kd in self._kinds}
                if len(self._kinds) > 1 else {}),
+            # slots whose recurrent layers hold a request's state
+            **({"state_slots": self.S - n_free}
+               if self.cfg.state_layers else {}),
         ) as tick:
             with phase("serving.admit") as admit:
                 self._advance_admissions(retired)
@@ -1901,6 +1989,9 @@ class ServingScheduler:
                     if self._expert_layers:
                         harvest.set_metadata(
                             experts_hit=self.experts_hit)
+                    if self.pairs_local is not None:
+                        harvest.set_metadata(
+                            pairs_local=self.pairs_local)
         if obs is not None:
             obs.tick_done(self, retired, tick, admit, decode, harvest)
         if lit:
@@ -1998,6 +2089,9 @@ class ServingScheduler:
         (first token emitted), not finished. None otherwise."""
         if not self.paged or req.finished or not req.tokens:
             return None
+        _refuse_state_layers(
+            self.cfg, "KV-page migration", "an exported image is ring "
+            "views behind a page table, and the state block is in none")
         if len(self._kinds) > 1:
             raise ValueError(
                 "KV-page migration moves one ring view per layer "
@@ -2143,6 +2237,9 @@ class ServingScheduler:
         return state
 
     def _check_adopt_compat(self, state: dict) -> None:
+        _refuse_state_layers(
+            self.cfg, "adopt_page_state", "a migrated image is ring "
+            "views behind a page table, and the state block is in none")
         if len(self._kinds) > 1:
             raise ValueError(
                 "adopt_page_state: a migrated image is one ring view "
@@ -2456,11 +2553,11 @@ class ServingScheduler:
                 # (identical bytes to what this prefill would compute)
                 cache = self._seed(
                     cache, self._caches,
-                    self._pt_rows(admit_kw["pids"]), jnp.int32(base),
+                    self._pt_rows(admit_kw["pids"]), np.int32(base),
                 )
             self._slot_req[s] = req
             self._admitting[s] = _Admitting(
-                req, cache, jnp.asarray(padded), n_chunks, base=base,
+                req, cache, padded, n_chunks, base=base,
                 **admit_kw,
             )
         req.admitted_tick = self.tick_count
@@ -2526,7 +2623,7 @@ class ServingScheduler:
         digests: list[bytes] = []
         fetch: list[bytes] = []
         m = 0
-        if Tp <= W:
+        if Tp <= W and self.shares_prefixes:
             # within-window prompts: ring slot s == position s, so the
             # page content is determined by the page-aligned prefix —
             # the shareable case. (A wrapped prompt's pages hold late
@@ -2630,7 +2727,7 @@ class ServingScheduler:
         # pages fully covered by the prompt hold registerable prefix
         # content once prefill lands them (done at finish)
         n_cover = min(req.prompt.size // self.P, self.max_pages) \
-            if req.prompt.size <= self.W else 0
+            if digests else 0
         return m * self.P, {
             "pids": all_pids, "digests": tuple(digests),
             "n_cover": n_cover, "wraps": [n[2] for n in needs],
@@ -2859,11 +2956,16 @@ class ServingScheduler:
                        chunk=i, of=st.n_chunks,
                        rows_seen=_chunk_rows_seen(
                            off, self.C, self.Lmax, self.cfg.windows)):
-            chunk = jax.lax.dynamic_slice_in_dim(
-                st.padded, i * self.C, self.C, axis=1
-            )
+            # host arrays and numpy scalars go to the device with the
+            # program's own dispatch; an eager slice or ``jnp.int32``
+            # is a dispatch (and a transfer) of its own, each a stretch
+            # in which the device waits for the host
+            chunk = st.padded[:, i * self.C:(i + 1) * self.C]
+            # recurrent layers are told where the prompt ends in the chunk
+            valid = ((np.int32(min(self.C, st.req.prompt.size - off)),)
+                     if self.cfg.state_layers else ())
             st.last_hidden, st.cache = self._extend(
-                self.params, chunk, st.cache, jnp.int32(off),
+                self.params, chunk, st.cache, np.int32(off), *valid,
             )
         st.next_chunk += 1
         if self._obs is not None:
@@ -2880,8 +2982,8 @@ class ServingScheduler:
             rkey = (st.req.key if st.req.key is not None
                     else jax.random.key(st.req.id + 1))
             tok0, ring = self._finish(
-                self.params, st.cache, st.last_hidden, jnp.int32(Tp),
-                jnp.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
+                self.params, st.cache, st.last_hidden, np.int32(Tp),
+                np.int32(st.base + (st.n_chunks - 1) * self.C), rkey,
             )
             # _finish read the arena without donating it: recycle it
             self._release_arena(st)
@@ -2900,7 +3002,7 @@ class ServingScheduler:
                     self._caches, ring, self._tok, self._pos, self._done,
                     self._keys,
                     self._pt_rows(kd.pt_host[s] for kd in self._kinds),
-                    jnp.int32(s), tok0, jnp.int32(Tp), rkey,
+                    np.int32(s), tok0, np.int32(Tp), rkey,
                 )
                 # the prompt-covered pages now hold exactly the content
                 # their chained prefix digests describe — publish them for
@@ -2915,7 +3017,7 @@ class ServingScheduler:
                 (self._caches, self._tok, self._pos, self._done,
                  self._keys) = self._place(
                     self._caches, ring, self._tok, self._pos, self._done,
-                    self._keys, jnp.int32(s), tok0, jnp.int32(Tp), rkey,
+                    self._keys, np.int32(s), tok0, np.int32(Tp), rkey,
                 )
         # the one place admission blocks on the device: the request's
         # first token comes back before the tick's decode is dispatched

@@ -203,6 +203,15 @@ def _truncated(params, d: int):
 
 
 def _check_draft_layers(cfg: TransformerConfig, draft_layers):
+    if cfg.state_layers:
+        # the cache-consistency argument above is about ROWS: a rejected
+        # draft's K/V are overwritten before they are read. A recurrent
+        # state that has swallowed a rejected token cannot be rewound
+        raise ValueError(
+            "speculative decoding verifies drafts by overwriting cache "
+            "rows; this configuration has gated delta-rule layers, "
+            "whose state cannot be rolled back past a rejected draft"
+        )
     if draft_layers is None:
         return None
     d = int(draft_layers)

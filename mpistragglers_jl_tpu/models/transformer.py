@@ -155,6 +155,30 @@ class TransformerConfig:
     # positions a full-attention layer's serving cache holds: a request
     # whose prompt and answer could pass it is refused at submit
     max_context: int | None = None
+    # rotary: the base, and how many leading dims of a head rotate
+    # (None = the whole head; pairs (i, i + rope_dims / 2))
+    rope_theta: float = 10000.0
+    rope_dims: int | None = None
+    # per-layer token mixer: "attn" (softmax attention over cached K/V
+    # rows) or "gdn" (gated delta rule: a causal depthwise conv
+    # and a recurrence whose per-request state is one fixed block, no
+    # row a token; :func:`gdn_half`). None for the whole field =
+    # attention everywhere.
+    layer_mixers: tuple | None = None
+    gdn_key_heads: int = 0          # each serves value_heads / key_heads
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128          # head sizes of q/k and of v
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4               # taps of the depthwise causal conv
+    # the dropless router's scores: "sigmoid" with a selection bias, or
+    # "softmax" over all experts (no bias), the k largest renormalised
+    route_score: str = "sigmoid"
+    shared_gate: bool = False       # shared expert * sigmoid(h @ ws_sgate)
+    # this chip's share of an expert layer: experts [lo, hi) of
+    # ``n_experts`` are held here. The router scores all ``n_experts``;
+    # the pairs that fall on a held expert are computed, the rest are
+    # left out (what a further chip would add). None = all held.
+    experts_held: tuple | None = None
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -180,7 +204,7 @@ class TransformerConfig:
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
-        for name in ("layer_windows", "layer_experts"):
+        for name in ("layer_windows", "layer_experts", "layer_mixers"):
             pat = getattr(self, name)
             if pat is not None and len(pat) != self.n_layers:
                 raise ValueError(
@@ -201,6 +225,38 @@ class TransformerConfig:
             raise ValueError(
                 f"attn_window must be >= 1, got {self.attn_window}"
             )
+        if self.rope_dims is not None and not (
+            0 < self.rope_dims <= self.head_dim and self.rope_dims % 2 == 0
+        ):
+            raise ValueError(
+                f"rope_dims {self.rope_dims} must be even and at most "
+                f"head_dim {self.head_dim}"
+            )
+        if self.layer_mixers is not None:
+            if any(m not in ("attn", "gdn") for m in self.layer_mixers):
+                raise ValueError(
+                    f"layer_mixers holds 'attn' or 'gdn', got "
+                    f"{self.layer_mixers}"
+                )
+            if "gdn" in self.layer_mixers and (
+                self.gdn_key_heads < 1
+                or self.gdn_value_heads % self.gdn_key_heads != 0
+                or self.gdn_value_heads < 1 or self.gdn_conv < 1
+            ):
+                raise ValueError(
+                    "a gated delta-rule layer needs gdn_key_heads >= 1 "
+                    "dividing gdn_value_heads, got "
+                    f"{self.gdn_key_heads} and {self.gdn_value_heads}"
+                )
+        if self.route_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown route_score {self.route_score!r}")
+        if self.experts_held is not None:
+            lo, hi = self.experts_held
+            if not 0 <= lo < hi <= self.n_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} must be a range "
+                    f"inside [0, n_experts={self.n_experts})"
+                )
         if self.n_kv_heads is not None and (
             self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads != 0
         ):
@@ -230,6 +286,23 @@ class TransformerConfig:
     def rope_at(self, li: int) -> bool:
         return self.rope_full or self.windows[li] is not None
 
+    def gdn(self, li: int) -> bool:
+        """Is layer ``li``'s token mixer the gated delta rule?"""
+        return bool(self.layer_mixers is not None
+                    and self.layer_mixers[li] == "gdn")
+
+    @property
+    def state_layers(self) -> bool:
+        """Does any layer keep recurrent state (no row a token)?"""
+        return any(self.gdn(li) for li in range(self.n_layers))
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights are here (``experts_held``, or all)."""
+        if self.experts_held is None:
+            return self.n_experts
+        return self.experts_held[1] - self.experts_held[0]
+
     @property
     def plain_block(self) -> bool:
         """The default block in every layer (pre-LayerNorm, GELU MLP or
@@ -243,6 +316,7 @@ class TransformerConfig:
             and self.tie_head and self.d_head is None
             and not (self.qk_norm or self.attn_gate or self.post_norm)
             and self.emb_scale == 1.0 and self.rope_full
+            and self.rope_dims is None and not self.state_layers
         )
 
     def expert_width(self) -> int:
@@ -276,21 +350,25 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
 
     layers = []
     for li in range(cfg.n_layers):
-        layer = {
-            **norm("ln1"),
-            "wq": sd(D, H, Dh),
-            "wk": sd(D, Hkv, Dh),
-            "wv": sd(D, Hkv, Dh),
-            # NB float(): an np.float64 scalar would silently promote
-            # the param to f64 under jax_enable_x64
-            "wo": sd(H, Dh, D) / float(np.sqrt(cfg.n_layers)),
-            **norm("ln2"),
-        }
-        if cfg.attn_gate:
-            layer["wog"] = sd(D, H, Dh)
-        if cfg.qk_norm:
-            layer["qn_s"] = jnp.ones((Dh,), cfg.dtype)
-            layer["kn_s"] = jnp.ones((Dh,), cfg.dtype)
+        if cfg.gdn(li):
+            layer = {**norm("ln1"), **init_gdn_layer(rng, cfg),
+                     **norm("ln2")}
+        else:
+            layer = {
+                **norm("ln1"),
+                "wq": sd(D, H, Dh),
+                "wk": sd(D, Hkv, Dh),
+                "wv": sd(D, Hkv, Dh),
+                # NB float(): an np.float64 scalar would silently
+                # promote the param to f64 under jax_enable_x64
+                "wo": sd(H, Dh, D) / float(np.sqrt(cfg.n_layers)),
+                **norm("ln2"),
+            }
+            if cfg.attn_gate:
+                layer["wog"] = sd(D, H, Dh)
+            if cfg.qk_norm:
+                layer["qn_s"] = jnp.ones((Dh,), cfg.dtype)
+                layer["kn_s"] = jnp.ones((Dh,), cfg.dtype)
         if cfg.post_norm:
             layer.update(norm("ln1p"))
             layer.update(norm("ln2p"))
@@ -340,6 +418,8 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
     if cfg.plain_block:
         return
     why = []
+    if cfg.state_layers:
+        why.append("gated delta-rule layers (recurrent state)")
     if len(set(cfg.windows)) > 1:
         why.append("layers of more than one cache width")
     if cfg.layer_experts and any(cfg.layer_experts):
@@ -434,11 +514,12 @@ def _norm(x, p, name, cfg):
     return _ln(x, p[name + "_s"], p[name + "_b"], cfg.norm_eps)
 
 
-def _rope(x, pos):
-    """Rotary embedding; pos carries GLOBAL token positions (L,)."""
+def _rope(x, pos, theta: float = 10000.0):
+    """Rotary embedding at base ``theta``; pos carries GLOBAL token
+    positions (L,)."""
     B, L, H, Dh = x.shape
     half = Dh // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (L, half)
     cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
@@ -446,6 +527,14 @@ def _rope(x, pos):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     )
+
+
+def _rope_leading(rope, t, dims: int | None):
+    """``rope`` on the ``dims`` leading dims of each head of ``t`` (None:
+    the whole head); the rest pass through."""
+    if dims is None or dims >= t.shape[-1]:
+        return rope(t)
+    return jnp.concatenate([rope(t[..., :dims]), t[..., dims:]], axis=-1)
 
 
 # The block, written once. Every forward (dense, sharded, incremental,
@@ -459,7 +548,8 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     projections, the q/k norms, rotary. ``rope(t)`` rotates a
     (B, L, H, Dh) tensor at the caller's positions. Returns
     ``(q, k, v, gate)``; ``gate`` (None without ``attn_gate``) goes to
-    :func:`attn_merge`. ``kv_slice`` post-selects kv heads from
+    :func:`attn_merge`. Of a head, ``cfg.rope_dims`` leading dims rotate
+    (None: all). ``kv_slice`` post-selects kv heads from
     tp-replicated K/V projections (the GQA kv_heads < tp case — see
     :func:`_kv_tp_sharded`)."""
     h = _norm(x, lp, "ln1", cfg)
@@ -472,7 +562,8 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
         q = _rms(q, lp["qn_s"], cfg.norm_eps)
         k = _rms(k, lp["kn_s"], cfg.norm_eps)
     if cfg.rope_at(li):
-        q, k = rope(q), rope(k)
+        q = _rope_leading(rope, q, cfg.rope_dims)
+        k = _rope_leading(rope, k, cfg.rope_dims)
     gate = None
     if cfg.attn_gate:
         gate = jnp.einsum("bld,dhk->blhk", h, lp["wog"])
@@ -491,6 +582,183 @@ def attn_merge(x, o, gate, lp, cfg, tp_psum=False):
     if cfg.post_norm:
         a = _norm(a, lp, "ln1p", cfg)
     return x + a
+
+
+# The gated delta-rule half, written once like the attention half: the
+# dense forward (the whole sequence from a zero state), a prefill chunk
+# and a decode step all call :func:`gdn_half` and differ in the state
+# they hand it. A layer's state is a fixed block a request: ``S``
+# (value heads, key dim, value dim) float32 and the last ``conv - 1``
+# rows that went into the depthwise conv. No leaf has a row a token.
+
+GDN_SUBCHUNK = 64  # rows the chunked form of the recurrence takes at once
+_HI = jax.lax.Precision.HIGHEST
+
+
+def init_gdn_layer(rng: np.random.Generator, cfg: TransformerConfig) -> dict:
+    """Leaves of one gated delta-rule mixer: ``gdn_wqkvz`` (D, 2 *
+    key + 2 * value width, laid out ``[q | k | v | z]``), ``gdn_wba``
+    (D, 2 * value heads, ``[b | a]``), the depthwise conv taps
+    ``gdn_conv_w`` (taps, q + k + v channels), ``gdn_A_log`` and
+    ``gdn_dt_bias`` (float32, a value head each, drawn as the published
+    initialisation does: A uniform in (0, 16], dt log-uniform in
+    [0.001, 0.1] with ``dt_bias`` its inverse softplus), the scale of
+    the norm over a value head and the out-projection."""
+    D = cfg.d_model
+    Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    kw, vw = Hk * cfg.gdn_key_dim, Hv * cfg.gdn_value_dim
+    sd = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[0]), cfg.dtype
+    )
+    A = 16.0 * (1.0 - rng.random(Hv))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), Hv))
+    return {
+        "gdn_wqkvz": sd(D, 2 * kw + 2 * vw),
+        "gdn_wba": sd(D, 2 * Hv),
+        "gdn_conv_w": jnp.asarray(
+            rng.uniform(-0.5, 0.5, (cfg.gdn_conv, 2 * kw + vw)), cfg.dtype),
+        "gdn_A_log": jnp.asarray(np.log(A), jnp.float32),
+        "gdn_dt_bias": jnp.asarray(dt + np.log(-np.expm1(-dt)),
+                                   jnp.float32),
+        "gdn_norm_s": jnp.ones((cfg.gdn_value_dim,), cfg.dtype),
+        "gdn_wout": sd(vw, D) / float(np.sqrt(cfg.n_layers)),
+    }
+
+
+def gdn_zero_state(cfg: TransformerConfig, B: int) -> dict:
+    """The state of ``B`` requests that have seen no token."""
+    Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    chans = 2 * Hk * cfg.gdn_key_dim + Hv * cfg.gdn_value_dim
+    return {
+        "S": jnp.zeros((B, Hv, cfg.gdn_key_dim, cfg.gdn_value_dim),
+                       jnp.float32),
+        "conv": jnp.zeros((B, cfg.gdn_conv - 1, chans), cfg.dtype),
+    }
+
+
+def _delta_rule_step(q, k, v, g, beta, S):
+    """One token of the recurrence, float32: q, k (B, H, Dk), v
+    (B, H, Dv), g, beta (B, H), S (B, H, Dk, Dv)."""
+    S = S * jnp.exp(g)[..., None, None]
+    mem = (S * k[..., None]).sum(axis=-2)            # S'^T k
+    delta = (v - mem) * beta[..., None]
+    S = S + k[..., None] * delta[..., None, :]
+    return (S * q[..., None]).sum(axis=-2), S        # S^T q
+
+
+def _delta_rule_chunks(q, k, v, g, beta, S):
+    """The same recurrence over T rows, ``GDN_SUBCHUNK`` at a time:
+    q, k (B, T, H, Dk), v (B, T, H, Dv), g, beta (B, T, H), S
+    (B, H, Dk, Dv), all float32. Inside a sub-chunk with G the running
+    sum of g, the rows' updates u solve the unit lower-triangular
+    system ``(I + A) u = beta (v - exp(G) k S0)``, ``A[t, s] = beta_t
+    exp(G_t - G_s) k_t.k_s`` (s < t); then ``o = exp(G) q S0 +
+    [exp(G_t - G_s) q_t.k_s]_{s <= t} u`` and ``S = exp(G_c) S0 + (k
+    exp(G_c - G))^T u``. A scan over the sub-chunks carries S. A row
+    with g = 0 and beta = 0 leaves S as it was (padding)."""
+    B, T, H, Dk = q.shape
+    c = min(GDN_SUBCHUNK, T)
+    pad = -T % c
+    if pad:
+        padt = lambda a: jnp.pad(
+            a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        q, k, v, g, beta = (padt(a) for a in (q, k, v, g, beta))
+    n = (T + pad) // c
+    def rows(a):  # (B, T, H[, D]) -> (n, B, H, c[, D]): scanned axis first
+        a = jnp.moveaxis(a.reshape((B, n, c) + a.shape[2:]), 1, 0)
+        return jnp.swapaxes(a, 2, 3)
+
+    q, k, v, g, beta = (rows(a) for a in (q, k, v, g, beta))
+    tri = jnp.tril(jnp.ones((c, c), bool))
+
+    def sub(S, xs):
+        q, k, v, g, beta = xs  # (B, H, c, D*), (B, H, c)
+        G = jnp.cumsum(g, axis=-1)
+        # exp(G_t - G_s) on and below the diagonal, 0 above: masked
+        # before the exponential (above it the difference is positive)
+        decay = jnp.exp(jnp.where(
+            tri, G[..., :, None] - G[..., None, :], -jnp.inf))
+        kk = jnp.einsum("bhtd,bhsd->bhts", k, k, precision=_HI)
+        eG = jnp.exp(G)[..., None]
+        rhs = jnp.concatenate([v, k * eG], axis=-1) * beta[..., None]
+        # I + A: the solve takes the diagonal as ones and reads the
+        # strict lower triangle alone
+        sol = jax.scipy.linalg.solve_triangular(
+            kk * decay * beta[..., None], rhs, lower=True,
+            unit_diagonal=True)
+        u = sol[..., :v.shape[-1]] - jnp.einsum(
+            "bhtk,bhkv->bhtv", sol[..., v.shape[-1]:], S, precision=_HI)
+        qk = jnp.einsum("bhtd,bhsd->bhts", q, k, precision=_HI) * decay
+        o = eG * jnp.einsum("bhtk,bhkv->bhtv", q, S, precision=_HI)
+        o = o + jnp.einsum("bhts,bhsv->bhtv", qk, u, precision=_HI)
+        last = jnp.exp(G[..., -1:] - G)[..., None]
+        S = S * jnp.exp(G[..., -1])[..., None, None] + jnp.einsum(
+            "bhtk,bhtv->bhkv", k * last, u, precision=_HI)
+        return S, o
+
+    S, o = jax.lax.scan(sub, S, (q, k, v, g, beta))
+    o = jnp.moveaxis(jnp.swapaxes(o, 2, 3), 0, 1).reshape(B, n * c, H, -1)
+    return o[:, :T], S
+
+
+def gdn_half(x, lp, state, cfg, valid=None):
+    """Layer's gated delta-rule half on (B, T, D) from ``state``
+    (:func:`gdn_zero_state`'s leaves): norm, projections, the causal
+    depthwise conv, the recurrence, the gated norm over each value
+    head, the out-projection, the residual. Returns ``(x, state)``.
+    ``valid`` (a traced count, None = T) says how many leading rows are
+    real: the rows after them are a padded prompt's tail and leave
+    ``S`` and the conv rows as the last real row left them."""
+    B, T, _ = x.shape
+    Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    Dk, Dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    kw, vw = Hk * Dk, Hv * Dv
+    h = _norm(x, lp, "ln1", cfg)
+    with jax.named_scope("gdn_proj"):
+        qkvz = jnp.einsum("bld,dc->blc", h, lp["gdn_wqkvz"])
+        qkv, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
+        ba = jnp.einsum("bld,dc->blc", h, lp["gdn_wba"],
+                        preferred_element_type=jnp.float32)
+    with jax.named_scope("gdn_conv"):
+        taps = cfg.gdn_conv
+        seen = jnp.concatenate(
+            [state["conv"], qkv.astype(state["conv"].dtype)], axis=1)
+        # the rows the next call's conv reaches back to: the last
+        # taps - 1 that went in, padding not counted
+        tail = seen[:, T:] if valid is None else (
+            jax.lax.dynamic_slice_in_dim(seen, valid, taps - 1, axis=1))
+        w = lp["gdn_conv_w"].astype(jnp.float32)
+        seen = seen.astype(jnp.float32)
+        y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
+        y = jax.nn.silu(y)
+    with jax.named_scope("gdn_rule"):
+        q = y[..., :kw].reshape(B, T, Hk, Dk)
+        k = y[..., kw:2 * kw].reshape(B, T, Hk, Dk)
+        v = y[..., 2 * kw:].reshape(B, T, Hv, Dv)
+        l2 = lambda a: a * jax.lax.rsqrt(
+            (a * a).sum(-1, keepdims=True) + 1e-6)
+        q, k = l2(q) * Dk ** -0.5, l2(k)
+        if Hv != Hk:  # key head j serves value heads [j * r, (j + 1) * r)
+            q = jnp.repeat(q, Hv // Hk, axis=2)
+            k = jnp.repeat(k, Hv // Hk, axis=2)
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(
+            ba[..., Hv:] + lp["gdn_dt_bias"])
+        if valid is not None:
+            real = (jnp.arange(T) < valid)[None, :, None]
+            g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+        if T == 1:
+            o, S = _delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], state["S"])
+            o = o[:, None]
+        else:
+            o, S = _delta_rule_chunks(q, k, v, g, beta, state["S"])
+    with jax.named_scope("gdn_out"):
+        o = _rms(o, lp["gdn_norm_s"].astype(jnp.float32), cfg.norm_eps)
+        o = o * jax.nn.silu(z.astype(jnp.float32)).reshape(B, T, Hv, Dv)
+        a = jnp.einsum("blc,cd->bld", o.reshape(B, T, vw).astype(x.dtype),
+                       lp["gdn_wout"])
+    return x + a, {"S": S, "conv": tail}
 
 
 def _mlp(x, lp):
@@ -579,6 +847,16 @@ def _local_attention(cfg: TransformerConfig):
     )
 
 
+def _mixer_dense(x, lp, cfg, li: int, rope, impl):
+    """Layer ``li``'s token mixer over a whole sequence: attention, or
+    the gated delta rule from a zero state."""
+    if cfg.gdn(li):
+        return gdn_half(x, lp, gdn_zero_state(cfg, x.shape[0]), cfg)[0]
+    q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
+    o = impl(q, k, v, causal=True, window=cfg.windows[li])
+    return attn_merge(x, o, gate, lp, cfg)
+
+
 def forward_dense(params: dict, tokens: jax.Array, cfg: TransformerConfig):
     """Unsharded oracle forward: full attention, no collectives. The
     sharded program must agree with this bit-for-float."""
@@ -589,13 +867,11 @@ def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
     """Dense forward returning (logits, summed MoE aux loss)."""
     pos = jnp.arange(tokens.shape[1])
     x = embed(params, tokens, cfg)
-    rope = partial(_rope, pos=pos)
+    rope = partial(_rope, pos=pos, theta=cfg.rope_theta)
     impl = resolve_attention_impl(cfg.attn_impl)
 
     def one_layer(x, lp, li):
-        q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
-        o = impl(q, k, v, causal=True, window=cfg.windows[li])
-        x = attn_merge(x, o, gate, lp, cfg)
+        x = _mixer_dense(x, lp, cfg, li, rope, impl)
         x, a, _ = ffn_half(x, lp, cfg, li)
         return x, a
 

@@ -44,16 +44,18 @@ def _compiled_text(fn, *args):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (query heads, K/V heads, table entries, pool pages): StarCoder2-3B's
-# group of 12 on a 64-entry table, Trinity-Mini's group of 8 on its
-# window table (32) and its full-attention table (68)
-CELLS = [(24, 2, 64, 1025), (32, 4, 32, 513), (32, 4, 68, 1089)]
+# (query heads, K/V heads, head size, table entries, pool pages):
+# StarCoder2-3B's group of 12 on a 64-entry table, Trinity-Mini's group
+# of 8 on its window table (32) and its full-attention table (68),
+# Qwen3-Next's group of 8 at a head size of 256 on its one table
+CELLS = [(24, 2, 128, 64, 1025), (32, 4, 128, 32, 513),
+         (32, 4, 128, 68, 1089), (16, 2, 256, 68, 1089)]
 
 
-@pytest.mark.parametrize("H,Hkv,max_pages,n_pages", CELLS)
-def test_paged_kernel_compiles_for_the_v5e(one_chip, H, Hkv, max_pages,
+@pytest.mark.parametrize("H,Hkv,D,max_pages,n_pages", CELLS)
+def test_paged_kernel_compiles_for_the_v5e(one_chip, H, Hkv, D, max_pages,
                                            n_pages):
-    B, D, P = 16, 128, 64
+    B, P = 16, 64
     sds = functools.partial(_sds, one_chip)
 
     def call(q, k, ks, v, vs, pos, pt):
@@ -95,3 +97,20 @@ def test_ring_kernel_compiles_at_a_group_of_12(one_chip):
         sds((B,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip):
+    """A 256-row chunk of 32 value heads of 128 x 128 (the unit
+    triangular solves and the products at Precision.HIGHEST, the scan
+    over 64-row sub-chunks), and the single-token step for 16 slots."""
+    from mpistragglers_jl_tpu.models import transformer as tr
+
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    T, H, Dk, Dv = 256, 32, 128, 128
+    text = _compiled_text(
+        tr._delta_rule_chunks, f32(1, T, H, Dk), f32(1, T, H, Dk),
+        f32(1, T, H, Dv), f32(1, T, H), f32(1, T, H), f32(1, H, Dk, Dv))
+    assert "while" in text  # the scan that carries S
+    _compiled_text(
+        tr._delta_rule_step, f32(16, H, Dk), f32(16, H, Dk), f32(16, H, Dv),
+        f32(16, H), f32(16, H), f32(16, H, Dk, Dv))

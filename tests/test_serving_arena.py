@@ -314,3 +314,34 @@ def test_a_warm_admission_calls_no_eager_zeros(paged, quantize_kv,
     # two slots: the second prompt is in prefill while the first still
     # is, so one more arena is made, by the program and not leaf by leaf
     assert kinds.count("reused") >= 2 and kinds.count("new") <= 1
+
+
+@QUANT
+@KINDS
+def test_a_warm_admission_dispatches_no_eager_slice_or_scalar(
+        paged, quantize_kv, monkeypatch):
+    """A chunk and the scalars of the admission programs go to the
+    device with those programs' own dispatch (host slices, numpy
+    scalars): no eager ``dynamic_slice`` or ``jnp.int32`` before them,
+    each of which is a dispatch and a transfer of its own; and the
+    scalars' types trace no program a second time."""
+    sched = _sched(paged, quantize_kv, slots=2)
+    alone = sched.submit(_prompt(9, 21), max_new=3)
+    sched.run()  # every program has been traced
+    sizes = {name: getattr(sched, name)._cache_size()
+             for name in ("_extend", "_finish")}
+
+    def refuse(*a, **kw):
+        raise AssertionError("an eager device operation in an admission")
+
+    monkeypatch.setattr(serving.jax.lax, "dynamic_slice_in_dim", refuse)
+    monkeypatch.setattr(serving.jnp, "int32", refuse)
+    again = sched.submit(_prompt(9, 21), max_new=3)
+    seen = []
+    while not again.finished:
+        sched.step()
+        seen += [type(st.padded) for st in sched._admitting.values()]
+    assert seen and all(t is np.ndarray for t in seen)
+    assert again.tokens == alone.tokens
+    assert sizes == {name: getattr(sched, name)._cache_size()
+                     for name in sizes}
