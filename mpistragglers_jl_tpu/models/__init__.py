@@ -76,11 +76,13 @@ def clear_cached_programs() -> None:
 
     for cache in (
         decode._dense_runner,
+        decode._grouped_layer,
         speculative._spec_runner,
         serving._fresh_arena,
         serving._serving_scan_dense,
         serving._serving_scan_paged,
         serving._extend_chunk_dense,
+        serving._extend_chunk_group,
         serving._finish_admit_dense,
         serving._place_dense,
         serving._seed_admit_paged,

@@ -588,6 +588,70 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
     return x, new_cache
 
 
+@functools.lru_cache(maxsize=64)
+def _grouped_layer(cfg: TransformerConfig, li: int):
+    """Layer ``li`` of :func:`_grouped_hidden` as a program of its own:
+    (x (n, T, d), the layer's weights, the n requests' own stores for
+    this layer, offsets (n,), valid) -> (x, the stores). What is per
+    token runs once on all rows of ``x``; row ``i``'s K/V is written
+    into store ``i`` at ``offsets[i]`` and its queries walk that store
+    alone, with the operations a lone chunk runs (:func:`_cache_write`,
+    :func:`_cached_attention`); a recurrent layer's state is batched on
+    its leading axis as it is. Jitted on its own so that a program
+    traces one layer of a kind (``cfg.layer_like``) and calls it for
+    the others: thirty layers of four requests each traced one by one
+    cost a process seconds before its first tick."""
+
+    @jax.jit
+    def grouped_layer(x, lp, rows, offsets, valid):
+        n, T = x.shape[:2]
+        if cfg.gdn(li):
+            state = {kk: jnp.concatenate([r[kk] for r in rows])
+                     for kk in rows[0]}
+            x, state = gdn_half(x, lp, state, cfg, valid)
+            rows = [{kk: a[i:i + 1] for kk, a in state.items()}
+                    for i in range(n)]
+        else:
+            qpos = offsets[:, None] + jnp.arange(T)  # (n, T)
+
+            def rope(t):  # each request's rows at its own positions
+                return jax.vmap(lambda a, pos: _rope(
+                    a[None], pos, cfg.rope_theta)[0])(t, qpos)
+
+            q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
+            rows = [_cache_write(r, k[i:i + 1], v[i:i + 1], offsets[i])
+                    for i, r in enumerate(rows)]
+            o = jnp.concatenate([
+                _cached_attention(q[i:i + 1], r, qpos[i],
+                                  cfg.head_dim ** -0.5, cfg.windows[li])
+                for i, r in enumerate(rows)
+            ])
+            x = attn_merge(x, o, gate, lp, cfg)
+        x, _, _ = ffn_half(x, lp, cfg, li)
+        return x, rows
+
+    return grouped_layer
+
+
+def _grouped_hidden(params, tokens, caches, offsets, cfg, valid=None):
+    """The chunks of ``n`` requests as ONE forward, so that every
+    weight (every expert) is read once for all of them: ``tokens``
+    (n, T), ``caches`` the n requests' own positional caches of one row
+    each, ``offsets`` (n,) where each chunk starts in its cache,
+    ``valid`` (n,) as in :func:`_incremental_hidden` (None = all).
+    Returns (hidden (n, T, d), the n caches). The rows of different
+    requests meet only in products that are row-wise
+    (:func:`_grouped_layer`)."""
+    x = embed(params, tokens, cfg)
+    caches = [list(c) for c in caches]
+    for li, lp in enumerate(params["layers"]):
+        x, rows = _grouped_layer(cfg, cfg.layer_like(li))(
+            x, lp, [c[li] for c in caches], offsets, valid)
+        for c, r in zip(caches, rows):
+            c[li] = r
+    return x, caches
+
+
 def _incremental_forward(params, tokens, cache, offset, cfg, **kw):
     """Chunk forward at global ``offset``; returns (logits (B, T, V),
     cache): the head on every row of :func:`_incremental_hidden`, whose

@@ -74,7 +74,17 @@ Design (TPU-first):
   ONE row of the last chunk's hidden state, the prompt's last
   position: a chunk program stops at the last layer's output, so the
   head's weights are read once a request. Decode stall per tick is
-  bounded by one chunk, not one prompt.
+  bounded by one chunk a request, not one prompt. The chunks that are
+  due in one tick (every admitting slot's next, and the first of each
+  request the tick admits) run as ONE program over their concatenated
+  rows where they can, up to ``_chunk_group_cap`` of them a program
+  (``_extend_chunk_group``, ``decode._grouped_hidden``): every weight,
+  every expert, is read once for all of them, while each request's
+  K/V goes into its own arena at its own offset and its queries walk
+  that arena alone. The schedule is untouched: the same chunks run
+  between the same ticks, and a request's admission still ends (first
+  token, pages registered) before the next request is planned wherever
+  its end can change that plan (``_due_at_once``).
 * **EOS retirement + slot reuse.** Rows that emit ``eos_id`` keep
   emitting it on-device (static shapes; ``_eos_clamp``); the host
   strips the tail, retires the request (EOS or its ``max_new`` budget),
@@ -121,6 +131,7 @@ from .decode import (
     _decode_kernel_interpreted,
     _chunk_rows_seen,
     _eos_clamp,
+    _grouped_hidden,
     _incremental_hidden,
     _is_quantized,
     _kernel_possible,
@@ -931,6 +942,56 @@ def _extend_chunk_dense(cfg: TransformerConfig, C: int, Lmax: int):
     return serving_prefill_chunk
 
 
+# Rows that must share one read of a weight before its product runs at
+# the chip's arithmetic pace and no longer at its memory's: bfloat16
+# operations a byte of HBM at the peaks (v5e: 197 TFLOP/s over 819 GB/s
+# = 240).
+_RIDGE_ROWS = 256
+
+
+def _chunk_group_cap(cfg: TransformerConfig, C: int, slots: int) -> int:
+    """How many requests' chunks one prefill program takes (1: each
+    chunk is a program of its own, as it always was). A chunk gives a
+    dense layer's weights ``C`` rows a read and an expert's ``C *
+    experts_per_token / n_experts`` (Trinity-Mini 16, Qwen3-Next 5):
+    below ``_RIDGE_ROWS`` the chunk waits for the weights' bytes, and a
+    program over the rows of several requests reads them once for all.
+    At or above it (StarCoder2-3B's dense chunk of 256) width buys
+    nothing; measured, it cost a fifth more set-up for +0.3% (PERF.md
+    section 6, PR 33). One size beside 1, because every program a
+    scheduler holds costs 1.5 to 2.6 s of set-up whether a tick ever
+    needs it or not: a group that does not fill it is padded
+    (:meth:`ServingScheduler._run_chunk_group`), which a chunk that
+    waits for bytes hardly feels. At most 4: a backlog of mixed lengths
+    has 2 to 4 chunks due in nine of ten of 16 slots' ticks."""
+    share = min(
+        (cfg.experts_per_token / cfg.n_experts if cfg.dropless(li) else 1.0
+         for li in range(cfg.n_layers)), default=1.0)
+    return 1 if C * share >= _RIDGE_ROWS else min(4, slots)
+
+
+@functools.lru_cache(maxsize=32)
+def _extend_chunk_group(cfg: TransformerConfig, C: int, Lmax: int, n: int):
+    """:func:`_extend_chunk_dense` for the chunks of ``n`` > 1 requests
+    in one program (``decode._grouped_hidden``): (params, chunks (n, C),
+    the n requests' arenas, offsets (n,)[, valid (n,)]) -> (n hidden
+    states (1, C, d), the n arenas). Each weight is read once for all n
+    chunks; each request's K/V goes into its own arena at its own
+    offset and its queries walk that arena alone. All arenas donated.
+    One program per ``(cfg, C, Lmax, n)``, named
+    ``serving_prefill_chunk_x<n>``; a scheduler has the one of its
+    ``_chunk_group_cap``."""
+
+    def chunk_group(params, chunks, caches, offsets, valid=None):
+        x, caches = _grouped_hidden(params, chunks, caches, offsets, cfg,
+                                    valid)
+        return tuple(x[i:i + 1] for i in range(n)), tuple(caches)
+
+    chunk_group.__name__ = chunk_group.__qualname__ = (
+        f"serving_prefill_chunk_x{n}")
+    return jax.jit(chunk_group, donate_argnums=(2,))
+
+
 @functools.lru_cache(maxsize=32)
 def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
                         temperature: float = 0.0,
@@ -1382,8 +1443,10 @@ class ServingScheduler:
 
     Each ``step()`` tick: (1) advance every admitting request by one
     prefill chunk, installing finished ones into their slot; (2) admit
-    queued requests into free slots; (3) run ``n_inner`` decode steps
-    for all slots in one device program; (4) harvest tokens, retire
+    queued requests into free slots, each running its first chunk
+    (chunks due together share their programs); (3) run ``n_inner``
+    decode steps for all slots in one device program; (4) harvest
+    tokens, retire
     rows that emitted EOS or exhausted their budget, free their slots.
     Greedy by default; ``temperature > 0`` (optionally ``top_k``)
     samples each slot with its request's own key (``submit(...,
@@ -1590,6 +1653,10 @@ class ServingScheduler:
         # one is made only while this list is empty, so list plus live
         # _Admitting.cache never exceed the slots
         self._free_arenas: list[list[dict]] = []
+        # slots whose next chunk is due and not yet dispatched: the
+        # chunks of one tick share their programs (_run_pending)
+        self._pending: list[int] = []
+        self._tick_chunks = self._tick_chunk_programs = 0
         self.tick_count = 0
         # device-resident row state + batched ring cache arena
         self.temperature = float(temperature)
@@ -1694,6 +1761,15 @@ class ServingScheduler:
                 self.use_kernel,
             )
         self._extend = _extend_chunk_dense(cfg, self.C, self.Lmax)
+        # the same chunk for up to ``_group`` requests in one program
+        # (None: a chunk of this model gains nothing from width); its
+        # padding writes into scratch arenas that are nobody's
+        self._group = _chunk_group_cap(cfg, self.C, self.S)
+        self._extend_group = (
+            _extend_chunk_group(cfg, self.C, self.Lmax, self._group)
+            if self._group > 1 else None
+        )
+        self._scratch_arenas: list[list[dict]] | None = None
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -1925,7 +2001,10 @@ class ServingScheduler:
         flight = self._flight
         lit = self._stamp_ticks  # obs, flight, OR exporter attached
         phase = _LitPhase if lit else _annotate
+        if self._extend_group is not None and self._scratch_arenas is None:
+            self._warm_chunk_group()
         self.tick_count += 1
+        self._tick_chunks = self._tick_chunk_programs = 0
         retired: list[Request] = []
         decode = harvest = None
         n_admitting = len(self._admitting)
@@ -1948,6 +2027,11 @@ class ServingScheduler:
             with phase("serving.admit") as admit:
                 self._advance_admissions(retired)
                 self._admit_from_queue(retired)
+                self._run_pending(retired)
+            # the chunks this tick ran (the admitting slots' and the
+            # first of each request it admitted), in how many programs
+            tick.set_metadata(chunks=self._tick_chunks,
+                              chunk_programs=self._tick_chunk_programs)
             decoding = [
                 s for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._admitting
@@ -2569,8 +2653,13 @@ class ServingScheduler:
                 tick=self.tick_count,
             )
         # first chunk runs this very tick (short prompts admit in
-        # one tick and decode from the next)
-        self._advance_admission(s, retired)
+        # one tick and decode from the next), in one program with the
+        # chunks already due. A one-chunk prompt ends its admission
+        # here and now where the next request's plan may count on what
+        # it registers or frees
+        self._pending.append(s)
+        if self._due_at_once(self._admitting[s]):
+            self._run_pending(retired)
 
     # -- paged admission planning --------------------------------------
 
@@ -2942,41 +3031,134 @@ class ServingScheduler:
             for li, cl in zip(kd.layers, moved):
                 self._caches[li] = cl
 
-    def _advance_admissions(self, retired: list[Request]) -> None:
-        for s in list(self._admitting):
-            self._advance_admission(s, retired)
+    def _warm_chunk_group(self) -> None:
+        """Compile (or load) the grouped prefill program before the
+        first tick returns, with one run on throw-away arenas: whether
+        and when a tick has two chunks due is the traffic's, and the
+        tick that is first to must not pay the compile. The lone
+        chunk's program needs no such run: the first request of any
+        traffic meets it. Two arenas fewer than the program takes stay
+        as its scratch (a group is at least two)."""
+        n = self._group
+        valid = ((np.zeros((n,), np.int32),)
+                 if self.cfg.state_layers else ())
+        _, arenas = self._extend_group(
+            self.params, np.zeros((n, self.C), np.int32),
+            tuple(_fresh_cache(self.cfg, 1, self.Lmax, self.quantize_kv)
+                  for _ in range(n)),
+            np.zeros((n,), np.int32), *valid)
+        self._scratch_arenas = list(arenas[2:])
 
-    def _advance_admission(self, s: int,
-                           retired: list[Request]) -> None:
-        st = self._admitting[s]
-        i = st.next_chunk
-        rid = st.req.id
-        off = st.base + i * self.C
-        with _annotate("serving.prefill_chunk", req=rid, slot=s,
-                       chunk=i, of=st.n_chunks,
-                       rows_seen=_chunk_rows_seen(
-                           off, self.C, self.Lmax, self.cfg.windows)):
+    def _advance_admissions(self, retired: list[Request]) -> None:
+        """Every admitting slot's next chunk is due. They wait for the
+        chunks of the requests this tick admits (:meth:`_run_pending`)
+        unless one of them cannot (:meth:`_due_at_once`): then all run
+        now, and the requests whose last chunk that was get their first
+        token before the queue is looked at, as when each chunk was a
+        program of its own."""
+        self._pending.extend(self._admitting)
+        if any(map(self._due_at_once, self._admitting.values())):
+            self._run_pending(retired)
+
+    def _due_at_once(self, st: _Admitting) -> bool:
+        """Can this request's next chunk not wait for the chunks the
+        rest of the tick brings? Where chunks share no program there is
+        nothing to wait for (and the device would wait for the host's
+        planning). Else only a LAST chunk whose admission must end
+        before the next request is planned, because its end changes
+        what a plan reads: it registers prefix pages the next request
+        may share, or it may retire at once (``max_new`` 1, or an EOS
+        as first token) and give back a slot and pages."""
+        if self._group == 1:
+            return True
+        return st.next_chunk + 1 == st.n_chunks and bool(
+            (self.paged and self.shares_prefixes and st.n_cover)
+            or st.req.max_new == 1 or self.eos_id is not None
+        )
+
+    def _run_pending(self, retired: list[Request]) -> None:
+        """Dispatch the chunks that are due, in the order the slots
+        came, as many a program as the grouped program takes (a lone
+        one left over, or every one where there is no grouped program,
+        in the program of one chunk); after each program the requests
+        whose last chunk it held are finished, in the same order."""
+        slots, self._pending = self._pending, []
+        for at in range(0, len(slots), self._group):
+            members = slots[at:at + self._group]
+            self._run_chunk_group(members)
+            for s in members:
+                st = self._admitting[s]
+                if st.next_chunk == st.n_chunks:
+                    self._finish_admission(s, retired)
+
+    def _run_chunk_group(self, slots: list[int]) -> None:
+        """One prefill program for the next chunk of each of ``slots``:
+        the program of one chunk for one, the grouped program for more,
+        padded to its size with chunks of no request (no valid row,
+        scratch arenas): a chunk short of the ridge waits for the
+        weights' bytes, which the padding does not add to."""
+        C, n = self.C, len(slots)
+        sts = [self._admitting[s] for s in slots]
+        offs = [st.base + st.next_chunk * C for st in sts]
+        each = lambda values: (values[0] if n == 1
+                               else ",".join(map(str, values)))
+        with _annotate(
+            "serving.prefill_chunk", chunks=n,
+            req=each([st.req.id for st in sts]), slot=each(slots),
+            chunk=each([st.next_chunk for st in sts]),
+            of=each([st.n_chunks for st in sts]),
+            rows_seen=sum(_chunk_rows_seen(off, C, self.Lmax,
+                                           self.cfg.windows)
+                          for off in offs),
+        ):
             # host arrays and numpy scalars go to the device with the
             # program's own dispatch; an eager slice or ``jnp.int32``
             # is a dispatch (and a transfer) of its own, each a stretch
             # in which the device waits for the host
-            chunk = st.padded[:, i * self.C:(i + 1) * self.C]
+            size = 1 if n == 1 else self._group
+            chunks = np.zeros((size, C), np.int32)
+            for i, st in enumerate(sts):
+                chunks[i] = st.padded[0, st.next_chunk * C:
+                                      (st.next_chunk + 1) * C]
             # recurrent layers are told where the prompt ends in the chunk
-            valid = ((np.int32(min(self.C, st.req.prompt.size - off)),)
-                     if self.cfg.state_layers else ())
-            st.last_hidden, st.cache = self._extend(
-                self.params, chunk, st.cache, np.int32(off), *valid,
-            )
-        st.next_chunk += 1
-        if self._obs is not None:
-            self._obs.prefill_chunk()
-        if self._trace is not None and st.req.trace is not None:
-            self._trace.event(
-                st.req.trace, "prefill_chunk", time.perf_counter(),
-                tick=self.tick_count,
-            )
-        if st.next_chunk < st.n_chunks:
-            return
+            valid = ()
+            if self.cfg.state_layers:
+                valid = (np.zeros((size,), np.int32),)
+                valid[0][:n] = [min(C, st.req.prompt.size - off)
+                                for st, off in zip(sts, offs)]
+            if n == 1:
+                hidden, caches = self._extend(
+                    self.params, chunks, sts[0].cache, np.int32(offs[0]),
+                    *(v[0] for v in valid),
+                )
+                hidden, caches = (hidden,), (caches,)
+            else:
+                pad = size - n
+                hidden, caches = self._extend_group(
+                    self.params, chunks,
+                    (*(st.cache for st in sts),
+                     *self._scratch_arenas[:pad]),
+                    np.array(offs + [0] * pad, np.int32), *valid,
+                )
+                self._scratch_arenas[:pad] = caches[n:]
+        self._tick_chunks += n
+        self._tick_chunk_programs += 1
+        for st, h, cache in zip(sts, hidden, caches):
+            st.last_hidden, st.cache = h, cache
+            st.next_chunk += 1
+            if self._obs is not None:
+                self._obs.prefill_chunk()
+            if self._trace is not None and st.req.trace is not None:
+                self._trace.event(
+                    st.req.trace, "prefill_chunk", time.perf_counter(),
+                    tick=self.tick_count,
+                )
+
+    def _finish_admission(self, s: int, retired: list[Request]) -> None:
+        """The request in slot ``s`` has had its last chunk: first
+        token, its window placed into the slot, the arena released."""
+        st = self._admitting[s]
+        rid = st.req.id
         with _annotate("serving.first_token", req=rid, slot=s):
             Tp = st.req.prompt.size
             rkey = (st.req.key if st.req.key is not None

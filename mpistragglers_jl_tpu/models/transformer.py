@@ -291,6 +291,15 @@ class TransformerConfig:
         return bool(self.layer_mixers is not None
                     and self.layer_mixers[li] == "gdn")
 
+    def layer_like(self, li: int) -> int:
+        """The first layer that is treated as ``li`` is: the same token
+        mixer, window (and so rotary) and kind of feed-forward, which
+        is all a forward reads of a layer here. Two such layers differ
+        in their weights alone, so a program may trace one and call it
+        for the other (``decode._grouped_layer``)."""
+        traits = lambda j: (self.gdn(j), self.windows[j], self.dropless(j))
+        return next(j for j in range(li + 1) if traits(j) == traits(li))
+
     @property
     def state_layers(self) -> bool:
         """Does any layer keep recurrent state (no row a token)?"""
@@ -708,7 +717,9 @@ def gdn_half(x, lp, state, cfg, valid=None):
     head, the out-projection, the residual. Returns ``(x, state)``.
     ``valid`` (a traced count, None = T) says how many leading rows are
     real: the rows after them are a padded prompt's tail and leave
-    ``S`` and the conv rows as the last real row left them."""
+    ``S`` and the conv rows as the last real row left them. A vector
+    ``(B,)`` gives every row of the batch its own count (the chunks of
+    several requests in one program, ``decode._grouped_hidden``)."""
     B, T, _ = x.shape
     Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
     Dk, Dv = cfg.gdn_key_dim, cfg.gdn_value_dim
@@ -725,8 +736,14 @@ def gdn_half(x, lp, state, cfg, valid=None):
             [state["conv"], qkv.astype(state["conv"].dtype)], axis=1)
         # the rows the next call's conv reaches back to: the last
         # taps - 1 that went in, padding not counted
-        tail = seen[:, T:] if valid is None else (
-            jax.lax.dynamic_slice_in_dim(seen, valid, taps - 1, axis=1))
+        if valid is None:
+            tail = seen[:, T:]
+        elif jnp.ndim(valid):
+            tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
+                rows, n, taps - 1))(seen, valid)
+        else:
+            tail = jax.lax.dynamic_slice_in_dim(seen, valid, taps - 1,
+                                                axis=1)
         w = lp["gdn_conv_w"].astype(jnp.float32)
         seen = seen.astype(jnp.float32)
         y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
@@ -745,7 +762,9 @@ def gdn_half(x, lp, state, cfg, valid=None):
         g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(
             ba[..., Hv:] + lp["gdn_dt_bias"])
         if valid is not None:
-            real = (jnp.arange(T) < valid)[None, :, None]
+            real = ((jnp.arange(T) < valid[:, None])[..., None]
+                    if jnp.ndim(valid) else
+                    (jnp.arange(T) < valid)[None, :, None])
             g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
         if T == 1:
             o, S = _delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],

@@ -948,9 +948,9 @@ class SimReplica:
     prefill chunk advanced in a tick adds ``chunk_s`` virtual seconds
     to it, so a long-prompt burst inflates every tick it shares a
     replica with and the in-flight decodes' inter-token gaps blow out
-    (the real scheduler's ``_advance_admissions`` loop runs one
-    ``_extend`` program per admitting slot per tick — this is that
-    cost, modeled; ``chunk_s=0`` keeps the pre-round-16 timing
+    (the real scheduler advances every admitting slot by one chunk
+    per tick, in programs of up to four chunks — this is that cost,
+    modeled per chunk; ``chunk_s=0`` keeps the pre-round-16 timing
     bit-identical). ``migrate_out`` freezes a decoding request into a
     :class:`SimTicket` sized by the ``kv_bytes_per_token`` byte model;
     ``adopt`` re-queues it with ``migrated=True`` — admission then

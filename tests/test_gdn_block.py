@@ -362,9 +362,12 @@ def test_spans_carry_state_slots_held_experts_hit_and_local_pairs():
         assert 0 < h.args["experts_hit"] <= E // 2
         # 3 slots x 4 experts a token, about half of them held here
         assert 0 < h.args["pairs_local"] < 3 * TOP_K
-    chunks = [s for s in seen if s.name == "serving.prefill_chunk"
-              and s.args["of"] == 2]  # the 20-token prompt's two
-    assert [c.args["rows_seen"] for c in chunks] == [16, 32]
+    # the 20-token prompt's two chunks: the first in one program with
+    # the 9-token prompt's only one (admitted in the same tick; rows
+    # seen are summed over a program's chunks), the second alone
+    chunks = [s for s in seen if s.name == "serving.prefill_chunk"]
+    assert [(c.args["chunks"], c.args["of"], c.args["rows_seen"])
+            for c in chunks] == [(2, "2,1", 16 + 16), (1, 2, 32)]
 
 
 # -- refusals, each by mechanism ---------------------------------------------

@@ -175,8 +175,13 @@ def test_arguments_equal_the_schedulers_own_state(traced, tiny):
     events, state, reqs = traced
     ticks = [e for e in events if e[0] == "serving.tick"]
     for tick, (begin, delivered, first, retired) in zip(ticks, state):
-        assert tick[3] == begin
         inside = _children(events, tick)
+        # counted when admission is done: the chunks the tick ran and
+        # the programs they ran in
+        programs = [e[3]["chunks"] for e in inside
+                    if e[0] == "serving.prefill_chunk"]
+        assert tick[3] == {**begin, "chunks": sum(programs),
+                           "chunk_programs": len(programs)}
         harvest = [e for e in inside if e[0] == "serving.harvest"]
         decode = [e for e in inside if e[0] == "serving.decode"]
         # tokens delivered by the decode scan: all but first tokens
@@ -204,10 +209,23 @@ def test_arguments_equal_the_schedulers_own_state(traced, tiny):
         assert e[3]["slot"] in (0, 1)
 
 
+def _member(args, rid):
+    """A span's arguments as they concern request ``rid``, or None: a
+    ``serving.prefill_chunk`` of several chunks names its members'
+    ``req``, ``slot``, ``chunk`` and ``of`` in order, comma-separated."""
+    ids = str(args.get("req", "")).split(",")
+    if str(rid) not in ids:
+        return None
+    i = ids.index(str(rid))
+    return {k: int(str(v).split(",")[i]) if k in ("req", "slot", "chunk",
+                                                  "of") else v
+            for k, v in args.items()}
+
+
 def test_the_spans_of_one_request_share_its_id(traced):
     events, _, reqs = traced
     for r in reqs:
-        mine = [e for e in events if e[3].get("req") == r.id]
+        mine = [e for e in events if _member(e[3], r.id) is not None]
         names = [e[0] for e in mine]
         chunks = -(-r.prompt.size // 8)
         assert names.count("serving.admit_new") == 1
@@ -217,12 +235,11 @@ def test_the_spans_of_one_request_share_its_id(traced):
         # in the order of a request's life, on one slot
         assert names[0] == "serving.admit_new"
         assert names[-1] == "serving.first_token_wait"
-        assert len({e[3]["slot"] for e in mine if "slot" in e[3]}) == 1
-        cursor = [e[3]["chunk"] for e in mine
-                  if e[0] == "serving.prefill_chunk"]
+        mine = [_member(e[3], r.id) for e in mine]
+        assert len({a["slot"] for a in mine if "slot" in a}) == 1
+        cursor = [a["chunk"] for a in mine if "chunk" in a]
         assert cursor == list(range(chunks))
-        assert {e[3]["of"] for e in mine
-                if e[0] == "serving.prefill_chunk"} == {chunks}
+        assert {a["of"] for a in mine if "of" in a} == {chunks}
 
 
 def test_admit_new_says_where_its_arena_came_from(traced):
@@ -239,9 +256,12 @@ def test_admit_new_says_where_its_arena_came_from(traced):
 @pytest.mark.parametrize("page_tokens", [4, None], ids=["paged", "dense"])
 def test_a_backlog_of_one_chunk_prompts_reuses_one_arena(
         tiny, tmp_path, page_tokens, quantize_kv):
-    """``arena="new"`` exactly when the free list was empty: with every
-    prompt through prefill in the tick that admits it, that is the
-    scheduler's first admission and no other."""
+    """``arena="new"`` exactly when the free list was empty: every
+    prompt is through prefill in the tick that admits it, so that is
+    the scheduler's first admission and, where two prompts admitted in
+    one tick share their prefill program and so are in prefill at once
+    (a prompt shorter than a page registers nothing the second's plan
+    could read), one more."""
     from mpistragglers_jl_tpu.models.serving import ServingScheduler
 
     cfg, params = tiny
@@ -260,8 +280,10 @@ def test_a_backlog_of_one_chunk_prompts_reuses_one_arena(
     new = [e for e in _host_events(str(tmp_path))
            if e[0] == "serving.admit_new"]
     assert [e[3]["req"] for e in new] == [r.id for r in reqs]
-    assert [e[3]["arena"] for e in new] == ["new"] + ["reused"] * 6
-    assert len(sched._free_arenas) == 1
+    kinds = [e[3]["arena"] for e in new]
+    assert kinds[0] == "new"
+    # never more arenas than prompts were in prefill at once (2 slots)
+    assert len(sched._free_arenas) == kinds.count("new") <= 2
 
 
 def test_prefill_chunk_says_how_many_key_rows_it_attends(tmp_path):
