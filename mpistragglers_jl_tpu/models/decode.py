@@ -79,8 +79,13 @@ from .transformer import (
     ffn_half,
     gdn_half,
     gdn_zero_state,
+    hc_fold,
+    hc_pre,
     head_logits,
     make_kv_slice,
+    mla_absorb,
+    mla_merge,
+    mla_project,
     param_specs,
 )
 
@@ -141,7 +146,7 @@ def _kernel_viable(q, cache_l) -> bool:
     block divisor for the cache length whose working set, at that
     tile, fits the kernel's VMEM budget. One predicate so the routing
     sites cannot drift from the kernel's actual constraints."""
-    if not _is_quantized(cache_l):
+    if not _is_quantized(cache_l) or "v" not in cache_l:
         return False
     Hq, Hkv = q.shape[2], cache_l["k"].shape[2]
     if q.shape[1] != 1 or q.shape[-1] % 128 != 0 or Hq % Hkv != 0:
@@ -164,8 +169,11 @@ def _kernel_possible(cfg, quantize_kv: bool) -> bool:
     guard (quantized cache, lane-aligned head_dim); the remaining
     conditions (GQA ratio, block divisor, ``_route_kernel``'s batch
     threshold) depend on per-shard shapes and stay trace-time. Also
-    scopes the vma carve-out (``_decode_kernel_interpreted``)."""
-    return bool(quantize_kv and cfg.head_dim % 128 == 0)
+    scopes the vma carve-out (``_decode_kernel_interpreted``). A latent
+    layer's one 576-wide row a position is no K/V head of the kernel's:
+    a configuration with one takes the ``jax.numpy`` route throughout."""
+    return bool(quantize_kv and cfg.head_dim % 128 == 0
+                and not cfg.latent_layers)
 
 
 def _paged_kernel_possible(cfg, quantize_kv: bool,
@@ -253,12 +261,65 @@ def _expand_kv_scale(s, Hq):
     return s.transpose(0, 2, 1)[:, :, None, :]
 
 
-def _cache_write(cache_l: dict, k, v, off) -> dict:
+# A latent-attention layer's cache (``TransformerConfig(layer_mixers=
+# "mla")``) is ONE row a position and no ``v``: ``k`` (B, L, 1, R +
+# rope) holds the normalised latent beside the one rotated key all
+# heads share, and an int8 cache one float32 scale for each of the two
+# parts, ``k_s`` (B, L, 2): 512 + 64 + 8 = 584 bytes a position at the
+# published sizes where the same 32 heads uncompressed keep 10,496. Keys
+# and values are both read from it: the absorbed query's
+# (``transformer.mla_absorb``) product with the whole row is the score,
+# and the probabilities' product with the row's first R dims the
+# result, which the value's up-projection then takes. The functions
+# below take the width R of the latent as ``latent`` (None: a K/V
+# cache); everything that moves rows (ring gather, pages, arenas) reads
+# the leaves as they come.
+
+
+def _latent_leaves(row, R: int, quantized: bool) -> dict:
+    """The leaves a latent layer's cache writes for ``row`` (..., 1, R +
+    rope): the row itself, or its int8 values with the scale of the
+    latent and the scale of the rotated key (``_kv_quantize`` on each
+    part)."""
+    if not quantized:
+        return {"k": row}
+    cq, cs = _kv_quantize(row[..., :R])
+    rq, rs = _kv_quantize(row[..., R:])
+    return {"k": jnp.concatenate([cq, rq], axis=-1),
+            "k_s": jnp.concatenate([cs, rs], axis=-1)}
+
+
+def _latent_scores(q, cache_l: dict, scale, R: int):
+    """The absorbed query (B, T, H, R + rope) against every row of a
+    latent cache: (B, H, T, L) float32; int8 rows dequantize through
+    the rank-1 correction of each part's scale."""
+    rows = cache_l["k"]
+    if not _is_quantized(cache_l):
+        return _group_scores(q, rows, scale)
+    rows, ks = rows.astype(q.dtype), cache_l["k_s"]
+    part = lambda sl, j: _group_scores(
+        q[..., sl], rows[..., sl], scale) * ks[:, None, None, :, j]
+    return part(slice(None, R), 0) + part(slice(R, None), 1)
+
+
+def _latent_pv(p, cache_l: dict, R: int):
+    """Probabilities (B, H, T, L) x the rows' latent part: (B, T, H, R)
+    float32, what the value's up-projection takes."""
+    if _is_quantized(cache_l):
+        p = p * cache_l["k_s"][:, None, None, :, 0]
+    return _group_pv(p, cache_l["k"][..., :R])
+
+
+def _cache_write(cache_l: dict, k, v, off, latent=None) -> dict:
     """Write a chunk's K/V at position-axis offset ``off``, quantizing
     when the cache is int8 (detected from the layout, so every caller
-    — masked, ring, chunked — shares one write path)."""
+    — masked, ring, chunked — shares one write path). A latent layer
+    (``latent``: the latent's width) writes its one row, ``k``."""
     upd = partial(jax.lax.dynamic_update_slice_in_dim, start_index=off,
                   axis=1)
+    if latent is not None:
+        return {kk: upd(cache_l[kk], update=u) for kk, u in _latent_leaves(
+            k, latent, _is_quantized(cache_l)).items()}
     if not _is_quantized(cache_l):
         return {"k": upd(cache_l["k"], update=k),
                 "v": upd(cache_l["v"], update=v)}
@@ -272,9 +333,11 @@ def _cache_write(cache_l: dict, k, v, off) -> dict:
     }
 
 
-def _cache_scores(q, cache_l: dict, scale):
+def _cache_scores(q, cache_l: dict, scale, latent=None):
     """Grouped scores against the cache, dequantizing via the rank-1
     score correction when int8."""
+    if latent is not None:
+        return _latent_scores(q, cache_l, scale, latent)
     kc = cache_l["k"]
     if not _is_quantized(cache_l):
         return _group_scores(q, kc, scale)
@@ -282,9 +345,11 @@ def _cache_scores(q, cache_l: dict, scale):
     return s * _expand_kv_scale(cache_l["k_s"], q.shape[2])
 
 
-def _cache_pv(p, cache_l: dict):
+def _cache_pv(p, cache_l: dict, latent=None):
     """Grouped probs x V against the cache; int8 V dequantizes by
     folding the per-position scale into the probabilities."""
+    if latent is not None:
+        return _latent_pv(p, cache_l, latent)
     if _is_quantized(cache_l):
         p = p * _expand_kv_scale(cache_l["v_s"], p.shape[1])
     return _group_pv(p, cache_l["v"])
@@ -308,6 +373,15 @@ def _zero_cache_layer(B, L, H, Dh, dtype, quantize_kv):
     return layer
 
 
+def _zero_latent_layer(B, L, cfg, quantize_kv):
+    """A latent layer's zeroed cache: one row a position."""
+    layer = {"k": jnp.zeros((B, L, 1, cfg.latent_width),
+                            jnp.int8 if quantize_kv else cfg.dtype)}
+    if quantize_kv:
+        layer["k_s"] = jnp.zeros((B, L, 2), jnp.float32)
+    return layer
+
+
 def init_cache(
     cfg: TransformerConfig, batch: int, max_len: int,
     mesh: Mesh | None = None, *, quantize_kv: bool = False,
@@ -322,6 +396,8 @@ def init_cache(
     return [
         # a gated delta-rule layer keeps its fixed block of state
         gdn_zero_state(cfg, batch) if cfg.gdn(li)
+        else _zero_latent_layer(batch, max_len, cfg, quantize_kv)
+        if cfg.mla(li)
         else _zero_cache_layer(batch, max_len, H, cfg.head_dim, cfg.dtype,
                                quantize_kv)
         for li in range(cfg.n_layers)
@@ -360,7 +436,7 @@ def shard_cache(cache, cfg: TransformerConfig, mesh: Mesh):
 CHUNK_BLOCK_K = 512
 
 
-def _chunk_attention(q, cache_l, qpos, scale, window):
+def _chunk_attention(q, cache_l, qpos, scale, window, latent=None):
     """A chunk's (T > 1) grouped attention, walking the key blocks its
     queries can see: block ``j`` holds the cache rows ``[j*bk,
     (j+1)*bk)`` (``bk = min(CHUNK_BLOCK_K, Lmax)``; absolute positions,
@@ -376,7 +452,9 @@ def _chunk_attention(q, cache_l, qpos, scale, window):
     rows, int8 rows dequantized with their per-position scales. No
     (H, T, Lmax) tensor exists and a block outside the walk costs
     nothing: the work follows the rows the chunk can see, not the
-    cache's length."""
+    cache's length. ``latent`` (the latent's width): ``q`` is the
+    absorbed query, the rows a latent layer's, the result (B, T, H,
+    latent)."""
     T = q.shape[1]
     Lmax = cache_l["k"].shape[1]
     bk = min(CHUNK_BLOCK_K, Lmax)
@@ -385,7 +463,8 @@ def _chunk_attention(q, cache_l, qpos, scale, window):
     hi = jnp.minimum(-(-(off + T) // bk), -(-Lmax // bk))
     # accumulators derived from q: they inherit its varying mesh axes
     # (make_extend runs this under shard_map), as in ring_self_attention
-    o0 = q.astype(jnp.float32) * 0.0
+    o0 = (q if latent is None else q[..., :latent]).astype(
+        jnp.float32) * 0.0
     zeros = o0.sum(-1).transpose(0, 2, 1)  # (B, H, T)
 
     def block(j, carry):
@@ -405,12 +484,13 @@ def _chunk_attention(q, cache_l, qpos, scale, window):
         if Lmax % bk:
             mask = jnp.logical_and(mask, (kpos >= j * bk)[None, :])
         mask = mask[None, None]
-        s = jnp.where(mask, _cache_scores(q, blk, scale), _NEG)
+        s = jnp.where(mask, _cache_scores(q, blk, scale, latent), _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m - m_new)  # (B, H, T)
         l = l * corr + p.sum(axis=-1)
-        o = o * corr.transpose(0, 2, 1)[..., None] + _cache_pv(p, blk)
+        o = o * corr.transpose(0, 2, 1)[..., None] + _cache_pv(
+            p, blk, latent)
         return o, m_new, l
 
     o, _, l = jax.lax.fori_loop(lo, hi, block, (o0, zeros + _NEG, zeros))
@@ -434,7 +514,7 @@ def _chunk_rows_seen(off: int, T: int, Lmax: int, windows) -> int:
 
 
 def _cached_attention(q, cache_l, qpos, scale, window=None,
-                      use_kernel: bool = False):
+                      use_kernel: bool = False, latent=None):
     """Grouped attention of the chunk's queries against the rows of the
     cache they can see.
 
@@ -453,7 +533,7 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     ``use_kernel`` is the program's resolved route (the module note).
     """
     if q.shape[1] > 1:
-        return _chunk_attention(q, cache_l, qpos, scale, window)
+        return _chunk_attention(q, cache_l, qpos, scale, window, latent)
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 
@@ -461,13 +541,13 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
             q, cache_l, qpos[0], scale, window
         )
     Lmax = cache_l["k"].shape[1]
-    s = _cache_scores(q, cache_l, scale)  # (B, H, 1, Lmax) f32
+    s = _cache_scores(q, cache_l, scale, latent)  # (B, H, 1, Lmax) f32
     # the one band predicate (parallel/ring_attention._band_mask): the
     # serving path cannot silently diverge from the training oracle
     mask = _band_mask(qpos, jnp.arange(Lmax), True, window)
     s = jnp.where(mask[None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
-    o = _cache_pv(p, cache_l)  # (B, 1, H, D) f32
+    o = _cache_pv(p, cache_l, latent)  # (B, 1, H, D) f32
     return o.astype(q.dtype)
 
 
@@ -518,18 +598,33 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     attention runs through :func:`_ring_cached_attention`. A gated
     delta-rule layer's ``cache_l`` is its state (no rows), carried
     through the chunk; of ``valid`` see ``gdn_half``."""
+    h, mix = hc_pre(x, lp, cfg, "hc1")
     if cfg.gdn(li):
-        x, cache_l = gdn_half(x, lp, cache_l, cfg, valid)
+        x, cache_l = gdn_half(h, lp, cache_l, cfg, valid, mix=mix)
         x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
         return x, cache_l
-    q, k, v, gate = attn_qkv(
-        x, lp, cfg, li, partial(_rope, pos=qpos, theta=cfg.rope_theta),
-        kv_slice)
+    rope = partial(_rope, pos=qpos, theta=cfg.rope_theta,
+                   table=cfg.rope_table)
+    if cfg.mla(li):
+        # a latent layer attends its cache whatever the chunk: the row
+        # it has just written is all that exists of a position's keys
+        # and values
+        if ring or tp_psum:
+            raise ValueError("a latent-attention layer has neither a "
+                             "ring-cache nor a tp-sharded form")
+        x, cache_l = _latent_attend(
+            h, lp, cfg, rope, mix, lambda row: _cache_write(
+                cache_l, row, None, qpos[0], cfg.mla_kv_rank),
+            lambda q, cl: _cached_attention(
+                q, cl, qpos, cfg.softmax_scale, latent=cfg.mla_kv_rank))
+        x, _, _ = ffn_half(x, lp, cfg, li)
+        return x, cache_l
+    q, k, v, gate = attn_qkv(h, lp, cfg, li, rope, kv_slice)
     off = qpos[0]
     if ring:
         off = jnp.mod(off, cache_l["k"].shape[1])
     cache_l = _cache_write(cache_l, k, v, off)
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.softmax_scale
     if chunk_attn is not None:
         # prefill at offset 0: attention lives entirely inside the chunk,
         # so the configured chunk kernel (flash on TPU) does the work on
@@ -541,9 +636,23 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     else:
         o = _cached_attention(q, cache_l, qpos, scale, cfg.windows[li],
                               use_kernel=decode_kernel)
-    x = attn_merge(x, o, gate, lp, cfg, tp_psum=tp_psum)
+    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix)
     x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
     return x, cache_l
+
+
+def _latent_attend(h, lp, cfg, rope, mix, write, attend):
+    """A latent-attention half over a cache, the same for a chunk, a
+    decode step and the serving tick, which differ in where the row
+    goes (``write(row) -> cache_l``) and which rows the absorbed query
+    sees (``attend(q, cache_l) -> (B, T, H, kv rank)``). Returns ``(x,
+    cache_l)``."""
+    qn, qr, row = mla_project(h, lp, cfg, rope)
+    q = mla_absorb(qn, qr, lp, cfg)
+    with jax.named_scope("mla_attn"):
+        cache_l = write(row)
+        o = attend(q, cache_l)
+    return mla_merge(h, o, lp, cfg, mix, latent=True), cache_l
 
 
 def _incremental_hidden(params, tokens, cache, offset, cfg,
@@ -585,7 +694,7 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
             ring=ring, decode_kernel=decode_kernel, valid=valid,
         )
         new_cache.append(cache_l)
-    return x, new_cache
+    return hc_fold(x, cfg), new_cache
 
 
 @functools.lru_cache(maxsize=64)
@@ -605,10 +714,11 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
     @jax.jit
     def grouped_layer(x, lp, rows, offsets, valid):
         n, T = x.shape[:2]
+        h, mix = hc_pre(x, lp, cfg, "hc1")
         if cfg.gdn(li):
             state = {kk: jnp.concatenate([r[kk] for r in rows])
                      for kk in rows[0]}
-            x, state = gdn_half(x, lp, state, cfg, valid)
+            x, state = gdn_half(h, lp, state, cfg, valid, mix=mix)
             rows = [{kk: a[i:i + 1] for kk, a in state.items()}
                     for i in range(n)]
         else:
@@ -616,17 +726,29 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
 
             def rope(t):  # each request's rows at its own positions
                 return jax.vmap(lambda a, pos: _rope(
-                    a[None], pos, cfg.rope_theta)[0])(t, qpos)
+                    a[None], pos, cfg.rope_theta, cfg.rope_table)[0])(
+                        t, qpos)
 
-            q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
-            rows = [_cache_write(r, k[i:i + 1], v[i:i + 1], offsets[i])
-                    for i, r in enumerate(rows)]
-            o = jnp.concatenate([
-                _cached_attention(q[i:i + 1], r, qpos[i],
-                                  cfg.head_dim ** -0.5, cfg.windows[li])
-                for i, r in enumerate(rows)
-            ])
-            x = attn_merge(x, o, gate, lp, cfg)
+            def attend(q, rows, **kw):  # each request's queries, its store
+                return jnp.concatenate([
+                    _cached_attention(q[i:i + 1], r, qpos[i],
+                                      cfg.softmax_scale, **kw)
+                    for i, r in enumerate(rows)])
+
+            if cfg.mla(li):
+                R = cfg.mla_kv_rank
+                x, rows = _latent_attend(
+                    h, lp, cfg, rope, mix,
+                    lambda row: [
+                        _cache_write(r, row[i:i + 1], None, offsets[i], R)
+                        for i, r in enumerate(rows)],
+                    functools.partial(attend, latent=R))
+            else:
+                q, k, v, gate = attn_qkv(h, lp, cfg, li, rope)
+                rows = [_cache_write(r, k[i:i + 1], v[i:i + 1], offsets[i])
+                        for i, r in enumerate(rows)]
+                o = attend(q, rows, window=cfg.windows[li])
+                x = attn_merge(h, o, gate, lp, cfg, mix=mix)
         x, _, _ = ffn_half(x, lp, cfg, li)
         return x, rows
 
@@ -649,7 +771,7 @@ def _grouped_hidden(params, tokens, caches, offsets, cfg, valid=None):
             x, lp, [c[li] for c in caches], offsets, valid)
         for c, r in zip(caches, rows):
             c[li] = r
-    return x, caches
+    return hc_fold(x, cfg), caches
 
 
 def _incremental_forward(params, tokens, cache, offset, cfg, **kw):
@@ -784,6 +906,13 @@ def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
             "configuration has gated delta-rule layers, whose state is "
             "one fixed block a request and has no width. "
             "ServingScheduler serves it"
+        )
+    if cfg.latent_layers:
+        raise ValueError(
+            "the ring cache is rows of K and of V, a head each; this "
+            "configuration has latent-attention layers, which keep one "
+            "row a position and no V. ServingScheduler and the "
+            "max_len cache of init_cache serve it"
         )
     return _row_widths(cfg)
 

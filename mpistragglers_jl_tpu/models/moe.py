@@ -460,7 +460,34 @@ def topk_route(x2d: jax.Array, router: jax.Array, bias, k: int,
 # 2048-row prefill chunk took 2.58 ms at this tiling against 3.33 ms
 # at (128, 512, 512) and 5.38 ms through ``jax.lax.ragged_dot``; of a
 # 128-row decode step, 1.50 against 1.91 and 1.90 ms.
+# Where K or N is no multiple of 1024 (3584 = 3.5 x 1024 is the first)
+# the tile follows the width (:func:`_width_tile`: 1792). It need not:
+# ``megablox.gmm`` masks both operands of a ragged last k step in
+# float32 and lets the last n block hang over. Read on one v5e chip
+# (my chip runs, PR 34) at 64 experts, 3584 -> 1024 -> 3584, bfloat16,
+# the three products with the 3584-wide dimension tiled at the divisors
+# 512 / 896 / 1792 / at the ragged 1024: a decode step's 64 rows 1.232
+# / 1.244 / 1.243 / 1.248 ms; a chunk's 1024 rows 2.282 / 2.254 /
+# 2.241 / 2.349 ms; a group of four chunks' 4096 rows 3.100 / 3.023 /
+# 2.978 / 3.134 ms. End to end on ``serve_xing4_mixed``, 1792 against
+# the ragged 1024 on four shared seeds: ``serve_tok_s`` 935.2, 928.2,
+# 923.3, 928.0 against 922.8, 912.4, 915.5, 926.4 (every pair, +0.2 to
+# +1.7%), and the median token gap 13.29 to 13.32 ms in its four runs
+# against 13.40 to 13.47 in the ragged tile's eight; in the traced
+# windows the prefill programs' ``gmm`` takes 0.502 against 0.538 s of
+# 4 s and the tick's the same (PERF.md section 6, PR 34).
 _GROUP_TILE = (128, 1024, 1024)
+
+
+def _width_tile(dim: int, cap: int) -> int:
+    """The k or n tile of a width ``dim``: ``cap`` where it divides
+    ``dim`` (or all of a narrower one), else the largest multiple of
+    128 lanes up to twice ``cap`` that does (3584 at 1024: 1792), else
+    ``cap`` with a ragged last tile."""
+    if dim <= cap or dim % cap == 0:
+        return min(dim, cap)
+    return max((t for t in range(128, 2 * cap + 1, 128) if dim % t == 0),
+               default=cap)
 
 
 def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
@@ -485,7 +512,8 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
     out = gmm(
         xs, w, sizes, preferred_element_type=out_dtype,
-        tiling=(tm, min(K, _GROUP_TILE[1]), min(N, _GROUP_TILE[2])),
+        tiling=(tm, _width_tile(K, _GROUP_TILE[1]),
+                _width_tile(N, _GROUP_TILE[2])),
         interpret=_use_interpret(),
     )
     return out[:M] if pad else out

@@ -40,6 +40,18 @@ Design (TPU-first):
   configuration shares no prefix page (``shares_prefixes`` is False),
   and ``qos=``, ``cache=``, page migration, ``make_serving_scan`` and
   speculation refuse it by mechanism.
+* **A third kind of leaf.** A latent-attention layer
+  (``layer_mixers`` value ``"mla"``) keeps ONE row a position for all
+  its heads, the normalised latent beside one rotated key, and no
+  ``v`` (``decode._latent_leaves``): int8 with a scale for each of the
+  two parts, in pages like any row cache (``_fresh_pages``), prefix
+  pages shared. Keys and values are both read from it in the absorbed
+  form (``decode._latent_attend``); the tick takes the gather route
+  (``_serving_scan_paged`` with ``use_kernel`` False: every slot's ring
+  gathered once a tick), the paged kernel having no such mode. The
+  sharded tick, migration and speculation refuse it by mechanism, as
+  they do a residual path of several streams (``hc_mult``), of which
+  nothing is cached.
 * **Per-row positions.** Unlike ``decode_step_ring_dense`` (one scalar
   position for the whole batch), every slot decodes at its own global
   position: RoPE angles, ring-slot writes, and the ``kpos >= 0``
@@ -135,6 +147,8 @@ from .decode import (
     _incremental_hidden,
     _is_quantized,
     _kernel_possible,
+    _latent_attend,
+    _latent_leaves,
     _kernel_viable,
     _kv_quantize,
     _paged_kernel_possible,
@@ -142,6 +156,7 @@ from .decode import (
     _ring_from_cache,
     _route_kernel,
     _row_widths,
+    _zero_latent_layer,
     ring_widths,
 )
 from .paging import (
@@ -153,12 +168,15 @@ from .paging import (
 from ..qos import DeficitScheduler, TenantRegistry
 from .transformer import (
     TransformerConfig,
+    _rope_freqs,
     attn_merge,
     attn_qkv,
     embed,
     ffn_half,
     gdn_half,
     gdn_zero_state,
+    hc_fold,
+    hc_pre,
     head_logits,
     make_kv_slice,
     param_specs,
@@ -189,6 +207,8 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
     def layer(li, length):
         if cfg.gdn(li):  # no rows: the layer's fixed block of state
             return gdn_zero_state(cfg, B)
+        if cfg.mla(li):  # one row a position: [latent | rotated key]
+            return _zero_latent_layer(B, length, cfg, quantize_kv)
         shape = (B, length, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
@@ -252,7 +272,10 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     (:data:`~.paging.NULL_PAGE`): rows nothing reads unmasked, the
     landing zone for retired-but-still-ticking rows. A gated
     delta-rule layer has no pages: its leaf is the fixed block of state
-    of each of the ``slots`` (its page count is not read)."""
+    of each of the ``slots`` (its page count is not read). A latent
+    layer's pages hold its one row a position, ``k`` ``(n_pages, P,
+    latent + rope)``, and the two scales of a row as two "heads" of
+    ``k_s``."""
     counts = ((n_pages,) * cfg.n_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
@@ -260,6 +283,12 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     def layer(li, n):
         if cfg.gdn(li):
             return gdn_zero_state(cfg, slots)
+        if cfg.mla(li):
+            out = {"k": jnp.zeros((n, P, cfg.latent_width), kvdt)}
+            if quantize_kv:
+                out["k_s"] = jnp.zeros((n, 2, paged_scale_lanes(P)),
+                                       jnp.float32)
+            return out
         shape = (n, P, cfg.kv_heads * cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
@@ -287,12 +316,12 @@ def _rows_to_pages(kk: str, x, P: int):
 
 def _pages_to_rows(kk: str, blk, Hkv: int, P: int):
     """Inverse of :func:`_rows_to_pages`: ``(..., n, P, Hkv * D) ->
-    (..., n * P, Hkv, D)`` and ``(..., n, Hkv, lanes) -> (..., n * P,
-    Hkv)``."""
+    (..., n * P, Hkv, D)`` and ``(..., n, H, lanes) -> (..., n * P,
+    H)`` (a scale leaf says itself how many scales a position has)."""
     lead, n = blk.shape[:-3], blk.shape[-3]
     if kk.endswith("_s"):
         blk = jnp.swapaxes(blk[..., :P], -1, -2)
-        return blk.reshape(lead + (n * P, Hkv))
+        return blk.reshape(lead + (n * P, blk.shape[-1]))
     return blk.reshape(lead + (n * P, Hkv, blk.shape[-1] // Hkv))
 
 
@@ -326,13 +355,14 @@ def _layer_tables(cfg: TransformerConfig, pt) -> list:
 # --------------------------------------------------------------------------
 
 
-def _rope_rows(x, pos, theta: float = 10000.0):
+def _rope_rows(x, pos, theta: float = 10000.0, table=None):
     """Rotary embedding for single-token rows: x (S, 1, H, D), pos (S,)
     global positions — the per-row counterpart of transformer._rope
-    (which shares one position vector across the batch), at its base."""
+    (which shares one position vector across the batch), at its base
+    (or its table of frequencies)."""
     Dh = x.shape[-1]
     half = Dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = _rope_freqs(half, theta, table)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (S, half)
     cos = jnp.cos(ang)[:, None, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[:, None, None, :].astype(x.dtype)
@@ -342,15 +372,20 @@ def _rope_rows(x, pos, theta: float = 10000.0):
     )
 
 
-def _ring_write_rows(cache_l: dict, k, v, slot):
+def _ring_write_rows(cache_l: dict, k, v, slot, latent=None):
     """Write each row's single-token K/V at its own ring slot:
     k, v (S, 1, Hkv, D), slot (S,) — a per-row scatter on the slot
-    axis (decode.py's ``_cache_write`` writes one shared offset)."""
+    axis (decode.py's ``_cache_write`` writes one shared offset). A
+    latent layer (``latent``: the latent's width) writes its one row,
+    ``k``."""
     rows = jnp.arange(k.shape[0])
 
     def put(c, u):
         return c.at[rows, slot].set(u[:, 0].astype(c.dtype))
 
+    if latent is not None:
+        return {kk: put(cache_l[kk], u) for kk, u in _latent_leaves(
+            k, latent, _is_quantized(cache_l)).items()}
     if not _is_quantized(cache_l):
         return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v)}
     kq, ks = _kv_quantize(k)
@@ -363,7 +398,8 @@ def _ring_write_rows(cache_l: dict, k, v, slot):
     }
 
 
-def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False):
+def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
+                         latent=None):
     """Single-query ring attention with a per-row position: the same
     ``kpos(s) = pos - ((pos - s) mod W), valid iff kpos >= 0`` invariant
     as decode.py's ``_ring_cached_attention``, evaluated rowwise. The
@@ -378,7 +414,9 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False):
     regime is where int8 finally converts its byte win into time
     (PERF.md section 6, PR 27). Default False: this function is also the dense
     ORACLE step (``serving_decode_step_dense``), which stays einsum so
-    kernel-vs-einsum parity is testable against it."""
+    kernel-vs-einsum parity is testable against it. ``latent`` (the
+    latent's width): q is the absorbed query, the ring a latent
+    layer's one row a slot, the result (S, 1, H, latent)."""
     W = cache_l["k"].shape[1]
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
@@ -386,13 +424,13 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False):
         return quantized_decode_attention(
             q, cache_l, pos, scale, ring=True
         )
-    s = _cache_scores(q, cache_l, scale)  # (S, H, 1, W) f32
+    s = _cache_scores(q, cache_l, scale, latent)  # (S, H, 1, W) f32
     kpos = pos[:, None] - jnp.mod(
         pos[:, None] - jnp.arange(W)[None, :], W
     )  # (S, W)
     s = jnp.where((kpos >= 0)[:, None, None, :], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
-    o = _cache_pv(p, cache_l)
+    o = _cache_pv(p, cache_l, latent)
     return o.astype(q.dtype)
 
 
@@ -498,17 +536,30 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     that got a row in a dropless expert layer, None elsewhere. A gated
     delta-rule layer's ``cache_l`` is every slot's state: one step of
     the recurrence a row, no position and no page."""
+    h, mix = hc_pre(x, lp, cfg, "hc1")
     if cfg.gdn(li):
-        x, cache_l = gdn_half(x, lp, cache_l, cfg)
+        x, cache_l = gdn_half(h, lp, cache_l, cfg, mix=mix)
         with jax.named_scope("decode_mlp"):
             x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
         return x, cache_l, hit
-    q, k, v, gate = attn_qkv(
-        x, lp, cfg, li,
-        functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta),
-        kv_slice
-    )
-    scale = cfg.head_dim ** -0.5
+    rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
+                             table=cfg.rope_table)
+    if cfg.mla(li):
+        # every slot's ring view of its pages (the tick gathers them
+        # once, ``_serving_scan_paged``): the row goes to slot ``pos``
+        # of a ring as wide as the context budget, which never wraps
+        R, W = cfg.mla_kv_rank, cache_l["k"].shape[1]
+        x, cache_l = _latent_attend(
+            h, lp, cfg, rope, mix,
+            lambda row: _ring_write_rows(cache_l, row, None,
+                                         jnp.mod(pos, W), R),
+            lambda q, cl: _ring_attention_rows(
+                q, cl, pos, cfg.softmax_scale, latent=R))
+        with jax.named_scope("decode_mlp"):
+            x, _, hit = ffn_half(x, lp, cfg, li)
+        return x, cache_l, hit
+    q, k, v, gate = attn_qkv(h, lp, cfg, li, rope, kv_slice)
+    scale = cfg.softmax_scale
     # scopes name the K/V traffic (cache write, scores, softmax, p @ v)
     # and the MLP in a device trace; the projections stay outside both
     with jax.named_scope("decode_attn"):
@@ -525,7 +576,7 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
             cache_l = _ring_write_rows(cache_l, k, v, jnp.mod(pos, W))
             o = _ring_attention_rows(q, cache_l, pos, scale,
                                      use_kernel=use_kernel)
-    x = attn_merge(x, o, gate, lp, cfg, tp_psum=tp_psum)
+    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix)
     with jax.named_scope("decode_mlp"):
         x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
     return x, cache_l, hit
@@ -552,7 +603,7 @@ def _serving_forward(params, tok, pos, caches, cfg, *, kv_slice=None,
         new.append(cl)
         if hit is not None:
             hits = hit if hits is None else hits + hit
-    return head_logits(params, x, cfg)[:, 0], new, hits
+    return head_logits(params, hc_fold(x, cfg), cfg)[:, 0], new, hits
 
 
 def serving_decode_step_dense(params, tok, pos, caches,
@@ -680,7 +731,8 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         with jax.named_scope("kv_page_gather"):
             # (a recurrent layer's state is no page: it goes through)
             views = [
-                cl if cfg.gdn(li) else _paged_gather(cl, t, cfg.kv_heads, P)
+                cl if cfg.gdn(li)
+                else _paged_gather(cl, t, cfg.cache_heads(li), P)
                 for li, (cl, t) in enumerate(zip(caches, pts))
             ]
         tok, pos, done, views, toks = _scan_body(
@@ -716,9 +768,9 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
         valid = jnp.arange(R) < ell
         nb = -(-R // P)  # pages that cover rows [0, R)
 
-        def seed(kk, c, pg, row):
+        def seed(kk, c, pg, row, heads):
             g = _pages_to_rows(
-                kk, jnp.take(pg, row[:nb], axis=0), cfg.kv_heads, P
+                kk, jnp.take(pg, row[:nb], axis=0), heads, P
             )[:R]  # (R, ...)
             g = jnp.where(
                 valid.reshape((R,) + (1,) * (g.ndim - 1)), g, 0
@@ -728,9 +780,10 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
             )
 
         return [
-            {kk: seed(kk, cl[kk], pl[kk], row) for kk in cl}
-            for cl, pl, row in zip(cache, pages,
-                                   _layer_tables(cfg, pt_row))
+            {kk: seed(kk, cl[kk], pl[kk], row, cfg.cache_heads(li))
+             for kk in cl}
+            for li, (cl, pl, row) in enumerate(zip(
+                cache, pages, _layer_tables(cfg, pt_row)))
         ]
 
     return serving_seed_prefix
@@ -750,8 +803,9 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
     @jax.jit
     def serving_gather_ring(caches, pt_row):
         return [
-            _paged_gather(cl, row[None], cfg.kv_heads, P)
-            for cl, row in zip(caches, _layer_tables(cfg, pt_row))
+            _paged_gather(cl, row[None], cfg.cache_heads(li), P)
+            for li, (cl, row) in enumerate(zip(
+                caches, _layer_tables(cfg, pt_row)))
         ]
 
     return serving_gather_ring
@@ -838,6 +892,29 @@ def _refuse_state_layers(cfg: TransformerConfig, what: str,
         )
 
 
+def _refuse_latent_layers(cfg: TransformerConfig, what: str,
+                          why: str) -> None:
+    """``what`` is written for caches that are rows of K and of V, a
+    head each; refuse, by mechanism, a configuration with
+    latent-attention layers."""
+    if cfg.latent_layers:
+        raise ValueError(
+            f"{what}: this configuration has latent-attention layers, "
+            f"whose cache is one row a position and no K/V head; {why}"
+        )
+
+
+def _refuse_streams(cfg: TransformerConfig, what: str, why: str) -> None:
+    """``what`` is written for one residual stream; refuse, by
+    mechanism, a configuration whose residual path is streams."""
+    if cfg.hc_mult > 1:
+        raise ValueError(
+            f"{what}: this configuration's residual path is "
+            f"{cfg.hc_mult} streams mixed by the token's own matrices; "
+            f"{why}"
+        )
+
+
 def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
                       *, eos_id: int | None = None,
                       quantize_kv: bool = False,
@@ -855,6 +932,14 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
         cfg, "make_serving_scan (the sharded tick)",
         "its cache specs shard rows over dp and heads over tp. One "
         "chip serves it through ServingScheduler")
+    _refuse_latent_layers(
+        cfg, "make_serving_scan (the sharded tick)",
+        "its cache specs shard K/V heads over tp. One chip serves it "
+        "through ServingScheduler")
+    _refuse_streams(
+        cfg, "make_serving_scan (the sharded tick)",
+        "its weights follow param_specs, which has no leaf of the "
+        "mixing. One chip serves it through ServingScheduler")
     _check_ring_cfg(cfg)
     _check_sampling_params(temperature, top_k)
     _refuse_switch_experts(cfg)
@@ -2023,6 +2108,12 @@ class ServingScheduler:
             # slots whose recurrent layers hold a request's state
             **({"state_slots": self.S - n_free}
                if self.cfg.state_layers else {}),
+            # rows the decoding slots attend in a latent layer when the
+            # tick begins (a row a position: their positions' sum)
+            **({"latent_rows": sum(
+                self._host_pos[s] for s, r in enumerate(self._slot_req)
+                if r is not None and s not in self._admitting)}
+               if self.cfg.latent_layers and self.paged else {}),
         ) as tick:
             with phase("serving.admit") as admit:
                 self._advance_admissions(retired)
@@ -2176,6 +2267,9 @@ class ServingScheduler:
         _refuse_state_layers(
             self.cfg, "KV-page migration", "an exported image is ring "
             "views behind a page table, and the state block is in none")
+        _refuse_latent_layers(
+            self.cfg, "KV-page migration", "a migrated image is K/V ring views of "
+            "kv_heads heads, which such a layer has not")
         if len(self._kinds) > 1:
             raise ValueError(
                 "KV-page migration moves one ring view per layer "
@@ -2324,6 +2418,9 @@ class ServingScheduler:
         _refuse_state_layers(
             self.cfg, "adopt_page_state", "a migrated image is ring "
             "views behind a page table, and the state block is in none")
+        _refuse_latent_layers(
+            self.cfg, "adopt_page_state", "a migrated image is K/V ring views of "
+            "kv_heads heads, which such a layer has not")
         if len(self._kinds) > 1:
             raise ValueError(
                 "adopt_page_state: a migrated image is one ring view "

@@ -212,6 +212,16 @@ def _check_draft_layers(cfg: TransformerConfig, draft_layers):
             "rows; this configuration has gated delta-rule layers, "
             "whose state cannot be rolled back past a rejected draft"
         )
+    if cfg.latent_layers or cfg.hc_mult > 1:
+        raise ValueError(
+            "speculative decoding's draft and verify programs are "
+            "written for K/V row caches under one residual stream; this "
+            "configuration has "
+            + ("latent-attention layers (one row a position)"
+               if cfg.latent_layers else
+               f"a residual path of {cfg.hc_mult} streams, which a "
+               "truncated draft would fold before they are mixed")
+        )
     if draft_layers is None:
         return None
     d = int(draft_layers)

@@ -179,6 +179,33 @@ class TransformerConfig:
     # the pairs that fall on a held expert are computed, the rest are
     # left out (what a further chip would add). None = all held.
     experts_held: tuple | None = None
+    # latent attention (``layer_mixers`` value "mla"; DeepSeek-V2's
+    # multi-head latent attention): queries through a rank
+    # ``mla_q_rank`` bottleneck to ``n_heads`` heads of ``mla_nope_dim +
+    # mla_rope_dim``; keys and values through ONE row a position, the
+    # normalised latent (``mla_kv_rank``) beside one rotated key of
+    # ``mla_rope_dim`` that all heads share, from which each head's
+    # ``mla_nope_dim`` of key and ``mla_v_dim`` of value are read
+    # (:func:`mla_project`). ``head_dim`` is then the q/k head's
+    # ``mla_nope_dim + mla_rope_dim``.
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
+    # rotary frequencies as a table, one per rotated pair
+    # (:func:`yarn_rope_table`; None = ``rope_theta ** (-i / pairs)``),
+    # and the softmax scale (None = ``head_dim ** -0.5``)
+    rope_table: tuple | None = None
+    attn_scale: float | None = None
+    # the residual path as ``hc_mult`` streams (manifold-constrained
+    # hyper-connections, arXiv:2512.24880): each half reads a mix of
+    # the streams and its result goes back through matrices made from
+    # the token itself, the stream-to-stream one doubly stochastic by
+    # ``hc_sinkhorn_iters`` Sinkhorn iterations (:func:`hc_pre`,
+    # :func:`hc_post`). 1 = the one stream, ``x + half(norm(x))``.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -233,11 +260,32 @@ class TransformerConfig:
                 f"head_dim {self.head_dim}"
             )
         if self.layer_mixers is not None:
-            if any(m not in ("attn", "gdn") for m in self.layer_mixers):
+            if any(m not in ("attn", "gdn", "mla")
+                   for m in self.layer_mixers):
                 raise ValueError(
-                    f"layer_mixers holds 'attn' or 'gdn', got "
+                    f"layer_mixers holds 'attn' or 'gdn' or 'mla', got "
                     f"{self.layer_mixers}"
                 )
+            if "mla" in self.layer_mixers:
+                sizes = (self.mla_q_rank, self.mla_kv_rank,
+                         self.mla_nope_dim, self.mla_rope_dim,
+                         self.mla_v_dim)
+                if min(sizes) < 1 or self.mla_rope_dim % 2 or (
+                    self.head_dim != self.mla_nope_dim + self.mla_rope_dim
+                ):
+                    raise ValueError(
+                        "a latent-attention layer needs its five sizes "
+                        "(mla_q_rank, mla_kv_rank, mla_nope_dim, an even "
+                        "mla_rope_dim, mla_v_dim) and d_head = "
+                        f"mla_nope_dim + mla_rope_dim, got {sizes} and "
+                        f"head_dim {self.head_dim}"
+                    )
+                if any(w is not None for w, m in
+                       zip(self.windows, self.layer_mixers) if m == "mla"):
+                    raise ValueError(
+                        "a latent-attention layer attends every earlier "
+                        "position: it takes no window"
+                    )
             if "gdn" in self.layer_mixers and (
                 self.gdn_key_heads < 1
                 or self.gdn_value_heads % self.gdn_key_heads != 0
@@ -248,6 +296,11 @@ class TransformerConfig:
                     "dividing gdn_value_heads, got "
                     f"{self.gdn_key_heads} and {self.gdn_value_heads}"
                 )
+        if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
+            raise ValueError(
+                f"hc_mult {self.hc_mult} and hc_sinkhorn_iters "
+                f"{self.hc_sinkhorn_iters} must be >= 1"
+            )
         if self.route_score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown route_score {self.route_score!r}")
         if self.experts_held is not None:
@@ -286,10 +339,33 @@ class TransformerConfig:
     def rope_at(self, li: int) -> bool:
         return self.rope_full or self.windows[li] is not None
 
+    def mixer(self, li: int) -> str:
+        """Layer ``li``'s token mixer: "attn", "gdn" or "mla"."""
+        return "attn" if self.layer_mixers is None else self.layer_mixers[li]
+
     def gdn(self, li: int) -> bool:
         """Is layer ``li``'s token mixer the gated delta rule?"""
-        return bool(self.layer_mixers is not None
-                    and self.layer_mixers[li] == "gdn")
+        return self.mixer(li) == "gdn"
+
+    def mla(self, li: int) -> bool:
+        """Is layer ``li``'s token mixer latent attention?"""
+        return self.mixer(li) == "mla"
+
+    def cache_heads(self, li: int) -> int:
+        """Heads a position's row has in layer ``li``'s cache: the K/V
+        heads, or the one row a latent layer keeps."""
+        return 1 if self.mla(li) else self.kv_heads
+
+    @property
+    def latent_width(self) -> int:
+        """Width of a latent layer's row: ``[latent | rotated key]``."""
+        return self.mla_kv_rank + self.mla_rope_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        if self.attn_scale is not None:
+            return self.attn_scale
+        return self.head_dim ** -0.5
 
     def layer_like(self, li: int) -> int:
         """The first layer that is treated as ``li`` is: the same token
@@ -297,13 +373,19 @@ class TransformerConfig:
         is all a forward reads of a layer here. Two such layers differ
         in their weights alone, so a program may trace one and call it
         for the other (``decode._grouped_layer``)."""
-        traits = lambda j: (self.gdn(j), self.windows[j], self.dropless(j))
+        traits = lambda j: (self.mixer(j), self.windows[j],
+                            self.dropless(j))
         return next(j for j in range(li + 1) if traits(j) == traits(li))
 
     @property
     def state_layers(self) -> bool:
         """Does any layer keep recurrent state (no row a token)?"""
         return any(self.gdn(li) for li in range(self.n_layers))
+
+    @property
+    def latent_layers(self) -> bool:
+        """Does any layer keep one latent row a position (no K/V heads)?"""
+        return any(self.mla(li) for li in range(self.n_layers))
 
     @property
     def held_experts(self) -> int:
@@ -326,6 +408,8 @@ class TransformerConfig:
             and not (self.qk_norm or self.attn_gate or self.post_norm)
             and self.emb_scale == 1.0 and self.rope_full
             and self.rope_dims is None and not self.state_layers
+            and not self.latent_layers and self.hc_mult == 1
+            and self.rope_table is None and self.attn_scale is None
         )
 
     def expert_width(self) -> int:
@@ -362,6 +446,9 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         if cfg.gdn(li):
             layer = {**norm("ln1"), **init_gdn_layer(rng, cfg),
                      **norm("ln2")}
+        elif cfg.mla(li):
+            layer = {**norm("ln1"), **init_mla_layer(rng, cfg),
+                     **norm("ln2")}
         else:
             layer = {
                 **norm("ln1"),
@@ -381,6 +468,8 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         if cfg.post_norm:
             layer.update(norm("ln1p"))
             layer.update(norm("ln2p"))
+        if cfg.hc_mult > 1:
+            layer.update(init_hc_layer(rng, cfg, li))
         if cfg.dropless(li):
             layer.update(init_topk_layer(rng, cfg))
         elif cfg.n_experts and cfg.layer_experts is None:
@@ -429,6 +518,11 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
     why = []
     if cfg.state_layers:
         why.append("gated delta-rule layers (recurrent state)")
+    if cfg.latent_layers:
+        why.append("latent-attention layers (one row a position, no "
+                   "K/V heads to shard)")
+    if cfg.hc_mult > 1:
+        why.append(f"a residual path of {cfg.hc_mult} streams")
     if len(set(cfg.windows)) > 1:
         why.append("layers of more than one cache width")
     if cfg.layer_experts and any(cfg.layer_experts):
@@ -523,12 +617,45 @@ def _norm(x, p, name, cfg):
     return _ln(x, p[name + "_s"], p[name + "_b"], cfg.norm_eps)
 
 
-def _rope(x, pos, theta: float = 10000.0):
-    """Rotary embedding at base ``theta``; pos carries GLOBAL token
-    positions (L,)."""
+def _rope_freqs(half: int, theta: float, table):
+    """A rotary pair's angle a position: ``theta ** (-i / half)``, or
+    ``table`` (``cfg.rope_table``: one entry a pair)."""
+    if table is None:
+        return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if len(table) != half:
+        raise ValueError(
+            f"rope_table has {len(table)} entries, the head rotates "
+            f"{half} pairs")
+    return jnp.asarray(table, jnp.float32)
+
+
+def yarn_rope_table(dims: int, theta: float, factor: float,
+                    original_max: int, beta_fast: float = 32.0,
+                    beta_slow: float = 1.0) -> tuple:
+    """YaRN's frequencies for ``dims`` rotated dims (``dims / 2``
+    pairs), as ``TransformerConfig(rope_table=...)`` takes them: pair i
+    of base frequency ``f_i = theta ** (-2i / dims)`` keeps ``f_i``
+    where it turns more than ``beta_fast`` times inside
+    ``original_max`` positions, takes ``f_i / factor`` where it turns
+    fewer than ``beta_slow`` times, and a linear blend over the pairs
+    between (the ramp's ends are the floor and the ceiling of the two
+    correction dims, as the published implementations have them)."""
+    half = dims // 2
+    f = theta ** (-np.arange(half, dtype=np.float64) / half)
+    turn = lambda r: (dims * np.log(original_max / (r * 2 * np.pi))
+                      / (2 * np.log(theta)))
+    lo = max(int(np.floor(turn(beta_fast))), 0)
+    hi = min(int(np.ceil(turn(beta_slow))), dims - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return tuple(float(v) for v in f * (1.0 - ramp) + f / factor * ramp)
+
+
+def _rope(x, pos, theta: float = 10000.0, table=None):
+    """Rotary embedding at base ``theta`` (or at ``table``'s
+    frequencies); pos carries GLOBAL token positions (L,)."""
     B, L, H, Dh = x.shape
     half = Dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = _rope_freqs(half, theta, table)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (L, half)
     cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
@@ -579,10 +706,11 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     return q, k, v, gate
 
 
-def attn_merge(x, o, gate, lp, cfg, tp_psum=False):
+def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None):
     """Second part of the attention half: the output gate, the
     out-projection (summed over ``tp`` when the heads were a shard),
-    the norm after the half, the residual."""
+    the norm after the half, the residual (``mix``: :func:`hc_pre`'s,
+    where the residual path is streams)."""
     if gate is not None:
         o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
     a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
@@ -590,7 +718,207 @@ def attn_merge(x, o, gate, lp, cfg, tp_psum=False):
         a = jax.lax.psum(a, "tp")
     if cfg.post_norm:
         a = _norm(a, lp, "ln1p", cfg)
-    return x + a
+    return hc_post(x, a, mix)
+
+
+# The residual path, written once. With ``hc_mult`` = 1 a half is
+# ``x + half(norm(x))``. With n > 1 the stream is n streams (B, L, n,
+# D): a half reads ``h = sum_j Hpre[j] X[j]`` and its result y goes back
+# as ``X'[i] = sum_j Hres[i, j] X[j] + Hpost[i] y``, the three made from
+# the token's own streams (manifold-constrained hyper-connections,
+# arXiv:2512.24880). Every place that adds a half to the stream calls
+# :func:`hc_pre` before it and hands :func:`hc_post` what that returned.
+
+# the implementation's two guards (the published ``hc_eps`` and
+# ``mhc_h_res_clamp_min / _max``): what a Sinkhorn round adds to a sum
+# before dividing by it, and the range the stream-to-stream matrix's
+# exponent is held to
+HC_EPS = 1e-6
+HC_RES_CLAMP = (-30.0, 30.0)
+
+
+def init_hc_layer(rng: np.random.Generator, cfg: TransformerConfig,
+                  li: int) -> dict:
+    """The mixing's leaves for both halves of layer ``li`` (``hc1``: the
+    token mixer's, ``hc2``: the feed-forward's), all float32: ``phi``
+    (n D, 2 n + n * n) laid out ``[pre | post | res]``, the three
+    scalars ``alpha`` and the biases ``b`` in the same layout
+    (:func:`hc_bias`). ``alpha`` is one (away from zero: the matrices
+    follow the token)."""
+    n, D = cfg.hc_mult, cfg.d_model
+    out = {}
+    for k, half in enumerate(("hc1", "hc2")):
+        out[half + "_phi"] = jnp.asarray(
+            rng.standard_normal((n * D, 2 * n + n * n)) / np.sqrt(n * D),
+            jnp.float32)
+        out[half + "_alpha"] = jnp.ones((3,), jnp.float32)
+        out[half + "_b"] = jnp.asarray(hc_bias(n, 2 * li + k), jnp.float32)
+    return out
+
+
+def hc_bias(n: int, k: int) -> np.ndarray:
+    """Where hyper-connections (arXiv:2409.19606) start their static
+    matrices, as the biases of the sigmoid and of the exponential: the
+    model's ``k``-th half reads mostly stream ``k mod n`` (+2 there, -2
+    elsewhere: 0.88 against 0.12), its result goes back to every stream
+    alike (0: ``Hpost`` 1), and a stream mostly keeps to itself (2 on
+    the diagonal of the stream-to-stream part: 0.7 after the rounds).
+    With every half reading every stream alike the streams would be
+    interchangeable and the stream-to-stream matrix would move
+    nothing."""
+    pre = np.where(np.arange(n) == k % n, 2.0, -2.0)
+    return np.concatenate([pre, np.zeros(n), 2.0 * np.eye(n).ravel()])
+
+
+def hc_pre(x, lp, cfg, half: str):
+    """What a half reads and how its result goes back: ``(h, mix)``
+    from the streams x (B, L, n, D) and the leaves ``<half>_phi /
+    _alpha / _b`` of ``lp``. With one stream ``(x, None)``. The token's
+    matrices, float32: ``u = RMSNorm(vec(X))`` over the n D values;
+    ``Hpre = sigmoid(a_pre u phi_pre + b_pre)``, ``Hpost = 2
+    sigmoid(a_post u phi_post + b_post)``, ``Hres`` the clamped
+    exponential of ``a_res u phi_res + b_res`` after
+    ``hc_sinkhorn_iters`` rounds of columns, then rows, divided by
+    their sums + ``HC_EPS``. The matrices are laid out entries first,
+    (n, n, B, L) with the tokens on the minor axes, and the rounds are
+    ONE ``fori_loop`` whose body is two sums over a leading axis and
+    two divisions: unrolled in Python the same rounds are a graph in
+    which every value has five readers twenty levels deep, which the
+    compiler's fusion pass does not come back from."""
+    if cfg.hc_mult == 1:
+        return x, None
+    n = cfg.hc_mult
+    B, L, _, D = x.shape
+    with jax.named_scope("hc_mix"):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=(-2, -1), keepdims=True)
+        u = (xf * jax.lax.rsqrt(ms + cfg.norm_eps)).reshape(B, L, n * D)
+        c = jnp.einsum("blc,cf->fbl", u, lp[half + "_phi"], precision=_HI)
+        alpha = jnp.repeat(lp[half + "_alpha"], np.array([n, n, n * n]))
+        c = alpha[:, None, None] * c + lp[half + "_b"][:, None, None]
+        pre = jax.nn.sigmoid(c[:n])
+        post = 2.0 * jax.nn.sigmoid(c[n:2 * n])
+        res = jnp.exp(jnp.clip(c[2 * n:], *HC_RES_CLAMP)).reshape(
+            n, n, B, L)
+
+        def sinkhorn(_, m):  # m[i, j]: columns over i, rows over j
+            m = m / (m.sum(axis=0, keepdims=True) + HC_EPS)
+            return m / (m.sum(axis=1, keepdims=True) + HC_EPS)
+
+        res = jax.lax.fori_loop(0, cfg.hc_sinkhorn_iters, sinkhorn, res)
+        h = sum(pre[j][..., None] * xf[:, :, j] for j in range(n))
+    return h.astype(x.dtype), (xf, res, post)
+
+
+def hc_post(x, y, mix):
+    """The half's result y back into the stream: ``x + y``, or with
+    streams (``mix`` from :func:`hc_pre`; x is then the mix the half
+    read and is not used) ``X'[i] = sum_j Hres[i][j] X[j] + Hpost[i]
+    y``."""
+    if mix is None:
+        return x + y
+    xf, res, post = mix
+    n = len(post)
+    with jax.named_scope("hc_mix"):
+        yf = y.astype(jnp.float32)
+        out = [sum(res[i, j][..., None] * xf[:, :, j] for j in range(n))
+               + post[i][..., None] * yf for i in range(n)]
+        return jnp.stack(out, axis=2).astype(y.dtype)
+
+
+def hc_fold(x, cfg):
+    """The streams folded into one before the final norm: their sum."""
+    if cfg.hc_mult == 1:
+        return x
+    return x.astype(jnp.float32).sum(axis=2).astype(x.dtype)
+
+
+# Latent attention, written once like the two other mixers. The dense
+# forward attends in the expanded form (every position's keys and
+# values for all heads out of its latent); a cache keeps the ONE row a
+# position and is attended in the absorbed form (:func:`mla_absorb`:
+# the key's up-projection folded into the query, the value's applied to
+# the result), so a cached row is never expanded to heads.
+
+
+def init_mla_layer(rng: np.random.Generator, cfg: TransformerConfig) -> dict:
+    """Leaves of one latent-attention mixer: ``mla_wdq`` (D, q rank)
+    and its norm's scale, ``mla_wuq`` (q rank, H, nope + rope);
+    ``mla_wdkv`` (D, kv rank + rope, laid out ``[latent | key's rotated
+    part]``) and the latent norm's scale, ``mla_wukv`` (kv rank, H,
+    nope + v, ``[key | value]`` a head); ``wo`` (H, v, D)."""
+    D, H = cfg.d_model, cfg.n_heads
+    sd = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[0]), cfg.dtype
+    )
+    return {
+        "mla_wdq": sd(D, cfg.mla_q_rank),
+        "mla_qn_s": jnp.ones((cfg.mla_q_rank,), cfg.dtype),
+        "mla_wuq": sd(cfg.mla_q_rank, H, cfg.head_dim),
+        "mla_wdkv": sd(D, cfg.latent_width),
+        "mla_kvn_s": jnp.ones((cfg.mla_kv_rank,), cfg.dtype),
+        "mla_wukv": sd(cfg.mla_kv_rank, H, cfg.mla_nope_dim + cfg.mla_v_dim),
+        "wo": sd(H, cfg.mla_v_dim, D) / float(np.sqrt(cfg.n_layers)),
+    }
+
+
+def mla_project(x, lp, cfg, rope):
+    """First part of a latent-attention half on (B, L, D): norm, both
+    down-projections with their norms, the query heads, rotary. Returns
+    ``(qn (B, L, H, nope), qr (B, L, H, rope) rotated, row (B, L, 1,
+    kv rank + rope))``; ``row`` is what a cache keeps of the position:
+    the normalised latent beside the one rotated key all heads share."""
+    R, nope = cfg.mla_kv_rank, cfg.mla_nope_dim
+    h = _norm(x, lp, "ln1", cfg)
+    with jax.named_scope("mla_q"):
+        cq = _rms(jnp.einsum("bld,dr->blr", h, lp["mla_wdq"]),
+                  lp["mla_qn_s"], cfg.norm_eps)
+        q = jnp.einsum("blr,rhk->blhk", cq, lp["mla_wuq"])
+        qn, qr = q[..., :nope], rope(q[..., nope:])
+    with jax.named_scope("mla_kv"):
+        ckr = jnp.einsum("bld,dr->blr", h, lp["mla_wdkv"])[:, :, None]
+        ckv = _rms(ckr[..., :R], lp["mla_kvn_s"], cfg.norm_eps)
+        row = jnp.concatenate([ckv, rope(ckr[..., R:])], axis=-1)
+    return qn, qr, row
+
+
+def mla_absorb(qn, qr, lp, cfg):
+    """The absorbed query ``[qn Wuk^T | qr]`` (B, L, H, kv rank +
+    rope): its product with a cached row is the head's whole score."""
+    with jax.named_scope("mla_q"):
+        qa = jnp.einsum("blhk,rhk->blhr", qn,
+                        lp["mla_wukv"][..., :cfg.mla_nope_dim])
+        return jnp.concatenate([qa, qr], axis=-1)
+
+
+def mla_expanded(qn, qr, row, lp, cfg):
+    """Causal attention of a whole sequence over itself in the expanded
+    form: every position's keys and values for all heads out of its
+    latent. Returns (B, L, H, v)."""
+    R, nope = cfg.mla_kv_rank, cfg.mla_nope_dim
+    L = row.shape[1]
+    kv = jnp.einsum("blr,rhk->blhk", row[:, :, 0, :R], lp["mla_wukv"])
+    s = jnp.einsum("bqhd,bkhd->bhqk", qn, kv[..., :nope],
+                   preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("bqhd,bkd->bhqk", qr, row[:, :, 0, R:],
+                       preferred_element_type=jnp.float32)
+    seen = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
+    p = jax.nn.softmax(
+        jnp.where(seen[None, None], s * cfg.softmax_scale, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhv->bqhv", p.astype(qn.dtype), kv[..., nope:])
+
+
+def mla_merge(x, o, lp, cfg, mix=None, latent=False):
+    """Second part of the latent-attention half: the out-projection
+    and the residual. ``latent``: ``o`` is the absorbed form's (B, L,
+    H, kv rank) and goes through the value's up-projection first;
+    else the expanded form's (B, L, H, v)."""
+    with jax.named_scope("mla_out"):
+        if latent:
+            o = jnp.einsum("blhr,rhv->blhv", o.astype(x.dtype),
+                           lp["mla_wukv"][..., cfg.mla_nope_dim:])
+        a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
+    return hc_post(x, a, mix)
 
 
 # The gated delta-rule half, written once like the attention half: the
@@ -710,7 +1038,7 @@ def _delta_rule_chunks(q, k, v, g, beta, S):
     return o[:, :T], S
 
 
-def gdn_half(x, lp, state, cfg, valid=None):
+def gdn_half(x, lp, state, cfg, valid=None, mix=None):
     """Layer's gated delta-rule half on (B, T, D) from ``state``
     (:func:`gdn_zero_state`'s leaves): norm, projections, the causal
     depthwise conv, the recurrence, the gated norm over each value
@@ -719,7 +1047,8 @@ def gdn_half(x, lp, state, cfg, valid=None):
     real: the rows after them are a padded prompt's tail and leave
     ``S`` and the conv rows as the last real row left them. A vector
     ``(B,)`` gives every row of the batch its own count (the chunks of
-    several requests in one program, ``decode._grouped_hidden``)."""
+    several requests in one program, ``decode._grouped_hidden``).
+    ``mix``: :func:`hc_pre`'s, where the residual path is streams."""
     B, T, _ = x.shape
     Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
     Dk, Dv = cfg.gdn_key_dim, cfg.gdn_value_dim
@@ -777,7 +1106,7 @@ def gdn_half(x, lp, state, cfg, valid=None):
         o = o * jax.nn.silu(z.astype(jnp.float32)).reshape(B, T, Hv, Dv)
         a = jnp.einsum("blc,cd->bld", o.reshape(B, T, vw).astype(x.dtype),
                        lp["gdn_wout"])
-    return x + a, {"S": S, "conv": tail}
+    return hc_post(x, a, mix), {"S": S, "conv": tail}
 
 
 def _mlp(x, lp):
@@ -796,7 +1125,9 @@ def ffn_half(x, lp, cfg, li, *, tp_psum=False):
     ``(x, aux, hit)``: the Switch layer's load-balance loss (0
     elsewhere) and, for a dropless expert layer, how many experts got
     at least one token (None elsewhere). ``tp_psum`` is the sharded
-    programs' (plain block only): hidden widths are ``tp`` shards."""
+    programs' (plain block only): hidden widths are ``tp`` shards.
+    Where the residual path is streams, x is the streams."""
+    x, mix = hc_pre(x, lp, cfg, "hc2")
     h = _norm(x, lp, "ln2", cfg)
     aux, hit = jnp.float32(0.0), None
     if cfg.dropless(li):
@@ -815,24 +1146,27 @@ def ffn_half(x, lp, cfg, li, *, tp_psum=False):
         y = _mlp(h, lp)
         if tp_psum:
             y = jax.lax.psum(y, "tp")  # d_ff shard partial-sum
-        if not cfg.post_norm:
+        if not cfg.post_norm and mix is None:
             return x + y + lp["b2"], aux, hit  # b2 replicated
         y = y + lp["b2"]
     if cfg.post_norm:
         y = _norm(y, lp, "ln2p", cfg)
-    return x + y, aux, hit
+    return hc_post(x, y, mix), aux, hit
 
 
 def embed(params, tokens, cfg):
     x = params["emb"][tokens]
     if cfg.emb_scale != 1.0:
         x = x * jnp.asarray(cfg.emb_scale, x.dtype)
+    if cfg.hc_mult > 1:  # every stream starts as the embedding
+        x = jnp.broadcast_to(
+            x[:, :, None], x.shape[:2] + (cfg.hc_mult, x.shape[-1]))
     return x
 
 
 def head_logits(params, x, cfg):
-    """Final norm and the output head on (B, L, D): the tied embedding,
-    or ``params["head"]``."""
+    """Final norm and the output head on (B, L, D) (streams already
+    folded, :func:`hc_fold`): the tied embedding, or ``params["head"]``."""
     x = _norm(x, params, "lnf", cfg)
     w = params["emb"] if cfg.tie_head else params["head"]
     return jnp.einsum("bld,vd->blv", x, w)
@@ -867,13 +1201,21 @@ def _local_attention(cfg: TransformerConfig):
 
 
 def _mixer_dense(x, lp, cfg, li: int, rope, impl):
-    """Layer ``li``'s token mixer over a whole sequence: attention, or
-    the gated delta rule from a zero state."""
+    """Layer ``li``'s token mixer over a whole sequence: attention, the
+    gated delta rule from a zero state, or latent attention in its
+    expanded form."""
+    x, mix = hc_pre(x, lp, cfg, "hc1")
     if cfg.gdn(li):
-        return gdn_half(x, lp, gdn_zero_state(cfg, x.shape[0]), cfg)[0]
+        return gdn_half(x, lp, gdn_zero_state(cfg, x.shape[0]), cfg,
+                        mix=mix)[0]
+    if cfg.mla(li):
+        qn, qr, row = mla_project(x, lp, cfg, rope)
+        with jax.named_scope("mla_attn"):
+            o = mla_expanded(qn, qr, row, lp, cfg)
+        return mla_merge(x, o, lp, cfg, mix)
     q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
     o = impl(q, k, v, causal=True, window=cfg.windows[li])
-    return attn_merge(x, o, gate, lp, cfg)
+    return attn_merge(x, o, gate, lp, cfg, mix=mix)
 
 
 def forward_dense(params: dict, tokens: jax.Array, cfg: TransformerConfig):
@@ -886,7 +1228,8 @@ def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
     """Dense forward returning (logits, summed MoE aux loss)."""
     pos = jnp.arange(tokens.shape[1])
     x = embed(params, tokens, cfg)
-    rope = partial(_rope, pos=pos, theta=cfg.rope_theta)
+    rope = partial(_rope, pos=pos, theta=cfg.rope_theta,
+                   table=cfg.rope_table)
     impl = resolve_attention_impl(cfg.attn_impl)
 
     def one_layer(x, lp, li):
@@ -900,7 +1243,7 @@ def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
     for li, lp in enumerate(params["layers"]):
         x, a = layer_fn(x, lp, li)
         aux = aux + a
-    return head_logits(params, x, cfg), aux
+    return head_logits(params, hc_fold(x, cfg), cfg), aux
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig):

@@ -36,3 +36,32 @@ from mpistragglers_jl_tpu.utils.compile_cache import (  # noqa: E402
 )
 
 wire_compile_cache()
+
+
+def pytest_collection_modifyitems(items):
+    """tests/chipbench/test_chunks_per_program.py (PR 33) asserts that
+    ITS metric is ``per_layer[-1]`` of BENCHMARK.json, where it means
+    that the entry is there and lists the serving cells. The driver's
+    contract for a PR that adds a per-layer metric: new entries go at
+    the END of their lists ("one put first or in the middle reads as a
+    change to what was there", which refuses the PR before any run),
+    and no file under the benchmark's ``paths`` is edited, that test
+    among them. So since PR 34 the first assertion cannot hold, and
+    only a ``benchmark`` PR may repair it (PERF.md section 7: look the
+    entry up by name, then drop this hook). Until then
+    ``tests/chipbench/test_serve_mla.py::
+    test_the_manifest_still_lists_chunks_per_prefill_program`` holds
+    the entry to everything that test asserts, by name, and the old
+    test is a STRICT expected failure of that one assertion: it fails
+    the run if it passes (the entry is last again: drop the hook) or if
+    it stops for any other reason than the assertion."""
+    import pytest
+
+    for item in items:
+        if item.nodeid.endswith(
+                "test_chunks_per_program.py::"
+                "test_the_manifest_lists_it_for_the_serving_cells"):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts per_layer[-1]; metrics are appended "
+                       "after it since PR 34",
+                raises=AssertionError, strict=True))

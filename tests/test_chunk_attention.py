@@ -42,9 +42,11 @@ from mpistragglers_jl_tpu.parallel.ring_attention import _band_mask
 T, D = 32, 16  # a chunk's rows, a head's width
 
 
-def _dense_masked_attention(q, cache_l, qpos, scale, window):
+def _dense_masked_attention(q, cache_l, qpos, scale, window, latent=None):
     """The whole cache scored at once: what ``_cached_attention`` did
-    for a chunk before the walk, and still does for one query."""
+    for a chunk before the walk, and still does for one query
+    (``_chunk_attention``'s parameters; K/V rows only)."""
+    assert latent is None
     Lmax = cache_l["k"].shape[1]
     s = _cache_scores(q, cache_l, scale)  # (B, H, T, Lmax) f32
     mask = _band_mask(qpos, jnp.arange(Lmax), True, window)

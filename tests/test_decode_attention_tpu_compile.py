@@ -114,3 +114,55 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip):
     _compiled_text(
         tr._delta_rule_step, f32(16, H, Dk), f32(16, H, Dk), f32(16, H, Dv),
         f32(16, H), f32(16, H), f32(16, H, Dk, Dv))
+
+
+def test_absorbed_latent_decode_compiles_at_published_widths(one_chip):
+    """The tick's latent attention for 16 slots over their gathered
+    rings of 68 pages x 64 rows: 32 absorbed query heads against one
+    576-wide int8 row a position with its two scales, the result the
+    row's first 512 dims; and the row's write before it."""
+    from mpistragglers_jl_tpu.models import serving
+
+    S, W, H, R, rope = 16, 68 * 64, 32, 512, 64
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, row, k, ks, pos):
+        ring = serving._ring_write_rows({"k": k, "k_s": ks}, row, None,
+                                        jnp.mod(pos, W), R)
+        return serving._ring_attention_rows(
+            q, ring, pos, 192 ** -0.5, latent=R), ring
+
+    text = _compiled_text(
+        call, sds((S, 1, H, R + rope), jnp.bfloat16),
+        sds((S, 1, 1, R + rope), jnp.bfloat16),
+        sds((S, W, 1, R + rope), jnp.int8), sds((S, W, 2), jnp.float32),
+        sds((S,), jnp.int32))
+    # no copy of every slot's ring at its expanded width (32 heads of
+    # 256) exists: the rows are read as the one head they are
+    assert f"{S},{W},{H}," not in text and f"{S},{H},{W},256" not in text
+    assert f"bf16[{S},1,{H},{R}]" in text or f"f32[{S},1,{H},{R}]" in text
+
+
+def test_chunk_latent_attention_compiles_at_published_widths(one_chip):
+    """A prefill chunk's 256 absorbed queries a head walking the key
+    blocks of a 4,096-row arena of int8 latent rows (the walk's
+    ``fori_loop``, one block's scores at a time)."""
+    from mpistragglers_jl_tpu.models import decode
+
+    T, L, H, R, rope = 256, 4096, 32, 512, 64
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, row, k, ks, off):
+        cache = decode._cache_write({"k": k, "k_s": ks}, row, None, off, R)
+        qpos = off + jnp.arange(T)
+        return decode._cached_attention(
+            q, cache, qpos, 192 ** -0.5, latent=R), cache
+
+    text = _compiled_text(
+        call, sds((1, T, H, R + rope), jnp.bfloat16),
+        sds((1, T, 1, R + rope), jnp.bfloat16),
+        sds((1, L, 1, R + rope), jnp.int8), sds((1, L, 2), jnp.float32),
+        sds((), jnp.int32))
+    assert "while" in text  # the walk over key blocks
+    # a block's scores, never the arena's: (H, T, 512), not (H, T, 4096)
+    assert f"{H},{T},{L}]" not in text
