@@ -57,6 +57,7 @@ _HOME = {
     "moe_ffn_sharded": "moe",
     "init_topk_layer": "moe",
     "topk_route": "moe",
+    "group_tiling": "moe",
     "grouped_matmul": "moe",
     "moe_ffn_topk": "moe",
     "ring_widths": "decode",

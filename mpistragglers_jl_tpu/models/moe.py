@@ -54,6 +54,7 @@ __all__ = [
     "moe_ffn_sharded",
     "init_topk_layer",
     "topk_route",
+    "group_tiling",
     "grouped_matmul",
     "moe_ffn_topk",
 ]
@@ -476,6 +477,35 @@ def topk_route(x2d: jax.Array, router: jax.Array, bias, k: int,
 # against 13.40 to 13.47 in the ragged tile's eight; in the traced
 # windows the prefill programs' ``gmm`` takes 0.502 against 0.538 s of
 # 4 s and the tick's the same (PERF.md section 6, PR 34).
+# Over more than two row tiles the k tile is all of K
+# (:func:`group_tiling`). Read on one v5e chip (my chip run, PR 35;
+# bfloat16, every token's experts drawn evenly, the three products of
+# one layer) at this tile / with K whole / with every group's rows
+# padded to whole row tiles under this tile (the way not taken: its
+# rows, and the row-wise work around the products that is not in
+# these readings, grow from M to M + E * 127):
+#   Trinity-Mini, 128 experts, 2048 -> 1024 -> 2048, top 8: a decode
+#   step's 128 rows 1.583 / 1.580 ms (the same tile); a chunk's 2048
+#   rows 2.579 / 2.417 / 2.762; a group of four chunks' 8192 rows
+#   3.471 / 3.027 / 2.876 (the bytes of 128 experts: 1.97 ms);
+#   Xing4.0, 64 experts, 3584 -> 1024 -> 3584, top 4: 64 rows 1.260 /
+#   1.263; 1024 rows 2.223 / 2.097 / 2.348; 4096 rows 2.964 / 2.610 /
+#   2.356 (the bytes: 1.72 ms);
+#   Qwen3-Next, 256 held of 512 experts, 2048 -> 512 -> 2048, top 10:
+#   160 rows 0.704 / 0.700; 2560 rows 2.524 / 2.318 / 3.189; 10240
+#   rows 2.851 / 2.534 / 3.329 (the bytes: 1.97 ms).
+# The gate product alone at Trinity's 8192 rows: 1.266 / 1.007 /
+# 0.911 ms where its bytes are 0.66: with K whole each expert is read
+# once (191 visits, 128 reads; this tile reads at all 191), and what is
+# left is the kernel's: a visit of a group already in VMEM computes
+# with no read beside it to hide behind, and every visit moves its row
+# and output blocks. A row tile of 256 with K whole read the same or
+# worse (3.043, 2.615, 2.855 at the groups' rows), an n tile of 512 at
+# K 2048 worse (3.135). Not taken: with K whole the down product
+# (K 1024 or 512) could have all of N as its n tile too, 1.150 ->
+# 1.086 ms at Trinity's 8192 rows and 0.961 -> 0.911 at Qwen3-Next's
+# 10240 (0.25 ms a program of four, under what the pairs can show).
+# End to end: PERF.md section 6, PR 35.
 _GROUP_TILE = (128, 1024, 1024)
 
 
@@ -490,6 +520,37 @@ def _width_tile(dim: int, cap: int) -> int:
                default=cap)
 
 
+# Bytes of one right-hand block where the k tile is all of K. The
+# pipeline holds two of them beside two row blocks, two output blocks
+# and the float32 sums, inside the 16 MiB of scoped VMEM a kernel gets
+# by default (``gmm`` hands no limit on): at (128, 2048, 1024) in
+# bfloat16 that is 8 + 1 + 0.5 + 0.5 MiB.
+_WHOLE_K_BLOCK = 4 << 20
+
+
+def group_tiling(M: int, K: int, N: int, itemsize: int):
+    """The (m, k, n) tile :func:`grouped_matmul` hands the kernel for
+    ``M`` rows against (K, N) matrices of ``itemsize`` bytes a number.
+
+    Up to two row tiles (a decode step's pairs): ``_GROUP_TILE``, each
+    width's tile from :func:`_width_tile`. Over more row tiles (a
+    prefill chunk's pairs, a group of chunks') many groups straddle a
+    row tile's edge and are visited once per row tile they touch. The
+    kernel fetches a right-hand block at every grid step whose block
+    index differs from the step before: with two k tiles that is
+    every visit, with ONE the visits of a group share its block and
+    its matrix is read once per n tile. So there the k tile is all of
+    K, and the n tile the widest that keeps the block within
+    ``_WHOLE_K_BLOCK`` (K 2048: 1024; 3584: 512). A K too wide for
+    even 128 columns of that keeps the first rule."""
+    tm, ck, cn = _GROUP_TILE
+    tm = tm if M >= tm else -(-M // 8) * 8
+    fit = _WHOLE_K_BLOCK // (K * itemsize) // 128 * 128
+    if M > 2 * tm and fit:
+        return tm, K, _width_tile(N, min(cn, fit))
+    return tm, _width_tile(K, ck), _width_tile(N, cn)
+
+
 def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
                    out_dtype) -> jax.Array:
     """``xs[rows of group e] @ w[e]`` for every group: ``xs`` (M, K)
@@ -497,7 +558,8 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
     (E, K, N) and ``sizes`` (E,) int32 counts each group's rows (zero
     allowed). One Pallas call (``megablox.gmm``) that visits, tile by
     tile, only the (row tile, group) pairs that exist: it reads the
-    matrices of the groups that have rows and multiplies each row once.
+    matrices of the groups that have rows and multiplies each row once
+    (:func:`group_tiling`: once a group, too, where the rows are many).
     Rows are padded to the kernel's row tile; the padding belongs to
     no group and is cut off again."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -505,15 +567,12 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, sizes: jax.Array,
     from ..ops.flash_attention import _use_interpret
 
     M, K = xs.shape
-    N = w.shape[2]
-    tm = _GROUP_TILE[0] if M >= _GROUP_TILE[0] else -(-M // 8) * 8
-    pad = -M % tm
+    tiling = group_tiling(M, K, w.shape[2], w.dtype.itemsize)
+    pad = -M % tiling[0]
     if pad:
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
     out = gmm(
-        xs, w, sizes, preferred_element_type=out_dtype,
-        tiling=(tm, _width_tile(K, _GROUP_TILE[1]),
-                _width_tile(N, _GROUP_TILE[2])),
+        xs, w, sizes, preferred_element_type=out_dtype, tiling=tiling,
         interpret=_use_interpret(),
     )
     return out[:M] if pad else out

@@ -159,6 +159,7 @@ from .decode import (
     _zero_latent_layer,
     ring_widths,
 )
+from .moe import group_tiling
 from .paging import (
     NULL_PAGE,
     PagePool,
@@ -1855,6 +1856,18 @@ class ServingScheduler:
             if self._group > 1 else None
         )
         self._scratch_arenas: list[list[dict]] | None = None
+        # ``serving.prefill_chunk``'s ``expert_tile``, by the chunks
+        # the program holds: the k x n tile its grouped gate and up
+        # products take (``moe.group_tiling``: whole K says each
+        # expert is read once); nothing without expert layers
+        w = next((lp["we_gate"] for lp in params["layers"]
+                  if "we_gate" in lp), None)
+        self._expert_tile = {} if w is None else {
+            n: {"expert_tile": "{1}x{2}".format(*group_tiling(
+                n * self.C * cfg.experts_per_token, *w.shape[1:],
+                w.dtype.itemsize))}
+            for n in (1, self._group)
+        }
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -3199,6 +3212,7 @@ class ServingScheduler:
         offs = [st.base + st.next_chunk * C for st in sts]
         each = lambda values: (values[0] if n == 1
                                else ",".join(map(str, values)))
+        size = 1 if n == 1 else self._group
         with _annotate(
             "serving.prefill_chunk", chunks=n,
             req=each([st.req.id for st in sts]), slot=each(slots),
@@ -3207,12 +3221,12 @@ class ServingScheduler:
             rows_seen=sum(_chunk_rows_seen(off, C, self.Lmax,
                                            self.cfg.windows)
                           for off in offs),
+            **self._expert_tile.get(size, {}),
         ):
             # host arrays and numpy scalars go to the device with the
             # program's own dispatch; an eager slice or ``jnp.int32``
             # is a dispatch (and a transfer) of its own, each a stretch
             # in which the device waits for the host
-            size = 1 if n == 1 else self._group
             chunks = np.zeros((size, C), np.int32)
             for i, st in enumerate(sts):
                 chunks[i] = st.padded[0, st.next_chunk * C:

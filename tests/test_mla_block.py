@@ -303,10 +303,29 @@ def test_grouped_product_where_the_tile_does_not_divide(monkeypatch, K, N,
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_the_tile_of_the_widths_the_benchmark_has(monkeypatch):
-    """Trinity's and Qwen3-Next's widths are handed to the kernel at
-    (128, 1024, 1024), a narrower width whole, and 3584 at its widest
-    divisor."""
+# (K, N) of the benchmark's expert matrices (Trinity-Mini's gate / up
+# and down, Qwen3-Next's gate / up, Xing4.0's gate / up and down): the
+# (k, n) tile of a decode step's rows, then of a chunk's and a group's
+WIDTHS = {
+    (2048, 1024): ((1024, 1024), (2048, 1024)),
+    (1024, 2048): ((1024, 1024), (1024, 1024)),
+    (2048, 512): ((1024, 512), (2048, 512)),
+    (3584, 1024): ((1792, 1024), (3584, 512)),
+    (1024, 3584): ((1024, 1792), (1024, 1792)),
+}
+
+
+@pytest.mark.parametrize("K,N", WIDTHS)
+@pytest.mark.parametrize("rows", [
+    64, 128, 160,        # a decode step's pairs (160: two row tiles)
+    1024, 2048, 2560,    # a prefill chunk's
+    4096, 8192, 10240,   # a group of four chunks'
+])
+def test_the_tile_of_the_widths_the_benchmark_has(monkeypatch, rows, K, N):
+    """A decode step's rows are handed to the kernel at (128, 1024,
+    1024), a narrower width whole, and 3584 at its widest divisor; a
+    chunk's and a group's take K whole and the n tile whose block
+    stays within 4 MiB of bfloat16."""
     from jax.experimental.pallas.ops.tpu import megablox
 
     seen = []
@@ -316,12 +335,54 @@ def test_the_tile_of_the_widths_the_benchmark_has(monkeypatch):
         return jnp.zeros((xs.shape[0], w.shape[2]), xs.dtype)
 
     monkeypatch.setattr(megablox, "gmm", gmm)
-    for K, N in ((2048, 1024), (1024, 2048), (2048, 512), (3584, 1024),
-                 (1024, 3584)):
-        moe.grouped_matmul(jnp.zeros((256, K)), jnp.zeros((2, K, N)),
-                           jnp.array([100, 156]), jnp.float32)
-    assert seen == [(128, 1024, 1024), (128, 1024, 1024), (128, 1024, 512),
-                    (128, 1792, 1024), (128, 1024, 1792)]
+    jax.eval_shape(
+        lambda xs, w: moe.grouped_matmul(
+            xs, w, jnp.array([60, rows - 60]), jnp.float32),
+        jax.ShapeDtypeStruct((rows, K), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, K, N), jnp.bfloat16))
+    tk, tn = WIDTHS[K, N][rows > 2 * 128]
+    assert seen == [(min(rows, 128), tk, tn)]
+    assert tk * tn * 2 <= moe._WHOLE_K_BLOCK
+
+
+@pytest.mark.parametrize("M,K,N,block,tile", [
+    (48, 512, 256, 4 << 20, (512, 256)),   # two k tiles became one
+    (48, 896, 384, 4 << 20, (896, 384)),   # 3.5 k tiles; n follows N
+    (48, 512, 256, 512 * 128 * 4, (512, 128)),  # the n tile shrinks to fit
+    (48, 512, 256, 512 * 127 * 4, (256, 256)),  # K too wide: as before
+    (16, 448, 384, 4 << 20, (256, 384)),   # two row tiles: ragged k tile
+])
+def test_grouped_product_with_k_whole_reads_what_the_plain_sum_does(
+        monkeypatch, M, K, N, block, tile):
+    """More than two row tiles: the k tile is all of K. Groups that
+    straddle a row tile's edge, an empty group, and rows of no group
+    behind the last (a layer that holds a share of its experts),
+    against the plain product, in the Pallas interpreter."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    monkeypatch.setattr(moe, "_GROUP_TILE", (8, 256, 256))
+    monkeypatch.setattr(moe, "_WHOLE_K_BLOCK", block)
+    assert moe.group_tiling(M, K, N, 4) == (8, *tile)
+    real, seen = megablox.gmm, []
+
+    def gmm(*a, tiling, **kw):
+        seen.append(tiling)
+        return real(*a, tiling=tiling, **kw)
+
+    monkeypatch.setattr(megablox, "gmm", gmm)
+    rng = np.random.default_rng(1)
+    sizes = np.array({48: [5, 0, 13, 9, 3, 11], 16: [1, 0, 3, 2, 1, 4]}[M],
+                     np.int32)
+    held = int(sizes.sum())  # the rows behind them are no group's
+    assert held < M and all(sizes.cumsum() % 8 != 0)
+    xs = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((6, K, N)) / np.sqrt(K), jnp.float32)
+    got = moe.grouped_matmul(xs, w, jnp.asarray(sizes), jnp.float32)
+    assert seen == [(8, *tile)] and got.shape == (M, N)
+    group = np.repeat(np.arange(6), sizes)
+    with jax.default_matmul_precision("highest"):
+        want = jnp.einsum("mk,mkn->mn", xs[:held], w[group])
+    np.testing.assert_allclose(got[:held], want, atol=2e-5)
 
 
 # -- the scheduler ---------------------------------------------------------------
@@ -365,7 +426,9 @@ def test_a_shared_prefix_page_is_shared():
     assert a.tokens == b.tokens
 
 
-def test_spans_carry_latent_rows():
+def _spans_of_a_run(sched):
+    """Run ``sched`` until it is empty; the ``serving.*`` spans it
+    entered, in order."""
     from mpistragglers_jl_tpu.obs import timeline
 
     seen = []
@@ -384,9 +447,6 @@ def test_spans_carry_latent_rows():
         def set_metadata(self, **args):
             self.args.update(args)
 
-    sched = _sched()
-    for n in (20, 9):
-        sched.submit(_tokens(n, seed=n), 6)
     real = serving._annotate
     serving._annotate = Spy
     try:
@@ -394,6 +454,14 @@ def test_spans_carry_latent_rows():
     finally:
         serving._annotate = real
     assert timeline.annotate is real
+    return seen
+
+
+def test_spans_carry_latent_rows():
+    sched = _sched()
+    for n in (20, 9):
+        sched.submit(_tokens(n, seed=n), 6)
+    seen = _spans_of_a_run(sched)
     ticks = [s for s in seen if s.name == "serving.tick"]
     # counted as the tick begins: nothing decodes in the first; the
     # 9-token prompt is placed in it and decodes 4 steps; in the second
@@ -403,6 +471,32 @@ def test_spans_carry_latent_rows():
     chunks = [s for s in seen if s.name == "serving.prefill_chunk"]
     assert [(c.args["chunks"], c.args["rows_seen"]) for c in chunks] == [
         (2, 16 + 16), (1, 32)]
+
+
+@pytest.mark.parametrize("experts", [True, False])
+def test_the_chunk_span_names_the_tile_of_its_expert_products(experts):
+    """``expert_tile`` on ``serving.prefill_chunk``: the k x n tile the
+    program's grouped gate and up products take for the rows it holds
+    (a lone chunk's 16 x 2 pairs, the grouped program's three times
+    that); a block without expert layers has no such product."""
+    if experts:
+        sched = _sched()
+    else:
+        cfg = TransformerConfig(vocab=97, d_model=32, n_heads=4,
+                                n_kv_heads=2, n_layers=2, d_ff=64,
+                                attn_window=64)
+        sched = ServingScheduler(
+            init_params(cfg, seed=5), cfg, slots=3, n_inner=4,
+            quantize_kv=True, page_tokens=P, prompt_chunk=16, max_prompt=64)
+    for n in (20, 9):
+        sched.submit(_tokens(n, seed=n), 6)
+    chunks = [s for s in _spans_of_a_run(sched)
+              if s.name == "serving.prefill_chunk"]
+    assert [c.args["chunks"] for c in chunks] == [2, 1]
+    if experts:  # (32, 16) matrices, whole at 96 and at 32 rows
+        assert [c.args["expert_tile"] for c in chunks] == ["32x16"] * 2
+    else:
+        assert not any("expert_tile" in c.args for c in chunks)
 
 
 # -- refusals, each by mechanism ---------------------------------------------
