@@ -533,7 +533,9 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     ``use_kernel`` is the program's resolved route (the module note).
     """
     if q.shape[1] > 1:
-        return _chunk_attention(q, cache_l, qpos, scale, window, latent)
+        with jax.named_scope("chunk_attn"):
+            return _chunk_attention(q, cache_l, qpos, scale, window,
+                                    latent)
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 

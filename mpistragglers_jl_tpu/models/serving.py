@@ -562,7 +562,9 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     q, k, v, gate = attn_qkv(h, lp, cfg, li, rope, kv_slice)
     scale = cfg.softmax_scale
     # scopes name the K/V traffic (cache write, scores, softmax, p @ v)
-    # and the MLP in a device trace; the projections stay outside both
+    # and the MLP in a device trace; the projections are under
+    # ``attn_qkv`` / ``attn_out``, which models/transformer.py's block
+    # opens itself, and the feed-forward's ``ffn`` nests in ``decode_mlp``
     with jax.named_scope("decode_attn"):
         if paged is not None:
             # kernel route only: the einsum paged tick runs THIS
@@ -656,8 +658,11 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
             params, tok, pos, caches, cfg, kv_slice=kv_slice,
             tp_psum=tp_psum, use_kernel=use_kernel, paged=paged,
         )
-        nxt = _pick_rows(lg, pos, keys, temperature, top_k, tok.dtype)
-        nxt, done = _eos_clamp(nxt, tok, done, eos_id)
+        # the compiler fuses the pick into the head's product; one
+        # scope over both keeps that fusion's time under ``head``
+        with jax.named_scope("head"):
+            nxt = _pick_rows(lg, pos, keys, temperature, top_k, tok.dtype)
+            nxt, done = _eos_clamp(nxt, tok, done, eos_id)
         out = nxt if hits is None else (nxt, hits.astype(nxt.dtype))
         return (nxt, pos + 1, done, caches), out
 
@@ -1102,10 +1107,11 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
             last_hidden, true_len - 1 - last_off, 1, axis=1
         )
         lg = head_logits(params, row, cfg)[:, 0]  # (1, V)
-        tok0 = _pick_rows(
-            lg, (true_len - 1)[None], key[None], temperature,
-            top_k, jnp.int32,
-        )[0]
+        with jax.named_scope("head"):  # as in ``_scan_body``
+            tok0 = _pick_rows(
+                lg, (true_len - 1)[None], key[None], temperature,
+                top_k, jnp.int32,
+            )[0]
         return tok0, ring
 
     return serving_first_token

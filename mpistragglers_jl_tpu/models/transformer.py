@@ -41,7 +41,7 @@ compute on real chips.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial, wraps
 from typing import Any
 
 import jax
@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.timeline import annotate
 from ..parallel.ring_attention import (
     _flash_interpreted,
     resolve_attention_impl,
@@ -688,22 +689,23 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     (None: all). ``kv_slice`` post-selects kv heads from
     tp-replicated K/V projections (the GQA kv_heads < tp case — see
     :func:`_kv_tp_sharded`)."""
-    h = _norm(x, lp, "ln1", cfg)
-    q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
-    k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
-    v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
-    if kv_slice is not None:
-        k, v = kv_slice(k), kv_slice(v)
-    if cfg.qk_norm:
-        q = _rms(q, lp["qn_s"], cfg.norm_eps)
-        k = _rms(k, lp["kn_s"], cfg.norm_eps)
-    if cfg.rope_at(li):
-        q = _rope_leading(rope, q, cfg.rope_dims)
-        k = _rope_leading(rope, k, cfg.rope_dims)
-    gate = None
-    if cfg.attn_gate:
-        gate = jnp.einsum("bld,dhk->blhk", h, lp["wog"])
-    return q, k, v, gate
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, lp, "ln1", cfg)
+        q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
+        k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
+        v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
+        if kv_slice is not None:
+            k, v = kv_slice(k), kv_slice(v)
+        if cfg.qk_norm:
+            q = _rms(q, lp["qn_s"], cfg.norm_eps)
+            k = _rms(k, lp["kn_s"], cfg.norm_eps)
+        if cfg.rope_at(li):
+            q = _rope_leading(rope, q, cfg.rope_dims)
+            k = _rope_leading(rope, k, cfg.rope_dims)
+        gate = None
+        if cfg.attn_gate:
+            gate = jnp.einsum("bld,dhk->blhk", h, lp["wog"])
+        return q, k, v, gate
 
 
 def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None):
@@ -711,14 +713,15 @@ def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None):
     out-projection (summed over ``tp`` when the heads were a shard),
     the norm after the half, the residual (``mix``: :func:`hc_pre`'s,
     where the residual path is streams)."""
-    if gate is not None:
-        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-    a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
-    if tp_psum:
-        a = jax.lax.psum(a, "tp")
-    if cfg.post_norm:
-        a = _norm(a, lp, "ln1p", cfg)
-    return hc_post(x, a, mix)
+    with jax.named_scope("attn_out"):
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+        a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
+        if tp_psum:
+            a = jax.lax.psum(a, "tp")
+        if cfg.post_norm:
+            a = _norm(a, lp, "ln1p", cfg)
+        return hc_post(x, a, mix)
 
 
 # The residual path, written once. With ``hc_mult`` = 1 a half is
@@ -1127,49 +1130,52 @@ def ffn_half(x, lp, cfg, li, *, tp_psum=False):
     at least one token (None elsewhere). ``tp_psum`` is the sharded
     programs' (plain block only): hidden widths are ``tp`` shards.
     Where the residual path is streams, x is the streams."""
-    x, mix = hc_pre(x, lp, cfg, "hc2")
-    h = _norm(x, lp, "ln2", cfg)
-    aux, hit = jnp.float32(0.0), None
-    if cfg.dropless(li):
-        y, hit = moe_ffn_topk(h, lp, cfg)
-    elif cfg.n_experts and cfg.layer_experts is None:
-        if tp_psum:
-            # expert hidden dims are tp shards; bias rides outside the
-            # psum (it is tp-replicated, see moe_ffn_sharded)
-            y, ybias, aux = moe_ffn_sharded(h, lp, cfg.capacity_factor)
-            y = jax.lax.psum(y, "tp") + ybias
+    with jax.named_scope("ffn"):
+        x, mix = hc_pre(x, lp, cfg, "hc2")
+        h = _norm(x, lp, "ln2", cfg)
+        aux, hit = jnp.float32(0.0), None
+        if cfg.dropless(li):
+            y, hit = moe_ffn_topk(h, lp, cfg)
+        elif cfg.n_experts and cfg.layer_experts is None:
+            if tp_psum:
+                # expert hidden dims are tp shards; bias rides outside
+                # the psum (it is tp-replicated, see moe_ffn_sharded)
+                y, ybias, aux = moe_ffn_sharded(h, lp, cfg.capacity_factor)
+                y = jax.lax.psum(y, "tp") + ybias
+            else:
+                y, aux = moe_ffn_dense(h, lp, cfg.capacity_factor)
+        elif cfg.ffn == "swiglu":
+            y = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
         else:
-            y, aux = moe_ffn_dense(h, lp, cfg.capacity_factor)
-    elif cfg.ffn == "swiglu":
-        y = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-    else:
-        y = _mlp(h, lp)
-        if tp_psum:
-            y = jax.lax.psum(y, "tp")  # d_ff shard partial-sum
-        if not cfg.post_norm and mix is None:
-            return x + y + lp["b2"], aux, hit  # b2 replicated
-        y = y + lp["b2"]
-    if cfg.post_norm:
-        y = _norm(y, lp, "ln2p", cfg)
-    return hc_post(x, y, mix), aux, hit
+            y = _mlp(h, lp)
+            if tp_psum:
+                y = jax.lax.psum(y, "tp")  # d_ff shard partial-sum
+            if not cfg.post_norm and mix is None:
+                return x + y + lp["b2"], aux, hit  # b2 replicated
+            y = y + lp["b2"]
+        if cfg.post_norm:
+            y = _norm(y, lp, "ln2p", cfg)
+        return hc_post(x, y, mix), aux, hit
 
 
 def embed(params, tokens, cfg):
-    x = params["emb"][tokens]
-    if cfg.emb_scale != 1.0:
-        x = x * jnp.asarray(cfg.emb_scale, x.dtype)
-    if cfg.hc_mult > 1:  # every stream starts as the embedding
-        x = jnp.broadcast_to(
-            x[:, :, None], x.shape[:2] + (cfg.hc_mult, x.shape[-1]))
-    return x
+    with jax.named_scope("embed"):
+        x = params["emb"][tokens]
+        if cfg.emb_scale != 1.0:
+            x = x * jnp.asarray(cfg.emb_scale, x.dtype)
+        if cfg.hc_mult > 1:  # every stream starts as the embedding
+            x = jnp.broadcast_to(
+                x[:, :, None], x.shape[:2] + (cfg.hc_mult, x.shape[-1]))
+        return x
 
 
 def head_logits(params, x, cfg):
     """Final norm and the output head on (B, L, D) (streams already
     folded, :func:`hc_fold`): the tied embedding, or ``params["head"]``."""
-    x = _norm(x, params, "lnf", cfg)
-    w = params["emb"] if cfg.tie_head else params["head"]
-    return jnp.einsum("bld,vd->blv", x, w)
+    with jax.named_scope("head"):
+        x = _norm(x, params, "lnf", cfg)
+        w = params["emb"] if cfg.tie_head else params["head"]
+        return jnp.einsum("bld,vd->blv", x, w)
 
 
 def make_kv_slice(cfg: TransformerConfig):
@@ -1306,16 +1312,20 @@ def nll_loss(logits, targets, axes):
     Written in logsumexp form (``lse - logits[target]``) rather than
     ``log_softmax`` + gather: same math, same gradient (softmax minus
     one-hot), but the full (B, L, V) normalized array is never
-    materialized in f32 — only the reductions are. On the chip that is
-    10.5 ms of a 116 ms flagship step (round 4: the head+loss phase
-    drops 22.5 -> 12.0 ms; earlier installation, not repeated on this one)."""
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = lse - tl
-    total = jax.lax.psum(nll.sum(), axes)
-    count = jax.lax.psum(jnp.asarray(nll.size, jnp.float32), axes)
-    return total / count
+    materialized in f32 — only the reductions are. On the chip the
+    scope ``loss`` is 2.4 ms of ``train_sc2_8k``'s 794 ms step, all of
+    it forward (PERF.md section 5, PR 36): the backward's softmax minus
+    one-hot is fused into the head's two backward products and reads
+    under ``head``."""
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        tl = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        nll = lse - tl
+        total = jax.lax.psum(nll.sum(), axes)
+        count = jax.lax.psum(jnp.asarray(nll.size, jnp.float32), axes)
+        return total / count
 
 
 def sgd_step(loss_fn, *, lr: float, donate: bool = False):
@@ -1391,9 +1401,10 @@ def sgd_step_from_grads(grad_fn, *, lr: float, donate: bool = False):
 
     def step(params, tokens, targets):
         loss, grads = grad_fn(params, tokens, targets)
-        params = jax.tree.map(
-            lambda p, g: p - lr * g.astype(p.dtype), params, grads
-        )
+        with jax.named_scope("sgd_update"):
+            params = jax.tree.map(
+                lambda p, g: p - lr * g.astype(p.dtype), params, grads
+            )
         return params, loss
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
@@ -1496,8 +1507,43 @@ def make_train_step(
     The loss/grad runs as one shard_map program (explicit ring/tp
     collectives inside); the parameter update stays in plain jit where
     XLA propagates the NamedShardings.
+
+    Each call's dispatch is inside the host span ``train.step``
+    (obs/timeline.py: annotate; written where a profiler session is
+    open, an atomic check where none is), whose arguments are
+    :func:`_train_step_counts`. The program keeps its name,
+    ``jit_step``, and what is returned keeps ``.lower``.
     """
-    return sgd_step(_make_loss_fn(cfg, mesh), lr=lr, donate=donate)
+    step = sgd_step(_make_loss_fn(cfg, mesh), lr=lr, donate=donate)
+
+    @wraps(step)
+    def train_step(params, tokens, targets):
+        with annotate("train.step",
+                      **_train_step_counts(cfg, tokens.shape)):
+            return step(params, tokens, targets)
+
+    train_step.lower = step.lower
+    return train_step
+
+
+@lru_cache(maxsize=32)
+def _train_step_counts(cfg: TransformerConfig, shape: tuple) -> dict:
+    """``train.step``'s arguments, counts the program knows from its
+    shapes alone, made once a shape: ``tokens`` (B x L of the global
+    batch) and, where the step's attention is the flash kernels over
+    the whole sequence (Ulysses, ``attn_impl="flash"``), what one
+    (batch x head) forward sweep of their grid does
+    (ops/flash_attention.py ``block_plan``): ``flash_block``,
+    ``flash_grid_steps``, ``flash_run_steps``, ``flash_pairs_run``,
+    ``flash_pairs_band``."""
+    B, L = shape
+    counts = {"tokens": B * L}
+    if cfg.attn == "ulysses" and cfg.attn_impl == "flash":
+        from ..ops.flash_attention import block_plan
+
+        plan = block_plan(L, L, causal=True, window=cfg.attn_window)
+        counts.update({f"flash_{k}": v for k, v in plan.items()})
+    return counts
 
 
 def shard_params(params: dict, cfg: TransformerConfig, mesh: Mesh) -> dict:

@@ -126,13 +126,14 @@ def _block_run(i, j, bq, bk, causal, window):
     band? Causal skips blocks entirely above the diagonal; a sliding
     window additionally skips blocks entirely LEFT of the band
     (min possible qpos - max possible kpos >= window). Returns a traced
-    bool (or True when nothing is masked)."""
+    bool (or True when nothing is masked); on plain integers
+    (:func:`block_plan`) a plain one."""
     run = True
     if causal:
         run = j * bk <= i * bq + bq - 1
     if window is not None:
         in_band = i * bq - (j * bk + bk - 1) < window
-        run = in_band if run is True else jnp.logical_and(run, in_band)
+        run = in_band if run is True else run & in_band
     return run
 
 
@@ -150,6 +151,40 @@ def _block_mask(i, j, bq, bk, causal, window):
         band = qpos - kpos < window
         mask = band if mask is None else jnp.logical_and(mask, band)
     return mask
+
+
+def block_plan(Lq: int, Lk: int, *, causal: bool, window: int | None,
+               block_q: int = 1024, block_k: int = 1024) -> dict:
+    """What ONE (batch x head) forward sweep of the kernels' grid does
+    at these lengths, counted on the host in plain integers: the blocks
+    :func:`_pick_block` chooses (``block``, "<bq>x<bk>"), the grid's
+    steps (``grid_steps`` = nq * nk), those :func:`_block_run` lets run
+    (``run_steps``), the (query, key) pairs those blocks hold
+    (``pairs_run`` = run_steps * bq * bk) and the pairs among them that
+    :func:`_block_mask`'s rule lets through (``pairs_band``: ``kpos <=
+    qpos`` when causal, ``qpos - kpos < window`` under a window).
+    ``pairs_band / pairs_run`` is the share of the kernels' score work
+    that is not masked away; the two backward kernels sweep the same
+    blocks. A step a grid skips still costs its launch, so
+    ``run_steps / grid_steps`` is there too."""
+    bq, bk = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
+    nq, nk = Lq // bq, Lk // bk
+    run_steps = pairs_band = 0
+    for i in range(nq):
+        for j in range(nk):
+            if not _block_run(i, j, bq, bk, causal, window):
+                continue
+            run_steps += 1
+            k0, k1 = j * bk, j * bk + bk - 1
+            for q in range(i * bq, i * bq + bq):
+                lo = k0 if window is None else max(k0, q - window + 1)
+                hi = min(k1, q) if causal else k1
+                pairs_band += max(0, hi - lo + 1)
+    return {
+        "block": f"{bq}x{bk}", "grid_steps": nq * nk,
+        "run_steps": run_steps, "pairs_run": run_steps * bq * bk,
+        "pairs_band": pairs_band,
+    }
 
 
 def _sds(shape, dtype, like):
