@@ -1533,15 +1533,18 @@ def _train_step_counts(cfg: TransformerConfig, shape: tuple) -> dict:
     batch) and, where the step's attention is the flash kernels over
     the whole sequence (Ulysses, ``attn_impl="flash"``), what one
     (batch x head) forward sweep of their grid does
-    (ops/flash_attention.py ``block_plan``): ``flash_block``,
-    ``flash_grid_steps``, ``flash_run_steps``, ``flash_pairs_run``,
-    ``flash_pairs_band``."""
+    (ops/flash_attention.py ``block_plan``, every key of it):
+    ``flash_block``, ``flash_tile``, ``flash_grid_steps``,
+    ``flash_tile_steps``, ``flash_run_steps``, ``flash_interior_steps``,
+    ``flash_pairs_run``, ``flash_pairs_band``."""
     B, L = shape
     counts = {"tokens": B * L}
     if cfg.attn == "ulysses" and cfg.attn_impl == "flash":
         from ..ops.flash_attention import block_plan
 
-        plan = block_plan(L, L, causal=True, window=cfg.attn_window)
+        plan = block_plan(
+            L, L, causal=True, window=cfg.attn_window,
+            head_dim=cfg.head_dim, itemsize=jnp.dtype(cfg.dtype).itemsize)
         counts.update({f"flash_{k}": v for k, v in plan.items()})
     return counts
 

@@ -166,3 +166,41 @@ def test_chunk_latent_attention_compiles_at_published_widths(one_chip):
     assert "while" in text  # the walk over key blocks
     # a block's scores, never the arena's: (H, T, 512), not (H, T, 4096)
     assert f"{H},{T},{L}]" not in text
+
+
+# (batch, length, query heads, K/V heads, head size, causal, window,
+# dtype): the training cell's sweep (every kind of tile in one 2048-
+# block), the odd length that is one whole block (its lse row is 1001
+# lanes wide), and two lengths that no multiple of the 512-tile divides
+# and whose callers name no block: 2000 in bfloat16 and 3000 in float32
+# are 1000-blocks computed whole, float32 the largest working set the
+# estimate admits (15.9 of 16 MiB). All inside the scoped VMEM that
+# Mosaic grants unasked: the kernels ask for no more.
+FLASH = [
+    (2, 8192, 24, 2, 128, True, 4096, jnp.bfloat16),
+    (2, 1001, 8, 8, 128, True, None, jnp.bfloat16),
+    (1, 2000, 4, 4, 128, True, None, jnp.bfloat16),
+    (1, 3000, 4, 2, 128, True, 1024, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("B,L,H,Hkv,D,causal,window,dtype", FLASH)
+def test_flash_kernels_compile_for_the_v5e(one_chip, B, L, H, Hkv, D,
+                                           causal, window, dtype):
+    """Forward, dq and dk/dv (the transposed tiles, the row-shaped lse
+    and delta, the loop over compute tiles, the clamped index maps) as
+    Mosaic takes them, which the interpreter cannot say."""
+    from mpistragglers_jl_tpu.ops.flash_attention import flash_attention
+
+    def grads(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, window=window, interpret=False),
+            q, k, v)
+        return vjp(out)
+
+    text = _compiled_text(
+        grads, _sds(one_chip, (B, L, H, D), dtype),
+        _sds(one_chip, (B, L, Hkv, D), dtype),
+        _sds(one_chip, (B, L, Hkv, D), dtype))
+    assert text.count("tpu_custom_call") >= 3

@@ -156,16 +156,56 @@ def test_odd_length_fallback_vmem_guard():
 
 
 def test_oversize_aligned_block_vmem_guard():
-    """Explicitly tuned oversize blocks get the same clear error as the
-    odd-L fallback (the PERF round-4 sweep's 2048-block Mosaic OOM)."""
+    """Blocks are upper bounds: ``_blocks`` halves them until
+    ``_vmem_estimate`` fits the 16 MiB Mosaic grants unasked, so the
+    default 2048-blocks stay where they were read on the chip (bfloat16
+    at head_dim 128), float32 or a wider head take the 1024 the kernels
+    had until PR 38, and an oversize block asked for is shrunk the same
+    way. What is left to refuse is a head too wide for the blocks."""
     import pytest
 
-    q = jnp.zeros((1, 2048, 1, 128), jnp.bfloat16)
+    from mpistragglers_jl_tpu.ops.flash_attention import (
+        _blocks, _check_vmem)
+
+    tile = (512, 512)
+    assert _blocks(8192, 8192, 128, 2, 2048, 2048) == (2048, 2048, tile)
+    assert _blocks(8192, 8192, 128, 4, 2048, 2048) == (1024, 1024, tile)
+    assert _blocks(8192, 8192, 256, 2, 2048, 2048) == (1024, 1024, tile)
+    assert _blocks(8192, 8192, 128, 2, 8192, 8192) == (2048, 2048, tile)
+    # the fused backward computes a block whole: charged as such
+    assert _blocks(8192, 8192, 128, 2, 2048, 2048, whole=True)[:2] == (
+        1024, 1024)
+    for itemsize in (2, 4):
+        _check_vmem(1024, 1024, 128, itemsize)
+    _check_vmem(2048, 2048, 128, 2)
     with pytest.raises(ValueError, match="lower block_q/block_k"):
-        flash_attention(
-            q, q, q, causal=True, block_q=2048, block_k=2048,
-            interpret=False,
-        )
+        _check_vmem(2048, 2048, 128, 4)
+    with pytest.raises(ValueError, match="lower block_q/block_k"):
+        _check_vmem(2048, 2048, 128, 2, whole=True)
+
+
+# every length a caller that names no block may bring (the Ulysses and
+# ring wrappers pass none): the reviewer's 2000, 6000, 10000 (one block
+# computed whole at 2048 would be a 2000 x 2000 tile), 3000 and 2560
+# (1500 and 1280 whole), lengths with few divisors, the cell's
+@pytest.mark.parametrize("L", [2000, 6000, 10000, 3000, 2560, 1536, 2008,
+                               2208, 1000, 1024, 4096, 8192, 520, 37])
+@pytest.mark.parametrize("D,itemsize", [(128, 2), (128, 4), (256, 2),
+                                        (64, 4)])
+def test_default_blocks_are_tiled_or_no_larger_than_before(L, D, itemsize):
+    """The default blocks divide the length, are computed in 512-tiles
+    that divide them or else span at most the 1024 of a block computed
+    whole until PR 38, and estimate inside the budget."""
+    from mpistragglers_jl_tpu.ops.flash_attention import (
+        _BLOCK, _VMEM_BUDGET, _blocks, _vmem_estimate)
+
+    bq, bk, (tq, tk) = _blocks(L, L, D, itemsize, _BLOCK, _BLOCK)
+    assert bq == bk and tq == tk and L % bq == 0 and bq % tq == 0
+    assert bq % 8 == 0 or bq == L
+    assert tq == 512 or (tq == bq and bq <= 1024)
+    assert _vmem_estimate(bq, bk, D, itemsize) <= _VMEM_BUDGET
+    if L % 2048 == 0:  # 2048-blocks were read in bfloat16 at 128 alone
+        assert bq == (1024 if D * itemsize >= 512 else 2048)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -221,3 +261,42 @@ def test_bwd_impl_auto_and_validation():
 
     with pytest.raises(ValueError, match="bwd_impl"):
         flash_attention(q, q, q, bwd_impl="nope")
+
+
+@pytest.mark.parametrize("Lq,Lk,bq,bk,causal,window", [
+    (64, 64, 8, 8, True, 20),
+    (64, 64, 16, 8, True, 20),
+    (64, 64, 8, 16, True, None),
+    (48, 80, 16, 16, False, 20),
+    (96, 32, 8, 8, True, 12),
+    (32, 32, 16, 16, False, None),
+    (8192, 8192, 1024, 1024, True, 4096),
+])
+def test_run_ranges_are_the_stretch_block_run_lets_run(Lq, Lk, bq, bk,
+                                                       causal, window):
+    """The index maps clamp a sweep's index into ``_k_run_range`` /
+    ``_q_run_range`` so that a skipped step fetches nothing new: the
+    range must be exactly the (contiguous) blocks ``_block_run`` lets
+    run, and a sweep with none still names a block that exists."""
+    from mpistragglers_jl_tpu.ops import flash_attention as fa
+
+    nq, nk = Lq // bq, Lk // bk
+    run = np.array([[bool(fa._block_run(i, j, bq, bk, causal, window))
+                     for j in range(nk)] for i in range(nq)])
+    for n, other, rng, line in (
+        (nq, nk, fa._k_run_range, run), (nk, nq, fa._q_run_range, run.T)
+    ):
+        for x in range(n):
+            lo_hi = rng(x, bq, bk, other, causal, window)
+            lo, hi = (int(v) for v in lo_hi)
+            ran = np.flatnonzero(line[x])
+            if ran.size:
+                assert (lo, hi) == (ran[0], ran[-1])
+                assert ran.size == hi - lo + 1
+            for y in range(other):
+                at = int(fa._clamp(y, lo_hi))
+                assert 0 <= at < other
+                if line[x][y]:
+                    assert at == y
+    if not causal and window is None:
+        assert fa._clamp("as it is", (0, nk - 1)) == "as it is"

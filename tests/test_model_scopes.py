@@ -212,8 +212,9 @@ def test_train_step_span_carries_the_counts(train_step, monkeypatch):
     assert second == (name, args) and name == "train.step"
     plan = fa.block_plan(64, 64, causal=True, window=32)
     assert args == {
-        "tokens": 128, "flash_block": "64x64", "flash_grid_steps": 1,
-        "flash_run_steps": 1, "flash_pairs_run": 64 * 64,
+        "tokens": 128, "flash_block": "64x64", "flash_tile": "64x64",
+        "flash_grid_steps": 1, "flash_tile_steps": 1, "flash_run_steps": 1,
+        "flash_interior_steps": 0, "flash_pairs_run": 64 * 64,
         "flash_pairs_band": plan["pairs_band"],
     }
 
@@ -228,32 +229,81 @@ def test_a_step_without_flash_kernels_counts_its_tokens_only():
 
 
 def test_the_block_plan_at_the_training_cells_shape():
+    """2048 x 2048 blocks computed in 512 x 512 tiles: of the grid's 16
+    steps 10 fetch a block, and of a sweep's 256 tiles 108 run, 84 of
+    them with no mask (in tiles of 1024 it would be 30 and 18, with
+    31,457,280 pairs run)."""
     assert fa.block_plan(8192, 8192, causal=True, window=4096) == {
-        "block": "1024x1024", "grid_steps": 64, "run_steps": 30,
+        "block": "2048x2048", "tile": "512x512", "grid_steps": 16,
+        "tile_steps": 256, "run_steps": 108, "interior_steps": 84,
+        "pairs_run": 28_311_552, "pairs_band": 25_167_872,
+    }
+
+
+@pytest.mark.parametrize("head_dim,itemsize", [(128, 4), (256, 2)])
+def test_the_block_plan_follows_the_kernels_blocks(head_dim, itemsize):
+    """Where the kernels take 1024-blocks (float32, a wider head: the
+    2048-blocks were read in bfloat16 at head size 128 alone) the plan
+    says so, from the same ``_blocks``; the tiles and what is counted
+    in them stay."""
+    at_cell = fa.block_plan(8192, 8192, causal=True, window=4096)
+    plan = fa.block_plan(8192, 8192, causal=True, window=4096,
+                         head_dim=head_dim, itemsize=itemsize)
+    assert fa._blocks(8192, 8192, head_dim, itemsize, fa._BLOCK,
+                      fa._BLOCK) == (1024, 1024, (512, 512))
+    assert plan == {**at_cell, "block": "1024x1024", "grid_steps": 64}
+
+
+def test_the_block_plan_in_the_tiles_of_the_whole_block_kernels(monkeypatch):
+    """1024 x 1024 blocks computed whole, as the kernels were until
+    PR 38: the cell's sweep runs 30 of its 64 blocks, 18 of them
+    interior."""
+    monkeypatch.setattr(fa, "_TILE", 1024)
+    plan = fa.block_plan(8192, 8192, causal=True, window=4096,
+                         block_q=1024, block_k=1024)
+    assert plan == {
+        "block": "1024x1024", "tile": "1024x1024", "grid_steps": 64,
+        "tile_steps": 64, "run_steps": 30, "interior_steps": 18,
         "pairs_run": 31_457_280, "pairs_band": 25_167_872,
     }
 
 
-@pytest.mark.parametrize("Lq,Lk,causal,window,bq,bk", [
-    (64, 64, True, 24, 16, 16),
-    (96, 96, True, None, 32, 16),
-    (48, 80, False, 20, 16, 16),
+@pytest.mark.parametrize("Lq,Lk,causal,window,bq,bk,tile", [
+    (64, 64, True, 24, 16, 16, 16),
+    (64, 64, True, 24, 16, 16, 8),
+    (96, 96, True, None, 32, 16, 8),
+    (48, 80, False, 20, 16, 16, 16),
+    (48, 80, False, 20, 16, 16, 4),
+    (32, 32, False, None, 16, 16, 8),
 ])
 def test_the_block_plan_is_a_brute_force_count(Lq, Lk, causal, window,
-                                               bq, bk):
-    """Against the kernels' own two functions evaluated block by block:
-    ``_block_run`` decides the steps, ``_block_mask`` the pairs."""
+                                               bq, bk, tile, monkeypatch):
+    """Against the kernels' own functions evaluated tile by tile:
+    ``_block_run`` decides the steps, ``_block_mask`` the pairs, and a
+    tile counts as interior exactly where its mask hides nothing, pair
+    by pair."""
+    monkeypatch.setattr(fa, "_TILE", tile)
     plan = fa.block_plan(Lq, Lk, causal=causal, window=window,
                          block_q=bq, block_k=bk)
-    run_steps = pairs = 0
-    for i in range(Lq // bq):
-        for j in range(Lk // bk):
-            mask = fa._block_mask(i, j, bq, bk, causal, window)
-            seen = bq * bk if mask is None else int(mask.sum())
-            if fa._block_run(i, j, bq, bk, causal, window):
+    tq, tk = fa._compute_tile(bq, bk)
+    assert (tq, tk) == (min(tile, bq), min(tile, bk))
+    run_steps = interior = pairs = 0
+    for i in range(Lq // tq):
+        for j in range(Lk // tk):
+            mask = fa._block_mask(i, j, tq, tk, causal, window)
+            seen = tq * tk if mask is None else int(mask.sum())
+            if mask is not None:  # the dk/dv kernel's is the same, turned
+                turned = fa._block_mask(i, j, tq, tk, causal, window,
+                                        transposed=True)
+                assert np.array_equal(np.asarray(turned).T,
+                                      np.asarray(mask))
+            full = bool(fa._block_interior(i, j, tq, tk, causal, window))
+            assert full == (seen == tq * tk)
+            if fa._block_run(i, j, tq, tk, causal, window):
                 run_steps += 1
+                interior += full
                 pairs += seen
-            else:  # a block the grid skips holds no visible pair
+            else:  # a tile the kernels skip holds no visible pair
                 assert seen == 0
     q, k = np.arange(Lq)[:, None], np.arange(Lk)[None, :]
     band = np.ones((Lq, Lk), bool)
@@ -263,7 +313,9 @@ def test_the_block_plan_is_a_brute_force_count(Lq, Lk, causal, window,
         band &= q - k < window
     assert pairs == int(band.sum())
     assert plan == {
-        "block": f"{bq}x{bk}", "grid_steps": (Lq // bq) * (Lk // bk),
-        "run_steps": run_steps, "pairs_run": run_steps * bq * bk,
-        "pairs_band": pairs,
+        "block": f"{bq}x{bk}", "tile": f"{tq}x{tk}",
+        "grid_steps": (Lq // bq) * (Lk // bk),
+        "tile_steps": (Lq // tq) * (Lk // tk),
+        "run_steps": run_steps, "interior_steps": interior,
+        "pairs_run": run_steps * tq * tk, "pairs_band": pairs,
     }

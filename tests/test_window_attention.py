@@ -109,6 +109,63 @@ def test_flash_window_matches_reference(W, hkv, bwd):
         )
 
 
+@pytest.mark.parametrize("tile", [8, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("hkv", [1, 4])
+def test_flash_grid_with_every_kind_of_block(hkv, dtype, tile,
+                                             monkeypatch):
+    """L 64 in blocks of 8 under a window of 20: every q block's sweep
+    holds one block visible in full (the body without a mask), the
+    causal diagonal and two blocks on the window's left edge (the body
+    with one) and skipped blocks on both sides; computed whole, and in
+    four sub-tiles of 4 x 4 a block, where a fetched block holds tiles
+    of more than one kind. Forward and the three gradients against the
+    oracle, float32 at this file's tolerances, bfloat16 at
+    tests/test_flash_attention.py's."""
+    from mpistragglers_jl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_TILE", tile)
+    L, W, blk = 64, 20, 8
+    plan = fa.block_plan(L, L, causal=True, window=W, block_q=blk,
+                         block_k=blk)
+    n = L // blk
+    assert plan["block"] == "8x8" and plan["grid_steps"] == n * n
+    assert plan["tile"] == f"{tile}x{tile}"
+    if tile == blk:  # interior: j == i - 1; run: j in i-3 .. i
+        assert plan["interior_steps"] == n - 1
+        assert plan["run_steps"] == n + (n - 1) + (n - 2) + (n - 3)
+    else:
+        assert plan["interior_steps"] > 3 * (n - 1)
+        assert plan["pairs_run"] < (4 * n - 6) * blk * blk
+    q, k, v = (x.astype(dtype) for x in _qkv(4, hkv, L=L, seed=11))
+    w = _qkv(4, hkv, L=L, seed=12)[0]
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=W, block_q=blk, block_k=blk)
+    oracle = lambda q, k, v: reference_attention(
+        q, k, v, causal=True, window=W)
+    exact = dtype == jnp.float32
+    tol = dict(atol=1e-5, rtol=1e-5) if exact else dict(atol=3e-2,
+                                                        rtol=3e-2)
+    got = flash(q, k, v)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(oracle(q, k, v), np.float32), **tol)
+    g_got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    gtol = dict(atol=1e-4, rtol=1e-4) if exact else tol
+    for a, b, name in zip(g_got, g_want, "qkv"):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            err_msg=f"d{name}", **gtol)
+
+
 @pytest.mark.parametrize(
     "shape,attn",
     [
