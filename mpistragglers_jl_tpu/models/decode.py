@@ -86,6 +86,7 @@ from .transformer import (
     mla_absorb,
     mla_merge,
     mla_project,
+    mtp_input,
     param_specs,
 )
 
@@ -657,10 +658,24 @@ def _latent_attend(h, lp, cfg, rope, mix, write, attend):
     return mla_merge(h, o, lp, cfg, mix, latent=True), cache_l
 
 
+def _mtp_rows(params, x, nxt, cfg, layer):
+    """A chunk's rows of the multi-token-prediction module's OWN cache
+    layer (the last of ``cfg.cache_layers``): the module's block on
+    ``mtp_input(x, nxt)``, ``x`` the model's last block output at the
+    chunk's positions and ``nxt`` the tokens that follow them, run by
+    ``layer(h, block weights, li)`` as the caller runs the model's last
+    layer. Only the rows it writes are kept: nothing reads the block's
+    output of a prompt's inner positions, and the compiler drops what
+    feeds it alone."""
+    with jax.named_scope("mtp"):
+        return layer(mtp_input(params, x, nxt, cfg),
+                     params["mtp"]["block"], cfg.n_layers - 1)[1]
+
+
 def _incremental_hidden(params, tokens, cache, offset, cfg,
                         *, prefill, kv_slice=None, tp_psum=False,
                         ring=False, decode_kernel: bool = False,
-                        valid=None):
+                        valid=None, nxt=None):
     """Chunk forward at global ``offset`` up to the last layer's output;
     returns (hidden (B, T, d), cache): :func:`_incremental_forward`
     without the head, for callers that read few of the chunk's rows (the
@@ -673,7 +688,10 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
     program's resolved int8-kernel route (the module note). ``valid``
     (a traced count, None = all) is how many leading rows of the chunk
     are the prompt's and not padding: attention never reads the
-    padding, a recurrent layer must be told to skip it.
+    padding, a recurrent layer must be told to skip it. ``nxt`` (B, T;
+    ``cfg.mtp_depth`` and one more layer in ``cache``): the tokens that
+    follow the chunk's, for the multi-token-prediction module's rows
+    (:func:`_mtp_rows`).
     """
     T = tokens.shape[1]
     if ring and (T != 1 or prefill):
@@ -696,7 +714,14 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
             ring=ring, decode_kernel=decode_kernel, valid=valid,
         )
         new_cache.append(cache_l)
-    return hc_fold(x, cfg), new_cache
+    x = hc_fold(x, cfg)
+    if nxt is not None:
+        new_cache.append(_mtp_rows(
+            params, x, nxt, cfg, lambda h, lp, li: _incremental_layer(
+                h, lp, cache[cfg.n_layers], qpos, cfg, li,
+                chunk_attn=chunk_attn, kv_slice=kv_slice,
+                tp_psum=tp_psum)))
+    return x, new_cache
 
 
 @functools.lru_cache(maxsize=64)
@@ -757,7 +782,8 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
     return grouped_layer
 
 
-def _grouped_hidden(params, tokens, caches, offsets, cfg, valid=None):
+def _grouped_hidden(params, tokens, caches, offsets, cfg, valid=None,
+                    nxt=None):
     """The chunks of ``n`` requests as ONE forward, so that every
     weight (every expert) is read once for all of them: ``tokens``
     (n, T), ``caches`` the n requests' own positional caches of one row
@@ -765,15 +791,25 @@ def _grouped_hidden(params, tokens, caches, offsets, cfg, valid=None):
     ``valid`` (n,) as in :func:`_incremental_hidden` (None = all).
     Returns (hidden (n, T, d), the n caches). The rows of different
     requests meet only in products that are row-wise
-    (:func:`_grouped_layer`)."""
+    (:func:`_grouped_layer`). ``nxt`` (n, T): as in
+    :func:`_incremental_hidden`."""
     x = embed(params, tokens, cfg)
     caches = [list(c) for c in caches]
-    for li, lp in enumerate(params["layers"]):
+
+    def layer(x, lp, li, at):  # cache layer ``at``, run as layer ``li``
         x, rows = _grouped_layer(cfg, cfg.layer_like(li))(
-            x, lp, [c[li] for c in caches], offsets, valid)
+            x, lp, [c[at] for c in caches], offsets, valid)
         for c, r in zip(caches, rows):
-            c[li] = r
-    return hc_fold(x, cfg), caches
+            c[at] = r
+        return x, rows
+
+    for li, lp in enumerate(params["layers"]):
+        x, _ = layer(x, lp, li, li)
+    x = hc_fold(x, cfg)
+    if nxt is not None:
+        _mtp_rows(params, x, nxt, cfg,
+                  lambda h, lp, li: layer(h, lp, li, cfg.n_layers))
+    return x, caches
 
 
 def _incremental_forward(params, tokens, cache, offset, cfg, **kw):
@@ -921,9 +957,12 @@ def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
 
 def _row_widths(cfg: TransformerConfig) -> tuple:
     """:func:`ring_widths` with None for a layer that keeps recurrent
-    state in place of rows."""
+    state in place of rows, and behind the model's layers the width of
+    a multi-token-prediction module's block (``cfg.cache_layers``: the
+    last layer's, whose kind it is)."""
     out = []
-    for li, w in enumerate(cfg.windows):
+    for li in range(cfg.cache_layers):
+        w = cfg.windows[cfg._like(li)]
         if cfg.gdn(li):
             out.append(None)
             continue

@@ -435,13 +435,19 @@ def init_topk_layer(rng: np.random.Generator, cfg) -> dict:
 
 
 def topk_route(x2d: jax.Array, router: jax.Array, bias, k: int,
-               scale: float, score: str = "sigmoid"):
+               scale: float, score: str = "sigmoid", groups: int = 1,
+               topk_groups: int = 1):
     """Top-k routing of (T, D) tokens: scores ``s = sigmoid(x @
     router)`` (or, ``score="softmax"``, the softmax over all experts)
     in float32; the ``k`` experts with the largest ``s + bias`` are
     chosen (the bias selects and does not weigh; None: no bias); their
     weights are ``s`` itself, normalised to sum to one and multiplied
-    by ``scale``. Returns ``(idx (T, k) int32, w (T, k) float32)``."""
+    by ``scale``. With ``groups`` > 1 the choice is group-limited: the
+    experts are ``groups`` equal runs, a group's score is the sum of
+    its two largest ``s + bias``, and only the experts of the
+    ``topk_groups`` best groups stand for the top-k (a token's experts
+    then lie on at most that many nodes). Returns ``(idx (T, k) int32,
+    w (T, k) float32)``."""
     logits = jnp.einsum(
         "td,de->te", x2d, router, preferred_element_type=jnp.float32,
     )
@@ -449,7 +455,16 @@ def topk_route(x2d: jax.Array, router: jax.Array, bias, k: int,
         s = jax.nn.softmax(logits, axis=-1)
     else:
         s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s if bias is None else s + bias, k)
+    sel = s if bias is None else s + bias
+    if groups > 1:
+        T, E = sel.shape
+        by_group = sel.reshape(T, groups, E // groups)
+        _, keep = jax.lax.top_k(
+            jax.lax.top_k(by_group, 2)[0].sum(axis=-1), topk_groups)
+        kept = jnp.zeros((T, groups), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        sel = jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(sel, k)
     w = jnp.take_along_axis(s, idx, axis=1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return idx, w
@@ -603,7 +618,8 @@ def moe_ffn_topk(h: jax.Array, lp: dict, cfg):
     held = cfg.experts_held
     with jax.named_scope("moe_route"):
         idx, w = topk_route(x, lp["router"], lp.get("router_bias"), k,
-                            cfg.route_scale, cfg.route_score)
+                            cfg.route_scale, cfg.route_score,
+                            cfg.route_groups, cfg.route_topk_groups)
         idx = idx.reshape(-1)
         if held is not None:
             # held experts count from 0; a pair for any other expert

@@ -52,6 +52,17 @@ Design (TPU-first):
   sharded tick, migration and speculation refuse it by mechanism, as
   they do a residual path of several streams (``hc_mult``), of which
   nothing is cached.
+* **One or two tokens a step.** With ``draft="mtp"`` and a
+  configuration that carries a multi-token-prediction module
+  (``TransformerConfig(mtp_depth=1)``) a decode step runs two rows a
+  slot, the last certain token and the module's draft of the next,
+  delivers two tokens where the model's own pick is the draft and one
+  where it is not, and lets the module draft again
+  (:func:`_draft_step`, whose note has the argument for the stale row
+  a rejected draft leaves). The stream is the drafter-off stream token
+  for token, greedy or sampled. Window layers, state layers, several
+  streams, ``qos=``, ``cache=`` and migration refuse it by mechanism,
+  and no prefix page is shared under it.
 * **Per-row positions.** Unlike ``decode_step_ring_dense`` (one scalar
   position for the whole batch), every slot decodes at its own global
   position: RoPE angles, ring-slot writes, and the ``kpos >= 0``
@@ -122,6 +133,7 @@ compiles and checks against the dense tick.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from collections import deque
@@ -149,6 +161,7 @@ from .decode import (
     _kernel_possible,
     _latent_attend,
     _latent_leaves,
+    _incremental_layer,
     _kernel_viable,
     _kv_quantize,
     _paged_kernel_possible,
@@ -180,6 +193,8 @@ from .transformer import (
     hc_pre,
     head_logits,
     make_kv_slice,
+    mtp_input,
+    mtp_logits,
     param_specs,
 )
 
@@ -202,7 +217,7 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
     layer). An eager ``jnp.zeros`` per leaf is a program launch and
     an allocation each, four a layer, while the device has nothing
     queued: admission's whole host cost at 30 layers."""
-    lengths = (L,) * cfg.n_layers if isinstance(L, int) else tuple(L)
+    lengths = (L,) * cfg.cache_layers if isinstance(L, int) else tuple(L)
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(li, length):
@@ -277,7 +292,7 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     layer's pages hold its one row a position, ``k`` ``(n_pages, P,
     latent + rope)``, and the two scales of a row as two "heads" of
     ``k_s``."""
-    counts = ((n_pages,) * cfg.n_layers if isinstance(n_pages, int)
+    counts = ((n_pages,) * cfg.cache_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
@@ -347,7 +362,7 @@ def _layer_tables(cfg: TransformerConfig, pt) -> list:
     tables = tuple(pt) if isinstance(pt, (tuple, list)) else (pt,)
     _, kind_of = _layer_kinds(cfg)
     if len(tables) == 1:
-        return [tables[0]] * cfg.n_layers
+        return [tables[0]] * cfg.cache_layers
     return [None if k is None else tables[k] for k in kind_of]
 
 
@@ -357,16 +372,20 @@ def _layer_tables(cfg: TransformerConfig, pt) -> list:
 
 
 def _rope_rows(x, pos, theta: float = 10000.0, table=None):
-    """Rotary embedding for single-token rows: x (S, 1, H, D), pos (S,)
-    global positions — the per-row counterpart of transformer._rope
-    (which shares one position vector across the batch), at its base
-    (or its table of frequencies)."""
+    """Rotary embedding for a slot's own rows: x (S, T, H, D), pos
+    (S, T) global positions ((S,) for the one row of a plain step) —
+    the per-row counterpart of transformer._rope (which shares one
+    position vector across the batch), at its base (or its table of
+    frequencies)."""
     Dh = x.shape[-1]
     half = Dh // 2
     freqs = _rope_freqs(half, theta, table)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (S, half)
-    cos = jnp.cos(ang)[:, None, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, None, None, :].astype(x.dtype)
+    # (S, half), or (S, T, half) to (S, T, 1, half)
+    ang = pos.astype(jnp.float32)[..., None] * freqs[None, :]
+    heads = ((slice(None), None, None, slice(None)) if pos.ndim == 1
+             else (slice(None), slice(None), None, slice(None)))
+    cos = jnp.cos(ang)[heads].astype(x.dtype)
+    sin = jnp.sin(ang)[heads].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
@@ -374,15 +393,17 @@ def _rope_rows(x, pos, theta: float = 10000.0, table=None):
 
 
 def _ring_write_rows(cache_l: dict, k, v, slot, latent=None):
-    """Write each row's single-token K/V at its own ring slot:
-    k, v (S, 1, Hkv, D), slot (S,) — a per-row scatter on the slot
-    axis (decode.py's ``_cache_write`` writes one shared offset). A
-    latent layer (``latent``: the latent's width) writes its one row,
-    ``k``."""
+    """Write each slot's own rows of K/V at their own ring slots:
+    k, v (S, T, Hkv, D), slot (S, T) ((S,) for T = 1) — a per-row
+    scatter on the slot axis (decode.py's ``_cache_write`` writes one
+    shared offset). A latent layer (``latent``: the latent's width)
+    writes its one row a position, ``k``."""
     rows = jnp.arange(k.shape[0])
 
     def put(c, u):
-        return c.at[rows, slot].set(u[:, 0].astype(c.dtype))
+        if slot.ndim == 1:
+            return c.at[rows, slot].set(u[:, 0].astype(c.dtype))
+        return c.at[rows[:, None], slot].set(u.astype(c.dtype))
 
     if latent is not None:
         return {kk: put(cache_l[kk], u) for kk, u in _latent_leaves(
@@ -417,7 +438,9 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
     ORACLE step (``serving_decode_step_dense``), which stays einsum so
     kernel-vs-einsum parity is testable against it. ``latent`` (the
     latent's width): q is the absorbed query, the ring a latent
-    layer's one row a slot, the result (S, 1, H, latent)."""
+    layer's one row a slot, the result (S, 1, H, latent). ``pos``
+    (S, T): the slot's T queries, each at its own position, over the
+    einsum (a drafting step's two rows, :func:`_draft_step`)."""
     W = cache_l["k"].shape[1]
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
@@ -425,11 +448,13 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
         return quantized_decode_attention(
             q, cache_l, pos, scale, ring=True
         )
-    s = _cache_scores(q, cache_l, scale, latent)  # (S, H, 1, W) f32
-    kpos = pos[:, None] - jnp.mod(
-        pos[:, None] - jnp.arange(W)[None, :], W
-    )  # (S, W)
-    s = jnp.where((kpos >= 0)[:, None, None, :], s, _NEG)
+    s = _cache_scores(q, cache_l, scale, latent)  # (S, H, T, W) f32
+    kpos = pos[..., None] - jnp.mod(
+        pos[..., None] - jnp.arange(W)[None, :], W
+    )  # (S, W), or (S, T, W)
+    seen = kpos >= 0
+    s = jnp.where(seen[:, None, None, :] if pos.ndim == 1
+                  else seen[:, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = _cache_pv(p, cache_l, latent)
     return o.astype(q.dtype)
@@ -536,7 +561,9 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     Returns ``(x, cache_l, hit)``; ``hit`` is the number of experts
     that got a row in a dropless expert layer, None elsewhere. A gated
     delta-rule layer's ``cache_l`` is every slot's state: one step of
-    the recurrence a row, no position and no page."""
+    the recurrence a row, no position and no page. ``pos`` (S, T) with
+    x (S, T, D): T rows a slot, each written before any is attended
+    (the slot-ring and gathered-view paths; a drafting step's two)."""
     h, mix = hc_pre(x, lp, cfg, "hc1")
     if cfg.gdn(li):
         x, cache_l = gdn_half(h, lp, cache_l, cfg, mix=mix)
@@ -585,13 +612,15 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     return x, cache_l, hit
 
 
-def _serving_forward(params, tok, pos, caches, cfg, *, kv_slice=None,
-                     tp_psum=False, use_kernel=False, paged=None):
-    """(tok (S,), pos (S,), caches) -> (logits (S, V), caches, hits).
+def _serving_hidden(params, tok, pos, caches, cfg, *, kv_slice=None,
+                    tp_psum=False, use_kernel=False, paged=None):
+    """The model's layers on T rows a slot: (tok (S, T), pos (S,) for
+    T = 1 or (S, T), caches) -> (the last block's output (S, T, d)
+    with the streams folded, the model's layers' caches, hits).
     ``paged``: ``(per-layer page tables, PAGE_TOKENS)``. ``hits`` sums,
     over the dropless expert layers, the experts that got a row this
     step (None for a configuration without such a layer)."""
-    x = embed(params, tok[:, None], cfg)  # (S, 1, d)
+    x = embed(params, tok, cfg)  # (S, T, d)
     new = []
     hits = None
     for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
@@ -606,7 +635,15 @@ def _serving_forward(params, tok, pos, caches, cfg, *, kv_slice=None,
         new.append(cl)
         if hit is not None:
             hits = hit if hits is None else hits + hit
-    return head_logits(params, hc_fold(x, cfg), cfg)[:, 0], new, hits
+    return hc_fold(x, cfg), new, hits
+
+
+def _serving_forward(params, tok, pos, caches, cfg, **kw):
+    """(tok (S,), pos (S,), caches) -> (logits (S, V), caches, hits):
+    :func:`_serving_hidden` on one row a slot, and the head."""
+    x, new, hits = _serving_hidden(params, tok[:, None], pos, caches, cfg,
+                                   **kw)
+    return head_logits(params, x, cfg)[:, 0], new, hits
 
 
 def serving_decode_step_dense(params, tok, pos, caches,
@@ -677,6 +714,109 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
     return tok, pos, done, caches, toks.swapaxes(0, 1)
 
 
+# A drafting step (``TransformerConfig(mtp_depth=1)`` under
+# ``ServingScheduler(draft="mtp")``; DeepSeek-V3's multi-token
+# prediction, arXiv:2412.19437 section 2.2, served). A slot holds its
+# certain tokens up to position p and a draft d of the token at p + 1.
+#
+# * The model runs TWO rows a slot, (t_p, d) at (p, p + 1), each layer
+#   writing both cache rows before it attends. ``x1 = pick(logits_p)``
+#   is the token at p + 1 whatever the draft was. If ``x1 == d`` the
+#   second row was computed on the right token and ``x2 =
+#   pick(logits_{p+1})`` is the token at p + 2: the step delivers two
+#   tokens for one read of the weights. Else it delivers ``x1`` alone.
+# * ``pick`` is the scheduler's own (``_pick_rows``): greedy, or
+#   ``argmax(logits / T + Gumbel(key folded with the position))``. The
+#   draft was picked with the key of the position it is a draft FOR, so
+#   draft and verification add the same noise and agree wherever their
+#   logits do: the delivered stream is, token for token, the stream the
+#   same scheduler delivers with the drafter off, at temperature 0 and
+#   above it. No acceptance probability is a parameter anywhere.
+# * The module then runs over the positions just made certain, rows
+#   (h_p, x1) and (h_{p+1}, x2) at (p, p + 1) of its own cache layer,
+#   and its row at the last certain position is the next step's draft.
+# * A rejected draft leaves a stale row at p + 1 in every layer's
+#   cache, the module's too. The next step starts at p + 1 and writes
+#   that row before it attends, and until then no query above p exists:
+#   the stale row is overwritten before it is read (the argument of
+#   models/speculative.py for its dense cache, here for int8 latent
+#   pages). A slot therefore writes one row past its certain cursor,
+#   and a tick ``2 * n_inner`` rows at most: the page budget and the
+#   context check count that (``ServingScheduler._tick_rows``).
+
+
+def _draft_step(params, tok, pos, done, caches, cfg, eos_id, keys,
+                temperature, top_k):
+    """One drafting step for all S slots on ring caches (or gathered
+    views): ``tok`` (S, 2) is ``[t_p, d]``. Returns ``(tok, pos, done,
+    caches)`` advanced by one or two positions a slot and ``(out,
+    hits, mtp_hits)``: ``out`` (S, 4) int32 holds ``[x1, x2, accepted,
+    d]`` and the hits are ``_serving_layer``'s, summed over the
+    model's expert layers and of the module's."""
+    n, dt = cfg.n_layers, tok.dtype
+    pos2 = pos[:, None] + jnp.arange(2, dtype=pos.dtype)
+    x, new, hits = _serving_hidden(params, tok, pos2, caches, cfg)
+    lg = head_logits(params, x, cfg)  # (S, 2, V)
+    pick = functools.partial(_pick_rows, keys=keys, temperature=temperature,
+                             top_k=top_k, dtype=dt)
+    with jax.named_scope("head"), jax.named_scope("verify"):
+        x1, done1 = _eos_clamp(pick(lg[:, 0], pos), tok[:, 0], done, eos_id)
+        x2, done2 = _eos_clamp(pick(lg[:, 1], pos + 1), x1, done1, eos_id)
+        # a stream that has ended delivers nothing behind its end
+        accept = x1 == tok[:, 1]
+        if eos_id is not None:
+            accept = accept & ~done2
+    with jax.named_scope("mtp"):
+        block = params["mtp"]["block"]
+        h = mtp_input(params, x, jnp.stack([x1, x2], axis=1), cfg)
+        h, cl, mtp_hits = _serving_layer(h, block, caches[n], pos2, cfg,
+                                         n - 1)
+        new.append(cl)
+        # the head once, on the row at the last certain position
+        last = jnp.where(accept[:, None, None], h[:, 1:], h[:, :1])
+        q = mtp_logits(params, last, cfg)[:, 0]
+        pos = pos + 1 + accept.astype(pos.dtype)
+        with jax.named_scope("mtp_head"):
+            draft = pick(q, pos)
+    out = jnp.stack([x1, x2, accept.astype(dt), tok[:, 1]], axis=1)
+    tok = jnp.stack([jnp.where(accept, x2, x1), draft], axis=1)
+    done = jnp.where(accept, done2, done1)
+    return (tok, pos, done, new), (out, hits, mtp_hits)
+
+
+def _scan_body_draft(params, tok, pos, done, caches, cfg, eos_id, n_inner,
+                     keys, *, temperature=0.0, top_k=None,
+                     use_kernel=False):
+    """:func:`_scan_body` for a drafting scheduler: ``n_inner``
+    :func:`_draft_step` under one scan (over the einsum alone: the
+    kernel takes one query a slot). Returns (tok (S, 2), pos, done,
+    caches, (out (S, n_inner, 4), counters (n_inner, c) int32)); the
+    counters are, step by step, the model's expert layers' hits (two
+    columns where the layers hold a share of their experts: the pairs
+    that fell on held ones) and then the module's, the same columns."""
+
+    assert not use_kernel
+
+    def step(carry, _):
+        carry, (out, hits, mtp_hits) = _draft_step(
+            params, *carry, cfg, eos_id, keys, temperature, top_k)
+        count = [jnp.atleast_1d(h).astype(jnp.int32)
+                 for h in (hits, mtp_hits) if h is not None]
+        return carry, (out, jnp.concatenate(count) if count
+                       else jnp.zeros((0,), jnp.int32))
+
+    (tok, pos, done, caches), (out, counters) = jax.lax.scan(
+        step, (tok, pos, done, caches), None, length=n_inner)
+    return tok, pos, done, caches, (out.swapaxes(0, 1), counters)
+
+
+def _tick_body(cfg: TransformerConfig):
+    """The scan a tick runs: the drafting one where the configuration
+    carries the module (a scheduler with the drafter off drops it from
+    its configuration, ``ServingScheduler.__init__``)."""
+    return _scan_body_draft if cfg.mtp_depth else _scan_body
+
+
 @functools.lru_cache(maxsize=32)
 def _serving_scan_dense(cfg: TransformerConfig, n_inner: int,
                         eos_id: int | None, temperature: float = 0.0,
@@ -689,9 +829,9 @@ def _serving_scan_dense(cfg: TransformerConfig, n_inner: int,
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def serving_tick_dense(params, tok, pos, done, caches, keys):
-        return _scan_body(params, tok, pos, done, caches, cfg, eos_id,
-                          n_inner, keys, temperature=temperature,
-                          top_k=top_k, use_kernel=use_kernel)
+        return _tick_body(cfg)(params, tok, pos, done, caches, cfg, eos_id,
+                               n_inner, keys, temperature=temperature,
+                               top_k=top_k, use_kernel=use_kernel)
 
     return serving_tick_dense
 
@@ -741,7 +881,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
                 else _paged_gather(cl, t, cfg.cache_heads(li), P)
                 for li, (cl, t) in enumerate(zip(caches, pts))
             ]
-        tok, pos, done, views, toks = _scan_body(
+        tok, pos, done, views, toks = _tick_body(cfg)(
             params, tok, pos, done, views, cfg, eos_id, n_inner, keys,
             temperature=temperature, top_k=top_k, use_kernel=False,
         )
@@ -1022,12 +1162,17 @@ def _extend_chunk_dense(cfg: TransformerConfig, C: int, Lmax: int):
     donated: chunks stream through one arena. ``valid`` (a traced
     count; a configuration with recurrent layers passes it, no other)
     is how many of the chunk's rows are the prompt's: the padding
-    after them must leave a recurrent layer's state alone."""
+    after them must leave a recurrent layer's state alone. ``nxt``
+    (1, C; a configuration with a multi-token-prediction module passes
+    it, no other): the tokens that follow the chunk's, from which the
+    module's own cache layer gets its rows."""
 
     @functools.partial(jax.jit, donate_argnums=(2,))
-    def serving_prefill_chunk(params, chunk, cache, offset, valid=None):
+    def serving_prefill_chunk(params, chunk, cache, offset, valid=None,
+                              nxt=None):
         return _incremental_hidden(
-            params, chunk, cache, offset, cfg, prefill=False, valid=valid
+            params, chunk, cache, offset, cfg, prefill=False, valid=valid,
+            nxt=nxt,
         )
 
     return serving_prefill_chunk
@@ -1073,9 +1218,9 @@ def _extend_chunk_group(cfg: TransformerConfig, C: int, Lmax: int, n: int):
     ``serving_prefill_chunk_x<n>``; a scheduler has the one of its
     ``_chunk_group_cap``."""
 
-    def chunk_group(params, chunks, caches, offsets, valid=None):
+    def chunk_group(params, chunks, caches, offsets, valid=None, nxt=None):
         x, caches = _grouped_hidden(params, chunks, caches, offsets, cfg,
-                                    valid)
+                                    valid, nxt)
         return tuple(x[i:i + 1] for i in range(n)), tuple(caches)
 
     chunk_group.__name__ = chunk_group.__qualname__ = (
@@ -1095,24 +1240,43 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     request:
     (params, cache, last_hidden (1, C, d), true_len, last_off, key) ->
     (tok0 (), ring leaves (1, W, ...)). A recurrent layer's "ring" is
-    its state as the last chunk left it."""
+    its state as the last chunk left it. With a multi-token-prediction
+    module ``tok0`` is (2,), the first token and the first DRAFT: the
+    module's row at the prompt's last position needs the token that
+    follows it, which is the first token, so that one row is run here
+    (into the arena's copy, before the window is gathered) and its
+    logits give the draft of the token behind the first."""
     widths = _row_widths(cfg)
 
     @jax.jit
     def serving_first_token(params, cache, last_hidden, true_len,
                             last_off, key):
-        ring = [cl if W is None else _ring_from_cache(cl, true_len, W)
-                for cl, W in zip(cache, widths)]
+        def window(cache):
+            return [cl if W is None else _ring_from_cache(cl, true_len, W)
+                    for cl, W in zip(cache, widths)]
+
+        ring = None if cfg.mtp_depth else window(cache)
         row = jax.lax.dynamic_slice_in_dim(
             last_hidden, true_len - 1 - last_off, 1, axis=1
         )
         lg = head_logits(params, row, cfg)[:, 0]  # (1, V)
+        pick = functools.partial(_pick_rows, keys=key[None],
+                                 temperature=temperature, top_k=top_k,
+                                 dtype=jnp.int32)
         with jax.named_scope("head"):  # as in ``_scan_body``
-            tok0 = _pick_rows(
-                lg, (true_len - 1)[None], key[None], temperature,
-                top_k, jnp.int32,
-            )[0]
-        return tok0, ring
+            tok0 = pick(lg, (true_len - 1)[None])[0]
+        if ring is not None:
+            return tok0, ring
+        n = cfg.n_layers
+        with jax.named_scope("mtp"):
+            h, cl = _incremental_layer(
+                mtp_input(params, row, tok0[None, None], cfg),
+                params["mtp"]["block"], cache[n], (true_len - 1)[None],
+                cfg, n - 1, chunk_attn=None, kv_slice=None, tp_psum=False)
+            q = mtp_logits(params, h, cfg)[:, 0]
+            with jax.named_scope("mtp_head"):
+                draft = pick(q, true_len[None])[0]
+        return jnp.stack([tok0, draft]), window(cache[:n] + [cl])
 
     return serving_first_token
 
@@ -1449,6 +1613,11 @@ class Request:
             raise ValueError(f"max_new must be >= 1, got {max_new}")
         self.max_new = int(max_new)
         self.tokens: list[int] = []
+        # under ``ServingScheduler(draft=...)``: every draft a decode
+        # step verified for this request, ``(index in tokens of the
+        # token it guessed, the draft, whether it was accepted)``; the
+        # token's position is ``len(prompt) + index``
+        self.drafts: list[tuple[int, int, bool]] = []
         self.finished = False
         self.reason: str | None = None
         # filled by the scheduler: admission tick and retirement tick,
@@ -1620,6 +1789,12 @@ class ServingScheduler:
     session sees them on the device trace's clock, and with none open
     each costs an atomic check. ``spans=`` and ``flight=`` cut their
     spans at the same boundaries.
+
+    ``draft="mtp"``: decode steps of one or two tokens a slot, drafted
+    by the configuration's own multi-token-prediction module (the
+    module docstring; ``Request.drafts``, ``drafted`` / ``accepted`` on
+    ``serving.tick``). Without it a configuration that carries the
+    module is served as if it did not.
     """
 
     def __init__(self, params, cfg: TransformerConfig, *, slots: int = 8,
@@ -1631,7 +1806,14 @@ class ServingScheduler:
                  qos: TenantRegistry | None = None,
                  max_queue: int | None = None, registry=None,
                  spans=None, flight=None, exporter=None, trace=None,
-                 cache=None):
+                 cache=None, draft: str | None = None):
+        cfg = self._resolve_draft(params, cfg, draft, qos, cache)
+        self.draft = draft
+        # rows a slot runs in a decode step, and the most a tick writes
+        # past a slot's position when it begins (a drafting step writes
+        # the draft's row one past the certain one)
+        self._rows = 2 if draft else 1
+        self._tick_rows = int(n_inner) * self._rows
         # every layer's ring width, and the distinct ones ("kinds",
         # narrowest first): W is the narrowest, which is the whole
         # story for a configuration of sliding-window layers alone
@@ -1654,8 +1836,11 @@ class ServingScheduler:
                 "boundary, which is kept nowhere")
         # a prompt's resident prefix pages let admission skip their
         # prefill; the recurrent state at the page boundary exists
-        # nowhere, so with state layers nothing is shared or registered
-        self.shares_prefixes = not cfg.state_layers
+        # nowhere, so with state layers nothing is shared or registered.
+        # Nor with a drafter: its module's row at a page's last
+        # position is made from the token BEHIND the page, which no
+        # prefix digest covers
+        self.shares_prefixes = not cfg.state_layers and draft is None
         if slots < 1 or n_inner < 1:
             raise ValueError("slots and n_inner must be >= 1")
         if prompt_chunk > max_prompt:
@@ -1753,7 +1938,15 @@ class ServingScheduler:
         # device-resident row state + batched ring cache arena
         self.temperature = float(temperature)
         self.top_k = top_k
-        self._tok = jnp.zeros((self.S,), jnp.int32)
+        # a slot's last certain token (with a drafter: and its draft of
+        # the next, ``(S, 2)``)
+        self._tok = jnp.zeros((self.S,) + (2,) * (draft is not None),
+                              jnp.int32)
+        # the tick's own counts with a drafter: steps that verified a
+        # draft of a live request, those that accepted it, and the
+        # module's expert layer's ``experts_hit``
+        self.drafted = self.accepted = 0
+        self.mtp_experts_hit: float | None = None
         self._pos = jnp.zeros((self.S,), jnp.int32)
         self._done = jnp.ones((self.S,), bool)  # idle rows stay done
         self._keys = jax.random.split(jax.random.key(0), self.S)
@@ -1832,7 +2025,7 @@ class ServingScheduler:
         if self.paged:
             self.use_kernel = (
                 _paged_kernel_possible(cfg, self.quantize_kv, self.P)
-                and _route_kernel(self.S)
+                and _route_kernel(self.S) and draft is None
             )
             self._scan = _serving_scan_paged(
                 cfg, self.n_inner, eos_id, self.temperature, top_k,
@@ -1846,7 +2039,7 @@ class ServingScheduler:
         else:
             self.use_kernel = (
                 _kernel_possible(cfg, self.quantize_kv)
-                and _route_kernel(self.S)
+                and _route_kernel(self.S) and draft is None
             )
             self._scan = _serving_scan_dense(
                 cfg, self.n_inner, eos_id, self.temperature, top_k,
@@ -1925,6 +2118,38 @@ class ServingScheduler:
             # recorder as a /trace source) on the ObsServer
             exporter.register_scheduler(self)
 
+    @staticmethod
+    def _resolve_draft(params, cfg: TransformerConfig, draft, qos, cache):
+        """The configuration the scheduler's programs are made for:
+        with ``draft="mtp"`` the one given, whose multi-token-prediction
+        module drafts; with the drafter off the same without the module
+        (no cache layer, no row and no read of it anywhere). What a
+        drafting step cannot serve is refused here, by mechanism."""
+        if draft is None:
+            return (dataclasses.replace(cfg, mtp_depth=0)
+                    if cfg.mtp_depth else cfg)
+        if draft != "mtp":
+            raise ValueError(f"draft is None or 'mtp', got {draft!r}")
+        if not cfg.mtp_depth or "mtp" not in params:
+            raise ValueError(
+                "draft='mtp' drafts with the configuration's own "
+                "multi-token-prediction module: TransformerConfig("
+                "mtp_depth=1) and its weights, params['mtp']")
+        if any(w is not None for w in cfg.windows):
+            raise ValueError(
+                "draft='mtp': a drafting step writes the draft's row one "
+                "past the certain one, which in a sliding-window layer's "
+                "ring is the slot of the oldest row the certain token "
+                "still attends; every layer must attend every earlier "
+                "position (max_context)")
+        if qos is not None or cache is not None:
+            raise ValueError(
+                "draft='mtp': page quotas (qos=) and the fleet prefix "
+                "cache (cache=) count and move prefix pages, and with a "
+                "drafter none is shared (the module's row at a page's "
+                "last position is made from the token behind the page)")
+        return cfg
+
     def attach_trace(self, book) -> None:
         """Arm causal tracing (constructor ``trace=`` routes here; a
         router propagates its book the same way). DRR admission
@@ -1993,11 +2218,12 @@ class ServingScheduler:
                 f"{self.Lmax}; raise max_prompt (one-time recompile)"
             )
         if (self._context is not None and req.prompt.size + req.max_new
-                + self.n_inner > self._context):
+                + self._tick_rows > self._context):
             raise ValueError(
                 f"prompt of {req.prompt.size} tokens plus max_new "
-                f"{req.max_new} (and the retirement tick's {self.n_inner}"
-                f" steps) passes max_context {self._context}: a "
+                f"{req.max_new} (and the retirement tick's "
+                f"{self._tick_rows} rows) passes max_context "
+                f"{self._context}: a "
                 "full-attention layer's ring is that wide and must "
                 "never wrap; raise TransformerConfig(max_context=)"
             )
@@ -2050,6 +2276,8 @@ class ServingScheduler:
             (self._tok, self._pos, self._done, self._caches,
              toks) = self._scan(*self._scan_args())
         with _annotate("serving.decode_wait"):
+            if self.draft is not None:
+                return self._fetch_drafted(toks)
             host = np.asarray(toks)  # (S, n_inner) one fetch per tick
         if self._expert_layers:
             # the tick's own counter, in the row under the tokens: the
@@ -2059,6 +2287,25 @@ class ServingScheduler:
             if self.cfg.experts_held is not None:
                 self.pairs_local = float(host[self.S + 1].sum()) / per
             host = host[:self.S]
+        return host
+
+    def _fetch_drafted(self, toks) -> np.ndarray:
+        """A drafting tick's fetch: ``(S, n_inner, 4)`` of ``[x1, x2,
+        accepted, the draft verified]`` and, beside it, the steps'
+        counters (``_scan_body_draft``), the model's layers' first and
+        the module's behind them. ``pairs_local`` is a mean per row a
+        slot runs, so that it stays a share of ``slots x k``."""
+        host, counts = jax.device_get(toks)
+        if counts.shape[1]:
+            total = counts.sum(axis=0) / self.n_inner
+            held = self.cfg.experts_held is not None
+            if self._expert_layers:
+                self.experts_hit = float(total[0]) / self._expert_layers
+                if held:
+                    self.pairs_local = float(total[1]) / (
+                        self._expert_layers * self._rows)
+            if self.cfg.dropless(self.cfg.n_layers):
+                self.mtp_experts_hit = float(total[-2 if held else -1])
         return host
 
     def lower_tick(self):
@@ -2158,13 +2405,19 @@ class ServingScheduler:
                     host = self._decode_scan_fetch()
                 with phase("serving.harvest") as harvest:
                     n_tokens = n_retired = 0
+                    self.drafted = self.accepted = 0
                     for s in decoding:
-                        if self.paged:
-                            self._host_pos[s] += self.n_inner
                         req = self._slot_req[s]
                         n_before = len(req.tokens)
-                        req.tokens.extend(int(t) for t in host[s])
+                        if self.draft is None:
+                            req.tokens.extend(int(t) for t in host[s])
+                        else:
+                            self._deliver_drafted(req, host[s])
+                        if self.paged:
+                            self._host_pos[s] += len(req.tokens) - n_before
                         due = self._retire_if_due(req)
+                        if self.draft is not None:
+                            self._count_drafts(req, n_before)
                         # count AFTER the retirement trim: the
                         # EOS-clamped tail the host strips was never
                         # delivered to anyone, and a tokens/s series
@@ -2186,6 +2439,14 @@ class ServingScheduler:
                     if self.pairs_local is not None:
                         harvest.set_metadata(
                             pairs_local=self.pairs_local)
+                    if self.draft is not None:
+                        # the drafts of live requests this tick verified
+                        # and how many of them it accepted; the module's
+                        # expert layer's own ``experts_hit``
+                        tick.set_metadata(
+                            drafted=self.drafted, accepted=self.accepted,
+                            **({} if self.mtp_experts_hit is None else
+                               {"mtp_experts_hit": self.mtp_experts_hit}))
         if obs is not None:
             obs.tick_done(self, retired, tick, admit, decode, harvest)
         if lit:
@@ -2203,6 +2464,28 @@ class ServingScheduler:
                     t=tick.t1,
                 )
         return retired
+
+    @staticmethod
+    def _deliver_drafted(req: Request, steps: np.ndarray) -> None:
+        """A drafting tick's ``n_inner`` steps of one slot, ``[x1, x2,
+        accepted, draft]`` each, into the request: one token or two a
+        step, and the draft each step verified with the index in
+        ``req.tokens`` of the token it was a draft OF."""
+        for x1, x2, accepted, draft in steps.tolist():
+            req.drafts.append((len(req.tokens), draft, bool(accepted)))
+            req.tokens.append(x1)
+            if accepted:
+                req.tokens.append(x2)
+
+    def _count_drafts(self, req: Request, n_before: int) -> None:
+        """After the retirement trim (which drops the drafts behind a
+        request's end): the tick's ``drafted`` and ``accepted``, over
+        the drafts of tokens this tick delivered."""
+        for at, _, accepted in reversed(req.drafts):
+            if at < n_before:
+                break
+            self.drafted += 1
+            self.accepted += accepted
 
     def cancel(self, req: Request) -> bool:
         """Withdraw ``req`` wherever it currently is — queued, mid-
@@ -2283,6 +2566,11 @@ class ServingScheduler:
         (first token emitted), not finished. None otherwise."""
         if not self.paged or req.finished or not req.tokens:
             return None
+        if self.draft is not None:
+            raise ValueError(
+                "KV-page migration moves a slot's last token and its "
+                "rows; a drafting slot also holds a draft and the "
+                "module's rows one position behind")
         _refuse_state_layers(
             self.cfg, "KV-page migration", "an exported image is ring "
             "views behind a page table, and the state block is in none")
@@ -2479,7 +2767,7 @@ class ServingScheduler:
         if free_s is None:
             return None
         Tp = int(state["prompt"].size)
-        horizon = Tp + state["max_new"] + self.n_inner
+        horizon = Tp + state["max_new"] + self._tick_rows
         wraps = horizon > self.W
         n_pages = -(-min(self.W, horizon) // self.P)
         shared: list[int] = []
@@ -2540,7 +2828,7 @@ class ServingScheduler:
         except ValueError:
             return False
         Tp = int(state["prompt"].size)
-        horizon = Tp + state["max_new"] + self.n_inner
+        horizon = Tp + state["max_new"] + self._tick_rows
         n_pages = -(-min(self.W, horizon) // self.P)
         # an empty pool has n_pages-1 usable pages (page 0 is the null
         # page); prefix sharing could only lower the demand
@@ -2788,7 +3076,8 @@ class ServingScheduler:
         cannot cover the plan — the caller leaves the request queued.
 
         The budget is the request's whole lifetime upper bound: ring
-        slots ``[0, min(W, Tp + max_new + n_inner))`` of each width —
+        slots ``[0, min(W, Tp + max_new + _tick_rows))`` of each width
+        (``_tick_rows``: ``n_inner``, twice that with a drafter) —
         prefill plus every decode write including the bounded overshoot
         of the retirement tick — so :class:`PagePoolExhausted` is
         unreachable mid-decode (the capacity contract the fuzz tests
@@ -2849,7 +3138,7 @@ class ServingScheduler:
                             d, exclude=self.cache_name) is None:
                         break
                     fetch.append(d)
-        horizon = Tp + req.max_new + self.n_inner
+        horizon = Tp + req.max_new + self._tick_rows
         needs = []
         for kd in self._kinds:
             shared = [kd.pool.lookup(d) for d in digests[:m]]
@@ -3079,7 +3368,7 @@ class ServingScheduler:
 
     def _prepare_tick_pages(self, decoding: list[int]) -> None:
         """Pre-tick COW pass: the next ``n_inner`` decode steps write
-        ring slots ``[pos, pos + n_inner)`` (mod W) of every decoding
+        ring slots ``[pos, pos + _tick_rows)`` (mod W) of every decoding
         row. Any touched page still shared (refcount > 1) is copied to
         a fresh page, consuming the reservation attached to the shared
         page at admission (``PagePool.cow_alloc``); a touched page
@@ -3100,7 +3389,7 @@ class ServingScheduler:
             pos = self._host_pos[s]
             touched = {
                 ((pos + t) % kd.W) // self.P
-                for t in range(self.n_inner)
+                for t in range(self._tick_rows)
             }
             for j in sorted(touched):
                 pid = int(pt_host[s, j])
@@ -3151,18 +3440,27 @@ class ServingScheduler:
         """Compile (or load) the grouped prefill program before the
         first tick returns, with one run on throw-away arenas: whether
         and when a tick has two chunks due is the traffic's, and the
-        tick that is first to must not pay the compile. The lone
-        chunk's program needs no such run: the first request of any
-        traffic meets it. Two arenas fewer than the program takes stay
-        as its scratch (a group is at least two)."""
+        tick that is first to must not pay the compile. Nor the tick
+        that is first to have ONE chunk due: a full backlog's first
+        ticks fill every program of a group, and with a drafter which
+        tick comes to a lone chunk follows the seed's acceptances; so
+        the lone chunk's program has a run here too. Two arenas fewer
+        than the grouped program takes stay as its scratch (a group is
+        at least two)."""
         n = self._group
         valid = ((np.zeros((n,), np.int32),)
                  if self.cfg.state_layers else ())
+        nxt = ({"nxt": np.zeros((n, self.C), np.int32)}
+               if self.draft is not None else {})
         _, arenas = self._extend_group(
             self.params, np.zeros((n, self.C), np.int32),
             tuple(_fresh_cache(self.cfg, 1, self.Lmax, self.quantize_kv)
                   for _ in range(n)),
-            np.zeros((n,), np.int32), *valid)
+            np.zeros((n,), np.int32), *valid, **nxt)
+        self._extend(
+            self.params, np.zeros((1, self.C), np.int32), arenas[0],
+            np.int32(0), *(v[0] for v in valid),
+            **{k: v[:1] for k, v in nxt.items()})
         self._scratch_arenas = list(arenas[2:])
 
     def _advance_admissions(self, retired: list[Request]) -> None:
@@ -3243,10 +3541,20 @@ class ServingScheduler:
                 valid = (np.zeros((size,), np.int32),)
                 valid[0][:n] = [min(C, st.req.prompt.size - off)
                                 for st, off in zip(sts, offs)]
+            # a drafter's module is given the tokens that follow the
+            # chunk's (behind the prompt's last: none yet, that row is
+            # the first token's program's)
+            nxt = {}
+            if self.draft is not None:
+                nxt["nxt"] = np.zeros((size, C), np.int32)
+                for i, st in enumerate(sts):
+                    after = st.padded[0, st.next_chunk * C + 1:
+                                      (st.next_chunk + 1) * C + 1]
+                    nxt["nxt"][i, :after.size] = after
             if n == 1:
                 hidden, caches = self._extend(
                     self.params, chunks, sts[0].cache, np.int32(offs[0]),
-                    *(v[0] for v in valid),
+                    *(v[0] for v in valid), **nxt,
                 )
                 hidden, caches = (hidden,), (caches,)
             else:
@@ -3255,7 +3563,7 @@ class ServingScheduler:
                     self.params, chunks,
                     (*(st.cache for st in sts),
                      *self._scratch_arenas[:pad]),
-                    np.array(offs + [0] * pad, np.int32), *valid,
+                    np.array(offs + [0] * pad, np.int32), *valid, **nxt,
                 )
                 self._scratch_arenas[:pad] = caches[n:]
         self._tick_chunks += n
@@ -3321,7 +3629,9 @@ class ServingScheduler:
         # the one place admission blocks on the device: the request's
         # first token comes back before the tick's decode is dispatched
         with _annotate("serving.first_token_wait", req=rid):
-            first = int(tok0)
+            # with a drafter ``[first token, first draft]``: the draft
+            # stays on the device, in the slot's row
+            first = int(np.asarray(tok0).reshape(-1)[0])
         st.req.tokens.append(first)
         if self._obs is not None:
             self._obs.first_token(st.req, time.perf_counter())
@@ -3361,6 +3671,10 @@ class ServingScheduler:
         if cut is None:
             return False
         del req.tokens[cut:]
+        # (a draft counts where the token it guessed was delivered: the
+        # steps a retiring slot ran on behind the end verified nothing)
+        while req.drafts and req.drafts[-1][0] >= cut:
+            req.drafts.pop()
         req.finished = True
         req.retired_tick = self.tick_count
         # owner-only terminal stamp (see _retire_cancelled)
@@ -3369,6 +3683,9 @@ class ServingScheduler:
             self._trace.event(
                 req.trace, "retired", time.perf_counter(),
                 outcome=req.reason, tokens=len(req.tokens),
+                **({"drafted": len(req.drafts),
+                    "accepted": sum(d[2] for d in req.drafts)}
+                   if req.drafts else {}),
             )
         return True
 

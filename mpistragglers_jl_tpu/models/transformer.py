@@ -70,6 +70,7 @@ __all__ = [
     "init_params",
     "param_specs",
     "forward_dense",
+    "forward_dense_mtp",
     "make_forward",
     "make_train_step",
     "make_optax_train_step",
@@ -207,6 +208,22 @@ class TransformerConfig:
     # :func:`hc_post`). 1 = the one stream, ``x + half(norm(x))``.
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
+    # group-limited routing (DeepSeek-V3, arXiv:2412.19437): the
+    # experts as ``route_groups`` equal groups, a group's score the sum
+    # of its two largest ``s + bias``; only the ``route_topk_groups``
+    # best groups' experts stand for the top-k (``moe.topk_route``).
+    # 1 = no limit.
+    route_groups: int = 1
+    route_topk_groups: int = 1
+    # multi-token prediction (the same paper, section 2.2): one more
+    # block of the last layer's kind behind the model, fed ``[norm(h_i)
+    # ; norm(emb(t_{i+1}))] eh_proj`` and read through the model's own
+    # head after a final norm of its own: its row i predicts token
+    # i + 2 (:func:`mtp_input`, :func:`mtp_logits`). The block keeps a
+    # cache layer of its own behind the model's (``cache_layers``).
+    # ``ServingScheduler(draft="mtp")`` drafts with it; no forward of
+    # the model itself reads it.
+    mtp_depth: int = 0
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -304,6 +321,30 @@ class TransformerConfig:
             )
         if self.route_score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown route_score {self.route_score!r}")
+        if self.route_groups != 1 or self.route_topk_groups != 1:
+            g, kg = self.route_groups, self.route_topk_groups
+            if (g < 1 or self.n_experts % g or not 1 <= kg <= g
+                    or self.n_experts // g < 2
+                    or self.experts_per_token > kg * (self.n_experts // g)):
+                raise ValueError(
+                    f"route_groups {g} must divide n_experts "
+                    f"{self.n_experts} into groups of at least two, and "
+                    f"route_topk_groups {kg} of them must hold "
+                    f"experts_per_token {self.experts_per_token} experts"
+                )
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(
+                f"mtp_depth is 0 or 1 (one module, one token ahead), got "
+                f"{self.mtp_depth}"
+            )
+        if self.mtp_depth and (self.hc_mult > 1 or self.state_layers):
+            raise ValueError(
+                "the multi-token-prediction module reads ONE residual "
+                "stream's last block output and keeps rows a position; "
+                "this configuration has "
+                + ("several residual streams" if self.hc_mult > 1
+                   else "gated delta-rule layers")
+            )
         if self.experts_held is not None:
             lo, hi = self.experts_held
             if not 0 <= lo < hi <= self.n_experts:
@@ -332,17 +373,30 @@ class TransformerConfig:
             return tuple(self.layer_windows)
         return (self.attn_window,) * self.n_layers
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers that keep a cache: the model's, then the
+        multi-token-prediction module's block."""
+        return self.n_layers + self.mtp_depth
+
+    def _like(self, li: int) -> int:
+        """A cache layer's index among the model's layers: its own, or
+        for the multi-token-prediction module's block the last one's
+        (the block is of the last layer's kind)."""
+        return min(li, self.n_layers - 1)
+
     def dropless(self, li: int) -> bool:
         """Is layer ``li``'s feed-forward the dropless top-k experts?"""
         return bool(self.layer_experts is not None
-                    and self.layer_experts[li])
+                    and self.layer_experts[self._like(li)])
 
     def rope_at(self, li: int) -> bool:
         return self.rope_full or self.windows[li] is not None
 
     def mixer(self, li: int) -> str:
         """Layer ``li``'s token mixer: "attn", "gdn" or "mla"."""
-        return "attn" if self.layer_mixers is None else self.layer_mixers[li]
+        return ("attn" if self.layer_mixers is None
+                else self.layer_mixers[self._like(li)])
 
     def gdn(self, li: int) -> bool:
         """Is layer ``li``'s token mixer the gated delta rule?"""
@@ -411,6 +465,7 @@ class TransformerConfig:
             and self.rope_dims is None and not self.state_layers
             and not self.latent_layers and self.hc_mult == 1
             and self.rope_table is None and self.attn_scale is None
+            and not self.mtp_depth
         )
 
     def expert_width(self) -> int:
@@ -428,7 +483,8 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     ``_b``, an RMSNorm ``_s`` alone; the GELU MLP ``w1 b1 w2 b2``, the
     gated one ``w_gate w_up w_down``; a dropless expert layer
     (:func:`~.moe.init_topk_layer`) its router, experts and shared
-    expert; an untied head ``params["head"]``."""
+    expert; an untied head ``params["head"]``; a multi-token-prediction
+    module ``params["mtp"]`` (``mtp_depth``)."""
     rng = np.random.default_rng(seed)
     D, H, Dh, F = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
     Hkv = cfg.kv_heads
@@ -442,8 +498,7 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
             out[name + "_b"] = jnp.zeros((width,), cfg.dtype)
         return out
 
-    layers = []
-    for li in range(cfg.n_layers):
+    def one_layer(li):
         if cfg.gdn(li):
             layer = {**norm("ln1"), **init_gdn_layer(rng, cfg),
                      **norm("ln2")}
@@ -496,7 +551,9 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
                     "b2": jnp.zeros((D,), cfg.dtype),
                 }
             )
-        layers.append(layer)
+        return layer
+
+    layers = [one_layer(li) for li in range(cfg.n_layers)]
     params = {
         "emb": jnp.asarray(
             rng.standard_normal((cfg.vocab, D)) * 0.02, cfg.dtype
@@ -508,6 +565,16 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         params["head"] = jnp.asarray(
             rng.standard_normal((cfg.vocab, D)) * 0.02, cfg.dtype
         )
+    if cfg.mtp_depth:
+        # drawn last, so the model's own leaves are what they are
+        # without the module: the two norms of its input, the
+        # projection of their concatenation, one more block of the last
+        # layer's kind and a final norm of its own; the embedding and
+        # the head it reads are the model's arrays
+        params["mtp"] = {
+            **norm("hn"), **norm("en"), "eh_proj": sd(2 * D, D),
+            "block": one_layer(cfg.n_layers), **norm("lnf"),
+        }
     return params
 
 
@@ -528,6 +595,8 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
         why.append("layers of more than one cache width")
     if cfg.layer_experts and any(cfg.layer_experts):
         why.append("dropless top-k expert layers")
+    if cfg.mtp_depth:
+        why.append("a multi-token-prediction module")
     if not why:
         why.append("a block other than pre-LayerNorm / GELU MLP / "
                    "tied head at head_dim = d_model // n_heads")
@@ -1178,6 +1247,39 @@ def head_logits(params, x, cfg):
         return jnp.einsum("bld,vd->blv", x, w)
 
 
+# The multi-token-prediction module (``cfg.mtp_depth``), written once:
+# every caller (the dense forward below, a prefill chunk, the first
+# token's program and the drafting tick of models/serving.py) makes the
+# block's input with :func:`mtp_input`, runs ``params["mtp"]["block"]``
+# as it runs the model's last layer (its own cache layer behind the
+# model's), and reads the result with :func:`mtp_logits`.
+
+
+def mtp_input(params, h, nxt, cfg):
+    """The module's block input at positions i: ``[norm_h(h_i) ;
+    norm_e(emb(t_{i+1}))] eh_proj`` from the model's last block output
+    ``h`` (B, L, D), before the final norm, and the tokens ``nxt``
+    (B, L) that FOLLOW those positions. Which half comes first is a
+    relabelling of ``eh_proj``'s rows."""
+    mp = params["mtp"]
+    e = embed(params, nxt, cfg)
+    with jax.named_scope("mtp_proj"):
+        x = jnp.concatenate(
+            [_norm(h, mp, "hn", cfg), _norm(e, mp, "en", cfg)], axis=-1)
+        return jnp.einsum("blc,cd->bld", x, mp["eh_proj"])
+
+
+def mtp_logits(params, x, cfg):
+    """The module's final norm and the MODEL'S head on its block's
+    output (B, L, D): row i's logits are for token i + 2. Its own
+    scope, not ``head``: a reader of the model's head reads one product
+    a step."""
+    with jax.named_scope("mtp_head"):
+        x = _norm(x, params["mtp"], "lnf", cfg)
+        w = params["emb"] if cfg.tie_head else params["head"]
+        return jnp.einsum("bld,vd->blv", x, w)
+
+
 def make_kv_slice(cfg: TransformerConfig):
     """GQA with kv_heads < tp (call inside shard_map): wk/wv arrive
     tp-REPLICATED (:func:`_kv_tp_sharded`); this device's q-head shard
@@ -1230,8 +1332,32 @@ def forward_dense(params: dict, tokens: jax.Array, cfg: TransformerConfig):
     return _forward_dense_aux(params, tokens, cfg)[0]
 
 
-def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
-    """Dense forward returning (logits, summed MoE aux loss)."""
+def forward_dense_mtp(params, tokens, cfg: TransformerConfig):
+    """The dense forward with the multi-token-prediction module behind
+    it: ``(logits (B, L, V), mtp_logits (B, L - 1, V))``. Row i of the
+    second is the module's guess of token i + 2 from the model's last
+    block output at i and token i + 1: the oracle of what
+    ``ServingScheduler(draft="mtp")`` drafts."""
+    if not cfg.mtp_depth:
+        raise ValueError("forward_dense_mtp needs TransformerConfig("
+                         "mtp_depth=1) and params['mtp']")
+    logits, _, h = _forward_dense_aux(params, tokens, cfg, hidden=True)
+    L, li = tokens.shape[1] - 1, cfg.n_layers - 1
+    rope = partial(_rope, pos=jnp.arange(L), theta=cfg.rope_theta,
+                   table=cfg.rope_table)
+    block = params["mtp"]["block"]
+    with jax.named_scope("mtp"):
+        x = mtp_input(params, h[:, :L], tokens[:, 1:], cfg)
+        x = _mixer_dense(x, block, cfg, li, rope,
+                         resolve_attention_impl(cfg.attn_impl))
+        x, _, _ = ffn_half(x, block, cfg, li)
+        return logits, mtp_logits(params, x, cfg)
+
+
+def _forward_dense_aux(params, tokens, cfg: TransformerConfig,
+                       hidden: bool = False):
+    """Dense forward returning (logits, summed MoE aux loss) and, with
+    ``hidden``, the last block's output before the final norm."""
     pos = jnp.arange(tokens.shape[1])
     x = embed(params, tokens, cfg)
     rope = partial(_rope, pos=pos, theta=cfg.rope_theta,
@@ -1249,7 +1375,9 @@ def _forward_dense_aux(params, tokens, cfg: TransformerConfig):
     for li, lp in enumerate(params["layers"]):
         x, a = layer_fn(x, lp, li)
         aux = aux + a
-    return head_logits(params, hc_fold(x, cfg), cfg), aux
+    x = hc_fold(x, cfg)
+    out = head_logits(params, x, cfg), aux
+    return out + (x,) if hidden else out
 
 
 def _forward_local(params, tokens, cfg: TransformerConfig):
