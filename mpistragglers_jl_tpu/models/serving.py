@@ -188,6 +188,7 @@ from .transformer import (
     embed,
     ffn_half,
     gdn_half,
+    gdn_rule_route,
     gdn_zero_state,
     hc_fold,
     hc_pre,
@@ -2067,6 +2068,11 @@ class ServingScheduler:
                 w.dtype.itemsize))}
             for n in (1, self._group)
         }
+        # and its ``gdn_rule``: the form the delta rule takes over a
+        # chunk's rows (``transformer.gdn_rule_route``, which
+        # ``gdn_half`` asks too); nothing without state layers
+        self._gdn_rule = ({"gdn_rule": gdn_rule_route(cfg, self.C)}
+                          if cfg.state_layers else {})
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -3525,7 +3531,7 @@ class ServingScheduler:
             rows_seen=sum(_chunk_rows_seen(off, C, self.Lmax,
                                            self.cfg.windows)
                           for off in offs),
-            **self._expert_tile.get(size, {}),
+            **self._expert_tile.get(size, {}), **self._gdn_rule,
         ):
             # host arrays and numpy scalars go to the device with the
             # program's own dispatch; an eager slice or ``jnp.int32``

@@ -50,6 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.timeline import annotate
+from ..ops.delta_rule import chunked_delta_rule, delta_rule_viable
 from ..parallel.ring_attention import (
     _flash_interpreted,
     resolve_attention_impl,
@@ -1110,6 +1111,18 @@ def _delta_rule_chunks(q, k, v, g, beta, S):
     return o[:, :T], S
 
 
+def gdn_rule_route(cfg: TransformerConfig, T: int) -> str:
+    """The form the recurrence takes over a call of T > 1 rows, from
+    what the shapes say: ``"kernel"`` (ops/delta_rule.py: whole
+    sub-chunks of its own, 128 rows, at head sizes of whole lane tiles,
+    the published widths) or ``"xla"`` (:func:`_delta_rule_chunks`).
+    :func:`gdn_half` asks it, and the serving scheduler for
+    ``serving.prefill_chunk``'s ``gdn_rule``."""
+    return "kernel" if delta_rule_viable(
+        T, cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+        cfg.gdn_value_dim) else "xla"
+
+
 def gdn_half(x, lp, state, cfg, valid=None, mix=None):
     """Layer's gated delta-rule half on (B, T, D) from ``state``
     (:func:`gdn_zero_state`'s leaves): norm, projections, the causal
@@ -1150,15 +1163,21 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
         y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
         y = jax.nn.silu(y)
     with jax.named_scope("gdn_rule"):
-        q = y[..., :kw].reshape(B, T, Hk, Dk)
-        k = y[..., kw:2 * kw].reshape(B, T, Hk, Dk)
-        v = y[..., 2 * kw:].reshape(B, T, Hv, Dv)
-        l2 = lambda a: a * jax.lax.rsqrt(
-            (a * a).sum(-1, keepdims=True) + 1e-6)
-        q, k = l2(q) * Dk ** -0.5, l2(k)
-        if Hv != Hk:  # key head j serves value heads [j * r, (j + 1) * r)
-            q = jnp.repeat(q, Hv // Hk, axis=2)
-            k = jnp.repeat(k, Hv // Hk, axis=2)
+        # a chunk of whole sub-chunks at widths of whole lane tiles goes
+        # through the kernel, which reads q, k and v out of y as they
+        # lie; anything else (one token, tiny widths, an odd length)
+        # through the plain forms below
+        kernel = gdn_rule_route(cfg, T) == "kernel"
+        if not kernel:
+            q = y[..., :kw].reshape(B, T, Hk, Dk)
+            k = y[..., kw:2 * kw].reshape(B, T, Hk, Dk)
+            v = y[..., 2 * kw:].reshape(B, T, Hv, Dv)
+            l2 = lambda a: a * jax.lax.rsqrt(
+                (a * a).sum(-1, keepdims=True) + 1e-6)
+            q, k = l2(q) * Dk ** -0.5, l2(k)
+            if Hv != Hk:  # key head j serves value heads [j * r, (j + 1) * r)
+                q = jnp.repeat(q, Hv // Hk, axis=2)
+                k = jnp.repeat(k, Hv // Hk, axis=2)
         beta = jax.nn.sigmoid(ba[..., :Hv])
         g = -jnp.exp(lp["gdn_A_log"]) * jax.nn.softplus(
             ba[..., Hv:] + lp["gdn_dt_bias"])
@@ -1167,7 +1186,11 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
                     if jnp.ndim(valid) else
                     (jnp.arange(T) < valid)[None, :, None])
             g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
-        if T == 1:
+        if kernel:
+            o, S = chunked_delta_rule(y, g, beta, state["S"], Hk=Hk, Hv=Hv,
+                                      Dk=Dk, Dv=Dv)
+            o = o.reshape(B, T, Hv, Dv)
+        elif T == 1:
             o, S = _delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                     beta[:, 0], state["S"])
             o = o[:, None]

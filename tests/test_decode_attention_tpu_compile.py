@@ -116,6 +116,30 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip):
         f32(16, H), f32(16, H), f32(16, H, Dk, Dv))
 
 
+@pytest.mark.parametrize("B", [4, 1])
+def test_delta_rule_kernel_compiles_at_published_widths(one_chip, B):
+    """The chunked delta rule as ONE kernel (ops/delta_rule.py): the
+    grouped program's four chunks and the lone chunk, 256 rows x 16 key
+    / 32 value heads of 128 x 128 read out of the conv's 8192-wide rows,
+    every product at ``contract_precision<fp32>``, the state aliased
+    through. No triangular solve is left for the TPU's own lowering."""
+    from mpistragglers_jl_tpu.ops.delta_rule import chunked_delta_rule
+
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    T, Hk, Hv, D = 256, 16, 32, 128
+    call = functools.partial(
+        chunked_delta_rule, Hk=Hk, Hv=Hv, Dk=D, Dv=D, interpret=False)
+    text = _compiled_text(
+        call, f32(B, T, (2 * Hk + Hv) * D), f32(B, T, Hv), f32(B, T, Hv),
+        f32(B, Hv, D, D))
+    assert "tpu_custom_call" in text
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    # q, k and v reach the kernel as the conv left them: no operation
+    # makes another array of a head-major or repeated layout on the way
+    for shape in (f"f32[{B},{T},{Hv},{D}]", f"f32[{B},{Hv},{T},{D}]"):
+        assert f"= {shape}" not in text
+
+
 def test_absorbed_latent_decode_compiles_at_published_widths(one_chip):
     """The tick's latent attention for 16 slots over their gathered
     rings of 68 pages x 64 rows: 32 absorbed query heads against one

@@ -8,6 +8,7 @@ program is traced and lowered, and its debug text searched."""
 
 from __future__ import annotations
 
+import dataclasses
 import re
 
 import jax
@@ -25,6 +26,7 @@ from mpistragglers_jl_tpu.models.transformer import (
     make_train_step,
 )
 from mpistragglers_jl_tpu.ops import flash_attention as fa
+from mpistragglers_jl_tpu.ops.delta_rule import SUBCHUNK
 
 DENSE = TransformerConfig(vocab=97, d_model=32, n_heads=4, n_kv_heads=2,
                           n_layers=2, d_ff=64, attn_window=32)
@@ -172,6 +174,31 @@ def test_every_older_scope_of_the_tick_keeps_its_name(programs, model,
 ])
 def test_older_scopes_of_the_chunk_keep_their_names(programs, model, scope):
     assert scope in programs[model]["chunk"]
+
+
+def test_the_delta_rule_kernel_is_called_under_gdn_rule():
+    """At head sizes of whole lane tiles a chunk's rows go through the
+    kernel (ops/delta_rule.py): in the lowered GROUPED prefill program
+    the call of the jitted kernel function carries ``gdn_rule`` on its
+    path (the compiler inlines the callee, whose own paths start at
+    ``delta_rule/pallas_call``, under the call's), which is where
+    ``gdn_prefill_share_pct`` finds the kernel's time."""
+    cfg = dataclasses.replace(DELTA, gdn_key_heads=1, gdn_value_heads=2,
+                              gdn_key_dim=128, gdn_value_dim=128,
+                              max_context=2 * SUBCHUNK + 64)
+    sched = ServingScheduler(
+        init_params(cfg, seed=1), cfg, slots=3, n_inner=4, quantize_kv=True,
+        page_tokens=16, prompt_chunk=SUBCHUNK, max_prompt=2 * SUBCHUNK)
+    n = sched._group
+    assert n > 1
+    arenas = tuple(serving._fresh_cache(cfg, 1, sched.Lmax, True)
+                   for _ in range(n))
+    held = paths(sched._extend_group.lower(
+        sched.params, jnp.zeros((n, sched.C), jnp.int32), arenas,
+        jnp.zeros((n,), jnp.int32), jnp.full((n,), sched.C, jnp.int32)))
+    calls = [p for p in held if p[-1] == "jit(delta_rule_call)"]
+    assert calls and all("gdn_rule" in p for p in calls)
+    assert ["delta_rule", "pallas_call"] in held
 
 
 def test_the_feed_forward_nests_under_the_ticks_older_scope():
