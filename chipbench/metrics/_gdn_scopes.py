@@ -11,6 +11,10 @@ chunk; reads and writes the state ``S``) and ``gdn_out`` (the gated
 norm over a value head and the out-projection). A program without them
 (a parent commit, or a model without such a layer) gives None
 everywhere here.
+
+The reduction itself is not the delta rule's: ``time_by_scope`` and
+``reduce_scopes`` take the scopes to sum under as a parameter, and
+``_mla_scopes.py`` calls them with the latent layers' names.
 """
 
 from __future__ import annotations
@@ -35,16 +39,20 @@ CHUNK_PROGRAM = "jit_serving_prefill_chunk"
 CACHE_KEY = "gdn_scopes"
 
 
-def time_by_scope(run, program: str) -> dict | None:
+def time_by_scope(run, program: str, scopes=GDN_SCOPES,
+                  cache_key: str = CACHE_KEY, label: str = "gdn"):
     """{'whole': s, 'runs': n, 'moves': s, 'gdn_proj': s, ...} for
-    ``program`` ("tick" or "chunk"): self time of that program's
-    operations inside the traced window, in all, under each ``gdn_*``
-    scope and in the compiler's own asynchronous copies (``MOVE_OPS``
-    outside every ``gdn_*`` scope), with the number of its executions. None without a device trace or where no
-    operation of the program carries such a scope."""
+    ``program`` ("tick", or "chunk": every prefill chunk program, the
+    lone chunk's and the grouped one): self time of that program's
+    operations inside the traced window, in all, under each of
+    ``scopes`` and in the compiler's own asynchronous copies
+    (``MOVE_OPS`` outside every one of them), with the number of its
+    executions. None without a device trace or where no operation of
+    the program carries such a scope. Read once a run and
+    ``cache_key``; prints ``note <program>_time_by_<label>_scope_ms``."""
     if run.summary is None:
         return None
-    key = f"{CACHE_KEY}_{program}"
+    key = f"{cache_key}_{program}"
     if key in run.info:
         return run.info[key]
     out = None
@@ -57,25 +65,26 @@ def time_by_scope(run, program: str) -> dict | None:
     window, _ = ps._host(run)
     if tick is not None and window is not None:
         out = reduce_scopes(trace_reduce.find_xplane(run.trace_dir),
-                            is_program, window)
+                            is_program, window, scopes)
     run.info[key] = out
     if out is not None:
-        print(f"note {program}_time_by_gdn_scope_ms " + " ".join(
-            f"{k}={1e3 * out[k]:.3f}"
-            for k in ("whole", "moves") + GDN_SCOPES
+        listed = ("whole", "moves") if label == "gdn" else ("whole",)
+        print(f"note {program}_time_by_{label}_scope_ms " + " ".join(
+            f"{k}={1e3 * out[k]:.3f}" for k in listed + tuple(scopes)
         ) + f" runs={out['runs']}", flush=True)
     return out
 
 
-def reduce_scopes(path: str, is_program, window) -> dict | None:
+def reduce_scopes(path: str, is_program, window,
+                  scopes=GDN_SCOPES) -> dict | None:
     """Over the chips of the trace at ``path``: the operations that run
     inside an execution of a program whose cleaned name
-    ``is_program`` accepts, their self time summed in all and by
-    ``gdn_*`` scope, a mean over the chips."""
+    ``is_program`` accepts, their self time summed in all, under each
+    of ``scopes`` and as ``moves``, a mean over the chips."""
     w0, w1 = window
     parts_of = {k: frozenset(ps.scope_parts(v))
                 for k, v in ps.op_scopes(path).items()}
-    total = {"whole": 0.0, "moves": 0.0, **{s: 0.0 for s in GDN_SCOPES}}
+    total = {"whole": 0.0, "moves": 0.0, **{s: 0.0 for s in scopes}}
     n_runs = 0
     chips = trace_reduce.load_xplane(path)["device"]
     for chip in chips.values():
@@ -95,12 +104,12 @@ def reduce_scopes(path: str, is_program, window) -> dict | None:
                 continue
             total["whole"] += self_ns
             parts = parts_of.get((runs[i][2], name), frozenset())
-            under = [scope for scope in GDN_SCOPES if scope in parts]
+            under = [scope for scope in scopes if scope in parts]
             for scope in under:
                 total[scope] += self_ns
             if not under and trace_reduce.clean_name(name) in MOVE_OPS:
                 total["moves"] += self_ns
-    if total["whole"] <= 0 or not any(total[s] > 0 for s in GDN_SCOPES):
+    if total["whole"] <= 0 or not any(total[s] > 0 for s in scopes):
         return None
     n = max(1, len(chips))
     out = {k: v * 1e-9 / n for k, v in total.items()}

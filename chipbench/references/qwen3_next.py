@@ -10,8 +10,16 @@ as the recurrence itself, one token at a time (``lax.scan`` over the
 sequence carrying ``S``). It imports nothing of the program (only its
 sibling reference's shared pieces) and is handed only arrays that the
 benchmark made. To fit beside the bfloat16 weights on one chip it works
-layer by layer, head by head inside attention and expert block by
-expert block.
+layer by layer and, inside a layer, over blocks of the stream's rows
+(``layer_forward_rows``): the delta rule's state and the conv's last
+rows are carried from block to block, a block of query rows attends
+the keys so far one head at a time, and the routed experts compute the
+(row, expert) pairs the router chose and no other. ``layer_forward`` is
+the same block over the whole stream at once, with the whole score
+matrix and every row through every held expert: it is what the tests
+compare the blocked forms with, and it cannot take a stream of tens of
+thousands of rows (16 heads x 47k x 47k scores are 141 GB, 256 experts
+over every row 76 PFLOP a layer).
 
 The layer, with what the published ``config.json`` does not carry
 marked A (each is how the ``qwen3_next`` modelling code has it, and each
@@ -132,22 +140,124 @@ def experts_sum(h, lp, w, precision: str, block: int = EXPERT_BLOCK):
     return acc
 
 
-def gated_attention(a, f, *, rope_dims: int, precision: str):
-    """The attention mixer on normed a: (B, T, D); f: float32 leaves."""
-    pos = jnp.arange(a.shape[1])
-    q = rms_norm(_mm("btd,dhk->bthk", a, f["wq"], precision), f["qn_s"])
+def experts_routed(h, lp, w, precision: str, top_k: int,
+                   capacity: int | None = None, block: int = EXPERT_BLOCK):
+    """``experts_sum`` over the (row, expert) pairs whose weight is not
+    zero, and no other: the weights elsewhere are exactly zero, so it
+    is the same sum, in another order of additions. For each held
+    expert the rows that chose it, ``capacity`` of them a pass (an
+    expert with more gets further passes, so no pair is dropped), go
+    through its three matrices; a row then adds up what its own pairs
+    gave, each at its weight: at most ``top_k`` of them.
+    h: (1, T, D); w: (1, T, E_held)."""
+    h2, w2 = h[0], w[0]
+    T, E = w2.shape
+    D = h2.shape[-1]
+    C = capacity or max(8, -(-T // 16))
+    block = math.gcd(E, block)
+    blocks = lambda a: a.reshape((E // block, block) + a.shape[1:])
+    chosen = w2 != 0
+    load = chosen.sum(0)                         # rows that chose an expert
+    # an expert's rows first, in their order, and a row's place among them
+    order = jnp.argsort(jnp.logical_not(chosen), axis=0, stable=True)
+    order = jnp.concatenate([order, jnp.full((C, E), T, order.dtype)])
+    place = jnp.cumsum(chosen, axis=0) - 1       # (T, E)
+    h_pad = jnp.concatenate([h2, jnp.zeros((1, D), h2.dtype)])  # row T: none
+    # a row's own pairs: its non-zero weights are among its top_k largest
+    wk, ek = jax.lax.top_k(w2, min(top_k, E))    # (T, k)
+    place_k = jnp.take_along_axis(place, ek, axis=1)
+
+    def one_pass(state):
+        p, acc = state
+        rows = jax.lax.dynamic_slice_in_dim(order, p * C, C, axis=0)
+        live = (p * C + jnp.arange(C))[:, None] < load[None, :]
+        rows = jnp.where(live, rows, T).T        # (E, C)
+
+        def one_block(_, args):
+            wg, wu, wd, rb = args
+            f32 = lambda a: a.astype(jnp.float32)
+            hb = h_pad[rb]                       # (block, C, D)
+            a = jax.nn.silu(_mm("ecd,edf->ecf", hb, f32(wg), precision))
+            a = a * _mm("ecd,edf->ecf", hb, f32(wu), precision)
+            return None, _mm("ecf,efd->ecd", a, f32(wd), precision)
+
+        _, y = jax.lax.scan(
+            one_block, None,
+            (blocks(lp["we_gate"]), blocks(lp["we_up"]),
+             blocks(lp["we_down"]), blocks(rows)))
+        at = place_k - p * C
+        mine = (wk != 0) & (at >= 0) & (at < C)
+        y = y.reshape(E * C, D)[jnp.where(mine, ek * C + at, 0)]  # (T, k, D)
+        return p + 1, acc + jnp.einsum(
+            "tkd,tk->td", y, jnp.where(mine, wk, 0.0), precision=HIGHEST)
+
+    _, acc = jax.lax.while_loop(
+        lambda state: state[0] * C < load.max(), one_pass,
+        (jnp.int32(0), jnp.zeros_like(h2)))
+    return acc[None]
+
+
+def attention_rows(q, k, v, row0: int, precision: str):
+    """``attention`` for the query rows ``row0..`` alone against the
+    keys so far. q: (B, R, H, Dh); k, v: (B, row0 + R, Hkv, Dh), the
+    stream's keys up to the block's last row. One head's (R, row0 + R)
+    scores at a time."""
+    B, R, H, Dh = q.shape
+    K = k.shape[1]
+    group = H // k.shape[2]
+    mask = (row0 + jnp.arange(R))[:, None] >= jnp.arange(K)[None, :]
+    scale = 1.0 / math.sqrt(Dh)
+
+    def one_head(args):
+        qh, kh, vh = args
+        s = _mm("qd,kd->qk", qh, kh, precision) * scale
+        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        return _mm("qk,kd->qd", p, vh, precision)
+
+    heads = lambda t: t.transpose(0, 2, 1, 3)
+    qf = heads(q).reshape(B * H, R, Dh)
+    kf = jnp.repeat(heads(k), group, axis=1).reshape(B * H, K, Dh)
+    vf = jnp.repeat(heads(v), group, axis=1).reshape(B * H, K, Dh)
+    o = jax.lax.map(one_head, (qf, kf, vf))
+    return o.reshape(B, H, R, Dh).transpose(0, 2, 1, 3)
+
+
+def gated_attention(a, f, *, rope_dims: int, precision: str,
+                    rows: int | None = None):
+    """The attention mixer on normed a: (B, T, D); f: float32 leaves.
+    With ``rows``, a block of that many query rows at a time against
+    the keys so far; without, the whole (T, T) score matrix of a head."""
+    T = a.shape[1]
+    pos = jnp.arange(T)
     k = rms_norm(_mm("btd,dhk->bthk", a, f["wk"], precision), f["kn_s"])
-    v = _mm("btd,dhk->bthk", a, f["wv"], precision)
-    gate = _mm("btd,dhk->bthk", a, f["wog"], precision)
-    q = rope_partial(q, pos, rope_dims, ROPE_BASE)
     k = rope_partial(k, pos, rope_dims, ROPE_BASE)
-    o = attention(q, k, v, None, precision) * jax.nn.sigmoid(gate)
-    return _mm("bthk,hkd->btd", o, f["wo"], precision)
+    v = _mm("btd,dhk->bthk", a, f["wv"], precision)
+
+    def block(r0, r1):
+        ab = a[:, r0:r1]
+        q = rms_norm(_mm("btd,dhk->bthk", ab, f["wq"], precision), f["qn_s"])
+        q = rope_partial(q, pos[r0:r1], rope_dims, ROPE_BASE)
+        gate = _mm("btd,dhk->bthk", ab, f["wog"], precision)
+        if rows is None:
+            o = attention(q, k, v, None, precision)
+        else:
+            o = attention_rows(q, k[:, :r1], v[:, :r1], r0, precision)
+        return _mm("bthk,hkd->btd", o * jax.nn.sigmoid(gate), f["wo"],
+                   precision)
+
+    if rows is None:
+        return block(0, T)
+    return jnp.concatenate(
+        [block(r0, r0 + rows) for r0 in range(0, T, rows)], axis=1)
 
 
-def gated_delta(a, f, *, key_heads: int, key_dim: int, precision: str):
-    """The gated delta-rule mixer on normed a: (B, T, D), from a zero
-    state, one token at a time."""
+def gated_delta(a, f, *, key_heads: int, key_dim: int, precision: str,
+                carry=None):
+    """The gated delta-rule mixer on normed a: (B, T, D), one token at
+    a time, from a zero state; or, given ``carry`` (the state ``S`` and
+    the last ``taps - 1`` rows that went into the conv, as an earlier
+    block of the stream's rows left them), from there, and then the
+    block's own ``(S, rows)`` is returned beside the result."""
     B, T, _ = a.shape
     Hv, Dv = f["gdn_A_log"].shape[0], f["gdn_norm_s"].shape[0]
     Hk, Dk = key_heads, key_dim
@@ -156,7 +266,12 @@ def gated_delta(a, f, *, key_heads: int, key_dim: int, precision: str):
     ba = _mm("btd,dc->btc", a, f["gdn_wba"], precision)
     qkv, z = qkvz[..., :2 * kw + vw], qkvz[..., 2 * kw + vw:]
     taps = f["gdn_conv_w"].shape[0]
-    back = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
+    if carry is None:
+        S0 = jnp.zeros((B, Hv, Dk, Dv), jnp.float32)
+        back = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
+    else:
+        S0 = carry[0]
+        back = jnp.concatenate([carry[1], qkv], axis=1)
     y = jax.nn.silu(sum(
         back[:, j:j + T] * f["gdn_conv_w"][j] for j in range(taps)))
     q = l2_norm(y[..., :kw].reshape(B, T, Hk, Dk)) / math.sqrt(Dk)
@@ -176,12 +291,18 @@ def gated_delta(a, f, *, key_heads: int, key_dim: int, precision: str):
         return S, _mm("bhkv,bhk->bhv", S, q, precision)
 
     by_token = lambda t: jnp.moveaxis(t, 1, 0)
-    _, o = jax.lax.scan(
-        token, jnp.zeros((B, Hv, Dk, Dv), jnp.float32),
-        tuple(by_token(t) for t in (q, k, v, g, beta)))
+    S, o = jax.lax.scan(
+        token, S0, tuple(by_token(t) for t in (q, k, v, g, beta)))
     o = rms_norm(jnp.moveaxis(o, 0, 1), f["gdn_norm_s"])
     o = o * jax.nn.silu(z.reshape(B, T, Hv, Dv))
-    return _mm("btc,cd->btd", o.reshape(B, T, vw), f["gdn_wout"], precision)
+    out = _mm("btc,cd->btd", o.reshape(B, T, vw), f["gdn_wout"], precision)
+    return out if carry is None else (out, (S, back[:, T:]))
+
+
+def shared_expert(h, f, precision: str):
+    """The gated shared expert every row goes through."""
+    m = gated_mlp(h, f["ws_gate"], f["ws_up"], f["ws_down"], precision)
+    return m * jax.nn.sigmoid(_mm("btd,do->bto", h, f["ws_sgate"], precision))
 
 
 def layer_forward(x, lp, *, precision: str = "float32", top_k: int = TOP_K,
@@ -202,10 +323,54 @@ def layer_forward(x, lp, *, precision: str = "float32", top_k: int = TOP_K,
     h = rms_norm(x, f["ln2_s"])
     w = route_weights(h, f["router"], top_k, precision)
     held = lp["we_gate"].shape[0]
-    m = gated_mlp(h, f["ws_gate"], f["ws_up"], f["ws_down"], precision)
-    m = m * jax.nn.sigmoid(_mm("btd,do->bto", h, f["ws_sgate"], precision))
-    return x + m + experts_sum(h, lp, w[..., held_lo:held_lo + held],
-                               precision)
+    return x + shared_expert(h, f, precision) + experts_sum(
+        h, lp, w[..., held_lo:held_lo + held], precision)
+
+
+def row_block(T: int, most: int = 4096) -> int:
+    """The largest divisor of T that is at most ``most``: the rows of a
+    block that ``layer_forward_rows`` takes at a time."""
+    return max(r for r in range(1, min(T, most) + 1) if T % r == 0)
+
+
+def layer_forward_rows(x, lp, *, rows: int, precision: str = "float32",
+                       top_k: int = TOP_K, held_lo: int = 0,
+                       key_heads: int = KEY_HEADS, key_dim: int = KEY_DIM,
+                       rope_dims: int = ROPE_DIMS):
+    """``layer_forward`` for one long stream, x: (1, T, D), ``rows`` of
+    its rows at a time (T a whole number of them): the mixer block by
+    block with what it carries, then the experts of each block over the
+    pairs the router chose (``experts_routed``)."""
+    stacked = ("we_gate", "we_up", "we_down")
+    f = {n: a.astype(jnp.float32) for n, a in lp.items() if n not in stacked}
+    T, D = x.shape[1:]
+    a = rms_norm(x, f["ln1_s"])
+    if "gdn_wqkvz" in lp:
+        Hv, Dv = f["gdn_A_log"].shape[0], f["gdn_norm_s"].shape[0]
+        taps, chans = f["gdn_conv_w"].shape
+
+        def delta(carry, ab):
+            out, carry = gated_delta(
+                ab[None], f, key_heads=key_heads, key_dim=key_dim,
+                precision=precision, carry=carry)
+            return carry, out[0]
+
+        zero = (jnp.zeros((1, Hv, key_dim, Dv), jnp.float32),
+                jnp.zeros((1, taps - 1, chans), jnp.float32))
+        _, mixed = jax.lax.scan(delta, zero, a[0].reshape(-1, rows, D))
+        x = x + mixed.reshape(1, T, D)
+    else:
+        x = x + gated_attention(a, f, rope_dims=rope_dims,
+                                precision=precision, rows=rows)
+    held = lp["we_gate"].shape[0]
+
+    def experts(xb):
+        h = rms_norm(xb[None], f["ln2_s"])
+        w = route_weights(h, f["router"], top_k, precision)
+        return (shared_expert(h, f, precision) + experts_routed(
+            h, lp, w[..., held_lo:held_lo + held], precision, top_k))[0]
+
+    return x + jax.lax.map(experts, x[0].reshape(-1, rows, D)).reshape(1, T, D)
 
 
 def head_logits(x, head, lnf_s, precision: str = "float32"):
@@ -214,10 +379,12 @@ def head_logits(x, head, lnf_s, precision: str = "float32"):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_layer(precision, top_k, held_lo, key_heads, key_dim, rope_dims):
+def _jitted_layer(rows, precision, top_k, held_lo, key_heads, key_dim,
+                  rope_dims):
     return jax.jit(functools.partial(
-        layer_forward, precision=precision, top_k=top_k, held_lo=held_lo,
-        key_heads=key_heads, key_dim=key_dim, rope_dims=rope_dims,
+        layer_forward_rows, rows=rows, precision=precision, top_k=top_k,
+        held_lo=held_lo, key_heads=key_heads, key_dim=key_dim,
+        rope_dims=rope_dims,
     ))
 
 
@@ -233,10 +400,10 @@ def stream_logits(params, tokens, first_row: int, n_rows: int, *,
     """Logits (n_rows, vocab) of rows first_row.. of one token sequence
     (tokens: (T,) int32, already padded to the length to compile for):
     row j predicts token j + 1. Which layers are delta-rule layers is
-    read from their leaves."""
+    read from their leaves. The head is over the rows asked for only."""
     x = params["emb"][tokens].astype(jnp.float32)[None]
-    layer = _jitted_layer(precision, top_k, held_lo, key_heads, key_dim,
-                          rope_dims)
+    layer = _jitted_layer(row_block(len(tokens)), precision, top_k, held_lo,
+                          key_heads, key_dim, rope_dims)
     for lp in params["layers"]:
         x = layer(x, lp)
     rows = jax.lax.dynamic_slice_in_dim(x[0], first_row, n_rows, axis=0)

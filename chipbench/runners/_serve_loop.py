@@ -104,6 +104,7 @@ class Deliveries:
         self.ticks: list[tuple[float, int, int]] = []  # (t_end, tokens, decoding)
         self.finished: list[int] = []
         self.kv_rows_sum = 0.0          # cached rows attended, summed
+        self.rows_by_tick: list[int] = []  # and tick by tick
         self.hits: list[float] = []     # the tick's own experts_hit
 
     def after_tick(self, t: float, record: bool) -> None:
@@ -134,6 +135,7 @@ class Deliveries:
         if record:
             self.ticks.append((t, delivered, decoding))
             self.kv_rows_sum += rows
+            self.rows_by_tick.append(rows)
             hit = getattr(self.sched, "experts_hit", None)
             if decoding and hit is not None:
                 self.hits.append(hit)
@@ -216,6 +218,7 @@ def serve(run, sched, reqs, kv_rows) -> Served:
         ticks=ticks, slots=slots, n_inner=int(program["n_inner"]),
         token_gaps=(gaps, gap_w),
         mean_kv_rows_per_tick=book.kv_rows_sum / max(1, len(ticks)),
+        kv_rows_by_tick=book.rows_by_tick,
     )
     print("series tick_ms " + common.compact(tick_ms, 1), flush=True)
     print(f"note window rounds {R} ticks {len(ticks)} seconds "

@@ -38,6 +38,7 @@ from chipbench.runners import _model, _serve_loop
 from chipbench.runners.serve_mla import _mscale, yarn
 
 KEY_SALT = 3  # weights.seed_key's salt for the requests' sampling keys
+ORDER_SALT = 4  # and for the order of the vocabulary's rows
 
 
 def sizes(config: dict) -> dict:
@@ -173,15 +174,26 @@ def make_params(config: dict, seed: int):
     are the model's arrays, so it has none of its own to draw. Made
     layer by layer, each from a seed of its own: the four expert layers
     and the module's block are one program's five runs, where one
-    program over all 11 GB takes a minute to compile."""
+    program over all 11 GB takes a minute to compile.
+
+    Where the configuration has ``weights_draw``, every seed gets the
+    SAME values, that draw's, in another order: the seed permutes the
+    vocabulary's rows of embedding and head (and draws the prompts and
+    the requests' keys, as everywhere). A draw of its own for every
+    seed changes the work of this kind's step: which of the held
+    experts the router likes is the weights', so the experts hit a
+    step, and with them the bytes it reads, move with the seed (PERF.md
+    section 2, ``serve_dsv3_chat``)."""
     import jax
     import jax.numpy as jnp
 
     z = sizes(config)
     shapes = param_shapes(config)
+    draw = config.get("weights_draw")
+    values = int(seed) if draw is None else int(draw["seed"])
 
     def make(tree, k: int):
-        return weights.make_params(tree, int(seed) * 64 + k,
+        return weights.make_params(tree, values * 64 + k,
                                    d_model=z["d_model"],
                                    n_layers=z["n_layers"])
 
@@ -198,10 +210,15 @@ def make_params(config: dict, seed: int):
         return (jnp.ones_like(a)
                 if weights.leaf_name(path).endswith("_s") else a)
 
-    return jax.jit(
-        lambda p: jax.tree_util.tree_map_with_path(redraw, p),
-        donate_argnums=(0,),
-    )(params)
+    def finish(p, key):
+        p = jax.tree_util.tree_map_with_path(redraw, p)
+        if draw is not None:
+            order = jax.random.permutation(key, z["vocab"])
+            p["emb"], p["head"] = p["emb"][order], p["head"][order]
+        return p
+
+    return jax.jit(finish, donate_argnums=(0,))(
+        params, weights.seed_key(seed, ORDER_SALT))
 
 
 def reference_sizes(config: dict) -> dict:

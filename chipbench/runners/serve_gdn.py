@@ -197,27 +197,43 @@ def make_params(config: dict, seed: int):
     )(params)
 
 
+PAD_ROWS = 4096   # a stream is padded to a whole number of these
+READ_ROWS = 256   # and as many rows are read: once, or four times
+
+
+def reference_shape(config: dict, prompt_len: int, served: int):
+    """(padded length, rows read) of the reference's program for one
+    stream: the next whole number of ``PAD_ROWS`` at or past the
+    stream's end, at most ``max_context`` (a cell whose contexts stop
+    under ``PAD_ROWS`` has one length), and ``READ_ROWS`` rows or four
+    times as many, so a cell's streams compile a handful of programs
+    and a chat stream does not pay for the longest document."""
+    max_context = int(config["program"]["max_context"])
+    end = prompt_len + served
+    rows = READ_ROWS if served <= READ_ROWS else 4 * READ_ROWS
+    if served > rows or end > max_context:
+        raise ValueError("a stream outgrew the reference's shapes")
+    length = min(max_context, -(-end // PAD_ROWS) * PAD_ROWS)
+    return length, min(rows, length)
+
+
 def reference_gaps(ref, config: dict, params, streams,
                    precision="float32"):
     """For each (prompt, served tokens): the reference's logits at the
     served positions, row by row: runners/serve_moe.py's function of
-    this name (every stream padded to ``max_context``, every read 256
-    rows, so each of the reference's programs compiles once) for this
-    block. Neither a causal layer nor a recurrence looks ahead, so the
-    padding changes no row that is read."""
+    this name for this block, with the stream padded and read as
+    ``reference_shape`` says. Neither a causal layer nor a recurrence
+    looks ahead, so the padding changes no row that is read."""
     import jax.numpy as jnp
 
-    max_context = int(config["program"]["max_context"])
-    rows = min(256, max_context)
     out = []
     for prompt, served in streams:
         tp, n = len(prompt), len(served)
-        if n > rows or tp + n > max_context:
-            raise ValueError("a stream outgrew the reference's one shape")
-        seq = np.zeros((max_context,), np.int32)
+        length, rows = reference_shape(config, tp, n)
+        seq = np.zeros((length,), np.int32)
         seq[:tp] = prompt
         seq[tp:tp + n] = served
-        first = min(tp - 1, max_context - rows)
+        first = min(tp - 1, length - rows)
         lg = np.asarray(ref.stream_logits(
             params, jnp.asarray(seq), first, rows, precision=precision,
             top_k=config["num_experts_per_tok"],

@@ -5,6 +5,7 @@ runs in a second. Nothing that is there is edited."""
 
 from __future__ import annotations
 
+import copy
 import json
 import shutil
 from pathlib import Path
@@ -106,3 +107,109 @@ def make_tiny_checkout(dst: Path) -> Path:
     })
     (dst / "BENCHMARK.json").write_text(json.dumps(manifest))
     return dst
+
+
+# -- the guard: what a later PR adds, at the END of every list ----------------
+#
+# One configuration, one serving mix, one cell and one per-layer metric
+# as new files and new entries behind everything BENCHMARK.json has,
+# the cell's name appended to the lists of the metrics its kind
+# reports. Every manifest-reading test of tests/chipbench runs on the
+# committed benchmark and on this copy (the ``checkout`` fixture of
+# conftest.py), so a test that holds an entry to a place, or a list of
+# names to what it was, fails here on the CPU and not in the PR that
+# adds the next cell. Unlike ``tiny_backlog`` the mix keeps every rule
+# of test_traffic.py; the cell is never run.
+
+GUARD_CONFIG = {
+    **CONFIGS["tiny-serve"],
+    "program": {**CONFIGS["tiny-serve"]["program"], "max_prompt": 512,
+                "max_context": 640},
+}
+
+GUARD_MIX = {
+    "arrivals": "backlog, as the committed serving mixes",
+    "round": 8, "rounds": 64, "warm_rounds": 2, "window_rounds": 58,
+    "check_requests": 4, "trace_seconds": 1,
+    "classes": [
+        {"name": "short", "share": 0.5,
+         "prompt": [16, 128, "log_uniform"],
+         "output": [32, 128, "log_uniform"]},
+        {"name": "long", "share": 0.5,
+         "prompt": [256, 512, "log_uniform"],
+         "output": [8, 32, "log_uniform"]},
+    ],
+}
+
+GUARD = {"config": "guard-serve", "traffic": "guard_backlog",
+         "cell": "guard_serve", "metric": "guard_attempted",
+         "like": "serve_sc2_chat"}
+
+
+def with_additions(manifest: dict) -> dict:
+    """``manifest`` with the guard's four entries appended, each at the
+    end of its list; nothing that is there is touched."""
+    out = copy.deepcopy(manifest)
+    out["configs"].append({
+        "name": GUARD["config"], "source": "tests/chipbench/_tiny.py",
+        "file": f"chipbench/configs/{GUARD['config']}.json", "reduced": [],
+        "why": "throw-away",
+    })
+    out["workloads"].append({
+        "name": GUARD["cell"], "config": GUARD["config"],
+        "traffic": GUARD["traffic"], "chips": 1, "why": "throw-away",
+    })
+    for m in out["end_to_end"] + out["per_layer"]:
+        if GUARD["like"] in m.get("workloads", ()):
+            m["workloads"].append(GUARD["cell"])
+    out["per_layer"].append({
+        "name": GUARD["metric"], "unit": "1", "better": "higher",
+        "source": "program_counter", "layer": "server",
+        "moves": "serve_tok_s", "workloads": [GUARD["cell"]],
+    })
+    return out
+
+
+def make_guard_checkout(dst: Path) -> Path:
+    """Copy BENCHMARK.json and the directories it names under ``paths``
+    to ``dst`` and add the guard's files and entries. Returns ``dst``."""
+    dst = Path(dst)
+    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    for base in manifest["paths"]:
+        shutil.copytree(REPO / base, dst / base,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    bench = dst / "chipbench"
+    (bench / "configs" / f"{GUARD['config']}.json").write_text(
+        json.dumps(GUARD_CONFIG))
+    (bench / "traffic" / f"{GUARD['traffic']}.json").write_text(
+        json.dumps(GUARD_MIX))
+    (bench / "metrics" / f"{GUARD['metric']}.py").write_text(
+        THROWAWAY_METRIC)
+    (dst / "BENCHMARK.json").write_text(
+        json.dumps(with_additions(manifest), indent=2) + "\n")
+    return dst
+
+
+def serving_cells(root: Path, manifest: dict) -> list[str]:
+    """The cells of ``manifest`` whose configuration's ``kind`` (its
+    file under ``root``) is a serving one, in the manifest's order."""
+    kind = {c["name"]: json.loads((Path(root) / c["file"]).read_text())["kind"]
+            for c in manifest["configs"]}
+    return [w["name"] for w in manifest["workloads"]
+            if kind[w["config"]].startswith("serve")]
+
+
+def serving_mixes(root: Path, manifest: dict) -> list[str]:
+    """The serving cells' ``traffic``, each once, in the manifest's
+    order: the serving mixes are what the manifest says they are."""
+    cells = set(serving_cells(root, manifest))
+    return list(dict.fromkeys(
+        w["traffic"] for w in manifest["workloads"] if w["name"] in cells))
+
+
+def stands_after(names: list, later, earlier) -> bool:
+    """Whether ``later`` is in ``names`` behind every one of
+    ``earlier`` (a name or several): what "was appended" means once
+    further entries may follow."""
+    earlier = [earlier] if isinstance(earlier, str) else list(earlier)
+    return all(names.index(later) > names.index(e) for e in earlier)

@@ -60,13 +60,21 @@ def test_nothing_to_read(summary):  # noqa: F811
     assert reader(NAME).read(idle) is None
 
 
-def test_the_manifest_lists_it_for_the_serving_cells():
+# The entry is looked up by name: later metrics and later serving cells
+# are appended behind it, as the guard's copy does (_tiny.py).
+@pytest.mark.parametrize("holds", ["the_entry", "every_serving_cell"])
+def test_the_manifest_lists_the_metric_by_name(checkout, holds):
     import json
 
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    entry = manifest["per_layer"][-1]
-    assert entry["name"] == NAME and entry["moves"] == "serve_tok_s"
-    assert entry["layer"] == "server" and entry["unit"] == "chunks"
-    serving = [w["name"] for w in manifest["workloads"]
-               if w["name"].startswith("serve_")]
-    assert entry["workloads"] == serving
+    import _tiny
+
+    manifest = json.loads((checkout / "BENCHMARK.json").read_text())
+    (entry,) = [m for m in manifest["per_layer"] if m["name"] == NAME]
+    if holds == "the_entry":
+        assert entry["moves"] == "serve_tok_s"
+        assert entry["layer"] == "server" and entry["unit"] == "chunks"
+        # behind the metrics that were accepted before it (PR 32's)
+        names = [m["name"] for m in manifest["per_layer"]]
+        assert _tiny.stands_after(names, NAME, "experts_local_pct")
+    else:
+        assert entry["workloads"] == _tiny.serving_cells(checkout, manifest)

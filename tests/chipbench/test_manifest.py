@@ -1,5 +1,8 @@
 """BENCHMARK.json against the contract's shape, and against the files it
-names: everything a cell needs is found by name."""
+names: everything a cell needs is found by name. Every check runs on
+the committed benchmark and on the guard's copy with one configuration,
+one serving mix, one cell and one per-layer metric appended at the ends
+of their lists (tests/chipbench/_tiny.py, conftest.py)."""
 
 from __future__ import annotations
 
@@ -9,14 +12,45 @@ from pathlib import Path
 
 import pytest
 
+import _tiny
+
 REPO = Path(__file__).resolve().parents[2]
-MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
+MANIFESTS = {"committed": json.loads((REPO / "BENCHMARK.json").read_text())}
+MANIFESTS["with_additions"] = _tiny.with_additions(MANIFESTS["committed"])
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-def test_top_level_keys():
+def each(*groups):
+    """(checkout, entry) for every entry of the named lists, in both
+    manifests: the ids a parametrised check runs under."""
+    return [pytest.param(which, e, id=f"{which}-{e['name']}")
+            for which, m in MANIFESTS.items()
+            for g in groups for e in m[g]]
+
+
+@pytest.fixture
+def at(checkout):
+    """(root, manifest as that checkout's file has it)."""
+    return checkout, json.loads((checkout / "BENCHMARK.json").read_text())
+
+
+def test_the_copy_holds_what_the_parametrised_checks_assume(root_of):
+    for which, manifest in MANIFESTS.items():
+        on_disk = json.loads(
+            (root_of(which) / "BENCHMARK.json").read_text())
+        assert on_disk == manifest
+    added = MANIFESTS["with_additions"]
+    names = lambda m, group: [e["name"] for e in m[group]]
+    for group in ("configs", "workloads", "per_layer"):
+        assert names(added, group)[:-1] == names(MANIFESTS["committed"],
+                                                 group)
+        assert names(added, group)[-1] in _tiny.GUARD.values()
+
+
+def test_top_level_keys(at):
+    REPO, MANIFEST = at
     assert set(MANIFEST) == {
         "command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer",
@@ -28,12 +62,8 @@ def test_top_level_keys():
 
 
 @pytest.mark.parametrize(
-    "entry",
-    MANIFEST["configs"] + MANIFEST["workloads"] + MANIFEST["end_to_end"]
-    + MANIFEST["per_layer"],
-    ids=lambda e: e["name"],
-)
-def test_names_units_and_lines(entry):
+    "which,entry", each("configs", "workloads", "end_to_end", "per_layer"))
+def test_names_units_and_lines(which, entry):
     assert NAME.match(entry["name"])
     for key in ("config", "traffic", "moves"):
         if key in entry:
@@ -51,7 +81,8 @@ def test_names_units_and_lines(entry):
         assert NAME.match(key)
 
 
-def test_entries_have_just_the_contract_keys():
+def test_entries_have_just_the_contract_keys(at):
+    _, MANIFEST = at
     for c in MANIFEST["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
     for w in MANIFEST["workloads"]:
@@ -67,7 +98,8 @@ def test_entries_have_just_the_contract_keys():
             "name", "unit", "better", "source", "layer", "moves"}
 
 
-def test_names_are_unique_and_cross_references_hold():
+def test_names_are_unique_and_cross_references_hold(at):
+    _, MANIFEST = at
     cells = [w["name"] for w in MANIFEST["workloads"]]
     configs = [c["name"] for c in MANIFEST["configs"]]
     metrics = [m["name"] for m in
@@ -90,9 +122,9 @@ def test_names_are_unique_and_cross_references_hold():
     assert four <= max(1, len(cells) // 4)
 
 
-@pytest.mark.parametrize("cell", MANIFEST["workloads"],
-                         ids=lambda w: w["name"])
-def test_every_cell_reports_enough_and_finds_its_files(cell):
+@pytest.mark.parametrize("which,cell", each("workloads"))
+def test_every_cell_reports_enough_and_finds_its_files(which, cell, root_of):
+    REPO, MANIFEST = root_of(which), MANIFESTS[which]
     name = cell["name"]
     has = lambda m: "workloads" not in m or name in m["workloads"]
     assert sum(1 for m in MANIFEST["end_to_end"] if has(m)) >= 2
@@ -109,15 +141,42 @@ def test_every_cell_reports_enough_and_finds_its_files(cell):
     assert "limits" in body
 
 
-@pytest.mark.parametrize("metric", MANIFEST["per_layer"],
-                         ids=lambda m: m["name"])
-def test_every_per_layer_metric_has_a_reader_of_its_own(metric):
-    path = REPO / "chipbench" / "metrics" / f"{metric['name']}.py"
+@pytest.mark.parametrize("which,cell", each("workloads"))
+def test_a_serving_cells_program_holds_its_mix(which, cell, root_of):
+    """A cell that names a mix its program cannot hold fails here and
+    not after set-up on the chip: no class's longest prompt is past
+    ``max_prompt``, and none's longest prompt with its longest answer
+    (and a drafting tick's rows past a request's end, where the program
+    drafts) is past ``max_context``, itself whole pages."""
+    root, manifest = root_of(which), MANIFESTS[which]
+    traffic = json.loads((root / "chipbench" / "traffic"
+                          / f"{cell['traffic']}.json").read_text())
+    if "round" not in traffic:
+        return  # not a serving mix: nothing to hold
+    config = next(c for c in manifest["configs"]
+                  if c["name"] == cell["config"])
+    program = json.loads((root / config["file"]).read_text())["program"]
+    spare = 2 * program["n_inner"] if program.get("draft") else 0
+    for c in traffic["classes"]:
+        assert c["prompt"][1] <= program["max_prompt"], c["name"]
+        if "max_context" in program:
+            assert (c["prompt"][1] + c["output"][1] + spare
+                    <= program["max_context"]), c["name"]
+    if "max_context" in program:
+        assert program["max_context"] % program["page_tokens"] == 0
+    assert program["prompt_chunk"] <= program["max_prompt"]
+
+
+@pytest.mark.parametrize("which,metric", each("per_layer"))
+def test_every_per_layer_metric_has_a_reader_of_its_own(which, metric,
+                                                        root_of):
+    path = root_of(which) / "chipbench" / "metrics" / f"{metric['name']}.py"
     assert path.is_file()
     assert "def read(" in path.read_text() or " as read" in path.read_text()
 
 
-def test_files_under_paths_are_named_from_name_characters():
+def test_files_under_paths_are_named_from_name_characters(at):
+    REPO, MANIFEST = at
     for base in MANIFEST["paths"]:
         for path in (REPO / base).rglob("*"):
             if "__pycache__" in path.parts:

@@ -162,17 +162,20 @@ def test_every_reader_finds_nothing_without_a_device_trace(name):
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_the_manifest_lists_the_metric_after_the_accepted_ones(name):
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+def test_the_manifest_lists_the_metric_after_the_accepted_ones(name,
+                                                               checkout):
+    import _tiny
+
+    manifest = json.loads((checkout / "BENCHMARK.json").read_text())
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names.index(name) >= 30 and names[:30][-1] == "hc_share_pct"
+    assert _tiny.stands_after(names, name, "hc_share_pct")
     layer, moves, cells = NEW[name]
     entry = manifest["per_layer"][names.index(name)]
     moved = next(m for m in manifest["end_to_end"] if m["name"] == moves)
     want = {
-        "train": ["train_sc2_8k"],
-        "serve": [w["name"] for w in manifest["workloads"]
-                  if w["name"].startswith("serve_")],
+        "train": [w["name"] for w in manifest["workloads"]
+                  if w["name"] not in _tiny.serving_cells(checkout, manifest)],
+        "serve": _tiny.serving_cells(checkout, manifest),
         "tail": moved["workloads"],
     }[cells]
     assert entry["workloads"] == want

@@ -239,12 +239,44 @@ def test_shapes_are_the_programs_own():
     assert (jax.random.key_data(a) != jax.random.key_data(b)).any()
 
 
+def test_a_fixed_draw_gives_every_seed_the_same_values_in_another_order():
+    """``weights_draw``: the values are that draw's for every seed; the
+    seed orders the vocabulary's rows, embedding and head alike, and
+    without the key every seed draws its own values as before."""
+    import jax
+    import numpy as np
+
+    from chipbench.runners import serve_dsv3
+
+    fixed = {**TINY, "weights_draw": {"seed": 7}}
+    a, b = (serve_dsv3.make_params(fixed, seed) for seed in (11, 2**31 + 12))
+    again = serve_dsv3.make_params(fixed, 11)
+    own = serve_dsv3.make_params(TINY, 7)
+    rest = lambda p: {k: v for k, v in p.items() if k not in ("emb", "head")}
+    same = lambda x, y: all(jax.tree.leaves(jax.tree.map(
+        lambda u, v: bool((u == v).all()), x, y)))
+    assert same(rest(a), rest(b)) and same(rest(a), rest(own))
+    assert same(a, again)
+    emb_a, emb_b, emb_own = (np.asarray(p["emb"]) for p in (a, b, own))
+    assert not (emb_a == emb_b).all()
+    # one order for embedding and head: row i of a is row order[i] of
+    # the draw, in both
+    order = [int(np.flatnonzero((emb_own == row).all(axis=1))[0])
+             for row in emb_a]
+    assert sorted(order) == list(range(TINY["vocab_size"]))
+    assert order != list(range(TINY["vocab_size"]))
+    assert (np.asarray(a["head"]) == np.asarray(own["head"])[order]).all()
+    # no key: the seed's own values
+    assert not same(rest(serve_dsv3.make_params(TINY, 11)), rest(own))
+
+
 # -- the configuration file against the catalog row ----------------------------
 
 
-def test_configuration_keeps_every_published_key_but_the_reduced():
+def test_configuration_keeps_every_published_key_but_the_reduced(checkout):
     if not CATALOG.is_file():
         pytest.skip("no catalog on this machine")
+    REPO = checkout
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
                if r["name"] == "DeepSeek-V3")
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -293,17 +325,27 @@ def test_configuration_keeps_every_published_key_but_the_reduced():
     assert prog["max_context"] >= (prog["max_prompt"] + 256
                                    + 2 * prog["n_inner"])
     assert (prog["temperature"], prog["draft"]) == (1.0, "mtp")
+    # one draw's values for every seed (PERF.md section 2)
+    assert cfg["weights_draw"]["seed"] == 39 and cfg["weights_draw"]["why"]
     assert set(cfg["limits"]) == {
         "logit_gap_worst", "logit_gap_mean", "draft_gap_worst",
         "draft_gap_mean", "accept_rate_gap"}
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL_NAME)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "dsv3-671b-a37b-serve", "chat_backlog", 1)
-    assert manifest["workloads"][-1] == cell
-    assert manifest["configs"][-1] == entry
+    # behind the cell and the configuration accepted before them
+    import _tiny
+
+    assert _tiny.stands_after([w["name"] for w in manifest["workloads"]],
+                              CELL_NAME, "serve_xing4_mixed")
+    assert _tiny.stands_after([c["name"] for c in manifest["configs"]],
+                              entry["name"], "xing4-29b-a4b-serve")
 
 
-def test_the_manifest_lists_the_cell_where_the_issue_names_it():
+def test_the_manifest_lists_the_cell_where_the_issue_names_it(checkout):
+    import _tiny
+
+    REPO = checkout
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     by_name = {m["name"]: m
                for m in manifest["end_to_end"] + manifest["per_layer"]}
@@ -317,12 +359,22 @@ def test_the_manifest_lists_the_cell_where_the_issue_names_it():
               "mla_prefill_share_pct", "tick_scoped_pct", "head_share_pct",
               "head_hbm_pct"]
     for name in joined:
-        assert by_name[name]["workloads"][-1] == CELL_NAME, name
+        # on the list, behind the cell that was accepted before it
+        cells = by_name[name]["workloads"]
+        assert CELL_NAME in cells, name
+        if "serve_xing4_mixed" in cells:
+            assert _tiny.stands_after(cells, CELL_NAME,
+                                      "serve_xing4_mixed"), name
     own = {"mtp_accept_pct": ("scheduler (host)", "%"),
            "tokens_per_step": ("server", "tokens"),
            "mtp_share_pct": ("model step", "%"),
            "mtp_hbm_pct": ("model step", "%")}
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == list(own)
+    # its own four: together, in this order, behind PR 36's metrics
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index("mtp_accept_pct")
+    assert names[at:at + 4] == list(own)
+    assert _tiny.stands_after(names, "mtp_accept_pct",
+                              "chunk_attn_share_pct")
     for name, (layer, unit) in own.items():
         m = by_name[name]
         assert (m["workloads"], m["moves"], m["layer"], m["unit"]) == (
@@ -336,36 +388,6 @@ def test_the_manifest_lists_the_cell_where_the_issue_names_it():
                  "first_token_wait_ms", "kv_full_pages_pct", "gdn_share_pct",
                  "hc_share_pct", "moe_experts_hbm_pct", "train_tok_s"):
         assert CELL_NAME not in by_name[name]["workloads"]
-
-
-def test_what_test_serve_mla_held_of_the_older_cell_still_holds():
-    """By name, everything test_serve_mla.py::
-    test_the_manifest_lists_the_cell_where_the_issue_names_it asserts of
-    ``serve_xing4_mixed``, but that it be LAST on each list: this PR's
-    cell is appended behind it, that file is a ``benchmark`` PR's to
-    edit, and tests/conftest.py holds the old test as a strict expected
-    failure of that one assertion."""
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    by_name = {m["name"]: m
-               for m in manifest["end_to_end"] + manifest["per_layer"]}
-    cell = "serve_xing4_mixed"
-    for name in ("serve_tok_s", "slot_occupancy_pct", "decode_step_hbm_pct",
-                 "serve_device_idle_pct", "idle_in_admit_pct",
-                 "idle_in_decode_pct", "idle_in_harvest_pct",
-                 "idle_outside_step_pct", "admitting_slots_pct",
-                 "tick_gather_share_pct", "chunks_per_prefill_program",
-                 "moe_share_pct", "moe_experts_hbm_pct", "experts_hit_pct"):
-        assert cell in by_name[name]["workloads"], name
-    for name in ("mla_attn_share_pct", "mla_cache_hbm_pct",
-                 "mla_prefill_share_pct", "hc_share_pct"):
-        m = by_name[name]
-        assert m["workloads"][0] == cell
-        assert (m["moves"], m["layer"], m["unit"]) == (
-            "serve_tok_s", "model step", "%")
-    for name in ("kv_full_pages_pct", "gdn_share_pct", "experts_local_pct",
-                 "train_tok_s", "itl_p95_ms", "prefill_share_pct",
-                 "itl_p50_ms", "first_token_wait_ms"):
-        assert cell not in by_name[name]["workloads"]
 
 
 def test_the_published_configuration_is_the_programs_block():

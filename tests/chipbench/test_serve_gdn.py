@@ -196,14 +196,17 @@ def test_shapes_are_the_programs_own():
 # -- the configuration file against the catalog row ----------------------------
 
 
-def test_configuration_keeps_every_published_key_but_the_reduced():
+@pytest.mark.parametrize("name", ["q3next-80b-a3b-serve",
+                                  "q3next-80b-a3b-serve-long"])
+def test_configuration_keeps_every_published_key_but_the_reduced(checkout,
+                                                                 name):
     if not CATALOG.is_file():
         pytest.skip("no catalog on this machine")
+    REPO = checkout
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
                if r["name"] == "Qwen3-Next-80B-A3B-Instruct")
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    entry = next(c for c in manifest["configs"]
-                 if c["name"] == "q3next-80b-a3b-serve")
+    entry = next(c for c in manifest["configs"] if c["name"] == name)
     cfg = json.loads((REPO / entry["file"]).read_text())
     assert entry["source"] == row["source_url"] == cfg["source"]
     reduced = set(entry["reduced"])
@@ -237,10 +240,30 @@ def test_configuration_keeps_every_published_key_but_the_reduced():
     assert (cfg["vocab_size"], cfg["tie_word_embeddings"]) == (151936, False)
     prog = cfg["program"]
     assert prog["max_context"] % prog["page_tokens"] == 0
-    assert prog["max_context"] >= prog["max_prompt"] + 256
     trinity = json.loads(
         (REPO / "chipbench/configs/trinity-mini-serve.json").read_text())
-    assert prog == trinity["program"]  # one schedule for both
+    if name == "q3next-80b-a3b-serve":
+        assert prog["max_context"] >= prog["max_prompt"] + 256
+        assert prog == trinity["program"]  # one schedule for both
+        return
+    # the long configuration: the same block and the same scheduler,
+    # contexts to 32,768 + the long mix's largest answer
+    short = json.loads(
+        (REPO / "chipbench/configs/q3next-80b-a3b-serve.json").read_text())
+    apart = {"program", "program_why", "departures", "limits", "limits_from"}
+    assert {k for k in set(cfg) | set(short)
+            if cfg.get(k) != short.get(k)} <= apart
+    assert cfg["departures"][:-1] == short["departures"][:-1]
+    assert (prog["max_prompt"], prog["max_context"]) == (32768, 33792)
+    assert {k: v for k, v in prog.items()
+            if k not in ("max_prompt", "max_context", "prompt_chunk")} == {
+        k: v for k, v in short["program"].items()
+        if k not in ("max_prompt", "max_context", "prompt_chunk")}
+    assert prog["max_context"] <= cfg["max_position_embeddings"]
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "serve_q3next_long")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        name, "long_backlog", 1)
 
 
 # -- the readers on hand-made spans and device events --------------------------

@@ -310,7 +310,13 @@ def test_the_lines_of_a_run_and_the_keys_the_readers_use(monkeypatch,
     series = next(ln for ln in out if ln.startswith("series tick_ms "))
     assert len(series.split(" ", 2)[2].split(",")) == len(ticks) - 1
     assert {"ticks", "slots", "n_inner", "token_gaps",
-            "mean_kv_rows_per_tick"} <= set(run.info)
+            "mean_kv_rows_per_tick", "kv_rows_by_tick"} <= set(run.info)
+    # the rows the decoding slots attend, tick by tick: what the mean
+    # is the mean of (``attn_rows_hbm_pct`` reads the traced ticks')
+    rows = run.info["kv_rows_by_tick"]
+    assert len(rows) == len(ticks) and any(rows)
+    assert run.info["mean_kv_rows_per_tick"] == pytest.approx(
+        sum(rows) / len(rows))
     assert set(run.end_to_end) == {"setup_s", "serve_tok_s", "itl_p95_ms"}
     # the rate is the whole window's: tokens after the first tick
     # boundary over the time to the last

@@ -216,9 +216,10 @@ def test_shapes_are_the_programs_own():
 # -- the configuration file against the catalog row ----------------------------
 
 
-def test_configuration_keeps_every_published_key_but_the_reduced():
+def test_configuration_keeps_every_published_key_but_the_reduced(checkout):
     if not CATALOG.is_file():
         pytest.skip("no catalog on this machine")
+    REPO = checkout
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
                if r["name"] == "Xing4.0-29B-A4B")
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -267,53 +268,49 @@ def test_configuration_keeps_every_published_key_but_the_reduced():
         "xing4-29b-a4b-serve", "mixed_backlog", 1)
 
 
-def test_the_manifest_lists_the_cell_where_the_issue_names_it():
-    """The cell's name on the shared metrics' lists and on its own
-    four, each looked up by name (a later PR appends after them)."""
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+# The cell and its metrics are looked up by name: later cells are
+# appended behind it on every list, as the guard's copy does (_tiny.py).
+@pytest.mark.parametrize("holds", ["shared_lists", "own_metrics",
+                                   "left_out"])
+def test_the_manifest_lists_the_cell_by_name(checkout, holds):
+    import _tiny
+
+    manifest = json.loads((checkout / "BENCHMARK.json").read_text())
     by_name = {m["name"]: m
                for m in manifest["end_to_end"] + manifest["per_layer"]}
     cell = "serve_xing4_mixed"
-    shared = ["serve_tok_s", "slot_occupancy_pct", "decode_step_hbm_pct",
-              "serve_device_idle_pct", "idle_in_admit_pct",
-              "idle_in_decode_pct", "idle_in_harvest_pct",
-              "idle_outside_step_pct",
-              "admitting_slots_pct", "tick_gather_share_pct",
-              "chunks_per_prefill_program", "moe_share_pct",
-              "moe_experts_hbm_pct", "experts_hit_pct"]
-    for name in shared:
-        assert by_name[name]["workloads"][-1] == cell, name
-    own = {"mla_attn_share_pct": "serve_tok_s",
-           "mla_cache_hbm_pct": "serve_tok_s",
-           "mla_prefill_share_pct": "serve_tok_s",
-           "hc_share_pct": "serve_tok_s"}
-    for name, moves in own.items():
-        m = by_name[name]
-        assert (m["workloads"], m["moves"], m["layer"], m["unit"]) == (
-            [cell], moves, "model step", "%")
-        assert (REPO / "chipbench/metrics" / f"{name}.py").is_file()
-    # not the tail, nor the three metrics that move it: its quartile
-    # distance read 3.1 to 6.8% in four sets of six on the chip, over
-    # the 3% a new cell is admitted at (PERF.md section 2)
-    for name in ("kv_full_pages_pct", "gdn_share_pct", "experts_local_pct",
-                 "train_tok_s", "itl_p95_ms", "prefill_share_pct",
-                 "itl_p50_ms", "first_token_wait_ms"):
-        assert cell not in by_name[name]["workloads"]
-
-
-def test_the_manifest_still_lists_chunks_per_prefill_program():
-    """By name, everything that test_chunks_per_program.py::
-    test_the_manifest_lists_it_for_the_serving_cells asserts of the
-    entry it expects at ``per_layer[-1]``: metrics are appended after
-    it since PR 34, and that file is a ``benchmark`` PR's to edit
-    (tests/conftest.py holds it as a strict expected failure)."""
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    (entry,) = [m for m in manifest["per_layer"]
-                if m["name"] == "chunks_per_prefill_program"]
-    assert entry["moves"] == "serve_tok_s"
-    assert entry["layer"] == "server" and entry["unit"] == "chunks"
-    assert entry["workloads"] == [w["name"] for w in manifest["workloads"]
-                                  if w["name"].startswith("serve_")]
+    if holds == "shared_lists":
+        # on each, behind the cell that was accepted before it
+        for name in ("serve_tok_s", "slot_occupancy_pct",
+                     "decode_step_hbm_pct", "serve_device_idle_pct",
+                     "idle_in_admit_pct", "idle_in_decode_pct",
+                     "idle_in_harvest_pct", "idle_outside_step_pct",
+                     "admitting_slots_pct", "tick_gather_share_pct",
+                     "chunks_per_prefill_program", "moe_share_pct",
+                     "moe_experts_hbm_pct", "experts_hit_pct"):
+            assert _tiny.stands_after(by_name[name]["workloads"], cell,
+                                      "serve_q3next_mixed"), name
+    elif holds == "own_metrics":
+        names = [m["name"] for m in manifest["per_layer"]]
+        for name in ("mla_attn_share_pct", "mla_cache_hbm_pct",
+                     "mla_prefill_share_pct", "hc_share_pct"):
+            m = by_name[name]
+            # the first of its cells: a later latent cell comes behind
+            assert m["workloads"][0] == cell
+            assert (m["moves"], m["layer"], m["unit"]) == (
+                "serve_tok_s", "model step", "%")
+            assert (checkout / "chipbench/metrics" / f"{name}.py").is_file()
+            assert _tiny.stands_after(names, name,
+                                      "chunks_per_prefill_program")
+    else:
+        # not the tail, nor the three metrics that move it: its quartile
+        # distance read 3.1 to 6.8% in four sets of six on the chip, over
+        # the 3% a new cell is admitted at (PERF.md section 2)
+        for name in ("kv_full_pages_pct", "gdn_share_pct",
+                     "experts_local_pct", "train_tok_s", "itl_p95_ms",
+                     "prefill_share_pct", "itl_p50_ms",
+                     "first_token_wait_ms"):
+            assert cell not in by_name[name]["workloads"]
 
 
 def test_the_published_configuration_is_the_programs_block():

@@ -139,9 +139,10 @@ def test_shapes_are_the_programs_own():
 # -- the configuration file against the catalog row ----------------------------
 
 
-def test_configuration_keeps_every_published_key_but_the_reduced():
+def test_configuration_keeps_every_published_key_but_the_reduced(checkout):
     if not CATALOG.is_file():
         pytest.skip("no catalog on this machine")
+    REPO = checkout
     row = next(r for r in map(json.loads, CATALOG.read_text().splitlines())
                if r["name"] == "Trinity-Mini")
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())

@@ -1,5 +1,8 @@
 """The traffic generator: every seed offers the same requests, in the
-same cyclic order, from another starting point."""
+same cyclic order, from another starting point. The serving mixes are
+what the manifest says they are: the traffic of the cells whose
+configuration is of a serving kind, on the committed benchmark and on
+the guard's copy with a mix appended (tests/chipbench/_tiny.py)."""
 
 from __future__ import annotations
 
@@ -9,20 +12,34 @@ from pathlib import Path
 
 import pytest
 
+import _tiny
 from chipbench import common, traffic_gen
 
 REPO = Path(__file__).resolve().parents[2]
-MIXES = ["chat_backlog", "mixed_backlog"]
 
 
-def mix(name):
+def mix(name, root=REPO):
     return json.loads(
-        (REPO / "chipbench" / "traffic" / f"{name}.json").read_text())
+        (Path(root) / "chipbench" / "traffic" / f"{name}.json").read_text())
 
 
-@pytest.mark.parametrize("name", MIXES)
-def test_the_traffic_is_one_round_repeated(name):
-    t = mix(name)
+COMMITTED = _tiny.serving_mixes(
+    REPO, json.loads((REPO / "BENCHMARK.json").read_text()))
+# (checkout, mix) for every serving mix either manifest names: the
+# guard's copy has the committed ones and its own behind them
+MIXES = [
+    pytest.param(which, name, id=f"{which}-{name}")
+    for which, names in (
+        ("committed", COMMITTED),
+        ("with_additions", COMMITTED + [_tiny.GUARD["traffic"]]))
+    for name in names
+]
+each_mix = pytest.mark.parametrize("which,name", MIXES)
+
+
+@each_mix
+def test_the_traffic_is_one_round_repeated(which, name, root_of):
+    t = mix(name, root_of(which))
     size = t["round"]
     reqs = traffic_gen.ordered_requests(t)
     base = traffic_gen.one_round(t)
@@ -33,18 +50,26 @@ def test_the_traffic_is_one_round_repeated(name):
     assert len(set(base)) > size // 2  # not one length, a spread
 
 
-SERVING_FILES = sorted(
-    p.stem for p in (REPO / "chipbench" / "traffic").glob("*.json")
-    if "round" in json.loads(p.read_text()))
+def test_every_serving_file_is_named_by_a_cell_and_every_mix_is_a_file(
+        checkout):
+    """No dead file: a file under ``chipbench/traffic/`` that states a
+    ``round`` is some serving cell's traffic; and no cell names a mix
+    that is not there."""
+    manifest = json.loads((checkout / "BENCHMARK.json").read_text())
+    files = sorted(
+        p.stem for p in (checkout / "chipbench" / "traffic").glob("*.json")
+        if "round" in json.loads(p.read_text()))
+    named = _tiny.serving_mixes(checkout, manifest)
+    assert files == sorted(named)
+    # what the parametrised tests of this file ran on is that list
+    which = "committed" if checkout == REPO else "with_additions"
+    assert [p.values[1] for p in MIXES if p.values[0] == which] == named
 
 
-def test_the_mixes_named_here_are_the_serving_files():
-    assert SERVING_FILES == sorted(MIXES)
-
-
-@pytest.mark.parametrize("name", SERVING_FILES)
-def test_a_serving_file_states_its_window_in_rounds_and_outlasts_it(name):
-    t = mix(name)
+@each_mix
+def test_a_serving_file_states_its_window_in_rounds_and_outlasts_it(
+        which, name, root_of):
+    t = mix(name, root_of(which))
     for key in ("round", "rounds", "warm_rounds", "window_rounds"):
         assert isinstance(t[key], int) and t[key] >= 1, key
     # two rounds to spare: the slots are full when the window closes
@@ -55,12 +80,13 @@ def test_a_serving_file_states_its_window_in_rounds_and_outlasts_it(name):
 WINDOW_TOKENS = 20000
 
 
-@pytest.mark.parametrize("name", SERVING_FILES)
-def test_the_window_is_the_fewest_rounds_holding_20000_output_tokens(name):
+@each_mix
+def test_the_window_is_the_fewest_rounds_holding_20000_output_tokens(
+        which, name, root_of):
     """``window_rounds`` follows from the file alone, not from any
     program's tick series: every serving cell's ``itl_p95_ms`` is the
     percentile of about as many token gaps, a thousand beyond it."""
-    t = mix(name)
+    t = mix(name, root_of(which))
     per_round = sum(olen for _, _, olen in traffic_gen.one_round(t))
     assert t["window_rounds"] == -(-WINDOW_TOKENS // per_round)
 
@@ -82,9 +108,10 @@ def test_every_seed_offers_the_same_lengths_and_other_contents():
     assert any((x != y).any() for x, y in zip(a, b))
 
 
-@pytest.mark.parametrize("name", MIXES)
-def test_a_round_holds_each_class_at_its_share_and_range(name):
-    t = mix(name)
+@each_mix
+def test_a_round_holds_each_class_at_its_share_and_range(which, name,
+                                                         root_of):
+    t = mix(name, root_of(which))
     base = traffic_gen.one_round(t)
     count = Counter(r[0] for r in base)
     for ci, c in enumerate(t["classes"]):
