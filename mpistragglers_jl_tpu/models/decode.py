@@ -77,8 +77,6 @@ from .transformer import (
     attn_merge,
     embed,
     ffn_half,
-    gdn_half,
-    gdn_zero_state,
     hc_fold,
     hc_pre,
     head_logits,
@@ -88,6 +86,10 @@ from .transformer import (
     mla_project,
     mtp_input,
     param_specs,
+    pool_cells,
+    sparse_pick,
+    state_half,
+    zero_state,
 )
 
 __all__ = [
@@ -148,6 +150,8 @@ def _kernel_viable(q, cache_l) -> bool:
     tile, fits the kernel's VMEM budget. One predicate so the routing
     sites cannot drift from the kernel's actual constraints."""
     if not _is_quantized(cache_l) or "v" not in cache_l:
+        return False
+    if "kp" in cache_l:  # a selection of blocks: the paged tick's kernel
         return False
     Hq, Hkv = q.shape[2], cache_l["k"].shape[2]
     if q.shape[1] != 1 or q.shape[-1] % 128 != 0 or Hq % Hkv != 0:
@@ -311,19 +315,27 @@ def _latent_pv(p, cache_l: dict, R: int):
     return _group_pv(p, cache_l["k"][..., :R])
 
 
-def _cache_write(cache_l: dict, k, v, off, latent=None) -> dict:
+def _cache_write(cache_l: dict, k, v, off, latent=None, *, cfg=None,
+                 valid=None) -> dict:
     """Write a chunk's K/V at position-axis offset ``off``, quantizing
     when the cache is int8 (detected from the layout, so every caller
     — masked, ring, chunked — shares one write path). A latent layer
-    (``latent``: the latent's width) writes its one row, ``k``."""
+    (``latent``: the latent's width) writes its one row, ``k``. A
+    cache that keeps pooled cells for a selection of blocks (``kp``;
+    ``cfg`` says their sizes) adds the rows to their cells, those
+    behind ``valid`` (a count, None = all) left out."""
     upd = partial(jax.lax.dynamic_update_slice_in_dim, start_index=off,
                   axis=1)
     if latent is not None:
         return {kk: upd(cache_l[kk], update=u) for kk, u in _latent_leaves(
             k, latent, _is_quantized(cache_l)).items()}
+    pooled = {}
+    if "kp" in cache_l:
+        with jax.named_scope("sparse_pool"):
+            pooled["kp"] = _pool_write(cache_l["kp"], k, off, cfg, valid)
     if not _is_quantized(cache_l):
         return {"k": upd(cache_l["k"], update=k),
-                "v": upd(cache_l["v"], update=v)}
+                "v": upd(cache_l["v"], update=v), **pooled}
     kq, ks = _kv_quantize(k)
     vq, vs = _kv_quantize(v)
     return {
@@ -331,7 +343,60 @@ def _cache_write(cache_l: dict, k, v, off, latent=None) -> dict:
         "v": upd(cache_l["v"], update=vq),
         "k_s": upd(cache_l["k_s"], update=ks),
         "v_s": upd(cache_l["v_s"], update=vs),
+        **pooled,
     }
+
+
+# A layer that attends a selection of its key blocks
+# (``TransformerConfig(sparse_block=...)``) keeps one more leaf beside
+# its rows: ``kp`` (B, cells, Hkv, D) float32, the mean of every
+# ``sparse_stride`` keys as they were before the cache quantized them
+# (``transformer.pool_cells``; a cell that is not full holds what it
+# has so far, and no window that reaches into it counts yet). A
+# positional cache of L rows has the cells of its whole blocks and one
+# to spare, which a write that does not start on a cell's first row
+# spills into.
+
+
+def _pool_cells_for(L: int, cfg) -> int:
+    return -(-L // cfg.sparse_block) * cfg.sparse_cells + 1
+
+
+def _pool_write(kp, k, off, cfg, valid=None):
+    """Add the rows k (B, T, Hkv, D) at positions ``[off, off + T)`` to
+    their cells. The rows are laid into a buffer of whole cells at
+    ``off mod stride``, so the cells' sums are one reshape whatever
+    ``off`` is."""
+    st = cfg.sparse_stride
+    B, T = k.shape[:2]
+    kf = k.astype(jnp.float32)
+    if valid is not None:
+        kf = jnp.where((jnp.arange(T) < valid)[None, :, None, None], kf, 0.0)
+    n = -(-T // st) + 1
+    buf = jnp.zeros((B, n * st) + k.shape[2:], jnp.float32)
+    buf = jax.lax.dynamic_update_slice_in_dim(buf, kf, off % st, axis=1)
+    c0 = off // st
+    old = jax.lax.dynamic_slice_in_dim(kp, c0, n, axis=1)
+    return jax.lax.dynamic_update_slice_in_dim(
+        kp, old + pool_cells(buf, cfg), c0, axis=1)
+
+
+def _select_blocks(q, cache_l, qpos, cfg):
+    """Which key blocks each of the chunk's queries attends
+    (``transformer.sparse_pick`` a request): q (B, T, H, D) at
+    positions ``qpos`` (T,) over a positional cache -> (B, T, Hkv,
+    blocks) bool."""
+    nb = -(-cache_l["k"].shape[1] // cfg.sparse_block)
+    with jax.named_scope("sparse_select"):
+        return jax.vmap(lambda qb, cb: sparse_pick(
+            qb, cb, qpos + 1, cfg, nb)[0])(q, cache_l["kp"])
+
+
+def _select_rows(stands, kpos, cfg, g: int):
+    """:func:`_select_blocks`'s answer for the key rows ``kpos``, a
+    query head each: (B, H, T, len(kpos)) bool."""
+    rows = jnp.take(stands, kpos // cfg.sparse_block, axis=-1)
+    return jnp.repeat(rows.transpose(0, 2, 1, 3), g, axis=1)
 
 
 def _cache_scores(q, cache_l: dict, scale, latent=None):
@@ -394,13 +459,20 @@ def init_cache(
     cache, dequantized inside the attention einsums (never at full
     size)."""
     H = _cache_heads_global(cfg, mesh)
+    def rows(li):
+        layer = _zero_cache_layer(batch, max_len, H, cfg.head_dim,
+                                  cfg.dtype, quantize_kv)
+        if cfg.sparse(li):
+            layer["kp"] = jnp.zeros(
+                (batch, _pool_cells_for(max_len, cfg), H, cfg.head_dim),
+                jnp.float32)
+        return layer
+
     return [
-        # a gated delta-rule layer keeps its fixed block of state
-        gdn_zero_state(cfg, batch) if cfg.gdn(li)
+        # a recurrent layer keeps its fixed block of state
+        zero_state(cfg, li, batch) if cfg.state(li)
         else _zero_latent_layer(batch, max_len, cfg, quantize_kv)
-        if cfg.mla(li)
-        else _zero_cache_layer(batch, max_len, H, cfg.head_dim, cfg.dtype,
-                               quantize_kv)
+        if cfg.mla(li) else rows(li)
         for li in range(cfg.n_layers)
     ]
 
@@ -437,7 +509,8 @@ def shard_cache(cache, cfg: TransformerConfig, mesh: Mesh):
 CHUNK_BLOCK_K = 512
 
 
-def _chunk_attention(q, cache_l, qpos, scale, window, latent=None):
+def _chunk_attention(q, cache_l, qpos, scale, window, latent=None,
+                     select=None):
     """A chunk's (T > 1) grouped attention, walking the key blocks its
     queries can see: block ``j`` holds the cache rows ``[j*bk,
     (j+1)*bk)`` (``bk = min(CHUNK_BLOCK_K, Lmax)``; absolute positions,
@@ -455,7 +528,10 @@ def _chunk_attention(q, cache_l, qpos, scale, window, latent=None):
     nothing: the work follows the rows the chunk can see, not the
     cache's length. ``latent`` (the latent's width): ``q`` is the
     absorbed query, the rows a latent layer's, the result (B, T, H,
-    latent)."""
+    latent). ``select`` (``(stands, cfg)``: :func:`_select_blocks`'s
+    answer): each row attends the blocks it picked alone; a block of
+    the walk that no row picked anything in is skipped, the rest
+    masked."""
     T = q.shape[1]
     Lmax = cache_l["k"].shape[1]
     bk = min(CHUNK_BLOCK_K, Lmax)
@@ -475,7 +551,7 @@ def _chunk_attention(q, cache_l, qpos, scale, window, latent=None):
         start = jnp.minimum(j * bk, Lmax - bk)
         blk = {
             name: jax.lax.dynamic_slice_in_dim(a, start, bk, axis=1)
-            for name, a in cache_l.items()
+            for name, a in cache_l.items() if name != "kp"
         }
         kpos = start + jnp.arange(bk)
         # the one band predicate (parallel/ring_attention._band_mask):
@@ -485,14 +561,23 @@ def _chunk_attention(q, cache_l, qpos, scale, window, latent=None):
         if Lmax % bk:
             mask = jnp.logical_and(mask, (kpos >= j * bk)[None, :])
         mask = mask[None, None]
-        s = jnp.where(mask, _cache_scores(q, blk, scale, latent), _NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m - m_new)  # (B, H, T)
-        l = l * corr + p.sum(axis=-1)
-        o = o * corr.transpose(0, 2, 1)[..., None] + _cache_pv(
-            p, blk, latent)
-        return o, m_new, l
+        if select is not None:
+            mask = mask & _select_rows(select[0], kpos, select[1],
+                                       q.shape[2] // blk["k"].shape[2])
+
+        def attend():
+            s = jnp.where(mask, _cache_scores(q, blk, scale, latent), _NEG)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)  # (B, H, T)
+            l_new = l * corr + p.sum(axis=-1)
+            o_new = o * corr.transpose(0, 2, 1)[..., None] + _cache_pv(
+                p, blk, latent)
+            return o_new, m_new, l_new
+
+        if select is None:
+            return attend()
+        return jax.lax.cond(jnp.any(mask), attend, lambda: carry)
 
     o, _, l = jax.lax.fori_loop(lo, hi, block, (o0, zeros + _NEG, zeros))
     # every row sees at least itself while the caller keeps off + T <=
@@ -515,7 +600,7 @@ def _chunk_rows_seen(off: int, T: int, Lmax: int, windows) -> int:
 
 
 def _cached_attention(q, cache_l, qpos, scale, window=None,
-                      use_kernel: bool = False, latent=None):
+                      use_kernel: bool = False, latent=None, sparse=None):
     """Grouped attention of the chunk's queries against the rows of the
     cache they can see.
 
@@ -532,11 +617,18 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     really are the int8 bytes — the einsum form's ``.astype`` is
     materialized by XLA and gives half the bytes back.
     ``use_kernel`` is the program's resolved route (the module note).
+    ``sparse`` (the configuration, for a layer that attends a
+    selection of its key blocks): every query's own blocks
+    (:func:`_select_blocks`) and no other row.
     """
+    select = None
+    if sparse is not None:
+        select = (_select_blocks(q, cache_l, qpos, sparse), sparse)
     if q.shape[1] > 1:
         with jax.named_scope("chunk_attn"):
-            return _chunk_attention(q, cache_l, qpos, scale, window,
-                                    latent)
+            return _chunk_attention(
+                q, cache_l, qpos, scale, window, latent,
+                **({} if select is None else {"select": select}))
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
 
@@ -547,8 +639,11 @@ def _cached_attention(q, cache_l, qpos, scale, window=None,
     s = _cache_scores(q, cache_l, scale, latent)  # (B, H, 1, Lmax) f32
     # the one band predicate (parallel/ring_attention._band_mask): the
     # serving path cannot silently diverge from the training oracle
-    mask = _band_mask(qpos, jnp.arange(Lmax), True, window)
-    s = jnp.where(mask[None, None], s, _NEG)
+    mask = _band_mask(qpos, jnp.arange(Lmax), True, window)[None, None]
+    if select is not None:
+        mask = mask & _select_rows(select[0], jnp.arange(Lmax), sparse,
+                                   q.shape[2] // cache_l["k"].shape[2])
+    s = jnp.where(mask, s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = _cache_pv(p, cache_l, latent)  # (B, 1, H, D) f32
     return o.astype(q.dtype)
@@ -602,12 +697,13 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     delta-rule layer's ``cache_l`` is its state (no rows), carried
     through the chunk; of ``valid`` see ``gdn_half``."""
     h, mix = hc_pre(x, lp, cfg, "hc1")
-    if cfg.gdn(li):
-        x, cache_l = gdn_half(h, lp, cache_l, cfg, valid, mix=mix)
-        x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
-        return x, cache_l
     rope = partial(_rope, pos=qpos, theta=cfg.rope_theta,
                    table=cfg.rope_table)
+    if cfg.state(li):
+        x, cache_l = state_half(h, lp, cache_l, cfg, li, rope, valid,
+                                mix=mix)
+        x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
+        return x, cache_l
     if cfg.mla(li):
         # a latent layer attends its cache whatever the chunk: the row
         # it has just written is all that exists of a position's keys
@@ -626,9 +722,17 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     off = qpos[0]
     if ring:
         off = jnp.mod(off, cache_l["k"].shape[1])
-    cache_l = _cache_write(cache_l, k, v, off)
+    cache_l = _cache_write(cache_l, k, v, off, cfg=cfg, valid=valid)
     scale = cfg.softmax_scale
-    if chunk_attn is not None:
+    if cfg.sparse(li):
+        # a selection of blocks is made from the cache's pooled cells,
+        # whatever the chunk: also a prefill at offset 0
+        if ring or tp_psum:
+            raise ValueError("a layer that attends a selection of its "
+                             "key blocks has neither a ring-cache nor a "
+                             "tp-sharded form")
+        o = _cached_attention(q, cache_l, qpos, scale, sparse=cfg)
+    elif chunk_attn is not None:
         # prefill at offset 0: attention lives entirely inside the chunk,
         # so the configured chunk kernel (flash on TPU) does the work on
         # the exact (unquantized) chunk K/V — only the cache quantizes
@@ -742,19 +846,21 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
     def grouped_layer(x, lp, rows, offsets, valid):
         n, T = x.shape[:2]
         h, mix = hc_pre(x, lp, cfg, "hc1")
-        if cfg.gdn(li):
+        qpos = offsets[:, None] + jnp.arange(T)  # (n, T)
+
+        def rope(t):  # each request's rows at its own positions
+            return jax.vmap(lambda a, pos: _rope(
+                a[None], pos, cfg.rope_theta, cfg.rope_table)[0])(
+                    t, qpos)
+
+        if cfg.state(li):
             state = {kk: jnp.concatenate([r[kk] for r in rows])
                      for kk in rows[0]}
-            x, state = gdn_half(h, lp, state, cfg, valid, mix=mix)
+            x, state = state_half(h, lp, state, cfg, li, rope, valid,
+                                  mix=mix)
             rows = [{kk: a[i:i + 1] for kk, a in state.items()}
                     for i in range(n)]
         else:
-            qpos = offsets[:, None] + jnp.arange(T)  # (n, T)
-
-            def rope(t):  # each request's rows at its own positions
-                return jax.vmap(lambda a, pos: _rope(
-                    a[None], pos, cfg.rope_theta, cfg.rope_table)[0])(
-                        t, qpos)
 
             def attend(q, rows, **kw):  # each request's queries, its store
                 return jnp.concatenate([
@@ -772,9 +878,12 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
                     functools.partial(attend, latent=R))
             else:
                 q, k, v, gate = attn_qkv(h, lp, cfg, li, rope)
-                rows = [_cache_write(r, k[i:i + 1], v[i:i + 1], offsets[i])
-                        for i, r in enumerate(rows)]
-                o = attend(q, rows, window=cfg.windows[li])
+                rows = [_cache_write(
+                    r, k[i:i + 1], v[i:i + 1], offsets[i], cfg=cfg,
+                    valid=None if valid is None else valid[i])
+                    for i, r in enumerate(rows)]
+                o = attend(q, rows, window=cfg.windows[li],
+                           sparse=cfg if cfg.sparse(li) else None)
                 x = attn_merge(h, o, gate, lp, cfg, mix=mix)
         x, _, _ = ffn_half(x, lp, cfg, li)
         return x, rows
@@ -941,9 +1050,16 @@ def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
     if cfg.state_layers:
         raise ValueError(
             "the ring cache is rows of K/V, a position each; this "
-            "configuration has gated delta-rule layers, whose state is "
-            "one fixed block a request and has no width. "
-            "ServingScheduler serves it"
+            "configuration has gated delta-rule layers (or decayed "
+            "linear attention), whose state is one fixed block a "
+            "request and has no width. ServingScheduler serves it"
+        )
+    if cfg.sparse_layers:
+        raise ValueError(
+            "the ring cache is rows of K/V alone; this configuration "
+            "attends a selection of key blocks, made from pooled keys "
+            "that a ring does not keep. ServingScheduler and the "
+            "max_len cache of init_cache serve it"
         )
     if cfg.latent_layers:
         raise ValueError(
@@ -963,7 +1079,7 @@ def _row_widths(cfg: TransformerConfig) -> tuple:
     out = []
     for li in range(cfg.cache_layers):
         w = cfg.windows[cfg._like(li)]
-        if cfg.gdn(li):
+        if cfg.state(li):
             out.append(None)
             continue
         if w is None:
@@ -1011,13 +1127,16 @@ def init_ring_cache(
     ]
 
 
-def _ring_from_cache(cache_l: dict, Tp: int, W: int) -> dict:
+def _ring_from_cache(cache_l: dict, Tp: int, W: int,
+                     stride: int | None = None) -> dict:
     """Gather a positional cache holding positions [0, Tp) into the ring
     layout: slot ``s`` <- the latest prompt position congruent to ``s``
     (mod W); slots no position has reached (Tp < W) stay zero — the
     ``kpos >= 0`` read mask of :func:`_ring_cached_attention` already
     treats them as unwritten. Every cache leaf (int8 scales included)
-    shares the position axis, so one gather covers the layout."""
+    shares the position axis, so one gather covers the layout; a
+    layer's pooled cells (``kp``, one every ``stride`` rows) are taken
+    as they lie."""
     s = jnp.arange(W)
     p = (Tp - 1) - jnp.mod((Tp - 1) - s, W)
     valid = p >= 0
@@ -1026,7 +1145,18 @@ def _ring_from_cache(cache_l: dict, Tp: int, W: int) -> dict:
         g = jnp.take(a, jnp.maximum(p, 0), axis=1)
         return jnp.where(valid.reshape((1, W) + (1,) * (a.ndim - 2)), g, 0)
 
-    return {kk: gather(a) for kk, a in cache_l.items()}
+    def cells(a):
+        # pooled cells lie on positions like the rows: a ring as wide
+        # as the context budget never wraps, so cell c stays cell c,
+        # and W rows have W / stride of them (those behind the prompt
+        # zero)
+        c = jnp.arange(W // stride)
+        g = jnp.take(a, jnp.minimum(c, a.shape[1] - 1), axis=1)
+        live = c < -(-Tp // stride)
+        return jnp.where(live.reshape((1, -1) + (1,) * (a.ndim - 2)), g, 0)
+
+    return {kk: cells(a) if kk == "kp" else gather(a)
+            for kk, a in cache_l.items()}
 
 
 def ring_from_cache(cache, Tp: int, cfg: TransformerConfig) -> list[dict]:

@@ -166,6 +166,8 @@ from .decode import (
     _kv_quantize,
     _paged_kernel_possible,
     _pick_token,
+    _pool_cells_for,
+    _select_rows,
     _ring_from_cache,
     _route_kernel,
     _row_widths,
@@ -187,16 +189,19 @@ from .transformer import (
     attn_qkv,
     embed,
     ffn_half,
-    gdn_half,
     gdn_rule_route,
-    gdn_zero_state,
     hc_fold,
     hc_pre,
     head_logits,
     make_kv_slice,
     mtp_input,
     mtp_logits,
+    la_rule_route,
     param_specs,
+    sparse_counts,
+    sparse_pick,
+    state_half,
+    zero_state,
 )
 
 __all__ = [
@@ -222,8 +227,8 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(li, length):
-        if cfg.gdn(li):  # no rows: the layer's fixed block of state
-            return gdn_zero_state(cfg, B)
+        if cfg.state(li):  # no rows: the layer's fixed block of state
+            return zero_state(cfg, li, B)
         if cfg.mla(li):  # one row a position: [latent | rotated key]
             return _zero_latent_layer(B, length, cfg, quantize_kv)
         shape = (B, length, cfg.kv_heads, cfg.head_dim)
@@ -231,6 +236,9 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L,
         if quantize_kv:
             out["k_s"] = jnp.zeros(shape[:3], jnp.float32)
             out["v_s"] = jnp.zeros(shape[:3], jnp.float32)
+        if cfg.sparse(li):  # the selector's pooled cells (decode.py)
+            out["kp"] = jnp.zeros(
+                (B, _pool_cells_for(length, cfg)) + shape[2:], jnp.float32)
         return out
 
     @jax.jit
@@ -292,14 +300,16 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     of each of the ``slots`` (its page count is not read). A latent
     layer's pages hold its one row a position, ``k`` ``(n_pages, P,
     latent + rope)``, and the two scales of a row as two "heads" of
-    ``k_s``."""
+    ``k_s``. A layer that attends a selection of its key blocks keeps
+    a page's pooled cells beside its rows: ``kp`` ``(n_pages, P /
+    sparse_stride, kv_heads * head_dim)`` float32."""
     counts = ((n_pages,) * cfg.cache_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(li, n):
-        if cfg.gdn(li):
-            return gdn_zero_state(cfg, slots)
+        if cfg.state(li):
+            return zero_state(cfg, li, slots)
         if cfg.mla(li):
             out = {"k": jnp.zeros((n, P, cfg.latent_width), kvdt)}
             if quantize_kv:
@@ -312,16 +322,25 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
             sshape = (n, cfg.kv_heads, paged_scale_lanes(P))
             out["k_s"] = jnp.zeros(sshape, jnp.float32)
             out["v_s"] = jnp.zeros(sshape, jnp.float32)
+        if cfg.sparse(li):
+            out["kp"] = jnp.zeros((n, P // cfg.sparse_stride, shape[2]),
+                                  jnp.float32)
         return out
 
     return [layer(li, n) for li, n in enumerate(counts)]
 
 
-def _rows_to_pages(kk: str, x, P: int):
+def _rows_to_pages(kk: str, x, P: int, stride: int | None = None):
     """Cache rows to pool-layout page blocks (:func:`_fresh_pages`):
     K/V ``(..., n * P, Hkv, D) -> (..., n, P, Hkv * D)``, a scale leaf
     (``kk`` ends in ``_s``) ``(..., n * P, Hkv) -> (..., n, Hkv,
-    lanes)``, zeros in the lanes past P."""
+    lanes)``, zeros in the lanes past P; a layer's pooled cells
+    (``kp``) ``(..., n * c, Hkv, D) -> (..., n, c, Hkv * D)``, c = P /
+    ``stride`` cells a page."""
+    if kk == "kp":
+        lead, (L, H, D) = x.shape[:-3], x.shape[-3:]
+        c = P // stride
+        return x.reshape(lead + (L // c, c, H * D))
     if kk.endswith("_s"):
         lead, (L, H) = x.shape[:-2], x.shape[-2:]
         x = jnp.swapaxes(x.reshape(lead + (L // P, P, H)), -1, -2)
@@ -336,6 +355,9 @@ def _pages_to_rows(kk: str, blk, Hkv: int, P: int):
     (..., n * P, Hkv, D)`` and ``(..., n, H, lanes) -> (..., n * P,
     H)`` (a scale leaf says itself how many scales a position has)."""
     lead, n = blk.shape[:-3], blk.shape[-3]
+    if kk == "kp":  # a page's pooled cells, however many it has
+        return blk.reshape(lead + (n * blk.shape[-2], Hkv,
+                                   blk.shape[-1] // Hkv))
     if kk.endswith("_s"):
         blk = jnp.swapaxes(blk[..., :P], -1, -2)
         return blk.reshape(lead + (n * P, blk.shape[-1]))
@@ -409,8 +431,18 @@ def _ring_write_rows(cache_l: dict, k, v, slot, latent=None):
     if latent is not None:
         return {kk: put(cache_l[kk], u) for kk, u in _latent_leaves(
             k, latent, _is_quantized(cache_l)).items()}
+    pooled = {}
+    if "kp" in cache_l:
+        # the row into its pooled cell (a ring as wide as the context
+        # budget: slot s is position s), one row a slot
+        kp = cache_l["kp"]
+        stride = cache_l["k"].shape[1] // kp.shape[1]
+        with jax.named_scope("sparse_pool"):
+            pooled["kp"] = kp.at[rows, slot // stride].add(
+                k[:, 0].astype(jnp.float32) / stride)
     if not _is_quantized(cache_l):
-        return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v)}
+        return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v),
+                **pooled}
     kq, ks = _kv_quantize(k)
     vq, vs = _kv_quantize(v)
     return {
@@ -418,11 +450,12 @@ def _ring_write_rows(cache_l: dict, k, v, slot, latent=None):
         "v": put(cache_l["v"], vq),
         "k_s": put(cache_l["k_s"], ks),
         "v_s": put(cache_l["v_s"], vs),
+        **pooled,
     }
 
 
 def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
-                         latent=None):
+                         latent=None, select=None):
     """Single-query ring attention with a per-row position: the same
     ``kpos(s) = pos - ((pos - s) mod W), valid iff kpos >= 0`` invariant
     as decode.py's ``_ring_cached_attention``, evaluated rowwise. The
@@ -441,7 +474,9 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
     latent's width): q is the absorbed query, the ring a latent
     layer's one row a slot, the result (S, 1, H, latent). ``pos``
     (S, T): the slot's T queries, each at its own position, over the
-    einsum (a drafting step's two rows, :func:`_draft_step`)."""
+    einsum (a drafting step's two rows, :func:`_draft_step`).
+    ``select`` (``(stands (S, Hkv, blocks), cfg)``): each slot attends
+    the blocks that stand for it alone (:func:`_slot_blocks`)."""
     W = cache_l["k"].shape[1]
     if use_kernel and _kernel_viable(q, cache_l):
         from ..ops.decode_attention import quantized_decode_attention
@@ -454,11 +489,25 @@ def _ring_attention_rows(q, cache_l, pos, scale, use_kernel=False,
         pos[..., None] - jnp.arange(W)[None, :], W
     )  # (S, W), or (S, T, W)
     seen = kpos >= 0
-    s = jnp.where(seen[:, None, None, :] if pos.ndim == 1
-                  else seen[:, None], s, _NEG)
+    seen = seen[:, None, None, :] if pos.ndim == 1 else seen[:, None]
+    if select is not None:
+        seen = seen & _select_rows(
+            select[0][:, None], jnp.arange(W), select[1],
+            q.shape[2] // cache_l["k"].shape[2])
+    s = jnp.where(seen, s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = _cache_pv(p, cache_l, latent)
     return o.astype(q.dtype)
+
+
+def _slot_blocks(q, cells, pos, cfg, n_blocks: int):
+    """Which key blocks each slot's one query attends
+    (``transformer.sparse_pick`` a slot): q (S, 1, H, D), ``cells`` (S,
+    cells, Hkv, D) each slot's pooled cells in position order, ``pos``
+    (S,) -> (S, Hkv, n_blocks) bool."""
+    with jax.named_scope("sparse_select"):
+        return jax.vmap(lambda qs, cs, p: sparse_pick(
+            qs, cs, p[None] + 1, cfg, n_blocks)[0][0])(q, cells, pos)
 
 
 def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
@@ -487,8 +536,12 @@ def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
         )
         return c.at[page].set(blk)
 
+    pooled = {}
+    if "kp" in cache_l:  # untouched here: :func:`_paged_pool_rows`
+        pooled["kp"] = cache_l["kp"]
     if not _is_quantized(cache_l):
-        return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v)}
+        return {"k": put(cache_l["k"], k), "v": put(cache_l["v"], v),
+                **pooled}
     kq, ks = _kv_quantize(k)
     vq, vs = _kv_quantize(v)
     return {
@@ -496,7 +549,59 @@ def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
         "v": put(cache_l["v"], vq),
         "k_s": put_s(cache_l["k_s"], ks),
         "v_s": put_s(cache_l["v_s"], vs),
+        **pooled,
     }
+
+
+def _paged_pool_rows(cache_l: dict, k, pt, slot, P: int) -> dict:
+    """Each row's key into its pooled cell, through the page table:
+    cell ``(slot % P) // stride`` of pool page ``pt[i, slot // P]``
+    (``kp``, :func:`_fresh_pages`). A page's cells were zeroed or
+    copied in when the request was placed."""
+    kp = cache_l["kp"]
+    stride = P // kp.shape[1]
+    page = pt[jnp.arange(k.shape[0]), slot // P]
+    with jax.named_scope("sparse_pool"):
+        kp = kp.at[page, (slot % P) // stride].add(
+            k[:, 0].reshape(k.shape[0], -1).astype(jnp.float32) / stride)
+    return {**cache_l, "kp": kp}
+
+
+def sparse_table_width(cfg: TransformerConfig) -> int:
+    """The most blocks a query attends: every block of
+    ``sparse_dense_len`` rows, or past it the first blocks, the top-k
+    and the blocks that hold the window (one more where it straddles)."""
+    blk = cfg.sparse_block
+    return max(-(-cfg.sparse_dense_len // blk),
+               cfg.sparse_init_blocks + cfg.sparse_topk
+               + -(-cfg.sparse_window // blk) + 1)
+
+
+def _paged_select(q, cache_l, pt, pos, cfg, P: int):
+    """The selection as the paged kernel reads it: for every slot and
+    K/V head the pool pages of the blocks that stand, in position order
+    (a block is a page: ``sparse_block == P``), and the position the
+    slot's query has among THOSE rows. Returns ``(pages (S, Hkv, n),
+    at (S, Hkv))``: the kernel walks ``pages[s, h]`` as it walks a
+    page table, rows ``<= at[s, h]`` live. n is the most blocks a
+    query attends (:func:`sparse_table_width`). The block the query's
+    own row lies in always stands and is the last, so every entry
+    before it is a whole page and the last is live up to the row."""
+    max_pages = pt.shape[1]
+    kp = cache_l["kp"]
+    S, Hkv = q.shape[0], cfg.kv_heads
+    with jax.named_scope("sparse_select"):
+        cells = jnp.take(kp, pt, axis=0)        # (S, pages, c, Hkv * D)
+        cells = cells.reshape(S, max_pages * kp.shape[1], Hkv, -1)
+        stands = _slot_blocks(q, cells, pos, cfg, max_pages)
+        n = min(max_pages, sparse_table_width(cfg))
+        b = jnp.arange(max_pages)
+        order = jnp.sort(jnp.where(stands, b, max_pages + b), axis=-1)
+        count = stands.sum(axis=-1).astype(jnp.int32)       # (S, Hkv)
+        ids = jnp.minimum(order[..., :n], max_pages - 1)
+        pages = jnp.take_along_axis(pt[:, None, :], ids, axis=-1)
+        at = (count - 1) * P + (pos % P)[:, None]
+    return pages, at
 
 
 def _paged_gather(cache_l: dict, pt, Hkv: int, P: int):
@@ -518,7 +623,8 @@ def _paged_gather(cache_l: dict, pt, Hkv: int, P: int):
     }
 
 
-def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int):
+def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int,
+                   stride: int | None = None):
     """Write a tick's updated ring views back through the page table —
     the inverse of :func:`_paged_gather`, one page-block scatter per
     leaf. Duplicate table entries (a prefix page shared by several
@@ -528,7 +634,7 @@ def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int):
     which nothing reads unmasked."""
     return {
         kk: a.at[pt].set(
-            _rows_to_pages(kk, view_l[kk], P).astype(a.dtype))
+            _rows_to_pages(kk, view_l[kk], P, stride).astype(a.dtype))
         for kk, a in cache_l.items()
     }
 
@@ -566,13 +672,13 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     x (S, T, D): T rows a slot, each written before any is attended
     (the slot-ring and gathered-view paths; a drafting step's two)."""
     h, mix = hc_pre(x, lp, cfg, "hc1")
-    if cfg.gdn(li):
-        x, cache_l = gdn_half(h, lp, cache_l, cfg, mix=mix)
+    rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
+                             table=cfg.rope_table)
+    if cfg.state(li):
+        x, cache_l = state_half(h, lp, cache_l, cfg, li, rope, mix=mix)
         with jax.named_scope("decode_mlp"):
             x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
         return x, cache_l, hit
-    rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
-                             table=cfg.rope_table)
     if cfg.mla(li):
         # every slot's ring view of its pages (the tick gathers them
         # once, ``_serving_scan_paged``): the row goes to slot ``pos``
@@ -593,6 +699,14 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     # and the MLP in a device trace; the projections are under
     # ``attn_qkv`` / ``attn_out``, which models/transformer.py's block
     # opens itself, and the feed-forward's ``ffn`` nests in ``decode_mlp``
+    # a layer that attends a selection of its key blocks: the row into
+    # its pooled cell first (``sparse_pool``), then the pick
+    # (``sparse_select``), both beside ``decode_attn`` and not in it
+    select = None
+    if cfg.sparse(li) and paged is not None:
+        pt, W, P = paged
+        cache_l = _paged_pool_rows(cache_l, k, pt, jnp.mod(pos, W), P)
+        select = _paged_select(q, cache_l, pt, pos, cfg, P)
     with jax.named_scope("decode_attn"):
         if paged is not None:
             # kernel route only: the einsum paged tick runs THIS
@@ -601,12 +715,22 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
             pt, W, P = paged
             cache_l = _paged_write_rows(cache_l, k, v, pt,
                                         jnp.mod(pos, W), P)
-            o = _paged_attention_rows(q, cache_l, pt, pos, scale, P)
+            if select is None:
+                o = _paged_attention_rows(q, cache_l, pt, pos, scale, P)
+            else:
+                from ..ops.decode_attention import paged_select_attention
+
+                o = paged_select_attention(
+                    q, {kk: a for kk, a in cache_l.items() if kk != "kp"},
+                    select[1], select[0], scale=scale, P=P)
         else:
             W = cache_l["k"].shape[1]
             cache_l = _ring_write_rows(cache_l, k, v, jnp.mod(pos, W))
+            if cfg.sparse(li):
+                select = (_slot_blocks(q, cache_l["kp"], pos, cfg,
+                                       W // cfg.sparse_block), cfg)
             o = _ring_attention_rows(q, cache_l, pos, scale,
-                                     use_kernel=use_kernel)
+                                     use_kernel=use_kernel, select=select)
     x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix)
     with jax.named_scope("decode_mlp"):
         x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
@@ -626,7 +750,7 @@ def _serving_hidden(params, tok, pos, caches, cfg, *, kv_slice=None,
     hits = None
     for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
         paged_l = None
-        if paged is not None and not cfg.gdn(li):
+        if paged is not None and not cfg.state(li):
             pt = paged[0][li]
             paged_l = (pt, pt.shape[1] * paged[1], paged[1])
         x, cl, hit = _serving_layer(
@@ -878,7 +1002,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         with jax.named_scope("kv_page_gather"):
             # (a recurrent layer's state is no page: it goes through)
             views = [
-                cl if cfg.gdn(li)
+                cl if cfg.state(li)
                 else _paged_gather(cl, t, cfg.cache_heads(li), P)
                 for li, (cl, t) in enumerate(zip(caches, pts))
             ]
@@ -888,7 +1012,8 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         )
         with jax.named_scope("kv_page_scatter"):
             caches = [
-                vw if cfg.gdn(li) else _paged_scatter(cl, vw, t, P)
+                vw if cfg.state(li)
+                else _paged_scatter(cl, vw, t, P, cfg.sparse_stride)
                 for li, (cl, vw, t) in enumerate(zip(caches, views, pts))
             ]
         return tok, pos, done, caches, toks
@@ -977,9 +1102,10 @@ def _place_paged(cfg: TransformerConfig, P: int):
         caches = [
             # a recurrent layer's block of state goes over slot s's
             {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
-             for kk in c} if cfg.gdn(li) else
+             for kk in c} if cfg.state(li) else
             {kk: c[kk].at[row].set(
-                _rows_to_pages(kk, r[kk][0], P).astype(c[kk].dtype))
+                _rows_to_pages(kk, r[kk][0], P,
+                               cfg.sparse_stride).astype(c[kk].dtype))
              for kk in c}
             for li, (c, r, row) in enumerate(zip(caches, ring, rows))
         ]
@@ -1033,9 +1159,22 @@ def _refuse_state_layers(cfg: TransformerConfig, what: str,
     mechanism, a configuration with recurrent layers."""
     if cfg.state_layers:
         raise ValueError(
-            f"{what}: this configuration has gated delta-rule layers, "
-            f"whose per-request state is one fixed block and no row a "
-            f"token; {why}"
+            f"{what}: this configuration has gated delta-rule layers "
+            f"(or decayed linear attention), whose per-request state is "
+            f"one fixed block and no row a token; {why}"
+        )
+
+
+def _refuse_sparse_layers(cfg: TransformerConfig, what: str,
+                          why: str) -> None:
+    """``what`` is written for layers that attend every row they keep;
+    refuse, by mechanism, a configuration that attends a selection of
+    its key blocks."""
+    if cfg.sparse_layers:
+        raise ValueError(
+            f"{what}: this configuration's attention layers read a "
+            f"selection of their key blocks, made from pooled keys kept "
+            f"beside the rows; {why}"
         )
 
 
@@ -1087,6 +1226,10 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
         cfg, "make_serving_scan (the sharded tick)",
         "its weights follow param_specs, which has no leaf of the "
         "mixing. One chip serves it through ServingScheduler")
+    _refuse_sparse_layers(
+        cfg, "make_serving_scan (the sharded tick)",
+        "its ring cache keeps rows alone. One chip serves it through "
+        "ServingScheduler")
     _check_ring_cfg(cfg)
     _check_sampling_params(temperature, top_k)
     _refuse_switch_experts(cfg)
@@ -1253,7 +1396,8 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     def serving_first_token(params, cache, last_hidden, true_len,
                             last_off, key):
         def window(cache):
-            return [cl if W is None else _ring_from_cache(cl, true_len, W)
+            return [cl if W is None else _ring_from_cache(
+                cl, true_len, W, cfg.sparse_stride or None)
                     for cl, W in zip(cache, widths)]
 
         ring = None if cfg.mtp_depth else window(cache)
@@ -1841,7 +1985,23 @@ class ServingScheduler:
         # Nor with a drafter: its module's row at a page's last
         # position is made from the token BEHIND the page, which no
         # prefix digest covers
-        self.shares_prefixes = not cfg.state_layers and draft is None
+        # Nor under a selection of key blocks: the seed of a prefill
+        # arena from shared pages copies rows, and is not written for
+        # the pooled cells a page keeps beside them
+        self.shares_prefixes = (not cfg.state_layers
+                                and not cfg.sparse_layers
+                                and draft is None)
+        if qos is not None or cache is not None:
+            _refuse_sparse_layers(
+                cfg, "page quotas (qos=) and the fleet prefix cache "
+                "(cache=)", "both count and move prefix pages, which "
+                "this configuration does not share")
+        if cfg.sparse_layers and page_tokens is not None and (
+                int(page_tokens) != cfg.sparse_block):
+            raise ValueError(
+                f"a selection of key blocks is a selection of pages: "
+                f"page_tokens {page_tokens} must be sparse_block "
+                f"{cfg.sparse_block}")
         if slots < 1 or n_inner < 1:
             raise ValueError("slots and n_inner must be >= 1")
         if prompt_chunk > max_prompt:
@@ -2068,11 +2228,17 @@ class ServingScheduler:
                 w.dtype.itemsize))}
             for n in (1, self._group)
         }
-        # and its ``gdn_rule``: the form the delta rule takes over a
-        # chunk's rows (``transformer.gdn_rule_route``, which
-        # ``gdn_half`` asks too); nothing without state layers
-        self._gdn_rule = ({"gdn_rule": gdn_rule_route(cfg, self.C)}
-                          if cfg.state_layers else {})
+        # and its ``gdn_rule`` / ``la_rule``: the form each kind of
+        # recurrence takes over a chunk's rows
+        # (``transformer.gdn_rule_route`` / ``la_rule_route``, which
+        # the halves ask too); nothing without such a layer
+        mixers = cfg.layer_mixers or ()
+        self._rule_routes = {
+            **({"gdn_rule": gdn_rule_route(cfg, self.C)}
+               if "gdn" in mixers else {}),
+            **({"la_rule": la_rule_route(cfg, self.C)}
+               if "la" in mixers else {}),
+        }
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -2386,6 +2552,11 @@ class ServingScheduler:
                 self._host_pos[s] for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._admitting)}
                if self.cfg.latent_layers and self.paged else {}),
+            # a selection of key blocks: the decoding slots that see
+            # more than ``sparse_dense_len`` rows as the tick begins,
+            # and the blocks they attend and see, summed over those
+            # slots and the K/V heads
+            **self._sparse_tick_counts(),
         ) as tick:
             with phase("serving.admit") as admit:
                 self._advance_admissions(retired)
@@ -2470,6 +2641,35 @@ class ServingScheduler:
                     t=tick.t1,
                 )
         return retired
+
+    def _sparse_tick_counts(self) -> dict:
+        """``serving.tick``'s ``sparse_slots`` / ``blocks_attended`` /
+        ``blocks_visible`` (nothing without a selection of blocks)."""
+        cfg = self.cfg
+        if not cfg.sparse_layers:
+            return {}
+        n = np.array([
+            r.prompt.size + len(r.tokens)
+            for s, r in enumerate(self._slot_req)
+            if r is not None and s not in self._admitting], np.int64)
+        return {"sparse_slots": int((n > cfg.sparse_dense_len).sum()),
+                **self._block_counts(n)}
+
+    def _block_counts(self, n) -> dict:
+        """``blocks_attended`` / ``blocks_visible`` of the queries that
+        see ``n`` rows (an array), summed over them and the K/V heads."""
+        attended, visible = sparse_counts(n, self.cfg)
+        return {"blocks_attended": attended * self.cfg.kv_heads,
+                "blocks_visible": visible * self.cfg.kv_heads}
+
+    def _sparse_chunk_counts(self, sts, offs) -> dict:
+        """``serving.prefill_chunk``'s ``blocks_attended`` /
+        ``blocks_visible``: the same over the chunks' real rows."""
+        if not self.cfg.sparse_layers:
+            return {}
+        return self._block_counts(np.concatenate([
+            np.arange(off, min(off + self.C, st.req.prompt.size)) + 1
+            for st, off in zip(sts, offs)]))
 
     @staticmethod
     def _deliver_drafted(req: Request, steps: np.ndarray) -> None:
@@ -2583,6 +2783,9 @@ class ServingScheduler:
         _refuse_latent_layers(
             self.cfg, "KV-page migration", "a migrated image is K/V ring views of "
             "kv_heads heads, which such a layer has not")
+        _refuse_sparse_layers(
+            self.cfg, "KV-page migration", "a migrated image is K/V "
+            "rows, and the pooled cells are in none")
         if len(self._kinds) > 1:
             raise ValueError(
                 "KV-page migration moves one ring view per layer "
@@ -2734,6 +2937,9 @@ class ServingScheduler:
         _refuse_latent_layers(
             self.cfg, "adopt_page_state", "a migrated image is K/V ring views of "
             "kv_heads heads, which such a layer has not")
+        _refuse_sparse_layers(
+            self.cfg, "adopt_page_state", "a migrated image is K/V "
+            "rows, and the pooled cells are in none")
         if len(self._kinds) > 1:
             raise ValueError(
                 "adopt_page_state: a migrated image is one ring view "
@@ -3455,7 +3661,7 @@ class ServingScheduler:
         at least two)."""
         n = self._group
         valid = ((np.zeros((n,), np.int32),)
-                 if self.cfg.state_layers else ())
+                 if self.cfg.counts_rows else ())
         nxt = ({"nxt": np.zeros((n, self.C), np.int32)}
                if self.draft is not None else {})
         _, arenas = self._extend_group(
@@ -3531,7 +3737,8 @@ class ServingScheduler:
             rows_seen=sum(_chunk_rows_seen(off, C, self.Lmax,
                                            self.cfg.windows)
                           for off in offs),
-            **self._expert_tile.get(size, {}), **self._gdn_rule,
+            **self._expert_tile.get(size, {}), **self._rule_routes,
+            **self._sparse_chunk_counts(sts, offs),
         ):
             # host arrays and numpy scalars go to the device with the
             # program's own dispatch; an eager slice or ``jnp.int32``
@@ -3541,9 +3748,10 @@ class ServingScheduler:
             for i, st in enumerate(sts):
                 chunks[i] = st.padded[0, st.next_chunk * C:
                                       (st.next_chunk + 1) * C]
-            # recurrent layers are told where the prompt ends in the chunk
+            # recurrent layers (and a selector's pooled cells) are told
+            # where the prompt ends in the chunk
             valid = ()
-            if self.cfg.state_layers:
+            if self.cfg.counts_rows:
                 valid = (np.zeros((size,), np.int32),)
                 valid[0][:n] = [min(C, st.req.prompt.size - off)
                                 for st, off in zip(sts, offs)]

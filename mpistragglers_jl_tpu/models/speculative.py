@@ -212,6 +212,13 @@ def _check_draft_layers(cfg: TransformerConfig, draft_layers):
             "rows; this configuration has gated delta-rule layers, "
             "whose state cannot be rolled back past a rejected draft"
         )
+    if cfg.sparse_layers:
+        raise ValueError(
+            "speculative decoding verifies drafts by overwriting cache "
+            "rows; this configuration keeps sums of keys beside its "
+            "rows (the pooled cells of a selection of key blocks), "
+            "from which a rejected draft's key cannot be taken back"
+        )
     if cfg.latent_layers or cfg.hc_mult > 1:
         raise ValueError(
             "speculative decoding's draft and verify programs are "

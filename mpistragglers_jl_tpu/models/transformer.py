@@ -41,6 +41,7 @@ compute on real chips.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import lru_cache, partial, wraps
 from typing import Any
 
@@ -225,6 +226,33 @@ class TransformerConfig:
     # ``ServingScheduler(draft="mtp")`` drafts with it; no forward of
     # the model itself reads it.
     mtp_depth: int = 0
+    # decayed linear attention (``layer_mixers`` value "la"; Lightning
+    # Attention, arXiv:2401.04658): ``la_heads`` heads of
+    # ``la_head_dim``, each keeping ``S_t = lam S_(t-1) + k_t^T v_t``
+    # (a fixed block a request, no row a token; :func:`la_half`) with
+    # ``lam`` a constant a head that the layer holds as data
+    # (``la_slope``: ``lam = exp(-slope)``)
+    la_heads: int = 0
+    la_head_dim: int = 128
+    # a selection of key blocks in the full-attention layers (InfLLM
+    # v2, arXiv:2506.07900; ``sparse_block`` 0 = every row): a query
+    # that sees more than ``sparse_dense_len`` rows attends the first
+    # ``sparse_init_blocks`` blocks of ``sparse_block`` rows, the
+    # blocks that hold its last ``sparse_window`` rows and the
+    # ``sparse_topk`` best of the others, scored by the query against
+    # means of ``sparse_kernel`` keys every ``sparse_stride`` rows
+    # (:func:`sparse_pick`)
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
+    sparse_dense_len: int = 0
+    # a constant on each half's result before it joins the residual,
+    # and one on the final norm's output before the head
+    residual_scale: float = 1.0
+    head_scale: float = 1.0
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -279,11 +307,20 @@ class TransformerConfig:
                 f"head_dim {self.head_dim}"
             )
         if self.layer_mixers is not None:
-            if any(m not in ("attn", "gdn", "mla")
+            if any(m not in ("attn", "gdn", "mla", "la")
                    for m in self.layer_mixers):
                 raise ValueError(
-                    f"layer_mixers holds 'attn' or 'gdn' or 'mla', got "
-                    f"{self.layer_mixers}"
+                    f"layer_mixers holds 'attn' or 'gdn' or 'mla' or "
+                    f"'la', got {self.layer_mixers}"
+                )
+            if "la" in self.layer_mixers and (
+                self.la_heads < 1 or self.la_head_dim < 2
+                or self.la_head_dim % 2
+            ):
+                raise ValueError(
+                    "a linear-attention layer needs la_heads >= 1 heads "
+                    "of an even la_head_dim, got "
+                    f"{self.la_heads} and {self.la_head_dim}"
                 )
             if "mla" in self.layer_mixers:
                 sizes = (self.mla_q_rank, self.mla_kv_rank,
@@ -315,6 +352,34 @@ class TransformerConfig:
                     "dividing gdn_value_heads, got "
                     f"{self.gdn_key_heads} and {self.gdn_value_heads}"
                 )
+        if self.sparse_block:
+            b, st, ks = (self.sparse_block, self.sparse_stride,
+                         self.sparse_kernel)
+            if (st < 1 or b % st or ks % st or ks < st
+                    or self.sparse_topk < 1 or self.sparse_init_blocks < 0
+                    or self.sparse_window < 1
+                    or self.sparse_dense_len < ks):
+                raise ValueError(
+                    "a selection of key blocks needs sparse_stride "
+                    "dividing sparse_block and sparse_kernel, sparse_topk "
+                    ">= 1, sparse_window >= 1 and sparse_dense_len >= "
+                    f"sparse_kernel, got block {b}, stride {st}, kernel "
+                    f"{ks}, topk {self.sparse_topk}, window "
+                    f"{self.sparse_window}, dense_len "
+                    f"{self.sparse_dense_len}"
+                )
+            if any(self.windows[li] is not None
+                   for li in range(self.n_layers) if self.sparse(li)):
+                raise ValueError(
+                    "a selection of key blocks is made among every "
+                    "earlier row: its attention layers take no window"
+                )
+            if self.latent_layers or self.mtp_depth or self.hc_mult > 1:
+                raise ValueError(
+                    "a selection of key blocks is written for K/V rows "
+                    "under one residual stream, with no latent layer "
+                    "and no multi-token-prediction module"
+                )
         if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1:
             raise ValueError(
                 f"hc_mult {self.hc_mult} and hc_sinkhorn_iters "
@@ -344,7 +409,7 @@ class TransformerConfig:
                 "stream's last block output and keeps rows a position; "
                 "this configuration has "
                 + ("several residual streams" if self.hc_mult > 1
-                   else "gated delta-rule layers")
+                   else "layers that keep recurrent state")
             )
         if self.experts_held is not None:
             lo, hi = self.experts_held
@@ -403,6 +468,32 @@ class TransformerConfig:
         """Is layer ``li``'s token mixer the gated delta rule?"""
         return self.mixer(li) == "gdn"
 
+    def state(self, li: int) -> bool:
+        """Does layer ``li`` keep recurrent state, one fixed block a
+        request and no row a token (the gated delta rule, decayed
+        linear attention)?"""
+        return self.mixer(li) in ("gdn", "la")
+
+    def sparse(self, li: int) -> bool:
+        """Does layer ``li`` attend a selection of its key blocks?"""
+        return bool(self.sparse_block) and self.mixer(li) == "attn"
+
+    @property
+    def counts_rows(self) -> bool:
+        """Must a padded chunk be told how many of its rows are real?
+        A recurrence, or a sum of keys, would swallow the padding that
+        attention never reads."""
+        return self.state_layers or self.sparse_layers
+
+    @property
+    def sparse_layers(self) -> bool:
+        return any(self.sparse(li) for li in range(self.n_layers))
+
+    @property
+    def sparse_cells(self) -> int:
+        """Pooled cells (means of ``sparse_stride`` keys) a block has."""
+        return self.sparse_block // self.sparse_stride
+
     def mla(self, li: int) -> bool:
         """Is layer ``li``'s token mixer latent attention?"""
         return self.mixer(li) == "mla"
@@ -436,7 +527,7 @@ class TransformerConfig:
     @property
     def state_layers(self) -> bool:
         """Does any layer keep recurrent state (no row a token)?"""
-        return any(self.gdn(li) for li in range(self.n_layers))
+        return any(self.state(li) for li in range(self.n_layers))
 
     @property
     def latent_layers(self) -> bool:
@@ -466,7 +557,8 @@ class TransformerConfig:
             and self.rope_dims is None and not self.state_layers
             and not self.latent_layers and self.hc_mult == 1
             and self.rope_table is None and self.attn_scale is None
-            and not self.mtp_depth
+            and not self.mtp_depth and not self.sparse_block
+            and self.residual_scale == 1.0 and self.head_scale == 1.0
         )
 
     def expert_width(self) -> int:
@@ -502,6 +594,9 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     def one_layer(li):
         if cfg.gdn(li):
             layer = {**norm("ln1"), **init_gdn_layer(rng, cfg),
+                     **norm("ln2")}
+        elif cfg.mixer(li) == "la":
+            layer = {**norm("ln1"), **init_la_layer(rng, cfg, li),
                      **norm("ln2")}
         elif cfg.mla(li):
             layer = {**norm("ln1"), **init_mla_layer(rng, cfg),
@@ -585,8 +680,13 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
     if cfg.plain_block:
         return
     why = []
-    if cfg.state_layers:
+    if any(map(cfg.gdn, range(cfg.n_layers))):
         why.append("gated delta-rule layers (recurrent state)")
+    if "la" in (cfg.layer_mixers or ()):
+        why.append("decayed linear-attention layers (recurrent state)")
+    if cfg.sparse_block:
+        why.append("attention layers that read a selection of their "
+                   "key blocks")
     if cfg.latent_layers:
         why.append("latent-attention layers (one row a position, no "
                    "K/V heads to shard)")
@@ -750,6 +850,14 @@ def _rope_leading(rope, t, dims: int | None):
 # where K/V live and how positions reach the rotary (``rope``).
 
 
+def _res(a, cfg):
+    """A half's result times ``cfg.residual_scale``, before it joins
+    the residual."""
+    if cfg.residual_scale == 1.0:
+        return a
+    return a * jnp.asarray(cfg.residual_scale, a.dtype)
+
+
 def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     """First part of layer ``li``'s attention half on (B, L, D): norm,
     projections, the q/k norms, rotary. ``rope(t)`` rotates a
@@ -791,7 +899,7 @@ def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None):
             a = jax.lax.psum(a, "tp")
         if cfg.post_norm:
             a = _norm(a, lp, "ln1p", cfg)
-        return hc_post(x, a, mix)
+        return hc_post(x, _res(a, cfg), mix)
 
 
 # The residual path, written once. With ``hc_mult`` = 1 a half is
@@ -991,7 +1099,7 @@ def mla_merge(x, o, lp, cfg, mix=None, latent=False):
             o = jnp.einsum("blhr,rhv->blhv", o.astype(x.dtype),
                            lp["mla_wukv"][..., cfg.mla_nope_dim:])
         a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
-    return hc_post(x, a, mix)
+    return hc_post(x, _res(a, cfg), mix)
 
 
 # The gated delta-rule half, written once like the attention half: the
@@ -1201,7 +1309,294 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
         o = o * jax.nn.silu(z.astype(jnp.float32)).reshape(B, T, Hv, Dv)
         a = jnp.einsum("blc,cd->bld", o.reshape(B, T, vw).astype(x.dtype),
                        lp["gdn_wout"])
-    return hc_post(x, a, mix), {"S": S, "conv": tail}
+    return hc_post(x, _res(a, cfg), mix), {"S": S, "conv": tail}
+
+
+# Decayed linear attention, written once like the delta rule: the dense
+# forward (the whole sequence from a zero state), a prefill chunk and a
+# decode step all call :func:`la_half` and differ in the state and the
+# rotary they hand it. A layer's state is ``S`` (heads, head dim, head
+# dim) float32 and nothing else: no conv, no gate that follows the
+# data, and so no solve. Over C rows it is all products.
+
+LA_SUBCHUNK = 256  # rows the chunked form takes at once
+
+
+def la_slopes(heads: int, layer: int, layers: int) -> np.ndarray:
+    """Lightning Attention's decay exponents (arXiv:2401.04658;
+    MiniMax-01, arXiv:2501.08313): head h forgets at ``exp(-s_h)`` a
+    token, ``s_h = 2 ** (-8 (h + 1) / heads)`` times the layer's
+    factor ``1 - layer / (layers - 1) + 1e-5`` (``layer`` of
+    ``layers``: the deeper the layer, the longer it remembers)."""
+    base = 2.0 ** (-8.0 * (np.arange(heads) + 1) / heads)
+    return base * (1.0 - layer / max(layers - 1, 1) + 1e-5)
+
+
+def init_la_layer(rng: np.random.Generator, cfg: TransformerConfig,
+                  li: int) -> dict:
+    """Leaves of one linear-attention mixer: ``la_wq`` / ``la_wk`` /
+    ``la_wv`` (D, heads, head dim), the q/k norms' scales, the gate's
+    projection ``la_wz`` (D, heads * head dim), the decay exponents
+    ``la_slope`` (heads, float32: :func:`la_slopes` at this layer's
+    index; data, so that a deployment's own constants can stand in
+    them), the scale of the norm over the joined heads and the
+    out-projection."""
+    D, H, Dh = cfg.d_model, cfg.la_heads, cfg.la_head_dim
+    sd = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[0]), cfg.dtype
+    )
+    return {
+        "la_wq": sd(D, H, Dh), "la_wk": sd(D, H, Dh), "la_wv": sd(D, H, Dh),
+        "la_qn_s": jnp.ones((Dh,), cfg.dtype),
+        "la_kn_s": jnp.ones((Dh,), cfg.dtype),
+        "la_wz": sd(D, H * Dh),
+        "la_slope": jnp.asarray(la_slopes(H, li, cfg.n_layers),
+                                jnp.float32),
+        "la_norm_s": jnp.ones((H * Dh,), cfg.dtype),
+        "la_wo": sd(H * Dh, D) / float(np.sqrt(cfg.n_layers)),
+    }
+
+
+def la_zero_state(cfg: TransformerConfig, B: int) -> dict:
+    """The state of ``B`` requests that have seen no token."""
+    return {"S": jnp.zeros((B, cfg.la_heads, cfg.la_head_dim,
+                            cfg.la_head_dim), jnp.float32)}
+
+
+def zero_state(cfg: TransformerConfig, li: int, B: int) -> dict:
+    """Layer ``li``'s recurrent state for ``B`` requests that have seen
+    no token (``cfg.state(li)``: the delta rule's or linear
+    attention's)."""
+    return (gdn_zero_state(cfg, B) if cfg.gdn(li)
+            else la_zero_state(cfg, B))
+
+
+def _la_chunks(q, k, v, slope, S, valid=None):
+    """``S_t = lam S_(t-1) + k_t^T v_t``, ``o_t = q_t S_t`` over T rows,
+    ``LA_SUBCHUNK`` at a time, all float32: q, k, v (B, T, H, D), slope
+    (H,) with ``lam = exp(-slope)``, S (B, H, D, D). Inside a sub-chunk
+    of c rows ``O = ((Q K^T) * L) V + diag(lam^(1..c)) Q S0`` with
+    ``L[t, i] = lam^(t - i)`` on and below the diagonal, and ``S = lam^c
+    S0 + (K * lam^(c - 1 - i))^T V``: every exponent of ``lam`` is a
+    count of rows, none is negative. ``valid`` (B,) says how many
+    leading rows are real; the rows behind them leave S as the last
+    real row left it."""
+    B, T, H, D = q.shape
+    c = min(LA_SUBCHUNK, T)
+    pad = -T % c
+    if pad:
+        padt = lambda a: jnp.pad(a, [(0, 0), (0, pad), (0, 0), (0, 0)])
+        q, k, v = padt(q), padt(k), padt(v)
+    n = (T + pad) // c
+    if valid is None:
+        valid = jnp.full((B,), T, jnp.int32)
+    rows = lambda a: jnp.moveaxis(
+        a.reshape(B, n, c, H, D), 1, 0).swapaxes(2, 3)  # (n, B, H, c, D)
+    q, k, v = rows(q), rows(k), rows(v)
+    t = jnp.arange(c)
+    slope = slope.astype(jnp.float32)[:, None]          # (H, 1)
+    below = t[:, None] >= t[None, :]
+    L = jnp.exp(jnp.where(below, -slope[..., None]
+                          * (t[:, None] - t[None, :]), -jnp.inf))  # (H,c,c)
+    into = jnp.exp(-slope * (t + 1))[..., None]         # lam^(t+1): (H,c,1)
+
+    def sub(S, xs):
+        q, k, v, at = xs                # (B, H, c, D); rows before: at
+        real = jnp.clip(valid - at, 0, c)               # (B,)
+        live = (t[None, :] < real[:, None])[:, None, :, None]
+        k = jnp.where(live, k, 0.0)
+        # lam^(real - 1 - i) for the real rows, masked before the
+        # exponential (behind them the exponent would be negative)
+        back = (real[:, None, None] - 1 - t[None, None, :])
+        out = jnp.exp(jnp.where(back >= 0, -slope[None] * back,
+                                -jnp.inf))[..., None]   # (B, H, c, 1)
+        qk = jnp.einsum("bhtd,bhsd->bhts", q, k, precision=_HI) * L
+        o = jnp.einsum("bhts,bhsd->bhtd", qk, v, precision=_HI)
+        o = o + into * jnp.einsum("bhtk,bhkv->bhtv", q, S, precision=_HI)
+        S = (S * jnp.exp(-slope * real[:, None, None])[..., None]
+             + jnp.einsum("bhtk,bhtv->bhkv", k * out, v, precision=_HI))
+        return S, o
+
+    S, o = jax.lax.scan(sub, S, (q, k, v, jnp.arange(n) * c))
+    o = jnp.moveaxis(o.swapaxes(2, 3), 0, 1).reshape(B, n * c, H, D)
+    return o[:, :T], S
+
+
+def la_rule_route(cfg: TransformerConfig, T: int) -> str:
+    """The form the recurrence takes over a call of T > 1 rows:
+    ``"xla"`` (:func:`_la_chunks`: products the compiler schedules;
+    there is no kernel). :func:`la_half`'s note, and the serving
+    scheduler's ``serving.prefill_chunk`` argument ``la_rule``."""
+    return "xla"
+
+
+def la_half(x, lp, state, cfg, rope, valid=None, mix=None):
+    """Layer's linear-attention half on (B, T, D) from ``state``
+    (:func:`la_zero_state`'s leaf): norm, projections, the norm over
+    each head of q and k, rotary over the whole head (``rope(t)`` at
+    the caller's positions), the recurrence in float32, the norm over
+    the joined heads, the sigmoid gate, the out-projection, the
+    residual. Returns ``(x, state)``. Of ``valid`` and ``mix`` see
+    :func:`gdn_half`."""
+    B, T, _ = x.shape
+    H, Dh = cfg.la_heads, cfg.la_head_dim
+    h = _norm(x, lp, "ln1", cfg)
+    with jax.named_scope("la_proj"):
+        q = jnp.einsum("bld,dhk->blhk", h, lp["la_wq"])
+        k = jnp.einsum("bld,dhk->blhk", h, lp["la_wk"])
+        v = jnp.einsum("bld,dhk->blhk", h, lp["la_wv"])
+        z = jnp.einsum("bld,dc->blc", h, lp["la_wz"])
+        q = rope(_rms(q, lp["la_qn_s"], cfg.norm_eps))
+        k = rope(_rms(k, lp["la_kn_s"], cfg.norm_eps))
+    with jax.named_scope("la_rule"):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        slope, S = lp["la_slope"], state["S"]
+        if T == 1 and valid is None:
+            S = S * jnp.exp(-slope)[:, None, None] + (
+                k[:, 0, :, :, None] * v[:, 0, :, None, :])
+            o = (S * q[:, 0, :, :, None]).sum(axis=-2)[:, None]
+        else:
+            if valid is not None and not jnp.ndim(valid):
+                valid = jnp.full((B,), valid, jnp.int32)
+            o, S = _la_chunks(q, k, v, slope, S, valid)
+        o = o * Dh ** -0.5
+    with jax.named_scope("la_out"):
+        o = _rms(o.reshape(B, T, H * Dh),
+                 lp["la_norm_s"].astype(jnp.float32), cfg.norm_eps)
+        o = o * jax.nn.sigmoid(z.astype(jnp.float32))
+        a = jnp.einsum("blc,cd->bld", o.astype(x.dtype), lp["la_wo"])
+    return hc_post(x, _res(a, cfg), mix), {"S": S}
+
+
+def state_half(x, lp, state, cfg, li, rope, valid=None, mix=None):
+    """Layer ``li``'s recurrent half (``cfg.state(li)``), whichever it
+    is: ``(x, state)``. ``rope`` is read by linear attention alone."""
+    if cfg.gdn(li):
+        return gdn_half(x, lp, state, cfg, valid, mix=mix)
+    return la_half(x, lp, state, cfg, rope, valid, mix=mix)
+
+
+# A selection of key blocks (``cfg.sparse_block``), written once: every
+# forward keeps, beside its K/V rows, the means of every
+# ``sparse_stride`` keys (a "cell": :func:`pool_cells`), from which a
+# pooling window's mean is the mean of its ``sparse_kernel /
+# sparse_stride`` cells, and asks :func:`sparse_pick` which blocks each
+# query attends.
+
+
+def pool_cells(k, cfg):
+    """Cell means of whole cells of rows: k (..., T, Hkv, D) with T a
+    whole number of ``sparse_stride`` -> (..., T / stride, Hkv, D)
+    float32, each the sum of its rows over the stride (rows of zeros
+    count nothing, so a cell that is not full yet holds what it has so
+    far)."""
+    st = cfg.sparse_stride
+    kf = k.astype(jnp.float32)
+    T = k.shape[-3]
+    shape = k.shape[:-3] + (T // st, st) + k.shape[-2:]
+    return kf.reshape(shape).sum(axis=-3) / st
+
+
+def sparse_pick(q, cells, n, cfg, n_blocks: int):
+    """Which key blocks each query attends: q (R, H, D), the R queries
+    of ONE request; ``cells`` (>= n_blocks * cells a block, Hkv, D)
+    float32, its pooled cells; ``n`` (R,) the rows each query sees
+    (its position + 1). Returns ``(stands, score)``: (R, Hkv,
+    n_blocks) bool and the blocks' scores float32.
+
+    A query with ``n <= sparse_dense_len`` attends every block it
+    sees. Else: window j is the mean of the keys ``[j stride, j stride
+    + kernel)`` and counts while it lies whole inside the n rows; ``p_h
+    = softmax_j(q_h . c_j * scale)`` per query head, ``s[j]`` its sum
+    over the query heads of a K/V head; a block's score is the largest
+    ``s[j]`` over the windows that TOUCH it (a window across two blocks
+    counts for both); the first ``sparse_init_blocks`` blocks and the
+    blocks that hold the last ``sparse_window`` rows stand, and of the
+    others the ``sparse_topk`` best (equal scores: the earlier
+    block). A caller opens the scope ``sparse_select`` around its call
+    (around the ``vmap`` where it maps this over requests: a scope
+    opened under a ``vmap`` is traced as ``vmap(sparse_select)``)."""
+    st, blk = cfg.sparse_stride, cfg.sparse_block
+    m, r = cfg.sparse_kernel // st, blk // st  # cells a window, a block
+    R, H, D = q.shape
+    Hkv = cells.shape[1]
+    nc = n_blocks * r
+    cells = cells[:nc]
+    t = jnp.einsum("rhgd,chd->hgrc",
+                   q.reshape(R, Hkv, H // Hkv, D).astype(jnp.float32),
+                   cells, precision=_HI)
+    nw = nc - m + 1
+    w = sum(t[..., i:i + nw] for i in range(m)) * (
+        cfg.softmax_scale / m)
+    whole = (jnp.arange(nw) * st + cfg.sparse_kernel) <= n[:, None]
+    p = jax.nn.softmax(jnp.where(whole, w, -1e30), axis=-1)
+    s = jnp.where(whole, p.sum(axis=1), -1.0)       # (Hkv, R, nw)
+    # block b is touched by the windows [b r - (m - 1), (b + 1) r):
+    # r + m - 1 strided reads of s, padded by m - 1 at both ends
+    s = jnp.pad(s, [(0, 0), (0, 0), (m - 1, m - 1)],
+                constant_values=-1.0)
+    score = functools.reduce(jnp.maximum, (
+        s[..., d:d + nc:r] for d in range(r + m - 1)))
+    score = score.transpose(1, 0, 2)                # (R, Hkv, blocks)
+    b = jnp.arange(n_blocks)
+    sees = b <= ((n - 1) // blk)[:, None]           # (R, blocks)
+    held = (b < cfg.sparse_init_blocks) | (
+        b >= (jnp.maximum(n - cfg.sparse_window, 0) // blk)[:, None])
+    held = (held & sees)[:, None]
+    open_ = (sees[:, None] & ~held)
+    vals, idx = jax.lax.top_k(jnp.where(open_, score, -1.0),
+                              min(cfg.sparse_topk, n_blocks))
+    best = jnp.zeros((R, Hkv, n_blocks), bool).at[
+        jnp.arange(R)[:, None, None], jnp.arange(Hkv)[None, :, None],
+        idx].set(vals >= 0.0)
+    stands = jnp.where((n > cfg.sparse_dense_len)[:, None, None],
+                       held | best, sees[:, None])
+    return stands, score
+
+
+def sparse_counts(n, cfg: TransformerConfig):
+    """``(blocks attended, blocks visible)`` of ONE K/V head, summed
+    over the queries that see ``n`` rows (a host array of counts) and
+    more than ``sparse_dense_len`` of them: what :func:`sparse_pick`
+    makes stand follows from the lengths alone, so a scheduler counts
+    it without a read from the device."""
+    n = np.asarray(n, np.int64).reshape(-1)
+    n = n[n > cfg.sparse_dense_len]
+    blk = cfg.sparse_block
+    sees = (n - 1) // blk + 1
+    first = np.maximum(n - cfg.sparse_window, 0) // blk
+    held = (sees - first) + np.minimum(cfg.sparse_init_blocks, first)
+    attended = held + np.minimum(cfg.sparse_topk, sees - held)
+    return int(attended.sum()), int(sees.sum())
+
+
+def sparse_attention_dense(q, k, v, cfg):
+    """Causal attention of a whole sequence over itself with every
+    query's own selection of key blocks (:func:`sparse_pick`), the
+    scores of all pairs materialised: the dense forward's form. q (B,
+    T, H, D), k, v (B, T, Hkv, D) -> (B, T, H, D)."""
+    B, T, H, D = q.shape
+    Hkv, blk, st = k.shape[2], cfg.sparse_block, cfg.sparse_stride
+    nb = -(-T // blk)
+    pad = nb * blk - T
+    with jax.named_scope("sparse_pool"):
+        cells = pool_cells(
+            jnp.pad(k, [(0, 0), (0, pad), (0, 0), (0, 0)]), cfg)
+    n = jnp.arange(T) + 1
+    with jax.named_scope("sparse_select"):
+        stands = jax.vmap(lambda qb, cb: sparse_pick(
+            qb, cb, n, cfg, nb)[0])(q, cells)           # (B, T, Hkv, nb)
+    rows = jnp.take(stands, jnp.arange(T) // blk, axis=-1)  # (B,T,Hkv,T)
+    seen = rows & (jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])[
+        None, :, None, :]
+    seen = jnp.repeat(seen.transpose(0, 2, 1, 3), H // Hkv, axis=1)
+    g = H // Hkv
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, g, axis=2),
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(jnp.where(seen, s * cfg.softmax_scale, -1e30),
+                       axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype),
+                      jnp.repeat(v, g, axis=2))
 
 
 def _mlp(x, lp):
@@ -1242,12 +1637,13 @@ def ffn_half(x, lp, cfg, li, *, tp_psum=False):
             y = _mlp(h, lp)
             if tp_psum:
                 y = jax.lax.psum(y, "tp")  # d_ff shard partial-sum
-            if not cfg.post_norm and mix is None:
+            if (not cfg.post_norm and mix is None
+                    and cfg.residual_scale == 1.0):
                 return x + y + lp["b2"], aux, hit  # b2 replicated
             y = y + lp["b2"]
         if cfg.post_norm:
             y = _norm(y, lp, "ln2p", cfg)
-        return hc_post(x, y, mix), aux, hit
+        return hc_post(x, _res(y, cfg), mix), aux, hit
 
 
 def embed(params, tokens, cfg):
@@ -1266,6 +1662,8 @@ def head_logits(params, x, cfg):
     folded, :func:`hc_fold`): the tied embedding, or ``params["head"]``."""
     with jax.named_scope("head"):
         x = _norm(x, params, "lnf", cfg)
+        if cfg.head_scale != 1.0:
+            x = x * jnp.asarray(cfg.head_scale, x.dtype)
         w = params["emb"] if cfg.tie_head else params["head"]
         return jnp.einsum("bld,vd->blv", x, w)
 
@@ -1336,16 +1734,19 @@ def _mixer_dense(x, lp, cfg, li: int, rope, impl):
     gated delta rule from a zero state, or latent attention in its
     expanded form."""
     x, mix = hc_pre(x, lp, cfg, "hc1")
-    if cfg.gdn(li):
-        return gdn_half(x, lp, gdn_zero_state(cfg, x.shape[0]), cfg,
-                        mix=mix)[0]
+    if cfg.state(li):
+        return state_half(x, lp, zero_state(cfg, li, x.shape[0]), cfg, li,
+                          rope, mix=mix)[0]
     if cfg.mla(li):
         qn, qr, row = mla_project(x, lp, cfg, rope)
         with jax.named_scope("mla_attn"):
             o = mla_expanded(qn, qr, row, lp, cfg)
         return mla_merge(x, o, lp, cfg, mix)
     q, k, v, gate = attn_qkv(x, lp, cfg, li, rope)
-    o = impl(q, k, v, causal=True, window=cfg.windows[li])
+    if cfg.sparse(li):
+        o = sparse_attention_dense(q, k, v, cfg)
+    else:
+        o = impl(q, k, v, causal=True, window=cfg.windows[li])
     return attn_merge(x, o, gate, lp, cfg, mix=mix)
 
 

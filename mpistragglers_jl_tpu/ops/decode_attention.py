@@ -87,7 +87,7 @@ _LANE = 128
 _SUB = 8  # TPU sublane tile: each GQA group pads to whole tiles of it
 
 __all__ = ["quantized_decode_attention", "paged_block_viable",
-           "paged_scale_lanes"]
+           "paged_scale_lanes", "paged_select_attention"]
 
 
 # Scoped-VMEM budget per (block row x kv head), CALIBRATED on the
@@ -314,31 +314,42 @@ def _update(q_ref, block, mask, acc, m_sc, l_sc, *, scale, Hkv, D, G):
     # static loop over kv heads: static row/lane slices, one MXU dot
     # per GQA group — the grouping costs index math, not DMA
     for h in range(Hkv):
-        rows = slice(h * G, (h + 1) * G)
-        q = q_ref[0][rows]  # (G, D): g live rows + padding
-        kb = kblk[:, h * D:(h + 1) * D].astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (G, bk)
-        s = s * k_scale(h)
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_sc[rows, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[rows] = jnp.broadcast_to(
-            l_sc[rows, :1] * corr + p.sum(axis=-1, keepdims=True),
-            (G, _LANE),
-        )
-        vb = vblk[:, h * D:(h + 1) * D].astype(jnp.float32)
-        pv = p * v_scale(h)
-        acc[rows] = acc[rows] * corr + jax.lax.dot_general(
-            pv, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_sc[rows] = jnp.broadcast_to(m_new, (G, _LANE))
+        _update_head(q_ref, h, kblk, vblk, slice(h * D, (h + 1) * D),
+                     k_scale(h), v_scale(h), mask, acc, m_sc, l_sc,
+                     scale=scale, G=G)
+
+
+def _update_head(q_ref, h, kblk, vblk, lanes, k_scale, v_scale, mask, acc,
+                 m_sc, l_sc, *, scale, G):
+    """:func:`_update` for K/V head ``h`` alone: its keys and values
+    are the ``lanes`` of ``kblk`` / ``vblk`` (int8, ``bk`` rows), their
+    scales the ``(1, bk)`` rows ``k_scale`` / ``v_scale``, its queries
+    the head's rows of the q tile."""
+    rows = slice(h * G, (h + 1) * G)
+    q = q_ref[0][rows]  # (G, D): g live rows + padding
+    kb = kblk[:, lanes].astype(q.dtype)
+    s = jax.lax.dot_general(
+        q, kb, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (G, bk)
+    s = s * k_scale
+    s = jnp.where(mask, s, _NEG)
+    m_prev = m_sc[rows, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[rows] = jnp.broadcast_to(
+        l_sc[rows, :1] * corr + p.sum(axis=-1, keepdims=True),
+        (G, _LANE),
+    )
+    vb = vblk[:, lanes].astype(jnp.float32)
+    pv = p * v_scale
+    acc[rows] = acc[rows] * corr + jax.lax.dot_general(
+        pv, vb, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_sc[rows] = jnp.broadcast_to(m_new, (G, _LANE))
 
 
 def _tile_q(q, Hkv: int):
@@ -552,4 +563,144 @@ def paged_decode_attention(q, cache_l: dict, pos, page_table, *, scale,
         ),
         interpret=interpret,
     )(posv, ptv, q3, kc, ks, vc, vs)
+    return _untile_o(o3, q, Hkv, G)
+
+
+# A selection of key blocks (models/transformer.py ``sparse_pick``): each
+# K/V head of each row attends its OWN short list of pages. The kernel
+# below is the paged form with the list a head in place of the table a
+# row: a static loop over the K/V heads, each walking its list and
+# copying in its own 128 lanes of a page alone (a page's tiles are
+# whole lane groups, so a head's half of a page is two contiguous
+# tiles, not a strided gather), the same online softmax a head.
+
+
+def _paged_select_kernel(at_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
+                         o_ref, kbuf, ksbuf, vbuf, vsbuf, sem, acc, m_sc,
+                         l_sc, *, n, P, width, scale, Hkv, D, G):
+    """One row of the batch a grid step. ``pt_ref[b, h * width + j]``:
+    the pool page of the j-th block that stands for head h, in position
+    order; ``at_ref[b, h]``: the query's position among those rows, so
+    the rows ``<= at`` are live and ``at // P + 1`` entries are walked.
+    ``kbuf``/``vbuf``: ``(2, n, P, D)`` int8, one head's lanes."""
+    b = pl.program_id(0)
+    bk = n * P
+    acc[:] = jnp.zeros_like(acc)
+    m_sc[:] = jnp.full_like(m_sc, _NEG)
+    l_sc[:] = jnp.zeros_like(l_sc)
+
+    def join(parts, axis):
+        return parts[0] if n == 1 else jnp.concatenate(parts, axis=axis)
+
+    for h in range(Hkv):
+        at = at_ref[b, h]
+        live = jnp.minimum(at // P + 1, width)
+        lanes = pl.ds(h * D, D)
+
+        def copies(blk, buf, h=h, live=live, lanes=lanes):
+            out = []
+            for i in range(n):
+                page = pt_ref[b, h * width + jnp.minimum(blk * n + i,
+                                                         live - 1)]
+                for src, dst in (
+                        (k_hbm.at[page, :, lanes], kbuf),
+                        (ks_hbm.at[page], ksbuf),
+                        (v_hbm.at[page, :, lanes], vbuf),
+                        (vs_hbm.at[page], vsbuf)):
+                    out.append(pltpu.make_async_copy(
+                        src, dst.at[buf, i], sem.at[buf]))
+            return out
+
+        for c in copies(0, 0):
+            c.start()
+
+        def block(blk, carry, h=h, at=at, live=live, copies=copies):
+            buf = blk % 2
+
+            @pl.when((blk + 1) * n < live)
+            def _prefetch():
+                for c in copies(blk + 1, 1 - buf):
+                    c.start()
+
+            for c in copies(blk, buf):
+                c.wait()
+            kpos = blk * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (1, bk), 1)
+            row = lambda sbuf: join(
+                [sbuf[buf, i][h:h + 1, :P] for i in range(n)], 1)
+            _update_head(
+                q_ref, h, join([kbuf[buf, i] for i in range(n)], 0),
+                join([vbuf[buf, i] for i in range(n)], 0), slice(None),
+                row(ksbuf), row(vsbuf), kpos <= at, acc, m_sc, l_sc,
+                scale=scale, G=G)
+            return carry
+
+        jax.lax.fori_loop(0, (live + n - 1) // n, block, 0)
+    l = jnp.maximum(l_sc[:, :1], 1e-20)
+    o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
+
+
+def select_pages_per_step(width: int, P: int, D: int, G: int) -> int | None:
+    """:func:`_pages_per_step` for a head's list of ``width`` pages."""
+    return _pages_per_step(width, P, 1, D, G)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "P", "interpret"))
+def paged_select_attention(q, cache_l: dict, at, pages, *, scale, P: int,
+                           interpret: bool | None = None):
+    """Single-query grouped attention over a selection of pages a K/V
+    head: q (B, 1, H, D); ``cache_l`` the page pools of
+    ``quantized_decode_attention(page_table=...)``; ``pages`` (B, Hkv,
+    width) int32, for each row and K/V head the pool pages it attends
+    in position order; ``at`` (B, Hkv) int32, the query's position among
+    those pages' rows (``(entries - 1) * P + its row in the last``).
+    Returns (B, 1, H, D). Jitted for the reason
+    :func:`paged_decode_attention` is."""
+    if interpret is None:
+        interpret = _use_interpret()
+    B, _, _, D = q.shape
+    kc, vc = cache_l["k"], cache_l["v"]
+    ks, vs = cache_l["k_s"], cache_l["v_s"]
+    Hkv, lanes = ks.shape[1], ks.shape[2]
+    width = pages.shape[2]
+    q3, G = _tile_q(q, Hkv)
+    rows = Hkv * G
+    n = select_pages_per_step(width, P, D, G)
+    if n is None:
+        raise ValueError(
+            f"a page of {P} rows of {D} does not fit the kernel's VMEM "
+            "budget; use the gather route")
+    kern = functools.partial(
+        _paged_select_kernel, n=n, P=P, width=width, scale=scale, Hkv=Hkv,
+        D=D, G=G)
+
+    def _row(b, at_ref, pt_ref):
+        del at_ref, pt_ref
+        return (b, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, rows, D), _row), hbm, hbm, hbm, hbm],
+        out_specs=pl.BlockSpec((1, rows, D), _row),
+        scratch_shapes=[
+            pltpu.VMEM((2, n, P, D), kc.dtype),
+            pltpu.VMEM((2, n, Hkv, lanes), ks.dtype),
+            pltpu.VMEM((2, n, P, D), vc.dtype),
+            pltpu.VMEM((2, n, Hkv, lanes), vs.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ] + _scratch(rows, D),
+    )
+    o3 = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=_sds((B, rows, D), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+    )(at.astype(jnp.int32), pages.astype(jnp.int32).reshape(B, Hkv * width),
+      q3, kc, ks, vc, vs)
     return _untile_o(o3, q, Hkv, G)

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from mpistragglers_jl_tpu.ops.decode_attention import (
     paged_scale_lanes,
+    paged_select_attention,
     quantized_decode_attention,
 )
 
@@ -73,6 +74,31 @@ def test_paged_kernel_compiles_for_the_v5e(one_chip, H, Hkv, D, max_pages,
     assert "tpu_custom_call" in text
     # the pools reach the kernel as they are stored: no operation makes
     # another array of a pool leaf's shape on the way in
+    for leaf in (f"s8[{n_pages},{P},{Hkv * D}]",
+                 f"f32[{n_pages},{Hkv},{paged_scale_lanes(P)}]"):
+        made = [ln for ln in text.splitlines()
+                if f"= {leaf}" in ln and " parameter(" not in ln]
+        assert not made, made[0]
+
+
+def test_paged_select_kernel_compiles_at_published_widths(one_chip):
+    """MiniCPM-SALA's attention layer: 32 query heads on 2 K/V heads of
+    128, a list of 128 pages a slot and K/V head, each head copying in
+    its own 128 lanes of a page (a slice of whole lane tiles)."""
+    B, P, H, Hkv, D, width, n_pages = 16, 64, 32, 2, 128, 128, 8449
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, k, ks, v, vs, at, pages):
+        return paged_select_attention(
+            q, {"k": k, "k_s": ks, "v": v, "v_s": vs}, at, pages,
+            scale=D ** -0.5, P=P, interpret=False)
+
+    pool = sds((n_pages, P, Hkv * D), jnp.int8)
+    scales = sds((n_pages, Hkv, paged_scale_lanes(P)), jnp.float32)
+    text = _compiled_text(
+        call, sds((B, 1, H, D), jnp.bfloat16), pool, scales, pool, scales,
+        sds((B, Hkv), jnp.int32), sds((B, Hkv, width), jnp.int32))
+    assert "tpu_custom_call" in text
     for leaf in (f"s8[{n_pages},{P},{Hkv * D}]",
                  f"f32[{n_pages},{Hkv},{paged_scale_lanes(P)}]"):
         made = [ln for ln in text.splitlines()
