@@ -2239,6 +2239,10 @@ class ServingScheduler:
             **({"la_rule": la_rule_route(cfg, self.C)}
                if "la" in mixers else {}),
         }
+        # ``serving.decode``'s ``gdn_rule``: the form ONE token takes
+        # in a step of the tick, from the same function
+        self._step_route = ({"gdn_rule": gdn_rule_route(cfg, 1)}
+                            if "gdn" in mixers else {})
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -2571,8 +2575,8 @@ class ServingScheduler:
                 if r is not None and s not in self._admitting
             ]
             if decoding:
-                with phase("serving.decode",
-                           slots=len(decoding)) as decode:
+                with phase("serving.decode", slots=len(decoding),
+                           **self._step_route) as decode:
                     if self.paged:
                         # COW pass: every page the next n_inner writes
                         # touch must be exclusively owned BEFORE the

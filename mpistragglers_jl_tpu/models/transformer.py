@@ -51,7 +51,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.timeline import annotate
-from ..ops.delta_rule import chunked_delta_rule, delta_rule_viable
+from ..ops.delta_rule import (
+    chunked_delta_rule,
+    delta_rule_step,
+    delta_rule_viable,
+    delta_step_viable,
+)
 from ..parallel.ring_attention import (
     _flash_interpreted,
     resolve_attention_impl,
@@ -1220,15 +1225,19 @@ def _delta_rule_chunks(q, k, v, g, beta, S):
 
 
 def gdn_rule_route(cfg: TransformerConfig, T: int) -> str:
-    """The form the recurrence takes over a call of T > 1 rows, from
-    what the shapes say: ``"kernel"`` (ops/delta_rule.py: whole
-    sub-chunks of its own, 128 rows, at head sizes of whole lane tiles,
-    the published widths) or ``"xla"`` (:func:`_delta_rule_chunks`).
-    :func:`gdn_half` asks it, and the serving scheduler for
-    ``serving.prefill_chunk``'s ``gdn_rule``."""
-    return "kernel" if delta_rule_viable(
-        T, cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
-        cfg.gdn_value_dim) else "xla"
+    """The form the recurrence takes over a call of T rows, from what
+    the shapes say: ``"kernel"`` (ops/delta_rule.py, at head sizes of
+    whole lane tiles, the published widths: for T > 1 the chunked
+    kernel, whole sub-chunks of its own, 128 rows; for one token the
+    kernel that updates S where it lies) or ``"xla"``
+    (:func:`_delta_rule_chunks`, :func:`_delta_rule_step`).
+    :func:`gdn_half` asks it, and the serving scheduler for the
+    ``gdn_rule`` of ``serving.prefill_chunk`` and ``serving.decode``."""
+    heads = (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+             cfg.gdn_value_dim)
+    viable = (delta_step_viable(*heads) if T == 1
+              else delta_rule_viable(T, *heads))
+    return "kernel" if viable else "xla"
 
 
 def gdn_half(x, lp, state, cfg, valid=None, mix=None):
@@ -1271,19 +1280,22 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
         y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
         y = jax.nn.silu(y)
     with jax.named_scope("gdn_rule"):
-        # a chunk of whole sub-chunks at widths of whole lane tiles goes
-        # through the kernel, which reads q, k and v out of y as they
-        # lie; anything else (one token, tiny widths, an odd length)
-        # through the plain forms below
+        # at widths of whole lane tiles a chunk of whole sub-chunks
+        # goes through the chunked kernel, which reads q, k and v out
+        # of y as they lie, and one token through the kernel that
+        # updates S where it lies, a key head serving its value heads
+        # there; anything else (tiny widths, an odd length) through the
+        # plain forms below, q and k repeated to the value heads
         kernel = gdn_rule_route(cfg, T) == "kernel"
-        if not kernel:
+        if not kernel or T == 1:
             q = y[..., :kw].reshape(B, T, Hk, Dk)
             k = y[..., kw:2 * kw].reshape(B, T, Hk, Dk)
             v = y[..., 2 * kw:].reshape(B, T, Hv, Dv)
             l2 = lambda a: a * jax.lax.rsqrt(
                 (a * a).sum(-1, keepdims=True) + 1e-6)
             q, k = l2(q) * Dk ** -0.5, l2(k)
-            if Hv != Hk:  # key head j serves value heads [j * r, (j + 1) * r)
+            if Hv != Hk and not kernel:
+                # key head j serves value heads [j * r, (j + 1) * r)
                 q = jnp.repeat(q, Hv // Hk, axis=2)
                 k = jnp.repeat(k, Hv // Hk, axis=2)
         beta = jax.nn.sigmoid(ba[..., :Hv])
@@ -1294,14 +1306,15 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
                     if jnp.ndim(valid) else
                     (jnp.arange(T) < valid)[None, :, None])
             g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
-        if kernel:
+        if T == 1:
+            step = delta_rule_step if kernel else _delta_rule_step
+            o, S = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                        state["S"])
+            o = o[:, None]
+        elif kernel:
             o, S = chunked_delta_rule(y, g, beta, state["S"], Hk=Hk, Hv=Hv,
                                       Dk=Dk, Dv=Dv)
             o = o.reshape(B, T, Hv, Dv)
-        elif T == 1:
-            o, S = _delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                    beta[:, 0], state["S"])
-            o = o[:, None]
         else:
             o, S = _delta_rule_chunks(q, k, v, g, beta, state["S"])
     with jax.named_scope("gdn_out"):

@@ -331,12 +331,14 @@ class FlightWatchdog:
             if stale > self.stall_s:
                 if self._armed:
                     self._armed = False
-                    self.fired += 1
                     self.flight.trip(
                         f"watchdog {self.name!r}: no activity for "
                         f"{stale:.3f}s (> {self.stall_s}s)",
                         path=self.path,
                     )
+                    # counted once the dump has landed: whoever sees
+                    # the count finds the file
+                    self.fired += 1
             else:
                 self._armed = True  # activity resumed; re-arm
 

@@ -562,8 +562,23 @@ def paged_decode_attention(q, cache_l: dict, pos, page_table, *, scale,
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
-    )(posv, ptv, q3, kc, ks, vc, vs)
+    )(posv, ptv, q3, *(_in_hbm(a, interpret) for a in (kc, ks, vc, vs)))
     return _untile_o(o3, q, Hkv, G)
+
+
+def _in_hbm(pool, interpret: bool):
+    """A page pool as the kernel's operand, declared to live in HBM.
+    The kernel copies in the live pages itself; left to choose
+    (``pl.ANY`` says nothing to it), the TPU compiler carries a pool
+    that fits its fast memory space through that space every step of
+    the tick's scan: the whole pool in before the step's row is
+    scattered into it, the whole pool out again for the scan's carry
+    (Qwen3-Next's two 35.7 MB pools: 143 MB a step to write 16 rows of
+    512 bytes; Trinity-Mini's tick 225 MB a step; PERF.md section 6,
+    PR 43). With the operand pinned the scatter updates the carry
+    where it lies. Nothing to pin in the interpreter."""
+    return pool if interpret else pltpu.with_memory_space_constraint(
+        pool, pltpu.HBM)
 
 
 # A selection of key blocks (models/transformer.py ``sparse_pick``): each

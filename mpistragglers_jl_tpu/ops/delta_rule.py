@@ -1,6 +1,7 @@
-"""Pallas TPU kernel for the chunked gated delta rule (the prefill
-chunks of a layer whose mixer is ``gdn``: models/transformer.py
-``gdn_half`` with T > 1).
+"""Pallas TPU kernels for the gated delta rule of a layer whose mixer
+is ``gdn`` (models/transformer.py ``gdn_half``): the chunked form for
+the prefill chunks (T > 1), and one token's step for the decode tick
+(T = 1; the last part of this header).
 
 The recurrence and its chunked form are ``_delta_rule_chunks``'
 (models/transformer.py; the equations are in its docstring). What this
@@ -47,6 +48,64 @@ the inverse is half of what is left.
 ``gdn_rule`` argument): what the kernel can take follows from the
 shapes alone. Inference-only: no VJP. Off the TPU the kernel runs
 interpreted, as the flash and decode kernels do.
+
+ONE TOKEN (``delta_rule_step``; ``delta_step_viable`` is its route's
+test). A step of the tick runs ``S' = exp(g) S; S = S' + k (x) beta (v -
+S'^T k); o = S^T q`` for every slot in every delta-rule layer: seven
+operations an element of S against eight bytes moved, so the bytes
+bound it by a factor of five and the kernel's whole business is where
+S goes. A grid step takes the heads of ONE slot (all 32 at the cell's
+widths: 2 MiB of S, read from HBM once, updated in VMEM head by head on
+the VPU in float32, written once), and the result is aliased to the
+operand AND declared to live in HBM (``pltpu.HBM`` as its
+``out_shape``). The second half is what the gain rests on: the TPU
+compiler assigns memory spaces on its own, and for the plain step, and
+for an aliased kernel whose result says nothing, it carries each
+layer's ``f32[16,32,128,128]`` through its fast memory space with
+asynchronous copies and slices of its own (``copy-start`` /
+``slice-start`` in the scan's body: in, and out again behind the
+update), which cost more than the update. With the result pinned the
+scan's carry is the kernel's operand, updated where it lies, and the
+compiled body holds no copy, slice or fusion of that shape
+(tests/test_decode_attention_tpu_compile.py). One thing that follows
+from the pin: a program whose ROOT is the kernel call itself, with S
+donated, is refused by the compiler's verifier (the result's memory
+space against the parameter's none); any operation between the call
+and the program's end, as every caller here has, lifts it.
+
+* q and k arrive a KEY head each, (B, Hk, Dk), normed outside (they
+  are a 128th of S); the index map hands a step its slot's key heads
+  and the loop serves ``Hv / Hk`` value heads from each: nothing is
+  repeated. What scales S's ROWS (k for both updates, q for the
+  read-out) is needed down the sublanes: the kernel sums the row's
+  diagonal matrix along the lanes, as the chunked kernel does for its
+  gates, and asks Mosaic for no transpose; no lane-padded column is
+  made in HBM.
+* exp(g) and beta arrive on every lane, (B, Hv, Dv) beside v: 2 x 128
+  floats a head beside its 16,384 of S, and a row of S is scaled by a
+  sublane broadcast.
+* no product goes over the MXU; every sum is float32 and only the
+  order of a Dk-term sum differs from the plain step's (rows and S to
+  1e-6 over 32 consecutive steps, tests/test_delta_rule_kernel.py). A
+  row with g = 0 and beta = 0 leaves S bit for bit.
+
+Measured on the v5e at the cell's shape (16 slots x 16 key / 32 value
+heads of 128, three layers' states carried by one scan of 256 steps as
+the tick carries them; milliseconds a step of all three layers, whose
+bytes are 0.246 ms at 819 GB/s; PERF.md section 6, PR 43):
+
+    form                                        ms a step   of the peak
+    the plain step, the compiler's 4 copies
+      of a state a step with it                   0.432        57%
+    this kernel, 32 heads a grid step (2 MiB)     0.317        77%
+    this kernel, 16 heads a grid step (1 MiB)     0.318        77%
+
+and in the serving tick itself (traced, 496 steps): the plain step
+0.196 under ``gdn_rule`` and 0.25 in the copies and slices that moved
+the states, 0.45 together; the kernel 0.316 and no such move. The block
+size is not what bounds it (the two read alike, and 77% is about what
+a plain elementwise pass reaches on this chip), so a step takes the
+most heads ``_STEP_BLOCK`` admits and the fewest grid steps.
 """
 
 from __future__ import annotations
@@ -77,7 +136,13 @@ _STRIP = 16  # rows of the strip that holds the inverse's small blocks
 _VMEM_CAP = 14 * 2 ** 20
 _VMEM_HEAD = 5 * 2 ** 18
 
-__all__ = ["SUBCHUNK", "chunked_delta_rule", "delta_rule_viable"]
+# the most of S a grid step of the single-token kernel takes: a slot's
+# 32 heads of 128 x 128 (its two buffers in and two out are 8 MiB of
+# the 16 Mosaic grants unasked)
+_STEP_BLOCK = 2 * 2 ** 20
+
+__all__ = ["SUBCHUNK", "chunked_delta_rule", "delta_rule_step",
+           "delta_rule_viable", "delta_step_viable"]
 
 
 def delta_rule_viable(T: int, Hk: int, Hv: int, Dk: int, Dv: int,
@@ -284,3 +349,97 @@ def delta_rule_call(qkv, g, beta, S, *, Hk: int, c: int, hb: int,
         name="delta_rule",
     )(qkv, qkv, qkv, G, lanes(beta), Gl, S)
     return o, S
+
+
+# -- one token ----------------------------------------------------------------
+
+
+def delta_step_viable(Hk: int, Hv: int, Dk: int, Dv: int) -> bool:
+    """Whether the single-token kernel takes these heads: the head
+    sizes are whole lane tiles, a key head serves a whole number of
+    value heads, and some number of value heads a grid step is legal
+    (:func:`_heads_per_token`)."""
+    return (Dk % _LANE == 0 and Dv % _LANE == 0 and Hv % Hk == 0
+            and _heads_per_token(Hk, Hv, Dk, Dv) > 0)
+
+
+def _heads_per_token(Hk: int, Hv: int, Dk: int, Dv: int) -> int:
+    """Value heads a grid step of the single-token kernel takes: the
+    most that are the groups of whole key heads, whose rows of v and of
+    q and k are whole sublane tiles of 8 (or all there are), and whose
+    states are at most ``_STEP_BLOCK``; 0 where none is."""
+    r = Hv // Hk
+    rows = lambda d, H: d % 8 == 0 or d == H
+    fits = lambda d: (Hv % d == 0 and d % r == 0 and rows(d, Hv)
+                      and rows(d // r, Hk)
+                      and 4 * d * Dk * Dv <= _STEP_BLOCK)
+    return max((d for d in range(1, Hv + 1) if fits(d)), default=0)
+
+
+def _step_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref,
+                 *, r: int):
+    Dk, Dv = s_ref.shape[2:]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (Dk, Dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (Dk, Dk), 1))
+    # a key head's Dk values down the sublanes (what scales S's rows):
+    # the row's diagonal matrix summed along the lanes, as in the
+    # chunked kernel; exact, and no transpose is asked of Mosaic
+    column = lambda x: jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
+    for i in range(q_ref.shape[1]):
+        q, k = column(q_ref[0, i:i + 1, :]), column(k_ref[0, i:i + 1, :])
+        for h in range(i * r, (i + 1) * r):
+            row = lambda ref: ref[0, h:h + 1, :]              # (1, Dv)
+            S = s_ref[0, h] * row(a_ref)                      # exp(g) S
+            mem = jnp.sum(S * k, axis=0, keepdims=True)       # S'^T k
+            S = S + k * ((row(v_ref) - mem) * row(b_ref))
+            so_ref[0, h] = S
+            o_ref[0, h:h + 1, :] = jnp.sum(S * q, axis=0, keepdims=True)
+
+
+def delta_rule_step(q, k, v, g, beta, S, *, interpret: bool | None = None):
+    """One token of the gated delta rule with ``S`` updated where it
+    lies: ``S' = exp(g) S``, ``S = S' + k (x) beta (v - S'^T k)``, ``o =
+    S^T q``, the arithmetic of ``transformer._delta_rule_step`` in
+    float32 on the VPU (no product goes over the MXU; only the order
+    of the Dk-term sums may differ). q, k (B, Hk, Dk), normed and
+    scaled, a KEY head each; v (B, Hv, Dv); g, beta (B, Hv); S (B, Hv,
+    Dk, Dv), all float32. Returns ``(o, S)``: o (B, Hv, Dv)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    (_, Hk, Dk), (_, Hv, Dv) = q.shape, v.shape
+    if not delta_step_viable(Hk, Hv, Dk, Dv):
+        raise ValueError(
+            f"{Hk} / {Hv} heads of {Dk} x {Dv} are not the single-token "
+            "kernel's; use the plain step")
+    return delta_step_call(q, k, v, g, beta, S,
+                           hb=_heads_per_token(Hk, Hv, Dk, Dv),
+                           interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
+def delta_step_call(q, k, v, g, beta, S, *, hb: int, interpret: bool):
+    """The pallas_call, ``hb`` value heads of one member a grid step:
+    each head's S read from HBM once and written once to the buffer it
+    came from. Jitted for the reason :func:`delta_rule_call` is; a
+    device trace shows the kernel as ``delta_rule_step``."""
+    (B, Hk, Dk), (_, Hv, Dv) = q.shape, v.shape
+    r = Hv // Hk
+    # a head's decay and beta on every lane (the kernel scales rows of
+    # Dv lanes by them): 2 x 128 floats a head beside its 16,384 of S
+    lanes = lambda a: jnp.broadcast_to(a[..., None], (B, Hv, Dv))
+    keyed = pl.BlockSpec((1, hb // r, Dk), lambda b, h: (b, h, 0))
+    valued = pl.BlockSpec((1, hb, Dv), lambda b, h: (b, h, 0))
+    state = pl.BlockSpec((1, hb, Dk, Dv), lambda b, h: (b, h, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_step_kernel, r=r),
+        grid=(B, Hv // hb),
+        in_specs=[keyed, keyed, valued, valued, valued, state],
+        out_specs=[valued, state],
+        out_shape=[_sds((B, Hv, Dv), jnp.float32, v),
+                   pltpu.HBM(S.shape, jnp.float32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="delta_rule_step",
+    )(q, k, v, lanes(jnp.exp(g)), lanes(beta), S)

@@ -38,6 +38,17 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _result_of(line, *ops):
+    """The result's type where a compiled program's line is one of the
+    operations ``ops`` ("" elsewhere)."""
+    head, _, rest = line.partition(" = ")
+    for op in ops:
+        kind, found, _ = rest.partition(f" {op}(")
+        if found and "(%" not in kind:
+            return kind
+    return ""
+
+
 def _compiled_text(fn, *args):
     # as the chip runs it: the suite's 64-bit mode (conftest.py) is not
     # the program's, and Mosaic has no float64
@@ -79,6 +90,56 @@ def test_paged_kernel_compiles_for_the_v5e(one_chip, H, Hkv, D, max_pages,
         made = [ln for ln in text.splitlines()
                 if f"= {leaf}" in ln and " parameter(" not in ln]
         assert not made, made[0]
+
+
+def test_qwen3_next_tick_leaves_states_and_pools_where_they_lie(one_chip):
+    """The whole decode tick of ``serve_q3next_mixed`` (the cell's own
+    configuration file: 16 slots, 8 steps, three delta-rule layers and
+    one attention layer over held experts), compiled as the scheduler
+    would run it on the kernel route. The scan's body moves neither a
+    delta-rule state nor a page pool through the compiler's fast memory
+    space: the step kernel's result and the paged kernel's pool
+    operands are declared to live in HBM. Until PR 43 a step moved 201
+    MB of states and 143 MB of pools that way, in and out again (PERF.md
+    section 6)."""
+    import json
+    import pathlib
+
+    from chipbench.runners import serve_gdn
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.ops import (
+        decode_attention,
+        delta_rule,
+        flash_attention,
+    )
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    config = json.loads((root / "chipbench" / "configs"
+                         / "q3next-80b-a3b-serve.json").read_text())
+    cfg, program = serve_gdn.transformer_config(config), config["program"]
+    sched = serving.ServingScheduler(
+        serve_gdn.param_shapes(config), cfg, slots=program["slots"],
+        n_inner=program["n_inner"], quantize_kv=program["quantize_kv"],
+        page_tokens=program["page_tokens"],
+        prompt_chunk=program["prompt_chunk"],
+        max_prompt=program["max_prompt"])
+    tick = serving._serving_scan_paged(
+        cfg, sched.n_inner, None, sched.temperature, None, True, sched.P)
+    args = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                        sched._scan_args())
+    # as on the chip: the kernels through Mosaic, not the interpreter
+    compiled = lambda: False
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        for module in (flash_attention, decode_attention, delta_rule):
+            patch.setattr(module, "_use_interpret", compiled)
+        text = tick.lower(*args).compile().as_text()
+    for name in ("delta_rule_step", "paged_decode_attention"):
+        assert f"%{name}" in text
+    # (the step's row is scattered into a pool by a fusion, in place)
+    for held in ("f32[16,32,128,128]", "s8[1089,64,512]"):
+        moved = [line for line in text.splitlines() if held in _result_of(
+            line, "copy", "copy-start", "slice-start")]
+        assert not moved, moved[:3]
 
 
 def test_paged_select_kernel_compiles_at_published_widths(one_chip):
@@ -164,6 +225,47 @@ def test_delta_rule_kernel_compiles_at_published_widths(one_chip, B):
     # makes another array of a head-major or repeated layout on the way
     for shape in (f"f32[{B},{T},{Hv},{D}]", f"f32[{B},{Hv},{T},{D}]"):
         assert f"= {shape}" not in text
+
+
+def test_delta_step_kernel_leaves_the_state_where_it_lies(one_chip):
+    """The single-token kernel (ops/delta_rule.py) for 16 slots of 16 key
+    / 32 value heads of 128 x 128 under a scan that carries three
+    layers' states, as the tick does: Mosaic takes it, and the compiled
+    program moves no ``f32[16,32,128,128]`` (no copy or slice of the
+    state into the fast memory and back, which is what the plain step's
+    time went to: PERF.md section 6, PR 43): the kernel's operand is the
+    scan's carry itself."""
+    from mpistragglers_jl_tpu.ops.delta_rule import delta_rule_step
+
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    B, Hk, Hv, D = 16, 16, 32, 128
+    step = functools.partial(delta_rule_step, interpret=False)
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def steps(states, q, k, v, g, beta):
+        def body(carry, _):
+            states, x = carry
+            out = []
+            for S in states:
+                x, S = step(q, k, v + x, g, beta, S)
+                out.append(S)
+            return (out, x), None
+        return jax.lax.scan(body, (states, jnp.zeros_like(v)), None,
+                            length=8)[0]
+
+    with jax.enable_x64(False):
+        text = steps.lower(
+            [f32(B, Hv, D, D)] * 3, f32(B, Hk, D), f32(B, Hk, D),
+            f32(B, Hv, D), f32(B, Hv), f32(B, Hv)).compile().as_text()
+    state = f"f32[{B},{Hv},{D},{D}]"
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and state in line]
+    assert len(calls) == 3
+    for line in calls:  # the operand comes straight out of the carry
+        assert "get-tuple-element" in line.split("custom-call(")[1]
+    moved = [line for line in text.splitlines() if _result_of(
+        line, "copy", "copy-start", "slice-start", "fusion").count(state)]
+    assert not moved, moved[:3]
 
 
 def test_absorbed_latent_decode_compiles_at_published_widths(one_chip):

@@ -201,6 +201,19 @@ def test_the_delta_rule_kernel_is_called_under_gdn_rule():
     assert ["delta_rule", "pallas_call"] in held
 
 
+def test_the_step_kernel_is_called_under_gdn_rule():
+    """And one token of the tick goes through the kernel that updates S
+    where it lies: in the lowered tick the call of its jitted function
+    carries ``gdn_rule`` on its path, which is where ``gdn_share_pct``
+    and ``gdn_state_hbm_pct`` find its time."""
+    cfg = dataclasses.replace(DELTA, gdn_key_heads=1, gdn_value_heads=2,
+                              gdn_key_dim=128, gdn_value_dim=128)
+    held = paths(_scheduler(cfg).lower_tick())
+    calls = [p for p in held if p[-1] == "jit(delta_step_call)"]
+    assert calls and all("gdn_rule" in p for p in calls)
+    assert ["delta_rule_step", "pallas_call"] in held
+
+
 def test_the_feed_forward_nests_under_the_ticks_older_scope():
     nested = [p for p in paths(_scheduler(DENSE).lower_tick())
               if "ffn" in p]
