@@ -175,8 +175,10 @@ def _kernel_possible(cfg, quantize_kv: bool) -> bool:
     conditions (GQA ratio, block divisor, ``_route_kernel``'s batch
     threshold) depend on per-shard shapes and stay trace-time. Also
     scopes the vma carve-out (``_decode_kernel_interpreted``). A latent
-    layer's one 576-wide row a position is no K/V head of the kernel's:
-    a configuration with one takes the ``jax.numpy`` route throughout."""
+    layer's one row a position is no K/V head of these kernels: the
+    positional and slot-ring programs of a configuration with one take
+    the ``jax.numpy`` route; its PAGED tick has a kernel of its own
+    (``_paged_kernel_possible``)."""
     return bool(quantize_kv and cfg.head_dim % 128 == 0
                 and not cfg.latent_layers)
 
@@ -193,7 +195,26 @@ def _paged_kernel_possible(cfg, quantize_kv: bool,
     and one page of it inside the kernel's VMEM budget at this head
     count and group tile. The serving scheduler resolves this ONCE at
     construction against its slot count; there is no trace-time
-    re-gate on the paged path."""
+    re-gate on the paged path.
+
+    Latent layers take the kernel's latent form
+    (``ops.decode_attention.latent_decode_attention``) where every layer
+    is one (latent layers beside K/V layers keep the gather route), the
+    cache is quantized, the latent is whole lane tiles (the row splits
+    into its two parts at a tile's edge) and one page fits the budget
+    under the absorbed query's ``n_heads`` rows."""
+    if cfg.latent_layers:
+        from ..ops.decode_attention import (
+            latent_pages_per_step,
+            paged_block_viable,
+        )
+
+        return bool(
+            quantize_kv and all(cfg.mla(li) for li in range(cfg.n_layers))
+            and cfg.mla_kv_rank % 128 == 0
+            and paged_block_viable(page_tokens)
+            and latent_pages_per_step(
+                1, page_tokens, cfg.latent_width, cfg.n_heads) is not None)
     if not _kernel_possible(cfg, quantize_kv):
         return False
     if cfg.n_heads % cfg.kv_heads:

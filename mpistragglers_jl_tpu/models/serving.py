@@ -44,14 +44,20 @@ Design (TPU-first):
   (``layer_mixers`` value ``"mla"``) keeps ONE row a position for all
   its heads, the normalised latent beside one rotated key, and no
   ``v`` (``decode._latent_leaves``): int8 with a scale for each of the
-  two parts, in pages like any row cache (``_fresh_pages``), prefix
-  pages shared. Keys and values are both read from it in the absorbed
-  form (``decode._latent_attend``); the tick takes the gather route
-  (``_serving_scan_paged`` with ``use_kernel`` False: every slot's ring
-  gathered once a tick), the paged kernel having no such mode. The
-  sharded tick, migration and speculation refuse it by mechanism, as
-  they do a residual path of several streams (``hc_mult``), of which
-  nothing is cached.
+  two parts, in pages like any row cache (``_fresh_pages``: the row in
+  whole lane tiles), prefix pages shared. Keys and values are both
+  read from it in the absorbed form (``decode._latent_attend``). The
+  paged tick writes the step's row into its page in place and reads
+  the pages a slot has filled where they lie, through the latent form
+  of the paged kernel (``ops.decode_attention.latent_decode_attention``;
+  ``decode._paged_kernel_possible`` says when: int8 rows, a latent of
+  whole lane tiles, every layer latent), a drafting step's two queries
+  a slot as two rows of the kernel; what the kernel cannot take (rows
+  in the model's dtype, the tests' latents of 24) takes the gather
+  route (``_serving_scan_paged`` with ``use_kernel`` False: every
+  slot's ring gathered once a tick). The sharded tick, migration and
+  speculation refuse it by mechanism, as they do a residual path of
+  several streams (``hc_mult``), of which nothing is cached.
 * **One or two tokens a step.** With ``draft="mtp"`` and a
   configuration that carries a multi-token-prediction module
   (``TransformerConfig(mtp_depth=1)``) a decode step runs two rows a
@@ -279,6 +285,14 @@ def paged_scale_lanes(P: int) -> int:
     return lanes(P)
 
 
+def paged_row_lanes(width: int) -> int:
+    """The minor axis of a latent layer's pool at a row of ``width``
+    values: the kernel's rule too (ops/decode_attention.py)."""
+    from ..ops.decode_attention import paged_row_lanes as lanes
+
+    return lanes(width)
+
+
 def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
                  quantize_kv: bool = False, slots: int = 0) -> list[dict]:
     """Zeroed per-layer PAGE POOL, shared by every slot, in the layout
@@ -299,10 +313,13 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     delta-rule layer has no pages: its leaf is the fixed block of state
     of each of the ``slots`` (its page count is not read). A latent
     layer's pages hold its one row a position, ``k`` ``(n_pages, P,
-    latent + rope)``, and the two scales of a row as two "heads" of
-    ``k_s``. A layer that attends a selection of its key blocks keeps
-    a page's pooled cells beside its rows: ``kp`` ``(n_pages, P /
-    sparse_stride, kv_heads * head_dim)`` float32."""
+    lanes)``, the ``latent + rope`` values of a row and zeros up to
+    whole 128-lane tiles behind them (``paged_row_lanes``: what the
+    device stores either way, and what the latent kernel can copy), and
+    the two scales of a row as two "heads" of ``k_s``. A layer that
+    attends a selection of its key blocks keeps a page's pooled cells
+    beside its rows: ``kp`` ``(n_pages, P / sparse_stride, kv_heads *
+    head_dim)`` float32."""
     counts = ((n_pages,) * cfg.cache_layers if isinstance(n_pages, int)
               else tuple(n_pages))
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
@@ -311,7 +328,8 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
         if cfg.state(li):
             return zero_state(cfg, li, slots)
         if cfg.mla(li):
-            out = {"k": jnp.zeros((n, P, cfg.latent_width), kvdt)}
+            out = {"k": jnp.zeros(
+                (n, P, paged_row_lanes(cfg.latent_width)), kvdt)}
             if quantize_kv:
                 out["k_s"] = jnp.zeros((n, 2, paged_scale_lanes(P)),
                                        jnp.float32)
@@ -330,13 +348,15 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     return [layer(li, n) for li, n in enumerate(counts)]
 
 
-def _rows_to_pages(kk: str, x, P: int, stride: int | None = None):
+def _rows_to_pages(kk: str, x, P: int, stride: int | None = None,
+                   lanes: int | None = None):
     """Cache rows to pool-layout page blocks (:func:`_fresh_pages`):
     K/V ``(..., n * P, Hkv, D) -> (..., n, P, Hkv * D)``, a scale leaf
     (``kk`` ends in ``_s``) ``(..., n * P, Hkv) -> (..., n, Hkv,
     lanes)``, zeros in the lanes past P; a layer's pooled cells
     (``kp``) ``(..., n * c, Hkv, D) -> (..., n, c, Hkv * D)``, c = P /
-    ``stride`` cells a page."""
+    ``stride`` cells a page. ``lanes``: the pool leaf's minor axis,
+    where it is wider than the rows (a latent layer's: zeros behind)."""
     if kk == "kp":
         lead, (L, H, D) = x.shape[:-3], x.shape[-3:]
         c = P // stride
@@ -347,13 +367,19 @@ def _rows_to_pages(kk: str, x, P: int, stride: int | None = None):
         pad = [(0, 0)] * (x.ndim - 1) + [(0, paged_scale_lanes(P) - P)]
         return jnp.pad(x, pad)
     lead, (L, H, D) = x.shape[:-3], x.shape[-3:]
-    return x.reshape(lead + (L // P, P, H * D))
+    x = x.reshape(lead + (L // P, P, H * D))
+    if lanes is not None and lanes > H * D:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - H * D)])
+    return x
 
 
-def _pages_to_rows(kk: str, blk, Hkv: int, P: int):
+def _pages_to_rows(kk: str, blk, Hkv: int, P: int,
+                   width: int | None = None):
     """Inverse of :func:`_rows_to_pages`: ``(..., n, P, Hkv * D) ->
     (..., n * P, Hkv, D)`` and ``(..., n, H, lanes) -> (..., n * P,
-    H)`` (a scale leaf says itself how many scales a position has)."""
+    H)`` (a scale leaf says itself how many scales a position has).
+    ``width``: the values a row has, where the pool's minor axis is
+    wider (a latent layer's)."""
     lead, n = blk.shape[:-3], blk.shape[-3]
     if kk == "kp":  # a page's pooled cells, however many it has
         return blk.reshape(lead + (n * blk.shape[-2], Hkv,
@@ -361,7 +387,16 @@ def _pages_to_rows(kk: str, blk, Hkv: int, P: int):
     if kk.endswith("_s"):
         blk = jnp.swapaxes(blk[..., :P], -1, -2)
         return blk.reshape(lead + (n * P, blk.shape[-1]))
+    if width is not None:
+        blk = blk[..., :width]
     return blk.reshape(lead + (n * P, Hkv, blk.shape[-1] // Hkv))
+
+
+def _row_values(cfg: TransformerConfig, li: int) -> int | None:
+    """The values a row of layer ``li``'s pool holds where the pool's
+    minor axis is wider than they are (a latent layer's row in whole
+    lane tiles, :func:`_fresh_pages`); None: the row is the axis."""
+    return cfg.latent_width if cfg.mla(li) else None
 
 
 def _layer_kinds(cfg: TransformerConfig) -> tuple[tuple[int, ...],
@@ -520,21 +555,8 @@ def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
     S = k.shape[0]
     page = pt[jnp.arange(S), slot // P]  # (S,)
     off = slot % P
-
-    def put(c, u):  # K/V: one (Hkv * D,) row of its page
-        return c.at[page, off].set(u[:, 0].reshape(S, -1).astype(c.dtype))
-
-    def put_s(c, u):
-        # scales: one position of each head's row of its page. Whole
-        # (Hkv, lanes) blocks out and back (a few KB), the position
-        # replaced on the way: an element scatter into this leaf makes
-        # the compiler keep it heads-minor through the scan, and
-        # re-lay it out for every kernel call
-        blk = jnp.where(
-            jnp.arange(c.shape[2]) == off[:, None, None],
-            u[:, 0, :, None].astype(c.dtype), jnp.take(c, page, axis=0),
-        )
-        return c.at[page].set(blk)
+    put = functools.partial(_put_page_row, page=page, off=off)
+    put_s = functools.partial(_put_page_scales, page=page, off=off)
 
     pooled = {}
     if "kp" in cache_l:  # untouched here: :func:`_paged_pool_rows`
@@ -551,6 +573,62 @@ def _paged_write_rows(cache_l: dict, k, v, pt, slot, P: int):
         "v_s": put_s(cache_l["v_s"], vs),
         **pooled,
     }
+
+
+def _put_page_row(c, u, *, page, off):
+    """K/V: each row's one ``(Hkv * D,)`` row ``u`` (S, 1, ...) into
+    row ``off`` of its page of the pool leaf ``c``."""
+    S = u.shape[0]
+    return c.at[page, off].set(u[:, 0].reshape(S, -1).astype(c.dtype))
+
+
+def _put_page_scales(c, u, *, page, off):
+    """Scales: one position of each head's row of its page. Whole
+    (Hkv, lanes) blocks out and back (a few KB), the position replaced
+    on the way: an element scatter into this leaf makes the compiler
+    keep it heads-minor through the scan, and re-lay it out for every
+    kernel call."""
+    blk = jnp.where(
+        jnp.arange(c.shape[2]) == off[:, None, None],
+        u[:, 0, :, None].astype(c.dtype), jnp.take(c, page, axis=0),
+    )
+    return c.at[page].set(blk)
+
+
+def _paged_write_latent(cache_l: dict, row, pt, slot, P: int, R: int):
+    """:func:`_paged_write_rows` for a latent layer's int8 pages: each
+    slot's T rows ``row`` (S, T, 1, R + rope) through its page table,
+    the row's values with zeros up to the pool's lanes behind them and
+    its two scales as the two "heads" of ``k_s``. One row of every slot
+    at a time: a drafting step's two rows may share a page, and the
+    second's block of scales must hold the first's."""
+    S, T = row.shape[:2]
+    slot = slot.reshape(S, T)
+    leaves = _latent_leaves(row, R, True)
+    k, ks = cache_l["k"], cache_l["k_s"]
+    zeros = k.shape[-1] - row.shape[-1]
+    kq = jnp.pad(leaves["k"], ((0, 0), (0, 0), (0, 0), (0, zeros)))
+    for t in range(T):
+        at = dict(page=pt[jnp.arange(S), slot[:, t] // P],
+                  off=slot[:, t] % P)
+        k = _put_page_row(k, kq[:, t:t + 1], **at)
+        ks = _put_page_scales(ks, leaves["k_s"][:, t:t + 1], **at)
+    return {"k": k, "k_s": ks}
+
+
+def _paged_latent_rows(q, cache_l, pt, pos, scale, P: int, R: int):
+    """The absorbed queries q (S, T, H, R + rope) at positions ``pos``
+    ((S,) for T = 1, else (S, T)) over a latent layer's pages, through
+    the latent form of the paged kernel (ops/decode_attention.py):
+    ``S * T`` rows with their own positions, a slot's table row once
+    for each of its queries. Returns (S, T, H, R)."""
+    from ..ops.decode_attention import latent_decode_attention
+
+    S, T = q.shape[:2]
+    o = latent_decode_attention(
+        q.reshape((S * T, 1) + q.shape[2:]), cache_l, pos.reshape(S * T),
+        jnp.repeat(pt, T, axis=0) if T > 1 else pt, scale=scale, P=P, R=R)
+    return o.reshape((S, T) + o.shape[2:])
 
 
 def _paged_pool_rows(cache_l: dict, k, pt, slot, P: int) -> dict:
@@ -604,7 +682,8 @@ def _paged_select(q, cache_l, pt, pos, cfg, P: int):
     return pages, at
 
 
-def _paged_gather(cache_l: dict, pt, Hkv: int, P: int):
+def _paged_gather(cache_l: dict, pt, Hkv: int, P: int,
+                  width: int | None = None):
     """Materialize every slot's W-row ring view out of the page pool:
     one PAGE-BLOCK ``jnp.take`` per leaf — ``(S, max_pages)`` indices
     moving whole pages. Page p's rows are ring slots ``[j*P, (j+1)*P)``
@@ -612,13 +691,14 @@ def _paged_gather(cache_l: dict, pt, Hkv: int, P: int):
     (:func:`_pages_to_rows`), are EXACTLY the slot-ring layout
     ``(S, W, Hkv, ...)`` and the einsum path runs the unchanged dense
     ring math on it — dense and paged decode are the identical math by
-    construction, which is what the CPU parity tests lean on. Speed
+    construction, which is what the CPU parity tests lean on (``width``:
+    the row's values, where the pool's minor axis is wider). Speed
     note: this gather runs once per TICK (hoisted out of the decode
     scan — see ``_serving_scan_paged``; a per-step gather measured
     0.66x the slot tick). Null page-table entries resolve to page 0,
     whose rows are only ever reached by ``kpos < 0`` (masked) slots."""
     return {
-        kk: _pages_to_rows(kk, jnp.take(a, pt, axis=0), Hkv, P)
+        kk: _pages_to_rows(kk, jnp.take(a, pt, axis=0), Hkv, P, width)
         for kk, a in cache_l.items()
     }
 
@@ -634,7 +714,8 @@ def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int,
     which nothing reads unmasked."""
     return {
         kk: a.at[pt].set(
-            _rows_to_pages(kk, view_l[kk], P, stride).astype(a.dtype))
+            _rows_to_pages(kk, view_l[kk], P, stride,
+                           a.shape[-1]).astype(a.dtype))
         for kk, a in cache_l.items()
     }
 
@@ -669,8 +750,9 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     that got a row in a dropless expert layer, None elsewhere. A gated
     delta-rule layer's ``cache_l`` is every slot's state: one step of
     the recurrence a row, no position and no page. ``pos`` (S, T) with
-    x (S, T, D): T rows a slot, each written before any is attended
-    (the slot-ring and gathered-view paths; a drafting step's two)."""
+    x (S, T, D): T rows a slot, each written before any is attended (a
+    drafting step's two: the slot-ring and gathered-view paths, and a
+    latent layer's pages)."""
     h, mix = hc_pre(x, lp, cfg, "hc1")
     rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
                              table=cfg.rope_table)
@@ -680,16 +762,26 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
             x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
         return x, cache_l, hit
     if cfg.mla(li):
-        # every slot's ring view of its pages (the tick gathers them
-        # once, ``_serving_scan_paged``): the row goes to slot ``pos``
-        # of a ring as wide as the context budget, which never wraps
-        R, W = cfg.mla_kv_rank, cache_l["k"].shape[1]
-        x, cache_l = _latent_attend(
-            h, lp, cfg, rope, mix,
-            lambda row: _ring_write_rows(cache_l, row, None,
-                                         jnp.mod(pos, W), R),
-            lambda q, cl: _ring_attention_rows(
-                q, cl, pos, cfg.softmax_scale, latent=R))
+        R, scale = cfg.mla_kv_rank, cfg.softmax_scale
+        if paged is not None:
+            # kernel route: the row into its page in place, the pages a
+            # slot has filled read where they lie
+            pt, W, P = paged
+            write = lambda row: _paged_write_latent(
+                cache_l, row, pt, jnp.mod(pos, W), P, R)
+            attend = lambda q, cl: _paged_latent_rows(
+                q, cl, pt, pos, scale, P, R)
+        else:
+            # every slot's ring (the gather route: its view of its
+            # pages, gathered once a tick, ``_serving_scan_paged``):
+            # the row goes to slot ``pos`` of a ring as wide as the
+            # context budget, which never wraps
+            W = cache_l["k"].shape[1]
+            write = lambda row: _ring_write_rows(
+                cache_l, row, None, jnp.mod(pos, W), R)
+            attend = lambda q, cl: _ring_attention_rows(
+                q, cl, pos, scale, latent=R)
+        x, cache_l = _latent_attend(h, lp, cfg, rope, mix, write, attend)
         with jax.named_scope("decode_mlp"):
             x, _, hit = ffn_half(x, lp, cfg, li)
         return x, cache_l, hit
@@ -737,6 +829,13 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     return x, cache_l, hit
 
 
+def _paged_layer(paged, li: int):
+    """Cache layer ``li``'s ``(page table, ring width, PAGE_TOKENS)``
+    out of a tick's ``(per-layer page tables, PAGE_TOKENS)``."""
+    pt = paged[0][li]
+    return pt, pt.shape[1] * paged[1], paged[1]
+
+
 def _serving_hidden(params, tok, pos, caches, cfg, *, kv_slice=None,
                     tp_psum=False, use_kernel=False, paged=None):
     """The model's layers on T rows a slot: (tok (S, T), pos (S,) for
@@ -751,8 +850,7 @@ def _serving_hidden(params, tok, pos, caches, cfg, *, kv_slice=None,
     for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
         paged_l = None
         if paged is not None and not cfg.state(li):
-            pt = paged[0][li]
-            paged_l = (pt, pt.shape[1] * paged[1], paged[1])
+            paged_l = _paged_layer(paged, li)
         x, cl, hit = _serving_layer(
             x, lp, cl, pos, cfg, li, kv_slice=kv_slice, tp_psum=tp_psum,
             use_kernel=use_kernel, paged=paged_l,
@@ -871,16 +969,18 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
 
 
 def _draft_step(params, tok, pos, done, caches, cfg, eos_id, keys,
-                temperature, top_k):
+                temperature, top_k, paged=None):
     """One drafting step for all S slots on ring caches (or gathered
-    views): ``tok`` (S, 2) is ``[t_p, d]``. Returns ``(tok, pos, done,
-    caches)`` advanced by one or two positions a slot and ``(out,
-    hits, mtp_hits)``: ``out`` (S, 4) int32 holds ``[x1, x2, accepted,
-    d]`` and the hits are ``_serving_layer``'s, summed over the
-    model's expert layers and of the module's."""
+    views; ``paged``, :func:`_serving_hidden`'s: on latent layers'
+    pages in place): ``tok`` (S, 2) is ``[t_p, d]``. Returns ``(tok,
+    pos, done, caches)`` advanced by one or two positions a slot and
+    ``(out, hits, mtp_hits)``: ``out`` (S, 4) int32 holds ``[x1, x2,
+    accepted, d]`` and the hits are ``_serving_layer``'s, summed over
+    the model's expert layers and of the module's."""
     n, dt = cfg.n_layers, tok.dtype
     pos2 = pos[:, None] + jnp.arange(2, dtype=pos.dtype)
-    x, new, hits = _serving_hidden(params, tok, pos2, caches, cfg)
+    x, new, hits = _serving_hidden(params, tok, pos2, caches, cfg,
+                                   paged=paged)
     lg = head_logits(params, x, cfg)  # (S, 2, V)
     pick = functools.partial(_pick_rows, keys=keys, temperature=temperature,
                              top_k=top_k, dtype=dt)
@@ -894,8 +994,9 @@ def _draft_step(params, tok, pos, done, caches, cfg, eos_id, keys,
     with jax.named_scope("mtp"):
         block = params["mtp"]["block"]
         h = mtp_input(params, x, jnp.stack([x1, x2], axis=1), cfg)
-        h, cl, mtp_hits = _serving_layer(h, block, caches[n], pos2, cfg,
-                                         n - 1)
+        h, cl, mtp_hits = _serving_layer(
+            h, block, caches[n], pos2, cfg, n - 1,
+            paged=None if paged is None else _paged_layer(paged, n))
         new.append(cl)
         # the head once, on the row at the last certain position
         last = jnp.where(accept[:, None, None], h[:, 1:], h[:, :1])
@@ -911,20 +1012,23 @@ def _draft_step(params, tok, pos, done, caches, cfg, eos_id, keys,
 
 def _scan_body_draft(params, tok, pos, done, caches, cfg, eos_id, n_inner,
                      keys, *, temperature=0.0, top_k=None,
-                     use_kernel=False):
+                     use_kernel=False, paged=None):
     """:func:`_scan_body` for a drafting scheduler: ``n_inner``
-    :func:`_draft_step` under one scan (over the einsum alone: the
-    kernel takes one query a slot). Returns (tok (S, 2), pos, done,
-    caches, (out (S, n_inner, 4), counters (n_inner, c) int32)); the
-    counters are, step by step, the model's expert layers' hits (two
-    columns where the layers hold a share of their experts: the pairs
-    that fell on held ones) and then the module's, the same columns."""
+    :func:`_draft_step` under one scan. Over the einsum on rings and
+    gathered views; with ``paged`` on latent layers' pages, where the
+    kernel takes a slot's two queries as two rows (the K/V kernels take
+    one query a slot: ``use_kernel`` without ``paged`` is refused).
+    Returns (tok (S, 2), pos, done, caches, (out (S, n_inner, 4),
+    counters (n_inner, c) int32)); the counters are, step by step, the
+    model's expert layers' hits (two columns where the layers hold a
+    share of their experts: the pairs that fell on held ones) and then
+    the module's, the same columns."""
 
-    assert not use_kernel
+    assert (paged is not None) == bool(use_kernel)
 
     def step(carry, _):
         carry, (out, hits, mtp_hits) = _draft_step(
-            params, *carry, cfg, eos_id, keys, temperature, top_k)
+            params, *carry, cfg, eos_id, keys, temperature, top_k, paged)
         count = [jnp.atleast_1d(h).astype(jnp.int32)
                  for h in (hits, mtp_hits) if h is not None]
         return carry, (out, jnp.concatenate(count) if count
@@ -972,9 +1076,14 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
     arena; ``W = max_pages * P`` is recovered from the table shape so
     one compiled program serves any pool size at a given (cfg, P).
 
-    ``use_kernel=True`` (the int8 route) reads pages IN PLACE every
+    ``use_kernel=True`` (the int8 route: K/V layers of whole GQA
+    groups at a lane-aligned head size, or latent layers alone with a
+    latent of whole lane tiles, drafting or not;
+    ``decode._paged_kernel_possible``) reads pages IN PLACE every
     step — the Pallas page-table mode's whole point. The einsum
-    fallback instead hoists the indirection OUT of the scan: the table
+    fallback (any cache in the model's dtype, widths off the lane
+    tile, latent layers beside K/V layers, fewer slots than
+    ``KERNEL_MIN_BATCH``) instead hoists the indirection OUT of the scan: the table
     is tick-invariant, so each layer's W-row ring view gathers ONCE,
     the unchanged dense ring scan runs on the views (the paged einsum
     tick IS the slot-ring tick on a gathered arena — parity by
@@ -994,7 +1103,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         # reads through its own kind's (``_layer_tables``)
         pts = _layer_tables(cfg, pt)
         if use_kernel:
-            return _scan_body(
+            return _tick_body(cfg)(
                 params, tok, pos, done, caches, cfg, eos_id, n_inner,
                 keys, temperature=temperature, top_k=top_k,
                 use_kernel=True, paged=(pts, P),
@@ -1003,7 +1112,8 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
             # (a recurrent layer's state is no page: it goes through)
             views = [
                 cl if cfg.state(li)
-                else _paged_gather(cl, t, cfg.cache_heads(li), P)
+                else _paged_gather(cl, t, cfg.cache_heads(li), P,
+                                   _row_values(cfg, li))
                 for li, (cl, t) in enumerate(zip(caches, pts))
             ]
         tok, pos, done, views, toks = _tick_body(cfg)(
@@ -1040,9 +1150,10 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
         valid = jnp.arange(R) < ell
         nb = -(-R // P)  # pages that cover rows [0, R)
 
-        def seed(kk, c, pg, row, heads):
+        def seed(kk, c, pg, row, li):
             g = _pages_to_rows(
-                kk, jnp.take(pg, row[:nb], axis=0), heads, P
+                kk, jnp.take(pg, row[:nb], axis=0), cfg.cache_heads(li),
+                P, _row_values(cfg, li),
             )[:R]  # (R, ...)
             g = jnp.where(
                 valid.reshape((R,) + (1,) * (g.ndim - 1)), g, 0
@@ -1052,8 +1163,7 @@ def _seed_admit_paged(cfg: TransformerConfig, R: int, P: int):
             )
 
         return [
-            {kk: seed(kk, cl[kk], pl[kk], row, cfg.cache_heads(li))
-             for kk in cl}
+            {kk: seed(kk, cl[kk], pl[kk], row, li) for kk in cl}
             for li, (cl, pl, row) in enumerate(zip(
                 cache, pages, _layer_tables(cfg, pt_row)))
         ]
@@ -1075,7 +1185,8 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
     @jax.jit
     def serving_gather_ring(caches, pt_row):
         return [
-            _paged_gather(cl, row[None], cfg.cache_heads(li), P)
+            _paged_gather(cl, row[None], cfg.cache_heads(li), P,
+                          _row_values(cfg, li))
             for li, (cl, row) in enumerate(zip(
                 caches, _layer_tables(cfg, pt_row)))
         ]
@@ -1104,8 +1215,8 @@ def _place_paged(cfg: TransformerConfig, P: int):
             {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
              for kk in c} if cfg.state(li) else
             {kk: c[kk].at[row].set(
-                _rows_to_pages(kk, r[kk][0], P,
-                               cfg.sparse_stride).astype(c[kk].dtype))
+                _rows_to_pages(kk, r[kk][0], P, cfg.sparse_stride,
+                               c[kk].shape[-1]).astype(c[kk].dtype))
              for kk in c}
             for li, (c, r, row) in enumerate(zip(caches, ring, rows))
         ]
@@ -2186,7 +2297,10 @@ class ServingScheduler:
         if self.paged:
             self.use_kernel = (
                 _paged_kernel_possible(cfg, self.quantize_kv, self.P)
-                and _route_kernel(self.S) and draft is None
+                and _route_kernel(self.S)
+                # a drafting step's two queries a slot: the latent form
+                # takes them as two rows, the K/V form has one a slot
+                and (draft is None or cfg.latent_layers)
             )
             self._scan = _serving_scan_paged(
                 cfg, self.n_inner, eos_id, self.temperature, top_k,

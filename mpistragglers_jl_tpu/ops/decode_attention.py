@@ -66,6 +66,11 @@ kv heads (static row/lane slices, one MXU dot per head group):
   ``_paged_gather`` + the einsum rows) stay numerically
   interchangeable.
 
+* a latent-attention layer's pages take the paged walk in a form of
+  their own (``latent_decode_attention``, below its siblings): the
+  pool's ONE int8 row a position is key and value both, under the
+  absorbed query's heads as one q tile.
+
 Inference-only: no VJP (the cache is never differentiated through).
 Interpret mode on non-TPU backends keeps the path testable on the CI
 mesh, same as the flash kernels.
@@ -87,7 +92,9 @@ _LANE = 128
 _SUB = 8  # TPU sublane tile: each GQA group pads to whole tiles of it
 
 __all__ = ["quantized_decode_attention", "paged_block_viable",
-           "paged_scale_lanes", "paged_select_attention"]
+           "paged_scale_lanes", "paged_select_attention",
+           "latent_decode_attention", "latent_pages_per_step",
+           "paged_row_lanes"]
 
 
 # Scoped-VMEM budget per (block row x kv head), CALIBRATED on the
@@ -141,6 +148,17 @@ def paged_scale_lanes(page_tokens: int) -> int:
     return -(-int(page_tokens) // _LANE) * _LANE
 
 
+def paged_row_lanes(width: int) -> int:
+    """Minor axis of a latent layer's page pool ``(n_pages, P, lanes)``:
+    its row of ``width`` values rounded up to whole 128-lane tiles (576
+    -> 640; the padding is zeros that nothing reads). That is what the
+    device stores either way, and a copy
+    out of HBM moves whole tiles of the minor axis alone: Mosaic refuses
+    the page's 576 lanes of 640 ("slice shape must be aligned to
+    tiling"), and takes the row it is declared whole."""
+    return -(-int(width) // _LANE) * _LANE
+
+
 def _pages_per_step(max_pages: int, P: int, Hkv: int, D: int,
                     G: int) -> int | None:
     """Pages to a block of the paged form's loop: the largest of 8, 4,
@@ -163,15 +181,53 @@ def _paged_kernel(pos_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
     """One row of the batch a grid step: the pools stay in HBM and the
     kernel copies in only the row's LIVE pages (those up to its
     position; every page once the ring has wrapped), ``n`` a block,
-    the next block's copies in flight while this one's dots run. A
-    table entry that is never visited costs nothing, which is what
-    makes a 64-entry table cheap for a request that fills five.
+    the next block's copies in flight while this one's dots run
+    (:func:`_walk_live_pages`). A table entry that is never visited
+    costs nothing, which is what makes a 64-entry table cheap for a
+    request that fills five.
 
     ``kbuf``/``vbuf``: ``(2, n, P, Hkv*D)`` int8, ``ksbuf``/``vsbuf``:
     ``(2, n, Hkv, lanes)`` float32, two buffers each; ``sem``: one DMA
     semaphore a buffer. A page's scales arrive as ``(Hkv, lanes)``,
     its P positions on the first lanes of head h's row: a slice is the
     row the scores want."""
+
+    def update(buf, mask):
+        def rows(sbuf):  # head h's (1, n * P) row of a block's scales
+            pages = [sbuf[buf, i] for i in range(n)]  # n x (Hkv, lanes)
+            return lambda h: _join([x[h:h + 1, :P] for x in pages], 1)
+
+        _update(
+            q_ref,
+            (_join([kbuf[buf, i] for i in range(n)], 0),
+             _join([vbuf[buf, i] for i in range(n)], 0),
+             rows(ksbuf), rows(vsbuf)),
+            mask, acc, m_sc, l_sc, scale=scale, Hkv=Hkv, D=D, G=G,
+        )
+
+    _walk_live_pages(
+        pos_ref, pt_ref, ((k_hbm, kbuf), (ks_hbm, ksbuf), (v_hbm, vbuf),
+                          (vs_hbm, vsbuf)), sem, update, o_ref, acc, m_sc,
+        l_sc, n=n, P=P, max_pages=max_pages)
+
+
+def _join(parts, axis):
+    """A block's pages side by side (one page: itself)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+def _walk_live_pages(pos_ref, pt_ref, pools, sem, update, o_ref, acc, m_sc,
+                     l_sc, *, n, P, max_pages, wraps=True):
+    """The page walk of one row of the batch (grid step ``b``), shared
+    by the paged forms: zero the softmax's carry, copy in the row's
+    live pages ``n`` a block out of every ``(pool in HBM, (2, n, ...)
+    buffer)`` of ``pools``, double-buffered, call ``update(buf, mask)``
+    on each block as it lands (``mask`` ``(1, n * P)``: the block's
+    ring-valid positions), and write the normalised result. ``wraps``
+    False: the ring is as wide as the context budget and no position
+    reaches its end, so a block's rows are valid up to the position
+    alone, and ``n`` need not divide the table (the last block repeats
+    the last live page behind it, masked)."""
     b = pl.program_id(0)
     pos = pos_ref[b]
     live = jnp.minimum(pos // P + 1, max_pages)  # pages with a live row
@@ -183,14 +239,10 @@ def _paged_kernel(pos_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
         out = []
         for i in range(n):
             page = pt_ref[b, jnp.minimum(blk * n + i, live - 1)]
-            for pool, dst in ((k_hbm, kbuf), (ks_hbm, ksbuf),
-                              (v_hbm, vbuf), (vs_hbm, vsbuf)):
+            for pool, dst in pools:
                 out.append(pltpu.make_async_copy(
                     pool.at[page], dst.at[buf, i], sem.at[buf]))
         return out
-
-    def join(parts, axis):
-        return parts[0] if n == 1 else jnp.concatenate(parts, axis=axis)
 
     acc[:] = jnp.zeros_like(acc)
     m_sc[:] = jnp.full_like(m_sc, _NEG)
@@ -210,19 +262,8 @@ def _paged_kernel(pos_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
             c.wait()
         kpos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         # ring validity: slot s <= pos, or the ring has wrapped
-        mask = jnp.logical_or(kpos <= pos, pos >= max_pages * P)
-
-        def rows(sbuf):  # head h's (1, n * P) row of a block's scales
-            pages = [sbuf[buf, i] for i in range(n)]  # n x (Hkv, lanes)
-            return lambda h: join([x[h:h + 1, :P] for x in pages], 1)
-
-        _update(
-            q_ref,
-            (join([kbuf[buf, i] for i in range(n)], 0),
-             join([vbuf[buf, i] for i in range(n)], 0),
-             rows(ksbuf), rows(vsbuf)),
-            mask, acc, m_sc, l_sc, scale=scale, Hkv=Hkv, D=D, G=G,
-        )
+        update(buf, jnp.logical_or(kpos <= pos, pos >= max_pages * P)
+               if wraps else kpos <= pos)
         return carry
 
     jax.lax.fori_loop(0, (live + n - 1) // n, block, 0)
@@ -333,6 +374,15 @@ def _update_head(q_ref, h, kblk, vblk, lanes, k_scale, v_scale, mask, acc,
         preferred_element_type=jnp.float32,
     ) * scale  # (G, bk)
     s = s * k_scale
+    _softmax_update(s, rows, vblk, lanes, v_scale, mask, acc, m_sc, l_sc)
+
+
+def _softmax_update(s, rows, vblk, lanes, v_scale, mask, acc, m_sc, l_sc):
+    """The online softmax's step for the q tile's ``rows``: their
+    scores ``s`` ``(G, bk)`` of a k-block, masked here, against the
+    block's values, the ``lanes`` of ``vblk`` (int8) with their ``(1,
+    bk)`` row of scales."""
+    G = s.shape[0]
     s = jnp.where(mask, s, _NEG)
     m_prev = m_sc[rows, :1]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -370,6 +420,13 @@ def _untile_o(o3, q, Hkv: int, G: int):
     """Drop each group's padding rows: (B, Hkv*G, D) -> (B, 1, H, D)."""
     B, _, H, D = q.shape
     return o3.reshape(B, Hkv, G, D)[:, :, :H // Hkv].reshape(B, 1, H, D)
+
+
+def _row(b, *prefetched):
+    """Index map of the paged forms' q and result blocks: grid step
+    ``b``'s own row, whatever the scalar-prefetched tables say."""
+    del prefetched
+    return (b, 0, 0)
 
 
 def _scratch(rows: int, D: int) -> list:
@@ -534,10 +591,6 @@ def paged_decode_attention(q, cache_l: dict, pos, page_table, *, scale,
         Hkv=Hkv, D=D, G=G,
     )
 
-    def _row(b, pos_ref, pt_ref):
-        del pos_ref, pt_ref
-        return (b, 0, 0)
-
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -581,6 +634,151 @@ def _in_hbm(pool, interpret: bool):
         pool, pltpu.HBM)
 
 
+# A latent-attention layer's pages (models/decode.py ``_latent_leaves``):
+# ONE int8 row a position for all heads, ``[latent R | rotated key]``,
+# with a float32 scale for each of the two parts and no ``v``. The
+# kernel below is the paged form with that row as key AND value: one
+# K/V "head", the q tile the absorbed query's H rows, the scores the
+# sum of the two parts' products each under its own scale, the values
+# the row's first R lanes. R is whole lane tiles and so is the pool's
+# row (``paged_row_lanes``: zeros behind the rotated key, never read),
+# so a page copies whole and the row splits at a tile's edge of a block
+# already in VMEM: nothing is sliced in HBM, and the query comes as it
+# is. Walk, copies and softmax are the paged form's own.
+
+
+def _paged_latent_kernel(pos_ref, pt_ref, q_ref, k_hbm, ks_hbm, o_ref, kbuf,
+                         ksbuf, sem, acc, m_sc, l_sc, *, n, P, max_pages,
+                         scale, R):
+    """One row of the batch a grid step. ``kbuf``: ``(2, n, P, lanes)``
+    int8; ``ksbuf``: ``(2, n, 2, scale lanes)`` float32, a page's
+    latent scales on row 0 and its rotated key's on row 1."""
+
+    def update(buf, mask):
+        kblk = _join([kbuf[buf, i] for i in range(n)], 0)
+        pages = [ksbuf[buf, i] for i in range(n)]  # n x (2, lanes)
+        latent_s, rope_s = (  # each part's (1, n * P) row of scales
+            _join([x[j:j + 1, :P] for x in pages], 1) for j in range(2))
+        q = q_ref[0]  # (H, R + rope)
+
+        def part(lanes, part_s):  # one part's scores under its scale
+            return jax.lax.dot_general(
+                q[:, lanes], kblk[:, lanes].astype(q.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale * part_s
+
+        s = (part(slice(None, R), latent_s)
+             + part(slice(R, q.shape[1]), rope_s))
+        _softmax_update(s, slice(None), kblk, slice(None, R), latent_s,
+                        mask, acc, m_sc, l_sc)
+
+    _walk_live_pages(
+        pos_ref, pt_ref, ((k_hbm, kbuf), (ks_hbm, ksbuf)), sem, update,
+        o_ref, acc, m_sc, l_sc, n=n, P=P, max_pages=max_pages, wraps=False)
+
+
+def latent_pages_per_step(max_pages: int, P: int, width: int,
+                          H: int) -> int | None:
+    """Pages to a block of the latent form's loop: the largest of 8, 4,
+    2, 1 whose joined k-block fits the calibrated VMEM budget under a q
+    tile of ``H`` rows (the row of ``width`` values is key and value
+    both, so the K/V budget a lane bounds it from above) and that a
+    table of ``max_pages`` entries can fill at least half. It need not
+    divide the table (68 -> 8, 13 -> 8, 3 -> 4): a latent layer's ring
+    never wraps. None: not even one page fits, and the layer keeps the
+    gather route."""
+    lanes, G = paged_row_lanes(width), _group_tile(H)
+    for n in (8, 4, 2, 1):
+        if (n < 2 * max_pages
+                and n * P * _row_head_bytes(lanes, G) <= _VMEM_CAP):
+            return n
+    return None
+
+
+def latent_decode_attention(q, cache_l: dict, pos, page_table, *, scale,
+                            P: int, R: int, interpret: bool | None = None):
+    """Single-query attention of the absorbed query over a latent
+    layer's page pool: q ``(B, 1, H, R + rope)``; ``cache_l`` {"k"}
+    int8 ``(n_pages, P, paged_row_lanes(R + rope))`` + {"k_s"} float32
+    ``(n_pages, 2, paged_scale_lanes(P))``, shared by all rows; ``pos``
+    ``(B,)`` each row's position; ``page_table`` ``(B, max_pages)``
+    int32. Returns ``(B, 1, H, R)`` in q's dtype: the online-softmax
+    evaluation of ``_ring_attention_rows(latent=R)`` on the rows'
+    gathered rings (models/serving.py), reading the pages a row has
+    filled where they lie. Every position lies inside the table's
+    ``max_pages * P`` rows (a latent layer's ring is as wide as the
+    context budget and never wraps). Two rows of the batch may name one
+    table row at two positions (a drafting step's two queries a slot).
+
+    The pool checked against the layout the kernel reads and the pages
+    a block worked out from the shapes, then the jitted call
+    (:func:`paged_latent_attention`, the name a device trace shows)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    T, H, width = q.shape[1:]
+    kc, ks = cache_l["k"], cache_l["k_s"]
+    lanes = paged_row_lanes(width)
+    if T != 1:
+        raise ValueError(f"decode kernel is single-query, got T={T}")
+    if (kc.shape[1:] != (P, lanes) or not 0 < R < width or R % _LANE
+            or ks.shape[1:] != (2, paged_scale_lanes(P))):
+        raise ValueError(
+            f"latent page pool leaves {kc.shape} / {ks.shape} are not "
+            f"(pages, {P}, {lanes}) / (pages, 2, {paged_scale_lanes(P)}) "
+            f"with the latent's {R} of the row's {width} whole lane tiles"
+        )
+    n = latent_pages_per_step(page_table.shape[1], P, width, H)
+    if n is None:
+        raise ValueError(
+            f"a page of {P} rows of {lanes} under {H} query rows does "
+            "not fit the kernel's VMEM budget; use the gather route")
+    return paged_latent_attention(q, cache_l, pos, page_table, scale=scale,
+                                  P=P, R=R, n=n, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "P", "R", "n", "interpret"))
+def paged_latent_attention(q, cache_l: dict, pos, page_table, *, scale,
+                           P: int, R: int, n: int, interpret: bool):
+    """The latent form's pallas_call (:func:`latent_decode_attention`
+    checks and sizes it): grid ``(B,)``, the pool left in HBM, ``n``
+    pages a block. Jitted for the reason :func:`paged_decode_attention`
+    is."""
+    B, _, H, width = q.shape
+    kc, ks = cache_l["k"], cache_l["k_s"]
+    lanes, G = kc.shape[2], _group_tile(H)
+    q3 = q[:, 0]
+    if H < G:  # rows to the sublane tile (tiny: the query)
+        q3 = jnp.pad(q3, ((0, 0), (0, G - H), (0, 0)))
+    kern = functools.partial(
+        _paged_latent_kernel, n=n, P=P, max_pages=page_table.shape[1],
+        scale=scale, R=R)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, G, width), _row), hbm, hbm],
+        out_specs=pl.BlockSpec((1, G, R), _row),
+        scratch_shapes=[
+            pltpu.VMEM((2, n, P, lanes), kc.dtype),
+            pltpu.VMEM((2, n, 2, ks.shape[2]), ks.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ] + _scratch(G, R),
+    )
+    o3 = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=_sds((B, G, R), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+    )(jnp.asarray(pos, jnp.int32).reshape(B), page_table.astype(jnp.int32),
+      q3, _in_hbm(kc, interpret), _in_hbm(ks, interpret))
+    return o3[:, None, :H]
+
+
 # A selection of key blocks (models/transformer.py ``sparse_pick``): each
 # K/V head of each row attends its OWN short list of pages. The kernel
 # below is the paged form with the list a head in place of the table a
@@ -603,9 +801,6 @@ def _paged_select_kernel(at_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
     acc[:] = jnp.zeros_like(acc)
     m_sc[:] = jnp.full_like(m_sc, _NEG)
     l_sc[:] = jnp.zeros_like(l_sc)
-
-    def join(parts, axis):
-        return parts[0] if n == 1 else jnp.concatenate(parts, axis=axis)
 
     for h in range(Hkv):
         at = at_ref[b, h]
@@ -641,11 +836,11 @@ def _paged_select_kernel(at_ref, pt_ref, q_ref, k_hbm, ks_hbm, v_hbm, vs_hbm,
                 c.wait()
             kpos = blk * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (1, bk), 1)
-            row = lambda sbuf: join(
+            row = lambda sbuf: _join(
                 [sbuf[buf, i][h:h + 1, :P] for i in range(n)], 1)
             _update_head(
-                q_ref, h, join([kbuf[buf, i] for i in range(n)], 0),
-                join([vbuf[buf, i] for i in range(n)], 0), slice(None),
+                q_ref, h, _join([kbuf[buf, i] for i in range(n)], 0),
+                _join([vbuf[buf, i] for i in range(n)], 0), slice(None),
                 row(ksbuf), row(vsbuf), kpos <= at, acc, m_sc, l_sc,
                 scale=scale, G=G)
             return carry
@@ -689,10 +884,6 @@ def paged_select_attention(q, cache_l: dict, at, pages, *, scale, P: int,
     kern = functools.partial(
         _paged_select_kernel, n=n, P=P, width=width, scale=scale, Hkv=Hkv,
         D=D, G=G)
-
-    def _row(b, at_ref, pt_ref):
-        del at_ref, pt_ref
-        return (b, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(

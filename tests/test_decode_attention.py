@@ -8,6 +8,7 @@ the same configs the flagship serves are what the CI mesh tests
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -266,6 +267,175 @@ def test_paged_kernel_refuses_a_pool_in_another_layout():
             q, rows, posv, 1.0, ring=True, page_table=pt,
             page_tokens=16, interpret=True,
         )
+
+
+# -- the latent form: one int8 row a position, key and value both -------------
+
+LR, LROPE, LP = 128, 8, 16  # the latent's width, rotated dims, page rows
+
+
+def _latent_case(B, H, max_pages, pos, seed, poison=True):
+    """A latent layer's page pool in the serving layout
+    (``_fresh_pages``: the row's values in whole lane tiles, its two
+    scales as two "heads" of ``k_s``), tables as :func:`_paged_case`
+    lays them: row 1 shares row 0's first page, the last row's last
+    entry is the null page where no position reaches it, page 0
+    poisoned. Returns (q, pool, page table, positions)."""
+    from mpistragglers_jl_tpu.models.decode import _latent_leaves
+    from mpistragglers_jl_tpu.models.serving import (
+        _rows_to_pages,
+        paged_row_lanes,
+    )
+
+    width = LR + LROPE
+    rng = np.random.default_rng(seed)
+    n_pages = B * max_pages + 1
+    rows = _latent_leaves(jnp.asarray(rng.standard_normal(
+        (1, n_pages * LP, 1, width)), jnp.float32), LR, True)
+    if poison:
+        rows["k_s"] = rows["k_s"].at[:, :LP].set(1e9)
+    pool = {kk: _rows_to_pages(kk, a[0], LP, lanes=paged_row_lanes(width))
+            for kk, a in rows.items()}
+    assert pool["k"].shape == (n_pages, LP, 256)
+    pt = rng.permutation(np.arange(1, n_pages)).reshape(B, max_pages)
+    if B > 1:
+        pt[1, 0] = pt[0, 0]
+    pos = np.asarray(pos, np.int32)
+    if pos.max(initial=0) < (max_pages - 1) * LP:
+        pt[-1, -1] = 0
+    T = pos.shape[1] if pos.ndim > 1 else 1  # queries a row
+    q = jnp.asarray(rng.standard_normal((B, T, H, width)), jnp.float32)
+    return q, pool, jnp.asarray(pt, jnp.int32), jnp.asarray(pos)
+
+
+def _latent_oracle(q, pool, pt, pos):
+    """The gather route of a latent layer's tick: every row's ring
+    view out of the pool, then ``_ring_attention_rows(latent=R)``."""
+    from mpistragglers_jl_tpu.models.serving import (
+        _paged_gather,
+        _ring_attention_rows,
+    )
+
+    view = _paged_gather(pool, pt, 1, LP, LR + LROPE)
+    return _ring_attention_rows(q, view, pos, 0.11, latent=LR)
+
+
+# (query rows, table entries, pages a grid step, positions of three
+# rows): a row at 0, at a page's last row and at the next page's first;
+# a slot inside its first page beside one at the table's last row
+# (every page live); tables that 8 pages a step do not divide, 68
+# entries (the null page behind a short request's budget) and 13: the
+# last block repeats the last live page behind it; 3 entries, 4 pages a
+# step; one entry. 32 and 128 query rows are the two cells' head
+# counts; 4 are padded to a sublane tile
+LATENT = [
+    (32, 8, 8, (0, LP - 1, LP)),
+    (32, 8, 8, (5, 8 * LP - 1, 3 * LP)),
+    (32, 68, 8, (3, 700, 68 * LP - 1)),
+    (32, 68, 8, (17, 2 * LP - 1, 40)),
+    (32, 13, 8, (0, 100, 13 * LP - 1)),
+    (32, 3, 4, (0, 2 * LP, 3 * LP - 1)),
+    (32, 1, 1, (0, 7, LP - 1)),
+    (128, 68, 8, (3, 700, 68 * LP - 1)),
+    (128, 13, 8, (LP, 100, 13 * LP - 1)),
+    (4, 8, 8, (5, 8 * LP - 1, 3 * LP)),
+]
+
+
+@pytest.mark.parametrize("H,max_pages,n,pos", LATENT)
+def test_latent_kernel_matches_gather_route(H, max_pages, n, pos):
+    """The latent form of the paged kernel against the einsum over the
+    gathered rings of the same int8 pages."""
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        latent_pages_per_step,
+        latent_decode_attention,
+    )
+
+    assert latent_pages_per_step(max_pages, LP, LR + LROPE, H) == n
+    q, pool, pt, posv = _latent_case(3, H, max_pages, pos,
+                                     seed=max_pages + pos[1])
+    got = latent_decode_attention(q, pool, posv, pt, scale=0.11, P=LP, R=LR,
+                                 interpret=True)
+    assert got.shape == (3, 1, H, LR)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_latent_oracle(q, pool, pt, posv)),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("pos", [(0, LP - 2, 77), (LP - 1, 3 * LP, 8 * LP - 2)])
+def test_latent_kernel_takes_a_drafting_steps_two_rows(pos):
+    """A drafting step's ``(S, 2)`` queries at ``(p, p + 1)``: the
+    kernel's ``2 S`` rows, a slot's table row once for each, against
+    the einsum's two queries a slot over the same pages (both rows in
+    the pages: the first must not see the second)."""
+    from mpistragglers_jl_tpu.models.serving import _paged_latent_rows
+
+    pos2 = np.asarray(pos, np.int32)[:, None] + np.arange(2, dtype=np.int32)
+    q, pool, pt, posv = _latent_case(3, 32, 8, pos2, seed=int(pos2.sum()))
+    assert q.shape[:2] == (3, 2)
+    got = _paged_latent_rows(q, pool, pt, posv, 0.11, LP, LR)
+    want = _latent_oracle(q, pool, pt, posv)
+    assert got.shape == want.shape == (3, 2, 32, LR)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_latent_kernel_on_a_retired_slots_null_table():
+    """A retired slot still ticks over a table of null pages at a
+    position past its end: its row reads page 0 alone, as the einsum's
+    does, and leaves the other rows' results where they were."""
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        latent_decode_attention,
+    )
+
+    q, pool, pt, posv = _latent_case(3, 32, 8, (9, 100, 50), seed=4,
+                                     poison=False)
+    call = lambda t: latent_decode_attention(
+        q, pool, posv, t, scale=0.11, P=LP, R=LR, interpret=True)
+    retired = pt.at[1].set(0)
+    got = call(retired)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_latent_oracle(q, pool, retired, posv)),
+        atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got[::2]),
+                                  np.asarray(call(pt)[::2]))
+
+
+def test_latent_kernel_reads_no_row_past_the_position():
+    """Rows behind a slot's position (a rejected draft's stale row, the
+    rest of its page, the pages behind) are never read: poison them."""
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        latent_decode_attention,
+    )
+
+    q, pool, pt, posv = _latent_case(1, 32, 8, (LP + 3,), seed=2)
+    page, behind = pt[0, 1], pt[0, 2:]
+    dirty = {"k": pool["k"].at[page, 4:].set(127).at[behind].set(127),
+             "k_s": pool["k_s"].at[page, :, 4:].set(1e9)
+                               .at[behind].set(1e9)}
+    call = lambda pl_: latent_decode_attention(
+        q, pl_, posv, pt, scale=0.11, P=LP, R=LR, interpret=True)
+    np.testing.assert_array_equal(np.asarray(call(pool)),
+                                  np.asarray(call(dirty)))
+
+
+def test_latent_kernel_refuses_what_it_cannot_read():
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        latent_pages_per_step,
+        latent_decode_attention,
+    )
+
+    q, pool, pt, posv = _latent_case(2, 32, 8, (3, 40), seed=1)
+    call = functools.partial(latent_decode_attention, scale=1.0, P=LP,
+                             interpret=True)
+    with pytest.raises(ValueError, match="latent page pool leaves"):
+        call(q, {**pool, "k": pool["k"][..., :LR + LROPE]}, posv, pt, R=LR)
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        call(q, pool, posv, pt, R=LR - 8)
+    with pytest.raises(ValueError, match="single-query"):
+        call(jnp.concatenate([q, q], axis=1), pool, posv, pt, R=LR)
+    # a page too large for the budget under 128 query rows
+    assert latent_pages_per_step(8, 2048, 576, 128) is None
 
 
 def test_ring_rejects_window():

@@ -142,6 +142,96 @@ def test_qwen3_next_tick_leaves_states_and_pools_where_they_lie(one_chip):
         assert not moved, moved[:3]
 
 
+# (rows of the call, absorbed query heads, table entries, pool pages):
+# Xing4.0's 16 slots of 32 heads on a 68-entry table (4 pages a block),
+# DeepSeek-V3's drafting step, two rows a slot of 128 heads on 13
+# entries (a page a block)
+LATENT_CELLS = [(16, 32, 68, 1089), (32, 128, 13, 209)]
+
+
+@pytest.mark.parametrize("B,H,max_pages,n_pages", LATENT_CELLS)
+def test_latent_kernel_compiles_at_published_widths(one_chip, B, H,
+                                                    max_pages, n_pages):
+    """The latent form at both cells' widths: one 576-value int8 row a
+    position in 640 lanes (a page of 576 lanes of 640 is no copy Mosaic
+    takes: ``paged_row_lanes``), split at lane 512 in VMEM, the pool an
+    operand as it is stored."""
+    from mpistragglers_jl_tpu.ops.decode_attention import (
+        latent_decode_attention,
+        paged_row_lanes,
+    )
+
+    P, R, width = 64, 512, 576
+    sds = functools.partial(_sds, one_chip)
+
+    def call(q, k, ks, pos, pt):
+        return latent_decode_attention(
+            q, {"k": k, "k_s": ks}, pos, pt, scale=192 ** -0.5, P=P, R=R,
+            interpret=False)
+
+    text = _compiled_text(
+        call, sds((B, 1, H, width), jnp.bfloat16),
+        sds((n_pages, P, paged_row_lanes(width)), jnp.int8),
+        sds((n_pages, 2, paged_scale_lanes(P)), jnp.float32),
+        sds((B,), jnp.int32), sds((B, max_pages), jnp.int32))
+    assert "tpu_custom_call" in text and "%paged_latent_attention" in text
+    for leaf in (f"s8[{n_pages},{P},640]",
+                 f"f32[{n_pages},2,{paged_scale_lanes(P)}]"):
+        made = [ln for ln in text.splitlines()
+                if f"= {leaf}" in ln and " parameter(" not in ln]
+        assert not made, made[0]
+
+
+def test_xing4_tick_reads_latent_pages_where_they_lie(one_chip):
+    """The whole decode tick of ``serve_xing4_mixed`` (the cell's own
+    configuration file: 16 slots, 8 steps, five latent layers under four
+    residual streams over 64 experts), compiled as the scheduler runs
+    it: on the kernel route. The latent kernel is in the program; the
+    scan's body carries no layer's pool through the compiler's fast
+    memory space (40 MB would fit it: the operands are declared to live
+    in HBM, as PR 43's are); and no array of a layer's gathered view
+    exists (``s8[16,4352,576]``: until PR 44 every slot's whole ring
+    was copied out of the pages and back each tick, 203 MB read a step
+    where the slots held 16)."""
+    import json
+    import pathlib
+
+    from chipbench.runners import serve_mla
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.ops import decode_attention, flash_attention
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    config = json.loads((root / "chipbench" / "configs"
+                         / "xing4-29b-a4b-serve.json").read_text())
+    cfg, program = serve_mla.transformer_config(config), config["program"]
+    compiled = lambda: False
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        # as on the chip: pages of 64 rows are blocks of the compiled
+        # kernel, and the kernels go through Mosaic
+        for module in (flash_attention, decode_attention):
+            patch.setattr(module, "_use_interpret", compiled)
+        sched = serving.ServingScheduler(
+            serve_mla.param_shapes(config), cfg, slots=program["slots"],
+            n_inner=program["n_inner"], quantize_kv=program["quantize_kv"],
+            page_tokens=program["page_tokens"],
+            prompt_chunk=program["prompt_chunk"],
+            max_prompt=program["max_prompt"])
+        assert sched.use_kernel
+        args = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                            sched._scan_args())
+        text = sched._scan.lower(*args).compile().as_text()
+    assert "%paged_latent_attention" in text
+    pool = sched._caches[0]["k"].shape
+    assert pool == (1089, 64, 640)
+    held = "s8[%d,%d,%d]" % pool
+    moved = [line for line in text.splitlines() if held in _result_of(
+        line, "copy", "copy-start", "slice-start")]
+    assert not moved, moved[:3]
+    for view in ("s8[16,4352,576]", "s8[16,4352,1,576]", "s8[16,4352,640]",
+                 "s8[16,68,64,640]"):
+        assert view not in text, view
+
+
 def test_paged_select_kernel_compiles_at_published_widths(one_chip):
     """MiniCPM-SALA's attention layer: 32 query heads on 2 K/V heads of
     128, a list of 128 pages a slot and K/V head, each head copying in

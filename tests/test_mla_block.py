@@ -77,7 +77,8 @@ def _chunk(params, chunk, arena, off, cfg, salt=None):
 
 @functools.partial(jax.jit, static_argnames=("cfg", "salt"))
 def _step(params, tok, pos, pool, pt, cfg, salt=None):
-    views = [serving._paged_gather(cl, pt, cfg.cache_heads(li), P)
+    views = [serving._paged_gather(cl, pt, cfg.cache_heads(li), P,
+                                   cfg.latent_width)
              for li, cl in enumerate(pool)]
     lg, views, _ = serving._serving_forward(params, tok, pos, views, cfg)
     return lg, [serving._paged_scatter(cl, vw, pt, P)
@@ -102,7 +103,8 @@ def _served_logits(tokens, prompt_len, quantize_kv, cfg=CFG, params=PARAMS,
     pool = serving._fresh_pages(cfg, W // P + 1, P, quantize_kv)
     pt = jnp.arange(1, W // P + 1, dtype=jnp.int32)[None]  # page 0: null
     pool = [{kk: c[kk].at[pt[0]].set(serving._rows_to_pages(
-        kk, r[kk][0], P).astype(c[kk].dtype)) for kk in c}
+        kk, r[kk][0], P, lanes=c[kk].shape[-1]).astype(c[kk].dtype))
+        for kk in c}
         for c, r in zip(pool, ring)]
     for pos in range(prompt_len, n):
         lg, pool = _step(params, jnp.asarray(tokens[pos:pos + 1]),
@@ -406,13 +408,74 @@ def test_scheduler_serves_what_the_reference_ranks_first():
     sched.run()
     pool = sched._caches[0]
     assert set(pool) == {"k", "k_s"}
-    assert pool["k"].shape[1:] == (P, R + ROPE) and pool["k"].dtype == jnp.int8
+    # a row's 28 values in whole lane tiles, zeros behind them
+    assert pool["k"].shape[1:] == (P, 128) and pool["k"].dtype == jnp.int8
+    assert not np.asarray(pool["k"][..., R + ROPE:]).any()
+    assert np.asarray(pool["k"][..., :R + ROPE]).any()
     assert pool["k_s"].shape[1] == 2
     for p, r in zip(prompts, reqs):
         assert len(r.tokens) == 10
         lg = _reference(np.concatenate([p, r.tokens]))[len(p) - 1:-1]
         gap = lg.max(-1) - lg[np.arange(10), r.tokens]
         assert gap.max() < 0.08
+
+
+# a latent of whole lane tiles: with four slots the paged tick takes the
+# kernel's latent form (interpreted here), with three it gathers
+KCFG = dataclasses.replace(CFG, mla_kv_rank=128)
+KPARAMS = init_params(KCFG, seed=5)
+
+
+def test_the_kernel_route_serves_the_gather_routes_tokens():
+    """The same requests over latent pages read in place and over
+    gathered views: token for token the same streams, a shared prefix
+    page among them, each token within the int8 rows' noise of the
+    reference's best; the tick span says which route it took."""
+    prompts = [_tokens(n, seed=n) for n in (5, 37, 20, 64, 9)]
+    prompts.append(np.concatenate([prompts[1][:2 * P], _tokens(3, seed=1)]))
+
+    def run(slots):
+        sched = ServingScheduler(
+            KPARAMS, KCFG, slots=slots, n_inner=4, quantize_kv=True,
+            page_tokens=P, prompt_chunk=16, max_prompt=64)
+        reqs = [sched.submit(p, 10) for p in prompts]
+        return sched, reqs, _spans_of_a_run(sched)
+
+    kern, got, spans = run(4)
+    gather, want, gather_spans = run(3)
+    assert kern.use_kernel and not gather.use_kernel
+    assert kern.pool.share_hits > 0
+    for route, seen in ((1, spans), (0, gather_spans)):
+        ticks = [s for s in seen if s.name == "serving.tick"]
+        assert ticks and {t.args["kernel"] for t in ticks} == {route}
+    text = kern.lower_tick().as_text(debug_info=True)
+    assert "paged_latent_attention" in text and "mla_attn" in text
+    assert "kv_page_gather" not in text
+    assert "kv_page_gather" in gather.lower_tick().as_text(debug_info=True)
+    ref = functools.partial(xing4_0.stream_logits, KPARAMS,
+                            **{**REF_KW, "kv_rank": 128})
+    for p, r, w in zip(prompts, got, want):
+        assert r.tokens == w.tokens and len(r.tokens) == 10
+        toks = np.concatenate([p, r.tokens])
+        lg = np.asarray(ref(jnp.asarray(toks), 0, len(toks)))[len(p) - 1:-1]
+        assert (lg.max(-1) - lg[np.arange(10), r.tokens]).max() < 0.08
+
+
+def test_the_latent_kernel_route_is_read_from_the_configuration():
+    """What the paged tick's latent form needs, each from the
+    configuration or the cache: int8 rows, a latent of whole lane
+    tiles, every layer latent, a page that is a block of the kernel's
+    and fits its budget under the absorbed query's rows."""
+    possible = decode._paged_kernel_possible
+    assert possible(KCFG, True, P)
+    assert not possible(KCFG, False, P)  # rows in the model's dtype
+    assert not possible(CFG, True, P)  # a latent of 24: no lane tile
+    assert not possible(KCFG, True, 12)  # no whole 8-row tiles
+    assert not possible(KCFG, True, 8192)  # a page past the VMEM budget
+    # the positional and slot-ring programs have no latent kernel
+    assert not decode._kernel_possible(KCFG, True)
+    assert not ServingScheduler(KPARAMS, KCFG, slots=4, quantize_kv=True,
+                                prompt_chunk=16, max_prompt=32).use_kernel
 
 
 def test_a_shared_prefix_page_is_shared():

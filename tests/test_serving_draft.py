@@ -56,9 +56,9 @@ MAX_NEW = [7, 12, 1, 9, 20, 2, 16, 11]
 
 
 def serve(draft, temperature, *, paged=True, quantize_kv=True, eos_id=None,
-          cfg=CFG, params=PARAMS):
+          cfg=CFG, params=PARAMS, slots=3):
     sched = ServingScheduler(
-        params, cfg, slots=3, n_inner=4, prompt_chunk=C, max_prompt=32,
+        params, cfg, slots=slots, n_inner=4, prompt_chunk=C, max_prompt=32,
         quantize_kv=quantize_kv, temperature=temperature, eos_id=eos_id,
         page_tokens=P if paged else None, draft=draft)
     reqs = [
@@ -90,6 +90,37 @@ def test_streams_equal_the_drafter_off_streams(temperature, paged):
             assert accepted == (r.tokens[at] == tok)
     if paged:  # every page came back
         assert all(p.used == 0 for p in sched.pools.values())
+
+
+# a latent of whole lane tiles: with four slots (``KERNEL_MIN_BATCH``)
+# the paged tick takes the kernel's latent form, a slot's two rows of a
+# drafting step as two rows of the kernel; with three it gathers
+KCFG = dataclasses.replace(CFG, mla_kv_rank=128)
+KPARAMS = init_params(KCFG, seed=3)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+def test_the_kernel_route_delivers_the_same_streams(temperature):
+    """On latent pages read in place, drafter on and off, the streams
+    are the gather route's token for token (the drafter-off streams
+    among them), every draft checked as above."""
+    kw = dict(cfg=KCFG, params=KPARAMS)
+    gather, want = serve(None, temperature, **kw)
+    off_sched, off = serve(None, temperature, slots=4, **kw)
+    sched, on = serve("mtp", temperature, slots=4, **kw)
+    assert not gather.use_kernel and off_sched.use_kernel and sched.use_kernel
+    for a, b, c in zip(want, off, on):
+        assert a.tokens == b.tokens == c.tokens
+        assert a.reason == b.reason == c.reason
+    assert any(d[2] for r in on for d in r.drafts)
+    assert any(not d[2] for r in on for d in r.drafts)
+    for r in on:
+        for at, tok, accepted in r.drafts:
+            assert accepted == (r.tokens[at] == tok)
+    assert all(p.used == 0 for p in sched.pools.values())
+    text = sched.lower_tick().as_text(debug_info=True)
+    assert "paged_latent_attention" in text and "mtp/mla_attn" in text
+    assert "kv_page_gather" not in text and "kv_page_scatter" not in text
 
 
 def test_the_cases_the_step_must_get_right_all_occur():
