@@ -63,6 +63,7 @@ def main() -> None:
 
     sched = ServingScheduler(
         params, cfg, slots=4, n_inner=4, prompt_chunk=16, max_prompt=64,
+        page_tokens=8,
     )
 
     def submit(n_prompt, max_new):

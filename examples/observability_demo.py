@@ -100,7 +100,7 @@ def serving_section(registry, spans):
     params = init_params(cfg, seed=11)
     sched = ServingScheduler(
         params, cfg, slots=2, n_inner=4, prompt_chunk=8, max_prompt=64,
-        registry=registry, spans=spans,
+        page_tokens=3, registry=registry, spans=spans,
     )
     rng = np.random.default_rng(0)
     reqs = [

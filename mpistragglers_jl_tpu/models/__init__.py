@@ -25,9 +25,6 @@ _HOME = {
     "make_ring_generate": "decode",
     "CodedGradTrainer": "coded_train",
     "transformer_chunk_loss": "coded_train",
-    "generate_speculative_dense": "speculative",
-    "make_speculative_dense": "speculative",
-    "make_speculative": "speculative",
     "ring_from_cache": "decode",
     "Request": "serving",
     "ServingScheduler": "serving",
@@ -68,24 +65,21 @@ __all__ = list(_HOME) + ["clear_cached_programs"]
 
 def clear_cached_programs() -> None:
     """Drop every lru-cached jitted program factory in the models
-    package (dense generation runners, speculative runners, serving
-    tick/admission programs). Compiled programs can pin device buffers;
+    package (dense generation runners, serving tick/admission
+    programs). Compiled programs can pin device buffers;
     long-running hosts that sweep many shapes (benchmarks, services)
     call this between phases to release HBM. One public chokepoint so
     callers cannot silently miss a newly added cache."""
-    from . import decode, serving, speculative
+    from . import decode, serving
 
     for cache in (
         decode._dense_runner,
         decode._grouped_layer,
-        speculative._spec_runner,
         serving._fresh_arena,
-        serving._serving_scan_dense,
         serving._serving_scan_paged,
         serving._extend_chunk_dense,
         serving._extend_chunk_group,
         serving._finish_admit_dense,
-        serving._place_dense,
         serving._seed_admit_paged,
         serving._place_paged,
         serving._copy_pages_paged,

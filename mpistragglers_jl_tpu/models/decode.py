@@ -12,7 +12,7 @@ Design (TPU-first):
   and attends causally. Prefill (``off == 0``) needs no cache reads, so
   it runs the configured chunk kernel — the flash Pallas kernel for
   long prompts. A later chunk (``T > 1`` at ``off > 0``: chunked
-  prefill, speculative verification) walks the key blocks of the cache
+  prefill) walks the key blocks of the cache
   its rows can see with an online softmax (:func:`_chunk_attention`),
   so it costs what ``off + T`` holds and not ``max_len``. Decode
   (``T == 1``) attends the single query against
@@ -176,7 +176,7 @@ def _kernel_possible(cfg, quantize_kv: bool) -> bool:
     threshold) depend on per-shard shapes and stay trace-time. Also
     scopes the vma carve-out (``_decode_kernel_interpreted``). A latent
     layer's one row a position is no K/V head of these kernels: the
-    positional and slot-ring programs of a configuration with one take
+    positional and ring programs of a configuration with one take
     the ``jax.numpy`` route; its PAGED tick has a kernel of its own
     (``_paged_kernel_possible``)."""
     return bool(quantize_kv and cfg.head_dim % 128 == 0

@@ -572,11 +572,6 @@ class _TierReplica:
     tier = "unified"
 
     def __init__(self, sched, *, planner: MigrationPlanner | None = None):
-        if not getattr(sched, "paged", False):
-            raise ValueError(
-                f"{type(self).__name__} needs a paged scheduler "
-                "(page_tokens=): migration is a page-layout transfer"
-            )
         self.sched = sched
         self.planner = planner if planner is not None \
             else MigrationPlanner()
@@ -603,7 +598,7 @@ class _TierReplica:
         return self.sched.active
 
     def __getattr__(self, name):
-        # pool/P/max_pages/paged/S/last_tick_at/...: the scheduler's
+        # pool/P/max_pages/S/last_tick_at/...: the scheduler's
         # surface IS this replica's surface. __dict__ access keeps a
         # half-constructed instance an AttributeError, not recursion.
         sched = self.__dict__.get("sched")

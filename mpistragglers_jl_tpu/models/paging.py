@@ -1,6 +1,6 @@
 """Host-side page-pool allocator for the paged serving KV cache.
 
-The serving scheduler's slot-ring cache gives every slot a fixed
+A ring a slot would give every slot a fixed
 ``(W, kv_heads, head_dim)`` arena regardless of request length: a
 12-token question strands the same HBM as a window-filling novel, and
 N users sharing one system prompt each pay full prefill AND full

@@ -1098,7 +1098,7 @@ class RequestRouter:
         if hits is not None:
             return int(hits(prompt))
         pool = getattr(r, "pool", None)
-        if pool is None or not getattr(r, "paged", False):
+        if pool is None:
             return 0
         p = np.asarray(prompt, np.int32).reshape(-1)
         digests = prefix_page_digests(p, r.P, r.max_pages)

@@ -3,7 +3,7 @@
 The reference is transport-only (src/MPIAsyncPools.jl:1-226 — no model,
 no serving); this is north-star serving scope (VERDICT r4 next-#1),
 converting the round-4 serving inventory (ring cache, GQA decode, int8
-KV, speculative/hedged) from single-request features into aggregate
+KV, hedged) from single-request features into aggregate
 throughput. At B=1 a decode step is weight-read-bound — the HBM traffic
 is the parameters, amortized over one token (PERF.md section 5). Batching S
 concurrent requests into one step amortizes the same weight reads over
@@ -13,17 +13,19 @@ near-linearly with S. That economics is the whole point of this module.
 Design (TPU-first):
 
 * **Fixed slots, static shapes.** The scheduler owns ``S`` serving
-  slots. Per-layer state is ONE batched O(W) ring cache
-  ``(S, W, kv_heads, head_dim)`` — the ring layout
-  (models/decode.py) makes every slot a fixed-size arena regardless of
-  how long its request runs, so slot reuse is a row overwrite, never a
-  reallocation, and one compiled program serves every scheduler tick.
+  slots. A slot's per-layer state is an O(W) ring (the ring layout of
+  models/decode.py: position ``p`` at ring slot ``p % W``) however
+  long its request runs, kept as pages of ``page_tokens`` ring slots in
+  one pool a layer (:class:`PagePool`, :func:`_fresh_pages`) and read
+  through the slot's row of a page table, so slot reuse is a table
+  write, never a reallocation, and one compiled program serves every
+  scheduler tick.
 * **A width per layer.** The block is data (``TransformerConfig``):
   a layer's ring is as wide as its own attention span
   (``decode.ring_widths``) — the window of a sliding-window layer, the
   context budget ``max_context`` of a full-attention layer, a ring
   that never wraps inside the budget, so the one ring invariant serves
-  both. Layers of one width are one *kind*: paged, each kind has its
+  both. Layers of one width are one *kind*: each kind has its
   own :class:`PagePool` and page table (``_PageKind``), and a request
   holds pages of every kind. Dropless top-k expert layers
   (``models/moe.py`` ``moe_ffn_topk``) are per token, so chunks and
@@ -38,8 +40,8 @@ Design (TPU-first):
   slot's old one, so a reused slot starts from the new prompt's state.
   The state at a page boundary is kept nowhere, so such a
   configuration shares no prefix page (``shares_prefixes`` is False),
-  and ``qos=``, ``cache=``, page migration, ``make_serving_scan`` and
-  speculation refuse it by mechanism.
+  and ``qos=``, ``cache=``, page migration and ``make_serving_scan``
+  refuse it by mechanism.
 * **A third kind of leaf.** A latent-attention layer
   (``layer_mixers`` value ``"mla"``) keeps ONE row a position for all
   its heads, the normalised latent beside one rotated key, and no
@@ -55,9 +57,9 @@ Design (TPU-first):
   a slot as two rows of the kernel; what the kernel cannot take (rows
   in the model's dtype, the tests' latents of 24) takes the gather
   route (``_serving_scan_paged`` with ``use_kernel`` False: every
-  slot's ring gathered once a tick). The sharded tick, migration and
-  speculation refuse it by mechanism, as they do a residual path of
-  several streams (``hc_mult``), of which nothing is cached.
+  slot's ring gathered once a tick). The sharded tick and migration
+  refuse it by mechanism, as they do a residual path of several
+  streams (``hc_mult``), of which nothing is cached.
 * **One or two tokens a step.** With ``draft="mtp"`` and a
   configuration that carries a multi-token-prediction module
   (``TransformerConfig(mtp_depth=1)``) a decode step runs two rows a
@@ -94,12 +96,14 @@ Design (TPU-first):
   quantization makes the chunk size invisible, so the stream is
   IDENTICAL at any ``prompt_chunk`` and equals the quantized oracle
   (``generate_ring_dense(quantize_kv=True)``, whose prefill runs the
-  same cached-attention math — ADVICE r5 repaired in PR 1; both the
-  identity and its chunk-invariance premise are pinned by
-  tests/test_serving.py). A request's prefill lands in a
+  same cached-attention math; both the equality and its
+  chunk-invariance premise are pinned by tests/test_serving.py at the
+  tests' sizes, and ``ServingScheduler``'s docstring says what that is
+  worth on a real model). A request's prefill lands in a
   transient positional cache; on the last chunk the final-W window
-  gathers into its slot's ring rows (``ring_from_cache`` math with a
-  traced length) and the first token comes from the head applied to
+  gathers into ring rows (``ring_from_cache`` math with a traced
+  length), which placement scatters into the slot's pages, and the
+  first token comes from the head applied to
   ONE row of the last chunk's hidden state, the prompt's last
   position: a chunk program stops at the last layer's output, so the
   head's weights are read once a request. Decode stall per tick is
@@ -133,8 +137,9 @@ cross-shape exactness; examples/continuous_batching.py demonstrates).
 
 ``make_serving_scan(cfg, mesh=...)`` is the sharded variant of the
 decode tick (slots over ``dp``, heads over ``tp``, the training path's
-psum placement) — the multi-chip serving program the driver dryrun
-compiles and checks against the dense tick.
+psum placement) over one ring a slot — the multi-chip serving program
+the driver dryrun compiles and checks against the dense per-row step
+(:func:`serving_decode_step_dense`).
 """
 
 from __future__ import annotations
@@ -204,6 +209,7 @@ from .transformer import (
     mtp_logits,
     la_rule_route,
     param_specs,
+    require_plain_block,
     sparse_counts,
     sparse_pick,
     state_half,
@@ -221,40 +227,38 @@ __all__ = [
 
 
 @functools.lru_cache(maxsize=32)
-def _fresh_arena(cfg: TransformerConfig, B: int, L,
+def _fresh_arena(cfg: TransformerConfig, B: int, L: int,
                  quantize_kv: bool):
     """Jitted ``serving_fresh_arena() -> [per-layer dict]``: every leaf
     of a zeroed ``(B, L, kv_heads, head_dim)`` cache out of ONE
-    dispatch (``L``: one length for all layers, or a tuple with one per
-    layer). An eager ``jnp.zeros`` per leaf is a program launch and
+    dispatch. An eager ``jnp.zeros`` per leaf is a program launch and
     an allocation each, four a layer, while the device has nothing
     queued: admission's whole host cost at 30 layers."""
-    lengths = (L,) * cfg.cache_layers if isinstance(L, int) else tuple(L)
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
-    def layer(li, length):
+    def layer(li):
         if cfg.state(li):  # no rows: the layer's fixed block of state
             return zero_state(cfg, li, B)
         if cfg.mla(li):  # one row a position: [latent | rotated key]
-            return _zero_latent_layer(B, length, cfg, quantize_kv)
-        shape = (B, length, cfg.kv_heads, cfg.head_dim)
+            return _zero_latent_layer(B, L, cfg, quantize_kv)
+        shape = (B, L, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
         if quantize_kv:
             out["k_s"] = jnp.zeros(shape[:3], jnp.float32)
             out["v_s"] = jnp.zeros(shape[:3], jnp.float32)
         if cfg.sparse(li):  # the selector's pooled cells (decode.py)
             out["kp"] = jnp.zeros(
-                (B, _pool_cells_for(length, cfg)) + shape[2:], jnp.float32)
+                (B, _pool_cells_for(L, cfg)) + shape[2:], jnp.float32)
         return out
 
     @jax.jit
     def serving_fresh_arena():
-        return [layer(li, length) for li, length in enumerate(lengths)]
+        return [layer(li) for li in range(cfg.cache_layers)]
 
     return serving_fresh_arena
 
 
-def _fresh_cache(cfg: TransformerConfig, B: int, L,
+def _fresh_cache(cfg: TransformerConfig, B: int, L: int,
                  quantize_kv: bool = False) -> list[dict]:
     """Zeroed positional/ring cache with DISTINCT buffers per leaf,
     made by one program (:func:`_fresh_arena`). decode.py's
@@ -688,14 +692,14 @@ def _paged_gather(cache_l: dict, pt, Hkv: int, P: int,
     one PAGE-BLOCK ``jnp.take`` per leaf — ``(S, max_pages)`` indices
     moving whole pages. Page p's rows are ring slots ``[j*P, (j+1)*P)``
     in offset order, so the gathered blocks, read as rows
-    (:func:`_pages_to_rows`), are EXACTLY the slot-ring layout
-    ``(S, W, Hkv, ...)`` and the einsum path runs the unchanged dense
-    ring math on it — dense and paged decode are the identical math by
+    (:func:`_pages_to_rows`), are EXACTLY one ring a slot,
+    ``(S, W, Hkv, ...)``, and the einsum path runs the unchanged ring
+    math on it — ring and paged decode are the identical math by
     construction, which is what the CPU parity tests lean on (``width``:
     the row's values, where the pool's minor axis is wider). Speed
     note: this gather runs once per TICK (hoisted out of the decode
-    scan — see ``_serving_scan_paged``; a per-step gather measured
-    0.66x the slot tick). Null page-table entries resolve to page 0,
+    scan — see ``_serving_scan_paged``). Null page-table entries
+    resolve to page 0,
     whose rows are only ever reached by ``kpos < 0`` (masked) slots."""
     return {
         kk: _pages_to_rows(kk, jnp.take(a, pt, axis=0), Hkv, P, width)
@@ -745,14 +749,15 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     """Layer ``li`` of the per-row serving step: models/transformer.py's
     block with per-row positions and the K/V store of this module.
     ``paged`` = (page_table, W, PAGE_TOKENS) switches the cache
-    write/read to the page-pool layout; None is the slot-ring path.
+    write/read to the page-pool layout; None is one ring a slot (the
+    gathered views, the sharded tick, ``serving_decode_step_dense``).
     Returns ``(x, cache_l, hit)``; ``hit`` is the number of experts
     that got a row in a dropless expert layer, None elsewhere. A gated
     delta-rule layer's ``cache_l`` is every slot's state: one step of
     the recurrence a row, no position and no page. ``pos`` (S, T) with
     x (S, T, D): T rows a slot, each written before any is attended (a
-    drafting step's two: the slot-ring and gathered-view paths, and a
-    latent layer's pages)."""
+    drafting step's two: the gathered views, and a latent layer's
+    pages)."""
     h, mix = hc_pre(x, lp, cfg, "hc1")
     rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
                              table=cfg.rope_table)
@@ -961,11 +966,10 @@ def _scan_body(params, tok, pos, done, caches, cfg, eos_id, n_inner,
 # * A rejected draft leaves a stale row at p + 1 in every layer's
 #   cache, the module's too. The next step starts at p + 1 and writes
 #   that row before it attends, and until then no query above p exists:
-#   the stale row is overwritten before it is read (the argument of
-#   models/speculative.py for its dense cache, here for int8 latent
-#   pages). A slot therefore writes one row past its certain cursor,
-#   and a tick ``2 * n_inner`` rows at most: the page budget and the
-#   context check count that (``ServingScheduler._tick_rows``).
+#   the stale row is overwritten before it is read. A slot therefore
+#   writes one row past its certain cursor, and a tick ``2 * n_inner``
+#   rows at most: the page budget and the context check count that
+#   (``ServingScheduler._tick_rows``).
 
 
 def _draft_step(params, tok, pos, done, caches, cfg, eos_id, keys,
@@ -1047,34 +1051,17 @@ def _tick_body(cfg: TransformerConfig):
 
 
 @functools.lru_cache(maxsize=32)
-def _serving_scan_dense(cfg: TransformerConfig, n_inner: int,
-                        eos_id: int | None, temperature: float = 0.0,
-                        top_k: int | None = None,
-                        use_kernel: bool = False):
-    """Jitted dense tick: (params, tok, pos, done, caches, keys) ->
-    (tok, pos, done, caches, toks). Caches donated — the tick updates
-    the arena in place in HBM. ``use_kernel`` is the scheduler's
-    RESOLVED int8-kernel routing."""
-
-    @functools.partial(jax.jit, donate_argnums=(4,))
-    def serving_tick_dense(params, tok, pos, done, caches, keys):
-        return _tick_body(cfg)(params, tok, pos, done, caches, cfg, eos_id,
-                               n_inner, keys, temperature=temperature,
-                               top_k=top_k, use_kernel=use_kernel)
-
-    return serving_tick_dense
-
-
-@functools.lru_cache(maxsize=32)
 def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
                         eos_id: int | None, temperature: float,
                         top_k: int | None, use_kernel: bool, P: int):
-    """Jitted PAGED tick: like :func:`_serving_scan_dense` plus the
-    ``(S, max_pages)`` int32 page table (a loop-invariant input — the
-    tick writes pages, never the table; COW retargeting happens
-    host-side between ticks). The page pool is donated like the ring
-    arena; ``W = max_pages * P`` is recovered from the table shape so
-    one compiled program serves any pool size at a given (cfg, P).
+    """Jitted tick: (params, tok, pos, done, caches, keys, pt) ->
+    (tok, pos, done, caches, toks); ``pt`` is the ``(S, max_pages)``
+    int32 page table (a loop-invariant input — the tick writes pages,
+    never the table; COW retargeting happens host-side between ticks).
+    The page pool is donated: the tick updates it in place in HBM.
+    ``W = max_pages * P`` is recovered from the table shape so one
+    compiled program serves any pool size at a given (cfg, P).
+    ``use_kernel`` is the scheduler's RESOLVED int8-kernel routing.
 
     ``use_kernel=True`` (the int8 route: K/V layers of whole GQA
     groups at a lane-aligned head size, or latent layers alone with a
@@ -1085,14 +1072,10 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
     tile, latent layers beside K/V layers, fewer slots than
     ``KERNEL_MIN_BATCH``) instead hoists the indirection OUT of the scan: the table
     is tick-invariant, so each layer's W-row ring view gathers ONCE,
-    the unchanged dense ring scan runs on the views (the paged einsum
-    tick IS the slot-ring tick on a gathered arena — parity by
-    construction), and one scatter writes the views back through the
-    table. A per-step gather measured 0.66x the slot tick (earlier
-    installation, not repeated on this one: XLA re-materializes the
-    view every step inside the scan);
-    hoisted, the gather amortizes over ``n_inner`` steps and the tick
-    lands within the <= 5% budget. The trade is a transient
+    the unchanged ring scan (:func:`_scan_body`) runs on the views,
+    and one scatter writes the views back through the table (inside
+    the scan XLA re-materializes the view every step; hoisted, the
+    gather amortizes over ``n_inner`` steps). The trade is a transient
     ``(S, W)``-row view per layer during the tick — active-slot bytes,
     not pool bytes; the kernel route has no such transient (PERF.md
     section 4: 4.83 GB reserved against 0.69)."""
@@ -1197,10 +1180,11 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
 @functools.lru_cache(maxsize=32)
 def _place_paged(cfg: TransformerConfig, P: int):
     """Paged install: scatter the admitted request's W ring rows into
-    its pages and set the row state — :func:`_place_dense` with the
-    cache row write routed through the page table. Shared prefix rows
-    write bytes IDENTICAL to what the pages already hold (the seed op
-    put those very bytes into the transient cache), so the
+    its pages (a page-block scatter through the page table) and set
+    the row state: first token, start position, key, ``done`` off.
+    Everything donated — admission is an in-place write. Shared prefix
+    rows write bytes IDENTICAL to what the pages already hold (the
+    seed op put those very bytes into the transient cache), so the
     unconditional scatter never perturbs a sharer; rows past the
     request's page budget land in the null page."""
 
@@ -1301,17 +1285,6 @@ def _refuse_latent_layers(cfg: TransformerConfig, what: str,
         )
 
 
-def _refuse_streams(cfg: TransformerConfig, what: str, why: str) -> None:
-    """``what`` is written for one residual stream; refuse, by
-    mechanism, a configuration whose residual path is streams."""
-    if cfg.hc_mult > 1:
-        raise ValueError(
-            f"{what}: this configuration's residual path is "
-            f"{cfg.hc_mult} streams mixed by the token's own matrices; "
-            f"{why}"
-        )
-
-
 def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
                       *, eos_id: int | None = None,
                       quantize_kv: bool = False,
@@ -1325,22 +1298,7 @@ def make_serving_scan(cfg: TransformerConfig, mesh: Mesh, n_inner: int,
     used only at ``temperature > 0``). ``quantize_kv=True`` serves an int8 ring
     cache (scale leaves shard like their K/V; the per-row write/score
     paths detect the layout)."""
-    _refuse_state_layers(
-        cfg, "make_serving_scan (the sharded tick)",
-        "its cache specs shard rows over dp and heads over tp. One "
-        "chip serves it through ServingScheduler")
-    _refuse_latent_layers(
-        cfg, "make_serving_scan (the sharded tick)",
-        "its cache specs shard K/V heads over tp. One chip serves it "
-        "through ServingScheduler")
-    _refuse_streams(
-        cfg, "make_serving_scan (the sharded tick)",
-        "its weights follow param_specs, which has no leaf of the "
-        "mixing. One chip serves it through ServingScheduler")
-    _refuse_sparse_layers(
-        cfg, "make_serving_scan (the sharded tick)",
-        "its ring cache keeps rows alone. One chip serves it through "
-        "ServingScheduler")
+    require_plain_block(cfg, "make_serving_scan (the sharded tick)")
     _check_ring_cfg(cfg)
     _check_sampling_params(temperature, top_k)
     _refuse_switch_experts(cfg)
@@ -1537,26 +1495,6 @@ def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
     return serving_first_token
 
 
-@functools.lru_cache(maxsize=32)
-def _place_dense(cfg: TransformerConfig):
-    """Install an admitted request into slot ``s``: ring rows into the
-    batched cache, first token + start position into the row state.
-    Everything donated — admission is an in-place row write."""
-
-    @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
-    def serving_place_ring(caches, ring, tok, pos, done, keys, s, tok0,
-                           pos0, key):
-        caches = [
-            {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
-             for kk in c}
-            for c, r in zip(caches, ring)
-        ]
-        return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
-                done.at[s].set(False), keys.at[s].set(key))
-
-    return serving_place_ring
-
-
 # --------------------------------------------------------------------------
 # observability: the tick's phase boundaries go to the profiler always;
 # the obs/ registry + timeline are strictly opt-in
@@ -1672,36 +1610,35 @@ class _ServingObs:
             help="decode ticks by resolved int8-kernel route",
             route="kernel" if sched.use_kernel else "einsum",
         )
-        # page-pool series (paged schedulers only): pool occupancy
-        # gauges plus prefix-share / COW counters published as deltas
-        # of the pool's lifetime tallies, so the registry stays
-        # monotone however often the pool is sampled
-        if sched.paged:
-            self.m_pages_free = registry.gauge(
-                "serving_cache_pages_free",
-                help="KV cache pages on the free list",
-            )
-            self.m_pages_used = registry.gauge(
-                "serving_cache_pages_used",
-                help="KV cache pages allocated to slots",
-            )
-            # tier-labeled (cache/ package): hbm = local share, the
-            # only tier a fleet-less scheduler ever increments;
-            # dram/peer appear lazily via fleet_hit when a fleet
-            # cache serves the page instead
-            self.m_share = registry.counter(
-                "serving_prefix_share_hits_total",
-                help="prompt prefix pages whose prefill was skipped "
-                "at admission, by serving tier (hbm = local share, "
-                "dram = host page store, peer = replica fetch)",
-                tier="hbm",
-            )
-            self._share_tier: dict[str, Any] = {"hbm": self.m_share}
-            self.m_cow = registry.counter(
-                "serving_cow_copies_total",
-                help="copy-on-write page copies (a slot wrote a page "
-                "another slot still reads)",
-            )
+        # page-pool series: pool occupancy gauges plus prefix-share /
+        # COW counters published as deltas of the pool's lifetime
+        # tallies, so the registry stays monotone however often the
+        # pool is sampled
+        self.m_pages_free = registry.gauge(
+            "serving_cache_pages_free",
+            help="KV cache pages on the free list",
+        )
+        self.m_pages_used = registry.gauge(
+            "serving_cache_pages_used",
+            help="KV cache pages allocated to slots",
+        )
+        # tier-labeled (cache/ package): hbm = local share, the
+        # only tier a fleet-less scheduler ever increments;
+        # dram/peer appear lazily via fleet_hit when a fleet
+        # cache serves the page instead
+        self.m_share = registry.counter(
+            "serving_prefix_share_hits_total",
+            help="prompt prefix pages whose prefill was skipped "
+            "at admission, by serving tier (hbm = local share, "
+            "dram = host page store, peer = replica fetch)",
+            tier="hbm",
+        )
+        self._share_tier: dict[str, Any] = {"hbm": self.m_share}
+        self.m_cow = registry.counter(
+            "serving_cow_copies_total",
+            help="copy-on-write page copies (a slot wrote a page "
+            "another slot still reads)",
+        )
         # QoS series (qos= schedulers only): per-tenant admission
         # counters plus deficit / page-quota-usage gauges, series
         # created lazily per tenant and cached (the _RouterObs
@@ -1744,16 +1681,15 @@ class _ServingObs:
                     tenant=t,
                 )
             g.set(drr.deficit(t))
-            if sched.paged:
-                q = self._q_quota.get(t)
-                if q is None:
-                    q = self._q_quota[t] = self.registry.gauge(
-                        "qos_pages_quota_used",
-                        help="KV pages attributed to the tenant "
-                        "(hot refs + cold cache) against its quota",
-                        tenant=t,
-                    )
-                q.set(sched._tenant_usage(t))
+            q = self._q_quota.get(t)
+            if q is None:
+                q = self._q_quota[t] = self.registry.gauge(
+                    "qos_pages_quota_used",
+                    help="KV pages attributed to the tenant "
+                    "(hot refs + cold cache) against its quota",
+                    tenant=t,
+                )
+            q.set(sched._tenant_usage(t))
 
     def first_token(self, req: "Request", t: float) -> None:
         self._tick_toks += 1
@@ -1810,14 +1746,13 @@ class _ServingObs:
                 self.m_route.inc()
             for req in retired:
                 self.m_retired[req.reason].inc()
-            if sched.paged:
-                pool = sched.pool
-                self.m_pages_free.set(pool.free)
-                self.m_pages_used.set(pool.used)
-                self.m_share.inc(pool.share_hits - self._last_share)
-                self._last_share = pool.share_hits
-                self.m_cow.inc(pool.cow_copies - self._last_cow)
-                self._last_cow = pool.cow_copies
+            pool = sched.pool
+            self.m_pages_free.set(pool.free)
+            self.m_pages_used.set(pool.used)
+            self.m_share.inc(pool.share_hits - self._last_share)
+            self._last_share = pool.share_hits
+            self.m_cow.inc(pool.cow_copies - self._last_cow)
+            self._last_cow = pool.cow_copies
             if self._qos is not None:
                 self.qos_gauges(sched)
         sp = self.spans
@@ -1834,8 +1769,7 @@ class _ServingObs:
                     sp.add(name, ph.t0, ph.t1 - ph.t0, track="scheduler")
             sp.count("queue_depth", sched.pending, t=tick.t1)
             sp.count("active_slots", sched.active, t=tick.t1)
-            if sched.paged:
-                sp.count("pages_used", sched.pool.used, t=tick.t1)
+            sp.count("pages_used", sched.pool.used, t=tick.t1)
 
 
 # --------------------------------------------------------------------------
@@ -1922,8 +1856,8 @@ class _PageKind:
 class _Admitting:
     """Per-slot chunked-prefill state machine: the transient positional
     cache (the arena: taken from the scheduler's free list, back on it
-    when the admission ends) plus the chunk cursor. Paged admissions
-    additionally carry the page plan: ``base`` (tokens of shared prefix
+    when the admission ends), the chunk cursor and the page plan:
+    ``base`` (tokens of shared prefix
     whose prefill is SKIPPED — chunk i runs at offset ``base + i*C``),
     ``pids`` (the slot's full page-table row of every cache width, in
     the scheduler's kind order, installed into the device tables only
@@ -1933,8 +1867,7 @@ class _Admitting:
     that ring — registered pages are then volatile)."""
 
     def __init__(self, req: Request, cache, padded, n_chunks: int, *,
-                 base: int = 0, pids=None, digests=(), n_cover: int = 0,
-                 wraps=()):
+                 base: int, pids, digests, n_cover: int, wraps):
         self.req = req
         self.cache = cache
         self.padded = padded  # (1, n_chunks * C) int32, on the host
@@ -1953,7 +1886,8 @@ class ServingScheduler:
     slots (dense single-device programs; the sharded tick is
     :func:`make_serving_scan`).
 
-    >>> sched = ServingScheduler(params, cfg, slots=8, eos_id=2)
+    >>> sched = ServingScheduler(params, cfg, slots=8, eos_id=2,
+    ...                          page_tokens=64)
     >>> r = sched.submit(prompt, max_new=64)   # any time, any order
     >>> sched.run()                            # or step() per tick
     >>> r.tokens                               # greedy == oracle
@@ -1969,26 +1903,32 @@ class ServingScheduler:
     samples each slot with its request's own key (``submit(...,
     key=...)``; id-derived when omitted) — a sampled stream equals
     ``generate_ring_dense(..., key=request_key)`` exactly, like the
-    greedy==oracle contract.
+    greedy==oracle contract. With ``quantize_kv=True`` that equality is
+    a reading, not an identity: admission's chunks and the oracle's
+    whole prompt both attend the already-quantized cache, but they are
+    different program shapes, and what the tests pin on their tiny
+    float32 configurations can round apart on a real model or a long
+    prompt.
 
     ``prompt_chunk`` bounds the decode stall a long prompt can inject
     into in-flight requests (one chunk per tick); ``max_prompt`` sizes
     the transient prefill arena (one compile for all prompt lengths).
 
-    ``page_tokens=P`` switches the cache from per-slot rings to the
-    PAGED pool (docs/API.md "Paged serving cache"): per-layer K/V live
-    in ``cache_pages`` fixed-size pages of P ring slots managed by a
-    host-side :class:`PagePool` (free list + refcounts), each slot
-    reading through a ``(max_pages,)`` page-index row. Three wins over
-    the slot ring, same token streams (the oracle identity holds
-    verbatim — the paged parity tests pin it):
+    The cache is a PAGE POOL (docs/API.md "Paged serving cache"):
+    per-layer K/V live in ``cache_pages`` fixed-size pages of
+    ``page_tokens=P`` ring slots managed by a host-side
+    :class:`PagePool` (free list + refcounts), each slot reading
+    through a ``(max_pages,)`` page-index row; ``P`` divides every
+    cache width, and one page a window (``P = W``) is a ring a slot.
+    What the pool does (the streams are the oracle's whatever ``P`` —
+    the paged parity tests pin it):
 
     * **Right-sized residency.** A request holds only the pages its
-      lifetime can touch (``ceil(min(W, Tp + max_new + n_inner) / P)``)
-      instead of a full ``W``-slot arena — short requests stop
-      stranding HBM, and ``cache_pages`` (not ``slots``) becomes the
-      capacity knob. Admission defers when the pool cannot cover a
-      request's whole budget, so mid-decode exhaustion cannot happen.
+      lifetime can touch (``ceil(min(W, Tp + max_new + n_inner) / P)``),
+      never a full window it will not fill, and ``cache_pages`` (not
+      ``slots``) is the capacity knob. Admission defers when the pool
+      cannot cover a request's whole budget, so mid-decode exhaustion
+      cannot happen.
       The DEFERRAL UNIT is the admission-order contract: FIFO (the
       default) defers the head of the one queue — no reordering, a
       large request cannot be starved by later small ones; under
@@ -2001,9 +1941,9 @@ class ServingScheduler:
     ``tenant=`` (unknown tenants refused by name) and admission order
     comes from a :class:`~..qos.DeficitScheduler` over per-tenant
     queues — weighted, work-conserving, deficits carried — instead of
-    FIFO. Paged schedulers additionally enforce each contract's page
-    QUOTA at plan time, with COW-aware graceful reclaim: a retiring
-    request's still-registered, refcount-1 prefix pages go COLD
+    FIFO. Each contract's page QUOTA is enforced at plan time, with
+    COW-aware graceful reclaim: a retiring request's
+    still-registered, refcount-1 prefix pages go COLD
     (resident for future sharers, attributed to the tenant) instead
     of freeing, and reclaim evicts cold pages oldest-first — an
     over-quota tenant's first — while a page shared with any live
@@ -2020,8 +1960,8 @@ class ServingScheduler:
       window-wrapping requests), so a reader's bytes are immutable.
 
     The decode tick reads K/V through the page table: the einsum path
-    gathers each slot's W-row ring view (``jnp.take`` — identical math
-    to the slot ring, the CPU-testable fallback); int8 caches route
+    gathers each slot's W-row ring view (``jnp.take``, then the ring
+    math: the CPU-testable fallback); int8 caches route
     the Pallas kernel's page-table mode, where the per-slot page row
     rides scalar-prefetch SMEM and block index maps gather pages
     directly (no materialized ring view at all).
@@ -2057,7 +1997,7 @@ class ServingScheduler:
                  n_inner: int = 8, eos_id: int | None = None,
                  prompt_chunk: int = 256, max_prompt: int = 2048,
                  quantize_kv: bool = False, temperature: float = 0.0,
-                 top_k: int | None = None, page_tokens: int | None = None,
+                 top_k: int | None = None, page_tokens: int,
                  cache_pages: int | None = None,
                  qos: TenantRegistry | None = None,
                  max_queue: int | None = None, registry=None,
@@ -2107,8 +2047,7 @@ class ServingScheduler:
                 cfg, "page quotas (qos=) and the fleet prefix cache "
                 "(cache=)", "both count and move prefix pages, which "
                 "this configuration does not share")
-        if cfg.sparse_layers and page_tokens is not None and (
-                int(page_tokens) != cfg.sparse_block):
+        if cfg.sparse_layers and int(page_tokens) != cfg.sparse_block:
             raise ValueError(
                 f"a selection of key blocks is a selection of pages: "
                 f"page_tokens {page_tokens} must be sparse_block "
@@ -2124,21 +2063,14 @@ class ServingScheduler:
                 "has layers of more than one cache width "
                 f"({list(kinds)}), a pool each"
             )
-        self.paged = page_tokens is not None
-        if self.paged:
-            self.P = int(page_tokens)
-            if self.P < 1 or any(w % self.P for w in kinds):
-                raise ValueError(
-                    f"page_tokens must divide the attention window "
-                    f"(W={W}) and every other cache width "
-                    f"({list(kinds)}), got {page_tokens}"
-                )
-            self.max_pages = W // self.P
-        elif cache_pages is not None:
+        self.P = int(page_tokens)
+        if self.P < 1 or any(w % self.P for w in kinds):
             raise ValueError(
-                "cache_pages without page_tokens: pass page_tokens to "
-                "enable the paged cache"
+                f"page_tokens must divide the attention window "
+                f"(W={W}) and every other cache width "
+                f"({list(kinds)}), got {page_tokens}"
             )
+        self.max_pages = W // self.P
         self.params = params
         self.cfg = cfg
         self.S = int(slots)
@@ -2222,104 +2154,82 @@ class ServingScheduler:
         self._pos = jnp.zeros((self.S,), jnp.int32)
         self._done = jnp.ones((self.S,), bool)  # idle rows stay done
         self._keys = jax.random.split(jax.random.key(0), self.S)
-        if self.paged:
-            # page-pool arena: the capacity knob is cache_pages, not
-            # slots x W. The default matches the slot-ring footprint
-            # (every slot could hold a full window) plus the null page
-            # — opting into paging never means LESS capacity.
-            # With several cache widths every kind has a pool of its
-            # own, sized the same way; cache_pages is then one count
-            # per kind, narrowest first.
-            if cache_pages is None or isinstance(cache_pages, int):
-                if cache_pages is not None and len(kinds) > 1:
-                    raise ValueError(
-                        f"cache_pages needs one count per cache width "
-                        f"({list(kinds)}), got {cache_pages}"
-                    )
-                cache_pages = [cache_pages] * len(kinds)
-            if len(cache_pages) != len(kinds):
+        # page-pool arena: the capacity knob is cache_pages, not
+        # slots x W. The default lets every slot hold a full window,
+        # plus the null page. With several cache widths every kind has
+        # a pool of its own, sized the same way; cache_pages is then
+        # one count per kind, narrowest first.
+        if cache_pages is None or isinstance(cache_pages, int):
+            if cache_pages is not None and len(kinds) > 1:
                 raise ValueError(
-                    f"cache_pages names {len(cache_pages)} pools, the "
-                    f"configuration has {len(kinds)} cache widths"
+                    f"cache_pages needs one count per cache width "
+                    f"({list(kinds)}), got {cache_pages}"
                 )
-            span_of = {w: span for w, span in zip(widths, cfg.windows)
-                       if w is not None}
-            n_windows = sum(span_of[w] is not None for w in kinds)
-            self._kinds: list[_PageKind] = []
-            for k, (Wk, n) in enumerate(zip(kinds, cache_pages)):
-                n_pages = (int(n) if n is not None
-                           else self.S * (Wk // self.P) + 1)
-                if n_pages < Wk // self.P + 1:
-                    raise ValueError(
-                        f"cache_pages {n_pages} cannot hold even one "
-                        f"window-filling request ({Wk // self.P} pages "
-                        "+ the null page)"
-                    )
-                name = ("full" if span_of[Wk] is None
-                        else "window" if n_windows == 1
-                        else f"window{Wk}")
-                self._kinds.append(_PageKind(
-                    name, Wk, self.P, n_pages, self.S,
-                    tuple(li for li, kk in enumerate(kind_of) if kk == k),
-                ))
-            self._caches = _fresh_pages(
-                cfg, tuple(0 if k is None else self._kinds[k].pool.n_pages
-                           for k in kind_of),
-                self.P, self.quantize_kv, slots=self.S,
+            cache_pages = [cache_pages] * len(kinds)
+        if len(cache_pages) != len(kinds):
+            raise ValueError(
+                f"cache_pages names {len(cache_pages)} pools, the "
+                f"configuration has {len(kinds)} cache widths"
             )
-            # the narrowest kind under the names the single-width code
-            # (quotas, fleet cache, migration) reads: the pool, the
-            # host-authoritative page table (the device copy refreshes
-            # lazily whenever admission/COW/retirement dirties it) and
-            # the per-slot wrap flags
-            self.pool = self._kinds[0].pool
-            self._pt_host = self._kinds[0].pt_host
-            self._slot_wraps = self._kinds[0].slot_wraps
-            self._pt_dev = None
-            # per-slot global position mirror (the COW pass must know
-            # which ring pages the NEXT tick will write, host-side)
-            self._host_pos = [0] * self.S
-        else:
-            self.pool = None
-            self._kinds = []
-            self._caches = _fresh_cache(
-                cfg, self.S,
-                W if len(kinds) == 1 else tuple(w or 0 for w in widths),
-                self.quantize_kv,
-            )
+        span_of = {w: span for w, span in zip(widths, cfg.windows)
+                   if w is not None}
+        n_windows = sum(span_of[w] is not None for w in kinds)
+        self._kinds: list[_PageKind] = []
+        for k, (Wk, n) in enumerate(zip(kinds, cache_pages)):
+            n_pages = (int(n) if n is not None
+                       else self.S * (Wk // self.P) + 1)
+            if n_pages < Wk // self.P + 1:
+                raise ValueError(
+                    f"cache_pages {n_pages} cannot hold even one "
+                    f"window-filling request ({Wk // self.P} pages "
+                    "+ the null page)"
+                )
+            name = ("full" if span_of[Wk] is None
+                    else "window" if n_windows == 1
+                    else f"window{Wk}")
+            self._kinds.append(_PageKind(
+                name, Wk, self.P, n_pages, self.S,
+                tuple(li for li, kk in enumerate(kind_of) if kk == k),
+            ))
+        self._caches = _fresh_pages(
+            cfg, tuple(0 if k is None else self._kinds[k].pool.n_pages
+                       for k in kind_of),
+            self.P, self.quantize_kv, slots=self.S,
+        )
+        # the narrowest kind under the names the single-width code
+        # (quotas, fleet cache, migration) reads: the pool, the
+        # host-authoritative page table (the device copy refreshes
+        # lazily whenever admission/COW/retirement dirties it) and
+        # the per-slot wrap flags
+        self.pool = self._kinds[0].pool
+        self._pt_host = self._kinds[0].pt_host
+        self._slot_wraps = self._kinds[0].slot_wraps
+        self._pt_dev = None
+        # per-slot global position mirror (the COW pass must know
+        # which ring pages the NEXT tick will write, host-side)
+        self._host_pos = [0] * self.S
         # int8 Pallas kernel routing, resolved at construction against
         # THIS scheduler's slot count (decode.py's _route_kernel: the tick
         # batches all S slots into one kernel call per layer, which is
         # what amortizes the scan boundary cost the B=1 path cannot).
-        # The paged tick adds the page-geometry conditions
-        # (_paged_kernel_possible) — all cfg-static, so the resolution
-        # stays a construction-time decision either way.
-        if self.paged:
-            self.use_kernel = (
-                _paged_kernel_possible(cfg, self.quantize_kv, self.P)
-                and _route_kernel(self.S)
-                # a drafting step's two queries a slot: the latent form
-                # takes them as two rows, the K/V form has one a slot
-                and (draft is None or cfg.latent_layers)
-            )
-            self._scan = _serving_scan_paged(
-                cfg, self.n_inner, eos_id, self.temperature, top_k,
-                self.use_kernel, self.P,
-            )
-            self._seed = _seed_admit_paged(cfg, min(W, self.Lmax),
-                                           self.P)
-            self._place_p = _place_paged(cfg, self.P)
-            self._copy = _copy_pages_paged(cfg, self.P)
-            self._gather = _gather_ring_paged(cfg, self.P)
-        else:
-            self.use_kernel = (
-                _kernel_possible(cfg, self.quantize_kv)
-                and _route_kernel(self.S) and draft is None
-            )
-            self._scan = _serving_scan_dense(
-                cfg, self.n_inner, eos_id, self.temperature, top_k,
-                self.use_kernel,
-            )
+        # The page-geometry conditions (_paged_kernel_possible) are all
+        # cfg-static, so the resolution is a construction-time decision.
+        self.use_kernel = (
+            _paged_kernel_possible(cfg, self.quantize_kv, self.P)
+            and _route_kernel(self.S)
+            # a drafting step's two queries a slot: the latent form
+            # takes them as two rows, the K/V form has one a slot
+            and (draft is None or cfg.latent_layers)
+        )
+        self._scan = _serving_scan_paged(
+            cfg, self.n_inner, eos_id, self.temperature, top_k,
+            self.use_kernel, self.P,
+        )
+        self._seed = _seed_admit_paged(cfg, min(W, self.Lmax),
+                                       self.P)
+        self._place = _place_paged(cfg, self.P)
+        self._copy = _copy_pages_paged(cfg, self.P)
+        self._gather = _gather_ring_paged(cfg, self.P)
         self._extend = _extend_chunk_dense(cfg, self.C, self.Lmax)
         # the same chunk for up to ``_group`` requests in one program
         # (None: a chunk of this model gains nothing from width); its
@@ -2360,7 +2270,6 @@ class ServingScheduler:
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
-        self._place = _place_dense(cfg)
         # instruments resolved once here; None = dark (no tick cost)
         self._obs = (
             _ServingObs(self, registry, spans)
@@ -2397,11 +2306,6 @@ class ServingScheduler:
         self.cache = cache
         self.cache_name: str | None = None
         if cache is not None:
-            if not self.paged:
-                raise ValueError(
-                    "cache= needs the paged arena: pass page_tokens "
-                    "(the fleet cache's unit is the prefix page)"
-                )
             self.cache_name = cache.attach(self)
         if exporter is not None:
             # register the tick-freshness health check (+ the span
@@ -2556,9 +2460,8 @@ class ServingScheduler:
                 else len(self._queue))
 
     def _scan_args(self) -> tuple:
-        args = (self.params, self._tok, self._pos, self._done,
-                self._caches, self._keys)
-        return args + (self._device_pt(),) if self.paged else args
+        return (self.params, self._tok, self._pos, self._done,
+                self._caches, self._keys, self._device_pt())
 
     def _decode_scan_fetch(self) -> np.ndarray:
         """Run the jitted decode tick and fence the tokens to host."""
@@ -2601,15 +2504,14 @@ class ServingScheduler:
     def lower_tick(self):
         """The decode tick's program lowered against the live state
         (nothing runs, nothing is donated): its text says which
-        attention path the tick traced — ``use_kernel`` alone does
-        not, since the slot-ring path re-gates at trace time."""
+        attention path the tick traced."""
         return self._scan.lower(*self._scan_args())
 
     @property
     def pools(self) -> dict[str, PagePool]:
         """The paged arena's pools by cache width: ``{"window": ...}``
         for sliding-window layers alone, ``"window"`` and ``"full"``
-        where full-attention layers stand beside them (empty unpaged)."""
+        where full-attention layers stand beside them."""
         return {kd.name: kd.pool for kd in self._kinds}
 
     def _device_pt(self):
@@ -2655,7 +2557,7 @@ class ServingScheduler:
             decoding=self.S - n_free - n_admitting,
             admitting=n_admitting, free=n_free,
             # the route the tick's attention takes: 1 the int8 Pallas
-            # kernel, 0 the einsum (over gathered views when paged)
+            # kernel, 0 the einsum over gathered views
             kernel=int(self.use_kernel),
             # pages in use when the tick begins, by cache width (where
             # the layers have more than one)
@@ -2669,7 +2571,7 @@ class ServingScheduler:
             **({"latent_rows": sum(
                 self._host_pos[s] for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._admitting)}
-               if self.cfg.latent_layers and self.paged else {}),
+               if self.cfg.latent_layers else {}),
             # a selection of key blocks: the decoding slots that see
             # more than ``sparse_dense_len`` rows as the tick begins,
             # and the blocks they attend and see, summed over those
@@ -2691,12 +2593,11 @@ class ServingScheduler:
             if decoding:
                 with phase("serving.decode", slots=len(decoding),
                            **self._step_route) as decode:
-                    if self.paged:
-                        # COW pass: every page the next n_inner writes
-                        # touch must be exclusively owned BEFORE the
-                        # jitted scan runs (the device program never
-                        # sees shared pages)
-                        self._prepare_tick_pages(decoding)
+                    # COW pass: every page the next n_inner writes
+                    # touch must be exclusively owned BEFORE the
+                    # jitted scan runs (the device program never
+                    # sees shared pages)
+                    self._prepare_tick_pages(decoding)
                     host = self._decode_scan_fetch()
                 with phase("serving.harvest") as harvest:
                     n_tokens = n_retired = 0
@@ -2708,8 +2609,7 @@ class ServingScheduler:
                             req.tokens.extend(int(t) for t in host[s])
                         else:
                             self._deliver_drafted(req, host[s])
-                        if self.paged:
-                            self._host_pos[s] += len(req.tokens) - n_before
+                        self._host_pos[s] += len(req.tokens) - n_before
                         due = self._retire_if_due(req)
                         if self.draft is not None:
                             self._count_drafts(req, n_before)
@@ -2813,7 +2713,7 @@ class ServingScheduler:
 
     def cancel(self, req: Request) -> bool:
         """Withdraw ``req`` wherever it currently is — queued, mid-
-        admission, or decoding — freeing its slot (and, paged, its
+        admission, or decoding — freeing its slot (and its
         pages) for the next request. Returns True when the request was
         live here and is now retired with ``reason == "cancelled"``;
         False when it already finished or was never this scheduler's
@@ -2839,20 +2739,19 @@ class ServingScheduler:
                 st = self._admitting.pop(s, None)
                 if st is not None:
                     self._release_arena(st)
-                    if self.paged:
-                        # mid-admission the slot's pages live in the
-                        # plan (_pt_host[s] stays NULL until finish), so
-                        # _free_slot's table walk would miss them —
-                        # release the committed plan here
-                        n_refs = 0
-                        for kd, pids, wraps in zip(self._kinds, st.pids,
-                                                   st.wraps):
-                            for pid in pids:
-                                if pid != NULL_PAGE:
-                                    kd.pool.decref(int(pid),
-                                                   wrapper=wraps)
-                                    n_refs += 1
-                        self._tenant_debit(req.tenant, n_refs)
+                    # mid-admission the slot's pages live in the
+                    # plan (_pt_host[s] stays NULL until finish), so
+                    # _free_slot's table walk would miss them —
+                    # release the committed plan here
+                    n_refs = 0
+                    for kd, pids, wraps in zip(self._kinds, st.pids,
+                                               st.wraps):
+                        for pid in pids:
+                            if pid != NULL_PAGE:
+                                kd.pool.decref(int(pid),
+                                               wrapper=wraps)
+                                n_refs += 1
+                    self._tenant_debit(req.tenant, n_refs)
                 self._free_slot(s)
                 self._retire_cancelled(req)
                 return True
@@ -2888,7 +2787,7 @@ class ServingScheduler:
     def _migration_slot(self, req: Request) -> int | None:
         """The slot of a migratable request: resident, past admission
         (first token emitted), not finished. None otherwise."""
-        if not self.paged or req.finished or not req.tokens:
+        if req.finished or not req.tokens:
             return None
         if self.draft is not None:
             raise ValueError(
@@ -3135,8 +3034,6 @@ class ServingScheduler:
         refusals — a config-mismatched state is False, not a raise, so
         the router's adoption gate can scan a heterogeneous tier
         without crashing the step loop."""
-        if not self.paged:
-            return False
         try:
             self._check_adopt_compat(state)
         except ValueError:
@@ -3151,8 +3048,6 @@ class ServingScheduler:
         pool is statically too small or the config mismatches); the
         two-tier router bounces such tickets back to the prefill tier
         instead of stranding the captured stream."""
-        if not self.paged:
-            return False
         try:
             self._check_adopt_compat(state)
         except ValueError:
@@ -3178,12 +3073,6 @@ class ServingScheduler:
         never perturbed. ``request``: override the continued request
         object (cross-process adoption rebuilds one; in-process the
         captured object rides in ``state`` and keeps streaming)."""
-        if not self.paged:
-            raise ValueError(
-                "adopt_page_state on an unpaged scheduler: migration "
-                "is a page-layout transfer (construct with "
-                "page_tokens=)"
-            )
         self._check_adopt_compat(state)
         plan = self._plan_adopt(state, reclaim=True)
         if plan is None:
@@ -3240,7 +3129,7 @@ class ServingScheduler:
             for cl in state["ring"]
         ]
         (self._caches, self._tok, self._pos, self._done,
-         self._keys) = self._place_p(
+         self._keys) = self._place(
             self._caches, ring, self._tok, self._pos, self._done,
             self._keys, jnp.asarray(self._pt_host[s]),
             jnp.int32(s), jnp.int32(state["tok"]),
@@ -3280,18 +3169,16 @@ class ServingScheduler:
             self._admit_drr(free, retired)
             return
         while self._queue and free:
-            plan = None
-            if self.paged:
-                plan = self._plan_pages(self._queue[0])
-                if plan is None:
-                    # head-of-line request does not fit the page
-                    # budget: admission waits for retirements to
-                    # return pages (FIFO — no reordering, so a large
-                    # request cannot be starved by later small ones;
-                    # the qos= DRR hook above is the per-TENANT
-                    # alternative, where only that tenant's queue
-                    # defers and the rotation tries the next)
-                    break
+            plan = self._plan_pages(self._queue[0])
+            if plan is None:
+                # head-of-line request does not fit the page
+                # budget: admission waits for retirements to
+                # return pages (FIFO — no reordering, so a large
+                # request cannot be starved by later small ones;
+                # the qos= DRR hook above is the per-TENANT
+                # alternative, where only that tenant's queue
+                # defers and the rotation tries the next)
+                break
             s = free.pop(0)
             req = self._queue.popleft()
             self._admit_into(s, req, plan, retired)
@@ -3313,13 +3200,11 @@ class ServingScheduler:
             if pick is None:
                 return
             tenant, req, cost = pick
-            plan = None
-            if self.paged:
-                plan = self._plan_pages_qos(req)
-                if plan is None:
-                    self._drr.restore(tenant, req, cost)
-                    deferred.add(tenant)
-                    continue
+            plan = self._plan_pages_qos(req)
+            if plan is None:
+                self._drr.restore(tenant, req, cost)
+                deferred.add(tenant)
+                continue
             s = free.pop(0)
             self._admit_into(s, req, plan, retired)
 
@@ -3347,14 +3232,11 @@ class ServingScheduler:
                     retired: list[Request]) -> None:
         """Install one dequeued request into free slot ``s`` (the
         admission body both the FIFO and DRR paths share); ``plan`` is
-        the committed-page plan on paged schedulers, None otherwise."""
+        the committed-page plan."""
         Tp = req.prompt.size
-        base = 0
-        admit_kw: dict[str, Any] = {}
         with _annotate("serving.admit_new", req=req.id, slot=s,
                        prompt_tokens=Tp) as span:
-            if self.paged:
-                base, admit_kw = self._commit_pages(req, plan)
+            base, admit_kw = self._commit_pages(req, plan)
             rem = Tp - base
             n_chunks = -(-rem // self.C)
             padded = np.zeros((1, n_chunks * self.C), np.int32)
@@ -3362,7 +3244,7 @@ class ServingScheduler:
             cache, arena = self._take_arena()
             span.set_metadata(
                 chunks=n_chunks,
-                shared_pages=base // self.P if self.paged else 0,
+                shared_pages=base // self.P,
                 arena=arena,
             )
             if base:
@@ -3816,7 +3698,7 @@ class ServingScheduler:
         if self._group == 1:
             return True
         return st.next_chunk + 1 == st.n_chunks and bool(
-            (self.paged and self.shares_prefixes and st.n_cover)
+            (self.shares_prefixes and st.n_cover)
             or st.req.max_new == 1 or self.eos_id is not None
         )
 
@@ -3926,38 +3808,31 @@ class ServingScheduler:
             )
             # _finish read the arena without donating it: recycle it
             self._release_arena(st)
-            if self.paged:
-                # install the page table NOW (stale row writes landed in
-                # the null page until this point), then scatter the ring
-                # window into the pages and flip the row live
-                for kd, pids, wraps in zip(self._kinds, st.pids,
-                                           st.wraps):
-                    kd.pt_host[s] = pids
-                    kd.slot_wraps[s] = wraps
-                self._pt_dev = None
-                self._host_pos[s] = Tp
-                (self._caches, self._tok, self._pos, self._done,
-                 self._keys) = self._place_p(
-                    self._caches, ring, self._tok, self._pos, self._done,
-                    self._keys,
-                    self._pt_rows(kd.pt_host[s] for kd in self._kinds),
-                    np.int32(s), tok0, np.int32(Tp), rkey,
-                )
-                # the prompt-covered pages now hold exactly the content
-                # their chained prefix digests describe — publish them for
-                # future admissions to share (first-wins; the shared ones
-                # are already registered)
-                for kd, pids, wraps in zip(self._kinds, st.pids,
-                                           st.wraps):
-                    for j in range(st.n_cover):
-                        kd.pool.register(st.digests[j], pids[j],
-                                         volatile=wraps)
-            else:
-                (self._caches, self._tok, self._pos, self._done,
-                 self._keys) = self._place(
-                    self._caches, ring, self._tok, self._pos, self._done,
-                    self._keys, np.int32(s), tok0, np.int32(Tp), rkey,
-                )
+            # install the page table NOW (stale row writes landed in
+            # the null page until this point), then scatter the ring
+            # window into the pages and flip the row live
+            for kd, pids, wraps in zip(self._kinds, st.pids,
+                                       st.wraps):
+                kd.pt_host[s] = pids
+                kd.slot_wraps[s] = wraps
+            self._pt_dev = None
+            self._host_pos[s] = Tp
+            (self._caches, self._tok, self._pos, self._done,
+             self._keys) = self._place(
+                self._caches, ring, self._tok, self._pos, self._done,
+                self._keys,
+                self._pt_rows(kd.pt_host[s] for kd in self._kinds),
+                np.int32(s), tok0, np.int32(Tp), rkey,
+            )
+            # the prompt-covered pages now hold exactly the content
+            # their chained prefix digests describe — publish them for
+            # future admissions to share (first-wins; the shared ones
+            # are already registered)
+            for kd, pids, wraps in zip(self._kinds, st.pids,
+                                       st.wraps):
+                for j in range(st.n_cover):
+                    kd.pool.register(st.digests[j], pids[j],
+                                     volatile=wraps)
         # the one place admission blocks on the device: the request's
         # first token comes back before the tick's decode is dispatched
         with _annotate("serving.first_token_wait", req=rid):
@@ -4027,56 +3902,55 @@ class ServingScheduler:
         # the row keeps decoding garbage until reused — done=True makes
         # it emit EOS-clamped tokens nobody reads; admission resets it
         self._done = self._done.at[s].set(True)
-        if self.paged:
-            # return the slot's pages (shared prefixes just drop one
-            # reference; a page frees — and leaves the prefix table —
-            # only when its last reader retires) and null the row so
-            # its zombie writes land in the null page. Under qos=, a
-            # sole-held page whose prefix digest is still registered
-            # goes COLD instead of freeing (the reclaim contract
-            # above): resident for future sharers, attributed to the
-            # departing tenant, evicted oldest-first under pressure.
-            tenant = (req.tenant if self._drr is not None
-                      and req is not None else None)
-            keep_cold = tenant is not None and not self._slot_wraps[s]
-            n_refs = 0
-            for pid in self._pt_host[s]:
-                if pid == NULL_PAGE:
-                    continue
-                pid = int(pid)
-                n_refs += 1
-                if (keep_cold and self.pool.refcount(pid) == 1
+        # return the slot's pages (shared prefixes just drop one
+        # reference; a page frees — and leaves the prefix table —
+        # only when its last reader retires) and null the row so
+        # its zombie writes land in the null page. Under qos=, a
+        # sole-held page whose prefix digest is still registered
+        # goes COLD instead of freeing (the reclaim contract
+        # above): resident for future sharers, attributed to the
+        # departing tenant, evicted oldest-first under pressure.
+        tenant = (req.tenant if self._drr is not None
+                  and req is not None else None)
+        keep_cold = tenant is not None and not self._slot_wraps[s]
+        n_refs = 0
+        for pid in self._pt_host[s]:
+            if pid == NULL_PAGE:
+                continue
+            pid = int(pid)
+            n_refs += 1
+            if (keep_cold and self.pool.refcount(pid) == 1
+                    and self.pool.registered(pid)):
+                self._cold[pid] = tenant
+                self._cold_count[tenant] = (
+                    self._cold_count.get(tenant, 0) + 1
+                )
+            else:
+                # fleet spill: a sole-held registered page is
+                # about to free (and leave the share table) —
+                # offer its bytes to the DRAM tier first, so a
+                # sibling's future admission fetches instead of
+                # re-prefilling. Cold retention above takes
+                # precedence (HBM residency beats DRAM); eviction
+                # of the cold set spills on its own path.
+                if (self.cache is not None
+                        and not self._slot_wraps[s]
+                        and self.pool.refcount(pid) == 1
                         and self.pool.registered(pid)):
-                    self._cold[pid] = tenant
-                    self._cold_count[tenant] = (
-                        self._cold_count.get(tenant, 0) + 1
-                    )
-                else:
-                    # fleet spill: a sole-held registered page is
-                    # about to free (and leave the share table) —
-                    # offer its bytes to the DRAM tier first, so a
-                    # sibling's future admission fetches instead of
-                    # re-prefilling. Cold retention above takes
-                    # precedence (HBM residency beats DRAM); eviction
-                    # of the cold set spills on its own path.
-                    if (self.cache is not None
-                            and not self._slot_wraps[s]
-                            and self.pool.refcount(pid) == 1
-                            and self.pool.registered(pid)):
-                        self._spill_page(pid, tenant=tenant)
-                    self.pool.decref(pid,
-                                     wrapper=self._slot_wraps[s])
-            self._tenant_debit(tenant, n_refs)
-            self._pt_host[s] = NULL_PAGE
-            self._slot_wraps[s] = False
-            # the wider kinds' pages (quotas and the fleet cache are
-            # one-width features, so nothing goes cold or spills here)
-            for kd in self._kinds[1:]:
-                for pid in kd.pt_host[s]:
-                    if pid != NULL_PAGE:
-                        kd.pool.decref(int(pid),
-                                       wrapper=kd.slot_wraps[s])
-                kd.pt_host[s] = NULL_PAGE
-                kd.slot_wraps[s] = False
-            self._pt_dev = None
-            self._host_pos[s] = 0
+                    self._spill_page(pid, tenant=tenant)
+                self.pool.decref(pid,
+                                 wrapper=self._slot_wraps[s])
+        self._tenant_debit(tenant, n_refs)
+        self._pt_host[s] = NULL_PAGE
+        self._slot_wraps[s] = False
+        # the wider kinds' pages (quotas and the fleet cache are
+        # one-width features, so nothing goes cold or spills here)
+        for kd in self._kinds[1:]:
+            for pid in kd.pt_host[s]:
+                if pid != NULL_PAGE:
+                    kd.pool.decref(int(pid),
+                                   wrapper=kd.slot_wraps[s])
+            kd.pt_host[s] = NULL_PAGE
+            kd.slot_wraps[s] = False
+        self._pt_dev = None
+        self._host_pos[s] = 0

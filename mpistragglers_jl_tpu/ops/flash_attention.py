@@ -64,8 +64,10 @@ _LANE = 128  # TPU lane width; m/l scratch is broadcast across lanes
 # Not taken: q carrying the scale (reads nothing, rounds q anew), p and
 # ds cast to bfloat16 (+0.1 / +0.2 and the same bits: Mosaic's default
 # precision already feeds a float32 operand in one bfloat16 pass).
-# bwd_impl="fused" beside the split kernels' 17.84: 21.82. Other shapes
-# and each candidate alone: PERF.md section 6, PR 38.
+# No fused backward: one kernel sharing s and dp (5 tile products, not 7)
+# for float32 partials of dk and dv a q block read 21.82 beside the split
+# kernels' 17.84. Other shapes and each candidate alone: PERF.md section
+# 6, PR 38.
 _BLOCK = 2048  # default side of the blocks the grid fetches
 _TILE = 512  # side of the sub-tile a fetched block is computed in
 _WHOLE = 1024  # most that a block computed whole spans (no tile divides it)
@@ -135,8 +137,7 @@ def _compute_tile(bq: int, bk: int) -> tuple[int, int]:
 _VMEM_BUDGET = 16 * 2 ** 20  # Mosaic's scoped VMEM allocation (bytes)
 
 
-def _vmem_estimate(bq: int, bk: int, D: int, itemsize: int,
-                   whole: bool = False) -> int:
+def _vmem_estimate(bq: int, bk: int, D: int, itemsize: int) -> int:
     """Bytes of VMEM a grid step of the heaviest kernel works in: the
     pipeline's two buffers of every fetched block (q, do and the dq
     output; k and v; the dq kernel's lse and delta columns, which a
@@ -145,8 +146,7 @@ def _vmem_estimate(bq: int, bk: int, D: int, itemsize: int,
     accumulators) and two float32 intermediates of one COMPUTE tile
     (score / probability, dp / ds): since the kernels compute a block
     in sub-tiles (:func:`_compute_tile`), a block's footprint grows
-    with its rows and not with its area. ``whole`` is the fused
-    backward's, whose kernel computes a block whole.
+    with its rows and not with its area.
 
     It errs high. The scoped allocation that Mosaic holds to the budget
     is the last two terms alone (the described v5e's compiler, the
@@ -158,7 +158,7 @@ def _vmem_estimate(bq: int, bk: int, D: int, itemsize: int,
     the windows as well keeps float32 at head_dim 128 and every wider
     head in the 1024-blocks they had before: the 2048-blocks were read
     on the chip in bfloat16 at head_dim 128 alone."""
-    tq, tk = (bq, bk) if whole else _compute_tile(bq, bk)
+    tq, tk = _compute_tile(bq, bk)
     return (
         2 * itemsize * (3 * bq * D + 2 * bk * D)
         + 2 * 2 * 4 * bq * _LANE
@@ -168,7 +168,7 @@ def _vmem_estimate(bq: int, bk: int, D: int, itemsize: int,
 
 
 def _blocks(Lq: int, Lk: int, D: int, itemsize: int, block_q: int,
-            block_k: int, whole: bool = False):
+            block_k: int):
     """(bq, bk, tile): the blocks the grid fetches and the tile the
     kernels compute, chosen in this one place for the kernels and for
     :func:`block_plan`. ``block_q`` / ``block_k`` are upper bounds:
@@ -176,10 +176,9 @@ def _blocks(Lq: int, Lk: int, D: int, itemsize: int, block_q: int,
     :func:`_vmem_estimate` says the working set does not fit the budget
     the larger side is halved until it does (the default 2048-blocks
     were read on the chip in bfloat16 at head_dim 128 alone; float32 or
-    a wider head takes the 1024 the kernels had until PR 38). ``whole``
-    is :func:`_vmem_estimate`'s."""
+    a wider head takes the 1024 the kernels had until PR 38)."""
     bq, bk = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
-    while _vmem_estimate(bq, bk, D, itemsize, whole) > _VMEM_BUDGET:
+    while _vmem_estimate(bq, bk, D, itemsize) > _VMEM_BUDGET:
         half = max(bq, bk) // 2
         smaller = (_pick_block(Lq, min(bq, half)),
                    _pick_block(Lk, min(bk, half)))
@@ -189,14 +188,13 @@ def _blocks(Lq: int, Lk: int, D: int, itemsize: int, block_q: int,
     return bq, bk, _compute_tile(bq, bk)
 
 
-def _check_vmem(bq: int, bk: int, D: int, itemsize: int,
-                whole: bool = False) -> None:
+def _check_vmem(bq: int, bk: int, D: int, itemsize: int) -> None:
     """Reject blocks that cannot fit VMEM, with a clear error instead
     of an opaque Mosaic mid-compile allocation failure: the odd-length
     whole-dimension fallback (see :func:`_pick_block`), which
     :func:`_blocks` cannot shrink, and a head too wide for the blocks
     asked for."""
-    est = _vmem_estimate(bq, bk, D, itemsize, whole)
+    est = _vmem_estimate(bq, bk, D, itemsize)
     if est > _VMEM_BUDGET:
         aligned = bq % 8 == 0 and bk % 8 == 0
         why = (
@@ -612,134 +610,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dkp_ref, dvp_ref, dq_acc,
-                      *, scale, causal, window, bq, bk, nk):
-    """Single-pass backward: one (i, j) sweep computes dq (accumulated
-    over the inner j sweep in scratch) AND per-q-block dk/dv partials
-    (reduced outside). The split kernels recompute s and dp twice —
-    7 block-dots + 2 exps per (i, j); this shares them: 5 dots + 1 exp,
-    a ~25% executed-FLOP cut exactly where the short-sequence
-    attention tax lives."""
-    i, j = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    run = _block_run(i, j, bq, bk, causal, window)
-
-    @pl.when(run)
-    def _update():
-        q = q_ref[0]
-        kb = k_ref[0]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        mask = _block_mask(i, j, bq, bk, causal, window)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG)
-        p = jnp.exp(s - lse_ref[0])  # (bq, bk)
-        do = do_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0])
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, kb.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dvp_ref[0, 0] = jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dvp_ref.dtype)
-        dkp_ref[0, 0] = (
-            jax.lax.dot_general(
-                ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-        ).astype(dkp_ref.dtype)
-
-    if causal or window is not None:
-        @pl.when(jnp.logical_not(run))
-        def _zero():
-            # skipped band-exterior blocks still own their partial block
-            dkp_ref[0, 0] = jnp.zeros_like(dkp_ref[0, 0])
-            dvp_ref[0, 0] = jnp.zeros_like(dvp_ref[0, 0])
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
-
-
-def _bwd_fused(q3, k3, v3, o3, lse, do3, scale, causal, window, bq, bk,
-               g, interpret):
-    """Fused backward dispatch: dq + f32 dk/dv partials per q block,
-    reduced by one XLA sum (and group-summed for GQA). Partial HBM is
-    (BH, nq, Lk, D) f32 — the traffic that made this variant measure
-    SLOWER than the split kernels on the chip (``_use_fused_bwd``);
-    it runs only under an explicit ``bwd_impl="fused"``."""
-    BH, Lq, D = q3.shape
-    Lk = k3.shape[1]
-    nq, nk = Lq // bq, Lk // bk
-    delta = jnp.sum(
-        do3.astype(jnp.float32) * o3.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
-    dq, dkp, dvp = pl.pallas_call(
-        functools.partial(
-            _bwd_fused_kernel, scale=scale, causal=causal, window=window,
-            bq=bq, bk=bk, nk=nk,
-        ),
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // g, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, i, j: (b, i, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, i, j: (b, i, j, 0)),
-        ],
-        out_shape=[
-            _sds((BH, Lq, D), q3.dtype, q3),
-            _sds((BH, nq, Lk, D), jnp.float32, k3),
-            _sds((BH, nq, Lk, D), jnp.float32, v3),
-        ],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_grid_params(),
-        interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
-    BHkv = BH // g
-    dk = (
-        dkp.reshape(BHkv, g * nq, Lk, D).sum(axis=1).astype(k3.dtype)
-    )
-    dv = (
-        dvp.reshape(BHkv, g * nq, Lk, D).sum(axis=1).astype(v3.dtype)
-    )
-    return dq, dk, dv
-
-
-def _use_fused_bwd() -> bool:
-    """auto -> split, always. The fused kernel shares the recompute of
-    s and dp (5 tile products where the split kernels make 7) and pays
-    with (BH, nq, Lk, D) float32 partials of dk and dv, written a q
-    block and summed outside. Read once on this installation (my chip
-    run, PR 38; the training cell's shape, one layer's whole backward):
-    21.82 ms against the split kernels' 17.84, and beside the split
-    kernels as they were until PR 38 (21.17) no better either. Kept
-    selectable (bwd_impl="fused") until a ``simplicity`` issue decides
-    on that number (ROADMAP D5); it computes a block whole and builds
-    its mask in every block that runs."""
-    return False
-
-
 @functools.partial(
     jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13), inline=True)
 def _bwd(q3, k3, v3, o3, lse, do3, scale, causal, window, bq, bk, tile,
@@ -841,28 +711,24 @@ def _bwd(q3, k3, v3, o3, lse, do3, scale, causal, window, bq, bk, tile,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
-def _flash3(q3, k3, v3, scale, causal, window, bq, bk, tile, g, fused_bwd,
-            interpret):
+def _flash3(q3, k3, v3, scale, causal, window, bq, bk, tile, g, interpret):
     o, _ = _fwd(q3, k3, v3, scale, causal, window, bq, bk, tile, g,
                 interpret)
     return o
 
 
 def _flash3_fwd(q3, k3, v3, scale, causal, window, bq, bk, tile, g,
-                fused_bwd, interpret):
+                interpret):
     o, lse = _fwd(q3, k3, v3, scale, causal, window, bq, bk, tile, g,
                   interpret)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash3_bwd(scale, causal, window, bq, bk, tile, g, fused_bwd,
-                interpret, res, do3):
+def _flash3_bwd(scale, causal, window, bq, bk, tile, g, interpret, res,
+                do3):
     q3, k3, v3, o3, lse = res
-    if fused_bwd:
-        return _bwd_fused(q3, k3, v3, o3, lse, do3, scale, causal, window,
-                          bq, bk, g, interpret)
     return _bwd(q3, k3, v3, o3, lse, do3, scale, causal, window, bq, bk,
                 tile, g, interpret)
 
@@ -880,7 +746,6 @@ def flash_attention(
     window: int | None = None,
     block_q: int = _BLOCK,
     block_k: int = _BLOCK,
-    bwd_impl: str = "auto",
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused flash attention on (B, L, H, D) tensors; differentiable.
@@ -900,15 +765,6 @@ def flash_attention(
     by its kind (:func:`_for_tiles`); a length that no multiple of 512
     divides is taken in blocks of at most 1024 computed whole, as until
     PR 38. Readings: the table above ``_BLOCK``.
-
-    ``bwd_impl``: ``"split"`` runs the classic two backward kernels
-    (dq over the k sweep; dk/dv over the q sweep — each recomputes
-    s/dp, 7 tile products in all); ``"fused"`` runs one kernel sharing
-    the recompute (5 products) at the cost of an (BH, nq, Lk, D) f32
-    dk/dv-partial buffer reduced outside, in blocks that it computes
-    whole. ``"auto"`` (default) resolves to split: the
-    fused variant read slower at the training cell's shape (21.8
-    against 17.8 ms a layer, see ``_use_fused_bwd``).
     """
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -922,18 +778,9 @@ def flash_attention(
         scale = D ** -0.5
     if interpret is None:
         interpret = _use_interpret()
-    if bwd_impl == "auto":
-        fused_bwd = _use_fused_bwd()
-    elif bwd_impl in ("split", "fused"):
-        fused_bwd = bwd_impl == "fused"
-    else:
-        raise ValueError(
-            f"bwd_impl must be 'auto'|'split'|'fused', got {bwd_impl!r}"
-        )
-    bq, bk, tile = _blocks(Lq, Lk, D, q.dtype.itemsize, block_q, block_k,
-                           whole=fused_bwd)
+    bq, bk, tile = _blocks(Lq, Lk, D, q.dtype.itemsize, block_q, block_k)
     if not interpret:  # the interpreter has no VMEM to blow
-        _check_vmem(bq, bk, D, q.dtype.itemsize, whole=fused_bwd)
+        _check_vmem(bq, bk, D, q.dtype.itemsize)
 
     def to3(x, L, h):
         return x.transpose(0, 2, 1, 3).reshape(B * h, L, D)
@@ -944,6 +791,6 @@ def flash_attention(
         to3(q, Lq, H), to3(k, Lk, Hkv), to3(v, Lk, Hkv),
         float(scale), bool(causal),
         None if window is None else int(window), bq, bk, tile, g,
-        fused_bwd, bool(interpret),
+        bool(interpret),
     )
     return o3.reshape(B, H, Lq, D).transpose(0, 2, 1, 3)

@@ -24,7 +24,7 @@ from mpistragglers_jl_tpu.models.transformer import (
     init_params,
 )
 
-W = 128  # every case's window: two pages of 64, or one 128-row ring
+W = 128  # every case's window: two pages of 64, or one page of 128
 
 
 @functools.lru_cache(maxsize=None)
@@ -39,7 +39,8 @@ def _model(head_dim: int, n_heads: int, kv_heads: int):
 
 # (quantize_kv, head_dim, n_heads, kv_heads, rows, page_tokens) -> kernel?
 # rows: the dense program's batch and the scheduler's slots;
-# page_tokens None: the slot ring
+# page_tokens None: the dense programs' rings (the generators, the
+# sharded tick), and the scheduler at one page a window
 ROUTES = {
     "ring_at_min_batch": (True, 128, 2, 1, KERNEL_MIN_BATCH, None, True),
     "ring_under_min_batch": (
@@ -65,15 +66,15 @@ def test_decode_route_table(case):
     quantize_kv, head_dim, n_heads, kv_heads, rows, P, kernel = ROUTES[case]
     cfg, params = _model(head_dim, n_heads, kv_heads)
     if P is None:
-        possible = _kernel_possible(cfg, quantize_kv)
-    else:
-        possible = _paged_kernel_possible(cfg, quantize_kv, P)
-        # what a page adds can only refuse
-        assert _kernel_possible(cfg, quantize_kv) or not possible
+        assert (_kernel_possible(cfg, quantize_kv)
+                and _route_kernel(rows)) == kernel
+        P = W
+    possible = _paged_kernel_possible(cfg, quantize_kv, P)
+    # what a page adds can only refuse
+    assert _kernel_possible(cfg, quantize_kv) or not possible
     assert (possible and _route_kernel(rows)) == kernel
     sched = ServingScheduler(
         params, cfg, slots=rows, n_inner=2, prompt_chunk=16,
         max_prompt=32, quantize_kv=quantize_kv, page_tokens=P,
     )
-    assert sched.paged == (P is not None)
     assert sched.use_kernel == kernel

@@ -277,12 +277,6 @@ class TestMigrationContract:
             _sched(quantize_kv=True).adopt_page_state(dict(st))
         with pytest.raises(ValueError, match="temperature mismatch"):
             _sched(temperature=0.5).adopt_page_state(dict(st))
-        # unpaged destinations cannot adopt at all
-        dense = ServingScheduler(PARAMS, CFG, slots=2, n_inner=2,
-                                 prompt_chunk=8, max_prompt=64)
-        with pytest.raises(ValueError, match="unpaged"):
-            dense.adopt_page_state(dict(st))
-        assert dense.can_adopt_state(dict(st)) is False
 
     def test_can_adopt_state_is_boolean_on_config_mismatch(self):
         """can_adopt_state answers False — never raises — for a

@@ -394,9 +394,9 @@ class TestLiveSpillFetch:
         _drained(b)
         hub.close()
 
-    def test_cache_refused_without_paged_arena(self):
+    def test_cache_refused_without_a_page_size(self):
         hub = FleetPrefixCache()
-        with pytest.raises(ValueError, match="page"):
+        with pytest.raises(TypeError, match="page_tokens"):
             ServingScheduler(PARAMS, CFG, slots=2, cache=hub)
 
     def test_geometry_drift_refused_at_attach(self):

@@ -30,9 +30,6 @@ from mpistragglers_jl_tpu.models.serving import (
     ServingScheduler,
     make_serving_scan,
 )
-from mpistragglers_jl_tpu.models.speculative import (
-    generate_speculative_dense,
-)
 from mpistragglers_jl_tpu.models.transformer import (
     TransformerConfig,
     forward_dense,
@@ -259,8 +256,9 @@ def test_softmax_routing_normalises_over_the_chosen():
 # -- through the scheduler -------------------------------------------------
 
 
-@pytest.mark.parametrize("paged,quantize", [(None, False), (P, False),
-                                            (P, True)])
+@pytest.mark.parametrize("paged,quantize", [
+    (CFG.max_context, False),  # one page a slot
+    (P, False), (P, True)])
 def test_chunks_then_ticks_follow_the_reference(paged, quantize):
     """Prompts of one chunk, of several and of no whole number of
     chunks, more requests than slots: every served token is the
@@ -275,8 +273,7 @@ def test_chunks_then_ticks_follow_the_reference(paged, quantize):
         rows = _reference(seq)[len(p) - 1:len(seq) - 1]
         gap = rows.max(-1) - rows[np.arange(9), r.tokens]
         assert gap.max() <= (0.05 if quantize else 1e-4), gap
-    if paged:
-        assert sched.pool.used == 0
+    assert sched.pool.used == 0
 
 
 def test_dense_generation_carries_the_state_too():
@@ -387,9 +384,6 @@ def test_what_is_written_for_rows_refuses_state_layers():
         make_serving_scan(CFG, mesh, 4)
     with pytest.raises(ValueError, match="gated delta-rule layers"):
         param_specs(CFG, mesh)
-    with pytest.raises(ValueError, match="cannot be rolled back"):
-        generate_speculative_dense(
-            PARAMS, jnp.asarray(_tokens(8))[None], 4, CFG)
     sched = _sched()
     r = sched.submit(_tokens(12), 20)
     sched.step()
@@ -400,7 +394,8 @@ def test_what_is_written_for_rows_refuses_state_layers():
     assert sched.can_adopt_state({}) is False
     with pytest.raises(ValueError, match="none K/V rows"):
         all_gdn = dataclasses.replace(CFG, layer_mixers=("gdn",) * 4)
-        ServingScheduler(init_params(all_gdn, 0), all_gdn, slots=2)
+        ServingScheduler(init_params(all_gdn, 0), all_gdn, slots=2,
+                         page_tokens=P)
 
 
 def test_fields_are_checked_at_construction():

@@ -29,9 +29,6 @@ from mpistragglers_jl_tpu.models.serving import (
     ServingScheduler,
     make_serving_scan,
 )
-from mpistragglers_jl_tpu.models.speculative import (
-    generate_speculative_dense,
-)
 from mpistragglers_jl_tpu.models.transformer import (
     TransformerConfig,
     forward_dense,
@@ -472,10 +469,8 @@ def test_the_latent_kernel_route_is_read_from_the_configuration():
     assert not possible(CFG, True, P)  # a latent of 24: no lane tile
     assert not possible(KCFG, True, 12)  # no whole 8-row tiles
     assert not possible(KCFG, True, 8192)  # a page past the VMEM budget
-    # the positional and slot-ring programs have no latent kernel
+    # the positional and ring programs have no latent kernel
     assert not decode._kernel_possible(KCFG, True)
-    assert not ServingScheduler(KPARAMS, KCFG, slots=4, quantize_kv=True,
-                                prompt_chunk=16, max_prompt=32).use_kernel
 
 
 def test_a_shared_prefix_page_is_shared():
@@ -573,9 +568,6 @@ def test_what_is_written_for_kv_heads_refuses_latent_layers():
         make_serving_scan(CFG, mesh, 4)
     with pytest.raises(ValueError, match="latent-attention layers"):
         param_specs(CFG, mesh)
-    with pytest.raises(ValueError, match="latent-attention layers"):
-        generate_speculative_dense(
-            PARAMS, jnp.asarray(_tokens(8))[None], 4, CFG)
     sched = _sched()
     r = sched.submit(_tokens(12), 20)
     sched.step()
@@ -596,9 +588,6 @@ def test_what_is_written_for_one_stream_refuses_streams():
         param_specs(streams, mesh)
     with pytest.raises(ValueError, match="2 streams"):
         make_serving_scan(streams, mesh, 4)
-    with pytest.raises(ValueError, match="2 streams"):
-        generate_speculative_dense(
-            params, jnp.asarray(_tokens(8))[None], 4, streams)
     # and every other mixer takes the streams: attention under two
     tokens = jnp.asarray(_tokens(12))[None]
     dense = forward_dense(params, tokens, streams)

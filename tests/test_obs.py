@@ -497,7 +497,7 @@ def _sched(cfg, params, **kw):
 
     return ServingScheduler(
         params, cfg, slots=2, n_inner=4, prompt_chunk=8, max_prompt=32,
-        **kw,
+        page_tokens=3, **kw,
     )
 
 

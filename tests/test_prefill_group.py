@@ -179,11 +179,12 @@ def test_the_group_follows_from_what_a_chunk_gives_a_weight():
     assert [_chunk_group_cap(PLAIN, C, s) for s in (1, 2, 3, 9)] == [
         1, 2, 3, 4]
     two = ServingScheduler(init_params(PLAIN, 1), PLAIN, slots=2,
-                           prompt_chunk=C, max_prompt=64)
+                           prompt_chunk=C, max_prompt=64, page_tokens=64)
     assert two._extend_group.__name__ == "serving_prefill_chunk_x2"
     assert two._extend.__name__ == "serving_prefill_chunk"
     wide = ServingScheduler(init_params(PLAIN, 1), PLAIN, slots=4,
-                            prompt_chunk=256, max_prompt=512)
+                            prompt_chunk=256, max_prompt=512,
+                            page_tokens=64)
     assert wide._group == 1 and wide._extend_group is None
     r = wide.submit(np.arange(1, 300) % PLAIN.vocab, max_new=2)
     wide.run()
@@ -254,7 +255,8 @@ LENGTHS = (5, 12, 20, 27, 36, 44, 52, 61)
 
 
 @pytest.mark.parametrize("quantize_kv", [True, False], ids=["int8", "bf16"])
-@pytest.mark.parametrize("page_tokens", [4, None], ids=["paged", "ring"])
+@pytest.mark.parametrize("page_tokens", [4, 16],
+                         ids=["paged", "one_page_a_window"])
 def test_streams_of_concurrent_admissions_match_the_oracle(
         spans, page_tokens, quantize_kv):
     sched = _sched(page_tokens=page_tokens, quantize_kv=quantize_kv)
@@ -315,13 +317,15 @@ def test_a_tick_dispatches_the_greedy_splits_number_of_programs(spans):
     # pages of 4: the 5-token prompt registers a page the next request's
     # plan may share, so its admission ends before that plan is made
     ({"page_tokens": 4}, [1, 4, 3]),
-    # rings register nothing: its one chunk waits for the other seven
-    ({"page_tokens": None}, [4, 4]),
+    # one page a window: the 5-token prompt fills none and registers
+    # nothing, so its one chunk waits for the other seven
+    ({"page_tokens": 16}, [4, 4]),
     # ... unless its first token may be the EOS that frees its slot
-    ({"page_tokens": None, "eos_id": 0}, [1, 4, 3]),
+    ({"page_tokens": 16, "eos_id": 0}, [1, 4, 3]),
     # a prompt shorter than a page registers nothing either
     ({"page_tokens": 8}, [4, 4]),
-], ids=["pages_to_register", "ring", "may_retire_at_once", "short_of_a_page"])
+], ids=["pages_to_register", "one_page_a_window", "may_retire_at_once",
+        "short_of_a_page"])
 def test_an_admission_ends_before_the_next_plan_only_where_it_binds_it(
         spans, kw, first):
     sched = _sched(**kw)

@@ -242,7 +242,8 @@ def _registry(**tenants):
 def test_scheduler_submit_requires_known_tenant():
     reg = _registry(a={})
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=4,
-                             prompt_chunk=8, max_prompt=32, qos=reg)
+                             prompt_chunk=8, max_prompt=32, qos=reg,
+                             page_tokens=3)
     with pytest.raises(ValueError, match="needs tenant="):
         sched.submit(_prompt(4), max_new=4)
     with pytest.raises(KeyError, match="unknown tenant 'ghost'"):
@@ -250,7 +251,7 @@ def test_scheduler_submit_requires_known_tenant():
     with pytest.raises(ValueError, match="at least one TenantContract"):
         ServingScheduler(PARAMS, CFG, slots=2, n_inner=4,
                          prompt_chunk=8, max_prompt=32,
-                         qos=TenantRegistry())
+                         qos=TenantRegistry(), page_tokens=3)
 
 
 def test_qos_streams_match_oracle_token_for_token():
@@ -282,7 +283,8 @@ def test_drr_admission_order_two_to_one_on_the_real_scheduler():
     not FIFO."""
     reg = _registry(a=dict(weight=2.0), b=dict(weight=1.0))
     sched = ServingScheduler(PARAMS, CFG, slots=1, n_inner=4,
-                             prompt_chunk=8, max_prompt=32, qos=reg)
+                             prompt_chunk=8, max_prompt=32, qos=reg,
+                             page_tokens=3)
     reqs = []
     for i in range(6):
         reqs.append((

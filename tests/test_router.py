@@ -70,6 +70,7 @@ def _sched(**kw):
     kw.setdefault("n_inner", 4)
     kw.setdefault("prompt_chunk", 8)
     kw.setdefault("max_prompt", 64)
+    kw.setdefault("page_tokens", 3)
     return ServingScheduler(PARAMS, CFG, **kw)
 
 
@@ -293,7 +294,7 @@ class TestLiveRouting:
                 return super().step()
 
         slow = Stalled(PARAMS, CFG, slots=2, n_inner=4,
-                       prompt_chunk=8, max_prompt=64)
+                       prompt_chunk=8, max_prompt=64, page_tokens=3)
         fast = _sched()
         router = RequestRouter([slow, fast], policy="hedge_p99",
                                ttft_slo=0.05)

@@ -229,7 +229,7 @@ def test_scheduler_serves_the_references_tokens(model, quantize):
     sched, reqs = _serve(cfg, params,
                          [(tokens(a, seed=a), b) for a, b in PROMPTS],
                          quantize)
-    assert sched.paged and not sched.use_kernel
+    assert not sched.use_kernel
     assert not sched.shares_prefixes
     assert all(len(r.tokens) == b for r, (_, b) in zip(reqs, PROMPTS))
     assert _gaps(params, reqs).max() <= (2e-3 if quantize else 1e-6)
@@ -333,19 +333,16 @@ def test_other_paths_refuse_by_mechanism(model):
     cfg, params = model
     from jax.sharding import Mesh
 
-    from mpistragglers_jl_tpu.models import speculative
     from mpistragglers_jl_tpu.models.transformer import make_train_step
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1),
                 ("dp", "sp", "tp"))
     with pytest.raises(ValueError, match="recurrent state"):
         make_train_step(cfg, mesh)
-    with pytest.raises(ValueError, match="one fixed block"):
+    with pytest.raises(ValueError, match="sharded tick.*recurrent state"):
         serving.make_serving_scan(cfg, mesh, 4)
     with pytest.raises(ValueError, match="has no width"):
         decode.ring_widths(cfg)
-    with pytest.raises(ValueError, match="cannot be rolled back"):
-        speculative._check_draft_layers(cfg, 2)
     with pytest.raises(ValueError, match="page_tokens 16 must be "
                        "sparse_block 8"):
         ServingScheduler(params, cfg, slots=2, page_tokens=16,
@@ -359,8 +356,6 @@ def test_other_paths_refuse_by_mechanism(model):
         decode.ring_widths(rows_only)
     with pytest.raises(ValueError, match="selection of their key blocks"):
         serving.make_serving_scan(rows_only, mesh, 4)
-    with pytest.raises(ValueError, match="cannot be taken back"):
-        speculative._check_draft_layers(rows_only, 1)
     with pytest.raises(ValueError, match="take no window"):
         TransformerConfig(n_layers=2, attn_window=8, **SIZES)
 

@@ -52,7 +52,7 @@ def _oracle(prompt, n_new, eos_id=None):
 
 def test_single_request_matches_oracle():
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=4,
-                             prompt_chunk=8, max_prompt=64)
+                             prompt_chunk=8, max_prompt=64, page_tokens=3)
     p = _prompt(5)
     r = sched.submit(p, max_new=13)
     sched.run()
@@ -65,7 +65,7 @@ def test_batch_matches_oracle_every_request():
     stream equals its independent oracle (batching changes wall-clock,
     never content)."""
     sched = ServingScheduler(PARAMS, CFG, slots=4, n_inner=4,
-                             prompt_chunk=8, max_prompt=64)
+                             prompt_chunk=8, max_prompt=64, page_tokens=3)
     reqs = [
         (sched.submit(p, max_new=n), p, n)
         for p, n in [(_prompt(3), 9), (_prompt(11), 6), (_prompt(8), 17),
@@ -84,7 +84,7 @@ def test_admission_queues_beyond_slots_and_reuses():
     (the kpos mask + row overwrite discipline)."""
     S = 2
     sched = ServingScheduler(PARAMS, CFG, slots=S, n_inner=2,
-                             prompt_chunk=8, max_prompt=32)
+                             prompt_chunk=8, max_prompt=32, page_tokens=3)
     reqs = [(sched.submit(_prompt(4 + i), max_new=5 + i), 4 + i, 5 + i)
             for i in range(6)]
     assert sched.pending == 6 - 0  # nothing admitted before a tick
@@ -102,7 +102,7 @@ def test_straggling_requests_slot_reuse_mid_flight():
     short requests retire and their slots serve late arrivals; the
     long-running request's stream is unperturbed."""
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=2,
-                             prompt_chunk=8, max_prompt=32)
+                             prompt_chunk=8, max_prompt=32, page_tokens=3)
     p_long = _prompt(6)
     r_long = sched.submit(p_long, max_new=24)
     p_short = _prompt(3)
@@ -134,7 +134,8 @@ def test_eos_retirement():
     free_run = _oracle(p, 16)
     eos = free_run[3]
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=4,
-                             prompt_chunk=8, max_prompt=32, eos_id=eos)
+                             prompt_chunk=8, max_prompt=32, eos_id=eos,
+                             page_tokens=3)
     r = sched.submit(p, max_new=16)
     sched.run()
     assert r.finished and r.reason == "eos"
@@ -147,7 +148,7 @@ def test_chunked_prefill_interleaves_with_decode():
     producing tokens during the admission ticks (the bounded-stall
     property), and the long prompt's stream still matches its oracle."""
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=2,
-                             prompt_chunk=4, max_prompt=64)
+                             prompt_chunk=4, max_prompt=64, page_tokens=3)
     r_first = sched.submit(_prompt(4), max_new=40)  # admits in 1 chunk
     sched.step()
     tokens_before = len(r_first.tokens)
@@ -164,7 +165,7 @@ def test_chunked_prefill_interleaves_with_decode():
 
 def test_request_validation():
     sched = ServingScheduler(PARAMS, CFG, slots=1, n_inner=1,
-                             prompt_chunk=4, max_prompt=8)
+                             prompt_chunk=4, max_prompt=8, page_tokens=3)
     with pytest.raises(ValueError, match="exceeds max_prompt"):
         sched.submit(_prompt(9), max_new=2)
     with pytest.raises(ValueError, match="max_new"):
@@ -173,12 +174,30 @@ def test_request_validation():
         Request(np.zeros(0, np.int32), 3)
     no_window = dataclasses.replace(CFG, attn_window=None)
     with pytest.raises(ValueError, match="ring cache"):
-        ServingScheduler(PARAMS, no_window, slots=1)
+        ServingScheduler(PARAMS, no_window, slots=1, page_tokens=3)
     moe = dataclasses.replace(
         CFG, n_experts=2, d_model=64, attn="ulysses"
     )
     with pytest.raises(ValueError, match="dense-FFN"):
-        ServingScheduler(init_params(moe, seed=1), moe, slots=1)
+        ServingScheduler(init_params(moe, seed=1), moe, slots=1, page_tokens=3)
+
+
+@pytest.mark.parametrize("block,names", [
+    (dict(norm="rmsnorm", ffn="swiglu"), "a block other than"),
+    (dict(tie_head=False), "a block other than"),
+    (dict(mtp_depth=1), "multi-token-prediction module"),
+    (dict(layer_windows=(6, None), max_context=32),
+     "more than one cache width"),
+], ids=["rmsnorm_swiglu", "untied_head", "mtp_module", "two_widths"])
+def test_the_sharded_tick_refuses_what_the_whitelist_excludes(block, names):
+    """``make_serving_scan`` asks ``require_plain_block``: whatever the
+    sharded layout is not written for is refused under the tick's own
+    name, with no function of the tick's to keep up to date."""
+    cfg = dataclasses.replace(CFG, **block)
+    assert not cfg.plain_block
+    mesh = make_mesh((1, 1), ("dp", "tp"))
+    with pytest.raises(ValueError, match=f"sharded tick.*{names}"):
+        make_serving_scan(cfg, mesh, 2)
 
 
 @pytest.mark.slow
@@ -223,7 +242,7 @@ def test_admission_time_retirement_in_step_return():
     """max_new=1 retires at admission; step() must report it (review
     r5 finding: it was freed but missing from the returned list)."""
     sched = ServingScheduler(PARAMS, CFG, slots=1, n_inner=2,
-                             prompt_chunk=8, max_prompt=16)
+                             prompt_chunk=8, max_prompt=16, page_tokens=3)
     p = _prompt(4)
     r = sched.submit(p, max_new=1)
     retired = sched.step()
@@ -242,7 +261,7 @@ def test_quantized_scheduler_matches_quantized_oracle():
     empirical coincidence of this checkpoint."""
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=3,
                              prompt_chunk=8, max_prompt=32,
-                             quantize_kv=True)
+                             quantize_kv=True, page_tokens=3)
     pairs = [(sched.submit(p, max_new=n), p, n)
              for p, n in [(_prompt(5), 8), (_prompt(9), 6),
                           (_prompt(3), 11)]]
@@ -271,7 +290,7 @@ def test_quantized_parity_is_chunk_size_invariant():
     for chunk in (2, 4, 8, 16):
         sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=3,
                                  prompt_chunk=chunk, max_prompt=32,
-                                 quantize_kv=True)
+                                 quantize_kv=True, page_tokens=3)
         reqs = [sched.submit(p, max_new=n) for p, n in prompts]
         sched.run()
         streams.append([r.tokens for r in reqs])
@@ -298,7 +317,7 @@ def test_quantized_scheduler_kernel_tick_matches_oracle():
     params = init_params(cfg, seed=31)
     sched = ServingScheduler(params, cfg, slots=4, n_inner=3,
                              prompt_chunk=8, max_prompt=32,
-                             quantize_kv=True)
+                             quantize_kv=True, page_tokens=128)
     assert sched.use_kernel  # the whole point: the tick is kernelized
     pairs = [(sched.submit(p, max_new=n), p, n)
              for p, n in [(_prompt(5), 8), (_prompt(9), 6),
@@ -398,7 +417,7 @@ def test_sampled_serving_matches_sampled_oracle():
     temp, tk = 0.8, 7
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=3,
                              prompt_chunk=8, max_prompt=32,
-                             temperature=temp, top_k=tk)
+                             temperature=temp, top_k=tk, page_tokens=3)
     pairs = []
     for i, (plen, n) in enumerate([(5, 9), (11, 6), (3, 12), (8, 7)]):
         p = _prompt(plen)
@@ -420,7 +439,7 @@ def test_sampled_serving_default_keys_differ_per_request():
     streams (id-derived keys) — no accidental stream coupling."""
     sched = ServingScheduler(PARAMS, CFG, slots=2, n_inner=3,
                              prompt_chunk=8, max_prompt=32,
-                             temperature=1.0)
+                             temperature=1.0, page_tokens=3)
     p = _prompt(6)
     r1 = sched.submit(p, 12)
     r2 = sched.submit(p, 12)
@@ -430,11 +449,12 @@ def test_sampled_serving_default_keys_differ_per_request():
 
 def test_sampling_validation():
     with pytest.raises(ValueError, match="temperature"):
-        ServingScheduler(PARAMS, CFG, slots=1, temperature=-0.5)
+        ServingScheduler(PARAMS, CFG, slots=1, temperature=-0.5, page_tokens=3)
     with pytest.raises(ValueError, match="top_k"):
-        ServingScheduler(PARAMS, CFG, slots=1, temperature=1.0, top_k=0)
+        ServingScheduler(PARAMS, CFG, slots=1, temperature=1.0, top_k=0,
+                         page_tokens=3)
     sched = ServingScheduler(PARAMS, CFG, slots=1, prompt_chunk=8,
-                             max_prompt=16)
+                             max_prompt=16, page_tokens=3)
     with pytest.raises(ValueError, match="greedy scheduler"):
         sched.submit(_prompt(3), 4, key=jax.random.key(1))
 
@@ -444,19 +464,18 @@ def test_clear_cached_programs_drops_all_model_caches():
     lru-cached jitted program factories (bench uses it between rung
     blocks to release HBM) — it must clear every registered cache."""
     from mpistragglers_jl_tpu.models import clear_cached_programs
-    from mpistragglers_jl_tpu.models import decode, serving, speculative
+    from mpistragglers_jl_tpu.models import decode, serving
 
     sched = ServingScheduler(PARAMS, CFG, slots=1, n_inner=1,
-                             prompt_chunk=4, max_prompt=8)
+                             prompt_chunk=4, max_prompt=8, page_tokens=3)
     r = sched.submit(_prompt(3), 2)
     sched.run()
     assert r.finished
     generate_ring_dense(PARAMS, jnp.asarray(_prompt(3))[None], 2, CFG)
     caches = (
-        decode._dense_runner, speculative._spec_runner,
-        serving._fresh_arena,
-        serving._serving_scan_dense, serving._extend_chunk_dense,
-        serving._finish_admit_dense, serving._place_dense,
+        decode._dense_runner, serving._fresh_arena,
+        serving._serving_scan_paged, serving._extend_chunk_dense,
+        serving._finish_admit_dense, serving._place_paged,
     )
     assert any(c.cache_info().currsize > 0 for c in caches)
     clear_cached_programs()

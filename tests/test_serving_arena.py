@@ -28,17 +28,17 @@ CFG = TransformerConfig(
 PARAMS = init_params(CFG, seed=5)
 C = 8  # prompt_chunk
 
-KINDS = pytest.mark.parametrize("paged", [True, False],
-                                ids=["paged", "dense"])
+KINDS = pytest.mark.parametrize("page_tokens", [4, CFG.attn_window],
+                                ids=["pages", "one_page_a_window"])
 QUANT = pytest.mark.parametrize("quantize_kv", [True, False],
                                 ids=["int8", "bf16"])
 
 
-def _sched(paged, quantize_kv, slots=3, max_prompt=32):
+def _sched(page_tokens, quantize_kv, slots=3, max_prompt=32):
     return ServingScheduler(
         PARAMS, CFG, slots=slots, n_inner=2, prompt_chunk=C,
         max_prompt=max_prompt, quantize_kv=quantize_kv,
-        page_tokens=4 if paged else None,
+        page_tokens=page_tokens,
     )
 
 
@@ -79,9 +79,7 @@ def _same_bytes(a, b) -> None:
 
 def _resident_bytes(sched, slot) -> list[np.ndarray]:
     """What the cache holds for the request in ``slot``: its pages in
-    the order of its page table, or its dense ring rows."""
-    if not sched.paged:
-        return [np.asarray(a[slot]) for a in jax.tree.leaves(sched._caches)]
+    the order of its page table."""
     return [sched._page_payload(int(pid)) for pid in sched._pt_host[slot]
             if pid != serving.NULL_PAGE]
 
@@ -116,8 +114,8 @@ def _arena_invariants(sched) -> None:
 @QUANT
 @KINDS
 def test_arena_leaves_are_buffers_of_their_own_and_a_reset_is_zeros(
-        paged, quantize_kv):
-    sched = _sched(paged, quantize_kv)
+        page_tokens, quantize_kv):
+    sched = _sched(page_tokens, quantize_kv)
     arena, kind = sched._take_arena()
     assert kind == "new"
     n_leaves = CFG.n_layers * (4 if quantize_kv else 2)
@@ -159,15 +157,15 @@ def test_the_arena_programs_have_names_of_their_own():
 @QUANT
 @KINDS
 def test_short_prompt_through_a_recycled_arena_is_served_as_by_a_fresh_one(
-        paged, quantize_kv):
-    used = _sched(paged, quantize_kv)
+        page_tokens, quantize_kv):
+    used = _sched(page_tokens, quantize_kv)
     kinds = _recorded(used)
     long = used.submit(_prompt(1, 29), max_new=3)
     used.run()
     assert long.finished and len(used._free_arenas) == 1
     # every row of the dead arena is the long prompt's
     assert all(x.any() for x in _bytes(used._free_arenas[0]))
-    fresh = _sched(paged, quantize_kv)
+    fresh = _sched(page_tokens, quantize_kv)
     short = _prompt(2, 5)
     a, b = used.submit(short, max_new=7), fresh.submit(short, max_new=7)
     _step_until_first_token(used, a)
@@ -198,7 +196,7 @@ def test_shared_prefix_seeds_a_recycled_arena_as_it_seeds_a_new_one(
     def serve(recycle: bool):
         # an arena longer than the window: the seed program rewrites
         # rows [0, W) only, the rows behind them are the arena's own
-        sched = _sched(True, quantize_kv, max_prompt=64)
+        sched = _sched(4, quantize_kv, max_prompt=64)
         kinds = _recorded(sched)
         if recycle:
             # retires at admission (max_new == 1); leaves every row of
@@ -235,8 +233,8 @@ def test_shared_prefix_seeds_a_recycled_arena_as_it_seeds_a_new_one(
 @QUANT
 @KINDS
 def test_cancel_mid_prefill_returns_the_arena_and_the_next_reuses_it(
-        paged, quantize_kv):
-    sched = _sched(paged, quantize_kv)
+        page_tokens, quantize_kv):
+    sched = _sched(page_tokens, quantize_kv)
     kinds = _recorded(sched)
     doomed = sched.submit(_prompt(7, 30), max_new=4)  # four chunks
     sched.step()
@@ -252,7 +250,7 @@ def test_cancel_mid_prefill_returns_the_arena_and_the_next_reuses_it(
     nxt = sched.submit(p, max_new=5)
     sched.run()
     assert kinds == ["new", "reused"]
-    other = _sched(paged, quantize_kv)
+    other = _sched(page_tokens, quantize_kv)
     same = other.submit(p, max_new=5)
     other.run()
     assert nxt.tokens == same.tokens and len(nxt.tokens) == 5
@@ -262,12 +260,12 @@ def test_cancel_mid_prefill_returns_the_arena_and_the_next_reuses_it(
 @QUANT
 @KINDS
 def test_free_list_and_live_admissions_stay_disjoint_under_churn(
-        paged, quantize_kv):
+        page_tokens, quantize_kv):
     """Any sequence of submit / step / cancel: no arena is on the list
     and in an admission at once, none was donated away, and there are
     never more of them than slots."""
-    rng = np.random.default_rng(11 + 2 * paged + quantize_kv)
-    sched = _sched(paged, quantize_kv)
+    rng = np.random.default_rng(11 + 2 * (page_tokens == 4) + quantize_kv)
+    sched = _sched(page_tokens, quantize_kv)
     kinds = _recorded(sched)
     reqs = []
     for i in range(60):
@@ -296,9 +294,9 @@ def test_free_list_and_live_admissions_stay_disjoint_under_churn(
 
 @QUANT
 @KINDS
-def test_a_warm_admission_calls_no_eager_zeros(paged, quantize_kv,
+def test_a_warm_admission_calls_no_eager_zeros(page_tokens, quantize_kv,
                                                monkeypatch):
-    sched = _sched(paged, quantize_kv, slots=2)
+    sched = _sched(page_tokens, quantize_kv, slots=2)
     first = sched.submit(_prompt(9, 13), max_new=3)
     sched.run()  # every program has been traced
     assert first.finished
@@ -319,13 +317,13 @@ def test_a_warm_admission_calls_no_eager_zeros(paged, quantize_kv,
 @QUANT
 @KINDS
 def test_a_warm_admission_dispatches_no_eager_slice_or_scalar(
-        paged, quantize_kv, monkeypatch):
+        page_tokens, quantize_kv, monkeypatch):
     """A chunk and the scalars of the admission programs go to the
     device with those programs' own dispatch (host slices, numpy
     scalars): no eager ``dynamic_slice`` or ``jnp.int32`` before them,
     each of which is a dispatch and a transfer of its own; and the
     scalars' types trace no program a second time."""
-    sched = _sched(paged, quantize_kv, slots=2)
+    sched = _sched(page_tokens, quantize_kv, slots=2)
     alone = sched.submit(_prompt(9, 21), max_new=3)
     sched.run()  # every program has been traced
     sizes = {name: getattr(sched, name)._cache_size()

@@ -15,6 +15,7 @@ program.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -55,12 +56,12 @@ PROMPTS = [np.random.default_rng(7).integers(0, CFG.vocab, n).astype(np.int32)
 MAX_NEW = [7, 12, 1, 9, 20, 2, 16, 11]
 
 
-def serve(draft, temperature, *, paged=True, quantize_kv=True, eos_id=None,
-          cfg=CFG, params=PARAMS, slots=3):
+def serve(draft, temperature, *, page_tokens=P, quantize_kv=True,
+          eos_id=None, cfg=CFG, params=PARAMS, slots=3):
     sched = ServingScheduler(
         params, cfg, slots=slots, n_inner=4, prompt_chunk=C, max_prompt=32,
         quantize_kv=quantize_kv, temperature=temperature, eos_id=eos_id,
-        page_tokens=P if paged else None, draft=draft)
+        page_tokens=page_tokens, draft=draft)
     reqs = [
         sched.submit(p, m, **({"key": jax.random.key(100 + i)}
                               if temperature else {}))
@@ -72,11 +73,12 @@ def serve(draft, temperature, *, paged=True, quantize_kv=True, eos_id=None,
 # -- the contract: drafter on == drafter off, token for token ------------------
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "rings"])
+@pytest.mark.parametrize("page_tokens", [P, CFG.max_context],
+                         ids=["pages", "one_page_a_slot"])
 @pytest.mark.parametrize("temperature", [0.0, 0.05, 1.0])
-def test_streams_equal_the_drafter_off_streams(temperature, paged):
-    _, off = serve(None, temperature, paged=paged)
-    sched, on = serve("mtp", temperature, paged=paged)
+def test_streams_equal_the_drafter_off_streams(temperature, page_tokens):
+    _, off = serve(None, temperature, page_tokens=page_tokens)
+    sched, on = serve("mtp", temperature, page_tokens=page_tokens)
     for a, b in zip(off, on):
         assert b.tokens == a.tokens and b.reason == a.reason
         assert not a.drafts
@@ -88,8 +90,90 @@ def test_streams_equal_the_drafter_off_streams(temperature, paged):
         for at, tok, accepted in r.drafts:
             assert 1 <= at < len(r.tokens)
             assert accepted == (r.tokens[at] == tok)
-    if paged:  # every page came back
-        assert all(p.used == 0 for p in sched.pools.values())
+    # every page came back
+    assert all(p.used == 0 for p in sched.pools.values())
+
+
+def _one(draft, prompt, n_new, *, n_inner=4, temperature=0.0, params=PARAMS):
+    """One request alone: the scheduler when it has drained, the
+    request, and the ticks' ``drafted`` and ``accepted`` summed."""
+    sched = ServingScheduler(
+        params, CFG, slots=2, n_inner=n_inner, prompt_chunk=C, max_prompt=32,
+        quantize_kv=True, temperature=temperature, page_tokens=P, draft=draft)
+    r = sched.submit(prompt, n_new, **({"key": jax.random.key(7)}
+                                       if temperature else {}))
+    drafted = accepted = 0
+    while sched.pending or sched.active:
+        sched.step()
+        drafted, accepted = drafted + sched.drafted, accepted + sched.accepted
+    return sched, r, drafted, accepted
+
+
+@functools.lru_cache(maxsize=None)
+def _off_stream(Tp, n_new, temperature):
+    return tuple(_one(None, _grid_prompt(Tp), n_new,
+                      temperature=temperature)[1].tokens)
+
+
+def _grid_prompt(Tp):
+    return np.random.default_rng(31 * Tp).integers(
+        0, CFG.vocab, Tp).astype(np.int32)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7], ids=["greedy", "T0.7"])
+@pytest.mark.parametrize("n_inner", [1, 3, 4, 8])
+@pytest.mark.parametrize("Tp,n_new", [(8, 17), (3, 5), (12, 30)])
+def test_a_stream_alone_equals_the_drafter_off_stream(Tp, n_new, n_inner,
+                                                     temperature):
+    """Short and long budgets against steps a tick that divide them and
+    do not (a tick of 8 drafting steps can overrun a budget of 5 by 11
+    tokens): the stream is the drafter-off stream, greedy and sampled
+    with the request's key."""
+    sched, r, drafted, _ = _one("mtp", _grid_prompt(Tp), n_new,
+                                n_inner=n_inner, temperature=temperature)
+    assert tuple(r.tokens) == _off_stream(Tp, n_new, temperature)
+    assert len(r.tokens) == n_new and r.reason == "length"
+    assert drafted == len(r.drafts) > 0
+    assert all(p.used == 0 for p in sched.pools.values())
+
+
+def _walk(r):
+    """The drafts of a finished request against its stream, token by
+    token: every token behind the first is the one a step verified (its
+    draft on record at that index) or the second of a step that
+    accepted. Returns the steps and the accepted among them."""
+    i = 1
+    for at, tok, hit in r.drafts:
+        assert at == i and hit == (r.tokens[i] == tok)
+        i += 1 + hit
+    # (the budget may end on the first of an accepted step's two)
+    assert i in (len(r.tokens), len(r.tokens) + 1)
+    return len(r.drafts), sum(d[2] for d in r.drafts)
+
+
+@pytest.mark.parametrize("kind", ["random", "repetitive", "never_accepted"])
+def test_drafted_and_accepted_are_the_streams_own_count(kind):
+    """Three kinds of stream, each the drafter-off stream, and the
+    ticks' ``drafted`` / ``accepted`` are what a walk along the stream
+    counts: a random prompt; a prompt of period 6; a module whose every
+    draft is rejected (its final norm zeroed: it drafts token 0, which
+    this stream never holds), where each step delivers one token."""
+    rng = np.random.default_rng(9)
+    params, n_new = PARAMS, 24
+    prompt = rng.integers(1, CFG.vocab, 12).astype(np.int32)
+    if kind == "repetitive":
+        prompt = np.tile(prompt[:6], 4)
+    if kind == "never_accepted":
+        params = {**PARAMS, "mtp": {
+            **PARAMS["mtp"], "lnf_s": jnp.zeros_like(PARAMS["mtp"]["lnf_s"])}}
+    off = _one(None, prompt, n_new)[1]
+    _, on, drafted, accepted = _one("mtp", prompt, n_new, params=params)
+    assert on.tokens == off.tokens and len(on.tokens) == n_new
+    assert (drafted, accepted) == _walk(on)
+    assert n_new - 1 <= drafted + accepted <= n_new
+    if kind == "never_accepted":
+        assert 0 not in off.tokens[1:]  # what the count rests on
+        assert accepted == 0 and {d[1] for d in on.drafts} == {0}
 
 
 # a latent of whole lane tiles: with four slots (``KERNEL_MIN_BATCH``)
@@ -243,15 +327,16 @@ def test_the_drafting_tick_carries_its_scopes():
 def test_refusals():
     with pytest.raises(ValueError, match="multi-token-prediction module"):
         ServingScheduler(PARAMS, dataclasses.replace(CFG, mtp_depth=0),
-                         draft="mtp")
+                         draft="mtp", page_tokens=P)
     with pytest.raises(ValueError, match="None or 'mtp'"):
-        ServingScheduler(PARAMS, CFG, draft="ngram")
+        ServingScheduler(PARAMS, CFG, draft="ngram", page_tokens=P)
     windowed = dataclasses.replace(
         CFG, layer_mixers=None, attn_window=16, mtp_depth=1, d_head=None,
         n_heads=4, layer_experts=None, n_experts=0, experts_held=None,
         route_groups=1, route_topk_groups=1)
     with pytest.raises(ValueError, match="sliding-window"):
-        ServingScheduler(init_params(windowed, 0), windowed, draft="mtp")
+        ServingScheduler(init_params(windowed, 0), windowed, draft="mtp",
+                         page_tokens=P)
     for bad in (dict(hc_mult=4), dict(layer_mixers=("gdn", "mla"),
                                       gdn_key_heads=2, gdn_value_heads=2)):
         with pytest.raises(ValueError, match="multi-token-prediction"):

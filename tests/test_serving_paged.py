@@ -77,10 +77,11 @@ def _drained(sched):
     assert sched.pool.used == 0 and sched.pool.reserved == 0
 
 
-@pytest.mark.parametrize("page_tokens", [2, 3, 6])
+@pytest.mark.parametrize("page_tokens", [1, 2, 3, 6])
 def test_paged_batch_matches_oracle_under_churn(page_tokens):
     """The slot-churn schedule of test_serving.py on the paged cache,
-    at every page size dividing the window (6): queueing beyond slots,
+    at every page size dividing the window (6), from a page a row to
+    one page a window (a ring a slot): queueing beyond slots,
     reuse, wrap, varied budgets — every stream equals its oracle and
     the pool drains leak-free."""
     sched = ServingScheduler(PARAMS, CFG, slots=3, n_inner=4,
@@ -365,14 +366,14 @@ def test_page_pool_metrics_exported():
 def test_paged_validation():
     with pytest.raises(ValueError, match="divide the attention window"):
         ServingScheduler(PARAMS, CFG, slots=1, page_tokens=4)  # W=6
-    with pytest.raises(ValueError, match="cache_pages without"):
-        ServingScheduler(PARAMS, CFG, slots=1, cache_pages=8)
     with pytest.raises(ValueError, match="cannot hold even one"):
         ServingScheduler(PARAMS, CFG, slots=1, page_tokens=2,
                          cache_pages=3)  # needs W/P + 1 = 4
 
 
-def test_default_scheduler_is_not_paged():
-    sched = ServingScheduler(PARAMS, CFG, slots=1, n_inner=1,
-                             prompt_chunk=4, max_prompt=8)
-    assert not sched.paged and sched.pool is None
+def test_a_scheduler_without_a_page_size_is_a_type_error():
+    """The page pool is the one cache manager: ``page_tokens`` has no
+    default, with ``cache_pages`` or without."""
+    for kw in ({}, {"cache_pages": 8}):
+        with pytest.raises(TypeError, match="page_tokens"):
+            ServingScheduler(PARAMS, CFG, slots=1, **kw)

@@ -253,7 +253,8 @@ def test_admit_new_says_where_its_arena_came_from(traced):
 
 @pytest.mark.parametrize("quantize_kv", [True, False],
                          ids=["int8", "bf16"])
-@pytest.mark.parametrize("page_tokens", [4, None], ids=["paged", "dense"])
+@pytest.mark.parametrize("page_tokens", [4, 8],
+                         ids=["paged", "one_page_a_window"])
 def test_a_backlog_of_one_chunk_prompts_reuses_one_arena(
         tiny, tmp_path, page_tokens, quantize_kv):
     """``arena="new"`` exactly when the free list was empty: every
@@ -450,9 +451,10 @@ def test_annotate_takes_arguments_and_resolves_its_class_once():
 
 PROGRAMS = {
     "_scan": "serving_tick_paged", "_seed": "serving_seed_prefix",
-    "_place_p": "serving_place_pages", "_copy": "serving_copy_pages",
+    "_place": "serving_place_pages", "_copy": "serving_copy_pages",
     "_gather": "serving_gather_ring", "_extend": "serving_prefill_chunk",
-    "_finish": "serving_first_token", "_place": "serving_place_ring",
+    "_finish": "serving_first_token",
+    "_extend_group": "serving_prefill_chunk_x2",
 }
 
 
@@ -462,15 +464,13 @@ def test_serving_programs_have_names_of_their_own(tiny, attr, name):
     assert getattr(_sched(cfg, params), attr).__name__ == name
 
 
-def test_dense_and_sharded_ticks_are_named_too(tiny):
+def test_the_sharded_tick_is_named_too(tiny):
     import jax
     from jax.sharding import Mesh
 
     from mpistragglers_jl_tpu.models.serving import make_serving_scan
 
     cfg, params = tiny
-    dense = _sched(cfg, params, page_tokens=None)
-    assert dense._scan.__name__ == "serving_tick_dense"
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
     assert make_serving_scan(cfg, mesh, 2).__name__ == (
         "serving_tick_sharded")

@@ -73,19 +73,20 @@ def test_reference_window_band_semantics():
         )
 
 
-@pytest.mark.parametrize("bwd", ["split", "fused"])
+@pytest.mark.parametrize("blocks", [(8, 8), (16, 8)])
 @pytest.mark.parametrize("hkv", [1, 4])
 @pytest.mark.parametrize("W", [1, 5, 16, 100])
-def test_flash_window_matches_reference(W, hkv, bwd):
+def test_flash_window_matches_reference(W, hkv, blocks):
     """Flash (block-skipping + in-block band mask) vs the oracle —
-    values and all three grads, GQA included, both backward impls;
-    W=100 > L pins window-larger-than-sequence == full causal."""
+    values and all three grads, GQA included, in square blocks and where
+    the band crosses unequal ones; W=100 > L pins
+    window-larger-than-sequence == full causal."""
     q, k, v = _qkv(4, hkv, L=32)
+    bq, bk = blocks
 
     def f_flash(q, k, v):
         o = flash_attention(
-            q, k, v, causal=True, window=W, block_q=8, block_k=8,
-            bwd_impl=bwd,
+            q, k, v, causal=True, window=W, block_q=bq, block_k=bk,
         )
         return (o.astype(jnp.float32) ** 2).sum()
 
@@ -94,7 +95,7 @@ def test_flash_window_matches_reference(W, hkv, bwd):
         return (o.astype(jnp.float32) ** 2).sum()
 
     o_got = flash_attention(
-        q, k, v, causal=True, window=W, block_q=8, block_k=8
+        q, k, v, causal=True, window=W, block_q=bq, block_k=bk
     )
     o_want = reference_attention(q, k, v, causal=True, window=W)
     np.testing.assert_allclose(
