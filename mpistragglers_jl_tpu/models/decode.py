@@ -88,6 +88,7 @@ from .transformer import (
     param_specs,
     pool_cells,
     sparse_pick,
+    ssm_half,
     state_half,
     zero_state,
 )
@@ -490,10 +491,13 @@ def init_cache(
         return layer
 
     return [
-        # a recurrent layer keeps its fixed block of state
-        zero_state(cfg, li, batch) if cfg.state(li)
+        # a recurrent layer keeps its fixed block of state; a layer that
+        # holds a state-space mixer beside its attention, rows AND state
+        zero_state(cfg, li, batch) if not cfg.rows(li)
         else _zero_latent_layer(batch, max_len, cfg, quantize_kv)
-        if cfg.mla(li) else rows(li)
+        if cfg.mla(li)
+        else {**rows(li), **zero_state(cfg, li, batch)} if cfg.ssm(li)
+        else rows(li)
         for li in range(cfg.n_layers)
     ]
 
@@ -702,6 +706,19 @@ def _ring_cached_attention(q, cache_l, pos, scale,
     return o.astype(q.dtype)
 
 
+# the leaves of a layer's cache that are recurrent state, one fixed
+# block a request (``transformer.*_zero_state``); every other leaf has a
+# row a position. A layer that holds a state-space mixer beside its
+# attention keeps both kinds in one dict.
+STATE_LEAVES = ("S", "conv")
+
+
+def _split_state(cache_l: dict) -> tuple[dict, dict]:
+    """``(rows, state)``: a cache layer's leaves by kind."""
+    return ({kk: a for kk, a in cache_l.items() if kk not in STATE_LEAVES},
+            {kk: a for kk, a in cache_l.items() if kk in STATE_LEAVES})
+
+
 def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
                        kv_slice, tp_psum, ring=False,
                        decode_kernel: bool = False, valid=None):
@@ -720,7 +737,16 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     h, mix = hc_pre(x, lp, cfg, "hc1")
     rope = partial(_rope, pos=qpos, theta=cfg.rope_theta,
                    table=cfg.rope_table)
-    if cfg.state(li):
+    beside, state = None, {}
+    if cfg.ssm(li):
+        # the state-space mixer beside the attention: its state carried
+        # through the chunk, its result joined to the attention's below
+        if ring or tp_psum:
+            raise ValueError("a layer that holds a state-space mixer has "
+                             "neither a ring-cache nor a tp-sharded form")
+        cache_l, state = _split_state(cache_l)
+        beside, state = ssm_half(h, lp, state, cfg, valid)
+    elif cfg.state(li):
         x, cache_l = state_half(h, lp, cache_l, cfg, li, rope, valid,
                                 mix=mix)
         x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
@@ -764,9 +790,10 @@ def _incremental_layer(x, lp, cache_l, qpos, cfg, li, *, chunk_attn,
     else:
         o = _cached_attention(q, cache_l, qpos, scale, cfg.windows[li],
                               use_kernel=decode_kernel)
-    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix)
+    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix,
+                   beside=beside)
     x, _, _ = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
-    return x, cache_l
+    return x, {**cache_l, **state}
 
 
 def _latent_attend(h, lp, cfg, rope, mix, write, attend):
@@ -874,13 +901,22 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
                 a[None], pos, cfg.rope_theta, cfg.rope_table)[0])(
                     t, qpos)
 
-        if cfg.state(li):
-            state = {kk: jnp.concatenate([r[kk] for r in rows])
-                     for kk in rows[0]}
-            x, state = state_half(h, lp, state, cfg, li, rope, valid,
-                                  mix=mix)
-            rows = [{kk: a[i:i + 1] for kk, a in state.items()}
+        def batched(stores):  # the n requests' state, one batch
+            return {kk: jnp.concatenate([r[kk] for r in stores])
+                    for kk in stores[0]}
+
+        def unbatched(state):
+            return [{kk: a[i:i + 1] for kk, a in state.items()}
                     for i in range(n)]
+
+        beside = None
+        if cfg.ssm(li):
+            rows, states = zip(*(_split_state(r) for r in rows))
+            beside, state = ssm_half(h, lp, batched(states), cfg, valid)
+        if not cfg.rows(li):
+            x, state = state_half(h, lp, batched(rows), cfg, li, rope,
+                                  valid, mix=mix)
+            rows = unbatched(state)
         else:
 
             def attend(q, rows, **kw):  # each request's queries, its store
@@ -905,7 +941,9 @@ def _grouped_layer(cfg: TransformerConfig, li: int):
                     for i, r in enumerate(rows)]
                 o = attend(q, rows, window=cfg.windows[li],
                            sparse=cfg if cfg.sparse(li) else None)
-                x = attn_merge(h, o, gate, lp, cfg, mix=mix)
+                x = attn_merge(h, o, gate, lp, cfg, mix=mix, beside=beside)
+        if beside is not None:
+            rows = [{**r, **st} for r, st in zip(rows, unbatched(state))]
         x, _, _ = ffn_half(x, lp, cfg, li)
         return x, rows
 
@@ -1100,7 +1138,7 @@ def _row_widths(cfg: TransformerConfig) -> tuple:
     out = []
     for li in range(cfg.cache_layers):
         w = cfg.windows[cfg._like(li)]
-        if cfg.state(li):
+        if not cfg.rows(li):
             out.append(None)
             continue
         if w is None:
@@ -1176,8 +1214,9 @@ def _ring_from_cache(cache_l: dict, Tp: int, W: int,
         live = c < -(-Tp // stride)
         return jnp.where(live.reshape((1, -1) + (1,) * (a.ndim - 2)), g, 0)
 
-    return {kk: cells(a) if kk == "kp" else gather(a)
-            for kk, a in cache_l.items()}
+    # (a state-space mixer's state beside the rows is handed on as it is)
+    return {kk: a if kk in STATE_LEAVES else cells(a) if kk == "kp"
+            else gather(a) for kk, a in cache_l.items()}
 
 
 def ring_from_cache(cache, Tp: int, cfg: TransformerConfig) -> list[dict]:

@@ -158,6 +158,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs.timeline import annotate as _annotate
 from .decode import (
+    STATE_LEAVES,
     _NEG,
     _cache_pv,
     _cache_scores,
@@ -179,6 +180,7 @@ from .decode import (
     _pick_token,
     _pool_cells_for,
     _select_rows,
+    _split_state,
     _ring_from_cache,
     _route_kernel,
     _row_widths,
@@ -212,6 +214,8 @@ from .transformer import (
     require_plain_block,
     sparse_counts,
     sparse_pick,
+    ssm_half,
+    ssm_rule_route,
     state_half,
     zero_state,
 )
@@ -237,12 +241,14 @@ def _fresh_arena(cfg: TransformerConfig, B: int, L: int,
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(li):
-        if cfg.state(li):  # no rows: the layer's fixed block of state
+        if not cfg.rows(li):  # the layer's fixed block of state alone
             return zero_state(cfg, li, B)
         if cfg.mla(li):  # one row a position: [latent | rotated key]
             return _zero_latent_layer(B, L, cfg, quantize_kv)
         shape = (B, L, cfg.kv_heads, cfg.head_dim)
         out = {"k": jnp.zeros(shape, kvdt), "v": jnp.zeros(shape, kvdt)}
+        if cfg.ssm(li):  # rows AND the state of the mixer beside them
+            out.update(zero_state(cfg, li, B))
         if quantize_kv:
             out["k_s"] = jnp.zeros(shape[:3], jnp.float32)
             out["v_s"] = jnp.zeros(shape[:3], jnp.float32)
@@ -315,7 +321,9 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     (:data:`~.paging.NULL_PAGE`): rows nothing reads unmasked, the
     landing zone for retired-but-still-ticking rows. A gated
     delta-rule layer has no pages: its leaf is the fixed block of state
-    of each of the ``slots`` (its page count is not read). A latent
+    of each of the ``slots`` (its page count is not read); a layer that
+    holds a state-space mixer beside its attention has pages AND that
+    block (``decode.STATE_LEAVES`` name the leaves that are state). A latent
     layer's pages hold its one row a position, ``k`` ``(n_pages, P,
     lanes)``, the ``latent + rope`` values of a row and zeros up to
     whole 128-lane tiles behind them (``paged_row_lanes``: what the
@@ -329,7 +337,7 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
     kvdt = jnp.int8 if quantize_kv else cfg.dtype
 
     def layer(li, n):
-        if cfg.state(li):
+        if not cfg.rows(li):
             return zero_state(cfg, li, slots)
         if cfg.mla(li):
             out = {"k": jnp.zeros(
@@ -347,6 +355,8 @@ def _fresh_pages(cfg: TransformerConfig, n_pages, P: int,
         if cfg.sparse(li):
             out["kp"] = jnp.zeros((n, P // cfg.sparse_stride, shape[2]),
                                   jnp.float32)
+        if cfg.ssm(li):  # pages, and beside them each slot's state
+            out.update(zero_state(cfg, li, slots))
         return out
 
     return [layer(li, n) for li, n in enumerate(counts)]
@@ -702,7 +712,9 @@ def _paged_gather(cache_l: dict, pt, Hkv: int, P: int,
     resolve to page 0,
     whose rows are only ever reached by ``kpos < 0`` (masked) slots."""
     return {
-        kk: _pages_to_rows(kk, jnp.take(a, pt, axis=0), Hkv, P, width)
+        # (the state beside a layer's rows is no page: it goes through)
+        kk: a if kk in STATE_LEAVES else _pages_to_rows(
+            kk, jnp.take(a, pt, axis=0), Hkv, P, width)
         for kk, a in cache_l.items()
     }
 
@@ -717,7 +729,7 @@ def _paged_scatter(cache_l: dict, view_l: dict, pt, P: int,
     back exactly as they went out. Null entries dump into page 0,
     which nothing reads unmasked."""
     return {
-        kk: a.at[pt].set(
+        kk: view_l[kk] if kk in STATE_LEAVES else a.at[pt].set(
             _rows_to_pages(kk, view_l[kk], P, stride,
                            a.shape[-1]).astype(a.dtype))
         for kk, a in cache_l.items()
@@ -761,7 +773,14 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
     h, mix = hc_pre(x, lp, cfg, "hc1")
     rope = functools.partial(_rope_rows, pos=pos, theta=cfg.rope_theta,
                              table=cfg.rope_table)
-    if cfg.state(li):
+    beside, state = None, {}
+    if cfg.ssm(li):
+        # the state-space mixer beside the attention: one step of its
+        # recurrence a row, every slot's S updated where it lies; its
+        # result joins the attention's below
+        cache_l, state = _split_state(cache_l)
+        beside, state = ssm_half(h, lp, state, cfg)
+    elif cfg.state(li):
         x, cache_l = state_half(h, lp, cache_l, cfg, li, rope, mix=mix)
         with jax.named_scope("decode_mlp"):
             x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
@@ -828,10 +847,11 @@ def _serving_layer(x, lp, cache_l, pos, cfg, li, *, kv_slice=None,
                                        W // cfg.sparse_block), cfg)
             o = _ring_attention_rows(q, cache_l, pos, scale,
                                      use_kernel=use_kernel, select=select)
-    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix)
+    x = attn_merge(h, o, gate, lp, cfg, tp_psum=tp_psum, mix=mix,
+                   beside=beside)
     with jax.named_scope("decode_mlp"):
         x, _, hit = ffn_half(x, lp, cfg, li, tp_psum=tp_psum)
-    return x, cache_l, hit
+    return x, {**cache_l, **state}, hit
 
 
 def _paged_layer(paged, li: int):
@@ -854,7 +874,7 @@ def _serving_hidden(params, tok, pos, caches, cfg, *, kv_slice=None,
     hits = None
     for li, (lp, cl) in enumerate(zip(params["layers"], caches)):
         paged_l = None
-        if paged is not None and not cfg.state(li):
+        if paged is not None and cfg.rows(li):
             paged_l = _paged_layer(paged, li)
         x, cl, hit = _serving_layer(
             x, lp, cl, pos, cfg, li, kv_slice=kv_slice, tp_psum=tp_psum,
@@ -1094,7 +1114,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         with jax.named_scope("kv_page_gather"):
             # (a recurrent layer's state is no page: it goes through)
             views = [
-                cl if cfg.state(li)
+                cl if not cfg.rows(li)
                 else _paged_gather(cl, t, cfg.cache_heads(li), P,
                                    _row_values(cfg, li))
                 for li, (cl, t) in enumerate(zip(caches, pts))
@@ -1105,7 +1125,7 @@ def _serving_scan_paged(cfg: TransformerConfig, n_inner: int,
         )
         with jax.named_scope("kv_page_scatter"):
             caches = [
-                vw if cfg.state(li)
+                vw if not cfg.rows(li)
                 else _paged_scatter(cl, vw, t, P, cfg.sparse_stride)
                 for li, (cl, vw, t) in enumerate(zip(caches, views, pts))
             ]
@@ -1195,14 +1215,14 @@ def _place_paged(cfg: TransformerConfig, P: int):
         # cache width's table row names: a page-block scatter
         rows = _layer_tables(cfg, pt_row)
         caches = [
-            # a recurrent layer's block of state goes over slot s's
+            # a block of recurrent state goes over slot s's (a reused
+            # slot starts from the new prompt's state), rows into pages
             {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
-             for kk in c} if cfg.state(li) else
-            {kk: c[kk].at[row].set(
+             if kk in STATE_LEAVES else c[kk].at[row].set(
                 _rows_to_pages(kk, r[kk][0], P, cfg.sparse_stride,
                                c[kk].shape[-1]).astype(c[kk].dtype))
              for kk in c}
-            for li, (c, r, row) in enumerate(zip(caches, ring, rows))
+            for c, r, row in zip(caches, ring, rows)
         ]
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
                 done.at[s].set(False), keys.at[s].set(key))
@@ -1255,8 +1275,9 @@ def _refuse_state_layers(cfg: TransformerConfig, what: str,
     if cfg.state_layers:
         raise ValueError(
             f"{what}: this configuration has gated delta-rule layers "
-            f"(or decayed linear attention), whose per-request state is "
-            f"one fixed block and no row a token; {why}"
+            f"(or decayed linear attention, or a state-space mixer), "
+            f"whose per-request state is one fixed block and no row a "
+            f"token; {why}"
         )
 
 
@@ -2151,6 +2172,9 @@ class ServingScheduler:
         # module's expert layer's ``experts_hit``
         self.drafted = self.accepted = 0
         self.mtp_experts_hit: float | None = None
+        # admissions that wrote a slot's recurrent state over (each
+        # one of a configuration with state layers does)
+        self.state_resets = 0
         self._pos = jnp.zeros((self.S,), jnp.int32)
         self._done = jnp.ones((self.S,), bool)  # idle rows stay done
         self._keys = jax.random.split(jax.random.key(0), self.S)
@@ -2262,11 +2286,17 @@ class ServingScheduler:
                if "gdn" in mixers else {}),
             **({"la_rule": la_rule_route(cfg, self.C)}
                if "la" in mixers else {}),
+            **({"ssm_rule": ssm_rule_route(cfg, self.C)}
+               if "attn_ssm" in mixers else {}),
         }
         # ``serving.decode``'s ``gdn_rule``: the form ONE token takes
         # in a step of the tick, from the same function
-        self._step_route = ({"gdn_rule": gdn_rule_route(cfg, 1)}
-                            if "gdn" in mixers else {})
+        self._step_route = {
+            **({"gdn_rule": gdn_rule_route(cfg, 1)}
+               if "gdn" in mixers else {}),
+            **({"ssm_rule": ssm_rule_route(cfg, 1)}
+               if "attn_ssm" in mixers else {}),
+        }
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -3798,7 +3828,13 @@ class ServingScheduler:
         token, its window placed into the slot, the arena released."""
         st = self._admitting[s]
         rid = st.req.id
-        with _annotate("serving.first_token", req=rid, slot=s):
+        with _annotate("serving.first_token", req=rid, slot=s,
+                       # the slot's recurrent state is written over by
+                       # this request's: a reset, counted with the
+                       # admission that makes it
+                       **({"state_reset": 1} if self.cfg.state_layers
+                          else {})):
+            self.state_resets += bool(self.cfg.state_layers)
             Tp = st.req.prompt.size
             rkey = (st.req.key if st.req.key is not None
                     else jax.random.key(st.req.id + 1))

@@ -57,6 +57,7 @@ from ..ops.delta_rule import (
     delta_rule_viable,
     delta_step_viable,
 )
+from ..ops.ssm_step import ssm_step, ssm_step_viable
 from ..parallel.ring_attention import (
     _flash_interpreted,
     resolve_attention_impl,
@@ -258,6 +259,36 @@ class TransformerConfig:
     # and one on the final norm's output before the head
     residual_scale: float = 1.0
     head_scale: float = 1.0
+    # a state-space mixer BESIDE attention in one layer (``layer_mixers``
+    # value "attn_ssm"; Falcon-H1's block, the mixer Mamba-2's,
+    # arXiv:2405.21060): both read the layer's one normed input and
+    # their results join the residual in ONE add (:func:`ssm_half`).
+    # ``ssm_heads`` heads of ``ssm_head_dim``, each keeping ``S_t = a_t
+    # S_(t-1) + dt_t B_t x_t^T`` (``ssm_state`` x ``ssm_head_dim``
+    # float32 a request) with ``a_t`` the token's own scalar a head and
+    # B, C shared by the heads of one of ``ssm_groups`` groups, behind a
+    # causal depthwise conv of ``ssm_conv`` taps; ``ssm_chunk`` rows a
+    # sub-chunk of the chunked form. The layer keeps K/V rows AND state.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # constants of the block, each applied where its source applies it
+    # (1.0 = absent from the program): on the normed input of the
+    # attention and of the state-space mixer, on the keys before rotary,
+    # on each mixer's result, on the five spans ``[z | x | B | C | dt]``
+    # of the state-space mixer's in-projection, on the gated
+    # feed-forward's gate before its activation and on its result
+    attn_in_scale: float = 1.0
+    attn_out_scale: float = 1.0
+    key_scale: float = 1.0
+    ssm_in_scale: float = 1.0
+    ssm_out_scale: float = 1.0
+    ssm_scales: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ffn_gate_scale: float = 1.0
+    ffn_down_scale: float = 1.0
 
     def __post_init__(self):
         if self.attn == "ring" and self.attn_impl == "flash":
@@ -312,11 +343,23 @@ class TransformerConfig:
                 f"head_dim {self.head_dim}"
             )
         if self.layer_mixers is not None:
-            if any(m not in ("attn", "gdn", "mla", "la")
+            if any(m not in ("attn", "gdn", "mla", "la", "attn_ssm")
                    for m in self.layer_mixers):
                 raise ValueError(
                     f"layer_mixers holds 'attn' or 'gdn' or 'mla' or "
-                    f"'la', got {self.layer_mixers}"
+                    f"'la' or 'attn_ssm', got {self.layer_mixers}"
+                )
+            if "attn_ssm" in self.layer_mixers and (
+                self.ssm_heads < 1 or self.ssm_groups < 1
+                or self.ssm_heads % self.ssm_groups or self.ssm_conv < 1
+                or self.ssm_chunk < 1 or len(self.ssm_scales) != 5
+            ):
+                raise ValueError(
+                    "a state-space mixer needs ssm_groups >= 1 dividing "
+                    "ssm_heads, ssm_conv and ssm_chunk >= 1 and five "
+                    f"ssm_scales, got {self.ssm_heads} heads in "
+                    f"{self.ssm_groups} groups, conv {self.ssm_conv}, "
+                    f"chunk {self.ssm_chunk}, scales {self.ssm_scales}"
                 )
             if "la" in self.layer_mixers and (
                 self.la_heads < 1 or self.la_head_dim < 2
@@ -465,7 +508,8 @@ class TransformerConfig:
         return self.rope_full or self.windows[li] is not None
 
     def mixer(self, li: int) -> str:
-        """Layer ``li``'s token mixer: "attn", "gdn" or "mla"."""
+        """Layer ``li``'s token mixer: "attn", "gdn", "mla", "la" or
+        "attn_ssm" (attention and a state-space mixer side by side)."""
         return ("attn" if self.layer_mixers is None
                 else self.layer_mixers[self._like(li)])
 
@@ -475,9 +519,20 @@ class TransformerConfig:
 
     def state(self, li: int) -> bool:
         """Does layer ``li`` keep recurrent state, one fixed block a
-        request and no row a token (the gated delta rule, decayed
-        linear attention)?"""
-        return self.mixer(li) in ("gdn", "la")
+        request (the gated delta rule, decayed linear attention, a
+        state-space mixer)?"""
+        return self.mixer(li) in ("gdn", "la", "attn_ssm")
+
+    def rows(self, li: int) -> bool:
+        """Does layer ``li`` keep a row a position in its cache? Every
+        attention does, also the one that stands beside a state-space
+        mixer: that layer's cache is rows AND a state."""
+        return self.mixer(li) in ("attn", "mla", "attn_ssm")
+
+    def ssm(self, li: int) -> bool:
+        """Does layer ``li`` hold a state-space mixer beside its
+        attention?"""
+        return self.mixer(li) == "attn_ssm"
 
     def sparse(self, li: int) -> bool:
         """Does layer ``li`` attend a selection of its key blocks?"""
@@ -609,6 +664,7 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         else:
             layer = {
                 **norm("ln1"),
+                **(init_ssm_layer(rng, cfg) if cfg.ssm(li) else {}),
                 "wq": sd(D, H, Dh),
                 "wk": sd(D, Hkv, Dh),
                 "wv": sd(D, Hkv, Dh),
@@ -689,6 +745,9 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
         why.append("gated delta-rule layers (recurrent state)")
     if "la" in (cfg.layer_mixers or ()):
         why.append("decayed linear-attention layers (recurrent state)")
+    if "attn_ssm" in (cfg.layer_mixers or ()):
+        why.append("layers that hold a state-space mixer beside their "
+                   "attention (recurrent state)")
     if cfg.sparse_block:
         why.append("attention layers that read a selection of their "
                    "key blocks")
@@ -863,6 +922,14 @@ def _res(a, cfg):
     return a * jnp.asarray(cfg.residual_scale, a.dtype)
 
 
+def _scaled(a, scale: float):
+    """``a`` times a constant of the block in ``a``'s own type; 1.0 is
+    absent from the program."""
+    if scale == 1.0:
+        return a
+    return a * jnp.asarray(scale, a.dtype)
+
+
 def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     """First part of layer ``li``'s attention half on (B, L, D): norm,
     projections, the q/k norms, rotary. ``rope(t)`` rotates a
@@ -873,9 +940,9 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
     tp-replicated K/V projections (the GQA kv_heads < tp case — see
     :func:`_kv_tp_sharded`)."""
     with jax.named_scope("attn_qkv"):
-        h = _norm(x, lp, "ln1", cfg)
+        h = _scaled(_norm(x, lp, "ln1", cfg), cfg.attn_in_scale)
         q = jnp.einsum("bld,dhk->blhk", h, lp["wq"])
-        k = jnp.einsum("bld,dhk->blhk", h, lp["wk"])
+        k = _scaled(jnp.einsum("bld,dhk->blhk", h, lp["wk"]), cfg.key_scale)
         v = jnp.einsum("bld,dhk->blhk", h, lp["wv"])
         if kv_slice is not None:
             k, v = kv_slice(k), kv_slice(v)
@@ -891,17 +958,23 @@ def attn_qkv(x, lp, cfg, li, rope, kv_slice=None):
         return q, k, v, gate
 
 
-def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None):
+def attn_merge(x, o, gate, lp, cfg, tp_psum=False, mix=None, beside=None):
     """Second part of the attention half: the output gate, the
     out-projection (summed over ``tp`` when the heads were a shard),
     the norm after the half, the residual (``mix``: :func:`hc_pre`'s,
-    where the residual path is streams)."""
+    where the residual path is streams). ``beside``: the result of the
+    mixer that stands beside the attention in this layer
+    (:func:`ssm_half`); the two are summed and join the residual in one
+    add."""
     with jax.named_scope("attn_out"):
         if gate is not None:
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
         a = jnp.einsum("blhk,hkd->bld", o, lp["wo"])
         if tp_psum:
             a = jax.lax.psum(a, "tp")
+        a = _scaled(a, cfg.attn_out_scale)
+        if beside is not None:
+            a = beside + a
         if cfg.post_norm:
             a = _norm(a, lp, "ln1p", cfg)
         return hc_post(x, _res(a, cfg), mix)
@@ -1224,6 +1297,31 @@ def _delta_rule_chunks(q, k, v, g, beta, S):
     return o[:, :T], S
 
 
+def _causal_conv(rows, kept, w, valid, bias=None):
+    """The causal depthwise conv both recurrent mixers run before their
+    rule: ``rows`` (B, T, channels) behind the ``taps - 1`` rows
+    ``kept`` from the call before, taps ``w`` (taps, channels), an
+    optional ``bias``, silu, in float32. Returns ``(y (B, T, channels),
+    tail)``: ``tail`` is what the next call's conv reaches back to, the
+    last ``taps - 1`` rows that went in, padding not counted (``valid``:
+    see :func:`gdn_half`)."""
+    T, taps = rows.shape[1], w.shape[0]
+    seen = jnp.concatenate([kept, rows.astype(kept.dtype)], axis=1)
+    if valid is None:
+        tail = seen[:, T:]
+    elif jnp.ndim(valid):
+        tail = jax.vmap(lambda a, n: jax.lax.dynamic_slice_in_dim(
+            a, n, taps - 1))(seen, valid)
+    else:
+        tail = jax.lax.dynamic_slice_in_dim(seen, valid, taps - 1, axis=1)
+    w = w.astype(jnp.float32)
+    seen = seen.astype(jnp.float32)
+    y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return jax.nn.silu(y), tail
+
+
 def gdn_rule_route(cfg: TransformerConfig, T: int) -> str:
     """The form the recurrence takes over a call of T rows, from what
     the shapes say: ``"kernel"`` (ops/delta_rule.py, at head sizes of
@@ -1262,23 +1360,7 @@ def gdn_half(x, lp, state, cfg, valid=None, mix=None):
         ba = jnp.einsum("bld,dc->blc", h, lp["gdn_wba"],
                         preferred_element_type=jnp.float32)
     with jax.named_scope("gdn_conv"):
-        taps = cfg.gdn_conv
-        seen = jnp.concatenate(
-            [state["conv"], qkv.astype(state["conv"].dtype)], axis=1)
-        # the rows the next call's conv reaches back to: the last
-        # taps - 1 that went in, padding not counted
-        if valid is None:
-            tail = seen[:, T:]
-        elif jnp.ndim(valid):
-            tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-                rows, n, taps - 1))(seen, valid)
-        else:
-            tail = jax.lax.dynamic_slice_in_dim(seen, valid, taps - 1,
-                                                axis=1)
-        w = lp["gdn_conv_w"].astype(jnp.float32)
-        seen = seen.astype(jnp.float32)
-        y = sum(seen[:, j:j + T] * w[j] for j in range(taps))
-        y = jax.nn.silu(y)
+        y, tail = _causal_conv(qkv, state["conv"], lp["gdn_conv_w"], valid)
     with jax.named_scope("gdn_rule"):
         # at widths of whole lane tiles a chunk of whole sub-chunks
         # goes through the chunked kernel, which reads q, k and v out
@@ -1378,8 +1460,10 @@ def la_zero_state(cfg: TransformerConfig, B: int) -> dict:
 
 def zero_state(cfg: TransformerConfig, li: int, B: int) -> dict:
     """Layer ``li``'s recurrent state for ``B`` requests that have seen
-    no token (``cfg.state(li)``: the delta rule's or linear
-    attention's)."""
+    no token (``cfg.state(li)``: the delta rule's, linear attention's
+    or a state-space mixer's)."""
+    if cfg.ssm(li):
+        return ssm_zero_state(cfg, B)
     return (gdn_zero_state(cfg, B) if cfg.gdn(li)
             else la_zero_state(cfg, B))
 
@@ -1487,6 +1571,200 @@ def state_half(x, lp, state, cfg, li, rope, valid=None, mix=None):
     if cfg.gdn(li):
         return gdn_half(x, lp, state, cfg, valid, mix=mix)
     return la_half(x, lp, state, cfg, rope, valid, mix=mix)
+
+
+# A state-space mixer beside attention (``layer_mixers`` value
+# "attn_ssm"), written once like the other recurrences: the dense
+# forward (the whole sequence from a zero state), a prefill chunk and a
+# decode step all call :func:`ssm_half` on the layer's input and hand
+# what it returns to :func:`attn_merge` as ``beside``. A layer's state
+# is ``S`` (heads, state dim, head dim) float32, the state dim down the
+# rows so that a head's x, decay and result lie along the lanes and the
+# group's B and C scale whole rows (ops/ssm_step.py), and the last
+# ``ssm_conv - 1`` rows that went into the depthwise conv.
+
+
+def ssm_widths(cfg: TransformerConfig) -> tuple[int, int, int]:
+    """``(heads * head dim, groups * state dim, in-projection's
+    width)``: the in-projection is laid out ``[z | x | B | C | dt]``,
+    the conv runs over ``[x | B | C]``."""
+    wide, gn = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    return wide, gn, 2 * wide + 2 * gn + cfg.ssm_heads
+
+
+def init_ssm_layer(rng: np.random.Generator, cfg: TransformerConfig) -> dict:
+    """Leaves of one state-space mixer: ``ssm_win`` (D, ``[z | x | B |
+    C | dt]``), the depthwise conv's taps and bias over ``[x | B | C]``,
+    ``ssm_A_log``, ``ssm_dt_bias`` and ``ssm_D`` (float32, a head each,
+    drawn as Mamba-2's reference initialisation does: A uniform in [1,
+    16], dt log-uniform in [0.001, 0.1] with ``dt_bias`` its inverse
+    softplus, D one), the gated norm's scale and the out-projection."""
+    D, H = cfg.d_model, cfg.ssm_heads
+    wide, gn, proj = ssm_widths(cfg)
+    sd = lambda *s: jnp.asarray(
+        rng.standard_normal(s) / np.sqrt(s[0]), cfg.dtype
+    )
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), H))
+    return {
+        "ssm_win": sd(D, proj),
+        "ssm_conv_w": jnp.asarray(
+            rng.uniform(-0.5, 0.5, (cfg.ssm_conv, wide + 2 * gn)), cfg.dtype),
+        "ssm_conv_b": jnp.asarray(
+            rng.uniform(-0.5, 0.5, (wide + 2 * gn,)), cfg.dtype),
+        "ssm_A_log": jnp.asarray(np.log(rng.uniform(1.0, 16.0, H)),
+                                 jnp.float32),
+        "ssm_dt_bias": jnp.asarray(dt + np.log(-np.expm1(-dt)), jnp.float32),
+        "ssm_D": jnp.ones((H,), jnp.float32),
+        "ssm_norm_s": jnp.ones((wide,), cfg.dtype),
+        "ssm_wout": sd(wide, D) / float(np.sqrt(cfg.n_layers)),
+    }
+
+
+def ssm_zero_state(cfg: TransformerConfig, B: int) -> dict:
+    """The state of ``B`` requests that have seen no token."""
+    wide, gn, _ = ssm_widths(cfg)
+    return {
+        "S": jnp.zeros((B, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
+                       jnp.float32),
+        "conv": jnp.zeros((B, cfg.ssm_conv - 1, wide + 2 * gn), cfg.dtype),
+    }
+
+
+def _ssm_step(x, Bm, Cm, dA, dt, S):
+    """One token of the recurrence, float32: x (B, H, P), Bm, Cm (B, G,
+    N), dA (the log decay) and dt (B, H), S (B, H, N, P). Returns ``(y
+    (B, H, P), S)``, ``y = S_t C_t`` without the skip."""
+    r = x.shape[1] // Bm.shape[1]
+    Bh, Ch = jnp.repeat(Bm, r, axis=1), jnp.repeat(Cm, r, axis=1)
+    S = S * jnp.exp(dA)[..., None, None] + (
+        Bh[..., :, None] * (dt[..., None] * x)[..., None, :])
+    return (S * Ch[..., :, None]).sum(axis=-2), S
+
+
+def _ssm_chunks(x, Bm, Cm, dA, dt, S, c: int):
+    """The same recurrence over T rows, ``c`` at a time, all float32: x
+    (B, T, H, P), Bm, Cm (B, T, G, N), dA, dt (B, T, H), S (B, H, N,
+    P). Inside a sub-chunk with ``L`` the running sum of dA, ``y_t =
+    sum_{s <= t} exp(L_t - L_s) (C_t . B_s) dt_s x_s + exp(L_t) C_t
+    S0`` and ``S = exp(L_c) S0 + sum_s exp(L_c - L_s) B_s (dt_s
+    x_s)^T``: :func:`_la_chunks` with a decay that is the token's own
+    scalar a head and B, C shared by a group's heads (their ``C B^T``
+    is made once a group). A scan over the sub-chunks carries S. A row
+    with dA = 0 and dt = 0 leaves S as it was (padding)."""
+    B, T, H, P = x.shape
+    G = Bm.shape[2]
+    c = min(c, T)
+    pad = -T % c
+    if pad:
+        padt = lambda a: jnp.pad(
+            a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        x, Bm, Cm, dA, dt = (padt(a) for a in (x, Bm, Cm, dA, dt))
+    n = (T + pad) // c
+
+    def rows(a):  # (B, T, H[, D]) -> (n, B, H, c[, D]): scanned axis first
+        a = jnp.moveaxis(a.reshape((B, n, c) + a.shape[2:]), 1, 0)
+        return jnp.swapaxes(a, 2, 3)
+
+    xs = rows(x * dt[..., None])
+    tri = jnp.tril(jnp.ones((c, c), bool))
+    heads = lambda a: a.reshape((B, G, H // G) + a.shape[2:])
+
+    def sub(S, xs):
+        u, Bm, Cm, dA = xs        # (B, H, c, P), (B, G, c, N) x 2, (B, H, c)
+        L = jnp.cumsum(dA, axis=-1)
+        # exp(L_t - L_s) on and below the diagonal, 0 above: masked
+        # before the exponential (above it the difference is positive)
+        decay = jnp.exp(jnp.where(
+            tri, L[..., :, None] - L[..., None, :], -jnp.inf))
+        cb = jnp.einsum("bgtn,bgsn->bgts", Cm, Bm, precision=_HI)
+        w = heads(decay) * cb[:, :, None]                # (B, G, r, c, c)
+        y = jnp.einsum("bgrts,bgrsp->bgrtp", w, heads(u), precision=_HI)
+        y = y + heads(jnp.exp(L))[..., None] * jnp.einsum(
+            "bgtn,bgrnp->bgrtp", Cm, heads(S), precision=_HI)
+        last = heads(jnp.exp(L[..., -1:] - L))[..., None]
+        S = S * jnp.exp(L[..., -1])[..., None, None] + jnp.einsum(
+            "bgsn,bgrsp->bgrnp", Bm, heads(u) * last,
+            precision=_HI).reshape(S.shape)
+        return S, y.reshape(u.shape)
+
+    S, y = jax.lax.scan(sub, S, (xs, rows(Bm), rows(Cm), rows(dA)))
+    y = jnp.moveaxis(jnp.swapaxes(y, 2, 3), 0, 1).reshape(B, n * c, H, P)
+    return y[:, :T], S
+
+
+def ssm_rule_route(cfg: TransformerConfig, T: int) -> str:
+    """The form the recurrence takes over a call of T rows, from what
+    the shapes say: for one token ``"kernel"`` (ops/ssm_step.py, which
+    updates S where it lies, at a head size of whole lane tiles: the
+    published widths) or ``"xla"`` (:func:`_ssm_step`); over T > 1 rows
+    ``"xla"`` (:func:`_ssm_chunks`: products the compiler schedules;
+    there is no kernel). :func:`ssm_half` asks it, and the serving
+    scheduler for the ``ssm_rule`` of its spans."""
+    if T == 1 and ssm_step_viable(cfg.ssm_heads, cfg.ssm_groups,
+                                  cfg.ssm_state, cfg.ssm_head_dim):
+        return "kernel"
+    return "xla"
+
+
+def ssm_half(x, lp, state, cfg, valid=None):
+    """The state-space mixer of a layer that holds one beside its
+    attention, on the layer's input (B, T, D) from ``state``
+    (:func:`ssm_zero_state`'s leaves): the layer's norm (the one the
+    attention reads), ``ssm_in_scale``, the in-projection times
+    ``ssm_scales`` over its spans ``[z | x | B | C | dt]``, the causal
+    depthwise conv with its bias and silu over ``[x | B | C]``, the
+    recurrence in float32 (``dt = softplus(dt + dt_bias)``, ``a =
+    exp(dt * -exp(A_log))``, ``S = a S + dt B x^T``, ``y = S C + D x``),
+    the gate ``y * silu(z)`` and THEN an RMSNorm over each group's
+    share of the joined heads, the out-projection, ``ssm_out_scale``.
+    Returns ``(s, state)``: ``s`` (B, T, D) is NOT joined to the
+    residual; :func:`attn_merge` takes it as ``beside``. Of ``valid``
+    see :func:`gdn_half`."""
+    B, T, _ = x.shape
+    H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                  cfg.ssm_groups)
+    wide, gn, proj = ssm_widths(cfg)
+    h = _scaled(_norm(x, lp, "ln1", cfg), cfg.ssm_in_scale)
+    with jax.named_scope("ssm_proj"):
+        zxbcdt = jnp.einsum("bld,dc->blc", h, lp["ssm_win"])
+        if any(s != 1.0 for s in cfg.ssm_scales):
+            zxbcdt = zxbcdt * jnp.asarray(np.repeat(
+                np.asarray(cfg.ssm_scales, np.float32),
+                [wide, wide, gn, gn, H]), zxbcdt.dtype)
+        z, xbc = zxbcdt[..., :wide], zxbcdt[..., wide:proj - H]
+        dt = zxbcdt[..., proj - H:].astype(jnp.float32)
+    with jax.named_scope("ssm_conv"):
+        y, tail = _causal_conv(xbc, state["conv"], lp["ssm_conv_w"], valid,
+                               lp["ssm_conv_b"])
+    with jax.named_scope("ssm_rule"):
+        xs = y[..., :wide].reshape(B, T, H, P)
+        Bm = y[..., wide:wide + gn].reshape(B, T, G, N)
+        Cm = y[..., wide + gn:].reshape(B, T, G, N)
+        dt = jax.nn.softplus(dt + lp["ssm_dt_bias"])
+        if valid is not None:
+            real = ((jnp.arange(T) < valid[:, None])[..., None]
+                    if jnp.ndim(valid) else
+                    (jnp.arange(T) < valid)[None, :, None])
+            dt = jnp.where(real, dt, 0.0)
+        dA = -jnp.exp(lp["ssm_A_log"]) * dt
+        if T == 1:
+            step = (ssm_step if ssm_rule_route(cfg, 1) == "kernel"
+                    else _ssm_step)
+            o, S = step(xs[:, 0], Bm[:, 0], Cm[:, 0], dA[:, 0], dt[:, 0],
+                        state["S"])
+            o = o[:, None]
+        else:
+            o, S = _ssm_chunks(xs, Bm, Cm, dA, dt, state["S"],
+                               cfg.ssm_chunk)
+        o = o + lp["ssm_D"][:, None] * xs
+    with jax.named_scope("ssm_out"):
+        o = o.reshape(B, T, wide) * jax.nn.silu(z.astype(jnp.float32))
+        o = _rms(o.reshape(B, T, G, wide // G),
+                 lp["ssm_norm_s"].astype(jnp.float32).reshape(G, wide // G),
+                 cfg.norm_eps).reshape(B, T, wide)
+        a = jnp.einsum("blc,cd->bld", o.astype(x.dtype), lp["ssm_wout"])
+        a = _scaled(a, cfg.ssm_out_scale)
+    return a, {"S": S, "conv": tail}
 
 
 # A selection of key blocks (``cfg.sparse_block``), written once: every
@@ -1617,8 +1895,9 @@ def _mlp(x, lp):
     return jnp.einsum("blf,fd->bld", a, lp["w2"])
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    a = jax.nn.silu(jnp.einsum("bld,df->blf", x, w_gate))
+def _swiglu(x, w_gate, w_up, w_down, gate_scale: float = 1.0):
+    a = jax.nn.silu(_scaled(jnp.einsum("bld,df->blf", x, w_gate),
+                            gate_scale))
     return jnp.einsum("blf,fd->bld", a * jnp.einsum("bld,df->blf", x, w_up),
                       w_down)
 
@@ -1645,7 +1924,8 @@ def ffn_half(x, lp, cfg, li, *, tp_psum=False):
             else:
                 y, aux = moe_ffn_dense(h, lp, cfg.capacity_factor)
         elif cfg.ffn == "swiglu":
-            y = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+            y = _scaled(_swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"],
+                                cfg.ffn_gate_scale), cfg.ffn_down_scale)
         else:
             y = _mlp(h, lp)
             if tp_psum:
@@ -1747,7 +2027,10 @@ def _mixer_dense(x, lp, cfg, li: int, rope, impl):
     gated delta rule from a zero state, or latent attention in its
     expanded form."""
     x, mix = hc_pre(x, lp, cfg, "hc1")
-    if cfg.state(li):
+    beside = None
+    if cfg.ssm(li):
+        beside = ssm_half(x, lp, zero_state(cfg, li, x.shape[0]), cfg)[0]
+    elif cfg.state(li):
         return state_half(x, lp, zero_state(cfg, li, x.shape[0]), cfg, li,
                           rope, mix=mix)[0]
     if cfg.mla(li):
@@ -1760,7 +2043,7 @@ def _mixer_dense(x, lp, cfg, li: int, rope, impl):
         o = sparse_attention_dense(q, k, v, cfg)
     else:
         o = impl(q, k, v, causal=True, window=cfg.windows[li])
-    return attn_merge(x, o, gate, lp, cfg, mix=mix)
+    return attn_merge(x, o, gate, lp, cfg, mix=mix, beside=beside)
 
 
 def forward_dense(params: dict, tokens: jax.Array, cfg: TransformerConfig):

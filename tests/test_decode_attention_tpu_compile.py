@@ -446,3 +446,92 @@ def test_flash_kernels_compile_for_the_v5e(one_chip, B, L, H, Hkv, D,
         _sds(one_chip, (B, L, Hkv, D), dtype),
         _sds(one_chip, (B, L, Hkv, D), dtype))
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_ssm_step_kernel_leaves_the_state_where_it_lies(one_chip):
+    """The state-space mixer's single-token kernel (ops/ssm_step.py) for
+    16 slots of 32 heads of 256 x 128 in 2 groups (Falcon-H1-34B's)
+    under a scan that carries six layers' states, as the tick does:
+    Mosaic takes it (a group's 16 heads a grid step, 2 MiB of S), and
+    the compiled program moves no ``f32[16,32,256,128]``: the kernel's
+    operand is the scan's carry itself."""
+    from mpistragglers_jl_tpu.ops.ssm_step import ssm_step
+
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    B, H, G, N, P = 16, 32, 2, 256, 128
+    step = functools.partial(ssm_step, interpret=False)
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def steps(states, x, Bm, Cm, dA, dt):
+        def body(carry, _):
+            states, y = carry
+            out = []
+            for S in states:
+                y, S = step(x + y, Bm, Cm, dA, dt, S)
+                out.append(S)
+            return (out, y), None
+        return jax.lax.scan(body, (states, jnp.zeros_like(x)), None,
+                            length=8)[0]
+
+    with jax.enable_x64(False):
+        text = steps.lower(
+            [f32(B, H, N, P)] * 6, f32(B, H, P), f32(B, G, N), f32(B, G, N),
+            f32(B, H), f32(B, H)).compile().as_text()
+    state = f"f32[{B},{H},{N},{P}]"
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and state in line]
+    assert len(calls) == 6
+    for line in calls:  # the operand comes straight out of the carry
+        assert "get-tuple-element" in line.split("custom-call(")[1]
+    moved = [line for line in text.splitlines() if _result_of(
+        line, "copy", "copy-start", "slice-start", "fusion").count(state)]
+    assert not moved, moved[:3]
+
+
+def test_falcon_h1_tick_leaves_states_and_pools_where_they_lie(one_chip):
+    """The whole decode tick of ``serve_falconh1_chat`` (the cell's own
+    configuration file: 16 slots, 8 steps, six layers that each hold
+    pages AND a state), compiled as the scheduler would run it on the
+    kernel route: the paged attention kernel at a group of 5 query heads
+    to a K/V head and the step kernel are both there, and the scan's
+    body moves neither a layer's states (67 MB) nor a page pool through
+    the compiler's fast memory space."""
+    import json
+    import pathlib
+
+    from chipbench.runners import serve_ssm
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.ops import (
+        decode_attention,
+        flash_attention,
+        ssm_step,
+    )
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    config = json.loads((root / "chipbench" / "configs"
+                         / "falcon-h1-34b-serve.json").read_text())
+    cfg, program = serve_ssm.transformer_config(config), config["program"]
+    sched = serving.ServingScheduler(
+        serve_ssm.param_shapes(config), cfg, slots=program["slots"],
+        n_inner=program["n_inner"], quantize_kv=program["quantize_kv"],
+        page_tokens=program["page_tokens"],
+        prompt_chunk=program["prompt_chunk"],
+        max_prompt=program["max_prompt"])
+    assert sched.use_kernel
+    tick = serving._serving_scan_paged(
+        cfg, sched.n_inner, None, sched.temperature, None, True, sched.P)
+    args = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                        sched._scan_args())
+    # as on the chip: the kernels through Mosaic, not the interpreter
+    compiled = lambda: False
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        for module in (flash_attention, decode_attention, ssm_step):
+            patch.setattr(module, "_use_interpret", compiled)
+        text = tick.lower(*args).compile().as_text()
+    for name in ("ssm_step", "paged_decode_attention"):
+        assert f"%{name}" in text
+    for held in ("f32[16,32,256,128]", "s8[193,64,512]"):
+        assert held in text
+        moved = [line for line in text.splitlines() if held in _result_of(
+            line, "copy", "copy-start", "slice-start")]
+        assert not moved, moved[:3]
