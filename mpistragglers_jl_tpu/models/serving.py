@@ -118,10 +118,31 @@ Design (TPU-first):
   between the same ticks, and a request's admission still ends (first
   token, pages registered) before the next request is planned wherever
   its end can change that plan (``_due_at_once``).
+* **A tick's admissions are planned behind the tick before.** Where a
+  request can only end by its length (no ``eos_id``, no drafter:
+  ``ServingScheduler._ends_known``) a decoding slot gains exactly
+  ``n_inner`` tokens a tick, so the host knows which slots a tick ends
+  before its tokens are back. ``step`` then dispatches the tick, frees
+  those slots, runs the NEXT tick's whole admit phase (the same plan,
+  the same chunks in the same programs, stamped as that tick's) while
+  the chip runs this one, and only then fetches and harvests: the
+  programs queue behind the tick by their data, and the chip goes from
+  a tick straight into the next one's prefill. A first token stays a
+  device value there (placement has put it into the slot's row) and
+  is read with the fetch of its request's first tick; no read of a
+  device value stands between two dispatches. The admit phase at the
+  top of ``step`` stays for what arrives between two steps.
 * **EOS retirement + slot reuse.** Rows that emit ``eos_id`` keep
   emitting it on-device (static shapes; ``_eos_clamp``); the host
   strips the tail, retires the request (EOS or its ``max_new`` budget),
-  and hands the slot to the next queued request.
+  and hands the slot to the next queued request. Retirement has two
+  halves: the slot's (the row done, its pages back, its table row
+  nulled) and the request's (tokens trimmed, ``finished``, ``reason``,
+  ``retired_tick``). In order they run together in the harvest; planned
+  ahead the slot's half runs by count right behind the dispatch and
+  the request's half in the harvest. With ``eos_id`` an end is a
+  token's VALUE, with a drafter a step's yield is: both keep the order
+  admit, tick, harvest and read a first token where it is made.
 
 Greedy decoding per row equals the single-request oracle
 (:func:`~.decode.generate_ring_dense`) token-for-token — the batched
@@ -146,6 +167,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import time
 from collections import deque
 from typing import Any, Sequence
@@ -1726,7 +1748,9 @@ class _ServingObs:
         if self._r:
             self.m_tokens.inc(n)
             last = req._t_last_tok
-            if last is not None and n:
+            # (a first token handed over with its tick's tokens is
+            # stamped with that tick's fetch: no gap to sample)
+            if last is not None and n and t > last:
                 self.m_intertoken.observe((t - last) / n)
         req._t_last_tok = t
 
@@ -1749,12 +1773,15 @@ class _ServingObs:
 
     def tick_done(
         self, sched: "ServingScheduler", retired, tick: _LitPhase,
-        admit: _LitPhase, decode: _LitPhase | None,
-        harvest: _LitPhase | None,
+        phases: list[tuple[str, _LitPhase]], ahead: bool,
     ) -> None:
-        """The closed phases of the tick just run (``serving.tick`` /
-        ``.admit`` / ``.decode`` / ``.harvest``; the last two None when
-        no slot decoded)."""
+        """The closed phases of the tick just run, in their order:
+        ``serving.tick`` and, by the recorder's names, ``admit`` /
+        ``decode`` / ``retire`` (``serving.harvest``). A tick in which
+        no slot decoded has its one ``admit``; a tick that planned the
+        next one's admissions behind its own program has a second
+        ``admit`` between two ``decode`` spans (``ahead``: this tick's
+        own admissions were planned that way)."""
         wall = tick.t1 - tick.t0
         n_toks, self._tick_toks = self._tick_toks, 0
         if self._r:
@@ -1763,7 +1790,7 @@ class _ServingObs:
             self.m_queue.set(sched.pending)
             self.m_active.set(sched.active)
             self.m_tok_rate.set(n_toks / wall if wall > 0 else 0.0)
-            if decode is not None:
+            if any(name == "decode" for name, _ in phases):
                 self.m_route.inc()
             for req in retired:
                 self.m_retired[req.reason].inc()
@@ -1782,12 +1809,10 @@ class _ServingObs:
                 f"tick {sched.tick_count}", tick.t0, wall,
                 track="scheduler", queue=sched.pending,
                 active=sched.active, tokens=n_toks,
-                retired=len(retired),
+                retired=len(retired), ahead=int(ahead),
             )
-            for name, ph in (("admit", admit), ("decode", decode),
-                             ("retire", harvest)):
-                if ph is not None:
-                    sp.add(name, ph.t0, ph.t1 - ph.t0, track="scheduler")
+            for name, ph in phases:
+                sp.add(name, ph.t0, ph.t1 - ph.t0, track="scheduler")
             sp.count("queue_depth", sched.pending, t=tick.t1)
             sp.count("active_slots", sched.active, t=tick.t1)
             sp.count("pages_used", sched.pool.used, t=tick.t1)
@@ -1835,6 +1860,10 @@ class Request:
         # the observability hooks the tests and bench read
         self.admitted_tick: int | None = None
         self.retired_tick: int | None = None
+        # the first token while it is still a device value: made when
+        # admission ended, handed over with the fetch of the request's
+        # first tick (scheduler-internal: ``_ends_known``)
+        self._first = None
         # latency stamps (perf_counter), set only by an instrumented
         # scheduler (registry=/spans=): submit time and last-token time
         self._t_submit: float | None = None
@@ -1920,6 +1949,12 @@ class ServingScheduler:
     decode steps for all slots in one device program; (4) harvest
     tokens, retire
     rows that emitted EOS or exhausted their budget, free their slots.
+    Without ``eos_id`` (and without a drafter, ``qos=`` or ``cache=``)
+    a tick's ends follow from lengths, and (1) and (2) of the NEXT tick
+    run between this tick's dispatch and the fetch of its tokens,
+    behind it on the device (:meth:`step` has the order): the same
+    schedule and streams, with the host's work hidden behind the
+    chip's; ``ticks_ahead`` counts the ticks planned that way.
     Greedy by default; ``temperature > 0`` (optionally ``top_k``)
     samples each slot with its request's own key (``submit(...,
     key=...)``; id-derived when omitted) — a sampled stream equals
@@ -2002,7 +2037,8 @@ class ServingScheduler:
     reads no clock and builds no registry object. What every tick does,
     attached or not, is enter one ``jax.profiler.TraceAnnotation`` per
     phase (``serving.tick`` around ``serving.admit`` / ``.decode`` /
-    ``.harvest``; the table is in docs/API.md): an open profiler
+    ``.harvest``, a second ``.admit`` between two ``.decode`` where the
+    next tick is planned ahead; the table is in docs/API.md): an open profiler
     session sees them on the device trace's clock, and with none open
     each costs an atomic check. ``spans=`` and ``flight=`` cut their
     spans at the same boundaries.
@@ -2160,6 +2196,19 @@ class ServingScheduler:
         self._pending: list[int] = []
         self._tick_chunks = self._tick_chunk_programs = 0
         self.tick_count = 0
+        # the ticks whose admissions were planned behind the tick
+        # before them (``step``), and what that plan left for the tick
+        # it was made for: the counts as that tick begins and the queue
+        # behind its admissions (None: nothing planned ahead), and the
+        # requests it admitted, whose ``admitted_tick`` that tick sets
+        self.ticks_ahead = 0
+        self._ahead: tuple[dict, int] | None = None
+        self._admitted_ahead: list[Request] = []
+        # the tick whose admission work is running: ``tick_count``, and
+        # one more while the next tick's is planned ahead
+        self._admit_tick = 0
+        # a dispatched tick's tokens until they are fetched
+        self._toks_dev = None
         # device-resident row state + batched ring cache arena
         self.temperature = float(temperature)
         self.top_k = top_k
@@ -2493,11 +2542,19 @@ class ServingScheduler:
         return (self.params, self._tok, self._pos, self._done,
                 self._caches, self._keys, self._device_pt())
 
-    def _decode_scan_fetch(self) -> np.ndarray:
-        """Run the jitted decode tick and fence the tokens to host."""
+    def _decode_dispatch(self) -> None:
+        """Launch the jitted decode tick; its tokens stay a device
+        value until :meth:`_decode_scan_fetch` brings them home."""
         with _annotate("serving.decode_dispatch"):
             (self._tok, self._pos, self._done, self._caches,
-             toks) = self._scan(*self._scan_args())
+             self._toks_dev) = self._scan(*self._scan_args())
+
+    def _decode_scan_fetch(self) -> np.ndarray:
+        """Fence the dispatched tick's tokens to the host (the tick was
+        launched by :meth:`_decode_dispatch`; between the two ``step``
+        may plan the next tick's admissions). The name is the handle
+        the benchmark's tests hold on the tokens a tick hands over."""
+        toks, self._toks_dev = self._toks_dev, None
         with _annotate("serving.decode_wait"):
             if self.draft is not None:
                 return self._fetch_drafted(toks)
@@ -2547,10 +2604,13 @@ class ServingScheduler:
     def _device_pt(self):
         """The device page tables, one per cache width, refreshed from
         the host-authoritative copies when admission/COW/retirement
-        dirtied them."""
+        dirtied them. Each is made from a COPY of the host's table:
+        the CPU backend may take a numpy buffer as it lies, and the
+        host writes the next tick's rows into its table while the tick
+        that reads this one is still running (``_admit_ahead``)."""
         if self._pt_dev is None:
             self._pt_dev = tuple(
-                jnp.asarray(kd.pt_host) for kd in self._kinds)
+                jnp.asarray(kd.pt_host.copy()) for kd in self._kinds)
         return self._pt_dev
 
     @staticmethod
@@ -2559,31 +2619,78 @@ class ServingScheduler:
         programs take it."""
         return tuple(np.array(r, np.int32) for r in rows)
 
-    def step(self) -> list[Request]:
-        """One scheduler tick; returns the requests retired in it
-        (including any that retire at admission — max_new == 1 or a
-        first-token EOS). Every phase is a profiler annotation
-        (``serving.tick`` around ``serving.admit`` / ``.decode`` /
-        ``.harvest``, see ``_LitPhase``): a ``jax.profiler`` session
-        sees them on the device trace's clock, and with none open they
-        cost an atomic check each. When instrumented (``registry=`` /
-        ``spans=`` / ``flight=`` / ``exporter=``) the same boundaries
-        also read the clock for the admit/decode/retire spans and the
-        queue/slot/token series; dark, the hot path reads no clock."""
-        obs = self._obs
-        flight = self._flight
-        lit = self._stamp_ticks  # obs, flight, OR exporter attached
-        phase = _LitPhase if lit else _annotate
-        if self._extend_group is not None and self._scratch_arenas is None:
-            self._warm_chunk_group()
-        self.tick_count += 1
-        self._tick_chunks = self._tick_chunk_programs = 0
-        retired: list[Request] = []
-        decode = harvest = None
+    def _ends_known(self) -> bool:
+        """Can the host count a tick's ends before its tokens are back?
+        Where a request ends by its length alone, a decoding slot gains
+        exactly ``n_inner`` tokens a tick, so which slots a tick frees,
+        which pages they give back and which queued requests take them
+        follow from lengths: ``step`` then plans the next tick's
+        admissions behind the running tick, and a first token stays on
+        the device until its request's first tick is fetched. Not with
+        ``eos_id`` (an end is a token's VALUE; run ahead, an EOS would
+        be found a tick after the next plan was made and cost its slot
+        ``n_inner`` steps more: a trade for a later change, with
+        traffic that ends by EOS to measure it on), not with a drafter
+        (a step delivers one token or two as the data decide), and not
+        under ``qos=`` or ``cache=`` (a departure there moves cold
+        pages, tenants' counts and, through the fleet cache, page
+        bytes the host reads: they stay in order)."""
+        return (self.eos_id is None and self.draft is None
+                and self._qos is None and self.cache is None)
+
+    def _counted_ends(self, live) -> list[int] | None:
+        """The slots whose request the tick just dispatched ends, by
+        count (``live``: its ``(slot, request)`` pairs), or None where
+        the next tick's admissions wait for this tick's harvest as they
+        always did: :meth:`_ends_known` is false, or a request with
+        ``max_new == 1`` is in prefill or among those the freed slots
+        could take (it retires where its first token is made, which is
+        a read of that token: an at-once case, kept in order)."""
+        if not self._ends_known():
+            return None
+        ending = [
+            s for s, req in live
+            if (len(req.tokens) + (req._first is not None)
+                + self.n_inner >= req.max_new)
+        ]
+        n_free = self._slot_req.count(None) + len(ending)
+        if (any(st.req.max_new == 1 for st in self._admitting.values())
+                or any(r.max_new == 1 for r in
+                       itertools.islice(self._queue, n_free))):
+            return None
+        return ending
+
+    def _admit_ahead(self, live, ending: list[int],
+                     retired: list[Request]) -> None:
+        """The next tick's admit phase, behind the tick still running:
+        the slot's half of every counted retirement (the row done, its
+        pages back, its table row nulled: the running tick was
+        dispatched with the old table, so its writes go where they
+        went), then what the top of the next ``step`` would run, on the
+        same plan, in the same programs, stamped as that tick's. Every
+        program it dispatches takes the running tick's outputs
+        (``_caches``, ``_done``, ``_tok``), so the device runs them
+        behind it, and no device value is read here. The request's half
+        of a retirement stays in this tick's harvest."""
+        for s, _ in live:
+            self._host_pos[s] += self.n_inner
+        for s in ending:
+            self._free_slot(s)
+        self._admit_tick = self.tick_count + 1
+        begin = self._tick_begin()
+        self._advance_admissions(retired)
+        self._admit_from_queue(retired)
+        self._run_pending(retired)
+        self._ahead = (begin, self.pending)
+
+    def _tick_begin(self) -> dict:
+        """``serving.tick``'s counts of the schedule as a tick begins
+        (before its admissions): read at the top of ``step`` or, for a
+        tick planned ahead, where its admit phase began."""
         n_admitting = len(self._admitting)
         n_free = self._slot_req.count(None)
-        with phase(
-            "serving.tick", tick=self.tick_count, queue=self.pending,
+        return dict(
+            queue=self.pending,
             decoding=self.S - n_free - n_admitting,
             admitting=n_admitting, free=n_free,
             # the route the tick's attention takes: 1 the int8 Pallas
@@ -2607,39 +2714,134 @@ class ServingScheduler:
             # and the blocks they attend and see, summed over those
             # slots and the K/V heads
             **self._sparse_tick_counts(),
-        ) as tick:
+        )
+
+    def step(self) -> list[Request]:
+        """One scheduler tick; returns the requests retired in it
+        (including any that retire at admission — max_new == 1 or a
+        first-token EOS), each with all its tokens.
+
+        The order of a tick: (1) ``serving.admit``: what this tick's
+        admissions still need; (2) ``serving.decode``: the
+        copy-on-write page pass and the DISPATCH of the tick's program;
+        (3) where the tick's ends can be counted before its tokens are
+        back (:meth:`_ends_known`: no ``eos_id``, no drafter, no
+        ``qos=`` / ``cache=``) a second ``serving.admit``: the slots of
+        the requests this tick ends are freed and the NEXT tick's whole
+        admit phase is planned and dispatched behind the running
+        program (:meth:`_admit_ahead`), so the chip goes from the tick
+        straight into the next tick's prefill programs; (4)
+        ``serving.decode`` again: the fetch of the tick's tokens; (5)
+        ``serving.harvest``: tokens to their requests (a first token in
+        front of its request's first tick's), the request's half of
+        each retirement. No read of a device value stands between two
+        dispatches on that path. A tick whose admissions were planned
+        ahead does in (1) only what has arrived since (a ``submit``
+        between two steps); in a backlog that is nothing. With
+        ``eos_id`` or a drafter an end is a token's value, and (3) is
+        left out: admit, dispatch and fetch, harvest with both halves
+        of a retirement, the first token read where it is made, the
+        order this method always had (so also around a request with
+        ``max_new == 1``: :meth:`_counted_ends`). The schedule is the
+        same either way: the same requests in the same slots and
+        pages, the same chunks in the same programs, the same
+        ``admitted_tick`` / ``retired_tick``, the same tokens.
+
+        Every phase is a profiler annotation
+        (``serving.tick`` around ``serving.admit`` / ``.decode`` /
+        ``.harvest``, siblings in that order, see ``_LitPhase``): a
+        ``jax.profiler`` session
+        sees them on the device trace's clock, and with none open they
+        cost an atomic check each. ``serving.tick``'s counts are the
+        schedule's as the tick begins, wherever its admit phase ran,
+        and ``ahead`` says whether it ran behind the tick before
+        (``ticks_ahead`` counts those ticks). When instrumented
+        (``registry=`` /
+        ``spans=`` / ``flight=`` / ``exporter=``) the same boundaries
+        also read the clock for the admit/decode/retire spans and the
+        queue/slot/token series; dark, the hot path reads no clock."""
+        obs = self._obs
+        flight = self._flight
+        lit = self._stamp_ticks  # obs, flight, OR exporter attached
+        phase = _LitPhase if lit else _annotate
+        if self._extend_group is not None and self._scratch_arenas is None:
+            self._warm_chunk_group()
+        self.tick_count += 1
+        self._admit_tick = self.tick_count
+        retired: list[Request] = []
+        phases = []  # (the recorder's name, the closed phase), in order
+        # this tick's admit phase ran behind the last tick: its counts
+        # are that moment's, its queue what stood there plus what has
+        # been submitted since
+        planned, self._ahead = self._ahead, None
+        if planned is None:
+            begin = self._tick_begin()
+        else:
+            begin, left = planned
+            begin["queue"] += self.pending - left
+            self.ticks_ahead += 1
+            # a request is stamped by the tick that admits it, when
+            # that tick begins: between two steps nothing carries a
+            # stamp of a tick that has not run
+            for req in self._admitted_ahead:
+                req.admitted_tick = self.tick_count
+            self._admitted_ahead = []
+        with phase("serving.tick", tick=self.tick_count,
+                   ahead=int(planned is not None), **begin) as tick:
             with phase("serving.admit") as admit:
-                self._advance_admissions(retired)
+                if planned is None:
+                    self._advance_admissions(retired)
                 self._admit_from_queue(retired)
                 self._run_pending(retired)
+            phases.append(("admit", admit))
             # the chunks this tick ran (the admitting slots' and the
             # first of each request it admitted), in how many programs
             tick.set_metadata(chunks=self._tick_chunks,
                               chunk_programs=self._tick_chunk_programs)
+            self._tick_chunks = self._tick_chunk_programs = 0
             decoding = [
                 s for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._admitting
             ]
             if decoding:
-                with phase("serving.decode", slots=len(decoding),
-                           **self._step_route) as decode:
+                # the slots and their requests as the tick is
+                # dispatched: planned ahead, a slot has its next
+                # request before this one's tokens are back
+                live = [(s, self._slot_req[s]) for s in decoding]
+                ending = self._counted_ends(live)
+                decode_args = dict(slots=len(decoding), **self._step_route)
+                with phase("serving.decode", **decode_args) as decode:
                     # COW pass: every page the next n_inner writes
                     # touch must be exclusively owned BEFORE the
                     # jitted scan runs (the device program never
                     # sees shared pages)
                     self._prepare_tick_pages(decoding)
-                    host = self._decode_scan_fetch()
+                    self._decode_dispatch()
+                    if ending is None:
+                        host = self._decode_scan_fetch()
+                if ending is not None:
+                    phases.append(("decode", decode))
+                    with phase("serving.admit") as admit:
+                        self._admit_ahead(live, ending, retired)
+                    phases.append(("admit", admit))
+                    with phase("serving.decode", **decode_args) as decode:
+                        host = self._decode_scan_fetch()
+                phases.append(("decode", decode))
                 with phase("serving.harvest") as harvest:
                     n_tokens = n_retired = 0
                     self.drafted = self.accepted = 0
-                    for s in decoding:
-                        req = self._slot_req[s]
+                    for s, req in live:
+                        if req._first is not None:
+                            self._deliver_first(
+                                req, decode.t1 if lit else None)
                         n_before = len(req.tokens)
                         if self.draft is None:
                             req.tokens.extend(int(t) for t in host[s])
                         else:
                             self._deliver_drafted(req, host[s])
-                        self._host_pos[s] += len(req.tokens) - n_before
+                        if ending is None:
+                            self._host_pos[s] += (len(req.tokens)
+                                                  - n_before)
                         due = self._retire_if_due(req)
                         if self.draft is not None:
                             self._count_drafts(req, n_before)
@@ -2653,7 +2855,10 @@ class ServingScheduler:
                         if obs is not None:
                             obs.tokens_emitted(req, n_new, decode.t1)
                         if due:
-                            self._free_slot(s)
+                            # (counted ahead, the slot's half is done
+                            # and the slot may have its next request)
+                            if ending is None:
+                                self._free_slot(s)
                             retired.append(req)
                             n_retired += 1
                     harvest.set_metadata(tokens=n_tokens,
@@ -2672,8 +2877,10 @@ class ServingScheduler:
                             drafted=self.drafted, accepted=self.accepted,
                             **({} if self.mtp_experts_hit is None else
                                {"mtp_experts_hit": self.mtp_experts_hit}))
+                phases.append(("retire", harvest))
         if obs is not None:
-            obs.tick_done(self, retired, tick, admit, decode, harvest)
+            obs.tick_done(self, retired, tick, phases,
+                          planned is not None)
         if lit:
             self.last_tick_at = tick.t1
             if flight is not None:
@@ -2696,8 +2903,10 @@ class ServingScheduler:
         cfg = self.cfg
         if not cfg.sparse_layers:
             return {}
+        # (a decoding slot's position is one short of the rows its next
+        # query sees: the prompt's and its tokens', the newest unwritten)
         n = np.array([
-            r.prompt.size + len(r.tokens)
+            self._host_pos[s] + 1
             for s, r in enumerate(self._slot_req)
             if r is not None and s not in self._admitting], np.int64)
         return {"sparse_slots": int((n > cfg.sparse_dense_len).sum()),
@@ -3290,13 +3499,16 @@ class ServingScheduler:
                 req, cache, padded, n_chunks, base=base,
                 **admit_kw,
             )
-        req.admitted_tick = self.tick_count
+        if self._admit_tick == self.tick_count:
+            req.admitted_tick = self.tick_count
+        else:  # planned ahead: the tick it is planned for stamps it
+            self._admitted_ahead.append(req)
         if self._obs is not None and req.tenant is not None:
             self._obs.qos_admitted(self, req.tenant)
         if self._trace is not None and req.trace is not None:
             self._trace.event(
                 req.trace, "admitted", time.perf_counter(),
-                tick=self.tick_count,
+                tick=self._admit_tick,
             )
         # first chunk runs this very tick (short prompts admit in
         # one tick and decode from the next), in one program with the
@@ -3820,7 +4032,7 @@ class ServingScheduler:
             if self._trace is not None and st.req.trace is not None:
                 self._trace.event(
                     st.req.trace, "prefill_chunk", time.perf_counter(),
-                    tick=self.tick_count,
+                    tick=self._admit_tick,
                 )
 
     def _finish_admission(self, s: int, retired: list[Request]) -> None:
@@ -3869,24 +4081,43 @@ class ServingScheduler:
                 for j in range(st.n_cover):
                     kd.pool.register(st.digests[j], pids[j],
                                      volatile=wraps)
-        # the one place admission blocks on the device: the request's
-        # first token comes back before the tick's decode is dispatched
-        with _annotate("serving.first_token_wait", req=rid):
-            # with a drafter ``[first token, first draft]``: the draft
-            # stays on the device, in the slot's row
-            first = int(np.asarray(tok0).reshape(-1)[0])
-        st.req.tokens.append(first)
-        if self._obs is not None:
-            self._obs.first_token(st.req, time.perf_counter())
-        if self._trace is not None and st.req.trace is not None:
-            self._trace.event(
-                st.req.trace, "first_token", time.perf_counter(),
-                tick=self.tick_count,
-            )
         del self._admitting[s]
+        st.req._first = tok0
+        if self._ends_known() and st.req.max_new > 1:
+            # the first token stays a device value (placement has put
+            # it into the slot's row): the host reads it with the fetch
+            # of the request's first tick, so that no read stands
+            # between this dispatch and the next
+            return
+        # admission blocks on the device: the request's first token
+        # comes back before the tick's decode is dispatched, because
+        # its value (an EOS) or its being the last (``max_new`` 1) may
+        # end the request here
+        self._deliver_first(st.req)
         if self._retire_if_due(st.req):  # max_new == 1 or prompt EOS
             self._free_slot(s)
             retired.append(st.req)
+
+    def _deliver_first(self, req: Request, t: float | None = None) -> None:
+        """Read the request's first token and hand it over: where it
+        was made (an end that waits for its value) or, kept on the
+        device, in the harvest of the request's first tick, in front of
+        that tick's tokens (``t``: the fetch's stamp, which is then the
+        token's)."""
+        tok0, req._first = req._first, None
+        with _annotate("serving.first_token_wait", req=req.id):
+            # with a drafter ``[first token, first draft]``: the draft
+            # stays on the device, in the slot's row
+            first = int(np.asarray(tok0).reshape(-1)[0])
+        req.tokens.append(first)
+        if self._obs is not None:
+            self._obs.first_token(
+                req, time.perf_counter() if t is None else t)
+        if self._trace is not None and req.trace is not None:
+            self._trace.event(
+                req.trace, "first_token", time.perf_counter(),
+                tick=self.tick_count,
+            )
 
     # -- retirement -----------------------------------------------------
 

@@ -285,32 +285,58 @@ def test_streams_of_concurrent_admissions_match_the_oracle(
         assert by_req[r.id] == list(range(-(-r.prompt.size // 8)))
 
 
+def _programs_by_tick(spans):
+    """{tick: the chunk counts of its prefill programs, in order}. A
+    tick's programs are dispatched in its admit phase, and that runs
+    wherever the scheduler can count a tick's ends BEHIND the dispatch
+    of the tick before (``ServingScheduler._admit_ahead``): what a step
+    runs after its own tick's dispatch is the next tick's."""
+    out: dict[int, list[int]] = {}
+    owner = 0
+    for s in spans:
+        if s.name == "serving.tick":
+            owner = s.args["tick"]
+        elif s.name == "serving.decode_dispatch":
+            owner += 1
+        elif s.name == "serving.prefill_chunk":
+            out.setdefault(owner, []).append(s.args["chunks"])
+    return out
+
+
+def _own(spans):
+    """The spans of one step up to its tick's dispatch: its own
+    tick's."""
+    names = [s.name for s in spans]
+    return spans[:names.index("serving.decode_dispatch")
+                 if "serving.decode_dispatch" in names else None]
+
+
 def test_a_tick_dispatches_the_greedy_splits_number_of_programs(spans):
     sched = _sched(page_tokens=4)
     for p in _prompts(LENGTHS):
         sched.submit(p, max_new=4)
-    while sched.pending or sched.active:
-        before = len(spans)
-        admitting = len(sched._admitting)
-        queued = sched.pending
-        sched.step()
-        mine = spans[before:]
-        tick = next(s for s in mine if s.name == "serving.tick")
-        programs = [s.args["chunks"] for s in mine
-                    if s.name == "serving.prefill_chunk"]
-        assert tick.args["admitting"] == admitting
+    sched.run()
+    ticks = [s for s in spans if s.name == "serving.tick"]
+    by_tick = _programs_by_tick(spans)
+    # the counts are the schedule's as a tick begins, wherever its
+    # admit phase ran: a prompt of n chunks is through after n ticks
+    assert [t.args["admitting"] for t in ticks][:9] == [
+        0, 7, 6, 5, 4, 3, 2, 1, 0]
+    for tick in ticks:
+        programs = by_tick.get(tick.args["tick"], [])
         assert tick.args["chunks"] == sum(programs)
         assert tick.args["chunk_programs"] == len(programs)
-        if not queued:
+        if not tick.args["queue"]:
             # nothing is admitted: the admitting slots' chunks are all
             # the tick runs, four a program
-            assert programs == _greedy(admitting)
+            assert programs == _greedy(tick.args["admitting"])
+    # every tick but the first was planned behind the one before it
+    assert [t.args["ahead"] for t in ticks] == [0] + [1] * (len(ticks) - 1)
+    assert sched.ticks_ahead == sched.tick_count - 1
     # the first tick admitted eight prompts: the seven of several
     # chunks wait for each other (the one-chunk prompt, first in the
     # queue, has its first token before the second is looked at)
-    first = [s.args["chunks"] for s in spans
-             if s.name == "serving.prefill_chunk"][:3]
-    assert first == [1, 4, 3]
+    assert by_tick[1] == [1, 4, 3]
 
 
 @pytest.mark.parametrize("kw,first", [
@@ -331,8 +357,7 @@ def test_an_admission_ends_before_the_next_plan_only_where_it_binds_it(
     sched = _sched(**kw)
     reqs = [sched.submit(p, max_new=4) for p in _prompts(LENGTHS)]
     sched.step()
-    assert [s.args["chunks"] for s in spans
-            if s.name == "serving.prefill_chunk"] == first
+    assert _programs_by_tick(spans)[1] == first
     assert reqs[0].tokens and not reqs[1].tokens
     sched.run()
     for r in reqs:
@@ -353,7 +378,7 @@ def test_a_chunk_joins_the_chunks_that_are_due_when_it_is_admitted(spans):
     c = sched.submit(short, max_new=3)
     before = len(spans)
     sched.step()
-    programs = [s.args for s in spans[before:]
+    programs = [s.args for s in _own(spans[before:])
                 if s.name == "serving.prefill_chunk"]
     assert [p["chunks"] for p in programs] == [3]
     assert programs[0]["req"] == f"{a.id},{b.id},{c.id}"

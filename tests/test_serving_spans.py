@@ -26,10 +26,20 @@ INSIDE = {
     "serving.admit_new": "serving.admit",
     "serving.prefill_chunk": "serving.admit",
     "serving.first_token": "serving.admit",
-    "serving.first_token_wait": "serving.admit",
+    # the first token is read with the fetch of its request's first
+    # tick (no eos_id here: ``ServingScheduler._ends_known``)
+    "serving.first_token_wait": "serving.harvest",
     "serving.decode_dispatch": "serving.decode",
     "serving.decode_wait": "serving.decode",
 }
+# the phases of a tick, siblings in this order: no slot decodes; in
+# order (the first tick, and every tick where an end is a token's
+# value); the next tick's admit phase planned behind the dispatch
+PHASE_ORDERS = (
+    ["admit"],
+    ["admit", "decode", "harvest"],
+    ["admit", "decode", "admit", "decode", "harvest"],
+)
 
 
 @pytest.fixture(scope="module")
@@ -95,40 +105,100 @@ def _profiled(log_dir):
         jax.profiler.stop_trace()
 
 
+WORK = [(5, 6), (11, 9), (3, 5)]  # (prompt tokens, max_new)
+
+
+def _submit_work(sched, vocab):
+    rng = np.random.default_rng(0)
+    return [sched.submit(rng.integers(1, vocab, size=p), max_new=m)
+            for p, m in WORK]
+
+
 @pytest.fixture(scope="module")
-def traced(tiny, tmp_path_factory):
+def in_order(tiny):
+    """The same work through a scheduler that answers False where it is
+    asked whether a tick's ends can be counted: every tick's admit phase
+    runs at the top of its own ``step``, so the scheduler's state before
+    a step IS the schedule as that tick begins. [(that state, what the
+    tick delivered to whom, who had no token yet, who retired)]."""
+    from mpistragglers_jl_tpu.models.serving import ServingScheduler
+
+    class InOrder(ServingScheduler):
+        def _ends_known(self):
+            return False
+
+    cfg, params = tiny
+    sched = InOrder(
+        params, cfg, slots=2, n_inner=4, prompt_chunk=8, max_prompt=32,
+        quantize_kv=True, page_tokens=4,
+    )
+    reqs = _submit_work(sched, cfg.vocab)
+    state = []
+    while sched.pending or sched.active:
+        n_free = sched._slot_req.count(None)
+        begin = {
+            "tick": sched.tick_count + 1, "queue": sched.pending,
+            "admitting": len(sched._admitting), "free": n_free,
+            "decoding": sched.S - n_free - len(sched._admitting),
+            "kernel": int(sched.use_kernel),
+        }
+        before = [len(r.tokens) for r in reqs]
+        first = {i for i, r in enumerate(reqs) if not r.tokens}
+        retired = sched.step()
+        delivered = [len(r.tokens) - n for r, n in zip(reqs, before)]
+        state.append((begin, delivered, first,
+                      [reqs.index(r) for r in retired]))
+    assert sched.ticks_ahead == 0
+    return state, [list(r.tokens) for r in reqs]
+
+
+@pytest.fixture(scope="module")
+def traced(tiny, in_order, tmp_path_factory):
     """A dark paged scheduler run to the end under a profiler session:
     three requests over two slots (one prompt of two chunks, one slot
-    reused). Returns (events, what the scheduler's own state was when
-    each tick began and ended, the requests)."""
+    reused). Returns (events, the schedule tick by tick as the
+    scheduler that runs in order has it, the requests): what each step
+    delivered and returned is checked against it here."""
     cfg, params = tiny
     sched = _sched(cfg, params)
-    rng = np.random.default_rng(0)
-    reqs = [
-        sched.submit(rng.integers(1, cfg.vocab, size=p), max_new=m)
-        for p, m in [(5, 6), (11, 9), (3, 5)]
-    ]
+    reqs = _submit_work(sched, cfg.vocab)
     log_dir = str(tmp_path_factory.mktemp("serving_trace"))
-    state = []
+    state, tokens = in_order
     with _profiled(log_dir):
-        while sched.pending or sched.active:
-            n_free = sched._slot_req.count(None)
-            begin = {
-                "tick": sched.tick_count + 1, "queue": sched.pending,
-                "admitting": len(sched._admitting), "free": n_free,
-                "decoding": sched.S - n_free - len(sched._admitting),
-                "kernel": int(sched.use_kernel),
-            }
-            before = {r.id: len(r.tokens) for r in reqs}
-            first = {r.id for r in reqs if not r.tokens}
-            retired = sched.step()
-            delivered = {
-                r.id: len(r.tokens) - before[r.id] for r in reqs
-            }
-            state.append((begin, delivered, first,
-                          [r.id for r in retired]))
+        for begin, delivered, first, retired in state:
+            assert sched.pending or sched.active
+            before = [len(r.tokens) for r in reqs]
+            assert {i for i, r in enumerate(reqs) if not r.tokens} == first
+            assert [reqs.index(r) for r in sched.step()] == retired
+            assert [len(r.tokens) - n
+                    for r, n in zip(reqs, before)] == delivered
+    assert not sched.pending and not sched.active
     assert all(r.finished for r in reqs)
+    assert [list(r.tokens) for r in reqs] == tokens
+    assert sched.ticks_ahead == sched.tick_count - 1
+    state = [
+        (begin, dict(zip((r.id for r in reqs), delivered)),
+         {reqs[i].id for i in first}, [reqs[i].id for i in retired])
+        for begin, delivered, first, retired in state
+    ]
     return _host_events(log_dir), state, reqs
+
+
+def _programs_by_tick(events):
+    """{tick: the ``chunks`` of its prefill programs, in order}: a
+    tick's programs are dispatched in its admit phase, and what a step
+    runs after its own tick's dispatch is the NEXT tick's admit phase,
+    planned ahead."""
+    out: dict[int, list[int]] = {}
+    owner = 0
+    for name, _, _, args in events:
+        if name == "serving.tick":
+            owner = args["tick"]
+        elif name == "serving.decode_dispatch":
+            owner += 1
+        elif name == "serving.prefill_chunk":
+            out.setdefault(owner, []).append(args["chunks"])
+    return out
 
 
 def _children(events, parent):
@@ -158,11 +228,8 @@ def test_admit_decode_harvest_partition_the_tick(traced):
             if e[0] in ("serving.admit", "serving.decode",
                         "serving.harvest")
         ]
-        names = [e[0] for e in parts]
-        assert names in (
-            ["serving.admit"],
-            ["serving.admit", "serving.decode", "serving.harvest"],
-        )
+        names = [e[0].removeprefix("serving.") for e in parts]
+        assert names in PHASE_ORDERS
         for a, b in zip(parts, parts[1:]):
             assert a[2] <= b[1]  # in order, no overlap
         tick_ns += tick[2] - tick[1]
@@ -174,13 +241,16 @@ def test_admit_decode_harvest_partition_the_tick(traced):
 def test_arguments_equal_the_schedulers_own_state(traced, tiny):
     events, state, reqs = traced
     ticks = [e for e in events if e[0] == "serving.tick"]
+    assert len(ticks) == len(state)
+    by_tick = _programs_by_tick(events)
     for tick, (begin, delivered, first, retired) in zip(ticks, state):
         inside = _children(events, tick)
         # counted when admission is done: the chunks the tick ran and
-        # the programs they ran in
-        programs = [e[3]["chunks"] for e in inside
-                    if e[0] == "serving.prefill_chunk"]
-        assert tick[3] == {**begin, "chunks": sum(programs),
+        # the programs they ran in; the counts as the tick begins are
+        # the in-order scheduler's own state before its step
+        programs = by_tick.get(begin["tick"], [])
+        assert tick[3] == {**begin, "ahead": int(begin["tick"] > 1),
+                           "chunks": sum(programs),
                            "chunk_programs": len(programs)}
         harvest = [e for e in inside if e[0] == "serving.harvest"]
         decode = [e for e in inside if e[0] == "serving.decode"]
@@ -192,8 +262,8 @@ def test_arguments_equal_the_schedulers_own_state(traced, tiny):
         if harvest:
             # no request here retires at admission, so whoever got a
             # first token this tick decodes in it
-            assert decode[0][3] == {
-                "slots": begin["decoding"] + len(firsts)}
+            for d in decode:
+                assert d[3] == {"slots": begin["decoding"] + len(firsts)}
             assert harvest[0][3] == {"tokens": from_decode,
                                      "retired": len(retired)}
         else:
@@ -343,8 +413,8 @@ def test_recorder_spans_are_cut_at_the_same_boundaries(tiny):
     assert len(ticks) == sched.tick_count
     for _, _, t0, dur, _ in ticks:
         mine = [s for s in parts if t0 <= s[2] and s[2] + s[3] <= t0 + dur]
-        assert [s[1] for s in mine] in (
-            ["admit"], ["admit", "decode", "retire"])
+        assert [s[1].replace("retire", "harvest")
+                for s in mine] in PHASE_ORDERS
         for a, b in zip(mine, mine[1:]):
             assert a[2] + a[3] <= b[2]
 
