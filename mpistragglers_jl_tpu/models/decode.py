@@ -1188,35 +1188,46 @@ def init_ring_cache(
 
 def _ring_from_cache(cache_l: dict, Tp: int, W: int,
                      stride: int | None = None) -> dict:
-    """Gather a positional cache holding positions [0, Tp) into the ring
-    layout: slot ``s`` <- the latest prompt position congruent to ``s``
-    (mod W); slots no position has reached (Tp < W) stay zero — the
+    """A positional cache holding positions [0, Tp) in the ring layout:
+    slot ``s`` <- the latest prompt position congruent to ``s``
+    (mod W); slots no position has reached (Tp < W) are zero — the
     ``kpos >= 0`` read mask of :func:`_ring_cached_attention` already
     treats them as unwritten. Every cache leaf (int8 scales included)
-    shares the position axis, so one gather covers the layout; a
-    layer's pooled cells (``kp``, one every ``stride`` rows) are taken
-    as they lie."""
-    s = jnp.arange(W)
-    p = (Tp - 1) - jnp.mod((Tp - 1) - s, W)
-    valid = p >= 0
+    shares the position axis, so one rule covers the layout, and the
+    two shapes choose its form: a leaf no longer than the ring cannot
+    wrap it (``Tp <= a.shape[1] <= W``), so slot ``s`` IS position
+    ``s`` and the ring is the leaf as it lies, zeroed from ``Tp`` on
+    (the rows a padded last chunk wrote behind the prompt) and padded
+    to ``W``; only a leaf longer than the ring is gathered by
+    position. A layer's pooled cells (``kp``, one every ``stride``
+    rows) are taken as they lie either way."""
 
-    def gather(a):
+    def zero_behind(a, n, live):
+        # the first n of a's positions, those behind ``live`` zero
+        a = a[:, :n] if a.shape[1] >= n else jnp.pad(
+            a, [(0, 0), (0, n - a.shape[1])] + [(0, 0)] * (a.ndim - 2))
+        keep = jnp.arange(n) < live
+        return jnp.where(keep.reshape((1, n) + (1,) * (a.ndim - 2)), a, 0)
+
+    def rows(a):
+        if a.shape[1] <= W:
+            return zero_behind(a, W, Tp)
+        s = jnp.arange(W)
+        p = (Tp - 1) - jnp.mod((Tp - 1) - s, W)
         g = jnp.take(a, jnp.maximum(p, 0), axis=1)
-        return jnp.where(valid.reshape((1, W) + (1,) * (a.ndim - 2)), g, 0)
+        return jnp.where((p >= 0).reshape((1, W) + (1,) * (a.ndim - 2)),
+                         g, 0)
 
     def cells(a):
         # pooled cells lie on positions like the rows: a ring as wide
         # as the context budget never wraps, so cell c stays cell c,
         # and W rows have W / stride of them (those behind the prompt
         # zero)
-        c = jnp.arange(W // stride)
-        g = jnp.take(a, jnp.minimum(c, a.shape[1] - 1), axis=1)
-        live = c < -(-Tp // stride)
-        return jnp.where(live.reshape((1, -1) + (1,) * (a.ndim - 2)), g, 0)
+        return zero_behind(a, W // stride, -(-Tp // stride))
 
     # (a state-space mixer's state beside the rows is handed on as it is)
     return {kk: a if kk in STATE_LEAVES else cells(a) if kk == "kp"
-            else gather(a) for kk, a in cache_l.items()}
+            else rows(a) for kk, a in cache_l.items()}
 
 
 def ring_from_cache(cache, Tp: int, cfg: TransformerConfig) -> list[dict]:

@@ -101,8 +101,10 @@ Design (TPU-first):
   tests' sizes, and ``ServingScheduler``'s docstring says what that is
   worth on a real model). A request's prefill lands in a
   transient positional cache; on the last chunk the final-W window
-  gathers into ring rows (``ring_from_cache`` math with a traced
-  length), which placement scatters into the slot's pages, and the
+  becomes ring rows (``ring_from_cache`` math with a traced length:
+  the cache as it lies where ``max_prompt`` cannot wrap the ring, a
+  gather by position where it can), of which placement writes the
+  pages the prompt covers into the slot's pages, and the
   first token comes from the head applied to
   ONE row of the last chunk's hidden state, the prompt's last
   position: a chunk program stops at the last layer's output, so the
@@ -1219,31 +1221,80 @@ def _gather_ring_paged(cfg: TransformerConfig, P: int):
     return serving_gather_ring
 
 
+# Table entries one step of placement's loop writes: a scatter of that
+# many page blocks a leaf, so a chat prompt's 1 to 8 pages are one step
+# and a window-filling one W / P / 8 of them.
+_PLACE_STEP = 8
+
+
 @functools.lru_cache(maxsize=32)
 def _place_paged(cfg: TransformerConfig, P: int):
-    """Paged install: scatter the admitted request's W ring rows into
-    its pages (a page-block scatter through the page table) and set
-    the row state: first token, start position, key, ``done`` off.
-    Everything donated — admission is an in-place write. Shared prefix
-    rows write bytes IDENTICAL to what the pages already hold (the
-    seed op put those very bytes into the transient cache), so the
-    unconditional scatter never perturbs a sharer; rows past the
-    request's page budget land in the null page."""
+    """Paged install: write the pages the request's rows have reached
+    into the pool through its page-table row, and set the row state:
+    first token, start position, key, ``done`` off. ``ring`` holds the
+    request's ``W`` ring rows a layer; of them the page blocks ``0 ..
+    ceil(pos0 / P) - 1`` go to their pages (all ``W / P`` once ``pos0``
+    has passed ``W``: a wrapped ring, which only a migration places),
+    ``_PLACE_STEP`` table entries a step of one loop whose trip count
+    follows ``pos0``. A page behind them keeps what the pool held:
+    every reader bounds its rows by the slot's position, the mask that
+    is also the slot-reuse guard (:func:`_ring_attention_rows`), and the
+    tick writes a row before a position can reach it. The one leaf that
+    is ADDED to and not written, a selecting layer's pooled cells
+    (``kp``, :func:`_paged_pool_rows`), needs zeros behind the prompt
+    and is scattered whole, every table entry, those past the request's
+    page budget into the null page. Everything donated — admission is
+    an in-place write. Shared prefix pages get bytes IDENTICAL to what
+    they already hold (the seed op put those very bytes into the
+    transient cache), so the write never perturbs a sharer."""
 
     @functools.partial(jax.jit, donate_argnums=(0, 2, 3, 4))
     def serving_place_pages(caches, ring, tok, pos, done, keys, pt_row,
                             s, tok0, pos0, key):
-        # the ring's rows as whole pages, each to the pool page its
-        # cache width's table row names: a page-block scatter
         rows = _layer_tables(cfg, pt_row)
+        held = -(-pos0 // P)  # pages the rows have reached, of any width
+
+        def blocks(c, kk, x):  # ring rows x as the pool's page blocks
+            return _rows_to_pages(kk, x, P, cfg.sparse_stride,
+                                  c[kk].shape[-1]).astype(c[kk].dtype)
+
+        def step(i, caches):
+            # table entries [i * _PLACE_STEP, (i + 1) * _PLACE_STEP) of
+            # each width's row, as the slice that lies inside the row;
+            # an entry before the step's first or behind the last page
+            # held goes nowhere (an index past the pool is dropped)
+            out = []
+            for c, r, row in zip(caches, ring, rows):
+                paged = [kk for kk in c
+                         if kk not in STATE_LEAVES and kk != "kp"]
+                new = dict(c)
+                if paged:
+                    n = min(_PLACE_STEP, row.shape[0])
+                    at = jnp.minimum(i * _PLACE_STEP, row.shape[0] - n)
+                    j = at + jnp.arange(n)
+                    to = jnp.where(
+                        (j >= i * _PLACE_STEP) & (j < held),
+                        jax.lax.dynamic_slice_in_dim(row, at, n),
+                        c[paged[0]].shape[0])
+                for kk in paged:
+                    new[kk] = c[kk].at[to].set(blocks(
+                        c, kk, jax.lax.dynamic_slice_in_dim(
+                            r[kk][0], at * P, n * P)), mode="drop")
+                out.append(new)
+            return out
+
+        most = max((row.shape[0] for row in rows if row is not None),
+                   default=0)
+        caches = jax.lax.fori_loop(
+            0, -(-jnp.minimum(held, most) // _PLACE_STEP), step, caches)
         caches = [
             # a block of recurrent state goes over slot s's (a reused
-            # slot starts from the new prompt's state), rows into pages
+            # slot starts from the new prompt's state); pooled cells
+            # whole, zeros behind the prompt
             {kk: c[kk].at[s].set(r[kk][0].astype(c[kk].dtype))
-             if kk in STATE_LEAVES else c[kk].at[row].set(
-                _rows_to_pages(kk, r[kk][0], P, cfg.sparse_stride,
-                               c[kk].shape[-1]).astype(c[kk].dtype))
-             for kk in c}
+             if kk in STATE_LEAVES
+             else c[kk].at[row].set(blocks(c, kk, r[kk][0])) if kk == "kp"
+             else c[kk] for kk in c}
             for c, r, row in zip(caches, ring, rows)
         ]
         return (caches, tok.at[s].set(tok0), pos.at[s].set(pos0),
@@ -1488,8 +1539,11 @@ def _extend_chunk_group(cfg: TransformerConfig, C: int, Lmax: int, n: int):
 def _finish_admit_dense(cfg: TransformerConfig, Lmax: int,
                         temperature: float = 0.0,
                         top_k: int | None = None):
-    """Gather the last-W window of a filled positional cache into ring
-    rows + pick the first token: the head on row ``true_len - 1 -
+    """The last-W window of a filled positional cache as ring rows
+    (:func:`~.decode._ring_from_cache`, a layer at a time: where
+    ``Lmax <= W`` no prompt wraps the ring and the arena's rows are the
+    ring's, zeroed behind the prompt; a narrower ring is gathered by
+    position) + pick the first token: the head on row ``true_len - 1 -
     last_off`` of the last chunk's hidden state, the prompt's last
     position (greedy, or sampled with the request's key there —
     decode.py's fold discipline), so the head's weights are read once a
@@ -2349,6 +2403,12 @@ class ServingScheduler:
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
+        # ``serving.first_token``'s ``ring_gathers``: the row layers
+        # whose ring an arena of ``max_prompt`` rows can wrap, which
+        # ``decode._ring_from_cache`` gathers by position (the others it
+        # takes as they lie)
+        self._ring_gathers = sum(
+            w is not None and self.Lmax > w for w in _row_widths(cfg))
         # instruments resolved once here; None = dark (no tick cost)
         self._obs = (
             _ServingObs(self, registry, spans)
@@ -4040,14 +4100,22 @@ class ServingScheduler:
         token, its window placed into the slot, the arena released."""
         st = self._admitting[s]
         rid = st.req.id
+        Tp = st.req.prompt.size
         with _annotate("serving.first_token", req=rid, slot=s,
+                       # what the hand-over costs: the pages placement
+                       # writes, over the cache widths (a prompt's own,
+                       # ``_place_paged``), and the layers whose ring
+                       # is still gathered row by row
+                       pages_placed=sum(
+                           min(-(-Tp // self.P), kd.max_pages)
+                           for kd in self._kinds),
+                       ring_gathers=self._ring_gathers,
                        # the slot's recurrent state is written over by
                        # this request's: a reset, counted with the
                        # admission that makes it
                        **({"state_reset": 1} if self.cfg.state_layers
                           else {})):
             self.state_resets += bool(self.cfg.state_layers)
-            Tp = st.req.prompt.size
             rkey = (st.req.key if st.req.key is not None
                     else jax.random.key(st.req.id + 1))
             tok0, ring = self._finish(

@@ -78,10 +78,14 @@ def _same_bytes(a, b) -> None:
 
 
 def _resident_bytes(sched, slot) -> list[np.ndarray]:
-    """What the cache holds for the request in ``slot``: its pages in
-    the order of its page table."""
-    return [sched._page_payload(int(pid)) for pid in sched._pt_host[slot]
-            if pid != serving.NULL_PAGE]
+    """What the cache holds for the request in ``slot``: the rows its
+    position has reached, read out of its pages leaf by leaf. (A page
+    behind them keeps what the pool held, since placement writes the
+    pages a prompt covers and no other; no reader passes the position:
+    tests/test_admit_handover.py.)"""
+    ring = sched._gather(sched._caches, jnp.asarray(sched._pt_host[slot]))
+    return [np.asarray(a[:, :sched._host_pos[slot]])
+            for a in jax.tree.leaves(ring)]
 
 
 def _slot_of(sched, req) -> int:

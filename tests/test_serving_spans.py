@@ -312,6 +312,90 @@ def test_the_spans_of_one_request_share_its_id(traced):
         assert {a["of"] for a in mine if "of" in a} == {chunks}
 
 
+def test_first_token_says_what_the_hand_over_costs(traced):
+    """``pages_placed``: the pages placement writes, which are the
+    prompt's own (rings of 8 rows in pages of 4: two for 5 and for 11
+    tokens, the second a wrapped ring, one for 3), never more than the
+    slot's table row names; ``ring_gathers``: both layers, since an
+    arena of 32 rows can wrap a ring of 8."""
+    events, _, reqs = traced
+    by_id = {r.id: r for r in reqs}
+    firsts = [e for e in events if e[0] == "serving.first_token"]
+    assert sorted(e[3]["req"] for e in firsts) == sorted(by_id)
+    for e in firsts:
+        r = by_id[e[3]["req"]]
+        assert e[3]["pages_placed"] == min(-(-r.prompt.size // 4), 8 // 4)
+        assert e[3]["ring_gathers"] == 2
+    assert [e[3]["pages_placed"] for e in sorted(
+        firsts, key=lambda e: e[3]["req"])] == [2, 2, 1]
+
+
+HANDOVERS = {
+    # rings no arena of 32 rows can wrap: nothing gathered
+    "no_wrap": (dict(attn_window=32), 0),
+    # two widths: rings of 8 rows (gathered) beside a budget of 64
+    "two_widths": (dict(n_layers=3, layer_windows=(8, 8, None),
+                        max_context=64), 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HANDOVERS))
+def test_the_hand_overs_counts_follow_the_page_tables(name, monkeypatch):
+    """Each width's share of ``pages_placed`` is the entries of its
+    table row that the prompt's rows reach, which the slot's row holds
+    as pages of its own when the span closes."""
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.models.paging import NULL_PAGE
+    from mpistragglers_jl_tpu.models.transformer import (
+        TransformerConfig,
+        init_params,
+    )
+
+    kw, gathers = HANDOVERS[name]
+    cfg = TransformerConfig(**{**dict(
+        vocab=37, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2,
+        d_ff=64), **kw})
+    sched = _sched(cfg, init_params(cfg, seed=3))
+    seen = []
+
+    class Spy:
+        def __init__(self, name, **args):
+            self.name, self.args = name, dict(args)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            if self.name == "serving.first_token":
+                rows = [kd.pt_host[self.args["slot"]] for kd in sched._kinds]
+                seen.append((self.args, [
+                    int((row != NULL_PAGE).sum()) for row in rows]))
+
+        def set_metadata(self, **args):
+            self.args.update(args)
+
+    monkeypatch.setattr(serving, "_annotate", Spy)
+    reqs = {r.id: r for r in _submit_work(sched, cfg.vocab)}
+    sched.run()
+    assert len(seen) == len(reqs)
+    for args, held in seen:
+        r = reqs[args["req"]]
+        reached = [min(-(-r.prompt.size // 4), kd.max_pages)
+                   for kd in sched._kinds]
+        assert args["pages_placed"] == sum(reached)
+        assert all(a <= b for a, b in zip(reached, held))
+        assert args["ring_gathers"] == gathers
+
+
+def test_the_phase_table_names_the_hand_overs_counts():
+    doc = os.path.join(os.path.dirname(__file__), "..", "docs", "API.md")
+    with open(doc) as f:
+        row = [ln for ln in f if ln.startswith("| `serving.first_token` |")]
+    assert len(row) == 1
+    arguments = row[0].split("|")[3]
+    assert "`pages_placed`" in arguments and "`ring_gathers`" in arguments
+
+
 def test_admit_new_says_where_its_arena_came_from(traced):
     """The scheduler's first admission finds the free list empty; the
     second runs in the same tick, after the first prompt's one chunk
