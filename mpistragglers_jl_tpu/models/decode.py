@@ -856,6 +856,7 @@ def _incremental_hidden(params, tokens, cache, offset, cfg,
     if prefill:
         chunk_attn = partial(
             resolve_attention_impl(cfg.attn_impl), causal=True,
+            scale=cfg.softmax_scale,
         )
     x = embed(params, tokens, cfg)
     new_cache = []
@@ -1110,7 +1111,8 @@ def ring_widths(cfg: TransformerConfig) -> tuple[int, ...]:
         raise ValueError(
             "the ring cache is rows of K/V, a position each; this "
             "configuration has gated delta-rule layers (or decayed "
-            "linear attention), whose state is one fixed block a "
+            "linear attention, or a state-space mixer beside attention "
+            "or alone), whose state is one fixed block a "
             "request and has no width. ServingScheduler serves it"
         )
     if cfg.sparse_layers:

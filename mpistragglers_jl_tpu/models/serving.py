@@ -1348,8 +1348,9 @@ def _refuse_state_layers(cfg: TransformerConfig, what: str,
     if cfg.state_layers:
         raise ValueError(
             f"{what}: this configuration has gated delta-rule layers "
-            f"(or decayed linear attention, or a state-space mixer), "
-            f"whose per-request state is one fixed block and no row a "
+            f"(or decayed linear attention, or a state-space mixer "
+            f"beside attention or alone in its layer), whose "
+            f"per-request state is one fixed block and no row a "
             f"token; {why}"
         )
 
@@ -2390,7 +2391,7 @@ class ServingScheduler:
             **({"la_rule": la_rule_route(cfg, self.C)}
                if "la" in mixers else {}),
             **({"ssm_rule": ssm_rule_route(cfg, self.C)}
-               if "attn_ssm" in mixers else {}),
+               if cfg.ssm_layers else {}),
         }
         # ``serving.decode``'s ``gdn_rule``: the form ONE token takes
         # in a step of the tick, from the same function
@@ -2398,8 +2399,17 @@ class ServingScheduler:
             **({"gdn_rule": gdn_rule_route(cfg, 1)}
                if "gdn" in mixers else {}),
             **({"ssm_rule": ssm_rule_route(cfg, 1)}
-               if "attn_ssm" in mixers else {}),
+               if cfg.ssm_layers else {}),
         }
+        # beside ``ssm_rule`` on both spans: how many of the model's
+        # layers keep a state and how many keep rows (a layer with the
+        # mixer beside its attention counts in both, one with the mixer
+        # alone in the first)
+        layers = range(cfg.n_layers)
+        self._layer_kinds = {
+            "state_layers": sum(map(cfg.state, layers)),
+            "row_layers": sum(map(cfg.rows, layers)),
+        } if cfg.ssm_layers else {}
         self._finish = _finish_admit_dense(
             cfg, self.Lmax, self.temperature, top_k
         )
@@ -2661,6 +2671,25 @@ class ServingScheduler:
         where full-attention layers stand beside them."""
         return {kd.name: kd.pool for kd in self._kinds}
 
+    def state_of(self, req: Request) -> tuple[int, list[dict | None]]:
+        """What the recurrent layers hold for a decoding request
+        between two ticks: ``(rows, layers)``, the number of rows of
+        (prompt + tokens) the state stands behind (every token but the
+        last delivered, which is sampled and not yet fed; the prompt
+        alone where the first token is still on the device) and, layer
+        by layer, the slot's part of each state leaf
+        (``decode.STATE_LEAVES``) as the cache keeps it (``S`` in
+        ``ops.ssm_step.ssm_state_shape``'s layout), None for a layer
+        that keeps none. A request that holds no slot, or whose prompt
+        is still being admitted, is refused."""
+        s = next((s for s, r in enumerate(self._slot_req) if r is req), None)
+        if s is None or s in self._admitting:
+            raise ValueError("state_of: the request is not decoding: it "
+                             "holds no slot or is still being admitted")
+        return self._host_pos[s], [
+            {kk: a[s] for kk, a in cl.items() if kk in STATE_LEAVES} or None
+            for cl in self._caches]
+
     def _device_pt(self):
         """The device page tables, one per cache width, refreshed from
         the host-authoritative copies when admission/COW/retirement
@@ -2869,7 +2898,8 @@ class ServingScheduler:
                 # request before this one's tokens are back
                 live = [(s, self._slot_req[s]) for s in decoding]
                 ending = self._counted_ends(live)
-                decode_args = dict(slots=len(decoding), **self._step_route)
+                decode_args = dict(slots=len(decoding), **self._step_route,
+                                   **self._layer_kinds)
                 with phase("serving.decode", **decode_args) as decode:
                     # COW pass: every page the next n_inner writes
                     # touch must be exclusively owned BEFORE the
@@ -4040,6 +4070,7 @@ class ServingScheduler:
                                            self.cfg.windows)
                           for off in offs),
             **self._expert_tile.get(size, {}), **self._rule_routes,
+            **self._layer_kinds,
             **self._sparse_chunk_counts(sts, offs),
         ):
             # host arrays and numpy scalars go to the device with the

@@ -57,7 +57,12 @@ from ..ops.delta_rule import (
     delta_rule_viable,
     delta_step_viable,
 )
-from ..ops.ssm_step import ssm_step, ssm_step_viable
+from ..ops.ssm_step import (
+    lane_pack,
+    ssm_state_shape,
+    ssm_step,
+    ssm_step_viable,
+)
 from ..parallel.ring_attention import (
     _flash_interpreted,
     resolve_attention_impl,
@@ -269,6 +274,9 @@ class TransformerConfig:
     # B, C shared by the heads of one of ``ssm_groups`` groups, behind a
     # causal depthwise conv of ``ssm_conv`` taps; ``ssm_chunk`` rows a
     # sub-chunk of the chunked form. The layer keeps K/V rows AND state.
+    # The same mixer ALONE in a layer is the value "ssm" (Granite-4.0-H's
+    # mamba layers): the layer keeps its state and no row a position, and
+    # the mixer's result joins the residual itself (:func:`state_half`).
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -343,13 +351,13 @@ class TransformerConfig:
                 f"head_dim {self.head_dim}"
             )
         if self.layer_mixers is not None:
-            if any(m not in ("attn", "gdn", "mla", "la", "attn_ssm")
+            if any(m not in ("attn", "gdn", "mla", "la", "attn_ssm", "ssm")
                    for m in self.layer_mixers):
                 raise ValueError(
                     f"layer_mixers holds 'attn' or 'gdn' or 'mla' or "
-                    f"'la' or 'attn_ssm', got {self.layer_mixers}"
+                    f"'la' or 'attn_ssm' or 'ssm', got {self.layer_mixers}"
                 )
-            if "attn_ssm" in self.layer_mixers and (
+            if self.ssm_layers and (
                 self.ssm_heads < 1 or self.ssm_groups < 1
                 or self.ssm_heads % self.ssm_groups or self.ssm_conv < 1
                 or self.ssm_chunk < 1 or len(self.ssm_scales) != 5
@@ -508,8 +516,9 @@ class TransformerConfig:
         return self.rope_full or self.windows[li] is not None
 
     def mixer(self, li: int) -> str:
-        """Layer ``li``'s token mixer: "attn", "gdn", "mla", "la" or
-        "attn_ssm" (attention and a state-space mixer side by side)."""
+        """Layer ``li``'s token mixer: "attn", "gdn", "mla", "la",
+        "attn_ssm" (attention and a state-space mixer side by side) or
+        "ssm" (a state-space mixer alone)."""
         return ("attn" if self.layer_mixers is None
                 else self.layer_mixers[self._like(li)])
 
@@ -520,19 +529,35 @@ class TransformerConfig:
     def state(self, li: int) -> bool:
         """Does layer ``li`` keep recurrent state, one fixed block a
         request (the gated delta rule, decayed linear attention, a
-        state-space mixer)?"""
-        return self.mixer(li) in ("gdn", "la", "attn_ssm")
+        state-space mixer, beside attention or alone)?"""
+        return self.mixer(li) in ("gdn", "la", "attn_ssm", "ssm")
 
     def rows(self, li: int) -> bool:
         """Does layer ``li`` keep a row a position in its cache? Every
         attention does, also the one that stands beside a state-space
-        mixer: that layer's cache is rows AND a state."""
+        mixer: that layer's cache is rows AND a state. A state-space
+        mixer alone in its layer keeps a state and NO row."""
         return self.mixer(li) in ("attn", "mla", "attn_ssm")
 
     def ssm(self, li: int) -> bool:
-        """Does layer ``li`` hold a state-space mixer beside its
-        attention?"""
+        """Does layer ``li`` hold a state-space mixer BESIDE its
+        attention (rows AND state; the mixer's result joins the
+        attention's)? False for the mixer alone in a layer, which is a
+        state layer like the others (:meth:`state` and not
+        :meth:`rows`); :meth:`ssm_mixer` is true for both."""
         return self.mixer(li) == "attn_ssm"
+
+    def ssm_mixer(self, li: int) -> bool:
+        """Is a state-space mixer among layer ``li``'s token mixers,
+        beside attention or alone? (Whose leaves and state the layer
+        has; who joins its result to the residual is :meth:`ssm`'s
+        question.)"""
+        return self.mixer(li) in ("attn_ssm", "ssm")
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers that hold a state-space mixer."""
+        return sum(self.ssm_mixer(li) for li in range(self.n_layers))
 
     def sparse(self, li: int) -> bool:
         """Does layer ``li`` attend a selection of its key blocks?"""
@@ -661,6 +686,9 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         elif cfg.mla(li):
             layer = {**norm("ln1"), **init_mla_layer(rng, cfg),
                      **norm("ln2")}
+        elif cfg.ssm_mixer(li) and not cfg.rows(li):  # the mixer alone
+            layer = {**norm("ln1"), **init_ssm_layer(rng, cfg),
+                     **norm("ln2")}
         else:
             layer = {
                 **norm("ln1"),
@@ -745,9 +773,9 @@ def require_plain_block(cfg: TransformerConfig, what: str) -> None:
         why.append("gated delta-rule layers (recurrent state)")
     if "la" in (cfg.layer_mixers or ()):
         why.append("decayed linear-attention layers (recurrent state)")
-    if "attn_ssm" in (cfg.layer_mixers or ()):
-        why.append("layers that hold a state-space mixer beside their "
-                   "attention (recurrent state)")
+    if cfg.ssm_layers:
+        why.append("layers that hold a state-space mixer, beside their "
+                   "attention or alone (recurrent state)")
     if cfg.sparse_block:
         why.append("attention layers that read a selection of their "
                    "key blocks")
@@ -1461,8 +1489,8 @@ def la_zero_state(cfg: TransformerConfig, B: int) -> dict:
 def zero_state(cfg: TransformerConfig, li: int, B: int) -> dict:
     """Layer ``li``'s recurrent state for ``B`` requests that have seen
     no token (``cfg.state(li)``: the delta rule's, linear attention's
-    or a state-space mixer's)."""
-    if cfg.ssm(li):
+    or a state-space mixer's, beside attention or alone)."""
+    if cfg.ssm_mixer(li):
         return ssm_zero_state(cfg, B)
     return (gdn_zero_state(cfg, B) if cfg.gdn(li)
             else la_zero_state(cfg, B))
@@ -1566,22 +1594,33 @@ def la_half(x, lp, state, cfg, rope, valid=None, mix=None):
 
 
 def state_half(x, lp, state, cfg, li, rope, valid=None, mix=None):
-    """Layer ``li``'s recurrent half (``cfg.state(li)``), whichever it
-    is: ``(x, state)``. ``rope`` is read by linear attention alone."""
+    """Layer ``li``'s recurrent half (``cfg.state(li)`` and no rows),
+    whichever it is: ``(x, state)``. ``rope`` is read by linear
+    attention alone. A state-space mixer alone in its layer
+    (``layer_mixers`` value "ssm") is :func:`ssm_half`'s body, the one
+    the mixer beside an attention runs, joined to the residual here as
+    every half's result is."""
     if cfg.gdn(li):
         return gdn_half(x, lp, state, cfg, valid, mix=mix)
+    if cfg.ssm_mixer(li):
+        a, state = ssm_half(x, lp, state, cfg, valid)
+        return hc_post(x, _res(a, cfg), mix), state
     return la_half(x, lp, state, cfg, rope, valid, mix=mix)
 
 
-# A state-space mixer beside attention (``layer_mixers`` value
-# "attn_ssm"), written once like the other recurrences: the dense
-# forward (the whole sequence from a zero state), a prefill chunk and a
-# decode step all call :func:`ssm_half` on the layer's input and hand
-# what it returns to :func:`attn_merge` as ``beside``. A layer's state
-# is ``S`` (heads, state dim, head dim) float32, the state dim down the
-# rows so that a head's x, decay and result lie along the lanes and the
-# group's B and C scale whole rows (ops/ssm_step.py), and the last
-# ``ssm_conv - 1`` rows that went into the depthwise conv.
+# A state-space mixer, beside attention (``layer_mixers`` value
+# "attn_ssm") or alone in its layer ("ssm"), written once like the
+# other recurrences: the dense forward (the whole sequence from a zero
+# state), a prefill chunk and a decode step all call :func:`ssm_half`
+# on the layer's input; beside attention they hand what it returns to
+# :func:`attn_merge` as ``beside``, alone :func:`state_half` joins it to
+# the residual. A layer's state is ``S`` (heads, state dim, head dim)
+# float32, the state dim down the rows so that a head's x, decay and
+# result lie along the lanes and the group's B and C scale whole rows
+# (ops/ssm_step.py), and the last ``ssm_conv - 1`` rows that went into
+# the depthwise conv. Where the step kernel takes a head narrower than
+# a lane tile, k heads share one: ``S`` is kept (heads / k, state dim,
+# k head dims) (:func:`ssm_state_heads` gives the heads back).
 
 
 def ssm_widths(cfg: TransformerConfig) -> tuple[int, int, int]:
@@ -1620,14 +1659,43 @@ def init_ssm_layer(rng: np.random.Generator, cfg: TransformerConfig) -> dict:
     }
 
 
+def _ssm_shape(cfg: TransformerConfig) -> tuple[int, int, int, int]:
+    return cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_head_dim
+
+
 def ssm_zero_state(cfg: TransformerConfig, B: int) -> dict:
-    """The state of ``B`` requests that have seen no token."""
+    """The state of ``B`` requests that have seen no token; ``S`` in
+    the layout the step kernel keeps (``ssm_step.ssm_state_shape``:
+    (heads, state dim, head dim), or k heads a lane tile)."""
     wide, gn, _ = ssm_widths(cfg)
     return {
-        "S": jnp.zeros((B, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
+        "S": jnp.zeros((B,) + ssm_state_shape(*_ssm_shape(cfg)),
                        jnp.float32),
         "conv": jnp.zeros((B, cfg.ssm_conv - 1, wide + 2 * gn), cfg.dtype),
     }
+
+
+def ssm_state_heads(S, cfg: TransformerConfig):
+    """A kept ``S`` (B, heads / k, N, k P) as (B, heads, N, P), a head
+    its own block: what the plain forms work on. ``S`` itself where no
+    heads share a lane tile (k = 1)."""
+    k = lane_pack(*_ssm_shape(cfg))
+    if k == 1:
+        return S
+    B, Hk, N, _ = S.shape
+    return S.reshape(B, Hk, N, k, cfg.ssm_head_dim).swapaxes(2, 3).reshape(
+        B, Hk * k, N, cfg.ssm_head_dim)
+
+
+def ssm_state_kept(S, cfg: TransformerConfig):
+    """:func:`ssm_state_heads`'s inverse: (B, heads, N, P) as the
+    cache keeps it."""
+    k = lane_pack(*_ssm_shape(cfg))
+    if k == 1:
+        return S
+    B, H, N, P = S.shape
+    return S.reshape(B, H // k, k, N, P).swapaxes(2, 3).reshape(
+        B, H // k, N, k * P)
 
 
 def _ssm_step(x, Bm, Cm, dA, dt, S):
@@ -1695,22 +1763,22 @@ def _ssm_chunks(x, Bm, Cm, dA, dt, S, c: int):
 def ssm_rule_route(cfg: TransformerConfig, T: int) -> str:
     """The form the recurrence takes over a call of T rows, from what
     the shapes say: for one token ``"kernel"`` (ops/ssm_step.py, which
-    updates S where it lies, at a head size of whole lane tiles: the
-    published widths) or ``"xla"`` (:func:`_ssm_step`); over T > 1 rows
+    updates S where it lies, at a head size of whole lane tiles or one
+    that divides a tile: the published widths) or ``"xla"``
+    (:func:`_ssm_step`); over T > 1 rows
     ``"xla"`` (:func:`_ssm_chunks`: products the compiler schedules;
     there is no kernel). :func:`ssm_half` asks it, and the serving
     scheduler for the ``ssm_rule`` of its spans."""
-    if T == 1 and ssm_step_viable(cfg.ssm_heads, cfg.ssm_groups,
-                                  cfg.ssm_state, cfg.ssm_head_dim):
+    if T == 1 and ssm_step_viable(*_ssm_shape(cfg)):
         return "kernel"
     return "xla"
 
 
 def ssm_half(x, lp, state, cfg, valid=None):
-    """The state-space mixer of a layer that holds one beside its
-    attention, on the layer's input (B, T, D) from ``state``
-    (:func:`ssm_zero_state`'s leaves): the layer's norm (the one the
-    attention reads), ``ssm_in_scale``, the in-projection times
+    """The state-space mixer of a layer that holds one, beside its
+    attention or alone, on the layer's input (B, T, D) from ``state``
+    (:func:`ssm_zero_state`'s leaves): the layer's norm (the one an
+    attention beside it reads), ``ssm_in_scale``, the in-projection times
     ``ssm_scales`` over its spans ``[z | x | B | C | dt]``, the causal
     depthwise conv with its bias and silu over ``[x | B | C]``, the
     recurrence in float32 (``dt = softplus(dt + dt_bias)``, ``a =
@@ -1718,8 +1786,9 @@ def ssm_half(x, lp, state, cfg, valid=None):
     the gate ``y * silu(z)`` and THEN an RMSNorm over each group's
     share of the joined heads, the out-projection, ``ssm_out_scale``.
     Returns ``(s, state)``: ``s`` (B, T, D) is NOT joined to the
-    residual; :func:`attn_merge` takes it as ``beside``. Of ``valid``
-    see :func:`gdn_half`."""
+    residual; :func:`attn_merge` takes it as ``beside``, and
+    :func:`state_half` joins the lone mixer's. Of ``valid`` see
+    :func:`gdn_half`."""
     B, T, _ = x.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                   cfg.ssm_groups)
@@ -1754,8 +1823,11 @@ def ssm_half(x, lp, state, cfg, valid=None):
                         state["S"])
             o = o[:, None]
         else:
-            o, S = _ssm_chunks(xs, Bm, Cm, dA, dt, state["S"],
+            # the plain form takes a head's S as a block of its own
+            o, S = _ssm_chunks(xs, Bm, Cm, dA, dt,
+                               ssm_state_heads(state["S"], cfg),
                                cfg.ssm_chunk)
+            S = ssm_state_kept(S, cfg)
         o = o + lp["ssm_D"][:, None] * xs
     with jax.named_scope("ssm_out"):
         o = o.reshape(B, T, wide) * jax.nn.silu(z.astype(jnp.float32))
@@ -2042,7 +2114,8 @@ def _mixer_dense(x, lp, cfg, li: int, rope, impl):
     if cfg.sparse(li):
         o = sparse_attention_dense(q, k, v, cfg)
     else:
-        o = impl(q, k, v, causal=True, window=cfg.windows[li])
+        o = impl(q, k, v, causal=True, window=cfg.windows[li],
+                 scale=cfg.softmax_scale)
     return attn_merge(x, o, gate, lp, cfg, mix=mix, beside=beside)
 
 

@@ -32,6 +32,19 @@ compiler's verifier; every caller has an operation behind it.
   order of the N-term sum differs from the plain step's
   (``transformer._ssm_step``). A row with ``a`` = 1 and ``dt`` = 0
   leaves S bit for bit.
+* a head NARROWER than a lane tile (Granite-4.0-H's 128 heads of 64 at
+  a state dim of 128) shares its tile: ``k = 128 / P`` neighbouring
+  heads of one group lie side by side along the lanes, S kept (slots,
+  heads / k, N, k P) (:func:`ssm_state_shape`; ``lane_pack`` says k).
+  ``dt x``, ``a`` and ``y`` are rows a head, so k heads' rows ARE one
+  row of 128 lanes as (slots, heads, P) arrays lie in memory, and B
+  and C are the group's: the kernel is the same kernel on ``heads / k``
+  heads of ``k P``, each lane decaying by its own head's ``a``. The
+  other form weighed (S as published, (heads, P, N), the state dim
+  along the lanes, B and C used as the rows they arrive as) needs ``dt
+  x`` down the sublanes and hands ``y`` back as a column a head: two
+  turns a head through the diagonal trick where this form has none,
+  and a second kernel body beside the first.
 
 ``ssm_step_viable`` is the route's test (``ssm_half`` asks it through
 ``transformer.ssm_rule_route``). Inference-only: no VJP. Off the TPU the
@@ -54,7 +67,27 @@ _LANE = 128
 # two buffers in and two out are 8 MiB of the 16 Mosaic grants unasked)
 _STEP_BLOCK = 2 * 2 ** 20
 
-__all__ = ["ssm_step", "ssm_step_viable"]
+__all__ = ["lane_pack", "ssm_state_shape", "ssm_step", "ssm_step_viable"]
+
+
+def lane_pack(H: int, G: int, N: int, P: int) -> int:
+    """Heads that share a lane tile in the state the kernel keeps: 1 at
+    a head size of whole lane tiles (or where the kernel does not take
+    the shape at all, :func:`ssm_step_viable`), ``128 / P`` for a head
+    that divides a tile."""
+    return _pack(P) if ssm_step_viable(H, G, N, P) else 1
+
+
+def ssm_state_shape(H: int, G: int, N: int, P: int) -> tuple[int, int, int]:
+    """One request's ``S`` as the step kernel wants it kept: ``(heads /
+    k, N, k P)`` with ``k = lane_pack(...)``; packed head j's lanes
+    ``[i P, (i + 1) P)`` are head ``j k + i``'s."""
+    k = lane_pack(H, G, N, P)
+    return H // k, N, k * P
+
+
+def _pack(P: int) -> int:
+    return _LANE // P if 0 < P < _LANE and _LANE % P == 0 else 1
 
 
 def _heads_per_step(H: int, G: int, N: int, P: int) -> int:
@@ -70,11 +103,14 @@ def _heads_per_step(H: int, G: int, N: int, P: int) -> int:
 
 def ssm_step_viable(H: int, G: int, N: int, P: int) -> bool:
     """Whether the kernel takes these heads: the head size is whole
-    lane tiles, the state dim whole sublane tiles, a group serves a
-    whole number of heads, and some number of heads a grid step is
-    legal (:func:`_heads_per_step`)."""
-    return (H > 0 and G > 0 and P % _LANE == 0 and N % 8 == 0
-            and H % G == 0 and _heads_per_step(H, G, N, P) > 0)
+    lane tiles, or divides one so that ``k`` heads fill it; the state
+    dim whole sublane tiles; a group serves a whole number of (packs
+    of k) heads; and some number of them a grid step is legal
+    (:func:`_heads_per_step`)."""
+    k = _pack(P)
+    return (H > 0 and G > 0 and (k * P) % _LANE == 0 and N % 8 == 0
+            and H % (G * k) == 0
+            and _heads_per_step(H // k, G, N, k * P) > 0)
 
 
 def _kernel(u_ref, a_ref, b_ref, c_ref, s_ref, y_ref, so_ref):
@@ -97,17 +133,24 @@ def ssm_step(x, Bm, Cm, dA, dt, S, *, interpret: bool | None = None):
     ``S = exp(dA) S + B (dt x)^T``, ``y = S^T C``, the arithmetic of
     ``transformer._ssm_step`` in float32 on the VPU. x (B, H, P); Bm, Cm
     (B, G, N), a GROUP each; dA (the log decay) and dt (B, H); S (B, H,
-    N, P), all float32. Returns ``(y, S)``: y (B, H, P), without the
-    skip ``D x``."""
+    N, P), all float32; at a head narrower than a lane tile S is
+    ``(B,) + ssm_state_shape(...)``, k heads a tile. Returns ``(y,
+    S)``: y (B, H, P), without the skip ``D x``; S as it came."""
     if interpret is None:
         interpret = _use_interpret()
-    (_, H, P), (_, G, N) = x.shape, Bm.shape
+    (B, H, P), (_, G, N) = x.shape, Bm.shape
     if not ssm_step_viable(H, G, N, P):
         raise ValueError(
             f"{H} heads of {N} x {P} in {G} groups are not the "
             "single-token kernel's; use the plain step")
-    return ssm_step_call(x * dt[..., None], jnp.exp(dA), Bm, Cm, S,
-                         hb=_heads_per_step(H, G, N, P), interpret=interpret)
+    k = _pack(P)
+    u = x * dt[..., None]
+    if k > 1:  # k heads' rows are one row of a whole lane tile
+        u = u.reshape(B, H // k, k * P)
+    y, S = ssm_step_call(u, jnp.exp(dA), Bm, Cm, S,
+                         hb=_heads_per_step(H // k, G, N, k * P),
+                         interpret=interpret)
+    return (y.reshape(B, H, P) if k > 1 else y), S
 
 
 @functools.partial(jax.jit, static_argnames=("hb", "interpret"))
@@ -115,8 +158,10 @@ def ssm_step_call(u, a, Bm, Cm, S, *, hb: int, interpret: bool):
     """The pallas_call, ``hb`` heads of one member and group a grid
     step: each head's S read from HBM once and written once to the
     buffer it came from. ``u = dt x`` (B, H, P), ``a`` the decay (B,
-    H). Jitted, so that the layers of a program share ONE traced and
-    lowered kernel; a device trace shows the kernel as ``ssm_step``."""
+    H), or with k heads a lane tile u (B, H / k, k P) beside ``a`` (B,
+    H): a lane takes its own head's decay. Jitted, so that the layers
+    of a program share ONE traced and lowered kernel; a device trace
+    shows the kernel as ``ssm_step``."""
     (B, H, P), (_, G, N) = u.shape, Bm.shape
     per = H // G // hb  # grid steps a group
     headed = pl.BlockSpec((1, hb, P), lambda b, h: (b, h, 0))
@@ -134,5 +179,6 @@ def ssm_step_call(u, a, Bm, Cm, S, *, hb: int, interpret: bool):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="ssm_step",
-    )(u, jnp.broadcast_to(a[..., None], (B, H, P)), Bm[:, :, None],
+    )(u, jnp.broadcast_to(a[..., None], a.shape + (H * P // a.shape[1],)
+                          ).reshape(B, H, P), Bm[:, :, None],
       Cm[:, :, None], S)

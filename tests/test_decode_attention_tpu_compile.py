@@ -535,3 +535,100 @@ def test_falcon_h1_tick_leaves_states_and_pools_where_they_lie(one_chip):
         moved = [line for line in text.splitlines() if held in _result_of(
             line, "copy", "copy-start", "slice-start")]
         assert not moved, moved[:3]
+
+
+def test_ssm_step_kernel_takes_a_head_of_half_a_lane_tile(one_chip):
+    """The same kernel for 16 slots of 128 heads of 128 x 64 in ONE
+    group (Granite-4.0-H Small's), two heads a lane tile: the state is
+    kept ``f32[16,64,128,128]`` and the kernel runs on 64 heads of 128
+    (32 a grid step, 2 MiB of S), under a scan that carries nine
+    layers' states as the tick does. Mosaic takes it, and the compiled
+    program moves no layer's state: the kernel's operand is the scan's
+    carry itself."""
+    from mpistragglers_jl_tpu.ops.ssm_step import ssm_state_shape, ssm_step
+
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    B, H, G, N, P = 16, 128, 1, 128, 64
+    kept = (B,) + ssm_state_shape(H, G, N, P)
+    assert kept == (16, 64, 128, 128)
+    step = functools.partial(ssm_step, interpret=False)
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def steps(states, x, Bm, Cm, dA, dt):
+        def body(carry, _):
+            states, y = carry
+            out = []
+            for S in states:
+                y, S = step(x + y, Bm, Cm, dA, dt, S)
+                out.append(S)
+            return (out, y), None
+        return jax.lax.scan(body, (states, jnp.zeros_like(x)), None,
+                            length=8)[0]
+
+    with jax.enable_x64(False):
+        text = steps.lower(
+            [f32(*kept)] * 9, f32(B, H, P), f32(B, G, N), f32(B, G, N),
+            f32(B, H), f32(B, H)).compile().as_text()
+    state = "f32[16,64,128,128]"
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and state in line]
+    assert len(calls) == 9
+    for line in calls:  # the operand comes straight out of the carry
+        assert "get-tuple-element" in line.split("custom-call(")[1]
+    moved = [line for line in text.splitlines() if _result_of(
+        line, "copy", "copy-start", "slice-start", "fusion").count(state)]
+    assert not moved, moved[:3]
+
+
+def test_granite4h_tick_leaves_states_and_pool_where_they_lie(one_chip):
+    """The whole decode tick of ``serve_granite4h_chat`` (the cell's
+    own configuration file: 16 slots, 8 steps, nine layers that each
+    hold a state and NO page, one that holds pages and no state, 36
+    held experts behind each), compiled as the scheduler would run it
+    on the kernel route: the step kernel nine times, the paged
+    attention kernel once at a group of 4 query heads to a K/V head,
+    three grouped products a layer; the scan's body moves neither a
+    layer's states (67 MB) nor the page pool through the compiler's
+    fast memory space."""
+    import json
+    import pathlib
+
+    from chipbench.runners import serve_ssm_moe
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.ops import (
+        decode_attention,
+        flash_attention,
+        ssm_step,
+    )
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    config = json.loads((root / "chipbench" / "configs"
+                         / "granite-4.0-h-small-serve.json").read_text())
+    cfg, program = serve_ssm_moe.transformer_config(config), config["program"]
+    sched = serving.ServingScheduler(
+        serve_ssm_moe.param_shapes(config), cfg, slots=program["slots"],
+        n_inner=program["n_inner"], quantize_kv=program["quantize_kv"],
+        page_tokens=program["page_tokens"],
+        prompt_chunk=program["prompt_chunk"],
+        max_prompt=program["max_prompt"])
+    assert sched.use_kernel
+    assert sched._step_route == {"ssm_rule": "kernel"}
+    assert sched._layer_kinds == {"state_layers": 9, "row_layers": 1}
+    tick = serving._serving_scan_paged(
+        cfg, sched.n_inner, None, sched.temperature, None, True, sched.P)
+    args = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                        sched._scan_args())
+    # as on the chip: the kernels through Mosaic, not the interpreter
+    compiled = lambda: False
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        for module in (flash_attention, decode_attention, ssm_step):
+            patch.setattr(module, "_use_interpret", compiled)
+        text = tick.lower(*args).compile().as_text()
+    for name in ("ssm_step", "paged_decode_attention"):
+        assert f"%{name}" in text
+    assert text.count("tpu_custom_call") == 9 + 1 + 3 * 10
+    for held in ("f32[16,64,128,128]", "s8[193,64,1024]"):
+        assert held in text
+        moved = [line for line in text.splitlines() if held in _result_of(
+            line, "copy", "copy-start", "slice-start")]
+        assert not moved, moved[:3]

@@ -402,7 +402,7 @@ def test_fields_are_checked_at_construction():
     with pytest.raises(ValueError, match="layer_mixers"):
         dataclasses.replace(CFG, layer_mixers=("gdn", "attn"))
     with pytest.raises(ValueError, match="'attn' or 'gdn'"):
-        dataclasses.replace(CFG, layer_mixers=("gdn", "ssm", "gdn", "attn"))
+        dataclasses.replace(CFG, layer_mixers=("gdn", "mamba", "gdn", "attn"))
     with pytest.raises(ValueError, match="gdn_key_heads"):
         dataclasses.replace(CFG, gdn_key_heads=3)
     with pytest.raises(ValueError, match="rope_dims"):
