@@ -109,17 +109,31 @@ Design (TPU-first):
   ONE row of the last chunk's hidden state, the prompt's last
   position: a chunk program stops at the last layer's output, so the
   head's weights are read once a request. Decode stall per tick is
-  bounded by one chunk a request, not one prompt. The chunks that are
-  due in one tick (every admitting slot's next, and the first of each
-  request the tick admits) run as ONE program over their concatenated
-  rows where they can, up to ``_chunk_group_cap`` of them a program
-  (``_extend_chunk_group``, ``decode._grouped_hidden``): every weight,
-  every expert, is read once for all of them, while each request's
-  K/V goes into its own arena at its own offset and its queries walk
-  that arena alone. The schedule is untouched: the same chunks run
-  between the same ticks, and a request's admission still ends (first
-  token, pages registered) before the next request is planned wherever
-  its end can change that plan (``_due_at_once``).
+  bounded by the tick's prefill programs, not by a prompt. The chunks
+  that are due in one tick (every admitting slot's next, and the first
+  of each request the tick admits) run as ONE program over their
+  concatenated rows where they can, up to ``_chunk_group_cap`` of them
+  a program (``_extend_chunk_group``, ``decode._grouped_hidden``):
+  every weight, every expert, is read once for all of them, while each
+  request's K/V goes into its own arena at its own offset and its
+  queries walk that arena alone. Grouping leaves the schedule as it
+  was: the same chunks run between the same ticks, and a request's
+  admission still ends (first token, pages registered) before the next
+  request is planned wherever its end can change that plan
+  (``_due_at_once``). Where such a program's rows are nearly free (a
+  group cap above 1: chunks wait for the weights' bytes) AND the
+  deployment serves documents (``max_prompt // prompt_chunk > slots``:
+  a prompt can hold its slot through more ticks of prefill than there
+  are slots), ONE request a tick takes a whole grouped program's rows
+  for itself: the one admitted first that has that many whole chunks
+  left before its last advances them all in the program
+  ``serving_prefill_chunk_w<cap>`` (``_extend_chunk_wide``: the lone
+  chunk's body at that width), the other due chunks are grouped as
+  ever, and a tick that goes wide runs one more prefill program at
+  most (``ServingScheduler._wide_slot``). Documents then finish one
+  after another and not side by side, each holding its slot a quarter
+  as long before its first token; the tokens are the same. Every other
+  scheduler builds no such program and runs the schedule it ran.
 * **A tick's admissions are planned behind the tick before.** Where a
   request can only end by its length (no ``eos_id``, no drafter:
   ``ServingScheduler._ends_known``) a decoding slot gains exactly
@@ -1507,7 +1521,10 @@ def _chunk_group_cap(cfg: TransformerConfig, C: int, slots: int) -> int:
     needs it or not: a group that does not fill it is padded
     (:meth:`ServingScheduler._run_chunk_group`), which a chunk that
     waits for bytes hardly feels. At most 4: a backlog of mixed lengths
-    has 2 to 4 chunks due in nine of ten of 16 slots' ticks."""
+    has 2 to 4 chunks due in nine of ten of 16 slots' ticks. The same
+    number is the width, in chunks, of the one further program a
+    scheduler that serves documents holds (:func:`_extend_chunk_wide`):
+    as many rows as the grouped program has, for the same reason."""
     share = min(
         (cfg.experts_per_token / cfg.n_experts if cfg.dropless(li) else 1.0
          for li in range(cfg.n_layers)), default=1.0)
@@ -1534,6 +1551,30 @@ def _extend_chunk_group(cfg: TransformerConfig, C: int, Lmax: int, n: int):
     chunk_group.__name__ = chunk_group.__qualname__ = (
         f"serving_prefill_chunk_x{n}")
     return jax.jit(chunk_group, donate_argnums=(2,))
+
+
+@functools.lru_cache(maxsize=32)
+def _extend_chunk_wide(cfg: TransformerConfig, C: int, Lmax: int, g: int):
+    """:func:`_extend_chunk_dense` at ``g`` chunks' rows of ONE request:
+    (params, chunk (1, g * C), cache, offset[, valid][, nxt]) -> (hidden
+    (1, g * C, d), cache), the lone chunk's body at another width (the
+    chunk's walk lies on absolute positions and a recurrent layer takes
+    any whole number of its sub-chunks, so the arena and the state come
+    out as ``g`` chunks one after another leave them). A program of the
+    grouped one's rows, all of them one prompt's: a document advances
+    ``g`` chunks where a chunk waits for the weights' bytes
+    (:meth:`ServingScheduler._wide_slot`). One program per ``(cfg, C,
+    Lmax, g)``, named ``serving_prefill_chunk_w<g>``."""
+
+    def chunk_wide(params, chunk, cache, offset, valid=None, nxt=None):
+        return _incremental_hidden(
+            params, chunk, cache, offset, cfg, prefill=False, valid=valid,
+            nxt=nxt,
+        )
+
+    chunk_wide.__name__ = chunk_wide.__qualname__ = (
+        f"serving_prefill_chunk_w{g}")
+    return jax.jit(chunk_wide, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=32)
@@ -1699,6 +1740,13 @@ class _ServingObs:
             "serving_prefill_chunks_total",
             help="admission prefill chunks advanced",
         )
+        # (only a scheduler that has the wide program has the series)
+        self.m_prefill_wide = None if sched._extend_wide is None else (
+            registry.counter(
+                "serving_prefill_wide_chunks_total",
+                help="those of them that one request advanced together, "
+                "in the wide prefill program",
+            ))
         # the route resolved for THIS scheduler (fixed
         # at construction against its slot count — see use_kernel);
         # incremented once per decode tick, so the series records when
@@ -1809,9 +1857,11 @@ class _ServingObs:
                 self.m_intertoken.observe((t - last) / n)
         req._t_last_tok = t
 
-    def prefill_chunk(self) -> None:
+    def prefill_chunk(self, n: int = 1, wide: bool = False) -> None:
         if self._r:
-            self.m_prefill.inc()
+            self.m_prefill.inc(n)
+            if wide:
+                self.m_prefill_wide.inc(n)
 
     def fleet_hit(self, tier: str) -> None:
         """One prefix page served from the fleet cache (``dram`` |
@@ -1998,7 +2048,9 @@ class ServingScheduler:
     >>> r.tokens                               # greedy == oracle
 
     Each ``step()`` tick: (1) advance every admitting request by one
-    prefill chunk, installing finished ones into their slot; (2) admit
+    prefill chunk (where a wide program exists, the module note, the
+    oldest document by ``_chunk_group_cap`` chunks), installing
+    finished ones into their slot; (2) admit
     queued requests into free slots, each running its first chunk
     (chunks due together share their programs); (3) run ``n_inner``
     decode steps for all slots in one device program; (4) harvest
@@ -2022,8 +2074,14 @@ class ServingScheduler:
     prompt.
 
     ``prompt_chunk`` bounds the decode stall a long prompt can inject
-    into in-flight requests (one chunk per tick); ``max_prompt`` sizes
-    the transient prefill arena (one compile for all prompt lengths).
+    into in-flight requests (a tick runs one chunk of each admitting
+    request, as many a program as share one; where the scheduler has
+    the wide program, at most one request a tick advances a grouped
+    program's rows instead, and that tick runs at most one program
+    more: ``prefill_chunks`` and ``wide_chunks`` count both);
+    ``max_prompt`` sizes the transient prefill arena (one compile for
+    all prompt lengths) and, with ``slots``, says whether the
+    deployment serves documents (the module note).
 
     The cache is a PAGE POOL (docs/API.md "Paged serving cache"):
     per-layer K/V live in ``cache_pages`` fixed-size pages of
@@ -2250,6 +2308,12 @@ class ServingScheduler:
         # chunks of one tick share their programs (_run_pending)
         self._pending: list[int] = []
         self._tick_chunks = self._tick_chunk_programs = 0
+        self._tick_wide = 0  # of ``_tick_chunks``, in the wide program
+        # the chunks (of ``prompt_chunk`` rows) run so far, and those of
+        # them that ran in the wide program: over any stretch of ticks
+        # the share of prefill rows that ran wide is the quotient of
+        # their differences
+        self.prefill_chunks = self.wide_chunks = 0
         self.tick_count = 0
         # the ticks whose admissions were planned behind the tick
         # before them (``step``), and what that plan left for the tick
@@ -2368,6 +2432,14 @@ class ServingScheduler:
             if self._group > 1 else None
         )
         self._scratch_arenas: list[list[dict]] | None = None
+        # and ``_group`` chunks of ONE prompt in a program of its own
+        # (_wide_slot), where width is free (chunks wait for bytes) and
+        # can engage (a prompt can need more ticks of prefill than there
+        # are slots: the deployment serves documents); None elsewhere
+        self._extend_wide = (
+            _extend_chunk_wide(cfg, self.C, self.Lmax, self._group)
+            if self._group > 1 and self.Lmax // self.C > self.S else None
+        )
         # ``serving.prefill_chunk``'s ``expert_tile``, by the chunks
         # the program holds: the k x n tile its grouped gate and up
         # products take (``moe.group_tiling``: whole K says each
@@ -2385,14 +2457,17 @@ class ServingScheduler:
         # (``transformer.gdn_rule_route`` / ``la_rule_route``, which
         # the halves ask too); nothing without such a layer
         mixers = cfg.layer_mixers or ()
-        self._rule_routes = {
-            **({"gdn_rule": gdn_rule_route(cfg, self.C)}
+        rule_routes = lambda T: {
+            **({"gdn_rule": gdn_rule_route(cfg, T)}
                if "gdn" in mixers else {}),
-            **({"la_rule": la_rule_route(cfg, self.C)}
+            **({"la_rule": la_rule_route(cfg, T)}
                if "la" in mixers else {}),
-            **({"ssm_rule": ssm_rule_route(cfg, self.C)}
+            **({"ssm_rule": ssm_rule_route(cfg, T)}
                if cfg.ssm_layers else {}),
         }
+        self._rule_routes = rule_routes(self.C)
+        # (the wide program's rows are one call of the recurrence)
+        self._wide_routes = rule_routes(self._group * self.C)
         # ``serving.decode``'s ``gdn_rule``: the form ONE token takes
         # in a step of the tick, from the same function
         self._step_route = {
@@ -2769,7 +2844,7 @@ class ServingScheduler:
         begin = self._tick_begin()
         self._advance_admissions(retired)
         self._admit_from_queue(retired)
-        self._run_pending(retired)
+        self._run_pending(retired, last=True)
         self._ahead = (begin, self.pending)
 
     def _tick_begin(self) -> dict:
@@ -2881,13 +2956,18 @@ class ServingScheduler:
                 if planned is None:
                     self._advance_admissions(retired)
                 self._admit_from_queue(retired)
-                self._run_pending(retired)
+                self._run_pending(retired, last=True)
             phases.append(("admit", admit))
             # the chunks this tick ran (the admitting slots' and the
-            # first of each request it admitted), in how many programs
+            # first of each request it admitted; a wide program's count
+            # as the chunks they are), in how many programs, and where
+            # there is a wide program how many of them ran in it
             tick.set_metadata(chunks=self._tick_chunks,
-                              chunk_programs=self._tick_chunk_programs)
+                              chunk_programs=self._tick_chunk_programs,
+                              **({} if self._extend_wide is None else
+                                 {"wide_chunks": self._tick_wide}))
             self._tick_chunks = self._tick_chunk_programs = 0
+            self._tick_wide = 0
             decoding = [
                 s for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._admitting
@@ -3988,9 +4068,9 @@ class ServingScheduler:
         that is first to have ONE chunk due: a full backlog's first
         ticks fill every program of a group, and with a drafter which
         tick comes to a lone chunk follows the seed's acceptances; so
-        the lone chunk's program has a run here too. Two arenas fewer
-        than the grouped program takes stay as its scratch (a group is
-        at least two)."""
+        the lone chunk's program has a run here too, and the wide one
+        where the scheduler has it. Two arenas fewer than the grouped
+        program takes stay as its scratch (a group is at least two)."""
         n = self._group
         valid = ((np.zeros((n,), np.int32),)
                  if self.cfg.counts_rows else ())
@@ -4005,6 +4085,12 @@ class ServingScheduler:
             self.params, np.zeros((1, self.C), np.int32), arenas[0],
             np.int32(0), *(v[0] for v in valid),
             **{k: v[:1] for k, v in nxt.items()})
+        if self._extend_wide is not None:
+            # the tick that is first to go wide pays no compile either
+            self._extend_wide(
+                self.params, np.zeros((1, n * self.C), np.int32), arenas[1],
+                np.int32(0), *(v[0] for v in valid),
+                **{k: v.reshape(1, -1) for k, v in nxt.items()})
         self._scratch_arenas = list(arenas[2:])
 
     def _advance_admissions(self, retired: list[Request]) -> None:
@@ -4034,13 +4120,22 @@ class ServingScheduler:
             or st.req.max_new == 1 or self.eos_id is not None
         )
 
-    def _run_pending(self, retired: list[Request]) -> None:
+    def _run_pending(self, retired: list[Request],
+                     last: bool = False) -> None:
         """Dispatch the chunks that are due, in the order the slots
         came, as many a program as the grouped program takes (a lone
         one left over, or every one where there is no grouped program,
         in the program of one chunk); after each program the requests
-        whose last chunk it held are finished, in the same order."""
+        whose last chunk it held are finished, in the same order.
+        ``last``: the tick's admit phase ends with this call, which is
+        where one of the slots may go wide (:meth:`_wide_slot`): its
+        program runs first, the others are grouped as ever."""
         slots, self._pending = self._pending, []
+        if last and self._extend_wide is not None:
+            wide = self._wide_slot(slots)
+            if wide is not None:
+                self._run_wide_chunk(wide)
+                slots.remove(wide)
         for at in range(0, len(slots), self._group):
             members = slots[at:at + self._group]
             self._run_chunk_group(members)
@@ -4048,6 +4143,74 @@ class ServingScheduler:
                 st = self._admitting[s]
                 if st.next_chunk == st.n_chunks:
                     self._finish_admission(s, retired)
+
+    def _wide_slot(self, slots: list[int]) -> int | None:
+        """Of the slots whose chunk is due, the one that advances
+        ``_group`` chunks in the wide program in this tick, or None.
+        The one admitted first (``slots`` is in admission's order) that
+        has ``_group`` whole chunks left BEFORE its last, which stays a
+        chunk of ``prompt_chunk`` rows (the first token is read off its
+        hidden state, at its offset): the oldest document finishes
+        first, and the documents one after another and not side by
+        side. None where the tick has run a prefill program already or
+        more than ``_group`` other chunks are due: a tick that goes
+        wide runs ONE more prefill program at most, so never more than
+        two, or than it would have run anyway; every decoding slot
+        waits for all of them. Counts the host has, never a token's
+        value: a plan made behind the running tick stays valid."""
+        g = self._group
+        if self._tick_chunk_programs or len(slots) > g + 1:
+            return None
+        for s in slots:
+            st = self._admitting[s]
+            if st.n_chunks - 1 - st.next_chunk >= g:
+                return s
+        return None
+
+    def _run_wide_chunk(self, s: int) -> None:
+        """The wide program for slot ``s``: its next ``_group`` chunks
+        as one chunk of their rows, every one the prompt's."""
+        C, g = self.C, self._group
+        st = self._admitting[s]
+        at, off = st.next_chunk * C, st.base + st.next_chunk * C
+        offs = [off + i * C for i in range(g)]
+        with _annotate(
+            "serving.prefill_chunk", chunks=g, wide=1, req=st.req.id,
+            slot=s, chunk=st.next_chunk, of=st.n_chunks,
+            rows_seen=_chunk_rows_seen(off, g * C, self.Lmax,
+                                       self.cfg.windows),
+            **self._expert_tile.get(g, {}), **self._wide_routes,
+            **self._layer_kinds,
+            **self._sparse_chunk_counts([st] * g, offs),
+        ):
+            valid = ((np.int32(g * C),) if self.cfg.counts_rows else ())
+            nxt = ({"nxt": st.padded[:, at + 1:at + g * C + 1]}
+                   if self.draft is not None else {})
+            # the hidden state goes unread: no row of a wide chunk is
+            # a prompt's last
+            _, st.cache = self._extend_wide(
+                self.params, st.padded[:, at:at + g * C], st.cache,
+                np.int32(off), *valid, **nxt)
+        st.last_hidden = None
+        self._tick_chunk_programs += 1
+        self._chunks_advanced(st, g)
+
+    def _chunks_advanced(self, st: _Admitting, n: int = 1) -> None:
+        """A program has run the request's next ``n`` chunks (more than
+        one: the wide program): the cursor and the counts."""
+        st.next_chunk += n
+        self._tick_chunks += n
+        self.prefill_chunks += n
+        if n > 1:
+            self._tick_wide += n
+            self.wide_chunks += n
+        if self._obs is not None:
+            self._obs.prefill_chunk(n, wide=n > 1)
+        if self._trace is not None and st.req.trace is not None:
+            self._trace.event(
+                st.req.trace, "prefill_chunk", time.perf_counter(),
+                tick=self._admit_tick, **({"chunks": n} if n > 1 else {}),
+            )
 
     def _run_chunk_group(self, slots: list[int]) -> None:
         """One prefill program for the next chunk of each of ``slots``:
@@ -4113,18 +4276,10 @@ class ServingScheduler:
                     np.array(offs + [0] * pad, np.int32), *valid, **nxt,
                 )
                 self._scratch_arenas[:pad] = caches[n:]
-        self._tick_chunks += n
         self._tick_chunk_programs += 1
         for st, h, cache in zip(sts, hidden, caches):
             st.last_hidden, st.cache = h, cache
-            st.next_chunk += 1
-            if self._obs is not None:
-                self._obs.prefill_chunk()
-            if self._trace is not None and st.req.trace is not None:
-                self._trace.event(
-                    st.req.trace, "prefill_chunk", time.perf_counter(),
-                    tick=self._admit_tick,
-                )
+            self._chunks_advanced(st)
 
     def _finish_admission(self, s: int, retired: list[Request]) -> None:
         """The request in slot ``s`` has had its last chunk: first

@@ -30,7 +30,8 @@ kind                      stamped by / meaning
 ``drr_queued``            DRR admission queue entry; attrs: tenant
 ``drr_picked``            DRR grant; attrs: tenant, cost
 ``admitted``              placed into a slot; attrs: replica/tick
-``prefill_chunk``         one prompt chunk advanced; attrs: replica
+``prefill_chunk``         one prompt chunk advanced (``chunks`` of them
+                          in a wide prefill program); attrs: replica
 ``first_token``           first decode token surfaced
 ``share_hit``             prefix page shared instead of prefilled
 ``cow_copy``              copy-on-write fork of a shared page

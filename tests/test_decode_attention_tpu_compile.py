@@ -632,3 +632,63 @@ def test_granite4h_tick_leaves_states_and_pool_where_they_lie(one_chip):
         moved = [line for line in text.splitlines() if held in _result_of(
             line, "copy", "copy-start", "slice-start")]
         assert not moved, moved[:3]
+
+
+def test_wide_prefill_program_compiles_at_the_long_cells_widths(one_chip):
+    """``serve_q3next_long``'s scheduler (the cell's own configuration
+    file: 16 slots, chunks of 256, prompts of up to 32,768) is the one
+    of the benchmark's that holds the wide prefill program, and the
+    program compiles as the chip would run it: 1,024 rows of one
+    request through the chunked delta-rule kernel in its three state
+    layers (whole sub-chunks of 128, two value heads a grid step) and
+    three grouped products in each of the four expert layers, the arena
+    donated. The same model with ``serve_q3next_mixed``'s program has
+    none: a prompt of 4,096 is 16 chunks, and there are 16 slots."""
+    import json
+    import pathlib
+
+    from chipbench.runners import serve_gdn
+    from mpistragglers_jl_tpu.models import serving
+    from mpistragglers_jl_tpu.ops import (
+        decode_attention,
+        delta_rule,
+        flash_attention,
+    )
+
+    root = pathlib.Path(__file__).resolve().parent.parent / "chipbench"
+
+    def scheduler(name):
+        config = json.loads((root / "configs" / name).read_text())
+        cfg, program = serve_gdn.transformer_config(config), config["program"]
+        return serving.ServingScheduler(
+            serve_gdn.param_shapes(config), cfg, slots=program["slots"],
+            n_inner=program["n_inner"], quantize_kv=program["quantize_kv"],
+            page_tokens=program["page_tokens"],
+            prompt_chunk=program["prompt_chunk"],
+            max_prompt=program["max_prompt"])
+
+    mixed = scheduler("q3next-80b-a3b-serve.json")
+    assert mixed._group == 4 and mixed._extend_wide is None
+    sched = scheduler("q3next-80b-a3b-serve-long.json")
+    wide, rows = sched._extend_wide, sched._group * sched.C
+    assert wide.__name__ == "serving_prefill_chunk_w4" and rows == 1024
+    assert sched._wide_routes == {"gdn_rule": "kernel"}
+    arena = jax.eval_shape(lambda: serving._fresh_cache(
+        sched.cfg, 1, sched.Lmax, sched.quantize_kv))
+    args = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (sched.params, jnp.zeros((1, rows), jnp.int32), arena,
+         jnp.int32(0), jnp.int32(0)))
+    # as on the chip: the kernels through Mosaic, not the interpreter
+    compiled = lambda: False
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        for module in (flash_attention, decode_attention, delta_rule):
+            patch.setattr(module, "_use_interpret", compiled)
+        program = wide.lower(*args).compile()
+    text = program.as_text()
+    assert "%delta_rule" in text
+    assert text.count("tpu_custom_call") == 3 + 3 * 4
+    # the arena goes in and comes out in place
+    memory = program.memory_analysis()
+    assert memory.alias_size_in_bytes >= 40e6
+    assert memory.temp_size_in_bytes < 1e9

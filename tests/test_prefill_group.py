@@ -370,7 +370,7 @@ def test_a_chunk_joins_the_chunks_that_are_due_when_it_is_admitted(spans):
     """A request admitted while others are mid-prompt runs its first
     chunk in their program, where none of them ends its admission in
     that tick."""
-    sched = _sched(slots=4, page_tokens=4)
+    sched = _sched(page_tokens=4)
     long_a, long_b, short = _prompts((40, 33, 6))
     a = sched.submit(long_a, max_new=3)
     b = sched.submit(long_b, max_new=3)

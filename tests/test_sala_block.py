@@ -262,7 +262,7 @@ def test_a_slot_taken_again_starts_from_nothing(model):
 def test_the_pages_pooled_cells_are_the_keys_means(model):
     cfg, params = model
     prompt = tokens(37, seed=21)
-    sched = ServingScheduler(params, cfg, slots=2, n_inner=4,
+    sched = ServingScheduler(params, cfg, slots=6, n_inner=4,
                              quantize_kv=False, page_tokens=8,
                              prompt_chunk=16, max_prompt=96)
     req = sched.submit(prompt, 12)

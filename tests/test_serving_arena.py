@@ -238,7 +238,9 @@ def test_shared_prefix_seeds_a_recycled_arena_as_it_seeds_a_new_one(
 @KINDS
 def test_cancel_mid_prefill_returns_the_arena_and_the_next_reuses_it(
         page_tokens, quantize_kv):
-    sched = _sched(page_tokens, quantize_kv)
+    # (four slots: with three, a prompt of four chunks would take three
+    # of them in one wide program, tests/test_prefill_wide.py)
+    sched = _sched(page_tokens, quantize_kv, slots=4)
     kinds = _recorded(sched)
     doomed = sched.submit(_prompt(7, 30), max_new=4)  # four chunks
     sched.step()
